@@ -11,22 +11,7 @@ Capability parity with ``petuum/autodist`` (see SURVEY.md); architecture is
 JAX/XLA-first: strategies lower to ``jax.sharding`` annotations (GSPMD) or a
 ``shard_map`` explicit-collective path — no graph surgery, no SSH fabric.
 """
-# Version gate (parity: /root/reference/autodist/__init__.py:35-43 pins
-# TF [1.15, 2.2); we require a jax with shard_map + NamedSharding).
-# 0.4.x jaxlibs carry shard_map under jax.experimental with the pre-rename
-# keywords; utils.compat grafts the modern surface on so one codebase spans
-# both — it must run before any submodule (or test) touches jax.shard_map.
-import jax as _jax
-
-from autodist_tpu.utils import compat as _compat
-
-_compat.install()
-if not hasattr(_jax, "shard_map"):  # pragma: no cover
-    raise ImportError(
-        f"autodist_tpu requires a jax with shard_map (>= 0.4.35); "
-        f"found {_jax.__version__}")
-
-from autodist_tpu._version import __version__  # noqa: E402
-from autodist_tpu.autodist import AutoDist, get_default_autodist  # noqa: E402
+from autodist_tpu._version import __version__
+from autodist_tpu.autodist import AutoDist, get_default_autodist
 
 __all__ = ["AutoDist", "get_default_autodist", "__version__"]

@@ -200,9 +200,11 @@ def _restore_raw_host(path):
     if os.path.isdir(default):  # CheckpointManager step dirs nest the item
         path = default
     ckptr = ocp.PyTreeCheckpointer()
+    # metadata() is a StepMetadata; the saved pytree's structure is the
+    # ``tree`` of its item metadata.
     restore_args = jax.tree_util.tree_map(
         lambda _: ocp.RestoreArgs(restore_type=np.ndarray),
-        ckptr.metadata(path))
+        ckptr.metadata(path).item_metadata.tree)
     return ckptr.restore(
         path, args=ocp.args.PyTreeRestore(restore_args=restore_args))
 

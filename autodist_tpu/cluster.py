@@ -137,13 +137,15 @@ class Cluster:
                  const.MESH_AXIS_MODEL: 4}
         names = sorted(axis_sizes, key=lambda a: order.get(a, 99))
         shape = tuple(axis_sizes[a] for a in names)
-        try:
-            # Preferred: topology-aware layout (respects ICI torus on real pods).
+        if devices.flat[0].platform == "cpu":
+            # Forced-host CPU devices carry no topology: plain order.
+            mesh_devices = devices.reshape(shape)
+        else:
+            # Topology-aware layout (respects the ICI torus); a failure
+            # here is a wrong mesh request and raises.
             from jax.experimental import mesh_utils
             mesh_devices = mesh_utils.create_device_mesh(
                 shape, devices=devices.flatten().tolist())
-        except Exception:  # noqa: BLE001 - forced-host CPU platforms may lack topology info
-            mesh_devices = devices.reshape(shape)
         self._mesh = Mesh(mesh_devices, axis_names=tuple(names))
         logging.info("Built mesh %s over %d devices", dict(zip(names, shape)), n)
         observability.record_event(

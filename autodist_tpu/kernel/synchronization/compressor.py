@@ -84,10 +84,7 @@ def _int8_quantize(x, block=_INT8_BLOCK):
 
 def _axis_size(axis_name):
     # Static at trace time for a named mesh axis.
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:  # older jax
-        return jax.lax.psum(1, axis_name)
+    return jax.lax.axis_size(axis_name)
 
 
 def _int8_allgather_mean(q, scale, pad, shape, dtype, axis_name):

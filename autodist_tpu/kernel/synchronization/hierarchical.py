@@ -20,9 +20,9 @@ magnitude faster, per ``Topology`` tiers), the DCN leg carries
 Leg layout over the runner's flat ``data`` axis (host-major device order,
 as produced by ``ResourceSpec``): with d = devices/host and h = hosts,
 ICI group g_h = [h*d .. h*d+d-1], DCN group g_i = [i, d+i, 2d+i, ...].
-Execution uses subgroup collectives (``axis_index_groups``) when the
-jaxlib supports them (``utils/compat.grouped_collectives_supported``),
-else intra-group ppermute rings.  :func:`hier_mean_nested` is the same
+Execution uses subgroup collectives (``axis_index_groups``);
+``grouped=False`` forces the same schedule over intra-group ppermute rings
+(the tests' second transport).  :func:`hier_mean_nested` is the same
 schedule over explicit nested ``(dcn, ici)`` mesh axes (see
 ``cluster.build_hierarchical_mesh``).
 
@@ -220,13 +220,13 @@ def init_hier_state(n, d, h, codec, dtype=jnp.float32):
 
 
 def hier_mean(x, axis_name, codec="bf16", devices_per_host=None, state=(),
-              grouped=None):
+              grouped=True):
     """Hierarchical mean all-reduce of ``x`` over the flat ``axis_name``.
 
     Returns ``(mean, new_state)``.  ``state`` is the EF residual for
     ``int8ef`` (from :func:`init_hier_state`), ``()`` otherwise.
-    ``grouped=None`` probes ``utils/compat`` for subgroup-collective
-    support; pass True/False to force a transport (tests)."""
+    ``grouped=False`` swaps the subgroup collectives for ppermute rings
+    (``tests/test_hierarchical.py`` runs both on the installed stack)."""
     W = _axis_size(axis_name)
     d, h = resolve_legs(W, devices_per_host)
     if h == 1:
@@ -239,9 +239,6 @@ def hier_mean(x, axis_name, codec="bf16", devices_per_host=None, state=(),
         if codec == "int8ef":
             st = st.reshape(-1)
         return out, st
-    if grouped is None:
-        from autodist_tpu.utils import compat
-        grouped = compat.grouped_collectives_supported()
     shape, dtype = x.shape, x.dtype
     flat = x.ravel().astype(jnp.float32)
     n = flat.shape[0]
@@ -272,10 +269,9 @@ def hier_mean(x, axis_name, codec="bf16", devices_per_host=None, state=(),
 
 
 def _hier_mean_ppermute(flat, state, axis_name, codec, d, h, shard):
-    """Fallback transport: the same three-leg schedule built from
+    """Second transport: the same three-leg schedule built from
     intra-group ppermute rings (every edge stays within one ICI or one
-    DCN group, so it runs where ``axis_index_groups`` collectives don't
-    lower).  ``flat`` is padded f32 of length ``shard * d``."""
+    DCN group).  ``flat`` is padded f32 of length ``shard * d``."""
     W = d * h
     idx = jax.lax.axis_index(axis_name)
     pos = jnp.mod(idx, d)                       # position within the host

@@ -141,29 +141,33 @@ _STARTUP_PHASES = ("capture", "strategy-build", "strategy-ship",
 _COMPILE_PHASES = ("compile", "aot-compile", "serve-aot-compile")
 
 #: Per-device peak TFLOP/s by device-kind substring (bf16/dense), checked
-#: in order; the platform defaults catch unknown parts.  Override with
-#: ``AUTODIST_PEAK_TFLOPS`` (docs/goodput.md has the table).
+#: in order.  An accelerator whose kind is not listed is an error, never a
+#: default.  Override with ``AUTODIST_PEAK_TFLOPS`` (docs/goodput.md has
+#: the table).
 PEAK_TFLOPS_TABLE = (
     ("v6e", 918.0), ("trillium", 918.0), ("v5p", 459.0),
     ("v5 lite", 197.0), ("v5e", 197.0), ("v4", 275.0),
     ("v3", 123.0), ("v2", 45.0),
     ("h100", 989.0), ("a100", 312.0), ("v100", 125.0),
 )
-PLATFORM_DEFAULT_TFLOPS = {"tpu": 197.0, "gpu": 312.0, "cpu": 0.05}
+#: The forced-device CPU test mesh: a nominal figure so MFU arithmetic
+#: runs in the CPU tier.
+CPU_TFLOPS = 0.05
 
 #: Per-device HBM capacity (GiB) by device-kind substring, same lookup
 #: shape as :data:`PEAK_TFLOPS_TABLE`; the memory ledger's feasibility
 #: checks price candidates against it (``AUTODIST_HBM_GB`` override, spec
-#: ``memory:`` block — docs/memory.md).  The CPU "device" default is the
-#: host-RAM ballpark a forced-device CPU test mesh actually has, so the
-#: CPU container never prunes candidates by accident.
+#: ``memory:`` block — docs/memory.md).
 PEAK_HBM_GB_TABLE = (
     ("v6e", 32.0), ("trillium", 32.0), ("v5p", 95.0),
     ("v5 lite", 16.0), ("v5e", 16.0), ("v4", 32.0),
     ("v3", 32.0), ("v2", 16.0),
     ("h100", 80.0), ("a100", 40.0), ("v100", 16.0),
 )
-PLATFORM_DEFAULT_HBM_GB = {"tpu": 16.0, "gpu": 40.0, "cpu": 64.0}
+#: The CPU "device" figure is the host-RAM ballpark a forced-device CPU
+#: test mesh actually has, so the CPU container never prunes candidates by
+#: accident.
+CPU_HBM_GB = 64.0
 
 _process_start = time.time()
 _last_summary = None
@@ -209,50 +213,45 @@ def reexec_env():
 # ---------------------------------------------------------------------------
 # peak flops
 
+def _lookup_by_kind(table, cpu_value, what, device):
+    """``table``'s entry for ``device``'s kind; ``cpu_value`` on the CPU
+    platform; anything else raises."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    kind = str(device.device_kind).lower()
+    for needle, value in table:
+        if needle in kind:
+            return value
+    if str(device.platform).lower() == "cpu":
+        return cpu_value
+    raise ValueError(
+        f"no {what} known for device kind {device.device_kind!r} "
+        f"(platform {device.platform!r}); add it to the table in "
+        f"observability/goodput.py or set the env override")
+
+
 def peak_flops_per_device(device=None):
     """Peak FLOP/s of one device: the ``AUTODIST_PEAK_TFLOPS`` override
-    when set, else the built-in table keyed by device kind/platform."""
+    when set, else the built-in table keyed by device kind.  Raises for an
+    accelerator the table does not list."""
     override = const.ENV.AUTODIST_PEAK_TFLOPS.val
     if override and override > 0:
         return float(override) * 1e12
-    kind, platform = "", "cpu"
-    try:
-        if device is None:
-            import jax
-            device = jax.devices()[0]
-        kind = str(getattr(device, "device_kind", "")).lower()
-        platform = str(getattr(device, "platform", "cpu")).lower()
-    except Exception:  # noqa: BLE001 - pre-init: fall to platform default
-        pass
-    for needle, tflops in PEAK_TFLOPS_TABLE:
-        if needle in kind:
-            return tflops * 1e12
-    return PLATFORM_DEFAULT_TFLOPS.get(platform,
-                                       PLATFORM_DEFAULT_TFLOPS["cpu"]) * 1e12
+    return _lookup_by_kind(PEAK_TFLOPS_TABLE, CPU_TFLOPS,
+                           "peak TFLOP/s", device) * 1e12
 
 
 def peak_hbm_bytes_per_device(device=None):
     """HBM capacity of one device in bytes: the ``AUTODIST_HBM_GB``
-    override when set, else the built-in table keyed by device
-    kind/platform — the same resolution shape as
-    :func:`peak_flops_per_device` (docs/memory.md)."""
+    override when set, else the built-in table keyed by device kind —
+    the same resolution as :func:`peak_flops_per_device`
+    (docs/memory.md)."""
     override = const.ENV.AUTODIST_HBM_GB.val
     if override and override > 0:
         return float(override) * (1 << 30)
-    kind, platform = "", "cpu"
-    try:
-        if device is None:
-            import jax
-            device = jax.devices()[0]
-        kind = str(getattr(device, "device_kind", "")).lower()
-        platform = str(getattr(device, "platform", "cpu")).lower()
-    except Exception:  # noqa: BLE001 - pre-init: fall to platform default
-        pass
-    for needle, gb in PEAK_HBM_GB_TABLE:
-        if needle in kind:
-            return gb * (1 << 30)
-    return PLATFORM_DEFAULT_HBM_GB.get(
-        platform, PLATFORM_DEFAULT_HBM_GB["cpu"]) * (1 << 30)
+    return _lookup_by_kind(PEAK_HBM_GB_TABLE, CPU_HBM_GB,
+                           "HBM capacity", device) * (1 << 30)
 
 
 # ---------------------------------------------------------------------------

@@ -14,8 +14,9 @@ the O(s^2) score transient of the old dense-recompute VJP never
 materializes. Block position offsets ride in as scalar-prefetch operands,
 so they may be traced values (ring attention's rotating K/V offsets).
 
-Falls back to the dense jnp path off-TPU (CPU tests use ``interpret=True``
-to exercise the kernels in the Pallas interpreter).
+Off TPU the dense jnp path runs instead (CPU tests use ``interpret=True``
+to exercise the kernels in the Pallas interpreter); every trace logs once,
+at info, which path it took and why.
 """
 import functools
 import math
@@ -24,8 +25,37 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from autodist_tpu import const
+from autodist_tpu.utils import logging
 
 _NEG_INF = -1e30
+_logged_paths = set()
+
+
+def _log_path(path, why):
+    """One info line per distinct (path, reason): a run that meant to
+    compile the kernels and got the dense reference says so."""
+    if (path, why) not in _logged_paths:
+        _logged_paths.add((path, why))
+        logging.info("flash_attention: %s path (%s)", path, why)
+
+
+def _pallas_interpret(interpret):
+    """Resolve the ``interpret`` argument at trace time: the flag to hand
+    ``pallas_call``, or None when this trace takes the dense reference
+    (``interpret=None`` off TPU)."""
+    if interpret is not None:
+        _log_path("interpreted pallas" if interpret else "pallas",
+                  f"interpret={bool(interpret)} requested")
+        return bool(interpret)
+    backend = jax.default_backend()
+    if backend == "tpu":
+        _log_path("pallas", "backend is tpu")
+        return False
+    _log_path("dense", f"backend is {backend}; the kernels compile for tpu")
+    return None
 
 
 def _sds(shape, dtype, *arrays):
@@ -208,6 +238,7 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, k_offset,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(offs, qr, kr, vr)
     return out.reshape(b, h, sq, d), lse.reshape(b, h, sq, 1)
 
@@ -340,6 +371,7 @@ def _flash_bwd(q, k, v, do, lse, delta, causal, block_q, block_k, q_offset,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(offs, qr, kr, vr, dor, lser, deltar)
 
     dk, dv = pl.pallas_call(
@@ -359,6 +391,7 @@ def _flash_bwd(q, k, v, do, lse, delta, causal, block_q, block_k, q_offset,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(offs, qr, kr, vr, dor, lser, deltar)
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
@@ -370,8 +403,11 @@ def _flash_bwd(q, k, v, do, lse, delta, causal, block_q, block_k, q_offset,
 def _use_pallas(sq, sk, block_q, block_k, interpret):
     if interpret:
         return True
-    return (jax.default_backend() == "tpu" and
-            sq % min(block_q, sq) == 0 and sk % min(block_k, sk) == 0)
+    if sq % min(block_q, sq) or sk % min(block_k, sk):
+        _log_path("dense", f"block attention: seq ({sq}, {sk}) does not "
+                           f"divide blocks ({block_q}, {block_k})")
+        return False
+    return _pallas_interpret(None) is not None
 
 
 def block_attn_fwd(q, k, v, causal, q_offset, k_offset, block_q=512,
@@ -428,21 +464,19 @@ def flash_attention(q, k, v, causal=False, block_q=512, block_k=1024,
     sequence); it must be a multiple of ``block_q``. ``interpret=None``
     picks the Pallas kernels on TPU and the dense path elsewhere.
     """
+    interpret = _pallas_interpret(interpret)
     if interpret is None:
-        if jax.default_backend() != "tpu":
-            return _dense_reference(q, k, v, causal, q_offset)
-        interpret = False
+        return _dense_reference(q, k, v, causal, q_offset)
     o, _ = _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, 0,
                       interpret)
     return o
 
 
 def _fwd_rule(q, k, v, causal, block_q, block_k, q_offset, interpret):
+    interpret = _pallas_interpret(interpret)
     if interpret is None:
-        if jax.default_backend() != "tpu":
-            o, lse = _dense_fwd(q, k, v, causal, q_offset)
-            return o.astype(q.dtype), (q, k, v, o.astype(q.dtype), lse)
-        interpret = False
+        o, lse = _dense_fwd(q, k, v, causal, q_offset)
+        return o.astype(q.dtype), (q, k, v, o.astype(q.dtype), lse)
     o, lse = _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, 0,
                         interpret)
     return o, (q, k, v, o, lse)
@@ -456,16 +490,60 @@ def _bwd_rule(causal, block_q, block_k, q_offset, interpret, res, do):
     # dense elsewhere); False = native Pallas kernels; True = interpreted
     # Pallas. An explicit False must NOT mean "dense" — that would hand the
     # default TPU transformer path the O(s^2) dense backward.
-    use_pallas = (interpret is not None) or jax.default_backend() == "tpu"
-    if use_pallas:
-        dq, dk, dv = _flash_bwd(q, k, v, do, lse, delta, causal, block_q,
-                                block_k, q_offset, 0, bool(interpret))
-    else:
+    interpret = _pallas_interpret(interpret)
+    if interpret is None:
         dq, dk, dv = _dense_bwd(q, k, v, do, lse, delta, causal, q_offset)
+    else:
+        dq, dk, dv = _flash_bwd(q, k, v, do, lse, delta, causal, block_q,
+                                block_k, q_offset, 0, interpret)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 flash_attention.defvjp(_fwd_rule, _bwd_rule)
+
+
+def _under_full_manual(fn, q, k, v):
+    """``fn(q, k, v)`` where a Mosaic kernel can lower on the active mesh.
+
+    jax refuses to partition a ``pallas_call`` automatically ("Mosaic
+    kernels cannot be automatically partitioned"): on a mesh of several
+    devices the kernel must sit in a region that is manual over every mesh
+    axis.  The Runner's explicit path over ``{data}`` alone already is one;
+    on the GSPMD path, or with further axes left automatic, the call goes
+    under a ``shard_map`` over the axes still free — batch split over
+    ``data``, heads over ``model``.  Any other axis of size > 1 would run
+    the whole kernel on each of its devices, so it raises instead.
+    """
+    from autodist_tpu.parallel import context as parallel_ctx
+    ctx = parallel_ctx.current()
+    mesh = ctx.mesh if ctx is not None else None
+    if mesh is None or mesh.size == 1:
+        return fn(q, k, v)
+    am = jax.sharding.get_abstract_mesh()
+    free = [a for a in mesh.axis_names if a not in am.manual_axes]
+    if not free:
+        return fn(q, k, v)
+    sizes = dict(mesh.shape)
+    dim_of = {const.MESH_AXIS_DATA: 0, const.MESH_AXIS_MODEL: 1}
+    spec = [None] * q.ndim
+    for a in free:
+        if sizes[a] == 1:
+            continue
+        dim = dim_of.get(a)
+        if dim is None or q.shape[dim] % sizes[a]:
+            raise NotImplementedError(
+                f"flash attention on mesh {sizes}: axis {a!r} cannot split "
+                f"q {q.shape} (batch over 'data', heads over 'model'), and "
+                f"leaving it automatic would run the whole kernel on each "
+                f"of its {sizes[a]} devices; pass attn_fn= to the model or "
+                f"pick a strategy without that axis")
+        spec[dim] = a
+    spec = P(*spec)
+    _log_path("pallas", f"under shard_map over {free} of mesh {sizes}")
+    # Nested in a manual region, jax wants the context's own mesh.
+    return jax.shard_map(fn, mesh=am if dict(am.shape) == sizes else mesh,
+                         in_specs=(spec, spec, spec), out_specs=spec,
+                         axis_names=set(free), check_vma=False)(q, k, v)
 
 
 def make_flash_attn_fn(causal=False, block_q=512, block_k=1024):
@@ -473,17 +551,24 @@ def make_flash_attn_fn(causal=False, block_q=512, block_k=1024):
 
     Uses the Pallas kernels on TPU when the sequence divides the block
     size; anything else — including an explicit boolean ``mask``, which the
-    fused kernel does not consume — falls back to the dense reference so
-    masking semantics are never silently dropped.
+    fused kernel does not consume — takes the dense reference so masking
+    semantics are never dropped, and logs that it did.
     """
     from autodist_tpu.models import layers as L
 
     def attn_fn(q, k, v, mask=None):
         if mask is not None:
+            _log_path("dense", "an explicit mask was passed")
             return L.dot_product_attention(q, k, v, mask)
         s = q.shape[2]
         bq, bk = min(block_q, s), min(block_k, s)
-        if jax.default_backend() != "tpu" or s % bq != 0 or s % bk != 0:
+        if s % bq != 0 or s % bk != 0:
+            _log_path("dense", f"seq {s} does not divide blocks "
+                               f"({block_q}, {block_k})")
             return _dense_reference(q, k, v, causal)
-        return flash_attention(q, k, v, causal, bq, bk, 0, False)
+        if _pallas_interpret(None) is None:
+            return _dense_reference(q, k, v, causal)
+        return _under_full_manual(
+            lambda ql, kl, vl: flash_attention(ql, kl, vl, causal, bq, bk,
+                                               0, False), q, k, v)
     return attn_fn

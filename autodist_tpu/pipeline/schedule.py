@@ -35,9 +35,8 @@ carries a plain data axis, the microbatch rows divide it, and no
 sequence-parallel composition is active — over ``data`` as well, making
 the region FULL-manual.  Batch-row semantics are unchanged (stage compute
 is row-independent; the gradient psum over ``data`` moves from GSPMD into
-shard_map's transpose), and full-manual regions avoid the partial-auto
-SPMD-partitioner CHECK-crash on jaxlib <= 0.4.x, so the pipelined path
-runs (and is bitwise-pinned) everywhere the test harness does.  The
+shard_map's transpose), and a full-manual region leaves nothing for the
+SPMD partitioner to split inside the schedule.  The
 seq-parallel composition keeps ``data`` auto (one manual region over
 {pipe, seq}; see ``pipeline_apply``'s seq_axis note).
 
@@ -304,8 +303,7 @@ def pipeline_apply(stage_params, stage_fn, x, num_microbatches, mesh,
         # manual in an enclosing region (explicit-path nesting).  Stage
         # compute is row-independent, so semantics are unchanged — the
         # gradient psum over ``data`` moves from GSPMD into shard_map's
-        # transpose — and a full-manual region sidesteps the partial-auto
-        # SPMD-partitioner crash on jaxlib <= 0.4.x.
+        # transpose.
         am_probe = jax.sharding.get_abstract_mesh()
         enclosing_manual = set(getattr(am_probe, "manual_axes", ()) or ()) \
             if am_probe is not None else set()

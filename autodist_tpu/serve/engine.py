@@ -307,9 +307,9 @@ class ReplicaRuntime:
 
     # -- dispatch loop -------------------------------------------------------
 
-    def _shard_item(self, item, poll=True):
+    def _shard_item(self, item):
         batch, group, rows = item
-        return (self.remapper.shard_batch(batch, poll=poll), group, rows)
+        return (self.remapper.shard_batch(batch), group, rows)
 
     def start(self, on_complete, depth=None):
         """Spin up the executor thread behind a depth-N prefetch window."""

@@ -14,8 +14,8 @@ Direction-aware: ``value`` (images/sec) regressing means it went DOWN;
 ``serve_p99_ms`` regressing means it went UP; ``tuner_prediction_error``
 is judged by magnitude.  The noise floor is the ``--threshold`` (default
 10%) raised to the headline's own measured spread for metrics that carry
-one (the relay's trial spread routinely exceeds 10% — flagging inside
-the noise band would cry wolf every round).
+one (a trial spread wider than 10% is common — flagging inside the noise
+band would cry wolf every round).
 
 Usage::
 

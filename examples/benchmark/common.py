@@ -12,6 +12,7 @@ import optax
 
 from autodist_tpu import AutoDist
 from autodist_tpu.data import DevicePrefetcher
+from autodist_tpu.utils import compile_cache
 from autodist_tpu.strategy import (AllReduce, PS, PSLoadBalancing, Parallax,
                                    PartitionedAR, PartitionedPS,
                                    RandomAxisPartitionAR, UnevenPartitionedPS,
@@ -87,6 +88,7 @@ def make_optimizer(args):
 
 
 def run_benchmark(name, args, params, loss_fn, batch_iter, example_batch):
+    compile_cache.enable()
     builder = STRATEGIES[args.strategy]()
     if getattr(args, "seq_parallel", 0):
         from autodist_tpu.strategy import SequenceParallel
@@ -116,7 +118,10 @@ def run_benchmark(name, args, params, loss_fn, batch_iter, example_batch):
         jax.profiler.stop_trace()
 
     ips = args.batch_size * args.steps / dt
-    print(f"{name} strategy={args.strategy} batch={args.batch_size} "
+    dev = jax.devices()[0]
+    print(f"{name} on {len(jax.devices())} x {dev.device_kind} "
+          f"({dev.platform}) strategy={args.strategy} "
+          f"batch={args.batch_size} "
           f"steps={args.steps}: {ips:.1f} samples/sec "
           f"({dt / args.steps * 1e3:.1f} ms/step, "
           f"loss={float(jax.device_get(metrics['loss'])):.4f})")

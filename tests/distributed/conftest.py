@@ -1,32 +1,7 @@
 """Fixtures for the multi-process distributed tier (helpers live in
 ``dist_scaffold.py`` so test files can import them by name under the bare
 ``pytest`` entry point)."""
-import os
-
 import pytest
-
-_TIER_DIR = os.path.dirname(os.path.abspath(__file__))
-
-
-def pytest_collection_modifyitems(config, items):
-    """The whole tier launches real multi-process CPU jobs; jaxlib 0.4.x
-    cannot compile them (``INVALID_ARGUMENT: Multiprocess computations
-    aren't implemented on the CPU backend``), so on such builds the tier
-    is skipped wholesale (capability probed once, cached per version).
-    NB: this hook receives the SESSION-wide item list, so it must filter
-    to this directory's items itself."""
-    tier_items = [item for item in items
-                  if os.path.abspath(str(item.fspath)).startswith(_TIER_DIR)]
-    if not tier_items:
-        return
-    from autodist_tpu.utils.compat import cpu_multiprocess_supported
-    if cpu_multiprocess_supported():
-        return
-    skip = pytest.mark.skip(
-        reason="this jaxlib's CPU backend does not implement multiprocess "
-               "computations; the distributed tier needs a newer jaxlib")
-    for item in tier_items:
-        item.add_marker(skip)
 
 
 @pytest.fixture

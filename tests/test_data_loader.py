@@ -403,8 +403,9 @@ def test_device_prefetcher_depths_deliver_all_batches(record_file, depth):
 
 
 def test_device_prefetcher_issues_transfers_without_blocking(record_file):
-    """depth>=1 issues every transfer with shard_batch(poll=False) — the
-    explicit-completion-handle contract — and settles before hand-out."""
+    """depth>=1 issues the whole window of transfers before the first
+    hand-out (shard_batch returns without waiting) and settles each batch
+    before it leaves."""
     path, data = record_file
     params, loss_fn, batch = mlp.tiny_fixture()
     ad = AutoDist(strategy_builder=AllReduce())
@@ -414,9 +415,9 @@ def test_device_prefetcher_issues_transfers_without_blocking(record_file):
     calls = []
     orig = runner.remapper.shard_batch
 
-    def spy(b, poll=True):
-        calls.append(poll)
-        return orig(b, poll=poll)
+    def spy(b):
+        calls.append(1)
+        return orig(b)
     runner.remapper.shard_batch = spy
 
     rng = np.random.RandomState(1)
@@ -424,10 +425,11 @@ def test_device_prefetcher_issues_transfers_without_blocking(record_file):
     feed = DevicePrefetcher(
         ((x, rng.randint(0, 4, (8,)).astype(np.int32)) for x in xs),
         runner.remapper, depth=2, pull_in_background=False)
-    got = list(feed)
-    assert len(got) == 4
-    # Every transfer went through the async (poll=False) path.
-    assert calls and all(p is False for p in calls)
+    first = next(feed)
+    # Both in-flight transfers were issued before the first batch came out.
+    assert len(calls) == 2
+    got = [first] + list(feed)
+    assert len(got) == 4 and len(calls) == 4
     # Delivery preserves order and content.
     for x, b in zip(xs, got):
         np.testing.assert_allclose(np.asarray(b[0]), x, rtol=1e-6)
@@ -471,13 +473,13 @@ def test_device_prefetcher_surfaces_iterator_errors(record_file):
         next(feed)
 
 
-def test_shard_batch_poll_false_returns_live_arrays():
+def test_shard_batch_returns_live_arrays():
     import jax
     params, loss_fn, batch = mlp.tiny_fixture()
     ad = AutoDist(strategy_builder=AllReduce())
     item = ad.capture(loss_fn, params, optax.sgd(0.1), example_batch=batch)
     runner = ad.create_distributed_session(item)
-    out = runner.remapper.shard_batch(batch, poll=False)
+    out = runner.remapper.shard_batch(batch)
     leaves = jax.tree_util.tree_leaves(out)
     assert all(isinstance(l, jax.Array) for l in leaves)
     jax.block_until_ready(leaves)

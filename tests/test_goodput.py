@@ -137,11 +137,16 @@ def test_peak_table_matches_device_kinds():
         Dev("TPU v5 lite", "tpu")) == pytest.approx(197e12)
     assert goodput.peak_flops_per_device(
         Dev("NVIDIA H100 80GB", "gpu")) == pytest.approx(989e12)
-    # unknown part => platform default
-    assert goodput.peak_flops_per_device(
-        Dev("TPU v99", "tpu")) == pytest.approx(197e12)
+    assert goodput.peak_hbm_bytes_per_device(
+        Dev("TPU v5 lite", "tpu")) == 16 * (1 << 30)
+    # the forced-device CPU test mesh keeps its nominal entry
     assert goodput.peak_flops_per_device(
         Dev("host", "cpu")) == pytest.approx(0.05e12)
+    # an accelerator the table does not list is an error, not a default
+    for lookup in (goodput.peak_flops_per_device,
+                   goodput.peak_hbm_bytes_per_device):
+        with pytest.raises(ValueError, match="TPU v99"):
+            lookup(Dev("TPU v99", "tpu"))
 
 
 # ---------------------------------------------------------------------------

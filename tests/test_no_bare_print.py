@@ -4,7 +4,7 @@ Everything under ``autodist_tpu/`` logs through ``utils.logging`` (level
 control, pid tagging, file sidecar) or records through the observability
 layer — a bare ``print`` bypasses all of it and, on multi-host jobs,
 interleaves uselessly across workers.  AST-based so prints inside string
-literals (the compat subprocess probes) don't false-positive, and so a
+literals (embedded subprocess scripts) don't false-positive, and so a
 ``# noqa``-style comment can't silently disable it.
 """
 import ast
