@@ -110,6 +110,13 @@ class AutoDist:
         return PS()
 
     @property
+    def runner(self):
+        """The Runner of the last ``build`` / ``create_distributed_session``
+        (None before it), so that a reader holding only
+        ``get_default_autodist()`` can reach the program's step."""
+        return self._runner
+
+    @property
     def resource_spec(self):
         return self._resource_spec
 

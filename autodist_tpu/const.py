@@ -145,7 +145,7 @@ class ENV(enum.Enum):
     AUTODIST_SKEW_RING = ("AUTODIST_SKEW_RING", int, 256)  # per-dispatch window ring for the skew decomposition (entries; 0 => no ring, no decomposition)
 
     AUTODIST_TELEMETRY = ("AUTODIST_TELEMETRY", bool, True)  # master switch: metrics + spans + flight recorder
-    AUTODIST_TRACE = ("AUTODIST_TRACE", str, "chrome")       # chrome | profiler (adds jax.profiler bridge) | 0 (off)
+    AUTODIST_TRACE = ("AUTODIST_TRACE", str, "chrome")       # chrome (trace-event JSON file) | 0 (no file)
     AUTODIST_METRICS_WINDOW = ("AUTODIST_METRICS_WINDOW", int, 256)  # histogram window (last-N observations)
     AUTODIST_MONITOR_PORT = ("AUTODIST_MONITOR_PORT", int, 0)  # chief HTTP monitor (/metrics + /status); 0 => no server, no thread
     AUTODIST_ANOMALY_ZSCORE = ("AUTODIST_ANOMALY_ZSCORE", float, 3.0)  # per-host latency z-score threshold for the anomaly detector

@@ -40,7 +40,7 @@ from collections import deque
 import jax
 import numpy as np
 
-from autodist_tpu import const
+from autodist_tpu import const, observability
 from autodist_tpu.utils import logging
 
 _SRC = os.path.join(os.path.dirname(__file__), "native", "prefetcher.cpp")
@@ -635,6 +635,7 @@ class DevicePrefetcher:
         self._wait_s_total = 0.0
         self._wait_s_last = 0.0
         self._batches = 0
+        self._obs = observability if observability.enabled() else None
         # ``shard_in_background`` is legacy (sharding now always happens
         # on the consumer thread); a truthy value still requests the pull
         # thread it used to imply.
@@ -673,9 +674,15 @@ class DevicePrefetcher:
     # -- transfer side -------------------------------------------------------
 
     def _settle(self, device_batch):
-        """Block until the batch's transfers completed."""
+        """Block until the batch's transfers completed; the wait is
+        ``autodist.data_wait`` in a profiler trace."""
+        obs = self._obs
         t0 = time.perf_counter()
-        jax.block_until_ready(device_batch)
+        if obs is None:
+            jax.block_until_ready(device_batch)
+        else:
+            with obs.annotate("data_wait"):
+                jax.block_until_ready(device_batch)
         dt = time.perf_counter() - t0
         self._wait_s_last = dt
         self._wait_s_total += dt

@@ -261,8 +261,9 @@ def scope_path(name_stack_text):
     for seg in text.split("/"):
         seg = seg.strip()
         # jit(f)/pjit(f) frames (or anything still carrying a call frame)
-        # are machinery, not user scopes.
-        if not seg or "(" in seg or ")" in seg:
+        # are machinery, not user scopes; so is the bare "shard_map" frame
+        # the explicit path's body is traced under.
+        if not seg or "(" in seg or ")" in seg or seg == "shard_map":
             continue
         segments.append(seg)
     return "/".join(segments)

@@ -10,8 +10,9 @@ default on):
 * :mod:`~autodist_tpu.observability.tracing` — context-manager spans
   around every framework phase (capture -> strategy build -> transform
   -> compile -> ship -> restore -> step loop), emitted as Chrome
-  trace-event JSON into ``DEFAULT_TRACE_DIR`` (Perfetto-loadable), with
-  an opt-in ``jax.profiler`` bridge (``AUTODIST_TRACE=profiler``);
+  trace-event JSON into ``DEFAULT_TRACE_DIR`` (Perfetto-loadable); every
+  span is also an ``autodist.<name>`` annotation in whatever
+  ``jax.profiler`` trace is being taken, on the device's clock;
 * :mod:`~autodist_tpu.observability.recorder` — a bounded JSONL flight
   recorder unifying the resilience event trail with compile/checkpoint/
   ship/worker lifecycle events, shipped per-worker to the chief over the
@@ -88,6 +89,14 @@ def span(name, **args):
     return tracing.Span(name, args)
 
 
+def annotate(name):
+    """``autodist.<name>`` in the profiler's trace only (the hot loop's
+    span: no ring record); a shared no-op when telemetry is off."""
+    if not enabled():
+        return tracing.NULL_SPAN
+    return tracing.annotate(name)
+
+
 def record_event(kind, detail="", **fields):
     """Append to the flight recorder (no-op when telemetry is off)."""
     if enabled():
@@ -149,7 +158,7 @@ def reset():
 
 
 __all__ = [
-    "enabled", "refresh", "span", "record_event", "registry",
+    "enabled", "refresh", "span", "annotate", "record_event", "registry",
     "phase_timings", "flush_trace", "sync_cluster", "snapshot", "reset",
     "metrics", "tracing", "recorder", "cluster", "attribution", "monitor",
     "profile", "goodput", "memory", "skew",

@@ -257,22 +257,31 @@ def peak_hbm_bytes_per_device(device=None):
 # ---------------------------------------------------------------------------
 # classification
 
-def _contained_in_loop_ms(events):
+def _contained_in_loop_ms(events, open_spans=()):
     """Per-phase span time scheduled INSIDE a step-loop span (us ring ->
     ms totals).  Those durations are billed into step latency (the first
     step's compile, a mid-loop save) but are not training — goodput
-    subtracts them; their own class keeps the full total."""
+    subtracts them; their own class keeps the full total.
+
+    The ledger is also persisted from inside a loop, when an elastic drain
+    ends a generation: that loop is still open (``tracing.open_spans()``).
+    A compile inside it ran in a dispatch and was billed like any other;
+    the drain's own save was made after the last flush and never billed,
+    so of an open loop only the compile phases count."""
     loops = [(e["ts"], e["ts"] + e["dur"]) for e in events
              if e.get("ph") == "X" and e.get("name") == "step-loop"]
+    open_loops = [(ts, float("inf")) for name, ts in open_spans
+                  if name == "step-loop"]
     out = {}
-    if not loops:
+    if not loops and not open_loops:
         return out
     for e in events:
         if e.get("ph") != "X" or e.get("name") == "step-loop":
             continue
         s, d = e.get("ts", 0.0), e.get("dur", 0.0)
         covered = 0.0
-        for ls, le in loops:
+        for ls, le in loops + (open_loops if e["name"] in _COMPILE_PHASES
+                               else []):
             covered = max(covered, max(0.0, min(le, s + d) - max(ls, s)))
         if covered > 0:
             out[e["name"]] = out.get(e["name"], 0.0) + covered / 1e3
@@ -330,7 +339,7 @@ def collect(runner=None, now=None):
     data_wait = (hists.get("step.data_wait_ms") or {}).get("total", 0.0)
 
     events = tracing.events()
-    inside = _contained_in_loop_ms(events)
+    inside = _contained_in_loop_ms(events, tracing.open_spans())
     # Emergency saves nest a checkpoint-save span; count the outer one.
     inside_saves = max(inside.get("checkpoint-save", 0.0),
                        inside.get("emergency-save", 0.0))

@@ -222,6 +222,142 @@ def hlo_scope_costs(hlo_text, known_scopes, topology=None, unroll=1):
 
 
 # ---------------------------------------------------------------------------
+# measured per-scope device time: compiled text x device trace
+
+
+#: The parts of a train step the Runner names (``runner.py``); their work
+#: is neither forward nor backward.
+UPDATE_SCOPES = ("optimizer", "grad_sync", "param_gather")
+#: Model scopes that fold into one row: the output projection and the loss.
+HEAD_SCOPES = ("logits", "lm_head", "mlm_head")
+#: Block sub-scopes that fold over every layer (``layer<i>/attn`` -> ``attn``).
+BLOCK_SCOPES = ("attn", "mlp")
+
+_INSTRUCTION_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+
+
+def _scope_and_phase(op_name):
+    """``(scope, phase)`` of one HLO ``op_name``.  The last segment of the
+    name stack is the primitive, what precedes it the user's scopes."""
+    from autodist_tpu.graph_item import scope_path
+    segs = scope_path(op_name).split("/")[:-1]
+    scope = UNATTRIBUTED
+    if segs:
+        scope = collapse("/".join(segs))
+        if segs[0] in HEAD_SCOPES:
+            scope = "head"
+        elif len(segs) > 1 and segs[1] in BLOCK_SCOPES:
+            scope = segs[1]
+        elif segs[0] in UPDATE_SCOPES:
+            scope = segs[0]
+    if scope in UPDATE_SCOPES:
+        phase = "update"
+    elif "transpose(jvp(" in op_name:
+        phase = "backward"
+    elif "jvp(" in op_name:
+        phase = "forward"
+    else:
+        phase = UNATTRIBUTED
+    return scope, phase
+
+
+_COMPUTATION_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
+_CALLS_RE = re.compile(r"\bcalls=%?([\w.\-]+)")
+
+
+def _parse_scopes(hlo_text):
+    """One pass over a compiled program's text: ``own`` maps every
+    instruction to the ``(scope, phase)`` of its own ``op_name`` (None
+    without one), ``calls`` maps a fusion to the computation it calls, and
+    ``votes`` counts, per computation, the ``(scope, phase)`` of its
+    instructions that carry a named scope."""
+    own, calls, votes = {}, {}, {}
+    computation = None
+    for line in hlo_text.splitlines():
+        if line[:1] not in (" ", "\t"):
+            header = _COMPUTATION_RE.match(line)
+            computation = header.group(1) if header else None
+            continue
+        m = _INSTRUCTION_RE.match(line)
+        if m is None:
+            continue
+        op = _OP_NAME_RE.search(line)
+        placed = _scope_and_phase(op.group(1)) if op else None
+        own[m.group(1)] = placed
+        called = _CALLS_RE.search(line)
+        if called:
+            calls[m.group(1)] = called.group(1)
+        if placed and placed[0] != UNATTRIBUTED and computation:
+            tally = votes.setdefault(computation, {})
+            tally[placed] = tally.get(placed, 0) + 1
+    return own, calls, votes
+
+
+def scope_table(hlo_text):
+    """``{instruction name: (scope, phase)}`` from a compiled program's
+    text, by the ``op_name`` metadata.  Pure.
+
+    A scope is the ``jax.named_scope`` path an instruction was traced
+    under, capped at :data:`SCOPE_DEPTH`, with ``layer<i>/attn`` of every
+    ``i`` folded into ``attn`` (likewise ``mlp``) and ``logits`` /
+    ``lm_head`` / ``mlm_head`` (the loss is traced inside them) into
+    ``head``.  The phase is ``backward`` where the name passes through
+    ``transpose(jvp(``, ``forward`` under ``jvp(`` alone, ``update`` for
+    the Runner's own scopes (:data:`UPDATE_SCOPES`).
+
+    A fusion runs as one instruction, and the ``op_name`` it carries itself
+    is that of one instruction it fused, not necessarily the one that does
+    its work (XLA fuses each weight's Adam update into the matmul that
+    makes its gradient, and the fusion keeps the matmul's name).  So a
+    fusion is placed where most of the scoped instructions of the
+    computation it calls are (:func:`mixed_fusions` lists those that hold
+    more than one scope); its own name decides a tie and a computation
+    with no scope in it.  An instruction with no ``op_name``, or with none
+    of the user's scopes in it, is :data:`UNATTRIBUTED` — surfaced, never
+    absorbed.
+    """
+    own, calls, votes = _parse_scopes(hlo_text)
+    table = {}
+    for name, placed in own.items():
+        tally = votes.get(calls.get(name), {})
+        if tally:
+            best = max(tally.values())
+            winners = [k for k, v in tally.items() if v == best]
+            placed = placed if placed in winners else winners[0]
+        table[name] = placed or (UNATTRIBUTED, UNATTRIBUTED)
+    return table
+
+
+def mixed_fusions(hlo_text):
+    """``{fusion name: {scope: scoped instructions}}`` for the fusions whose
+    computation holds instructions of more than one scope: what
+    :func:`scope_table` had to place by a vote."""
+    _, calls, votes = _parse_scopes(hlo_text)
+    mixed = {}
+    for name, computation in calls.items():
+        scopes = {}
+        for (scope, _), n in votes.get(computation, {}).items():
+            scopes[scope] = scopes.get(scope, 0) + n
+        if len(scopes) > 1:
+            mixed[name] = scopes
+    return mixed
+
+
+def device_time_by_scope(events, table):
+    """Join a device trace's ``(instruction name, start, end)`` events with
+    a :func:`scope_table`: ``{"scope": {scope: seconds}, "phase": {phase:
+    seconds}}``.  An event whose instruction the table does not hold goes
+    to :data:`UNATTRIBUTED`, so each side sums to the events' total."""
+    out = {"scope": {}, "phase": {}}
+    missing = (UNATTRIBUTED, UNATTRIBUTED)
+    for name, start, end in events:
+        scope, phase = table.get(name, missing)
+        out["scope"][scope] = out["scope"].get(scope, 0.0) + (end - start)
+        out["phase"][phase] = out["phase"].get(phase, 0.0) + (end - start)
+    return out
+
+
+# ---------------------------------------------------------------------------
 # the profile object: measured structure + model predictions
 
 

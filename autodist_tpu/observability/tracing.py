@@ -5,16 +5,31 @@ Every framework phase (capture -> strategy build -> transform -> compile
 spans land in a bounded in-memory ring and flush to
 ``DEFAULT_TRACE_DIR/autodist_trace_<pid>.json`` in the Chrome
 trace-event format — drag the file into https://ui.perfetto.dev (or
-chrome://tracing) for the waterfall.  An opt-in bridge
-(``AUTODIST_TRACE=profiler``) additionally wraps each span in
-``jax.profiler.TraceAnnotation`` so framework phases line up with
-device-side timelines in the XLA profiler.
+chrome://tracing) for the waterfall.
 
-Overhead discipline: a span costs two ``time.perf_counter()`` calls and
-one deque append; the ring is bounded (old events drop) so tracing never
-grows with job length; flushing is explicit (end of ``Runner.run``,
-``flush()``) plus a best-effort ``atexit`` — and everything is
-fail-open (a broken filesystem degrades tracing to in-memory only).
+One clock: every span also enters a ``jax.profiler.TraceAnnotation``
+named ``autodist.<span name>``, so whenever a device trace is being
+taken (``Runner.run(trace_dir=...)``, ``jax.profiler.start_trace``) the
+framework's phases lie on the host plane of that trace, on the device's
+clock, with no knob to set.  :func:`annotate` is the hot loop's form: the
+annotation alone, no ring record.  With no trace session open an
+annotation is a flag check.
+
+JAX's own compile timings (``jax.monitoring``) become spans too, once
+:func:`watch_jax_compiles` has registered its listeners: ``jax-trace``,
+``jax-lower`` and ``xla-compile`` (backend compile or cache read), each
+placed at (now - duration), and the counters ``compile.count``,
+``compile.cache_hits`` and ``compile.cache_misses``.  They fire for
+every jit in the process; a reader tells the Runner's by nesting inside
+a ``compile`` span, and sums only the outermost span of each kind (JAX
+traces and lowers inner functions inside outer ones).
+
+Overhead discipline: a span costs two ``time.perf_counter()`` calls, the
+annotation and one deque append; the ring is bounded (old events drop)
+so tracing never grows with job length; flushing is explicit (end of
+``Runner.run``, ``flush()``) plus a best-effort ``atexit`` — and
+everything is fail-open (a broken filesystem degrades tracing to
+in-memory only).
 """
 import atexit
 import json
@@ -24,7 +39,24 @@ import time
 
 from collections import deque
 
+import jax
+
 from autodist_tpu import const
+from autodist_tpu.observability import metrics
+
+#: Prefix of every annotation this module writes into a profiler trace.
+ANNOTATION_PREFIX = "autodist."
+
+#: ``jax.monitoring`` duration events -> span names.
+_JAX_DURATION_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax-trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax-lower",
+    "/jax/core/compile/backend_compile_duration": "xla-compile",
+}
+#: ``jax.monitoring`` events counted as ``compile.cache_hits`` / ``_misses``
+#: (a miss is recorded when the compiled program is written to the cache).
+_JAX_CACHE_EVENTS = ("/jax/compilation_cache/cache_hits",
+                     "/jax/compilation_cache/cache_misses")
 
 _MAX_EVENTS = 20_000
 
@@ -34,6 +66,10 @@ _lock = threading.Lock()
 # separately from the ring so phase totals survive event eviction (bench
 # attribution reads these, not the ring).
 _phase = {}
+# Spans entered and not yet left: id(span) -> (name, start us).  A reader
+# that runs inside a phase (the goodput ledger, persisted while the step
+# loop drains) sees that phase here; completed spans are in the ring.
+_open = {}
 _origin = time.perf_counter()
 # Wall-clock epoch of the perf_counter origin: trace ts 0 corresponds to
 # this absolute moment.  Captured back-to-back so per-host traces are
@@ -44,16 +80,11 @@ _mode_cache = None
 
 
 def _mode():
-    """Effective AUTODIST_TRACE mode: "chrome" | "profiler" | "" (off)."""
+    """Effective AUTODIST_TRACE mode: "chrome" | "" (no trace file)."""
     global _mode_cache
     if _mode_cache is None:
         raw = str(const.ENV.AUTODIST_TRACE.val).strip().lower()
-        if raw in ("0", "off", "false", "none"):
-            _mode_cache = ""
-        elif raw in ("profiler", "jax"):
-            _mode_cache = "profiler"
-        else:  # default / "1" / "chrome"
-            _mode_cache = "chrome"
+        _mode_cache = "" if raw in ("0", "off", "false", "none") else "chrome"
     return _mode_cache
 
 
@@ -71,6 +102,13 @@ def perf_to_epoch(t_perf):
     """A ``perf_counter`` reading -> wall-clock epoch seconds (the skew
     ring converts dispatch windows with this, off the hot loop)."""
     return _origin_epoch + (t_perf - _origin)
+
+
+def to_perf_counter(ts_us):
+    """An event's ``ts`` (microseconds since this module's origin) as a
+    ``time.perf_counter()`` reading, so that a reader can order a span
+    against a host-clock instant of its own."""
+    return _origin + ts_us * 1e-6
 
 
 def epoch_anchor_us():
@@ -92,17 +130,19 @@ class Span:
 
     def __enter__(self):
         self._t0 = _now_us()
-        if _mode() == "profiler":
-            try:
-                import jax
-                self._annotation = jax.profiler.TraceAnnotation(self.name)
-                self._annotation.__enter__()
-            except Exception:  # noqa: BLE001 - telemetry must never kill a run
-                self._annotation = None
+        with _lock:
+            _open[id(self)] = (self.name, self._t0)
+        try:
+            self._annotation = annotate(self.name)
+            self._annotation.__enter__()
+        except Exception:  # noqa: BLE001 - telemetry must never kill a run
+            self._annotation = None
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = _now_us()
+        with _lock:
+            _open.pop(id(self), None)
         if self._annotation is not None:
             try:
                 self._annotation.__exit__(exc_type, exc, tb)
@@ -125,6 +165,74 @@ class _NullSpan:
 
 
 NULL_SPAN = _NullSpan()
+
+
+def annotate(name):
+    """``autodist.<name>`` in the profiler's trace and nowhere else: the
+    hot loop's form of a span (no clock read, no ring record)."""
+    return jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
+
+
+_jax_watched = False
+
+
+def _telemetry_on():
+    from autodist_tpu import observability
+    return observability.enabled()
+
+
+def _on_jax_duration(event, duration_secs, **kwargs):
+    name = _JAX_DURATION_SPANS.get(event)
+    if name is None or not _telemetry_on():
+        return
+    dur_us = duration_secs * 1e6
+    ts_us = _now_us() - dur_us
+    _drop_nested_tail(name, ts_us)
+    record_complete(name, ts_us, dur_us,
+                    {"fun_name": kwargs.get("fun_name", "")})
+    if name == "xla-compile":
+        metrics.registry().counter("compile.count").inc()
+
+
+def _drop_nested_tail(name, ts_us, slack_us=20.0):
+    """JAX traces every inner function inside the outer one (hundreds of
+    ``jax-trace`` events a layer), and reports the inner ones first.  Only
+    the outermost is kept: the events of this name and thread at the
+    ring's tail that began after ``ts_us`` lie inside the one about to be
+    recorded, and would otherwise push the run's phases out of the ring."""
+    tid = threading.get_ident() & 0xFFFF
+    with _lock:
+        while _events:
+            ev = _events[-1]
+            if ev["name"] != name or ev["tid"] != tid \
+                    or ev["ts"] < ts_us - slack_us:
+                break
+            _events.pop()
+            acc = _phase[name]
+            acc[1] -= ev["dur"]
+            acc[2] -= 1
+
+
+def _on_jax_event(event, **kwargs):
+    if event not in _JAX_CACHE_EVENTS or not _telemetry_on():
+        return
+    if event.endswith("cache_hits"):
+        metrics.registry().counter("compile.cache_hits").inc()
+    else:
+        metrics.registry().counter("compile.cache_misses").inc()
+
+
+def watch_jax_compiles():
+    """Register the ``jax.monitoring`` listeners, once a process.  Called
+    where telemetry is known to be on (the Runner's construction), never
+    at import."""
+    global _jax_watched
+    with _lock:
+        if _jax_watched:
+            return
+        _jax_watched = True
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    jax.monitoring.register_event_listener(_on_jax_event)
 
 
 def record_complete(name, ts_us, dur_us, args=None):
@@ -161,6 +269,12 @@ def events():
         return list(_events)
 
 
+def open_spans():
+    """``[(name, start_us)]`` of the spans entered and not yet left."""
+    with _lock:
+        return list(_open.values())
+
+
 def phase_summary():
     """{phase: {"start_ms", "total_ms", "count"}} — bench attribution and
     the report's waterfall read this, not the raw ring."""
@@ -175,6 +289,7 @@ def clear():
     with _lock:
         _events.clear()
         _phase.clear()
+        _open.clear()
 
 
 def default_trace_path():

@@ -13,7 +13,7 @@ import numpy as np
 import jax
 from jax.sharding import NamedSharding, PartitionSpec
 
-from autodist_tpu import const
+from autodist_tpu import const, observability
 
 
 def transfers_copy_host_buffer():
@@ -44,6 +44,9 @@ class Remapper:
         self._program = program
         self._mesh = program.mesh
         self._sharding_cache = {}  # (treedef, ndims) -> sharding list (hot path)
+        # Resolved once, like the Runner's handle: telemetry off means no
+        # telemetry call on the per-step path.
+        self._obs = observability if observability.enabled() else None
 
     def _shardings_for(self, batch):
         leaves, treedef = jax.tree_util.tree_flatten(batch)
@@ -105,6 +108,13 @@ class Remapper:
         asynchronous); DevicePrefetcher overlaps the H2D with other work
         and blocks on the arrays just before hand-out.
         """
+        obs = self._obs
+        if obs is None:
+            return self._shard_batch(batch)
+        with obs.annotate("shard_batch"):
+            return self._shard_batch(batch)
+
+    def _shard_batch(self, batch):
         n = self._program.data_axis_size
         leaves, treedef, shardings = self._shardings_for(batch)
         if all(self._already_placed(l, s)

@@ -99,7 +99,14 @@ class Runner:
         # Scheduled-HLO text stashed by the AOT path (text, unroll): the
         # per-layer profiler upgrades its measured structure from it.
         self._scheduled_hlo_text = None
+        # A step built by _compile / _megastep_fn has not compiled anything
+        # yet: jit traces, lowers and compiles inside its first call.  That
+        # call runs under the `compile` span, and its abstract signature
+        # (shapes, dtypes, shardings; no arrays) is kept for scope_table().
+        self._uncalled = False
+        self._first_call_signature = None   # (jitted fn, (state, batch))
         if self._obs is not None:
+            self._obs.tracing.watch_jax_compiles()
             # Live cluster monitor (docs/observability.md): opt-in chief
             # HTTP endpoint; with no AUTODIST_MONITOR_PORT (or telemetry
             # off) this is a single int check — no thread, no port.
@@ -527,6 +534,15 @@ class Runner:
         Parity: the reference runs variable initializers at session
         construction (``runner.py:97-100``).
         """
+        with self._span("create-state"):
+            return self._create_state()
+
+    def _span(self, name, **args):
+        obs = self._obs
+        return (obs.span(name, **args) if obs is not None
+                else observability.tracing.NULL_SPAN)
+
+    def _create_state(self):
         item, prog, opt = self._item, self._program, self._opt
         self._ensure_live(
             item.params, "the captured parameter tree",
@@ -557,14 +573,18 @@ class Runner:
                               params=storage,
                               opt_state=opt.init(storage),
                               sync_state=sync_state)
-        state = jax.jit(init_fn, out_shardings=shardings)(item.params)
+        # `init` is the call alone (trace, compile, dispatch): the spans
+        # add no wait, so the device's work is not in it.
+        with self._span("init"):
+            state = jax.jit(init_fn, out_shardings=shardings)(item.params)
         # create_state may run again, so the captured initial values stay
         # with the GraphItem — on the host.  Left where the user's init put
         # them, one device carries a second copy of the whole model beside
         # its shard of the state for the life of the run.
-        item.params = jax.tree_util.tree_map(
-            lambda x: np.asarray(x) if isinstance(x, jax.Array)
-            and x.is_fully_addressable else x, item.params)
+        with self._span("host-copy"):
+            item.params = jax.tree_util.tree_map(
+                lambda x: np.asarray(x) if isinstance(x, jax.Array)
+                and x.is_fully_addressable else x, item.params)
         return state
 
     # -- step compilation ----------------------------------------------------
@@ -667,13 +687,16 @@ class Runner:
             else:
                 loss, grads = vg(state.params, batch)
                 aux = None
-            if overlap_on:
-                grads = ordered_constrain(grads)
-            else:
-                grads = jax.tree_util.tree_map(constrain, grads,
-                                               grad_shardings)
-            updates, opt_state = opt.update(grads, state.opt_state, state.params)
-            params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("grad_sync"):
+                if overlap_on:
+                    grads = ordered_constrain(grads)
+                else:
+                    grads = jax.tree_util.tree_map(constrain, grads,
+                                                   grad_shardings)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = opt.update(grads, state.opt_state,
+                                                state.params)
+                params = optax.apply_updates(state.params, updates)
             return (TrainState(state.step + 1, params, opt_state, state.sync_state),
                     self._metrics(loss, aux))
 
@@ -778,7 +801,9 @@ class Runner:
                 if kind == "fsdp":
                     return jax.lax.all_gather(x, axis, axis=dim, tiled=True)
                 return x
-            full = jax.tree_util.tree_map_with_path(gather, storage_params)
+            with jax.named_scope("param_gather"):
+                full = jax.tree_util.tree_map_with_path(gather,
+                                                        storage_params)
             with parallel_ctx.use(prog.parallel_context()):
                 return item.loss_fn(self._unpad_params(full), batch)
 
@@ -941,7 +966,8 @@ class Runner:
                            jax.tree_util.tree_flatten_with_path(grads)[0]}
             sync_local = jax.tree_util.tree_map(lambda x: x[0],
                                                 state.sync_state)
-            synced, sync_local = sync_grads(named_grads, sync_local)
+            with jax.named_scope("grad_sync"):
+                synced, sync_local = sync_grads(named_grads, sync_local)
 
             # Update views: leaf shapes must agree across grads / params /
             # optimizer state (shards for zero1/fsdp, full for ar, squeezed
@@ -967,8 +993,10 @@ class Runner:
                 lambda x, nm: x[0] if _is_stale(nm) else x,
                 state.opt_state, opt_names)
 
-            updates, opt_local = opt.update(grads_u, opt_local, params_u_tree)
-            new_params_u = optax.apply_updates(params_u_tree, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_local = opt.update(grads_u, opt_local,
+                                                params_u_tree)
+                new_params_u = optax.apply_updates(params_u_tree, updates)
 
             # Back to storage layout.
             def to_storage(path, p_new):
@@ -985,8 +1013,9 @@ class Runner:
                 if kind == "zero1":
                     return jax.lax.all_gather(p_new, axis, axis=dim, tiled=True)
                 return p_new  # fsdp shard / ar full
-            new_params = jax.tree_util.tree_map_with_path(to_storage,
-                                                          new_params_u)
+            with jax.named_scope("param_gather"):
+                new_params = jax.tree_util.tree_map_with_path(to_storage,
+                                                              new_params_u)
 
             new_opt = jax.tree_util.tree_map(
                 lambda x, nm: x[None] if _is_stale(nm) else x,
@@ -1027,25 +1056,66 @@ class Runner:
                              out_specs=(state_specs, PartitionSpec()),
                              axis_names={axis}, check_vma=False)
 
+    @property
+    def _lowering(self):
+        return "explicit" if self._program.use_explicit_path else "gspmd"
+
     def _compile(self, batch):
-        obs = self._obs
-        path = ("explicit" if self._program.use_explicit_path else "gspmd")
-        t0 = time.perf_counter()
-        with (obs.span("compile", path=path) if obs is not None
-              else observability.tracing.NULL_SPAN):
+        """Build the jitted step.  Nothing compiles here: the first call
+        does (``_first_call``)."""
+        with self._span("build-step", path=self._lowering):
             specs = self._program.batch_specs(batch)
             if self._program.use_explicit_path:
                 compiled = self._build_explicit_step(specs)
             else:
                 compiled = self._build_gspmd_step(self._named(specs))
-        logging.info("Runner: compiled %s step", path)
-        if obs is not None:
-            dt_ms = (time.perf_counter() - t0) * 1e3
-            obs.registry().gauge("compile.ms").set(round(dt_ms, 3))
-            obs.record_event("compile", f"{path} step built in {dt_ms:.0f}ms")
+        logging.info("Runner: built %s step", self._lowering)
+        self._uncalled = True
         self._record_wire_split()
-        self._auto_report()
+        with self._span("report"):
+            self._auto_report()
         return compiled
+
+    def _first_call(self, fn, state, batch, **span_args):
+        """The first call of a newly built step: jit traces, lowers and
+        compiles (or reads the compile cache) and dispatches inside it, so
+        this is what the ``compile`` span, the ``compile.ms`` gauge and
+        the ``compile`` flight event cover.  The step's execution is not
+        in it (dispatch is asynchronous)."""
+        self._first_call_signature = (fn, jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(
+                jnp.shape(x), jnp.result_type(x),
+                sharding=getattr(x, "sharding", None)), (state, batch)))
+        obs = self._obs
+        if obs is None:
+            return fn(state, batch)
+        t0 = time.perf_counter()
+        with obs.span("compile", path=self._lowering, **span_args):
+            out = fn(state, batch)
+        dt_ms = (time.perf_counter() - t0) * 1e3
+        obs.registry().gauge("compile.ms").set(round(dt_ms, 3))
+        what = " ".join(f"{k}={v}" for k, v in span_args.items()) or "step"
+        obs.record_event(
+            "compile", f"{self._lowering} {what} compiled in {dt_ms:.0f}ms")
+        return out
+
+    def step_text(self):
+        """The compiled text of the step that ran.  Lowers the step again
+        on the abstract signature of the call that compiled it and takes
+        the executable JAX already holds, so nothing new compiles; called
+        on demand, never from the step loop."""
+        if self._first_call_signature is None:
+            raise RuntimeError(
+                "autodist_tpu: step_text() needs a step that has run")
+        fn, (state, batch) = self._first_call_signature
+        return fn.lower(state, batch).compile().as_text()
+
+    def scope_table(self):
+        """``{instruction name: (scope, phase)}`` of the compiled step
+        (``observability.profile.scope_table`` of :meth:`step_text`), for
+        the join with a device trace."""
+        from autodist_tpu.observability import profile
+        return profile.scope_table(self.step_text())
 
     def _record_wire_split(self):
         """Per-leg (ICI/DCN) wire-byte gauges for this program's gradient
@@ -1097,8 +1167,7 @@ class Runner:
         if fn is None:
             obs = self._obs
             t0 = time.perf_counter()
-            with (obs.span("aot-compile") if obs is not None
-                  else observability.tracing.NULL_SPAN):
+            with self._span("aot-compile"):
                 fn = self._compiled.lower(self.state_struct, batch).compile()
             if obs is not None:
                 obs.registry().gauge("aot_compile.ms").set(
@@ -1181,7 +1250,15 @@ class Runner:
             batch = self._remapper.shard_batch(batch)
         if self._compiled is None:
             self._compiled = self._compile(batch)
-        return self._compiled(state, batch)
+        if self._uncalled:
+            out = self._first_call(self._compiled, state, batch)
+            self._uncalled = False
+            return out
+        obs = self._obs
+        if obs is None:
+            return self._compiled(state, batch)
+        with obs.annotate("dispatch"):
+            return self._compiled(state, batch)
 
     # -- fused multi-step ("megastep") dispatch ------------------------------
 
@@ -1211,11 +1288,7 @@ class Runner:
         fn = self._jit_cache.get(key)
         if fn is not None:
             return fn
-        obs = self._obs
-        path = ("explicit" if self._program.use_explicit_path else "gspmd")
-        t0 = time.perf_counter()
-        with (obs.span("compile", path=path, unroll=k) if obs is not None
-              else observability.tracing.NULL_SPAN):
+        with self._span("build-step", path=self._lowering, unroll=k):
             sample = jax.tree_util.tree_unflatten(treedef, [
                 jax.ShapeDtypeStruct(tuple(jnp.shape(l))[1:],
                                      jnp.result_type(l)) for l in leaves])
@@ -1263,16 +1336,11 @@ class Runner:
                                        block_shardings),
                          out_shardings=(self.state_shardings, None),
                          donate_argnums=(0, 1))
-        logging.info("Runner: compiled %s megastep (unroll=%d)", path, k)
-        if obs is not None:
-            dt_ms = (time.perf_counter() - t0) * 1e3
-            obs.registry().gauge("compile.ms").set(round(dt_ms, 3))
-            obs.record_event(
-                "compile", f"{path} megastep unroll={k} built in "
-                           f"{dt_ms:.0f}ms")
+        logging.info("Runner: built %s megastep (unroll=%d)",
+                     self._lowering, k)
 
         def warmup(state, blk):
-            # The first call lowers the program; the scanned block cannot
+            # The first call compiles the program; the scanned block cannot
             # alias any output, so XLA warns the donation is "unusable" —
             # but it still releases the block buffers early, which is the
             # point.  Silence that one expected notice, then swap the
@@ -1282,7 +1350,7 @@ class Runner:
                 warnings.filterwarnings(
                     "ignore",
                     message="Some donated buffers were not usable")
-                out = fn(state, blk)
+                out = self._first_call(fn, state, blk, unroll=k)
             self._jit_cache[key] = fn
             return out
 
@@ -1399,12 +1467,6 @@ class Runner:
                 f"unroll={unroll}; megasteps dispatch whole K-step blocks")
         data_iter, yields_blocks = self._wire_loader(data_iter, unroll)
         obs = self._obs
-        if trace_dir is None and obs is not None and \
-                observability.tracing._mode() == "profiler":
-            # AUTODIST_TRACE=profiler: device-side timeline without the
-            # caller having to plumb a trace_dir.
-            const.ensure_working_dirs()
-            trace_dir = const.DEFAULT_TRACE_DIR
         metrics = None
         ctx = None
         if trace_dir:
@@ -1636,9 +1698,7 @@ class Runner:
                 mem_ledger.sample("flush")
 
         metrics = None
-        span = (obs.span("step-loop", steps=num_steps, unroll=k)
-                if obs is not None else observability.tracing.NULL_SPAN)
-        with span:
+        with self._span("step-loop", steps=num_steps, unroll=k):
             if obs is not None and k > 1:
                 # Unroll badge: report/telemetry readers must interpret
                 # step.latency_ms as per-dispatch/K.

@@ -402,7 +402,18 @@ def test_disabled_step_loop_makes_zero_telemetry_calls(monkeypatch,
     monkeypatch.setattr(observability.memory, "finalize",
                         spy("memory-finalize"))
 
+    # ISSUE 23 contract extension: the hot loop's profiler annotations
+    # (Runner.step's dispatch, Remapper.shard_batch, the prefetcher's
+    # data_wait) and the jax.monitoring listeners make zero calls.
+    monkeypatch.setattr(observability.tracing, "annotate", spy("annotate"))
+    monkeypatch.setattr(observability.tracing, "watch_jax_compiles",
+                        spy("watch-jax-compiles"))
+
     state, metrics_out = runner.run(state, _repeat(batch), 5)
+    from autodist_tpu.data import DevicePrefetcher
+    for placed in DevicePrefetcher(iter([batch] * 2), runner.remapper,
+                                   depth=1, pull_in_background=False):
+        state, metrics_out = runner.step(state, placed, shard_inputs=False)
     assert calls == [], f"telemetry calls on disabled step loop: {calls}"
     assert metrics_out is not None  # the loop itself still works
     assert not observability.monitor.running()
