@@ -14,6 +14,20 @@ the O(s^2) score transient of the old dense-recompute VJP never
 materializes. Block position offsets ride in as scalar-prefetch operands,
 so they may be traced values (ring attention's rotating K/V offsets).
 
+float32 lives in the VMEM accumulators (o, dq, dk, dv) and in the softmax
+statistics (scores, running max and sum, lse, delta, p, ds) and nowhere else.
+All nine MXU products take operands of the inputs' dtype — the f32 ``p`` and
+``ds`` are cast down to it, not ``q``/``k``/``v``/``do`` up — and accumulate
+in f32; o and the three gradients leave the kernels in the inputs' dtype,
+rounded once from the accumulator. Ring attention's per-hop partials are
+summed across hops, so ``block_attn_fwd``/``block_attn_bwd`` ask for f32
+results. With f32 inputs every one of these casts is the identity. At the
+default precision Mosaic rounds an f32 MXU operand to bf16 itself, so with
+bf16 inputs the cast changes no bit of the result (v5e, PERF.md PR 24); it
+makes the nine products single-pass whatever ``jax_default_matmul_precision``
+the caller traces under, where f32 operands would follow it (1.4-2.5x the
+kernel time at ``highest``).
+
 Off TPU the dense jnp path runs instead (CPU tests use ``interpret=True``
 to exercise the kernels in the Pallas interpreter); every trace logs once,
 at info, which path it took and why.
@@ -42,17 +56,19 @@ def _log_path(path, why):
         logging.info("flash_attention: %s path (%s)", path, why)
 
 
-def _pallas_interpret(interpret):
+def _pallas_interpret(interpret, dtype):
     """Resolve the ``interpret`` argument at trace time: the flag to hand
     ``pallas_call``, or None when this trace takes the dense reference
-    (``interpret=None`` off TPU)."""
+    (``interpret=None`` off TPU). ``dtype`` is the inputs': the kernels'
+    log line names it, since it is what all nine MXU products multiply."""
+    operands = f"{jnp.dtype(dtype).name} MXU operands, f32 accumulators"
     if interpret is not None:
         _log_path("interpreted pallas" if interpret else "pallas",
-                  f"interpret={bool(interpret)} requested")
+                  f"interpret={bool(interpret)} requested; {operands}")
         return bool(interpret)
     backend = jax.default_backend()
     if backend == "tpu":
-        _log_path("pallas", "backend is tpu")
+        _log_path("pallas", f"backend is tpu; {operands}")
         return False
     _log_path("dense", f"backend is {backend}; the kernels compile for tpu")
     return None
@@ -172,7 +188,7 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l, *,
         p = jnp.where(s > _NEG_INF / 2, jnp.exp(s - m_new), 0.0)
         l[:] = l[:] * alpha + p.sum(-1, keepdims=True)
         acc[:] = acc[:] * alpha + jax.lax.dot_general(
-            p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m[:] = m_new
 
@@ -233,8 +249,7 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, k_offset,
         out_shape=[_sds((b * h, sq, d), out_dtype, qr, kr, vr, offs),
                    _sds((b * h, sq, 1), jnp.float32, qr, kr, vr, offs)],
         # batch/q-block programs are independent; only the k dimension
-        # carries the accumulator. Measured on v5e-class hardware this + the
-        # (512, 1024) default blocks beat a monolithic-KV kernel by ~25%.
+        # carries the accumulator.
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -278,7 +293,7 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta_ref[0]) * scale
         dq_acc[:] += jax.lax.dot_general(
-            ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     @pl.when(ik == num_kb - 1)
@@ -316,13 +331,13 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             s = s + causal_bias(block_q, block_k, q_start, k_start)
         p = jnp.where(s > _NEG_INF / 2, jnp.exp(s - lse_ref[0]), 0.0)
         dv_acc[:] += jax.lax.dot_general(
-            p, do.astype(jnp.float32), (((0,), (0,)), ((), ())),
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)          # p^T do
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta_ref[0]) * scale
         dk_acc[:] += jax.lax.dot_general(
-            ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)          # ds^T q
 
     @pl.when(iq == num_qb - 1)
@@ -332,13 +347,15 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd(q, k, v, do, lse, delta, causal, block_q, block_k, q_offset,
-               k_offset, interpret):
-    """Fused backward. Returns (dq, dk, dv) in f32."""
+               k_offset, interpret, out_dtype=None):
+    """Fused backward. Returns (dq, dk, dv) in out_dtype: the f32
+    accumulators are rounded once, by the kernels' last step."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     assert sq % block_q == 0 and sk % block_k == 0
+    out_dtype = out_dtype or q.dtype
     qr = q.reshape(b * h, sq, d)
     kr = k.reshape(b * h, sk, d)
     vr = v.reshape(b * h, sk, d)
@@ -367,7 +384,7 @@ def _flash_bwd(q, k, v, do, lse, delta, causal, block_q, block_k, q_offset,
             out_specs=qspec,
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         ),
-        out_shape=_sds((b * h, sq, d), jnp.float32, qr, kr, vr, dor, offs),
+        out_shape=_sds((b * h, sq, d), out_dtype, qr, kr, vr, dor, offs),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -386,8 +403,8 @@ def _flash_bwd(q, k, v, do, lse, delta, causal, block_q, block_k, q_offset,
             scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                             pltpu.VMEM((block_k, d), jnp.float32)],
         ),
-        out_shape=[_sds((b * h, sk, d), jnp.float32, qr, kr, vr, dor, offs),
-                   _sds((b * h, sk, d), jnp.float32, qr, kr, vr, dor, offs)],
+        out_shape=[_sds((b * h, sk, d), out_dtype, qr, kr, vr, dor, offs),
+                   _sds((b * h, sk, d), out_dtype, qr, kr, vr, dor, offs)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -400,14 +417,15 @@ def _flash_bwd(q, k, v, do, lse, delta, causal, block_q, block_k, q_offset,
 # block-attention helpers (ring attention's per-hop compute)
 
 
-def _use_pallas(sq, sk, block_q, block_k, interpret):
+def _use_pallas(q, k, block_q, block_k, interpret):
     if interpret:
         return True
+    sq, sk = q.shape[2], k.shape[2]
     if sq % min(block_q, sq) or sk % min(block_k, sk):
         _log_path("dense", f"block attention: seq ({sq}, {sk}) does not "
                            f"divide blocks ({block_q}, {block_k})")
         return False
-    return _pallas_interpret(None) is not None
+    return _pallas_interpret(None, q.dtype) is not None
 
 
 def block_attn_fwd(q, k, v, causal, q_offset, k_offset, block_q=512,
@@ -417,7 +435,7 @@ def block_attn_fwd(q, k, v, causal, q_offset, k_offset, block_q=512,
     Offsets may be traced scalars (ring hop positions). Rows with no
     visible key get o = 0 and lse = -1e30 (finite sentinel), which the
     logsumexp-combine treats as an empty partial."""
-    if _use_pallas(q.shape[2], k.shape[2], block_q, block_k, interpret):
+    if _use_pallas(q, k, block_q, block_k, interpret):
         return _flash_fwd(q, k, v, causal, block_q, block_k, q_offset,
                           k_offset, interpret, out_dtype=jnp.float32)
     o, lse = _dense_fwd(q, k, v, causal, q_offset, k_offset)
@@ -435,9 +453,10 @@ def block_attn_bwd(q, k, v, do, lse, delta, causal, q_offset, k_offset,
     """Fused per-block backward vs the GLOBAL lse (FA2 cross-block form):
     p = exp(s - lse) are the true softmax probabilities even when this block
     is one hop of a longer ring. Returns (dq, dk, dv) f32."""
-    if _use_pallas(q.shape[2], k.shape[2], block_q, block_k, interpret):
+    if _use_pallas(q, k, block_q, block_k, interpret):
         return _flash_bwd(q, k, v, do, lse, delta, causal, block_q, block_k,
-                          q_offset, k_offset, interpret)
+                          q_offset, k_offset, interpret,
+                          out_dtype=jnp.float32)
     return _dense_bwd(q, k, v, do, lse, delta, causal, q_offset, k_offset)
 
 
@@ -464,7 +483,7 @@ def flash_attention(q, k, v, causal=False, block_q=512, block_k=1024,
     sequence); it must be a multiple of ``block_q``. ``interpret=None``
     picks the Pallas kernels on TPU and the dense path elsewhere.
     """
-    interpret = _pallas_interpret(interpret)
+    interpret = _pallas_interpret(interpret, q.dtype)
     if interpret is None:
         return _dense_reference(q, k, v, causal, q_offset)
     o, _ = _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, 0,
@@ -473,7 +492,7 @@ def flash_attention(q, k, v, causal=False, block_q=512, block_k=1024,
 
 
 def _fwd_rule(q, k, v, causal, block_q, block_k, q_offset, interpret):
-    interpret = _pallas_interpret(interpret)
+    interpret = _pallas_interpret(interpret, q.dtype)
     if interpret is None:
         o, lse = _dense_fwd(q, k, v, causal, q_offset)
         return o.astype(q.dtype), (q, k, v, o.astype(q.dtype), lse)
@@ -490,13 +509,12 @@ def _bwd_rule(causal, block_q, block_k, q_offset, interpret, res, do):
     # dense elsewhere); False = native Pallas kernels; True = interpreted
     # Pallas. An explicit False must NOT mean "dense" — that would hand the
     # default TPU transformer path the O(s^2) dense backward.
-    interpret = _pallas_interpret(interpret)
+    interpret = _pallas_interpret(interpret, q.dtype)
     if interpret is None:
         dq, dk, dv = _dense_bwd(q, k, v, do, lse, delta, causal, q_offset)
-    else:
-        dq, dk, dv = _flash_bwd(q, k, v, do, lse, delta, causal, block_q,
-                                block_k, q_offset, 0, interpret)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+        return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    return _flash_bwd(q, k, v, do, lse, delta, causal, block_q, block_k,
+                      q_offset, 0, interpret)
 
 
 flash_attention.defvjp(_fwd_rule, _bwd_rule)
@@ -566,7 +584,7 @@ def make_flash_attn_fn(causal=False, block_q=512, block_k=1024):
             _log_path("dense", f"seq {s} does not divide blocks "
                                f"({block_q}, {block_k})")
             return _dense_reference(q, k, v, causal)
-        if _pallas_interpret(None) is None:
+        if _pallas_interpret(None, q.dtype) is None:
             return _dense_reference(q, k, v, causal)
         return _under_full_manual(
             lambda ql, kl, vl: flash_attention(ql, kl, vl, causal, bq, bk,

@@ -171,7 +171,7 @@ def ulysses_attention(q, k, v, axis_name=const.MESH_AXIS_SEQ, causal=False,
         # kernels on TPU (custom-VJP flash path), dense softmax elsewhere.
         s = q.shape[-2]
         bq, bk = min(512, s), min(1024, s)
-        if _use_pallas(s, s, bq, bk, False):
+        if _use_pallas(q, k, bq, bk, False):
             o = _flash_attn(q, k, v, causal, bq, bk)
         else:
             o = _dense_reference(q, k, v, causal)
