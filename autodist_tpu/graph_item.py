@@ -82,6 +82,21 @@ class VariableItem:
                 f"sparse={self.sparse_access})")
 
 
+def _trace_loss(loss_fn, params, batch_struct):
+    """``(closed jaxpr, output structs)`` of ``loss_fn`` on abstract params
+    and batch, or None where it does not trace (capture's reading of the
+    loss is best-effort: the Runner's own trace is the one that must
+    succeed)."""
+    try:
+        return jax.make_jaxpr(loss_fn, return_shape=True)(
+            tree_map(lambda l: jax.ShapeDtypeStruct(jnp.shape(l),
+                                                    jnp.result_type(l)),
+                     params), batch_struct)
+    except Exception as e:  # noqa: BLE001 - best-effort
+        logging.debug("loss not traced at capture: %s", e)
+        return None
+
+
 def _bf16_compute(loss_fn, aux_output):
     """Mixed-precision policy: bf16 compute, f32 master weights/loss.
 
@@ -299,13 +314,18 @@ class GraphItem:
 
     @classmethod
     def capture(cls, loss_fn, params, optimizer=None, example_batch=None,
-                sparse_params=(), non_trainable=(), aux_output=False,
+                sparse_params=(), non_trainable=(), aux_output=None,
                 precision=None):
         """Build a GraphItem from a single-device loss function.
 
         Args:
             loss_fn: ``(params, batch) -> loss`` (or ``(loss, aux)`` with
                 ``aux_output=True``).
+            aux_output: whether ``loss_fn`` returns ``(loss, aux)``.  Left
+                None it is read off the loss as traced on
+                ``example_batch`` (False without one).  Given, it must
+                agree with that trace: a loss that returns a pair under
+                ``aux_output=False``, or a bare loss under True, raises.
             params: parameter pytree (arrays or ShapeDtypeStructs).
             optimizer: optax GradientTransformation.
             example_batch: example batch pytree; first dim is treated as the
@@ -343,11 +363,23 @@ class GraphItem:
                                      jnp.result_type(l), path_to_name(p))
                           for p, l in bleaves]
 
-        batch_struct = None
+        batch_struct = traced = None
         if example_batch is not None:
             batch_struct = tree_map(
                 lambda l: jax.ShapeDtypeStruct(jnp.shape(l), jnp.result_type(l)),
                 example_batch)
+            traced = _trace_loss(loss_fn, params, batch_struct)
+        if traced is not None:
+            returns_pair = isinstance(traced[1], (tuple, list)) \
+                and len(traced[1]) == 2
+            if aux_output is None:
+                aux_output = returns_pair
+            elif bool(aux_output) != returns_pair:
+                raise ValueError(
+                    f"aux_output={aux_output!r}, but loss_fn returns "
+                    f"{'a (loss, aux) pair' if returns_pair else 'a bare loss'}"
+                    f" on example_batch")
+        aux_output = bool(aux_output)
         item = cls(loss_fn, params, optimizer,
                    batch_spec=batch_spec, variables=variables,
                    optimizer_name=getattr(optimizer, "__name__", "") or
@@ -359,7 +391,7 @@ class GraphItem:
             # would interpose convert_element_type between the param invar
             # and the gather, hiding embedding lookups from the jaxpr scan
             # (and mis-routing them to dense sync under Parallax).
-            item._detect_sparse_access(example_batch)
+            item._detect_sparse_access(traced)
         for v in item.variables:
             if any(s in v.name for s in sparse_params):
                 v.sparse_access = True
@@ -367,23 +399,18 @@ class GraphItem:
             item.loss_fn = _bf16_compute(loss_fn, aux_output)
         return item
 
-    def _detect_sparse_access(self, example_batch):
+    def _detect_sparse_access(self, traced):
         """Mark parameters read through `gather` (embedding lookups) as sparse.
 
         Replaces the reference's IndexedSlices-based sparse routing
-        (``/root/reference/autodist/graph_item.py:319-339``): trace the loss,
-        and any parameter leaf that is the gathered operand of a ``gather``
-        primitive gets ``sparse_access=True``.
+        (``/root/reference/autodist/graph_item.py:319-339``): in the traced
+        loss (:func:`_trace_loss`; None skips the detection), any parameter
+        leaf that is the gathered operand of a ``gather`` primitive gets
+        ``sparse_access=True``.
         """
-        try:
-            closed = jax.make_jaxpr(self.loss_fn)(
-                tree_map(lambda l: jax.ShapeDtypeStruct(jnp.shape(l), jnp.result_type(l)),
-                         self.params),
-                tree_map(lambda l: jax.ShapeDtypeStruct(jnp.shape(l), jnp.result_type(l)),
-                         example_batch))
-        except Exception as e:  # noqa: BLE001 - detection is best-effort
-            logging.debug("sparse-access detection skipped: %s", e)
+        if traced is None:
             return
+        closed = traced[0]
         n_params = len(jax.tree_util.tree_leaves(self.params))
         param_invars = set(map(id, closed.jaxpr.invars[:n_params]))
 

@@ -115,6 +115,17 @@ def layernorm(p, x, eps=1e-6):
     return (y * p["scale"] + p["bias"]).astype(x.dtype)
 
 
+def rmsnorm_init(dim):
+    return {"scale": jnp.ones((dim,))}
+
+
+def rmsnorm(p, x, eps=1e-5):
+    """``x / sqrt(mean(x^2) + eps) * scale`` in float32, no centring."""
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+    return (y * p["scale"]).astype(x.dtype)
+
+
 # -- embedding ---------------------------------------------------------------
 
 def embed_init(key, vocab, dim, stddev=0.02):
@@ -128,37 +139,68 @@ def embed(p, ids):
 
 # -- attention ---------------------------------------------------------------
 
-def mha_init(key, dim, num_heads):
+def mha_init(key, dim, num_heads, use_bias=True, qk_norm=False):
     ks = jax.random.split(key, 4)
-    return {
-        "query": dense_init(ks[0], dim, dim),
-        "key": dense_init(ks[1], dim, dim),
-        "value": dense_init(ks[2], dim, dim),
-        "out": dense_init(ks[3], dim, dim),
+    p = {
+        "query": dense_init(ks[0], dim, dim, use_bias),
+        "key": dense_init(ks[1], dim, dim, use_bias),
+        "value": dense_init(ks[2], dim, dim, use_bias),
+        "out": dense_init(ks[3], dim, dim, use_bias),
     }
+    if qk_norm:
+        p["q_norm"], p["k_norm"] = rmsnorm_init(dim), rmsnorm_init(dim)
+    return p
 
 
-def mha(p, x, num_heads, mask=None, dtype=None, attn_fn=None):
+def rope_tables(seq_len, head_dim, theta=10000.0):
+    """``(cos, sin)``, each (seq, head_dim) in float32, of the rotate-half
+    rotary embedding: ``inv_freq_i = theta^(-2i / head_dim)`` and the
+    angles repeated over both halves of a head."""
+    inv_freq = 1.0 / theta ** (
+        jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+    angles = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * inv_freq
+    angles = jnp.concatenate([angles, angles], axis=-1)
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def apply_rope(x, tables):
+    """Rotate (batch, heads, seq, head_dim) by its positions: element i
+    pairs with element i + head_dim / 2 (the rotate-half form)."""
+    cos, sin = tables
+    xf = x.astype(jnp.float32)
+    x1, x2 = jnp.split(xf, 2, axis=-1)
+    rotated = jnp.concatenate([-x2, x1], axis=-1)
+    return (xf * cos + rotated * sin).astype(x.dtype)
+
+
+def mha(p, x, num_heads, mask=None, dtype=None, attn_fn=None, rope=None,
+        norm_eps=1e-5):
     """Multi-head self-attention.
 
     ``attn_fn(q, k, v, causal)`` may override the inner attention computation
     (the hook used to swap in the Pallas flash kernel or ring attention).
-    q/k/v are (batch, heads, seq, head_dim).
+    q/k/v are (batch, heads, seq, head_dim).  Where the parameters hold
+    ``q_norm`` / ``k_norm`` (QK-norm), q and k are RMS-normalised over the
+    whole projected vector before the split into heads; ``rope``
+    (:func:`rope_tables`) rotates q and k after it.
     """
-    b, s, d = x.shape
-    hd = d // num_heads
+    b, s, _ = x.shape
 
-    def split(t):
-        return t.reshape(b, s, num_heads, hd).transpose(0, 2, 1, 3)
+    def project(name, norm=None):
+        t = dense(p[name], x, dtype)
+        if norm in p:
+            t = rmsnorm(p[norm], t, norm_eps)
+        return t.reshape(b, s, num_heads, -1).transpose(0, 2, 1, 3)
 
-    q = split(dense(p["query"], x, dtype))
-    k = split(dense(p["key"], x, dtype))
-    v = split(dense(p["value"], x, dtype))
+    q, k = project("query", "q_norm"), project("key", "k_norm")
+    v = project("value")
+    if rope is not None:
+        q, k = apply_rope(q, rope), apply_rope(k, rope)
     if attn_fn is not None:
         o = attn_fn(q, k, v, mask)
     else:
         o = dot_product_attention(q, k, v, mask)
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, d)
+    o = o.transpose(0, 2, 1, 3).reshape(b, s, -1)
     return dense(p["out"], o, dtype)
 
 

@@ -23,18 +23,57 @@ def lm_tiny(vocab=256, dtype=jnp.float32, max_len=64):
                                max_len=max_len, causal=True, dtype=dtype)
 
 
+def olmoe_1b_7b(num_layers=16, dtype=jnp.bfloat16):
+    """OLMoE-1B-7B (allenai/OLMoE-1B-7B-0125-Instruct ``config.json``,
+    arXiv:2409.02060): pre-RMSNorm, rotary positions, QK-norm, no biases,
+    16 heads of 128, 64 SwiGLU experts of 1,024 with 8 a token whose
+    weights are not renormalised, an untied head; trained with the
+    load-balancing term at 0.01 and the router z-loss at 0.001."""
+    return T.TransformerConfig(
+        vocab=50304, dim=2048, num_heads=16,
+        num_layers=num_layers, max_len=4096, causal=True, dtype=dtype,
+        norm="rmsnorm", norm_eps=1e-5, positions="rope", rope_theta=10000.0,
+        qk_norm=True, bias=False, tied_head=False, ffn="moe",
+        num_experts=64, experts_per_token=8, expert_dim=1024,
+        norm_topk=False, load_balance_coef=0.01, router_z_coef=0.001)
+
+
 def init(key, cfg):
     return T.init(key, cfg)
 
 
 def make_loss_fn(cfg, attn_fn=None):
-    """Next-token loss. batch = (tokens,) — inputs are tokens[:-1], targets tokens[1:]."""
+    """Next-token loss. batch = (tokens,) — inputs are tokens[:-1], targets tokens[1:].
+
+    For ``ffn="moe"`` the function returns ``(loss, aux)`` (``capture``
+    sees the pair in its trace of the loss): the loss is the
+    cross-entropy plus the mean over the expert layers of the
+    load-balancing term and of the router z-loss at the configuration's
+    coefficients; ``aux`` holds the three terms and the router's
+    statistics under the names of docs/observability.md.
+    """
     def loss_fn(params, batch):
         (tokens,) = batch if isinstance(batch, (tuple, list)) else (batch,)
-        hidden = T.encode(params, cfg, tokens[:, :-1], attn_fn=attn_fn)
+        hidden, stats = T.encode_with_stats(params, cfg, tokens[:, :-1],
+                                            attn_fn=attn_fn)
         with jax.named_scope("lm_head"):
             lg = T.logits(params, cfg, hidden)
-            return L.softmax_xent(lg, tokens[:, 1:])
+            xent = L.softmax_xent(lg, tokens[:, 1:])
+        if not stats:
+            return xent
+
+        def over_layers(name, reduce=jnp.mean):
+            return reduce(jnp.stack([s[name] for s in stats]))
+
+        aux = {"xent": xent,
+               "moe.load_balance_loss": over_layers("load_balance"),
+               "moe.router_z_loss": over_layers("z_loss"),
+               "moe.load_max_over_mean": over_layers("load_max_over_mean",
+                                                     jnp.max),
+               "moe.dropped": over_layers("dropped", jnp.sum)}
+        loss = xent + cfg.load_balance_coef * aux["moe.load_balance_loss"] \
+            + cfg.router_z_coef * aux["moe.router_z_loss"]
+        return loss, aux
     return loss_fn
 
 

@@ -8,17 +8,38 @@ Param scopes are Megatron-friendly: ``attn/{query,key,value,out}`` and
 ``mlp/{up,down}`` — tensor-parallel sharding rules key off these names
 (column-split q/k/v and up: output dim on the model axis; row-split out and
 down: input dim on the model axis).
+
+One block, chosen by the configuration's architectural fields.  The
+defaults are the GPT-2 / BERT block (LayerNorm, learned positions, biased
+projections, heads of ``dim / num_heads``, a GELU MLP, the embedding reused
+as the head); ``norm="rmsnorm"``, ``positions="rope"``, ``qk_norm``,
+``bias=False``, ``tied_head=False`` and ``ffn="moe"`` make the
+current decoder block whose feed-forward is a layer of routed experts
+(``parallel/moe.py:dropless_apply``, parameters under ``layer<i>/moe``).
 """
 import jax
 import jax.numpy as jnp
 
 from autodist_tpu.models import layers as L
+from autodist_tpu.parallel import moe
 
 
 class TransformerConfig:
     def __init__(self, vocab=32000, dim=512, num_heads=8, num_layers=6,
                  mlp_dim=None, max_len=512, causal=False, dtype=jnp.bfloat16,
-                 num_segments=0, scan_layers=False):
+                 num_segments=0, scan_layers=False, norm="layernorm",
+                 norm_eps=1e-6, positions="learned", rope_theta=10000.0,
+                 qk_norm=False, bias=True, tied_head=True,
+                 ffn="mlp", num_experts=0, experts_per_token=0,
+                 expert_dim=None, norm_topk=True, load_balance_coef=0.0,
+                 router_z_coef=0.0):
+        for name, value, known in (("norm", norm, ("layernorm", "rmsnorm")),
+                                   ("positions", positions,
+                                    ("learned", "rope")),
+                                   ("ffn", ffn, ("mlp", "moe"))):
+            if value not in known:
+                raise ValueError(f"{name} must be one of {known}, got "
+                                 f"{value!r}")
         self.vocab = vocab
         self.dim = dim
         self.num_heads = num_heads
@@ -32,39 +53,85 @@ class TransformerConfig:
         # subtree with a leading layer dim, applied via ops.scan_blocks —
         # sequential by default, GPipe-pipelined under a Pipeline strategy.
         self.scan_layers = scan_layers
+        self.norm, self.norm_eps = norm, norm_eps
+        self.positions, self.rope_theta = positions, rope_theta
+        self.qk_norm, self.bias, self.tied_head = qk_norm, bias, tied_head
+        self.ffn = ffn
+        # The expert layer (ffn="moe"): SwiGLU experts of ``expert_dim``,
+        # ``experts_per_token`` a token, none dropped; the loss adds the
+        # load-balancing term and the router z-loss at these coefficients.
+        self.moe = None
+        self.load_balance_coef = load_balance_coef
+        self.router_z_coef = router_z_coef
+        if ffn == "moe":
+            if scan_layers:
+                raise NotImplementedError(
+                    "scan_layers does not carry the expert layers' "
+                    "auxiliary terms out of the scan; build an ffn='moe' "
+                    "configuration with scan_layers=False")
+            self.moe = moe.MoEConfig(
+                num_experts=num_experts, top_k=experts_per_token,
+                d_model=dim, d_hidden=expert_dim or self.mlp_dim,
+                dtype=dtype, expert="swiglu", norm_topk=norm_topk)
+
+def _norm_init(cfg):
+    return L.rmsnorm_init(cfg.dim) if cfg.norm == "rmsnorm" \
+        else L.layernorm_init(cfg.dim)
+
+
+def _norm(cfg, p, x):
+    return L.rmsnorm(p, x, cfg.norm_eps) if cfg.norm == "rmsnorm" \
+        else L.layernorm(p, x, cfg.norm_eps)
 
 
 def block_init(key, cfg):
     k1, k2, k3 = jax.random.split(key, 3)
-    return {
-        "ln1": L.layernorm_init(cfg.dim),
-        "attn": L.mha_init(k1, cfg.dim, cfg.num_heads),
-        "ln2": L.layernorm_init(cfg.dim),
-        "mlp": {"up": L.dense_init(k2, cfg.dim, cfg.mlp_dim),
-                "down": L.dense_init(k3, cfg.mlp_dim, cfg.dim)},
+    p = {
+        "ln1": _norm_init(cfg),
+        "attn": L.mha_init(k1, cfg.dim, cfg.num_heads, cfg.bias,
+                           cfg.qk_norm),
+        "ln2": _norm_init(cfg),
     }
+    if cfg.ffn == "moe":
+        p["moe"] = moe.init(k2, cfg.moe)
+    else:
+        p["mlp"] = {"up": L.dense_init(k2, cfg.dim, cfg.mlp_dim, cfg.bias),
+                    "down": L.dense_init(k3, cfg.mlp_dim, cfg.dim, cfg.bias)}
+    return p
 
 
-def block_apply(p, x, cfg, mask=None, attn_fn=None):
+def block_apply(p, x, cfg, mask=None, attn_fn=None, rope=None):
+    """One block: ``(x, stats)``, ``stats`` the expert layer's
+    (``moe.dropless_apply``) and None for an MLP."""
     # attn/mlp scopes nest under the caller's layer scope, mirroring the
     # param paths ("layer<i>/attn/...") for the per-layer profiler.
     with jax.named_scope("attn"):
-        h = L.layernorm(p["ln1"], x)
+        h = _norm(cfg, p["ln1"], x)
         x = x + L.mha(p["attn"], h, cfg.num_heads, mask=mask, dtype=cfg.dtype,
-                      attn_fn=attn_fn)
+                      attn_fn=attn_fn, rope=rope, norm_eps=cfg.norm_eps)
+    if cfg.ffn == "moe":
+        with jax.named_scope("moe"):
+            y, stats = moe.dropless_apply(p["moe"], cfg.moe,
+                                          _norm(cfg, p["ln2"], x))
+            return x + y, stats
     with jax.named_scope("mlp"):
-        h = L.layernorm(p["ln2"], x)
+        h = _norm(cfg, p["ln2"], x)
         h = jax.nn.gelu(L.dense(p["mlp"]["up"], h, cfg.dtype))
-        return x + L.dense(p["mlp"]["down"], h, cfg.dtype)
+        return x + L.dense(p["mlp"]["down"], h, cfg.dtype), None
 
 
 def init(key, cfg):
     keys = jax.random.split(key, cfg.num_layers + 3)
     params = {
         "embed": L.embed_init(keys[0], cfg.vocab, cfg.dim),
-        "pos_embed": L.normal(keys[1], (cfg.max_len, cfg.dim), 0.02),
-        "ln_f": L.layernorm_init(cfg.dim),
+        "ln_f": _norm_init(cfg),
     }
+    if cfg.positions == "learned":
+        params["pos_embed"] = L.normal(keys[1], (cfg.max_len, cfg.dim), 0.02)
+    if not cfg.tied_head:
+        params["lm_head"] = L.dense_init(
+            jax.random.fold_in(keys[1], 1), cfg.dim, cfg.vocab,
+            use_bias=False)
     if cfg.num_segments:
         params["seg_embed"] = L.normal(keys[2], (cfg.num_segments, cfg.dim), 0.02)
     if cfg.scan_layers:
@@ -82,9 +149,17 @@ def encode(params, cfg, ids, segment_ids=None, attn_fn=None):
     With no explicit ``attn_fn``, on TPU the fused Pallas flash-attention
     kernel is used (ops/flash_attention.py); elsewhere the dense reference.
     """
+    return encode_with_stats(params, cfg, ids, segment_ids, attn_fn)[0]
+
+
+def encode_with_stats(params, cfg, ids, segment_ids=None, attn_fn=None):
+    """:func:`encode` and the expert layers' statistics: ``(hidden,
+    [stats of layer 0, ...])``, the list empty for ``ffn="mlp"``."""
     s = ids.shape[1]
     with jax.named_scope("embed"):
-        x = L.embed(params["embed"], ids) + params["pos_embed"][:s]
+        x = L.embed(params["embed"], ids)
+        if cfg.positions == "learned":
+            x = x + params["pos_embed"][:s]
         if cfg.num_segments and segment_ids is not None:
             x = x + params["seg_embed"][segment_ids]
         x = x.astype(cfg.dtype)
@@ -102,29 +177,49 @@ def encode(params, cfg, ids, segment_ids=None, attn_fn=None):
         # Explicit attn_fns keep the documented mha contract: they receive
         # the boolean mask (and may ignore it if causality is positional).
         mask = L.causal_mask(s) if cfg.causal else None
+    rope = L.rope_tables(s, cfg.dim // cfg.num_heads, cfg.rope_theta) \
+        if cfg.positions == "rope" else None
+    stats = []
     if cfg.scan_layers:
         from autodist_tpu.ops import scan_blocks
         with jax.named_scope("blocks"):
             x = scan_blocks(params["blocks"],
-                            lambda bp, a: block_apply(bp, a, cfg, mask=mask,
-                                                      attn_fn=attn_fn), x)
+                            lambda bp, a: block_apply(
+                                bp, a, cfg, mask=mask, attn_fn=attn_fn,
+                                rope=rope)[0], x)
     else:
         for i in range(cfg.num_layers):
             with jax.named_scope(f"layer{i}"):
-                x = block_apply(params[f"layer{i}"], x, cfg, mask=mask,
-                                attn_fn=attn_fn)
+                x, layer_stats = block_apply(
+                    params[f"layer{i}"], x, cfg, mask=mask, attn_fn=attn_fn,
+                    rope=rope)
+            if layer_stats is not None:
+                stats.append(layer_stats)
     with jax.named_scope("ln_f"):
-        return L.layernorm(params["ln_f"], x)
+        return _norm(cfg, params["ln_f"], x), stats
 
 
 def logits(params, cfg, hidden):
-    """Tied-embedding output projection."""
+    """Output projection in float32: the embedding matrix again, or the
+    head's own (``tied_head=False``)."""
     with jax.named_scope("logits"):
-        return (hidden.astype(jnp.float32)
-                @ params["embed"]["embedding"].T.astype(jnp.float32))
+        hidden = hidden.astype(jnp.float32)
+        head = params["embed"]["embedding"].T if cfg.tied_head \
+            else params["lm_head"]["kernel"]
+        return hidden @ head.astype(jnp.float32)
 
 
 # -- autoregressive decode (KV cache) ----------------------------------------
+
+def _decodable(cfg):
+    block = (cfg.norm, cfg.positions, cfg.ffn, cfg.qk_norm, cfg.bias,
+             cfg.tied_head)
+    if block != ("layernorm", "learned", "mlp", False, True, True):
+        raise NotImplementedError(
+            "decoding is implemented for the default block only (LayerNorm, "
+            "learned positions, biased projections, an MLP, a tied head); "
+            "through rope, QK-norm or moe it waits for ROADMAP R2")
+
 
 def init_cache(cfg, slots, cache_len, dtype=None):
     """Preallocated per-layer KV cache: (slots, heads, cache_len,
@@ -133,6 +228,7 @@ def init_cache(cfg, slots, cache_len, dtype=None):
     engine's batch dimension — it shards over the data axis exactly like
     a request batch.  Zeros are safe initial content: the ``j <= pos``
     mask means unwritten rows are never exposed (layers.mha_decode)."""
+    _decodable(cfg)
     if cache_len > cfg.max_len:
         raise ValueError(
             f"cache_len {cache_len} exceeds the model's max_len "
@@ -173,6 +269,7 @@ def decode_step(params, cfg, cache, tokens, pos):
     dense attention) and reading row ``pos`` — the KV cache is a pure
     optimization, never an approximation.
     """
+    _decodable(cfg)
     if cfg.scan_layers:
         raise NotImplementedError(
             "decode_step does not support scan_layers layouts; build the "

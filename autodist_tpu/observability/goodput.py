@@ -109,6 +109,7 @@ EVENT_CLASS = {
     "goodput": None,
     "mesh-built": "startup_ms",
     "memory": None,
+    "moe": None,
     "monitor-start": None,
     "oom": None,
     "pipeline": None,

@@ -232,6 +232,10 @@ UPDATE_SCOPES = ("optimizer", "grad_sync", "param_gather")
 HEAD_SCOPES = ("logits", "lm_head", "mlm_head")
 #: Block sub-scopes that fold over every layer (``layer<i>/attn`` -> ``attn``).
 BLOCK_SCOPES = ("attn", "mlp")
+#: The expert layer folds over the layers one level further down, by its own
+#: sub-scopes: ``layer<i>/moe/router`` -> ``moe/router`` (likewise
+#: ``moe/dispatch`` and ``moe/experts``; what sits in none of them is ``moe``).
+MOE_SCOPE = "moe"
 
 _INSTRUCTION_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
 
@@ -248,6 +252,8 @@ def _scope_and_phase(op_name):
             scope = "head"
         elif len(segs) > 1 and segs[1] in BLOCK_SCOPES:
             scope = segs[1]
+        elif len(segs) > 1 and segs[1] == MOE_SCOPE:
+            scope = "/".join(segs[1:3])
         elif segs[0] in UPDATE_SCOPES:
             scope = segs[0]
     if scope in UPDATE_SCOPES:
@@ -299,9 +305,10 @@ def scope_table(hlo_text):
 
     A scope is the ``jax.named_scope`` path an instruction was traced
     under, capped at :data:`SCOPE_DEPTH`, with ``layer<i>/attn`` of every
-    ``i`` folded into ``attn`` (likewise ``mlp``) and ``logits`` /
-    ``lm_head`` / ``mlm_head`` (the loss is traced inside them) into
-    ``head``.  The phase is ``backward`` where the name passes through
+    ``i`` folded into ``attn`` (likewise ``mlp``; ``layer<i>/moe/router``
+    into ``moe/router``, likewise ``moe/dispatch``, ``moe/experts``) and
+    ``logits`` / ``lm_head`` / ``mlm_head`` (the loss is traced inside
+    them) into ``head``.  The phase is ``backward`` where the name passes through
     ``transpose(jvp(``, ``forward`` under ``jvp(`` alone, ``update`` for
     the Runner's own scopes (:data:`UPDATE_SCOPES`).
 

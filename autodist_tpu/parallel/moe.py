@@ -19,6 +19,14 @@ parity tests use to pin :func:`apply` against :func:`dense_apply` and
 
 Top-k routing uses a load-balancing auxiliary loss (Switch-style):
 ``aux = E * sum_e(mean_tokens(gate_e) * frac_tokens_routed_e)``.
+
+The second dispatch form (:func:`dropless_apply`) has no capacity: the
+``T * k`` assignments are sorted by expert and each expert's rows go through
+its matrices in one grouped matrix product (:func:`grouped_product`, the
+megablox ``gmm`` Pallas kernel and its own gradient), so every assignment
+is computed and none pays for an empty buffer slot.  It is
+the form the language-model block uses (``models/transformer.py``,
+``ffn="moe"``); :func:`dense_apply` is the oracle of both.
 """
 import math
 
@@ -31,14 +39,20 @@ from autodist_tpu.utils import logging
 
 # Sharding rule for ModelParallel-style overlays: expert dim on `expert` axis.
 EXPERT_RULES = (
-    (r"moe/(up|down)/kernel$", 0),
+    (r"moe/(up|down|glu)/kernel$", 0),
     (r"moe/gate/kernel$", 1),
 )
+
+EXPERT_KINDS = ("gelu", "swiglu")
 
 
 class MoEConfig:
     def __init__(self, num_experts=8, top_k=2, d_model=64, d_hidden=256,
-                 dtype=jnp.float32, capacity_factor=1.25):
+                 dtype=jnp.float32, capacity_factor=1.25, expert="gelu",
+                 norm_topk=True):
+        if expert not in EXPERT_KINDS:
+            raise ValueError(f"expert must be one of {EXPERT_KINDS}, got "
+                             f"{expert!r}")
         self.num_experts = num_experts
         self.top_k = top_k
         self.d_model = d_model
@@ -47,17 +61,29 @@ class MoEConfig:
         # Per-expert buffer size C = ceil(T * top_k / E * capacity_factor).
         # >= E/top_k guarantees C = T (no token ever dropped).
         self.capacity_factor = capacity_factor
+        # "gelu": down(gelu(up x)).  "swiglu": down(silu(glu x) * up x),
+        # a third stacked matrix ``glu`` beside ``up`` and ``down``.
+        self.expert = expert
+        # Whether a token's top-k weights are rescaled to sum to one, or
+        # left as the softmax over all experts gave them.
+        self.norm_topk = norm_topk
 
 
 def init(key, cfg):
-    k1, k2, k3 = jax.random.split(key, 3)
-    return {
-        "gate": {"kernel": L.glorot(k1, (cfg.d_model, cfg.num_experts))},
-        "up": {"kernel": L.glorot(k2, (cfg.num_experts, cfg.d_model, cfg.d_hidden),
-                                  in_axis=-2, out_axis=-1)},
-        "down": {"kernel": L.glorot(k3, (cfg.num_experts, cfg.d_hidden, cfg.d_model),
+    swiglu = cfg.expert == "swiglu"
+    keys = jax.random.split(key, 4 if swiglu else 3)
+    up_shape = (cfg.num_experts, cfg.d_model, cfg.d_hidden)
+    params = {
+        "gate": {"kernel": L.glorot(keys[0], (cfg.d_model, cfg.num_experts))},
+        "up": {"kernel": L.glorot(keys[1], up_shape, in_axis=-2, out_axis=-1)},
+        "down": {"kernel": L.glorot(keys[2], (cfg.num_experts, cfg.d_hidden,
+                                              cfg.d_model),
                                     in_axis=-2, out_axis=-1)},
     }
+    if swiglu:
+        params["glu"] = {"kernel": L.glorot(keys[3], up_shape, in_axis=-2,
+                                            out_axis=-1)}
+    return params
 
 
 def _constrain_expert_sharded(buf):
@@ -87,10 +113,13 @@ def _route(gates, cfg):
     """Top-k routing shared by the dispatch and dense paths.
 
     gates: (T, E) softmax probabilities.
-    Returns (top_vals (T, k) normalized, top_idx (T, k), aux scalar).
+    Returns (top_vals (T, k), normalized unless ``cfg.norm_topk`` is off,
+    top_idx (T, k), aux scalar).
     """
     top_vals, top_idx = jax.lax.top_k(gates, cfg.top_k)
-    top_vals = top_vals / jnp.maximum(top_vals.sum(-1, keepdims=True), 1e-9)
+    if cfg.norm_topk:
+        top_vals = top_vals / jnp.maximum(top_vals.sum(-1, keepdims=True),
+                                          1e-9)
 
     # Switch-style load-balancing auxiliary loss (computed pre-drop, the
     # standard formulation: drops depend on buffer order, load balance
@@ -103,6 +132,16 @@ def _route(gates, cfg):
     density_proxy = gates.mean(0)           # mean gate prob per expert
     aux = cfg.num_experts * jnp.sum(density * density_proxy)
     return top_vals, top_idx, aux
+
+
+def _expert_hidden(params, cfg, x, spec):
+    """The experts' hidden activations for the einsum ``spec`` that takes
+    ``x`` through a stacked ``(E, d, h)`` matrix."""
+    h = jnp.einsum(spec, x, params["up"]["kernel"].astype(cfg.dtype))
+    if cfg.expert == "swiglu":
+        glu = params["glu"]["kernel"].astype(cfg.dtype)
+        return jax.nn.silu(jnp.einsum(spec, x, glu)) * h
+    return jax.nn.gelu(h)
 
 
 def apply(params, cfg, x):
@@ -159,13 +198,12 @@ def apply(params, cfg, x):
         .at[flat_ec].set(tok_ids + 1)[:num_e * capacity]
 
     xc = flat_x.astype(cfg.dtype)
-    up = params["up"]["kernel"].astype(cfg.dtype)
     down = params["down"]["kernel"].astype(cfg.dtype)
     occupied = (buf > 0)[:, None]
     expert_in = jnp.where(occupied, xc[jnp.maximum(buf - 1, 0)], 0) \
         .reshape(num_e, capacity, cfg.d_model)
     expert_in = _constrain_expert_sharded(expert_in)
-    h = jax.nn.gelu(jnp.einsum("ecd,edh->ech", expert_in, up))
+    h = _expert_hidden(params, cfg, expert_in, "ecd,edh->ech")
     expert_out = jnp.einsum("ech,ehd->ecd", h, down) \
         .reshape(num_e * capacity, cfg.d_model)
 
@@ -176,6 +214,192 @@ def apply(params, cfg, x):
     out = jax.ops.segment_sum(y.astype(jnp.float32) * w[:, None],
                               tok_ids, num_segments=tokens)
     return out.reshape(lead_shape + (cfg.d_model,)).astype(x.dtype), aux
+
+
+#: Rows, contraction and columns of one tile of the grouped product; at
+#: (65536, 2048) x (64, 2048, 1024) on a v5e the nine products of a step
+#: take 25.2 ms with it, 28.4 at 512 x 512 x 1024, 32.5 at 512^3 (which is
+#: what ``jax.lax.ragged_dot`` compiles to there: 35.1 ms), and a tile of
+#: 1,024 rows or 2,048 deep does not fit the kernel's memory (PERF.md, PR 25).
+GMM_TILING = (512, 1024, 1024)
+
+
+def grouped_product(lhs, rhs, group_sizes):
+    """``lhs`` (m, k) rows, sorted into ``len(group_sizes)`` contiguous
+    groups, times ``rhs`` (groups, k, n): each group's rows through its own
+    matrix, (m, n) in ``lhs``'s dtype with float32 accumulation.
+
+    The megablox kernel (``jax.experimental.pallas.ops.tpu.megablox``) and
+    its own gradient (the same kernel on the transposed matrices for the
+    rows' gradient, its ``tgmm`` twin for the matrices').  It works a tile
+    of rows at a time and visits a tile that straddles two groups once for
+    each, so its work follows the rows, not the groups' sizes.  Off the TPU
+    the same kernel runs interpreted.  As a Mosaic kernel it cannot be
+    partitioned automatically: on a mesh of several devices it must be
+    traced inside a region manual over every axis (the Runner's explicit
+    path over ``data`` is one), and this raises otherwise.
+    """
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    from autodist_tpu.parallel import context as pctx
+    interpret = jax.default_backend() != "tpu"
+    ctx = pctx.current()
+    mesh = ctx.mesh if ctx is not None else None
+    if not interpret and mesh is not None and mesh.size > 1:
+        manual = jax.sharding.get_abstract_mesh().manual_axes
+        free = [a for a in mesh.axis_names
+                if a not in manual and mesh.shape[a] > 1]
+        if free:
+            raise NotImplementedError(
+                f"the experts' grouped product is a Mosaic kernel and mesh "
+                f"{dict(mesh.shape)} leaves {free} automatic; use a "
+                f"strategy whose step is manual over every axis (the "
+                f"explicit shard_map lowering over 'data')")
+    m, k = lhs.shape
+    tiling = (math.gcd(m, GMM_TILING[0]), min(k, GMM_TILING[1]),
+              min(rhs.shape[2], GMM_TILING[2]))
+    return gmm(lhs, rhs, group_sizes.astype(jnp.int32), lhs.dtype, tiling,
+               interpret=interpret)
+
+
+_announced = set()
+
+
+def _announce(cfg, assignments):
+    """Gauges and a ``moe`` event for the dropless layer being traced; the
+    event is recorded once a process for each shape traced."""
+    from autodist_tpu import observability
+    if not observability.enabled():
+        return
+    registry = observability.registry()
+    registry.gauge("moe.experts").set(cfg.num_experts)
+    registry.gauge("moe.top_k").set(cfg.top_k)
+    registry.gauge("moe.assignments_per_step").set(assignments)
+    detail = (f"dropless: {assignments} assignments a step over "
+              f"{cfg.num_experts} {cfg.expert} experts, {cfg.top_k} a token; "
+              f"grouped product megablox gmm tiled {GMM_TILING}, "
+              f"({assignments}, {cfg.d_model}) x ({cfg.num_experts}, "
+              f"{cfg.d_model}, {cfg.d_hidden}) and back")
+    if detail not in _announced:
+        _announced.add(detail)
+        observability.record_event("moe", detail)
+
+
+def _sorted_rows(x, order, inverse, top_k):
+    """``x`` (T, d) repeated ``top_k`` times a token and put in the sorted
+    order of the assignments: ``out[j] = x[order[j] // top_k]``.  Its
+    transpose is written as the gather it is (each token sums its own
+    ``top_k`` rows), where autodiff would leave a scatter-add."""
+    @jax.custom_vjp
+    def take(x):
+        return x[order // top_k]
+
+    def fwd(x):
+        return take(x), None
+
+    def bwd(_, g):
+        return (g[inverse].reshape(x.shape[0], top_k, -1)
+                .sum(1).astype(g.dtype),)
+
+    take.defvjp(fwd, bwd)
+    return take(x)
+
+
+def _unsorted_rows(y, order, inverse):
+    """``y`` (T * k, d) back in the assignments' own order (token-major):
+    ``out[a] = y[inverse[a]]``; the transpose is the gather by ``order``."""
+    @jax.custom_vjp
+    def take(y):
+        return y[inverse]
+
+    def fwd(y):
+        return take(y), None
+
+    def bwd(_, g):
+        return (g[order],)
+
+    take.defvjp(fwd, bwd)
+    return take(y)
+
+
+def _uncovered(sorted_experts, group_sizes):
+    """How many of the sorted assignments a grouped product over
+    ``group_sizes`` does not take through the expert they chose (float32
+    scalar).  The product gives row ``i`` to the first group whose running
+    sum of sizes passes ``i``, which is the number of groups that end at or
+    before ``i``, and to none past the last group's end (the count is then
+    the number of groups, which no assignment chose)."""
+    ends = jnp.cumsum(group_sizes)
+    row = jnp.arange(sorted_experts.shape[0])
+    given = jnp.sum(row[:, None] >= ends[None, :], axis=1)
+    return jnp.sum(given != sorted_experts).astype(jnp.float32)
+
+
+def dropless_apply(params, cfg, x):
+    """x: (rows, seq, d_model) -> (moe_out, stats); no assignment dropped.
+
+    The router runs in float32; a token's ``top_k`` weights are left as the
+    softmax gave them unless ``cfg.norm_topk``.  The ``T * k`` assignments
+    are sorted by expert (stable, so an expert's rows keep the tokens'
+    order), the tokens' rows gathered in that order, and each of the
+    expert matrices applied to its contiguous group of rows by one grouped
+    product.  The results go back to the assignments' own order, are
+    weighted, and summed per token.
+
+    ``stats`` (all float32 scalars): ``load_balance`` = E * sum_e f_e P_e
+    with f_e the share of a row's ``seq * k`` assignments that expert e
+    got (sums to one over the experts) and P_e the row's mean router
+    probability, computed a row of the batch at a time and averaged over
+    the rows; ``z_loss`` = mean(logsumexp(router logits)^2);
+    ``load_max_over_mean`` = the busiest expert's assignments over the
+    mean, over the whole batch; ``dropped`` = the assignments whose sorted
+    row the grouped products do not put through its own expert
+    (:func:`_uncovered`): 0 while the sort and the group sizes agree.
+    """
+    rows, seq, _ = x.shape
+    tokens, num_e, top_k = rows * seq, cfg.num_experts, cfg.top_k
+    flat_x = x.reshape(tokens, cfg.d_model)
+    _announce(cfg, tokens * top_k)
+    with jax.named_scope("router"):
+        logits = flat_x.astype(jnp.float32) @ \
+            params["gate"]["kernel"].astype(jnp.float32)
+        gates = jax.nn.softmax(logits, axis=-1)                 # (T, E)
+        # The capacity path's whole-batch balance term is not used here.
+        top_vals, top_idx, _ = _route(gates, cfg)
+        flat_idx = top_idx.reshape(-1)                          # token-major
+        # (rows, E) counts; a row's sum to seq * k.
+        counts = jax.vmap(lambda i: jnp.bincount(i, length=num_e))(
+            top_idx.reshape(rows, seq * top_k))
+        density = counts.astype(jnp.float32) / (seq * top_k)
+        mean_gate = gates.reshape(rows, seq, num_e).mean(1)
+        group_sizes = counts.sum(0).astype(jnp.int32)
+        stats = {
+            "load_balance": jnp.mean(
+                num_e * jnp.sum(density * mean_gate, axis=-1)),
+            "z_loss": jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2),
+            "load_max_over_mean": group_sizes.max().astype(jnp.float32)
+            * num_e / (tokens * top_k),
+        }
+    with jax.named_scope("dispatch"):
+        # argsort, keeping the sorted keys the same sort produces.
+        sorted_experts, order = jax.lax.sort_key_val(
+            flat_idx, jnp.arange(tokens * top_k, dtype=flat_idx.dtype))
+        inverse = jnp.argsort(order)
+        stats["dropped"] = _uncovered(sorted_experts, group_sizes)
+        sorted_x = _sorted_rows(flat_x.astype(cfg.dtype), order, inverse,
+                                top_k)
+    with jax.named_scope("experts"):
+        def grouped(lhs, name):
+            return grouped_product(
+                lhs, params[name]["kernel"].astype(cfg.dtype), group_sizes)
+        hidden = jax.nn.silu(grouped(sorted_x, "glu")) * \
+            grouped(sorted_x, "up") if cfg.expert == "swiglu" \
+            else jax.nn.gelu(grouped(sorted_x, "up"))
+        expert_out = grouped(hidden, "down")                    # (T * k, d)
+    with jax.named_scope("dispatch"):
+        y = _unsorted_rows(expert_out, order, inverse) \
+            .reshape(tokens, top_k, cfg.d_model)
+        out = jnp.sum(y.astype(jnp.float32) * top_vals[..., None], axis=1)
+    return out.reshape(x.shape).astype(x.dtype), stats
 
 
 def dense_apply(params, cfg, x):
@@ -195,9 +419,8 @@ def dense_apply(params, cfg, x):
         combine, top_idx, top_vals).reshape(gates.shape)        # (..., E)
 
     xc = x.astype(cfg.dtype)
-    up = params["up"]["kernel"].astype(cfg.dtype)
     down = params["down"]["kernel"].astype(cfg.dtype)
-    h = jax.nn.gelu(jnp.einsum("...d,edh->...eh", xc, up))
+    h = _expert_hidden(params, cfg, xc, "...d,edh->...eh")
     per_expert = jnp.einsum("...eh,ehd->...ed", h, down)
     out = jnp.einsum("...ed,...e->...d", per_expert.astype(jnp.float32), combine)
     return out.astype(x.dtype), aux

@@ -71,6 +71,10 @@ class Runner:
         self._remapper = Remapper(program)
         self._compiled = None
         self._state_shardings = None
+        # The ``aux`` of the last step() whose loss function returns one:
+        # device values, never waited for here, for a reader to fetch once
+        # its loop has ended (docs/observability.md, "Auxiliary outputs").
+        self.last_aux = None
         # Latency-hiding collective scheduler (docs/usage/performance.md):
         # reverse-layer bucket issue + megastep weight-AG reorder, with
         # XLA's async-collective/latency-hiding flags enabled so the
@@ -590,6 +594,8 @@ class Runner:
     # -- step compilation ----------------------------------------------------
 
     def _metrics(self, loss, aux):
+        """The step's traced outputs: ``loss``, ``aux`` where the loss
+        function returns one, and the divergence flag."""
         metrics = {"loss": loss}
         if aux is not None:
             metrics["aux"] = aux
@@ -1250,15 +1256,18 @@ class Runner:
             batch = self._remapper.shard_batch(batch)
         if self._compiled is None:
             self._compiled = self._compile(batch)
+        obs = self._obs
         if self._uncalled:
             out = self._first_call(self._compiled, state, batch)
             self._uncalled = False
-            return out
-        obs = self._obs
-        if obs is None:
-            return self._compiled(state, batch)
-        with obs.annotate("dispatch"):
-            return self._compiled(state, batch)
+        elif obs is None:
+            out = self._compiled(state, batch)
+        else:
+            with obs.annotate("dispatch"):
+                out = self._compiled(state, batch)
+        if self._item.aux_output:
+            self.last_aux = out[1]["aux"]
+        return out
 
     # -- fused multi-step ("megastep") dispatch ------------------------------
 
