@@ -106,6 +106,7 @@ EVENT_CLASS = {
     "compile": "compile_ms",
     "divergence-abort": "rollback_ms",
     "emergency-save": "emergency_save_ms",
+    "flash": None,
     "goodput": None,
     "mesh-built": "startup_ms",
     "memory": None,

@@ -28,6 +28,16 @@ makes the nine products single-pass whatever ``jax_default_matmul_precision``
 the caller traces under, where f32 operands would follow it (1.4-2.5x the
 kernel time at ``highest``).
 
+A program of the grid handles ``G`` (batch, head) rows: where a row's score
+tile is small (128 x 128 at s = 128) most of a one-row program is what a
+program costs whatever it computes, so rows share a program until its tiles
+are as large as a long-sequence program's or VMEM is full
+(``_rows_per_program``; the operands' shape alone decides, and from s = 1,024
+``G`` is 1 and the program is the one-row program unchanged). Inside, a loop
+on the device takes as many rows a step as fit the vector registers, through
+the same arithmetic with a leading rows axis (``_for_rows``). A row's results
+do not depend on ``G``: they are bit for bit those of one row a program.
+
 Off TPU the dense jnp path runs instead (CPU tests use ``interpret=True``
 to exercise the kernels in the Pallas interpreter); every trace logs once,
 at info, which path it took and why.
@@ -85,6 +95,122 @@ def _sds(shape, dtype, *arrays):
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# The score tile of a long-sequence program (the default blocks): a program
+# of short rows takes rows until its tiles add up to this.
+_MAX_TILE = 512 * 1024
+# Mosaic's scoped VMEM limit is 16 MiB a kernel on the v5e, and the padded
+# estimate below leaves out what the compiler keeps for a row's own values
+# (the s x s tile and its copies).
+_VMEM_BUDGET = 12 * 2 ** 20
+# The score tiles one step of a program's loop over its rows may hold: the
+# vector registers (64 of 1,024 f32). A row is a chain (product, softmax,
+# product) in which each link waits for the one before; where several rows'
+# tiles fit the registers, a step takes them together and the compiler fills
+# one row's waits with its neighbours. On the v5e at (3072, 128, 64), G = 16:
+# 1.71 / 1.15 / 1.46 ms a call (fwd / dq / dkv) one row a step, 0.94 / 1.11 /
+# 1.24 at four; at (768, 512, 64), where one row's tile is four times the
+# registers, two rows a step are no faster than one (PERF.md, PR 26).
+_STEP_TILE = 64 * 1024
+_announced = set()
+
+
+def _padded_bytes(shape, dtype):
+    """VMEM bytes of a block of ``shape``: the last dimension occupies whole
+    128-lane rows (a width of 64 or of 1 as much as one of 128), the one
+    before it whole tiles of 8 sublanes of 32 bits."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublanes = 8 * max(1, 4 // itemsize)
+    *lead, rows, lanes = shape
+    return (math.prod(lead) * -(-rows // sublanes) * sublanes
+            * -(-lanes // 128) * 128 * itemsize)
+
+
+def _rows_per_program(rows, block_q, block_k, blocks, scratch):
+    """``(G, vmem_bytes)``: how many of the ``rows`` (batch x heads) one
+    program handles, and the VMEM those ``G`` rows occupy.
+
+    ``blocks`` are one row's pipelined in/out blocks (double-buffered),
+    ``scratch`` its accumulators and statistics, each ``(shape, dtype)``.
+    ``G`` is the largest divisor of ``rows`` whose score tiles stay within
+    ``_MAX_TILE`` and whose padded VMEM stays within ``_VMEM_BUDGET``; 1
+    where not even one row does (the blocks are the caller's choice)."""
+    vmem_a_row = (2 * sum(_padded_bytes(*b) for b in blocks)
+                  + sum(_padded_bytes(*b) for b in scratch))
+    most = min(_MAX_TILE // (block_q * block_k), _VMEM_BUDGET // vmem_a_row)
+    g = max((g for g in range(1, most + 1) if rows % g == 0), default=1)
+    return g, g * vmem_a_row
+
+
+def _announce(kernel, operand, sk, block_q, block_k, rows, vmem_bytes):
+    """One info line a distinct kernel and shape, and with telemetry on the
+    gauge ``flash.rows_per_program`` and a ``flash`` event: which program
+    the rule above made of this call, read at trace time."""
+    bh, sq, d = operand.shape
+    programs = bh // rows * (sq // block_q) * (sk // block_k)
+    detail = (f"{kernel} {jnp.dtype(operand.dtype).name}[{bh},{sq},{d}] "
+              f"over {sk} keys: blocks {block_q} x {block_k}, G = {rows} "
+              f"(batch, head) rows a program, {programs} programs a call, "
+              f"{vmem_bytes} bytes of VMEM by the padded estimate")
+    _log_path("pallas", detail)
+    from autodist_tpu import observability
+    if not observability.enabled():
+        return
+    observability.registry().gauge("flash.rows_per_program").set(rows)
+    if detail not in _announced:
+        _announced.add(detail)
+        observability.record_event("flash", detail)
+
+
+def _for_rows(rows, tile, body):
+    """``body(at)`` over a program's rows, ``tile`` a row's score tile and
+    ``at`` what indexes the rows of one step in a block: the one row of a
+    one-row program, which has no loop and two-dimensional arithmetic; else
+    a loop on the device whose step takes as many rows as ``_STEP_TILE``
+    allows, one as an index, several as a slice, so that a kernel's code does
+    not grow with ``rows``."""
+    if rows == 1:
+        body(0)
+        return
+    a_step = max((n for n in range(1, rows + 1)
+                  if rows % n == 0 and n * tile <= _STEP_TILE), default=1)
+    if a_step == rows:
+        body(slice(None))
+        return
+
+    def step(i, carry):
+        body(i if a_step == 1
+             else pl.ds(pl.multiple_of(i * a_step, a_step), a_step))
+        return carry
+    jax.lax.fori_loop(0, rows // a_step, step, 0)
+
+
+def _dot(a, b, contract_a, contract_b):
+    """``a . b`` in f32 over the given axes, counted from the end; the
+    leading axis of rank-3 operands is the rows of a loop step."""
+    rows = tuple(range(a.ndim - 2))
+    return jax.lax.dot_general(
+        a, b, (((a.ndim + contract_a,), (b.ndim + contract_b,)),
+               (rows, rows)), preferred_element_type=jnp.float32)
+
+
+def _row_of(scratch, at):
+    """``at`` for a program's scratch, which has no rows dimension in a
+    program of one row (``_scratch``): the long-sequence cells' kernels
+    compile to the same Mosaic module whether or not short rows group."""
+    return slice(None) if len(scratch.shape) == 2 else at
+
+
+def _all_rows(rows):
+    """The index of all of a program's rows in a block, for a value read from
+    its scratch: the one row of a one-row program, whose scratch has no rows
+    dimension."""
+    return 0 if rows == 1 else slice(None)
+
+
+def _scratch(g, shape):
+    return pltpu.VMEM(shape if g == 1 else (g,) + shape, jnp.float32)
 
 
 def causal_bias(sq, sk, q_offset=0, k_offset=0):
@@ -146,8 +272,10 @@ def _dense_bwd(q, k, v, do, lse, delta, causal, q_offset=0, k_offset=0):
 
 def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l, *,
                 block_q, block_k, causal, skip_blocks):
-    """Grid (batch*heads, q-blocks, k-blocks): k innermost, accumulators in
-    VMEM scratch carried across the k dimension."""
+    """Grid (batch*heads / G, q-blocks, k-blocks): k innermost, accumulators
+    in VMEM scratch carried across the k dimension, each of a program's G
+    rows with its own."""
+    rows = q_ref.shape[0]
     iq = pl.program_id(1)
     ik = pl.program_id(2)
     num_kb = pl.num_programs(2)
@@ -173,35 +301,38 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l, *,
 
     @pl.when(visible)
     def _block():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = s + causal_bias(block_q, block_k, q_start, k_start)
-        m_prev = m[:]
-        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        # Masked entries contribute EXACTLY zero (not exp(-1e30 - m)): in a
-        # fully-masked block m_new stays at the sentinel and s - m_new = 0.
-        p = jnp.where(s > _NEG_INF / 2, jnp.exp(s - m_new), 0.0)
-        l[:] = l[:] * alpha + p.sum(-1, keepdims=True)
-        acc[:] = acc[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m[:] = m_new
+        def _rows(at):
+            row = _row_of(acc, at)
+            q = q_ref[at]
+            k = k_ref[at]
+            v = v_ref[at]
+            s = _dot(q, k, -1, -1) * scale
+            if causal:
+                s = s + causal_bias(block_q, block_k, q_start, k_start)
+            m_prev = m[row]
+            m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            # Masked entries contribute EXACTLY zero (not exp(-1e30 - m)): in
+            # a fully-masked block m_new stays at the sentinel and
+            # s - m_new = 0.
+            p = jnp.where(s > _NEG_INF / 2, jnp.exp(s - m_new), 0.0)
+            l[row] = l[row] * alpha + p.sum(-1, keepdims=True)
+            acc[row] = acc[row] * alpha + _dot(p.astype(v.dtype), v, -1, -2)
+            m[row] = m_new
+        _for_rows(rows, block_q * block_k, _rows)
 
     @pl.when(ik == num_kb - 1)
     def _finalize():
+        every = _all_rows(rows)
         # 1e-30, NOT 1e-38: f32 subnormals flush to zero on TPU (and in the
         # interpret pipeline), and max(0, ftz(1e-38)) / 0 is how a guard
         # epsilon turns into NaN for rows that saw no visible block.
-        o_ref[0] = (acc[:] / jnp.maximum(l[:], 1e-30)).astype(o_ref.dtype)
+        o_ref[every] = (acc[:] / jnp.maximum(l[:], 1e-30)).astype(o_ref.dtype)
         # Rows that saw no visible block keep the finite sentinel (not -inf:
         # downstream combines subtract lse values and -inf - -inf = nan).
-        lse_ref[0] = jnp.where(l[:] > 0, m[:] + jnp.log(jnp.maximum(l[:], 1e-30)),
-                               _NEG_INF).astype(lse_ref.dtype)
+        lse_ref[every] = jnp.where(
+            l[:] > 0, m[:] + jnp.log(jnp.maximum(l[:], 1e-30)),
+            _NEG_INF).astype(lse_ref.dtype)
 
 
 def _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, k_offset,
@@ -223,24 +354,28 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, k_offset,
     kr = k.reshape(b * h, sk, d)
     vr = v.reshape(b * h, sk, d)
     offs = jnp.asarray([q_offset, k_offset], jnp.int32)
-    grid = (b * h, sq // block_q, sk // block_k)
+    f32 = jnp.float32
+    scratch = [((block_q, d), f32), ((block_q, 1), f32), ((block_q, 1), f32)]
+    g, vmem = _rows_per_program(
+        b * h, block_q, block_k,
+        [((block_q, d), q.dtype), ((block_k, d), k.dtype),
+         ((block_k, d), v.dtype), ((block_q, d), out_dtype),
+         ((block_q, 1), f32)], scratch)
+    _announce("flash_fwd", qr, sk, block_q, block_k, g, vmem)
+    grid = (b * h // g, sq // block_q, sk // block_k)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda ibh, iq, ik, offs: (ibh, iq, 0)),
-            pl.BlockSpec((1, block_k, d), lambda ibh, iq, ik, offs: (ibh, ik, 0)),
-            pl.BlockSpec((1, block_k, d), lambda ibh, iq, ik, offs: (ibh, ik, 0)),
+            pl.BlockSpec((g, block_q, d), lambda ibh, iq, ik, offs: (ibh, iq, 0)),
+            pl.BlockSpec((g, block_k, d), lambda ibh, iq, ik, offs: (ibh, ik, 0)),
+            pl.BlockSpec((g, block_k, d), lambda ibh, iq, ik, offs: (ibh, ik, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda ibh, iq, ik, offs: (ibh, iq, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda ibh, iq, ik, offs: (ibh, iq, 0)),
+            pl.BlockSpec((g, block_q, d), lambda ibh, iq, ik, offs: (ibh, iq, 0)),
+            pl.BlockSpec((g, block_q, 1), lambda ibh, iq, ik, offs: (ibh, iq, 0)),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-        ],
+        scratch_shapes=[_scratch(g, shape) for shape, _ in scratch],
     )
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, block_q=block_q, block_k=block_k,
@@ -264,6 +399,8 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, k_offset,
 
 def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dq_acc, *, block_q, block_k, causal, skip_blocks):
+    rows = q_ref.shape[0]
+    iq = pl.program_id(1)
     ik = pl.program_id(2)
     num_kb = pl.num_programs(2)
     d = q_ref.shape[-1]
@@ -273,37 +410,37 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    q_start = offs_ref[0] + pl.program_id(1) * block_q
+    q_start = offs_ref[0] + iq * block_q
     k_start = offs_ref[1] + ik * block_k
     visible = jnp.logical_or(not (causal and skip_blocks),
                              q_start + block_q - 1 >= k_start)
 
     @pl.when(visible)
     def _block():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = s + causal_bias(block_q, block_k, q_start, k_start)
-        p = jnp.where(s > _NEG_INF / 2, jnp.exp(s - lse_ref[0]), 0.0)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0]) * scale
-        dq_acc[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        def _rows(at):
+            q = q_ref[at]
+            k = k_ref[at]
+            v = v_ref[at]
+            do = do_ref[at]
+            s = _dot(q, k, -1, -1) * scale
+            if causal:
+                s = s + causal_bias(block_q, block_k, q_start, k_start)
+            p = jnp.where(s > _NEG_INF / 2, jnp.exp(s - lse_ref[at]), 0.0)
+            dp = _dot(do, v, -1, -1)
+            ds = p * (dp - delta_ref[at]) * scale
+            dq_acc[_row_of(dq_acc, at)] += _dot(ds.astype(k.dtype), k, -1, -2)
+        _for_rows(rows, block_q * block_k, _rows)
 
     @pl.when(ik == num_kb - 1)
     def _finalize():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        dq_ref[_all_rows(rows)] = dq_acc[:].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, block_q, block_k,
                     causal, skip_blocks):
+    rows = q_ref.shape[0]
+    ik = pl.program_id(1)
     iq = pl.program_id(2)
     num_qb = pl.num_programs(2)
     d = q_ref.shape[-1]
@@ -315,35 +452,33 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     q_start = offs_ref[0] + iq * block_q
-    k_start = offs_ref[1] + pl.program_id(1) * block_k
+    k_start = offs_ref[1] + ik * block_k
     visible = jnp.logical_or(not (causal and skip_blocks),
                              q_start + block_q - 1 >= k_start)
 
     @pl.when(visible)
     def _block():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = s + causal_bias(block_q, block_k, q_start, k_start)
-        p = jnp.where(s > _NEG_INF / 2, jnp.exp(s - lse_ref[0]), 0.0)
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # p^T do
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0]) * scale
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # ds^T q
+        def _rows(at):
+            row = _row_of(dk_acc, at)
+            q = q_ref[at]
+            k = k_ref[at]
+            v = v_ref[at]
+            do = do_ref[at]
+            s = _dot(q, k, -1, -1) * scale
+            if causal:
+                s = s + causal_bias(block_q, block_k, q_start, k_start)
+            p = jnp.where(s > _NEG_INF / 2, jnp.exp(s - lse_ref[at]), 0.0)
+            dv_acc[row] += _dot(p.astype(do.dtype), do, -2, -2)    # p^T do
+            dp = _dot(do, v, -1, -1)
+            ds = p * (dp - delta_ref[at]) * scale
+            dk_acc[row] += _dot(ds.astype(q.dtype), q, -2, -2)     # ds^T q
+        _for_rows(rows, block_q * block_k, _rows)
 
     @pl.when(iq == num_qb - 1)
     def _finalize():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        every = _all_rows(rows)
+        dk_ref[every] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[every] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _flash_bwd(q, k, v, do, lse, delta, causal, block_q, block_k, q_offset,
@@ -364,52 +499,53 @@ def _flash_bwd(q, k, v, do, lse, delta, causal, block_q, block_k, q_offset,
     deltar = delta.reshape(b * h, sq, 1)
     offs = jnp.asarray([q_offset, k_offset], jnp.int32)
 
-    qspec = pl.BlockSpec((1, block_q, d), lambda ibh, i, j, offs: (ibh, i, 0))
-    qspec_inner = pl.BlockSpec((1, block_q, d),
-                               lambda ibh, i, j, offs: (ibh, j, 0))
-    rowspec = pl.BlockSpec((1, block_q, 1), lambda ibh, i, j, offs: (ibh, i, 0))
-    rowspec_inner = pl.BlockSpec((1, block_q, 1),
-                                 lambda ibh, i, j, offs: (ibh, j, 0))
-    kspec = pl.BlockSpec((1, block_k, d), lambda ibh, i, j, offs: (ibh, j, 0))
-    kspec_outer = pl.BlockSpec((1, block_k, d),
-                               lambda ibh, i, j, offs: (ibh, i, 0))
+    f32 = jnp.float32
+    q_block, k_block, row_block = (block_q, d), (block_k, d), (block_q, 1)
+    ins = [(q_block, q.dtype), (k_block, k.dtype), (k_block, v.dtype),
+           (q_block, do.dtype), (row_block, f32), (row_block, f32)]
 
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, block_q=block_q, block_k=block_k,
-                          causal=causal, skip_blocks=not interpret),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b * h, sq // block_q, sk // block_k),
-            in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
-            out_specs=qspec,
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        ),
-        out_shape=_sds((b * h, sq, d), out_dtype, qr, kr, vr, dor, offs),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(offs, qr, kr, vr, dor, lser, deltar)
+    def outer(ibh, i, j, offs):
+        return ibh, i, 0
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
-                          causal=causal, skip_blocks=not interpret),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b * h, sk // block_k, sq // block_q),
-            in_specs=[qspec_inner, kspec_outer, kspec_outer, qspec_inner,
-                      rowspec_inner, rowspec_inner],
-            out_specs=[kspec_outer, kspec_outer],
-            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                            pltpu.VMEM((block_k, d), jnp.float32)],
-        ),
-        out_shape=[_sds((b * h, sk, d), out_dtype, qr, kr, vr, dor, offs),
-                   _sds((b * h, sk, d), out_dtype, qr, kr, vr, dor, offs)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="flash_bwd_dkv",
-    )(offs, qr, kr, vr, dor, lser, deltar)
+    def inner(ibh, i, j, offs):
+        return ibh, j, 0
+
+    def call(name, body, grid_tail, at_q, at_k, out_block, out_len, n_out):
+        """One backward kernel: ``n_out`` results of ``out_block`` a row at
+        the grid's second index, each with its f32 accumulator in scratch."""
+        g, vmem = _rows_per_program(
+            b * h, block_q, block_k, ins + [(out_block, out_dtype)] * n_out,
+            [(out_block, f32)] * n_out)
+        _announce(name, qr, sk, block_q, block_k, g, vmem)
+
+        def spec(shape, at):
+            return pl.BlockSpec((g,) + shape, at)
+        return pl.pallas_call(
+            functools.partial(body, block_q=block_q, block_k=block_k,
+                              causal=causal, skip_blocks=not interpret),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(b * h // g,) + grid_tail,
+                in_specs=[spec(q_block, at_q), spec(k_block, at_k),
+                          spec(k_block, at_k), spec(q_block, at_q),
+                          spec(row_block, at_q), spec(row_block, at_q)],
+                out_specs=[spec(out_block, outer)] * n_out,
+                scratch_shapes=[_scratch(g, out_block)] * n_out,
+            ),
+            out_shape=[_sds((b * h, out_len, d), out_dtype, qr, kr, vr, dor,
+                            offs)] * n_out,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name=name,
+        )(offs, qr, kr, vr, dor, lser, deltar)
+
+    # dq: q blocks outside, accumulated over the k blocks inside; dk and dv:
+    # k blocks outside, accumulated over the q blocks inside.
+    dq, = call("flash_bwd_dq", _bwd_dq_kernel,
+               (sq // block_q, sk // block_k), outer, inner, q_block, sq, 1)
+    dk, dv = call("flash_bwd_dkv", _bwd_dkv_kernel,
+                  (sk // block_k, sq // block_q), inner, outer, k_block, sk, 2)
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
 

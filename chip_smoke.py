@@ -43,9 +43,11 @@ from autodist_tpu.utils import compile_cache
 SEED = 0
 OUT_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                        "chiprun_out")
-# (batch, heads, seq, head width): the lm1b attention shape, and the long
-# sequence at head width 128 that the next configurations need.
-KERNEL_SHAPES = ((2, 16, 512, 64), (1, 16, 4096, 128))
+# (batch, heads, seq, head width): the lm1b attention shape, the long
+# sequence at head width 128 that the next configurations need, and short
+# rows, where a program takes several (batch, head) rows (16 of these 96):
+# the grouped Mosaic lowering, which the CPU tests only interpret.
+KERNEL_SHAPES = ((2, 16, 512, 64), (1, 16, 4096, 128), (8, 12, 128, 64))
 # bf16 outputs against an f32 reference of the same bf16 inputs: one bf16
 # rounding is 2^-8 relative, sums over keys add a little.
 KERNEL_ATOL = KERNEL_RTOL = 2e-2
