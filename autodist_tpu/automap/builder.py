@@ -36,7 +36,7 @@ BASE_EXCLUDED_FAMILIES = ("Automap", "ModelParallel", "SequenceParallel",
                           "Pipeline")
 
 # Last AutomapResult produced in this process: the report's per-op
-# proposal table and the bench worker read it.
+# proposal table reads it.
 _last_result = None
 
 
@@ -72,7 +72,7 @@ class AutomapResult:
     def rediscovered(self):
         """{"tp": bool, "ep": bool}: did the search shard anything on a
         model (tensor-parallel) / expert axis — the ROADMAP acceptance
-        flags the bench worker persists.  A composed plan sets BOTH."""
+        flags tests/test_automap.py asserts.  A composed plan sets BOTH."""
         plan = self.chosen_plan
         axes = plan.axes if plan is not None else {}
         return {"tp": const.MESH_AXIS_MODEL in axes,
@@ -80,8 +80,8 @@ class AutomapResult:
 
     @property
     def composition(self):
-        """Multi-axis surface of the chosen plan (the bench worker's
-        composed-rediscovery flags): the carved axes, the mesh name, the
+        """Multi-axis surface of the chosen plan (the composed-
+        rediscovery flags): the carved axes, the mesh name, the
         placement verdict, and whether a pipe axis rode along."""
         plan = self.chosen_plan
         if plan is None:

@@ -144,8 +144,21 @@ class Cluster:
             # Topology-aware layout (respects the ICI torus); a failure
             # here is a wrong mesh request and raises.
             from jax.experimental import mesh_utils
-            mesh_devices = mesh_utils.create_device_mesh(
-                shape, devices=devices.flatten().tolist())
+            flat = devices.flatten().tolist()
+            n_slices = len({getattr(d, "slice_index", 0) for d in flat})
+            if n_slices == 1:
+                mesh_devices = mesh_utils.create_device_mesh(
+                    shape, devices=flat)
+            elif shape[0] % n_slices:
+                raise ValueError(f"Mesh {dict(zip(names, shape))}: the "
+                                 f"outermost axis does not divide over "
+                                 f"{n_slices} slices")
+            else:
+                # Slices joined by DCN: the outermost axis (data) spans
+                # them, every other axis stays on one slice's ICI.
+                mesh_devices = mesh_utils.create_hybrid_device_mesh(
+                    (shape[0] // n_slices,) + shape[1:],
+                    (n_slices,) + (1,) * (len(shape) - 1), devices=flat)
         self._mesh = Mesh(mesh_devices, axis_names=tuple(names))
         logging.info("Built mesh %s over %d devices", dict(zip(names, shape)), n)
         observability.record_event(

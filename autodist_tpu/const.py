@@ -102,7 +102,7 @@ class ENV(enum.Enum):
     # -- hierarchical collectives (docs/collectives.md) ----------------------
     AUTODIST_HIER_COLLECTIVES = ("AUTODIST_HIER_COLLECTIVES", str, "auto")  # auto => tuner searches the two-level +hier=<codec> exec variants on multi-host topologies; off/0 => flat collectives only
     AUTODIST_HIER_DCN_CODEC = ("AUTODIST_HIER_DCN_CODEC", str, "")  # restrict the searched DCN-leg codec: bf16 | int8 | int8ef ("" => all three)
-    AUTODIST_HIER_ICI = ("AUTODIST_HIER_ICI", int, 0)  # ICI-leg size (devices per host) override for the execution-side leg split (0 => ResourceSpec.devices_per_host; testing/bench knob)
+    AUTODIST_HIER_ICI = ("AUTODIST_HIER_ICI", int, 0)  # ICI-leg size (devices per host) override for the execution-side leg split (0 => ResourceSpec.devices_per_host; testing knob)
 
     # -- pipeline parallelism (docs/pipelining.md) ---------------------------
     AUTODIST_PIPELINE_STAGES = ("AUTODIST_PIPELINE_STAGES", int, 0)  # pipeline stage count S for Pipeline() with no explicit num_stages (0 => the spec's pipeline: mesh hint, else the stage cutter's choice)

@@ -110,7 +110,7 @@ def registry():
 
 
 def phase_timings():
-    """{phase: {"start_ms", "total_ms", "count"}} for bench attribution."""
+    """{phase: {"start_ms", "total_ms", "count"}} (the report's waterfall)."""
     return tracing.phase_summary()
 
 

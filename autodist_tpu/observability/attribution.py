@@ -13,8 +13,8 @@ decomposes measured wall step time into named causes::
   (the runner's per-dispatch ``next()`` clock, same source as
   ``step.data_wait_ms``);
 * ``host_dispatch`` — per-dispatch host overhead (jit dispatch + batch
-  placement + clock reads), sourced from the bench-calibrated
-  ``host_dispatch_ms`` when a ``bench.py dispatch`` run persisted one,
+  placement + clock reads), sourced from the calibration's measured
+  ``host_dispatch_ms`` when one was persisted (nothing writes it today),
   else the cost model's ``DISPATCH_MS`` seed — amortized by ``unroll``;
 * ``device_compute`` — the cost model's FLOPs + optimizer-HBM roofline
   for this program (``tuner/cost_model``), scaled by the per-term
@@ -43,7 +43,7 @@ from typing import NamedTuple
 
 from autodist_tpu.utils import logging
 
-# Component keys, in render order (report / monitor / bench reuse this).
+# Component keys, in render order (report and monitor reuse this).
 COMPONENTS = ("data_wait_ms", "host_dispatch_ms", "device_compute_ms",
               "exposed_comms_ms", "residual_ms")
 
@@ -54,7 +54,7 @@ class ModelTerms(NamedTuple):
     """Model-sourced attribution terms (ms; compute/comms are per STEP,
     host_dispatch is per DISPATCH).  ``raw_*`` carry the unscaled model
     predictions the per-term calibration folds residuals against;
-    ``sources`` records where each term came from (report/bench honesty:
+    ``sources`` records where each term came from (the report's honesty:
     a term estimated from seeds reads differently than a measured one).
     """
     host_dispatch_ms: float = 0.0
@@ -231,7 +231,7 @@ def feed_calibration(summary, calibration=None):
 
 def finalize(ledger, registry=None):
     """End-of-run bookkeeping: publish the ``attr.*`` gauges, stash the
-    summary for cluster snapshots / report / monitor / bench, feed the
+    summary for cluster snapshots / report / monitor, feed the
     per-term calibration, and drop a flight-recorder event."""
     summary = ledger.summary()
     if not summary:

@@ -76,7 +76,7 @@ import time
 from autodist_tpu import const
 from autodist_tpu.utils import logging
 
-#: Badput classes, in render order (report / monitor / bench reuse this).
+#: Badput classes, in render order (report and monitor reuse this).
 #: ``goodput_ms`` + these sum to the segment's wall-clock exactly.
 BADPUT_CLASSES = (
     "startup_ms", "compile_ms", "restore_ms", "reshard_ms",

@@ -472,7 +472,7 @@ def last_oom_report():
 
 def finalize(ledger, registry=None):
     """End-of-run bookkeeping: publish the ``mem.*`` gauges, stash the
-    summary for cluster snapshots / report / monitor / bench, feed the
+    summary for cluster snapshots / report / monitor, feed the
     ``mem:`` calibration terms, write the ``memory.json`` sidecar under
     ``AUTODIST_DUMP_GRAPHS``, and drop a ``memory`` flight event.
     Callers gate on telemetry — with ``AUTODIST_TELEMETRY=0`` this is

@@ -542,7 +542,7 @@ def feed_calibration(summary, calibration=None):
 
 def finalize(profile, attr_summary, registry=None):
     """End-of-run bookkeeping: reconcile against the ledger, publish the
-    ``profile.*`` gauges, stash the summary for monitor/report/bench,
+    ``profile.*`` gauges, stash the summary for monitor and report,
     write the ``profile.json`` sidecar under ``AUTODIST_DUMP_GRAPHS``,
     and feed the per-class calibration."""
     summary = profile.reconcile(attr_summary)

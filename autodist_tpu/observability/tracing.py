@@ -63,8 +63,8 @@ _MAX_EVENTS = 20_000
 _events = deque(maxlen=_MAX_EVENTS)
 _lock = threading.Lock()
 # Phase accumulator: name -> [first_start_us, total_us, count].  Kept
-# separately from the ring so phase totals survive event eviction (bench
-# attribution reads these, not the ring).
+# separately from the ring so phase totals survive event eviction (the
+# report's waterfall reads these, not the ring).
 _phase = {}
 # Spans entered and not yet left: id(span) -> (name, start us).  A reader
 # that runs inside a phase (the goodput ledger, persisted while the step
@@ -276,8 +276,8 @@ def open_spans():
 
 
 def phase_summary():
-    """{phase: {"start_ms", "total_ms", "count"}} — bench attribution and
-    the report's waterfall read this, not the raw ring."""
+    """{phase: {"start_ms", "total_ms", "count"}} — the report's
+    waterfall reads this, not the raw ring."""
     with _lock:
         return {name: {"start_ms": round(s / 1e3, 3),
                        "total_ms": round(d / 1e3, 3), "count": n}

@@ -31,7 +31,7 @@ from autodist_tpu.utils import logging
 #: "stage2/block1" top-levels like "stage2" — any prefix + digits).
 _INDEXED = re.compile(r"^(?P<prefix>.*?)(?P<idx>\d+)$")
 
-# Last StageCut produced in this process (report/bench surface, like
+# Last StageCut produced in this process (the report's surface, like
 # tuner.last_result / automap.last_result).
 _last_cut = None
 

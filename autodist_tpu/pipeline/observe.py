@@ -5,8 +5,8 @@ profiler story stays closed: the Runner's cold-path finalize calls
 :func:`finalize` once per observed step loop, which prices the measured
 step p50 into a bubble share using the schedule model
 (``(S-1)/(S+M-1)``, conveyor-adjusted) and publishes the ``pipeline.*``
-gauges the monitor ``/status`` pipeline section, the report's Pipeline
-section, and ``bench.py pipeline`` all read.  Telemetry off
+gauges the monitor ``/status`` pipeline section and the report's
+Pipeline section both read.  Telemetry off
 (``AUTODIST_TELEMETRY=0``) never reaches this module — the zero-call
 contract test spies on it (tests/test_pipeline.py).
 """
@@ -25,8 +25,8 @@ def pipeline_shape(program):
 
 
 def predicted_bubble(stages, microbatches):
-    """The schedule's idle-slot fraction, conveyor-adjusted (the number
-    the bench's skip-vs-noskip pair measures)."""
+    """The schedule's idle-slot fraction, conveyor-adjusted (the ticks
+    tests/test_pipeline.py counts with and without the skip)."""
     sharded = microbatches % stages == 0 and stages > 1
     return schedule.bubble_fraction(stages, microbatches,
                                     sharded_commit=sharded)

@@ -55,8 +55,8 @@ from autodist_tpu import const
 #: ``shift`` — the pipelined schedule; ``sequential`` — the bitwise
 #: unpipelined control arm; ``shift-noskip`` — shift with the fill/drain
 #: compute skip disabled (every idle slot executes garbage work), the
-#: measurement arm ``bench.py pipeline`` pairs against ``shift`` to turn
-#: the schedule's idle-slot share into wall-clock on a timeshared host;
+#: arm that pairs against ``shift`` to turn the schedule's idle-slot
+#: share into wall-clock (no caller pairs them today: ROADMAP D7);
 #: ``1f1b`` — shift with the stage body rematerialized in backward, so
 #: the scan retains only stage-boundary activations: the resident hold
 #: drops from GPipe's all-M to 1F1B's min(S, M) in-flight depth
@@ -73,8 +73,8 @@ def resolve_skip_idle(backend=None, seq_manual=False):
       conditional aborts XLA's rendezvous);
     * XLA:CPU => **off**: the cond's TRANSPOSE under reverse-mode AD
       lowers to full select chains, measured SLOWER than the garbage
-      fill/drain compute the skip avoids (``bench.py pipeline``'s
-      skip-vs-noskip pair on the CPU container);
+      fill/drain compute the skip avoids (a skip-vs-noskip pair, timed
+      on the CPU container);
     * every other backend (TPU/GPU) => **on**: fill/drain slots skip
       their stage compute, erasing the bubble's FLOPs.
     """
@@ -98,7 +98,7 @@ def bubble_fraction(p_size, num_microbatches, sharded_commit=None):
     round-robin output conveyor is in play (``sharded_commit=True``) the
     scan runs M + 2P - 3 ticks of which M are compute ticks per rank, so
     the idle fraction is ``(2P-3)/(M+2P-3)`` — identical at P=2, and the
-    number ``bench.py pipeline`` measures via its skip-vs-noskip pair.
+    share of ticks tests/test_pipeline.py counts as idle.
     With ``sharded_commit=None`` the classic model is returned.
     """
     if sharded_commit:

@@ -75,8 +75,8 @@ def collective_summary(hlo_text, ops=None, keep_zeros=False):
     """{op: count} over an HLO/StableHLO text.
 
     The single home of the HLO op-invocation pattern (async ``-start``
-    forms and ``.N`` suffixes included) — bench's zero-verify worker and
-    the HLO test tiers count through here too.
+    forms and ``.N`` suffixes included) — the HLO test tiers, CPU and
+    detached v5e (tests/test_topology_aot.py), count through here too.
     """
     out = {}
     for op in (ops or _COLLECTIVES):
@@ -89,8 +89,8 @@ def collective_summary(hlo_text, ops=None, keep_zeros=False):
 def replica_group_sizes(hlo_text):
     """Set of collective replica-group sizes in an HLO text.  A collective
     spanning mesh axis X has group size == axis size — the signature used
-    to prove an exchange really crosses that axis (bench verify arms,
-    ``tests/test_moe_hlo.py``).
+    to prove an exchange really crosses that axis
+    (``tests/test_moe_hlo.py``, ``tests/test_topology_aot.py``).
 
     Both replica-group syntaxes XLA emits are parsed: the iota form
     ``replica_groups=[G,S]<=[...]`` (S = group size) and the explicit

@@ -14,7 +14,7 @@ evaluation window the chief:
    in ``full`` mode — every mesh-compatible candidate strategy from the
    tuner's last ranking, all under the CURRENT persisted
    :class:`~autodist_tpu.tuner.calibration.Calibration` (term scales,
-   ``profile:<scope>`` scales, link overrides, the bench-calibrated
+   ``profile:<scope>`` scales, link overrides, a measured
    host-dispatch floor);
 2. anchors predictions to reality: a challenger's estimated step time is
    ``measured_p50 * predicted(challenger) / predicted(incumbent)`` — the
@@ -97,7 +97,7 @@ _declined_once = False
 
 
 def last_controller():
-    """The most recent controller in this process (report/monitor/bench
+    """The most recent controller in this process (report/monitor
     surface); ``None`` before the first retune-enabled observed loop."""
     return _last_controller
 
@@ -376,7 +376,7 @@ class Controller:
 
     def _cost_model(self):
         """A cost model priced under the CURRENT persisted calibration —
-        re-loaded every window, so mid-run re-fits (and bench-persisted
+        re-loaded every window, so mid-run re-fits (and persisted
         host-dispatch floors) take effect immediately."""
         import jax
         from autodist_tpu.tuner.calibration import Calibration
@@ -762,8 +762,8 @@ class Controller:
     # -- surfaces ------------------------------------------------------------
 
     def status(self):
-        """JSON-serializable controller state (monitor /status, report,
-        bench)."""
+        """JSON-serializable controller state (monitor /status,
+        report)."""
         return {
             "mode": self._mode,
             "role": ("follower" if isinstance(self, FollowerController)

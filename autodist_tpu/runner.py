@@ -1941,7 +1941,7 @@ class Runner:
         testable offline.  The parsed async-window summary is written
         alongside as ``4-scheduled-hlo.windows.json`` (``{"windows":
         [...], "exposed_ms_per_step": ...}``) so offline tooling — and
-        ``bench.py``'s overlap worker — reads the result instead of
+        tests/test_profile.py — reads the result instead of
         re-parsing the text.  Same failure contract as
         :meth:`dump_compiled`: re-raises under the env knob, else
         returns the failure message."""

@@ -112,7 +112,7 @@ class Pipeline(StrategyBuilder):
                 f"stacked-blocks layout (ops.scan_blocks; e.g. "
                 f"TransformerConfig(scan_layers=True)).")
 
-        # Stage cut: balance ledger + report/bench surface.  The cut is a
+        # Stage cut: balance ledger + the report's surface.  The cut is a
         # pure function of (program, S) with a deterministic tie-break, so
         # chief and workers agree on it like they do on the strategy.
         cut = None

@@ -1,8 +1,7 @@
 """Offline operator tooling (no jax import required).
 
-* :mod:`~autodist_tpu.tools.trend` — the bench trend sentinel: load the
-  ``BENCH_r*.json`` history + the latest ``BENCH_DETAILS.json``, compute
-  per-metric deltas vs the previous and the best round, flag regressions
-  beyond a noise floor, and emit a markdown/JSON trend table
-  (``python -m autodist_tpu.tools.trend`` or ``bench.py --trend``).
+* :mod:`~autodist_tpu.tools.timeline` — merges every host's Chrome trace,
+  flight log and skew summary under a working directory into one
+  offset-corrected trace for Perfetto
+  (``python -m autodist_tpu.tools.timeline <logdir>``).
 """

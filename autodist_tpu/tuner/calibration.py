@@ -21,8 +21,8 @@ paths converge on reality:
   of small/large all-reduces on the live mesh separates per-collective
   latency from bandwidth and stores tier overrides.
 
-A ``bench.py dispatch`` run additionally persists the fitted per-dispatch
-host overhead as :attr:`host_dispatch_ms` — the attribution ledger's
+A fitted per-dispatch host overhead persists as :attr:`host_dispatch_ms`
+(nothing in the tree fits one today: ROADMAP D3) — the attribution ledger's
 host-dispatch term reads it instead of the ``DISPATCH_MS`` seed.
 
 State persists as JSON (default ``<working_dir>/tuner_calibration.json``,
@@ -67,8 +67,8 @@ class Calibration:
         # feedback): {"compute": ..., "comms": ...}.
         self.term_scales = {"compute": 1.0, "comms": 1.0,
                             **(term_scales or {})}
-        # Measured per-dispatch host overhead (ms) from bench's dispatch
-        # worker; None => the cost model's DISPATCH_MS seed.
+        # Measured per-dispatch host overhead (ms), where a caller
+        # fitted one; None => the cost model's DISPATCH_MS seed.
         self.host_dispatch_ms = (float(host_dispatch_ms)
                                  if host_dispatch_ms else None)
         # Last run-level MFU from the goodput ledger (docs/goodput.md) —

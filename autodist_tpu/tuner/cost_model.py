@@ -243,7 +243,7 @@ class Topology:
     # "Wire bytes" here means bytes RECEIVED per device per step on a leg;
     # these formulas are mirrored byte-for-byte by the execution-side
     # trace tally (``hierarchical._tally_hier`` / ``_tally_flat``), which
-    # is what lets bench check measured against predicted exactly.
+    # is what lets tests/test_hierarchical.py check measured against predicted.
 
     def flat_wire_split(self, total_wire_bytes, group_size):
         """Split one FLAT collective's wire bytes (phase- and compression-

@@ -154,7 +154,7 @@ def reprice(strategy, graph_item, cost_model, unrolls=(1,),
     strategy object is reused, so a full re-pricing pass is pure
     cost-model arithmetic.
 
-    ``host_dispatch_ms`` (the bench-calibrated per-dispatch host
+    ``host_dispatch_ms`` (the calibration's measured per-dispatch host
     overhead, :attr:`Calibration.host_dispatch_ms`) replaces the
     ``DISPATCH_MS`` seed in every variant's total when given — the
     measured dispatch floor is exactly the term that makes unroll rank.
@@ -422,7 +422,7 @@ def enumerate_candidates(graph_item, resource_spec, budget=None,
 
 
 class TuningResult:
-    """Ranked search outcome; also the report/bench surface."""
+    """Ranked search outcome; also what the report renders."""
 
     def __init__(self, ranked, pruned, budget, space_size, topology,
                  calibration, objective=DEFAULT_OBJECTIVE):
@@ -613,7 +613,7 @@ def sidecar_path(strategy_id):
 
 def write_sidecar(result, strategy_id):
     """Persist the ranked table next to the strategy artifact (fail-open);
-    bench.py folds this into BENCH_DETAILS.json."""
+    tests/test_tuner.py reads it back."""
     path = sidecar_path(strategy_id)
     try:
         const.ensure_working_dirs()

@@ -1,6 +1,6 @@
 """Where XLA's persistent compilation cache lives.
 
-One rule for every entry point (``chip_smoke.py``, ``bench.py``'s workers,
+One rule for every entry point (``chip_smoke.py``, ``chipbench``'s program,
 ``examples/benchmark``): where ``JAX_COMPILATION_CACHE_DIR`` is set, jax
 reads it itself and no code sets another; where it is not, the cache is
 ``<checkout>/.jax_cache`` — a fixed, git-ignored path (the path is part of

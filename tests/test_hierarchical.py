@@ -7,8 +7,8 @@ Three contracts pinned here:
   on BOTH transports (subgroup collectives and the ppermute fallback)
   and on the explicit nested ``(dcn, ici)`` mesh;
 * accounting: the trace-time wire tally equals the cost model's
-  ``hier_wire_split`` byte for byte — the equality the bench's
-  measured-vs-predicted check rides — and the codec factor tables and
+  ``hier_wire_split`` byte for byte (measured against predicted: what
+  lets the tuner trust its per-leg pricing) and the codec factor tables and
   int8 transport crossover stay in sync across modules;
 * tuning: ``hierarchical_ar_cost`` degenerates EXACTLY to the flat
   all-reduce price (single host, or f32 DCN wire), is monotonic in the
@@ -159,7 +159,7 @@ def test_int8ef_reinjects_residual_across_calls(monkeypatch):
 @pytest.mark.parametrize("codec", CODECS)
 def test_wire_tally_matches_cost_model_split(codec, monkeypatch):
     """The trace-time tally and ``Topology.hier_wire_split`` must agree
-    byte for byte — the bench's measured-vs-predicted equality."""
+    byte for byte: measured against predicted."""
     monkeypatch.setenv("AUTODIST_HIER_ICI", "4")
     grads = _grads()
     n = grads.shape[1]

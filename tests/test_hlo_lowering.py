@@ -8,6 +8,11 @@ all_gather-VJP), so these tests assert on compiled HLO text and fail if the
 mechanism regresses.  Parity claim under test:
 ``autodist_tpu/kernel/synchronization/ps_synchronizer.py`` (accumulator +
 take_grad -> ReduceScatter; reference ``ps_synchronizer.py:553-630``).
+
+These are the CPU pipeline's view, on the live 8-device mesh, with a step
+run first.  The same claims against the real v5e compiler, for a detached
+``v5e:2x4`` topology and with the TPU backend's rewrites applied, sit in
+``tests/test_topology_aot.py::test_v5e_compiler_hlo``.
 """
 import re
 

@@ -68,7 +68,7 @@ def _build(apply_fn):
 
 def _ffn_dot_lead_dims(text):
     """Leading (expert-batch) dims of every compiled expert-FFN op —
-    shared matcher with the bench's TPU-compiler verify arm
+    shared matcher with the v5e-compiler case in test_topology_aot.py
     (``report.einsum_result_lead_dims``)."""
     from autodist_tpu.report import einsum_result_lead_dims
     return einsum_result_lead_dims(text, ("ecd,edh->ech", "ech,ehd->ecd"))

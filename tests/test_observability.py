@@ -121,18 +121,25 @@ def test_step_metrics_consistent_with_wall_clock():
     snap = observability.registry().snapshot()
     assert snap["counters"]["step.count"] == steps
     assert snap["counters"]["step.examples"] == steps * BATCH
+    assert snap["counters"]["host_transfer.batches"] == steps
     hist = snap["histograms"]["step.latency_ms"]
     assert hist["count"] == steps
-    # The histogram total is the loop's own wall clock (host deltas):
-    # it cannot exceed the surrounding wall time and must account for
-    # most of it (the loop body IS the measurement).
-    assert 0 < hist["total"] <= wall_ms * 1.05
-    assert hist["total"] >= 0.5 * wall_ms
+    assert snap["histograms"]["step.data_wait_ms"]["count"] == steps
+    # The histogram's total is the sum of the loop's own host deltas, each
+    # taken inside the surrounding interval: it cannot exceed it.  How much
+    # of the interval it accounts for depends on what else the host runs
+    # (end-of-loop bookkeeping under six test workers), so no lower bound:
+    # the sums are held to each other instead.
+    assert 0 < hist["total"] <= wall_ms
     assert hist["min"] <= hist["p50"] <= hist["p90"] <= hist["max"]
-    # Throughput gauge agrees with the histogram's own arithmetic.
+    assert steps * hist["min"] <= hist["total"] * (1 + 1e-9)
+    assert hist["total"] <= steps * hist["max"] * (1 + 1e-9)
+    # The throughput gauge is the last flushed window's examples over that
+    # window's deltas: a mean of recorded steps, so it lies between the
+    # rates of the slowest and the fastest one (the gauge rounds to 0.1).
     eps = snap["gauges"]["step.examples_per_sec"]
-    implied = steps * BATCH / (hist["total"] / 1e3)
-    assert eps == pytest.approx(implied, rel=0.35)
+    assert BATCH / (hist["max"] / 1e3) - 0.1 <= eps
+    assert eps <= BATCH / (hist["min"] / 1e3) + 0.1
 
 
 def test_step_data_wait_metric_populated():

@@ -533,7 +533,7 @@ def test_anchors_skipped_event_on_explicit_path(monkeypatch):
 def test_skip_idle_default_resolves_per_backend():
     """The fill/drain compute skip defaults ON only where it pays: OFF on
     XLA:CPU (the lax.cond transpose under AD is slower than the garbage
-    compute it avoids — bench.py pipeline's skip-vs-noskip pair) and OFF
+    compute it avoids, timed once as a skip-vs-noskip pair) and OFF
     under the sequence-parallel composition (lax.cond cannot wrap the
     stage's manual seq-axis collectives); ON on TPU/GPU."""
     from autodist_tpu.pipeline import resolve_skip_idle

@@ -218,7 +218,7 @@ def test_telemetry_off_makes_zero_profiling_calls(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# surfacing: report, monitor, sidecar, bench persistence
+# surfacing: report, monitor, sidecar
 
 
 def test_report_renders_per_layer_profile():

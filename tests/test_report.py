@@ -12,9 +12,20 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import optax
+import pytest
 
 from autodist_tpu import AutoDist, const
 from autodist_tpu.strategy import PS
+
+
+@pytest.fixture(autouse=True)
+def _graph_dump_dir_of_its_own(monkeypatch, tmp_path):
+    """``report.html`` mirrors the newest compile of every process that
+    shares the working directory, so under ``-n 6`` a neighbour's compile
+    replaces or removes the file a test here is about to read.  Each test
+    renders into a directory no other process knows."""
+    monkeypatch.setattr(const, "DEFAULT_GRAPH_DUMP_DIR",
+                        str(tmp_path / "graphs"))
 
 
 def _build():
@@ -33,11 +44,10 @@ def _build():
     return runner, batch
 
 
-def test_report_auto_rendered_on_compile(tmp_path):
+def test_report_auto_rendered_on_compile():
     runner, batch = _build()
     path = os.path.join(const.DEFAULT_GRAPH_DUMP_DIR, "report.html")
-    if os.path.exists(path):
-        os.remove(path)
+    assert not os.path.exists(path)
     state = runner.create_state()
     runner.step(state, batch)  # first compile triggers the chief's report
     assert os.path.exists(path), "report.html not auto-rendered on compile"
@@ -92,9 +102,9 @@ def test_report_written_per_strategy_with_stable_alias_and_history():
 
 
 # -- collective_summary / replica_group_sizes edge cases ---------------------
-# These regexes back the bench verified flags (zero-verify, pod-compile):
-# an HLO form they silently stop matching flips a verified claim to a
-# false negative, so every form XLA emits is pinned here.
+# These regexes back the HLO assertions of tests/test_topology_aot.py and
+# tests/test_moe_hlo.py: an HLO form they silently stop matching empties
+# an assertion, so every form XLA emits is pinned here.
 
 
 def test_collective_summary_counts_plain_and_suffixed_invocations():
@@ -162,7 +172,7 @@ def test_collective_summary_does_not_cross_match_op_names():
 def test_replica_group_sizes_parses_both_hlo_syntaxes():
     """XLA emits replica groups either as iota form [G,S]<=[...] or as the
     explicit brace form {{0,1},{2,3}}; a pass/version switching form must
-    not silently empty the set (it feeds the bench verified flags)."""
+    not silently empty the set (the detached-topology cases read it)."""
     from autodist_tpu.report import replica_group_sizes
     iota = "all-reduce(a), replica_groups=[4,2]<=[8], to_apply=add"
     brace = "all-reduce(a), replica_groups={{0,1,2,3},{4,5,6,7}}"

@@ -2,11 +2,24 @@
 
 The ``AutoDist(devices=...)`` override exists so programs can be AOT-
 compiled against a *detached* TPU topology (``jax.experimental.
-topologies``) — the bench's ``zero-verify`` worker asserts chip-compiled
-HLO this way (VERDICT r3 item 8).  On the CPU test mesh the same contract
-is exercised with a subset of the live devices: the mesh must span exactly
-the devices handed in, and the step must lower+compile from
-ShapeDtypeStructs alone (no state materialization)."""
+topologies``): no chip attached, no buffer materialised, the real v5e
+compiler.  Two tiers sit side by side here:
+
+* on the CPU test mesh the contract itself, with a subset of the live
+  devices: the mesh must span exactly the devices handed in, and the step
+  must lower+compile from ShapeDtypeStructs alone;
+* ``test_v5e_compiler_hlo``: the train step compiled by libtpu for a
+  detached ``v5e:2x4`` (and a 256-chip ``v5e:16x16``), asserting the
+  optimized HLO that ``tests/test_hlo_lowering.py`` and
+  ``tests/test_moe_hlo.py`` can only see through the CPU pipeline, which
+  applies none of the TPU backend's rewrites.  Where this machine's libtpu
+  cannot describe the topology the cases skip and say why.
+"""
+import functools
+import re
+import subprocess
+import sys
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -14,7 +27,10 @@ import optax
 import pytest
 
 from autodist_tpu import AutoDist
-from autodist_tpu.strategy import PS
+from autodist_tpu.parallel import moe as moe_mod
+from autodist_tpu.report import (collective_summary, einsum_result_lead_dims,
+                                 replica_group_sizes)
+from autodist_tpu.strategy import PS, AllReduce, ModelParallel
 
 
 def _loss_fn(params, batch):
@@ -55,7 +71,7 @@ def test_devices_override_builds_mesh_over_subset(tmp_path):
 
 def test_aot_compile_from_structs_without_state(tmp_path):
     """lower(state_struct, batch_struct).compile() must work with no live
-    arrays — the detached-topology contract (zero-verify worker)."""
+    arrays — the detached-topology contract."""
     devs = jax.devices()[:4]
     if len(devs) < 4:
         pytest.skip("needs the forced 8-device CPU mesh")
@@ -70,7 +86,235 @@ def test_aot_compile_from_structs_without_state(tmp_path):
     text = compiled.lower(runner.state_struct, batch_struct).compile().as_text()
     # The 4-device PS program carries its collectives (explicit path:
     # psum_scatter -> reduce-scatter + all_gather).
-    from autodist_tpu.report import collective_summary
     counts = collective_summary(text, keep_zeros=True)
     assert counts["reduce-scatter"] >= 1
     assert counts["all-gather"] >= 1
+
+
+class _Chip:
+    """What ``build_mesh`` reads of a device that is not a CPU."""
+    platform = "tpu"
+
+    def __init__(self, i, slice_index):
+        self.id, self.slice_index = i, slice_index
+
+
+@pytest.mark.parametrize("slices, axes, want", [
+    (1, {"data": 4, "model": 2}, ("one", (4, 2))),
+    (2, {"data": 4, "model": 2}, ("hybrid", (2, 2), (2, 1))),
+    (2, {"data": 8}, ("hybrid", (4,), (2,))),
+    (3, {"data": 4, "model": 3}, ValueError),
+])
+def test_build_mesh_spans_slices_with_the_outermost_axis(
+        tmp_path, monkeypatch, slices, axes, want):
+    """Slices are joined by DCN: the outermost axis (data) spans them and
+    every other axis stays on one slice's ICI; one slice takes the plain
+    topology-aware layout.  Held on fakes so that it runs where libtpu
+    describes no topology (the two-slice case below compiles the real one)."""
+    from jax.experimental import mesh_utils
+    from autodist_tpu.cluster import Cluster
+    from autodist_tpu.resource_spec import ResourceSpec
+    n = int(np.prod(list(axes.values())))
+    devs = [_Chip(i, i * slices // n) for i in range(n)]
+    calls = []
+
+    def one(shape, devices):
+        calls.append(("one", tuple(shape)))
+        return np.array(devices, dtype=object).reshape(shape)
+
+    def hybrid(ici, dcn, devices):
+        calls.append(("hybrid", tuple(ici), tuple(dcn)))
+        return np.array(devices, dtype=object).reshape(
+            tuple(a * b for a, b in zip(ici, dcn)))
+
+    monkeypatch.setattr(mesh_utils, "create_device_mesh", one)
+    monkeypatch.setattr(mesh_utils, "create_hybrid_device_mesh", hybrid)
+    cluster = Cluster(ResourceSpec(_spec_4cpu(tmp_path)))
+    if want is ValueError:
+        with pytest.raises(ValueError, match="3 slices"):
+            cluster.build_mesh(axes, devices=devs)
+        return
+    mesh = cluster.build_mesh(axes, devices=devs)
+    assert calls == [want]
+    assert dict(mesh.shape) == axes
+
+
+# ---------------------------------------------------------------------------
+# The real v5e compiler, for a detached topology.
+
+_PROBE = ("from jax.experimental import topologies as t; "
+          "d = t.get_topology_desc(platform='tpu', "
+          "topology_name='v5e:2x4').devices; "
+          "print('DETACHED', len(d), sorted({x.device_kind for x in d}))")
+
+
+@functools.cache
+def _why_no_detached_topology():
+    """'' where libtpu describes a ``v5e:2x4`` here.  Asked once, in a child
+    with a minute to answer: a libtpu that waits for hardware must cost the
+    suite one skip reason, not its time limit."""
+    try:
+        out = subprocess.run([sys.executable, "-c", _PROBE], timeout=60,
+                             capture_output=True, text=True)
+    except subprocess.TimeoutExpired:
+        return "get_topology_desc('v5e:2x4') gave no answer in 60 s"
+    if "DETACHED 8 ['TPU v5 lite']" in out.stdout:
+        return ""
+    said = (out.stdout + out.stderr).strip().splitlines()[-1:] or ["nothing"]
+    return (f"no detached v5e:2x4 from this libtpu (rc={out.returncode}): "
+            f"{said[0][:200]}")
+
+
+def _compile_on_topology(builder, loss_fn, params, batch, tmp_path,
+                         topology_name="v5e:2x4", num_slices=1):
+    """AOT-compile the full train step for a detached TPU topology and
+    return (optimized HLO text, runner).  ``batch`` is ShapeDtypeStructs: a
+    pod-scale global batch never exists as an array."""
+    from jax.experimental import topologies
+    topo = topologies.get_topology_desc(
+        platform="tpu", topology_name=topology_name, num_slices=num_slices)
+    # A single-process spec whatever the slice count: this process only
+    # compiles (jax.distributed must not start); the device list carries
+    # the true shape.
+    spec = tmp_path / "spec.yml"
+    spec.write_text(
+        "nodes:\n  - address: 127.0.0.1\n    chief: true\n"
+        f"    tpus: [{', '.join(str(i) for i in range(len(topo.devices)))}]\n")
+    ad = AutoDist(str(spec), builder, devices=topo.devices)
+    item = ad.capture(loss_fn, params, optax.adam(1e-3), example_batch=batch)
+    runner = ad.create_distributed_session(item)
+    exe = runner._compile(batch).lower(runner.state_struct, batch).compile()
+    return exe.as_text(), runner
+
+
+def _mlp_loss(params, batch):
+    x, y = batch
+    h = jax.nn.relu(x @ params["w1"])
+    pred = h @ params["w2"] + params["b"]
+    return jnp.mean((pred - y) ** 2)
+
+
+def _mlp(batch_size=32):
+    """tests/test_hlo_lowering.py's model: three trainable variables, so a
+    per-variable gradient all-reduce shows as a count above two."""
+    params = {"w1": jnp.zeros((64, 128)), "w2": jnp.zeros((128, 8)),
+              "b": jnp.zeros((8,))}
+    batch = (jax.ShapeDtypeStruct((batch_size, 64), jnp.float32),
+             jax.ShapeDtypeStruct((batch_size, 8), jnp.float32))
+    return _mlp_loss, params, batch
+
+
+_EP, _E = 4, 8
+
+
+def _moe():
+    """tests/test_moe_hlo.py's block, without the head."""
+    cfg = moe_mod.MoEConfig(num_experts=_E, top_k=2, d_model=32, d_hidden=128)
+    params = {"moe": moe_mod.init(jax.random.PRNGKey(1), cfg)}
+
+    def loss(p, b):
+        h, aux = moe_mod.apply(p["moe"], cfg, b[0])
+        return jnp.mean(h ** 2) + 0.01 * aux
+
+    batch = (jax.ShapeDtypeStruct((256, 32), jnp.float32),
+             jax.ShapeDtypeStruct((256,), jnp.int32))
+    return loss, params, batch
+
+
+def _counts(text):
+    return collective_summary(
+        text, ops=("reduce-scatter", "all-reduce", "all-gather",
+                   "dynamic-slice"), keep_zeros=True)
+
+
+def _all_reduces_of_w1(text):
+    """All-reduce instructions that carry a buffer of ``w1``'s full shape."""
+    return [ln for ln in text.splitlines()
+            if re.search(r"= .*\ball-reduce(-start)?(\.\d+)?\(", ln)
+            and "f32[64,128]" in ln.split(" all-reduce")[0]]
+
+
+def _check_ps_explicit(text, runner):
+    # The sharded variable's gradient is reduce-scattered and no all-reduce
+    # carries it whole.  The TPU pipeline combines what is left (the two
+    # small variables' gradients and the loss) into at most two.
+    c = _counts(text)
+    assert c["reduce-scatter"] >= 1 and c["all-gather"] >= 1, c
+    assert c["all-reduce"] <= 2, c
+    assert not _all_reduces_of_w1(text)
+
+
+def _check_ps_gspmd_update(text, runner):
+    # This XLA reshards the gradients as all-reduce + dynamic-slice even on
+    # the TPU pipeline (no AR -> RS rewrite: why the explicit path is the
+    # default).  The claim is the shard-local update: slice -> update ->
+    # all-gather.
+    c = _counts(text)
+    assert c["reduce-scatter"] == 0 and _all_reduces_of_w1(text), c
+    assert c["dynamic-slice"] >= 1 and c["all-gather"] >= 1, c
+
+
+def _check_tensor_parallel(text, runner):
+    # Kernel storage sharded over `model`, and a collective whose replica
+    # groups span that axis (size 2): the data-axis gradient all-reduces
+    # (groups of 4) do not satisfy it, so replicated activations fail here.
+    assert "model" in str(runner.state_shardings.params["w1"].spec)
+    assert 2 in replica_group_sizes(text), replica_group_sizes(text)
+
+
+def _check_expert_parallel(text, runner):
+    # Every expert product on an E/ep buffer, and tokens crossing the
+    # expert axis through a collective of that group size.
+    lead = einsum_result_lead_dims(text, ("ecd,edh->ech", "ech,ehd->ecd"))
+    assert lead and set(lead) == {_E // _EP}, lead
+    assert _EP in replica_group_sizes(text), replica_group_sizes(text)
+
+
+def _check_two_slices(text, runner):
+    # The compiler makes the 16-way gradient all-reduce hierarchical: one
+    # slice's eight chips reduce over ICI, and the slices' partial sums
+    # cross DCN as a megascale transfer.  One slice has no such transfer.
+    assert runner.program.mesh.devices.size == 16
+    assert 8 in replica_group_sizes(text), replica_group_sizes(text)
+    assert '_xla_megascale_transfer_type="ALL_REDUCE"' in text
+
+
+def _check_pod(text, runner):
+    # The gradient all-reduce is one replica group over the whole pod.
+    assert _counts(text)["all-reduce"] >= 1
+    assert 256 in replica_group_sizes(text), replica_group_sizes(text)
+
+
+# name -> (strategy builder, model, topology, slices, check of the HLO)
+_V5E_CASES = {
+    "ps_explicit_reduce_scatter":
+        (PS, _mlp, "v5e:2x4", 1, _check_ps_explicit),
+    "ps_gspmd_update_shard_local":
+        (lambda: PS(gspmd_update=True), _mlp, "v5e:2x4", 1,
+         _check_ps_gspmd_update),
+    "tensor_parallel_dp4_tp2":
+        (lambda: ModelParallel(rules=(("w1", 1), ("w2", 0))), _mlp,
+         "v5e:2x4", 1, _check_tensor_parallel),
+    "expert_parallel_dp2_ep4":
+        (lambda: ModelParallel(AllReduce(), model_axis=_EP,
+                               rules=moe_mod.EXPERT_RULES,
+                               mesh_axis="expert"), _moe,
+         "v5e:2x4", 1, _check_expert_parallel),
+    "two_slices_of_2x4":
+        (AllReduce, _mlp, "v5e:2x4", 2, _check_two_slices),
+    "pod_16x16":
+        (AllReduce, functools.partial(_mlp, batch_size=256), "v5e:16x16", 1,
+         _check_pod),
+}
+
+
+@pytest.mark.parametrize("case", list(_V5E_CASES))
+def test_v5e_compiler_hlo(case, tmp_path):
+    why_not = _why_no_detached_topology()
+    if why_not:
+        pytest.skip(why_not)
+    builder, model, topology, slices, check = _V5E_CASES[case]
+    text, runner = _compile_on_topology(
+        builder(), *model(), tmp_path, topology_name=topology,
+        num_slices=slices)
+    check(text, runner)
