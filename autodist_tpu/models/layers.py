@@ -164,8 +164,9 @@ def rope_tables(seq_len, head_dim, theta=10000.0):
 
 
 def apply_rope(x, tables):
-    """Rotate (batch, heads, seq, head_dim) by its positions: element i
-    pairs with element i + head_dim / 2 (the rotate-half form)."""
+    """Rotate ``x`` (..., head_dim) by its positions, ``tables`` broadcasting
+    against it ((seq, head_dim) for (batch, heads, seq, head_dim)): element
+    i pairs with element i + head_dim / 2 (the rotate-half form)."""
     cos, sin = tables
     xf = x.astype(jnp.float32)
     x1, x2 = jnp.split(xf, 2, axis=-1)
@@ -177,25 +178,38 @@ def mha(p, x, num_heads, mask=None, dtype=None, attn_fn=None, rope=None,
         norm_eps=1e-5):
     """Multi-head self-attention.
 
-    ``attn_fn(q, k, v, causal)`` may override the inner attention computation
+    ``attn_fn(q, k, v, mask)`` may override the inner attention computation
     (the hook used to swap in the Pallas flash kernel or ring attention).
-    q/k/v are (batch, heads, seq, head_dim).  Where the parameters hold
+    q/k/v are (batch, heads, seq, head_dim).  A hook may carry
+    ``attn_fn.bshd(num_heads, head_dim)``; where that gives a function, it
+    is the same attention on (batch, seq, heads, head_dim), the projections'
+    own (batch, seq, dim) seen as heads, and it is called instead: neither
+    q, k, v nor the result is transposed (the flash kernels read and write
+    that layout, ``ops/flash_attention.py``).  Where the parameters hold
     ``q_norm`` / ``k_norm`` (QK-norm), q and k are RMS-normalised over the
     whole projected vector before the split into heads; ``rope``
     (:func:`rope_tables`) rotates q and k after it.
     """
     b, s, _ = x.shape
+    bshd = getattr(attn_fn, "bshd", None)
+    if bshd is not None:
+        bshd = bshd(num_heads, p["query"]["kernel"].shape[1] // num_heads)
 
     def project(name, norm=None):
         t = dense(p[name], x, dtype)
         if norm in p:
             t = rmsnorm(p[norm], t, norm_eps)
-        return t.reshape(b, s, num_heads, -1).transpose(0, 2, 1, 3)
+        t = t.reshape(b, s, num_heads, -1)
+        return t if bshd else t.transpose(0, 2, 1, 3)
 
     q, k = project("query", "q_norm"), project("key", "k_norm")
     v = project("value")
     if rope is not None:
+        if bshd:    # (seq, head_dim) against (batch, seq, heads, head_dim)
+            rope = tuple(t[:, None] for t in rope)
         q, k = apply_rope(q, rope), apply_rope(k, rope)
+    if bshd:
+        return dense(p["out"], bshd(q, k, v, mask).reshape(b, s, -1), dtype)
     if attn_fn is not None:
         o = attn_fn(q, k, v, mask)
     else:

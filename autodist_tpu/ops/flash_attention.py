@@ -28,20 +28,48 @@ makes the nine products single-pass whatever ``jax_default_matmul_precision``
 the caller traces under, where f32 operands would follow it (1.4-2.5x the
 kernel time at ``highest``).
 
-A program of the grid handles ``G`` (batch, head) rows: where a row's score
-tile is small (128 x 128 at s = 128) most of a one-row program is what a
-program costs whatever it computes, so rows share a program until its tiles
-are as large as a long-sequence program's or VMEM is full
-(``_rows_per_program``; the operands' shape alone decides, and from s = 1,024
-``G`` is 1 and the program is the one-row program unchanged). Inside, a loop
-on the device takes as many rows a step as fit the vector registers, through
-the same arithmetic with a leading rows axis (``_for_rows``). A row's results
-do not depend on ``G``: they are bit for bit those of one row a program.
+The kernels read their operands in one of two layouts, chosen from the shape
+and never by an argument.  **Packed**: ``(batch, s, heads x d)``, what the
+projection matmuls write and the output projection and the weight-gradient
+matmuls read.  A block is ``(rows, block, lanes)`` with ``lanes`` = lcm(d,
+128), whole heads (two at d = 64, one at d = 128); the block's index along the
+last axis picks the heads, so the pipeline's own transfers do what the head
+split's transpose did and no copy of q, k, v, ``do`` or a result is run around
+the kernels.  Inside a program a head is ``d`` of the block's lanes: its
+products run over the whole width with the other heads' lanes zeroed on one
+operand (exact zeros; at d = 64 a product fills half the MXU either way), the
+accumulators are lane-dense ``(block, lanes)`` tiles, and the head is an index
+of the loop on the device, so the body is traced once whatever the heads.
+**Split**: ``(batch, heads, s, d)`` flattened to ``(batch x heads, s, d)``,
+one head a block of ``d`` lanes: the same body with nothing to mask.  The rule
+(``_heads_per_block``, ``make_flash_attn_fn``): packed where ``heads x d``
+splits into blocks of lcm(d, 128) lanes that hold several heads and no mesh
+axis splits the heads; split otherwise (25 heads of 64; heads over ``model``;
+heads of 128, a block each, whose transposes cost less than packed blocks'
+short rows cost the kernels), and for every caller of ``flash_attention(q,
+k, v)`` itself: ring attention's ``block_attn_fwd`` / ``block_attn_bwd``,
+Ulysses, the chip smoke.  Where the rule says split, ``models.layers.mha``
+and the compiled step are what they were.  The row statistics (``lse``,
+``delta``) are ``(batch, heads, s, 1)`` in both.
+
+A program of the grid handles ``G`` (batch, head) rows, batch rows x the
+heads of a block: where a row's score tile is small (128 x 128 at s = 128)
+most of a one-row program is what a program costs whatever it computes, so
+rows share a program until its tiles are as large as a long-sequence
+program's or VMEM is full (``_rows_per_program``; the operands' shape alone
+decides, and from s = 1,024 ``G`` is the heads of one block: the split
+layout's one-row program unchanged).  Inside, a loop on the device takes as
+many rows a step as fit the vector registers, through the same arithmetic
+with a leading rows axis (``_for_rows``).  A row's results do not depend on
+``G``, nor on the layout beyond the order of a sum: on the v5e the packed
+kernels' o, dq, dk, dv at d = 64 are bit for bit the split ones' (PERF.md,
+PR 28).
 
 Off TPU the dense jnp path runs instead (CPU tests use ``interpret=True``
 to exercise the kernels in the Pallas interpreter); every trace logs once,
 at info, which path it took and why.
 """
+import dataclasses
 import functools
 import math
 
@@ -127,63 +155,202 @@ def _padded_bytes(shape, dtype):
             * -(-lanes // 128) * 128 * itemsize)
 
 
-def _rows_per_program(rows, block_q, block_k, blocks, scratch):
-    """``(G, vmem_bytes)``: how many of the ``rows`` (batch x heads) one
-    program handles, and the VMEM those ``G`` rows occupy.
+def _rows_per_program(units, block_q, block_k, blocks, scratch, heads=1):
+    """``(G, vmem_bytes)``: how many (batch, head) rows one program handles,
+    and the VMEM those ``G`` rows occupy.
 
-    ``blocks`` are one row's pipelined in/out blocks (double-buffered),
+    ``units`` is what may share a program: the batch x heads rows of the
+    split layout, the batch rows of the packed one, where a unit is the
+    ``heads`` heads of a 128-lane block and ``G`` counts batch rows x heads.
+    ``blocks`` are one unit's pipelined in/out blocks (double-buffered),
     ``scratch`` its accumulators and statistics, each ``(shape, dtype)``.
-    ``G`` is the largest divisor of ``rows`` whose score tiles stay within
-    ``_MAX_TILE`` and whose padded VMEM stays within ``_VMEM_BUDGET``; 1
-    where not even one row does (the blocks are the caller's choice)."""
-    vmem_a_row = (2 * sum(_padded_bytes(*b) for b in blocks)
-                  + sum(_padded_bytes(*b) for b in scratch))
-    most = min(_MAX_TILE // (block_q * block_k), _VMEM_BUDGET // vmem_a_row)
-    g = max((g for g in range(1, most + 1) if rows % g == 0), default=1)
-    return g, g * vmem_a_row
+    ``G`` is ``heads`` times the largest divisor of ``units`` whose score
+    tiles stay within ``_MAX_TILE`` and whose padded VMEM stays within
+    ``_VMEM_BUDGET``; one unit where not even one does (the blocks are the
+    caller's choice, a block's lanes the layout's)."""
+    vmem_a_unit = (2 * sum(_padded_bytes(*b) for b in blocks)
+                   + sum(_padded_bytes(*b) for b in scratch))
+    most = min(_MAX_TILE // (heads * block_q * block_k),
+               _VMEM_BUDGET // vmem_a_unit)
+    n = max((n for n in range(1, most + 1) if units % n == 0), default=1)
+    return n * heads, n * vmem_a_unit
 
 
-def _announce(kernel, operand, sk, block_q, block_k, rows, vmem_bytes):
+@dataclasses.dataclass(frozen=True)
+class _Layout:
+    """How a call's operands reach the kernels: the one difference between
+    the two layouts, read by the code that builds a ``pallas_call`` and
+    never by a kernel's body, which sees only its blocks.
+
+    split: ``q`` is ``(batch, heads, s, d)`` and the kernels' arrays are
+    ``(batch x heads, s, d)``: one head a block, ``d`` lanes, a unit of the
+    grid's first dimension one (batch, head) row, the row statistics
+    ``(batch x heads, s, 1)``.
+
+    packed: ``q`` is ``(batch, s, heads, d)``, the projections' own
+    ``(batch, s, heads x d)`` seen as heads, and the kernels' arrays are
+    just that: a block holds ``lanes`` = lcm(d, 128) lanes, ``lanes / d``
+    whole heads (two at d = 64, one at d = 128), a unit is one batch row's
+    heads of one such block, and the block's index along the last axis picks
+    the heads, so the pipeline's transfers do what a transpose did.  The row
+    statistics stay ``(batch, heads, s, 1)``."""
+    packed: bool
+    batch: int
+    num_heads: int
+    d: int
+
+    @classmethod
+    def of(cls, q, packed):
+        if packed:
+            batch, _, num_heads, d = q.shape
+        else:
+            batch, num_heads, _, d = q.shape
+        return cls(packed, batch, num_heads, d)
+
+    @property
+    def name(self):
+        return "packed" if self.packed else "split"
+
+    @property
+    def lanes(self):
+        return math.lcm(self.d, 128) if self.packed else self.d
+
+    @property
+    def heads(self):
+        """Heads a block."""
+        return self.lanes // self.d
+
+    @property
+    def units(self):
+        return self.batch if self.packed else self.batch * self.num_heads
+
+    @property
+    def lane_blocks(self):
+        """Blocks along the arrays' last axis; a program's index in the
+        grid's first dimension is (group of units) x lane_blocks + (lane
+        block)."""
+        return self.num_heads // self.heads if self.packed else 1
+
+    def array(self, x):
+        """``x`` (q, k, v, do) as the kernels read it: a reshape, no copy."""
+        if self.packed:
+            return x.reshape(x.shape[0], x.shape[1], -1)
+        return x.reshape(self.units, x.shape[2], self.d)
+
+    def stat(self, x):
+        return x if self.packed else x.reshape(self.units, x.shape[2], 1)
+
+    def shape(self, length, stat=False):
+        """A result of ``length`` positions, as the kernels write it."""
+        if not self.packed:
+            return self.units, length, 1 if stat else self.d
+        if stat:
+            return self.batch, self.num_heads, length, 1
+        return self.batch, length, self.num_heads * self.d
+
+    def result(self, x, stat=False):
+        """A kernel's result in the layout ``q`` came in."""
+        if self.packed:
+            return x if stat else x.reshape(
+                self.batch, x.shape[1], self.num_heads, self.d)
+        return x.reshape(self.batch, self.num_heads, x.shape[1], x.shape[2])
+
+    def block(self, length, stat=False):
+        """One unit's block of ``length`` positions."""
+        if stat:
+            return (self.heads, length, 1) if self.packed else (length, 1)
+        return length, self.lanes
+
+    def spec(self, n, length, seq, stat=False):
+        """BlockSpec of ``n`` units' blocks whose position along the sequence
+        is the grid's index number ``seq`` (1 or 2)."""
+        across = self.lane_blocks
+
+        def index(*grid):
+            i, j = grid[0], grid[seq]
+            if not self.packed:
+                return i, j, 0
+            # lax, not ``//`` and ``%``: jnp's take a sign's care that costs
+            # a step's lowering seconds over its hundreds of index maps.
+            rows, block = (i, 0) if across == 1 else (
+                jax.lax.div(i, across), jax.lax.rem(i, across))
+            return (rows, block, j, 0) if stat else (rows, j, block)
+        return pl.BlockSpec((n,) + self.block(length, stat), index)
+
+
+def _heads_per_block(num_heads, d):
+    """Heads a block where the hook takes the packed layout, else None: the
+    rule between the two layouts.  None where ``num_heads x d`` lanes do not
+    split into blocks of lcm(d, 128) lanes, whole heads each (25 heads of
+    64), and where a block would be one head (d = 128): there the head
+    split's transposes are 128 lanes wide, run near the HBM's rate and fuse
+    with rotary, and cost less than what the packed blocks' 256-byte rows
+    add to the kernels (OLMoE at 4,096 x 128: 6.22 -> 6.74 ms of kernels and
+    -1.5% tokens/s packed; PERF.md, PR 28).  The kernels themselves take one
+    head a block in either layout."""
+    heads = math.lcm(d, 128) // d
+    return heads if heads > 1 and num_heads % heads == 0 else None
+
+
+def _announce(kernel, layout, operand, sk, block_q, block_k, rows, vmem_bytes):
     """One info line a distinct kernel and shape, and with telemetry on the
-    gauge ``flash.rows_per_program`` and a ``flash`` event: which program
-    the rule above made of this call, read at trace time."""
-    bh, sq, d = operand.shape
-    programs = bh // rows * (sq // block_q) * (sk // block_k)
-    detail = (f"{kernel} {jnp.dtype(operand.dtype).name}[{bh},{sq},{d}] "
-              f"over {sk} keys: blocks {block_q} x {block_k}, G = {rows} "
-              f"(batch, head) rows a program, {programs} programs a call, "
-              f"{vmem_bytes} bytes of VMEM by the padded estimate")
+    gauges ``flash.rows_per_program`` and ``flash.heads_per_block`` and a
+    ``flash`` event: which layout the shape gave this call and which program
+    the rule above made of it, read at trace time."""
+    sq = operand.shape[1]
+    programs = (layout.units * layout.heads // rows * layout.lane_blocks
+                * (sq // block_q) * (sk // block_k))
+    shape = ",".join(str(n) for n in operand.shape)
+    detail = (f"{kernel} {jnp.dtype(operand.dtype).name}[{shape}] "
+              f"over {sk} keys: {layout.name} layout, {layout.heads} heads a "
+              f"block of {layout.lanes} lanes, blocks {block_q} x {block_k}, "
+              f"G = {rows} (batch, head) rows a program, {programs} programs "
+              f"a call, {vmem_bytes} bytes of VMEM by the padded estimate")
     _log_path("pallas", detail)
     from autodist_tpu import observability
     if not observability.enabled():
         return
     observability.registry().gauge("flash.rows_per_program").set(rows)
+    observability.registry().gauge("flash.heads_per_block").set(layout.heads)
     if detail not in _announced:
         _announced.add(detail)
         observability.record_event("flash", detail)
 
 
-def _for_rows(rows, tile, body):
-    """``body(at)`` over a program's rows, ``tile`` a row's score tile and
-    ``at`` what indexes the rows of one step in a block: the one row of a
-    one-row program, which has no loop and two-dimensional arithmetic; else
-    a loop on the device whose step takes as many rows as ``_STEP_TILE``
-    allows, one as an index, several as a slice, so that a kernel's code does
-    not grow with ``rows``."""
-    if rows == 1:
-        body(0)
-        return
-    a_step = max((n for n in range(1, rows + 1)
-                  if rows % n == 0 and n * tile <= _STEP_TILE), default=1)
-    if a_step == rows:
-        body(slice(None))
+def _for_rows(rows, heads, tile, body):
+    """``body(at, head)`` over a program's (batch, head) rows, ``tile`` a
+    row's score tile.  ``at`` indexes the rows of one step in a block's first
+    dimension: the one row of a one-row program, which has no loop and
+    two-dimensional arithmetic; else a loop on the device whose step takes as
+    many rows as ``_STEP_TILE`` allows, one as an index, several as a slice,
+    so that a kernel's code does not grow with ``rows``.  ``head`` is None
+    where a block holds one head; where it holds several the loop takes them
+    one a step, inside the rows', and ``head`` is its index on the device:
+    the body masks lanes by it and is traced once whatever the heads."""
+    a_step = 1 if rows == 1 else max(
+        (n for n in range(1, rows + 1)
+         if rows % n == 0 and n * tile <= _STEP_TILE), default=1)
+    steps = rows // a_step
+
+    def at(i):
+        if rows == 1:
+            return 0
+        if steps == 1:
+            return slice(None)
+        return (i if a_step == 1
+                else pl.ds(pl.multiple_of(i * a_step, a_step), a_step))
+
+    if steps * heads == 1:
+        body(at(0), None)
         return
 
     def step(i, carry):
-        body(i if a_step == 1
-             else pl.ds(pl.multiple_of(i * a_step, a_step), a_step))
+        if heads == 1:
+            body(at(i), None)
+        else:
+            body(at(jax.lax.div(i, heads)), jax.lax.rem(i, heads))
         return carry
-    jax.lax.fori_loop(0, rows // a_step, step, 0)
+    jax.lax.fori_loop(0, steps * heads, step, 0)
 
 
 def _dot(a, b, contract_a, contract_b):
@@ -195,22 +362,100 @@ def _dot(a, b, contract_a, contract_b):
                (rows, rows)), preferred_element_type=jnp.float32)
 
 
-def _row_of(scratch, at):
-    """``at`` for a program's scratch, which has no rows dimension in a
-    program of one row (``_scratch``): the long-sequence cells' kernels
-    compile to the same Mosaic module whether or not short rows group."""
-    return slice(None) if len(scratch.shape) == 2 else at
+def _lanes_of(head, d, x):
+    """Which lanes of a block's value ``x`` are ``head``'s ``d``, as a mask
+    that broadcasts against it; None where the block is one head."""
+    lanes = x.shape[-1]
+    if lanes == d:
+        return None
+    lane = jax.lax.broadcasted_iota(
+        jnp.int32, (1,) * (x.ndim - 1) + (lanes,), x.ndim - 1)
+    return jnp.logical_and(lane >= head * d, lane < (head + 1) * d)
 
 
-def _all_rows(rows):
+def _only(keep, x):
+    """``x`` with the lanes outside ``keep`` zeroed.  A product over a
+    block's whole width with one operand so masked is one head's product
+    (the other heads' lanes add exact zeros), and a product that makes the
+    whole width from a masked operand leaves zeros in their lanes: at d = 64
+    a product fills half the 128-wide MXU either way, so neither costs a
+    pass more than the head's own 64 lanes would."""
+    return x if keep is None else jnp.where(keep, x, jnp.zeros_like(x))
+
+
+def _over_lanes(stat, d, like):
+    """A program's row statistics ``(rows, heads, block, 1)`` spread over
+    their heads' lanes of a block shaped ``like``; as they are where the
+    block is one head a row (the split layout) and they broadcast."""
+    if len(stat.shape) < 4:
+        return stat
+    out = stat[:, 0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, like.shape[-1]), 2)
+    for head in range(1, stat.shape[1]):
+        out = jnp.where(lane >= head * d, stat[:, head], out)
+    return out
+
+
+def _row_of(ref, at, head=None):
+    """The rows ``at`` of a program's scratch or statistics.  Scratch has no
+    rows dimension in a program of one row of the split layout
+    (``_scratch``): the long-sequence cells' kernels compile to the same
+    Mosaic module whether or not short rows group.  The packed layout's
+    statistics are ``(rows, heads, block, 1)`` and give those of ``head``
+    (the one head where a block holds one)."""
+    if len(ref.shape) == 4:
+        return at, 0 if head is None else head
+    return slice(None) if len(ref.shape) == 2 else at
+
+
+def _all_rows(scratch):
     """The index of all of a program's rows in a block, for a value read from
-    its scratch: the one row of a one-row program, whose scratch has no rows
-    dimension."""
-    return 0 if rows == 1 else slice(None)
+    its scratch: the one row where the scratch has no rows dimension."""
+    return 0 if len(scratch.shape) == 2 else slice(None)
 
 
-def _scratch(g, shape):
-    return pltpu.VMEM(shape if g == 1 else (g,) + shape, jnp.float32)
+def _scratch(layout, n, shape):
+    """f32 scratch of ``shape`` a unit for a program of ``n`` units."""
+    return pltpu.VMEM(shape if n == 1 and not layout.packed else (n,) + shape,
+                      jnp.float32)
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "name", "body", "layout", "n", "grid_tail", "ins", "outs", "scratch",
+    "block_q", "block_k", "causal", "interpret"))
+def _kernel_call(offs, *arrays, name, body, layout, n, grid_tail, ins, outs,
+                 scratch, block_q, block_k, causal, interpret):
+    """One kernel over ``arrays`` in the kernels' own shapes, ``n`` units a
+    program.  ``ins`` give each array's block as ``(length, which of the
+    grid's indices places it along the sequence, whether it is a row
+    statistic)``, ``outs`` each result's the same way and then ``(dtype,
+    whole length)``, ``scratch`` the f32 accumulators' shapes a unit.
+
+    An inlined ``jit``: a model's layers make the same call, and every one
+    after the first takes the first's equations from the cache, the kernel's
+    body traced once and (its jaxpr being one object) lowered to Mosaic once;
+    the benchmark's process spends 5-10 times a clean process's time on
+    tracing (PERF.md section 7), and a step's attention is most of a model's
+    traced operations."""
+    return pl.pallas_call(
+        functools.partial(body, d=layout.d, block_q=block_q, block_k=block_k,
+                          causal=causal, skip_blocks=not interpret),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(layout.units // n * layout.lane_blocks,) + grid_tail,
+            in_specs=[layout.spec(n, *block) for block in ins],
+            out_specs=[layout.spec(n, *out[:3]) for out in outs],
+            scratch_shapes=[_scratch(layout, n, shape) for shape in scratch],
+        ),
+        out_shape=[_sds(layout.shape(length, stat), dtype, offs, *arrays)
+                   for _, _, stat, dtype, length in outs],
+        # Programs of the first two dimensions are independent; only the
+        # innermost carries the accumulators.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name=name,
+    )(offs, *arrays)
 
 
 def causal_bias(sq, sk, q_offset=0, k_offset=0):
@@ -271,15 +516,14 @@ def _dense_bwd(q, k, v, do, lse, delta, causal, q_offset=0, k_offset=0):
 
 
 def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l, *,
-                block_q, block_k, causal, skip_blocks):
-    """Grid (batch*heads / G, q-blocks, k-blocks): k innermost, accumulators
-    in VMEM scratch carried across the k dimension, each of a program's G
-    rows with its own."""
+                d, block_q, block_k, causal, skip_blocks):
+    """Grid (units / rows a program x lane blocks, q-blocks, k-blocks): k
+    innermost, accumulators in VMEM scratch carried across the k dimension,
+    each of a program's rows with its own; ``d`` lanes a head."""
     rows = q_ref.shape[0]
     iq = pl.program_id(1)
     ik = pl.program_id(2)
     num_kb = pl.num_programs(2)
-    d = q_ref.shape[-1]
     scale = 1.0 / math.sqrt(d)
 
     @pl.when(ik == 0)
@@ -301,33 +545,38 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l, *,
 
     @pl.when(visible)
     def _block():
-        def _rows(at):
-            row = _row_of(acc, at)
+        def _rows(at, head):
+            row, stat = _row_of(acc, at), _row_of(m, at, head)
             q = q_ref[at]
             k = k_ref[at]
             v = v_ref[at]
+            keep = _lanes_of(head, d, q)
+            q, v = _only(keep, q), _only(keep, v)
             s = _dot(q, k, -1, -1) * scale
             if causal:
                 s = s + causal_bias(block_q, block_k, q_start, k_start)
-            m_prev = m[row]
+            m_prev = m[stat]
             m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
             # Masked entries contribute EXACTLY zero (not exp(-1e30 - m)): in
             # a fully-masked block m_new stays at the sentinel and
             # s - m_new = 0.
             p = jnp.where(s > _NEG_INF / 2, jnp.exp(s - m_new), 0.0)
-            l[row] = l[row] * alpha + p.sum(-1, keepdims=True)
+            l[stat] = l[stat] * alpha + p.sum(-1, keepdims=True)
+            if keep is not None:    # the other heads' lanes keep theirs
+                alpha = jnp.where(keep, alpha, 1.0)
             acc[row] = acc[row] * alpha + _dot(p.astype(v.dtype), v, -1, -2)
-            m[row] = m_new
-        _for_rows(rows, block_q * block_k, _rows)
+            m[stat] = m_new
+        _for_rows(rows, q_ref.shape[-1] // d, block_q * block_k, _rows)
 
     @pl.when(ik == num_kb - 1)
     def _finalize():
-        every = _all_rows(rows)
+        every = _all_rows(acc)
         # 1e-30, NOT 1e-38: f32 subnormals flush to zero on TPU (and in the
         # interpret pipeline), and max(0, ftz(1e-38)) / 0 is how a guard
         # epsilon turns into NaN for rows that saw no visible block.
-        o_ref[every] = (acc[:] / jnp.maximum(l[:], 1e-30)).astype(o_ref.dtype)
+        o_ref[every] = (acc[:] / _over_lanes(jnp.maximum(l[:], 1e-30), d,
+                                             acc)).astype(o_ref.dtype)
         # Rows that saw no visible block keep the finite sentinel (not -inf:
         # downstream combines subtract lse values and -inf - -inf = nan).
         lse_ref[every] = jnp.where(
@@ -335,62 +584,49 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l, *,
             _NEG_INF).astype(lse_ref.dtype)
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, k_offset,
-               interpret, out_dtype=None):
-    """Fused forward. Returns (o (b,h,sq,d) out_dtype, lse f32 (b,h,sq,1)).
-
-    ``q_offset``/``k_offset`` may be traced scalars (scalar-prefetch)."""
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
+def _blocks(sq, sk, block_q, block_k):
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     assert sq % block_q == 0 and sk % block_k == 0, \
         f"seq ({sq},{sk}) must divide blocks ({block_q},{block_k})"
+    return block_q, block_k
+
+
+def _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, k_offset,
+               interpret, out_dtype=None, packed=False):
+    """Fused forward. Returns (o out_dtype in q's layout, lse f32
+    (b,h,sq,1)): q/k/v are (b,h,s,d), or with ``packed`` (b,s,h,d).
+
+    ``q_offset``/``k_offset`` may be traced scalars (scalar-prefetch)."""
+    layout = _Layout.of(q, packed)
+    qr, kr, vr = layout.array(q), layout.array(k), layout.array(v)
+    sq, sk = qr.shape[1], kr.shape[1]
+    block_q, block_k = _blocks(sq, sk, block_q, block_k)
     if isinstance(q_offset, int) and causal:
         assert q_offset % block_q == 0, \
             f"q_offset {q_offset} must be a multiple of block_q {block_q}"
-    out_dtype = out_dtype or q.dtype
-    qr = q.reshape(b * h, sq, d)
-    kr = k.reshape(b * h, sk, d)
-    vr = v.reshape(b * h, sk, d)
+    out_dtype = jnp.dtype(out_dtype or q.dtype)
     offs = jnp.asarray([q_offset, k_offset], jnp.int32)
-    f32 = jnp.float32
-    scratch = [((block_q, d), f32), ((block_q, 1), f32), ((block_q, 1), f32)]
+    f32 = jnp.dtype(jnp.float32)
+    q_block, k_block = layout.block(block_q), layout.block(block_k)
+    row_block = layout.block(block_q, stat=True)
+    scratch = (q_block, row_block, row_block)
     g, vmem = _rows_per_program(
-        b * h, block_q, block_k,
-        [((block_q, d), q.dtype), ((block_k, d), k.dtype),
-         ((block_k, d), v.dtype), ((block_q, d), out_dtype),
-         ((block_q, 1), f32)], scratch)
-    _announce("flash_fwd", qr, sk, block_q, block_k, g, vmem)
-    grid = (b * h // g, sq // block_q, sk // block_k)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((g, block_q, d), lambda ibh, iq, ik, offs: (ibh, iq, 0)),
-            pl.BlockSpec((g, block_k, d), lambda ibh, iq, ik, offs: (ibh, ik, 0)),
-            pl.BlockSpec((g, block_k, d), lambda ibh, iq, ik, offs: (ibh, ik, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((g, block_q, d), lambda ibh, iq, ik, offs: (ibh, iq, 0)),
-            pl.BlockSpec((g, block_q, 1), lambda ibh, iq, ik, offs: (ibh, iq, 0)),
-        ],
-        scratch_shapes=[_scratch(g, shape) for shape, _ in scratch],
-    )
-    out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, block_q=block_q, block_k=block_k,
-                          causal=causal, skip_blocks=not interpret),
-        grid_spec=grid_spec,
-        out_shape=[_sds((b * h, sq, d), out_dtype, qr, kr, vr, offs),
-                   _sds((b * h, sq, 1), jnp.float32, qr, kr, vr, offs)],
-        # batch/q-block programs are independent; only the k dimension
-        # carries the accumulator.
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="flash_fwd",
-    )(offs, qr, kr, vr)
-    return out.reshape(b, h, sq, d), lse.reshape(b, h, sq, 1)
+        layout.units, block_q, block_k,
+        [(q_block, q.dtype), (k_block, k.dtype), (k_block, v.dtype),
+         (q_block, out_dtype), (row_block, f32)],
+        [(shape, f32) for shape in scratch], layout.heads)
+    _announce("flash_fwd", layout, qr, sk, block_q, block_k, g, vmem)
+    # q's blocks follow the grid's second index, k's and v's its third.
+    out, lse = _kernel_call(
+        offs, qr, kr, vr, name="flash_fwd", body=_fwd_kernel, layout=layout,
+        n=g // layout.heads, grid_tail=(sq // block_q, sk // block_k),
+        ins=((block_q, 1, False), (block_k, 2, False), (block_k, 2, False)),
+        outs=((block_q, 1, False, out_dtype, sq),
+              (block_q, 1, True, f32, sq)),
+        scratch=scratch, block_q=block_q, block_k=block_k, causal=causal,
+        interpret=interpret)
+    return layout.result(out), layout.result(lse, stat=True)
 
 
 # ---------------------------------------------------------------------------
@@ -398,12 +634,12 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, k_offset,
 
 
 def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, dq_acc, *, block_q, block_k, causal, skip_blocks):
+                   dq_ref, dq_acc, *, d, block_q, block_k, causal,
+                   skip_blocks):
     rows = q_ref.shape[0]
     iq = pl.program_id(1)
     ik = pl.program_id(2)
     num_kb = pl.num_programs(2)
-    d = q_ref.shape[-1]
     scale = 1.0 / math.sqrt(d)
 
     @pl.when(ik == 0)
@@ -417,33 +653,35 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(visible)
     def _block():
-        def _rows(at):
+        def _rows(at, head):
+            stat = _row_of(lse_ref, at, head)
             q = q_ref[at]
             k = k_ref[at]
             v = v_ref[at]
             do = do_ref[at]
+            keep = _lanes_of(head, d, q)
+            k, v = _only(keep, k), _only(keep, v)
             s = _dot(q, k, -1, -1) * scale
             if causal:
                 s = s + causal_bias(block_q, block_k, q_start, k_start)
-            p = jnp.where(s > _NEG_INF / 2, jnp.exp(s - lse_ref[at]), 0.0)
+            p = jnp.where(s > _NEG_INF / 2, jnp.exp(s - lse_ref[stat]), 0.0)
             dp = _dot(do, v, -1, -1)
-            ds = p * (dp - delta_ref[at]) * scale
+            ds = p * (dp - delta_ref[stat]) * scale
             dq_acc[_row_of(dq_acc, at)] += _dot(ds.astype(k.dtype), k, -1, -2)
-        _for_rows(rows, block_q * block_k, _rows)
+        _for_rows(rows, q_ref.shape[-1] // d, block_q * block_k, _rows)
 
     @pl.when(ik == num_kb - 1)
     def _finalize():
-        dq_ref[_all_rows(rows)] = dq_acc[:].astype(dq_ref.dtype)
+        dq_ref[_all_rows(dq_acc)] = dq_acc[:].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, block_q, block_k,
+                    dk_ref, dv_ref, dk_acc, dv_acc, *, d, block_q, block_k,
                     causal, skip_blocks):
     rows = q_ref.shape[0]
     ik = pl.program_id(1)
     iq = pl.program_id(2)
     num_qb = pl.num_programs(2)
-    d = q_ref.shape[-1]
     scale = 1.0 / math.sqrt(d)
 
     @pl.when(iq == 0)
@@ -458,95 +696,76 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(visible)
     def _block():
-        def _rows(at):
-            row = _row_of(dk_acc, at)
+        def _rows(at, head):
+            row, stat = _row_of(dk_acc, at), _row_of(lse_ref, at, head)
             q = q_ref[at]
             k = k_ref[at]
             v = v_ref[at]
             do = do_ref[at]
+            keep = _lanes_of(head, d, q)
+            q, do = _only(keep, q), _only(keep, do)
             s = _dot(q, k, -1, -1) * scale
             if causal:
                 s = s + causal_bias(block_q, block_k, q_start, k_start)
-            p = jnp.where(s > _NEG_INF / 2, jnp.exp(s - lse_ref[at]), 0.0)
+            p = jnp.where(s > _NEG_INF / 2, jnp.exp(s - lse_ref[stat]), 0.0)
             dv_acc[row] += _dot(p.astype(do.dtype), do, -2, -2)    # p^T do
             dp = _dot(do, v, -1, -1)
-            ds = p * (dp - delta_ref[at]) * scale
+            ds = p * (dp - delta_ref[stat]) * scale
             dk_acc[row] += _dot(ds.astype(q.dtype), q, -2, -2)     # ds^T q
-        _for_rows(rows, block_q * block_k, _rows)
+        _for_rows(rows, q_ref.shape[-1] // d, block_q * block_k, _rows)
 
     @pl.when(iq == num_qb - 1)
     def _finalize():
-        every = _all_rows(rows)
+        every = _all_rows(dk_acc)
         dk_ref[every] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[every] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _flash_bwd(q, k, v, do, lse, delta, causal, block_q, block_k, q_offset,
-               k_offset, interpret, out_dtype=None):
-    """Fused backward. Returns (dq, dk, dv) in out_dtype: the f32
-    accumulators are rounded once, by the kernels' last step."""
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
-    assert sq % block_q == 0 and sk % block_k == 0
-    out_dtype = out_dtype or q.dtype
-    qr = q.reshape(b * h, sq, d)
-    kr = k.reshape(b * h, sk, d)
-    vr = v.reshape(b * h, sk, d)
-    dor = do.reshape(b * h, sq, d)
-    lser = lse.reshape(b * h, sq, 1)
-    deltar = delta.reshape(b * h, sq, 1)
+               k_offset, interpret, out_dtype=None, packed=False):
+    """Fused backward. Returns (dq, dk, dv) in out_dtype and the layout of
+    q, k, v: the f32 accumulators are rounded once, by the kernels' last
+    step.  ``lse`` and ``delta`` are (b,h,sq,1) in either layout."""
+    layout = _Layout.of(q, packed)
+    qr, kr, vr, dor = (layout.array(x) for x in (q, k, v, do))
+    sq, sk = qr.shape[1], kr.shape[1]
+    block_q, block_k = _blocks(sq, sk, block_q, block_k)
+    out_dtype = jnp.dtype(out_dtype or q.dtype)
+    lser, deltar = layout.stat(lse), layout.stat(delta)
     offs = jnp.asarray([q_offset, k_offset], jnp.int32)
 
-    f32 = jnp.float32
-    q_block, k_block, row_block = (block_q, d), (block_k, d), (block_q, 1)
-    ins = [(q_block, q.dtype), (k_block, k.dtype), (k_block, v.dtype),
-           (q_block, do.dtype), (row_block, f32), (row_block, f32)]
-
-    def outer(ibh, i, j, offs):
-        return ibh, i, 0
-
-    def inner(ibh, i, j, offs):
-        return ibh, j, 0
+    f32 = jnp.dtype(jnp.float32)
+    q_block, k_block = layout.block(block_q), layout.block(block_k)
+    row_block = layout.block(block_q, stat=True)
+    in_blocks = [(q_block, q.dtype), (k_block, k.dtype), (k_block, v.dtype),
+                 (q_block, do.dtype), (row_block, f32), (row_block, f32)]
 
     def call(name, body, grid_tail, at_q, at_k, out_block, out_len, n_out):
-        """One backward kernel: ``n_out`` results of ``out_block`` a row at
-        the grid's second index, each with its f32 accumulator in scratch."""
+        """One backward kernel: ``n_out`` results of ``out_block`` a unit at
+        the grid's second index, each with its f32 accumulator in scratch;
+        q's blocks follow the grid's index ``at_q``, k's ``at_k``."""
         g, vmem = _rows_per_program(
-            b * h, block_q, block_k, ins + [(out_block, out_dtype)] * n_out,
-            [(out_block, f32)] * n_out)
-        _announce(name, qr, sk, block_q, block_k, g, vmem)
-
-        def spec(shape, at):
-            return pl.BlockSpec((g,) + shape, at)
-        return pl.pallas_call(
-            functools.partial(body, block_q=block_q, block_k=block_k,
-                              causal=causal, skip_blocks=not interpret),
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
-                grid=(b * h // g,) + grid_tail,
-                in_specs=[spec(q_block, at_q), spec(k_block, at_k),
-                          spec(k_block, at_k), spec(q_block, at_q),
-                          spec(row_block, at_q), spec(row_block, at_q)],
-                out_specs=[spec(out_block, outer)] * n_out,
-                scratch_shapes=[_scratch(g, out_block)] * n_out,
-            ),
-            out_shape=[_sds((b * h, out_len, d), out_dtype, qr, kr, vr, dor,
-                            offs)] * n_out,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=interpret,
-            name=name,
-        )(offs, qr, kr, vr, dor, lser, deltar)
+            layout.units, block_q, block_k,
+            in_blocks + [(out_block, out_dtype)] * n_out,
+            [(out_block, f32)] * n_out, layout.heads)
+        _announce(name, layout, qr, sk, block_q, block_k, g, vmem)
+        at_q, at_k = (block_q, at_q, False), (block_k, at_k, False)
+        return _kernel_call(
+            offs, qr, kr, vr, dor, lser, deltar, name=name, body=body,
+            layout=layout, n=g // layout.heads, grid_tail=grid_tail,
+            ins=(at_q, at_k, at_k, at_q, at_q[:2] + (True,),
+                 at_q[:2] + (True,)),
+            outs=((out_block[0], 1, False, out_dtype, out_len),) * n_out,
+            scratch=(out_block,) * n_out, block_q=block_q, block_k=block_k,
+            causal=causal, interpret=interpret)
 
     # dq: q blocks outside, accumulated over the k blocks inside; dk and dv:
     # k blocks outside, accumulated over the q blocks inside.
     dq, = call("flash_bwd_dq", _bwd_dq_kernel,
-               (sq // block_q, sk // block_k), outer, inner, q_block, sq, 1)
+               (sq // block_q, sk // block_k), 1, 2, q_block, sq, 1)
     dk, dv = call("flash_bwd_dkv", _bwd_dkv_kernel,
-                  (sk // block_k, sq // block_q), inner, outer, k_block, sk, 2)
-    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
+                  (sk // block_k, sq // block_q), 2, 1, k_block, sk, 2)
+    return layout.result(dq), layout.result(dk), layout.result(dv)
 
 
 # ---------------------------------------------------------------------------
@@ -656,7 +875,49 @@ def _bwd_rule(causal, block_q, block_k, q_offset, interpret, res, do):
 flash_attention.defvjp(_fwd_rule, _bwd_rule)
 
 
-def _under_full_manual(fn, q, k, v):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_attention_packed(q, k, v, causal, block_q, block_k, interpret):
+    """:func:`flash_attention` on the packed layout: q/k/v and the result
+    are ``(batch, seq, heads, head_dim)``, the ``(batch, seq, heads x
+    head_dim)`` a projection writes seen as heads.  The kernels only
+    (``interpret`` is True or False): the caller has settled that they serve
+    the shape (``_heads_per_block``) and the backend."""
+    return _packed_fwd_rule(q, k, v, causal, block_q, block_k, interpret)[0]
+
+
+def _packed_fwd_rule(q, k, v, causal, block_q, block_k, interpret):
+    o, lse = _flash_fwd(q, k, v, causal, block_q, block_k, 0, 0, interpret,
+                        packed=True)
+    return o, (q, k, v, o, lse)
+
+
+def _packed_bwd_rule(causal, block_q, block_k, interpret, res, do):
+    q, k, v, o, lse = res
+    # (b,s,h) sums to the statistics' (b,h,s,1): 1/d of an operand's bytes.
+    delta = (do.astype(jnp.float32) * o.astype(jnp.float32)) \
+        .sum(-1).transpose(0, 2, 1)[..., None]
+    return _flash_bwd(q, k, v, do, lse, delta, causal, block_q, block_k,
+                      0, 0, interpret, packed=True)
+
+
+_flash_attention_packed.defvjp(_packed_fwd_rule, _packed_bwd_rule)
+
+
+def _free_axes():
+    """``(mesh, abstract mesh, {axis: size})`` for the axes of the active
+    mesh that no manual region covers yet, None where there is nothing to
+    cover (no mesh, one device, or every axis manual already)."""
+    from autodist_tpu.parallel import context as parallel_ctx
+    ctx = parallel_ctx.current()
+    mesh = ctx.mesh if ctx is not None else None
+    if mesh is None or mesh.size == 1:
+        return None
+    am = jax.sharding.get_abstract_mesh()
+    free = {a: n for a, n in mesh.shape.items() if a not in am.manual_axes}
+    return (mesh, am, free) if free else None
+
+
+def _under_full_manual(fn, q, k, v, heads_dim=1):
     """``fn(q, k, v)`` where a Mosaic kernel can lower on the active mesh.
 
     jax refuses to partition a ``pallas_call`` automatically ("Mosaic
@@ -665,35 +926,31 @@ def _under_full_manual(fn, q, k, v):
     axis.  The Runner's explicit path over ``{data}`` alone already is one;
     on the GSPMD path, or with further axes left automatic, the call goes
     under a ``shard_map`` over the axes still free — batch split over
-    ``data``, heads over ``model``.  Any other axis of size > 1 would run
-    the whole kernel on each of its devices, so it raises instead.
+    ``data``, heads (dimension ``heads_dim`` of q/k/v) over ``model``.  Any
+    other axis of size > 1 would run the whole kernel on each of its
+    devices, so it raises instead.
     """
-    from autodist_tpu.parallel import context as parallel_ctx
-    ctx = parallel_ctx.current()
-    mesh = ctx.mesh if ctx is not None else None
-    if mesh is None or mesh.size == 1:
+    found = _free_axes()
+    if found is None:
         return fn(q, k, v)
-    am = jax.sharding.get_abstract_mesh()
-    free = [a for a in mesh.axis_names if a not in am.manual_axes]
-    if not free:
-        return fn(q, k, v)
+    mesh, am, free = found
     sizes = dict(mesh.shape)
-    dim_of = {const.MESH_AXIS_DATA: 0, const.MESH_AXIS_MODEL: 1}
+    dim_of = {const.MESH_AXIS_DATA: 0, const.MESH_AXIS_MODEL: heads_dim}
     spec = [None] * q.ndim
-    for a in free:
-        if sizes[a] == 1:
+    for a, size in free.items():
+        if size == 1:
             continue
         dim = dim_of.get(a)
-        if dim is None or q.shape[dim] % sizes[a]:
+        if dim is None or q.shape[dim] % size:
             raise NotImplementedError(
                 f"flash attention on mesh {sizes}: axis {a!r} cannot split "
                 f"q {q.shape} (batch over 'data', heads over 'model'), and "
                 f"leaving it automatic would run the whole kernel on each "
-                f"of its {sizes[a]} devices; pass attn_fn= to the model or "
+                f"of its {size} devices; pass attn_fn= to the model or "
                 f"pick a strategy without that axis")
         spec[dim] = a
     spec = P(*spec)
-    _log_path("pallas", f"under shard_map over {free} of mesh {sizes}")
+    _log_path("pallas", f"under shard_map over {list(free)} of mesh {sizes}")
     # Nested in a manual region, jax wants the context's own mesh.
     return jax.shard_map(fn, mesh=am if dict(am.shape) == sizes else mesh,
                          in_specs=(spec, spec, spec), out_specs=spec,
@@ -707,22 +964,57 @@ def make_flash_attn_fn(causal=False, block_q=512, block_k=1024):
     size; anything else — including an explicit boolean ``mask``, which the
     fused kernel does not consume — takes the dense reference so masking
     semantics are never dropped, and logs that it did.
+
+    The hook carries ``attn_fn.bshd(num_heads, head_dim)``, which
+    ``models.layers.mha`` reads off it: the same attention for q/k/v and a
+    result of ``(batch, seq, heads, head_dim)``, the projections' own layout
+    seen as heads, which the kernels read and write themselves so that
+    ``mha`` transposes nothing; or None where heads of that shape keep
+    ``(batch, heads, seq, head_dim)`` (``_heads_per_block``; a mesh axis
+    that splits the heads) and ``mha`` and the program are as they were.
     """
     from autodist_tpu.models import layers as L
 
-    def attn_fn(q, k, v, mask=None):
+    def kernels(s, dtype, mask):
+        """``(block_q, block_k, interpret)`` where the kernels serve a call
+        of ``s`` positions, None where the dense reference does."""
         if mask is not None:
             _log_path("dense", "an explicit mask was passed")
-            return L.dot_product_attention(q, k, v, mask)
-        s = q.shape[2]
+            return None
         bq, bk = min(block_q, s), min(block_k, s)
         if s % bq != 0 or s % bk != 0:
             _log_path("dense", f"seq {s} does not divide blocks "
                                f"({block_q}, {block_k})")
-            return _dense_reference(q, k, v, causal)
-        if _pallas_interpret(None, q.dtype) is None:
-            return _dense_reference(q, k, v, causal)
+            return None
+        interpret = _pallas_interpret(None, dtype)
+        return None if interpret is None else (bq, bk, interpret)
+
+    def attn_fn(q, k, v, mask=None):
+        plan = kernels(q.shape[2], q.dtype, mask)
+        if plan is None:
+            return (L.dot_product_attention(q, k, v, mask) if mask is not None
+                    else _dense_reference(q, k, v, causal))
+        bq, bk, interpret = plan
         return _under_full_manual(
             lambda ql, kl, vl: flash_attention(ql, kl, vl, causal, bq, bk,
-                                               0, False), q, k, v)
+                                               0, interpret), q, k, v)
+
+    def packed(q, k, v, mask=None):
+        plan = kernels(q.shape[1], q.dtype, mask)
+        if plan is None:        # the dense reference, in the layout it reads
+            q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+            return attn_fn(q, k, v, mask).transpose(0, 2, 1, 3)
+        bq, bk, interpret = plan
+        return _under_full_manual(
+            lambda ql, kl, vl: _flash_attention_packed(
+                ql, kl, vl, causal, bq, bk, interpret), q, k, v, heads_dim=2)
+
+    def bshd(num_heads, head_dim):
+        found = _free_axes()
+        if (_heads_per_block(num_heads, head_dim) is None
+                or found and found[2].get(const.MESH_AXIS_MODEL, 1) > 1):
+            return None
+        return packed
+
+    attn_fn.bshd = bshd
     return attn_fn

@@ -35,7 +35,9 @@ from autodist_tpu import AutoDist
 from autodist_tpu.data import DevicePrefetcher
 from autodist_tpu.models import lm
 from autodist_tpu.observability import goodput
-from autodist_tpu.ops.flash_attention import _dense_reference, flash_attention
+from autodist_tpu.ops.flash_attention import (_dense_reference,
+                                              _flash_attention_packed,
+                                              flash_attention)
 from autodist_tpu.report import collective_summary
 from autodist_tpu.strategy import PartitionedPS
 from autodist_tpu.utils import compile_cache
@@ -48,6 +50,10 @@ OUT_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 # rows, where a program takes several (batch, head) rows (16 of these 96):
 # the grouped Mosaic lowering, which the CPU tests only interpret.
 KERNEL_SHAPES = ((2, 16, 512, 64), (1, 16, 4096, 128), (8, 12, 128, 64))
+# The same (batch, heads, seq, head_dim) read as the projections write them,
+# (batch, seq, heads x head_dim), two heads a 128-lane block: one batch row a
+# program, and four sharing one.
+PACKED_KERNEL_SHAPES = ((2, 16, 1024, 64), (8, 12, 128, 64))
 # bf16 outputs against an f32 reference of the same bf16 inputs: one bf16
 # rounding is 2^-8 relative, sums over keys add a little.
 KERNEL_ATOL = KERNEL_RTOL = 2e-2
@@ -188,12 +194,15 @@ def check_train(report, vocab, peak_flops_per_device):
     check("kernels-compiled",
           all(report["kernel_calls"].get(k) for k in KERNEL_NAMES),
           f"Mosaic custom calls in the step: {report['kernel_calls']}")
-    per_device_rows = report["global_batch"] // n * report["heads"]
+    # A device's share of the batch: (rows, seq, heads x d) in the kernels'
+    # packed layout, (rows x heads, seq, d) in the split one.
+    rows = report["global_batch"] // n
     q_rows = sorted({shape[0] for shape in report["kernel_q_shapes"]})
-    check("kernel-operands-per-device", q_rows == [per_device_rows],
+    check("kernel-operands-per-device",
+          q_rows in ([rows], [rows * report["heads"]]),
           f"q operands {report['kernel_q_shapes']}; global batch "
-          f"{report['global_batch']} / {n} devices x {report['heads']} "
-          f"heads = {per_device_rows} rows")
+          f"{report['global_batch']} / {n} devices = {rows} rows of "
+          f"{report['heads']} heads")
 
     peak = peak_flops_per_device * n
     floor_ms = report["model_flops_per_step"] / peak * 1e3
@@ -228,18 +237,26 @@ def check_train(report, vocab, peak_flops_per_device):
           f"{coll}")
 
 
-def kernel_phase(shape, interpret):
+def kernel_phase(shape, interpret, packed=False):
     """``flash_attention`` (causal, bf16, default blocks) against the dense
-    reference at one shape: forward and ``jax.grad``.  Returns the largest
-    error of each output and the Mosaic custom calls in the two
-    executables."""
+    reference at one shape: forward and ``jax.grad``.  With ``packed`` the
+    kernels read the operands as (batch, seq, heads, head_dim), the layout
+    ``models.layers.mha`` hands the flash hook.  Returns the largest error
+    of each output and the Mosaic custom calls in the two executables."""
     kq, kk, kv, kc = jax.random.split(jax.random.PRNGKey(SEED), 4)
     q, k, v = (jax.random.normal(key, shape, jnp.bfloat16)
                for key in (kq, kk, kv))
     cot = jax.random.normal(kc, shape, jnp.float32)
 
+    def swap(x):
+        return x.transpose(0, 2, 1, 3)
+
     def flash(q, k, v):
-        return flash_attention(q, k, v, True, interpret=interpret)
+        if not packed:
+            return flash_attention(q, k, v, True, interpret=interpret)
+        return swap(_flash_attention_packed(
+            swap(q), swap(k), swap(v), True, min(512, shape[2]),
+            min(1024, shape[2]), interpret))
 
     def dense(q, k, v):
         return _dense_reference(q, k, v, True)
@@ -263,7 +280,8 @@ def kernel_phase(shape, interpret):
         errors[name] = float(np.max(np.abs(a - b)))
         within[name] = bool(np.all(
             np.abs(a - b) <= KERNEL_ATOL + KERNEL_RTOL * np.abs(b)))
-    return {"shape": shape, "max_abs_error": errors, "within": within,
+    return {"shape": shape, "layout": "packed" if packed else "split",
+            "max_abs_error": errors, "within": within,
             "finite": bool(all(np.isfinite(np.asarray(a, np.float32)).all()
                                for a in got)),
             "kernel_calls": [name for name, _ in
@@ -273,6 +291,8 @@ def kernel_phase(shape, interpret):
 
 def check_kernel(report):
     tag = "x".join(str(d) for d in report["shape"])
+    if report["layout"] == "packed":
+        tag += "-packed"
     check(f"kernel-compiled-{tag}",
           sorted(report["kernel_calls"]) == sorted(
               ("flash_fwd",) + KERNEL_NAMES),
@@ -305,8 +325,9 @@ def main():
     check_train(train, cfg.vocab, peak)
 
     kernels = []
-    for shape in KERNEL_SHAPES:
-        kernels.append(kernel_phase(shape, interpret=False))
+    for shape, packed in ([(shape, False) for shape in KERNEL_SHAPES]
+                          + [(shape, True) for shape in PACKED_KERNEL_SHAPES]):
+        kernels.append(kernel_phase(shape, interpret=False, packed=packed))
         print(f"chip_smoke: kernel {json.dumps(kernels[-1])}", flush=True)
         check_kernel(kernels[-1])
 

@@ -66,9 +66,34 @@ def test_train_phase_reports_every_field_on_the_cpu_mesh():
     assert report["kernel_calls"] == {}
 
 
-def test_kernel_phase_holds_its_tolerance_interpreted():
-    report = chip_smoke.kernel_phase((2, 2, 32, 16), interpret=True)
+@pytest.mark.parametrize("shape,packed", [((2, 2, 32, 16), False),
+                                          ((2, 2, 32, 64), True)],
+                         ids=["split", "packed"])
+def test_kernel_phase_holds_its_tolerance_interpreted(shape, packed):
+    report = chip_smoke.kernel_phase(shape, interpret=True, packed=packed)
+    assert report["layout"] == ("packed" if packed else "split")
     assert report["finite"] and all(report["within"].values()), report
+
+
+@pytest.mark.parametrize("q_shape,ok", [((16, 512, 1024), True),
+                                        ((256, 512, 64), True),
+                                        ((128, 512, 64), False)])
+def test_a_devices_q_operand_is_its_share_of_the_batch(q_shape, ok,
+                                                       monkeypatch):
+    """Sixteen rows of sixteen heads a device: (16, seq, heads x d) as the
+    packed kernels read them, or (256, seq, d) split; nothing else."""
+    failed = []
+    monkeypatch.setattr(
+        chip_smoke, "check",
+        lambda name, passed, detail: passed or failed.append(name))
+    report = {
+        "devices": 1, "losses": [7.0, 6.0], "global_batch": 16, "heads": 16,
+        "kernel_calls": dict.fromkeys(chip_smoke.KERNEL_NAMES, 16),
+        "kernel_q_shapes": [q_shape], "model_flops_per_step": 1e12,
+        "achieved_flops_per_s": 1e12, "step_ms_median": 1000.0,
+        "bytes_in_use": [1], "peak_bytes_in_use": [1]}
+    chip_smoke.check_train(report, vocab=1097, peak_flops_per_device=1e15)
+    assert ("kernel-operands-per-device" in failed) == (not ok), failed
 
 
 def test_kernel_calls_reads_a_recorded_executable():

@@ -112,6 +112,9 @@ def _eqns(jaxpr):
         yield eqn
         for sub in _sub_jaxprs(eqn):
             yield from _eqns(sub)
+        for param in eqn.params.values():    # shard_map's is a bare Jaxpr
+            if hasattr(param, "eqns"):
+                yield from _eqns(param)
 
 
 def _force_rows(monkeypatch, rows):
@@ -278,57 +281,265 @@ def test_rows_a_program_change_no_bit(rows, causal, block_k, dtype,
     assert np.isfinite(np.asarray(got[0], np.float32)).all()
 
 
-# (batch x heads, seq, head width) of a kernel call, a chip: the five cells
-# of the benchmark, then counts of rows that are prime.
+# ---------------------------------------------------------------------------
+# the packed layout: (batch, s, heads x d) as the projections write it
+
+
+def _swap(x):
+    return x.transpose(0, 2, 1, 3)
+
+
+def _attend(hook, q, k, v):
+    """What ``layers.mha`` makes of a hook for q/k/v of (batch, seq, heads,
+    head_dim): the hook's own function of that layout where it gives one for
+    these heads, else the hook behind the head split's transposes."""
+    packed = hook.bshd(*q.shape[2:])
+    if packed is not None:
+        return packed(q, k, v)
+    return _swap(hook(_swap(q), _swap(k), _swap(v)))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("shape,blocks,rows", [
+    ((8, 128, 2, 64), (128, 128), 16),     # s = 128: eight batch rows grouped
+    ((1, 128, 4, 64), (64, 32), 2),        # one batch row, two lane blocks
+    ((4, 128, 2, 128), (128, 128), 4),     # d = 128: one head a block
+    ((1, 256, 2, 128), (128, 64), 1),      # ... and one row a program
+], ids=["d64-grouped", "d64-one-row", "d128-grouped", "d128-one-row"])
+def test_packed_matches_split(shape, blocks, rows, causal, dtype):
+    """The kernels on (batch, s, heads x d), two heads a 128-lane block at
+    d = 64 and one at d = 128, against the same kernels on the transposed
+    (batch, heads, s, d): o and the three gradients, with batch rows sharing
+    a program and not, one block a side and several.  A head's product over
+    the block's whole width adds exact zeros, so the two agree to the
+    rounding of a sum's order."""
+    b, s, h, d = shape
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    q, k, v, w = (jax.random.normal(kk, shape, jnp.float32).astype(dtype)
+                  for kk in ks)
+    picked, rule = [], fa._rows_per_program
+
+    def recording(*args):
+        picked.append(rule(*args)[0])
+        return rule(*args)
+
+    def packed(q, k, v):
+        return fa._flash_attention_packed(q, k, v, causal, *blocks, True)
+
+    def split(q, k, v):
+        return _swap(flash_attention(_swap(q), _swap(k), _swap(v), causal,
+                                     *blocks, 0, True))
+
+    def grads(attn):
+        return jax.grad(lambda *x: (attn(*x).astype(jnp.float32)
+                                    * w.astype(jnp.float32)).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+    fa._rows_per_program = recording
+    try:
+        got = (packed(q, k, v),) + grads(packed)
+    finally:
+        fa._rows_per_program = rule
+    assert picked[-3:] == [rows] * 3, picked
+    want = (split(q, k, v),) + grads(split)
+    tol = 1e-5 if dtype == jnp.float32 else 1e-2
+    for name, a, e in zip(("o", "dq", "dk", "dv"), got, want):
+        assert a.shape == shape and a.dtype == dtype, name
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(e, np.float32),
+                                   rtol=tol, atol=tol, err_msg=name)
+    np.testing.assert_allclose(
+        np.asarray(got[0], np.float32),
+        np.asarray(_swap(_dense_reference(_swap(q), _swap(k), _swap(v),
+                                          causal)), np.float32),
+        rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("heads,d,packed", [
+    (2, 64, True), (3, 64, False), (25, 64, False), (1, 128, False),
+    (16, 128, False), (8, 16, True), (4, 16, False)])
+def test_hook_takes_the_layout_the_shape_gives(heads, d, packed, monkeypatch):
+    """``make_flash_attn_fn``'s hook for heads of a shape: where several
+    fill a block of 128 lanes it gives ``mha`` a function of (batch, s,
+    heads, d), the kernels read that layout and the jaxpr has no transpose
+    of an operand; an odd count of 64-wide heads (gpt2-xl's 25) does not
+    pack, a 128-wide head (OLMoE's) is a block alone and gains nothing, and
+    there the hook gives None and ``mha`` transposes as ever."""
+    hook = _interpreted_hook(monkeypatch, causal=True)
+    assert (hook.bshd(heads, d) is not None) == packed
+    assert (fa._heads_per_block(heads, d) is not None) == packed
+    x = jax.ShapeDtypeStruct((2, 32, heads, d), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda *x: _attend(hook, *x))(x, x, x).jaxpr
+    kernel = _kernels(jaxpr)["flash_fwd"]
+    transposes = [e for e in _eqns(jaxpr) if e.primitive.name == "transpose"]
+    if packed:
+        assert kernel.outvars[0].aval.shape == (2, 32, heads * d)
+        assert not transposes
+    else:
+        assert kernel.outvars[0].aval.shape == (2 * heads, 32, d)
+        assert len(transposes) == 4
+    q, k, v = (jax.random.normal(kk, x.shape) for kk in
+               jax.random.split(jax.random.PRNGKey(2), 3))
+    np.testing.assert_allclose(
+        np.asarray(_attend(hook, q, k, v)),
+        np.asarray(_swap(_dense_reference(_swap(q), _swap(k), _swap(v),
+                                          True))), rtol=2e-5, atol=2e-5)
+
+
+def test_hook_in_its_own_layout_falls_back_to_the_dense_reference():
+    """Off the TPU (and with a mask, or a length no block divides) the
+    hook's (batch, s, heads, d) function is the dense reference behind the
+    transposes it reads through, as the hook itself is."""
+    hook = fa.make_flash_attn_fn(causal=True)
+    q, k, v = (_swap(x) for x in _qkv(h=2, s=32, d=64))
+    want = _swap(_dense_reference(_swap(q), _swap(k), _swap(v), True))
+    packed = hook.bshd(2, 64)
+    np.testing.assert_array_equal(np.asarray(packed(q, k, v)),
+                                  np.asarray(want))
+    mask = L.causal_mask(32)
+    np.testing.assert_allclose(np.asarray(packed(q, k, v, mask)),
+                               np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+def test_hook_keeps_the_split_layout_where_a_mesh_axis_splits_the_heads(
+        monkeypatch):
+    """Heads split over ``model`` are dimension 1 of (batch, heads, s, d)
+    under ``_under_full_manual``: the hook gives no function of its own
+    layout there; over ``data`` alone it does, the batch split on dimension
+    0."""
+    from jax.sharding import Mesh
+    from autodist_tpu.parallel import context as parallel_ctx
+    hook = _interpreted_hook(monkeypatch)
+    q, k, v = (_swap(x) for x in _qkv(b=4, h=8, s=32))    # (4, 32, 8, 16)
+    want = _swap(_dense_reference(_swap(q), _swap(k), _swap(v), False))
+    devices = np.array(jax.devices())
+    for mesh, inner in (
+            (Mesh(devices.reshape(4, 2), ("data", "model")), (4, 32, 16)),
+            (Mesh(devices[:4], ("data",)), (1, 32, 128))):
+        with parallel_ctx.use(parallel_ctx.ParallelContext(mesh)):
+            # A function of its own a mesh: jax keeps a function's trace.
+            jaxpr = jax.make_jaxpr(lambda *x: _attend(hook, *x))(q, k, v).jaxpr
+            got = jax.jit(lambda *x: _attend(hook, *x))(q, k, v)
+        # Local views: batch 4 / data 4, and heads 8 / model 2 where split.
+        assert _kernels(jaxpr)["flash_fwd"].outvars[0].aval.shape == inner
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+
+
+def test_mha_transposes_nothing_for_a_hook_that_takes_its_layout(monkeypatch):
+    """``layers.mha`` with the flash hook against the dense reference, rotary
+    positions and QK-norm on as the OLMoE block sets them: outputs and every
+    parameter's gradient agree, and the packed path's jaxpr, forward and
+    backward, transposes nothing the size of q, k, v or o (the row
+    statistics' (batch, s, heads) still turn to (batch, heads, s))."""
+    heads, d, b, s = 2, 64, 2, 32
+    dim = heads * d
+    p = L.mha_init(jax.random.PRNGKey(0), dim, heads, use_bias=False,
+                   qk_norm=True)
+    x = jax.random.normal(jax.random.PRNGKey(1), (b, s, dim))
+    rope = L.rope_tables(s, d)
+    hook = _interpreted_hook(monkeypatch, causal=True)
+
+    def dense(q, k, v, mask):
+        return _dense_reference(q, k, v, True)
+
+    def loss(attn_fn):
+        return lambda p, x: (L.mha(p, x, heads, attn_fn=attn_fn, rope=rope)
+                             ** 2).sum()
+    np.testing.assert_allclose(
+        np.asarray(L.mha(p, x, heads, attn_fn=hook, rope=rope)),
+        np.asarray(L.mha(p, x, heads, attn_fn=dense, rope=rope)),
+        rtol=1e-4, atol=1e-4)
+    got = jax.grad(loss(hook), argnums=(0, 1))(p, x)
+    want = jax.grad(loss(dense), argnums=(0, 1))(p, x)
+    for a, e in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(e),
+                                   rtol=2e-3, atol=2e-3)
+    jaxpr = jax.make_jaxpr(jax.grad(loss(hook), argnums=(0, 1)))(p, x).jaxpr
+    assert set(_kernels(jaxpr)) == {"flash_fwd", "flash_bwd_dq",
+                                    "flash_bwd_dkv"}
+    for eqn in _eqns(jaxpr):
+        if eqn.primitive.name == "transpose":
+            size = max(v.aval.size for v in eqn.invars)
+            assert size < b * s * dim or eqn.invars[0].aval.ndim == 2, eqn
+    # The same layer with a hook that does not say so is transposed as ever.
+    plain = jax.make_jaxpr(loss(dense))(p, x).jaxpr
+    assert sum(e.primitive.name == "transpose" and e.invars[0].aval.ndim == 4
+               for e in _eqns(plain)) == 4
+
+
+# (batch, heads, seq, head width) of a kernel call, a chip, the layout its
+# shape gives with the heads a block, and the least and most rows a program:
+# the five cells of the benchmark, then counts of rows that are prime.
 _CELL_SHAPES = {
-    "gpt2-medium.train-s1024": ((128, 1024, 64), (1, 1)),
-    "gpt2-xl.train-s1024-x4": ((50, 1024, 64), (1, 1)),
-    "olmoe-1b-7b.train-s4096": ((32, 4096, 128), (1, 1)),
-    "bert-base.mlm-s512": ((768, 512, 64), (2, 2)),
-    "bert-base.mlm-s128": ((3072, 128, 64), (8, 32)),
-    "seven-rows-s128": ((7, 128, 64), (7, 7)),
-    "thirty-seven-rows-s128": ((37, 128, 64), (1, 1)),
+    "gpt2-medium.train-s1024": ((8, 16, 1024, 64), ("packed", 2), (2, 2)),
+    "gpt2-xl.train-s1024-x4": ((2, 25, 1024, 64), ("split", 1), (1, 1)),
+    "olmoe-1b-7b.train-s4096": ((2, 16, 4096, 128), ("split", 1), (1, 1)),
+    "bert-base.mlm-s512": ((64, 12, 512, 64), ("packed", 2), (2, 2)),
+    "bert-base.mlm-s128": ((256, 12, 128, 64), ("packed", 2), (8, 32)),
+    "seven-rows-s128": ((7, 1, 128, 64), ("split", 1), (7, 7)),
+    "thirty-seven-rows-s128": ((37, 1, 128, 64), ("split", 1), (1, 1)),
+    "seven-rows-of-two-heads-s128": ((7, 2, 128, 64), ("packed", 2), (14, 14)),
+    "thirty-seven-rows-of-two-heads-s128": ((37, 2, 128, 64), ("packed", 2),
+                                            (2, 2)),
 }
 _plans = {}
 
 
+def _interpreted_hook(monkeypatch=None, causal=False):
+    """``make_flash_attn_fn``'s hook with the kernels interpreted: the hook
+    asks the backend, which reads ``cpu`` here, and takes no argument for
+    it."""
+    if monkeypatch is not None:
+        monkeypatch.setattr(fa, "_pallas_interpret", lambda *_: True)
+    return fa.make_flash_attn_fn(causal)
+
+
 def _plan(cell):
-    """Trace forward and backward at the cell's shape (nothing runs):
-    ``(kernels of the jaxpr, [(G, vmem bytes)] as the rule returned them)``."""
+    """Trace forward and backward at the cell's shape through the hook
+    ``models.layers.mha`` reads the layout off (nothing runs): ``(kernels of
+    the jaxpr, [(G, vmem bytes)] as the rule returned them)``."""
     if cell not in _plans:
-        (bh, s, d), _ = _CELL_SHAPES[cell]
-        x = jax.ShapeDtypeStruct((1, bh, s, d), jnp.bfloat16)
-        rule, picked = fa._rows_per_program, []
+        (b, h, s, d), _, _ = _CELL_SHAPES[cell]
+        x = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16)
+        rule, resolve, picked = fa._rows_per_program, fa._pallas_interpret, []
 
         def recording(*args):
             picked.append(rule(*args))
             return picked[-1]
 
         def loss(q, k, v):
-            o = flash_attention(q, k, v, False, 512, 1024, 0, True)
+            o = _attend(_interpreted_hook(), q, k, v)
             return (o.astype(jnp.float32) ** 2).sum()
         fa._rows_per_program = recording
+        fa._pallas_interpret = lambda *_: True
         try:
             jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x)
         finally:
-            fa._rows_per_program = rule
+            fa._rows_per_program, fa._pallas_interpret = rule, resolve
         _plans[cell] = _kernels(jaxpr.jaxpr), picked
     return _plans[cell]
 
 
 @pytest.mark.parametrize("cell", list(_CELL_SHAPES))
 def test_rows_a_program_follow_the_shape(cell):
-    """The rule alone: one row a program where a row's tile is as large as
-    a long-sequence program's (those cells' kernels are the programs they
-    were), two at s = 512, 8 to 32 at s = 128; always a divisor of
-    batch x heads, and the padded VMEM estimate within the budget."""
-    (bh, s, _), (least, most) = _CELL_SHAPES[cell]
+    """The rule alone: the heads of one 128-lane block a program where a
+    row's tile is as large as a long-sequence program's (one row in the split
+    layout, whose kernels are the programs they were; two heads at d = 64 in
+    the packed one), 8 to 32 rows at s = 128; always whole blocks' heads
+    times a divisor of the batch (of batch x heads in the split layout), and
+    the padded VMEM estimate within the budget."""
+    (b, h, s, d), (layout, heads), (least, most) = _CELL_SHAPES[cell]
     _, picked = _plan(cell)
     assert len(picked) == 3                      # fwd, dq, dkv
+    assert fa._heads_per_block(h, d) == (heads if layout == "packed" else None)
     for g, vmem in picked:
         assert least <= g <= most, picked
-        assert bh % g == 0, picked
-        assert g * min(s, 512) * min(s, 1024) <= fa._MAX_TILE or g == 1
+        assert g % heads == 0, picked
+        assert (b if layout == "packed" else b * h) % (g // heads) == 0
+        assert g * min(s, 512) * min(s, 1024) <= fa._MAX_TILE or g == heads
         assert 0 < vmem <= fa._VMEM_BUDGET, picked
 
 
@@ -336,42 +547,58 @@ def test_rows_a_program_follow_the_shape(cell):
 def test_grid_starts_with_programs_not_rows(cell):
     """Each layer still makes three ``pallas_call``s under their three
     names (the benchmark's trace reader finds them by name and counts
-    calls); the grid's first dimension is batch x heads / G."""
-    (bh, s, d), _ = _CELL_SHAPES[cell]
+    calls); the grid's first dimension is batch x heads / G, and the
+    results are (batch, s, heads x d) in the packed layout, (batch x heads,
+    s, d) in the split one."""
+    (b, h, s, d), (layout, _), _ = _CELL_SHAPES[cell]
     kernels, picked = _plan(cell)
     assert list(kernels) == ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
     for (name, eqn), (g, _) in zip(kernels.items(), picked):
         grid = eqn.params["grid_mapping"].grid
-        assert grid[0] == bh // g, (name, grid, g)
+        assert grid[0] == b * h // g, (name, grid, g)
         assert grid[1:] == (s // min(s, 512), s // min(s, 1024)) or \
             name == "flash_bwd_dkv" and \
             grid[1:] == (s // min(s, 1024), s // min(s, 512)), (name, grid)
-        for out in eqn.outvars[:1]:
-            assert out.aval.shape == (bh, s, d)
+        results = eqn.outvars if name != "flash_fwd" else eqn.outvars[:1]
+        for out in results:
+            assert out.aval.shape == ((b, s, h * d) if layout == "packed"
+                                      else (b * h, s, d)), (name, out.aval)
+            assert out.aval.dtype == jnp.bfloat16
+    lse = kernels["flash_fwd"].outvars[1].aval
+    assert lse.shape == ((b, h, s, 1) if layout == "packed"
+                         else (b * h, s, 1)) and lse.dtype == jnp.float32
 
 
-@pytest.mark.parametrize("rows,tile,steps,a_step", [
-    (1, 128 * 128, 1, 1), (16, 128 * 128, 4, 4), (6, 128 * 128, 2, 3),
-    (7, 128 * 128, 7, 1), (2, 512 * 512, 2, 1), (4, 64 * 64, 1, 4)])
-def test_rows_a_step_follow_the_tile(rows, tile, steps, a_step):
+@pytest.mark.parametrize("rows,heads,tile,steps,a_step", [
+    (1, 1, 128 * 128, 1, 1), (16, 1, 128 * 128, 4, 4), (6, 1, 128 * 128, 2, 3),
+    (7, 1, 128 * 128, 7, 1), (2, 1, 512 * 512, 2, 1), (4, 1, 64 * 64, 1, 4),
+    (1, 2, 512 * 1024, 1, 1), (8, 2, 128 * 128, 2, 4), (4, 2, 128 * 128, 1, 4),
+    (3, 8, 512 * 512, 3, 1)])
+def test_rows_a_step_follow_the_tile(rows, heads, tile, steps, a_step):
     """A step of a program's loop takes the rows whose score tiles fit the
     vector registers, four at 128 x 128 and one at 512 x 512: one row as an
     index (the two-dimensional arithmetic of a one-row program), several as
-    a slice of the block."""
+    a slice of the block.  Where a block holds several heads the loop takes
+    them one a step, each an index on the device, inside the rows'."""
     from jax.experimental import pallas as pl
     seen = []
-    jaxpr = jax.make_jaxpr(lambda: fa._for_rows(rows, tile, seen.append))()
+    jaxpr = jax.make_jaxpr(lambda: fa._for_rows(
+        rows, heads, tile, lambda at, head: seen.append((at, head))))()
     loops = [e.params["length"] for e in _eqns(jaxpr.jaxpr)
              if e.primitive.name == "scan"]
-    assert loops == ([steps] if steps > 1 else [])
-    # The body is traced once, whatever the rows.
-    (at,) = seen
+    assert loops == ([steps * heads] if steps * heads > 1 else [])
+    # The body is traced once, whatever the rows and the heads.
+    ((at, head),) = seen
+    if heads == 1:
+        assert head is None
+    else:
+        assert head.shape == () and head.dtype == jnp.int32
     if rows == 1:
         assert at == 0
-    elif a_step == 1:
-        assert at.shape == () and at.dtype == jnp.int32
     elif steps == 1:
         assert at == slice(None)
+    elif a_step == 1:
+        assert at.shape == () and at.dtype == jnp.int32
     else:
         assert isinstance(at, pl.Slice) and at.size == a_step
 
@@ -384,26 +611,48 @@ def test_padded_bytes_count_whole_lanes_and_sublanes():
     assert fa._padded_bytes((3, 512, 128), jnp.float32) == 3 * 512 * 128 * 4
 
 
-def test_flash_event_and_gauge_carry_the_rows_at_trace_time():
-    """Telemetry says which program the rule made of a call: the gauge
-    ``flash.rows_per_program`` and one ``flash`` event a kernel and shape,
-    written while tracing (nothing runs)."""
+@pytest.mark.parametrize("heads,layout,lanes,shape", [
+    (4, "packed", "2 heads a block of 128 lanes", "3,128,256"),
+    (3, "split", "1 heads a block of 64 lanes", "9,128,64")])
+def test_flash_event_and_gauges_carry_the_program_at_trace_time(
+        heads, layout, lanes, shape, monkeypatch):
+    """Telemetry says which layout a call's shape gave it and which program
+    the rule made of it: the gauges ``flash.rows_per_program`` and
+    ``flash.heads_per_block`` and one ``flash`` event a kernel and shape,
+    written while tracing (nothing runs); the info line of the log is the
+    same text, so a head count that does not pack (three heads of 64) says
+    ``split`` there."""
+    import logging
     from autodist_tpu import observability
     from autodist_tpu.observability import recorder
+    from autodist_tpu.utils import logging as ad_logging
     observability.reset()
-    x = jax.ShapeDtypeStruct((3, 4, 128, 64), jnp.bfloat16)
+    monkeypatch.setattr(fa, "_logged_paths", set())
+    lines = []
+    handler = logging.Handler()
+    handler.emit = lambda record: lines.append(record.getMessage())
+    ad_logging.get_logger().addHandler(handler)
+    x = jax.ShapeDtypeStruct((3, 128, heads, 64), jnp.bfloat16)
+    hook = _interpreted_hook(monkeypatch)
 
     def loss(q, k, v):
-        o = flash_attention(q, k, v, False, 512, 1024, 0, True)
-        return (o.astype(jnp.float32) ** 2).sum()
-    for _ in range(2):
-        jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x)
+        return (_attend(hook, q, k, v).astype(jnp.float32) ** 2).sum()
+    try:
+        for _ in range(2):
+            jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x)
+    finally:
+        ad_logging.get_logger().removeHandler(handler)
     gauges = observability.registry().snapshot()["gauges"]
-    assert gauges["flash.rows_per_program"] == 12
+    rows = 3 * heads if layout == "split" else 6
+    assert gauges["flash.rows_per_program"] == rows
+    assert gauges["flash.heads_per_block"] == (2 if layout == "packed" else 1)
     events = [e["detail"] for e in recorder.events() if e["kind"] == "flash"]
     assert len(events) == len(set(events)) == 3
     for kernel, detail in zip(("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"),
                               events):
-        assert detail.startswith(f"{kernel} bfloat16[12,128,64]"), detail
-        assert "blocks 128 x 128, G = 12 " in detail, detail
-        assert " 1 programs a call" in detail and "VMEM" in detail, detail
+        assert detail.startswith(f"{kernel} bfloat16[{shape}] over 128 keys: "
+                                 f"{layout} layout, {lanes}, "), detail
+        assert f"blocks 128 x 128, G = {rows} " in detail, detail
+        programs = 1 if layout == "split" else 2
+        assert f" {programs} programs a call" in detail and "VMEM" in detail
+        assert f"flash_attention: pallas path ({detail})" in lines

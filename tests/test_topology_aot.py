@@ -318,3 +318,54 @@ def test_v5e_compiler_hlo(case, tmp_path):
         builder(), *model(), tmp_path, topology_name=topology,
         num_slices=slices)
     check(text, runner)
+
+
+@pytest.mark.parametrize("layout", ["packed", "split"])
+def test_v5e_compiler_runs_no_head_split_copy_for_the_packed_layout(
+        layout, monkeypatch):
+    """A BERT-width attention layer (12 heads of 64), forward and backward,
+    compiled by libtpu for one detached v5e chip with the flash kernels as
+    Mosaic custom calls.  Through the hook's ``bshd`` the projections' (batch,
+    s, 768) is what the kernels read and write: no standalone ``copy`` of an
+    array that size is left in the optimized HLO.  The same layer with the
+    hook's (batch, heads, s, d) function alone still has the head split's
+    copies: q, k, v and their gradients' way back at the least."""
+    why_not = _why_no_detached_topology()
+    if why_not:
+        pytest.skip(why_not)
+    import importlib
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from autodist_tpu.models import layers as L
+    fa = importlib.import_module("autodist_tpu.ops.flash_attention")
+    # The hook asks the backend, which reads ``cpu`` in this process.
+    monkeypatch.setattr(fa, "_pallas_interpret", lambda *_: False)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x4")
+    chip = SingleDeviceSharding(topo.devices[0])
+    b, s, heads, d = 8, 512, 12, 64
+    hook = fa.make_flash_attn_fn(causal=False)
+    if layout == "split":
+        split = hook
+        hook = lambda q, k, v, mask=None: split(q, k, v, mask)  # no ``bshd``
+
+    def loss(p, x):
+        y = L.mha(p, x, heads, dtype=jnp.bfloat16, attn_fn=hook)
+        return (y.astype(jnp.float32) ** 2).sum()
+    params = jax.eval_shape(
+        lambda: L.mha_init(jax.random.PRNGKey(0), heads * d, heads))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        params)
+    x = jax.ShapeDtypeStruct((b, s, heads * d), jnp.bfloat16, sharding=chip)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+    copies = [m.group(1) for m in
+              re.finditer(r"= bf16\[([\d,]+)\]\S* copy\(", text)
+              if np.prod([int(n) for n in m.group(1).split(",")])
+              == b * s * heads * d]
+    if layout == "packed":
+        assert not copies, copies
+    else:
+        assert len(copies) >= 6, copies
