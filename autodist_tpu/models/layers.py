@@ -279,6 +279,135 @@ def mha_decode(p, x, num_heads, k_cache, v_cache, pos, dtype=None):
     return dense(p["out"], o, dtype), k_cache, v_cache
 
 
+# -- gated delta rule (linear attention) ---------------------------------------
+
+def gdn_init(key, dim, heads, key_dim, value_dim, conv_width=4):
+    """Parameters of one gated-delta mixer (:func:`gdn`): six projections
+    in (``q``, ``k`` of heads x key_dim, ``v`` and the output gate ``z`` of
+    heads x value_dim, the decay's ``a`` and the write strength's ``b`` of
+    heads), one depthwise convolution kernel (conv_width, 2 heads key_dim +
+    heads value_dim) over q, k and v together, ``A_log`` and ``dt_bias`` a
+    head, one norm scale of value_dim shared by the heads, and ``out``.
+    No bias anywhere.  ``A_log`` is the log of uniform(0, 16) and
+    ``dt_bias`` the inverse softplus of a step drawn log-uniformly in
+    [1e-3, 1e-1], as the Gated DeltaNet paper's code draws them."""
+    ks = jax.random.split(key, 10)
+    qk, vz = heads * key_dim, heads * value_dim
+    step = jnp.exp(jax.random.uniform(ks[8], (heads,), minval=math.log(1e-3),
+                                      maxval=math.log(1e-1)))
+    return {
+        "q": dense_init(ks[0], dim, qk, use_bias=False),
+        "k": dense_init(ks[1], dim, qk, use_bias=False),
+        "v": dense_init(ks[2], dim, vz, use_bias=False),
+        "z": dense_init(ks[3], dim, vz, use_bias=False),
+        "a": dense_init(ks[4], dim, heads, use_bias=False),
+        "b": dense_init(ks[5], dim, heads, use_bias=False),
+        # A tap is one of conv_width inputs of its channel, as a row of a
+        # dense kernel is one of fan_in: uniform in +-1/sqrt(conv_width).
+        "conv": {"kernel": jax.random.uniform(
+            ks[6], (conv_width, 2 * qk + vz), minval=-conv_width ** -0.5,
+            maxval=conv_width ** -0.5)},
+        "A_log": jnp.log(jax.random.uniform(ks[7], (heads,), minval=1e-4,
+                                            maxval=16.0)),
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "norm": rmsnorm_init(value_dim),
+        "out": dense_init(ks[9], vz, dim, use_bias=False),
+    }
+
+
+@jax.custom_vjp
+def causal_depthwise_conv(kernel, x):
+    """``y_t = sum_j kernel[j] * x_(t - (taps - 1) + j)`` per channel of
+    ``x`` (batch, s, channels), positions before the row's start zero; the
+    taps are accumulated in float32.  Its gradient is written out, from
+    ``kernel`` and ``x`` alone: autodiff would keep every tap's float32
+    slice of ``x``."""
+    return _conv(kernel, x)
+
+
+def _tap_slices(x, taps):
+    """For each tap, the positions of ``x`` (zero-padded at its start) that
+    the tap meets: ``taps`` arrays of ``x``'s shape."""
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    return [padded[:, j:j + x.shape[1]] for j in range(taps)]
+
+
+def _conv(kernel, x):
+    y = sum(w.astype(jnp.float32) * t
+            for w, t in zip(kernel, _tap_slices(x, kernel.shape[0])))
+    return y.astype(x.dtype)
+
+
+def _conv_fwd(kernel, x):
+    return _conv(kernel, x), (kernel, x)
+
+
+def _conv_bwd(res, dy):
+    kernel, x = res
+    dkernel = jnp.stack([jnp.sum(dy.astype(jnp.float32) * t, axis=(0, 1))
+                         for t in _tap_slices(x, kernel.shape[0])])
+    # dx_t = sum_j kernel[j] * dy_(t + (taps - 1) - j): the same taps met
+    # from the other end.
+    dx = _conv(kernel, dy[:, ::-1])[:, ::-1]
+    return dkernel.astype(kernel.dtype), dx.astype(x.dtype)
+
+
+causal_depthwise_conv.defvjp(_conv_fwd, _conv_bwd)
+
+
+def l2_unit(t, eps=1e-6):
+    """``t / sqrt(sum(t^2) + eps)`` over the last axis, in float32."""
+    t = t.astype(jnp.float32)
+    return t * lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + eps)
+
+
+def gated_rmsnorm(p, x, gate, eps=1e-6):
+    """``rmsnorm(x) * silu(gate)``: the mixer's output gate."""
+    return rmsnorm(p, x, eps) * jax.nn.silu(gate)
+
+
+def gdn(p, x, heads, dtype=None, allow_neg_eigval=True, norm_eps=1e-6):
+    """One gated-delta mixer over ``x`` (batch, s, dim): ``(y, final
+    state)``, the state (batch, heads, key_dim, value_dim) in float32.
+
+    ``q~, k~, v~, z, a, b`` are projections of ``x``; each channel of q~,
+    k~, v~ is convolved causally over time with its own taps, then
+    ``silu``; per head q and k are L2-normalised (eps 1e-6 inside the root;
+    q also scaled by key_dim^-1/2); ``beta = sigmoid(b)`` (twice that with
+    ``allow_neg_eigval``, so that the transition's eigenvalues reach -1),
+    ``g = -exp(A_log) softplus(a + dt_bias)`` in float32; the chunked rule
+    (``ops/gated_delta.py``); ``y = W_o(rmsnorm(o) * silu(z))``, the norm a
+    head at a time with one scale shared by the heads.  The five named
+    scopes are the rows of the profiler's table (``gdn/<part>``)."""
+    from autodist_tpu.ops import gated_delta
+    b, s, _ = x.shape
+    with jax.named_scope("proj"):
+        q, k, v, z, a, beta = (dense(p[name], x, dtype)
+                               for name in ("q", "k", "v", "z", "a", "b"))
+    with jax.named_scope("conv"):
+        kernel = p["conv"]["kernel"]
+        qk = q.shape[-1]
+        q, k, v = (jax.nn.silu(causal_depthwise_conv(kernel[:, lo:hi], t))
+                   for t, lo, hi in ((q, 0, qk), (k, qk, 2 * qk),
+                                     (v, 2 * qk, kernel.shape[1])))
+    with jax.named_scope("gates"):
+        key_dim = qk // heads
+        q = (l2_unit(q.reshape(b, s, heads, key_dim))
+             * key_dim ** -0.5).astype(q.dtype)
+        k = l2_unit(k.reshape(b, s, heads, key_dim)).astype(k.dtype)
+        beta = jax.nn.sigmoid(beta.astype(jnp.float32))
+        if allow_neg_eigval:
+            beta = 2.0 * beta
+        g = -jnp.exp(p["A_log"]) * jax.nn.softplus(
+            a.astype(jnp.float32) + p["dt_bias"])
+    with jax.named_scope("scan"):
+        o, state = gated_delta.gated_delta_rule(
+            q, k, v.reshape(b, s, heads, -1), g, beta)
+    with jax.named_scope("out"):
+        o = gated_rmsnorm(p["norm"], o, z.reshape(o.shape), norm_eps)
+        return dense(p["out"], o.reshape(b, s, -1), dtype), state
+
+
 # -- recurrent ---------------------------------------------------------------
 
 def lstm_init(key, in_dim, hidden):

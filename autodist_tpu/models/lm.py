@@ -38,6 +38,24 @@ def olmoe_1b_7b(num_layers=16, dtype=jnp.bfloat16):
         norm_topk=False, load_balance_coef=0.01, router_z_coef=0.001)
 
 
+def olmo_hybrid_7b(num_layers=32, vocab=100352, dtype=jnp.bfloat16):
+    """Olmo-Hybrid-7B (allenai/Olmo-Hybrid-7B ``config.json``): three
+    gated-delta-rule layers (30 heads of 96 x 192, a 4-tap convolution,
+    eigenvalues down to -1) to every full-attention layer (30 heads of 128,
+    QK-norm, no rotary: ``rope_theta`` is null), RMSNorm on each sublayer's
+    output, a SwiGLU MLP of 11,008, no biases, an untied head.
+    ``num_layers`` keeps the pattern's first layers (a period is four)."""
+    period = (T.LINEAR,) * 3 + (T.FULL,)
+    return T.TransformerConfig(
+        vocab=vocab, dim=3840, num_heads=30, num_layers=num_layers,
+        mlp_dim=11008, max_len=65536, causal=True, dtype=dtype,
+        norm="rmsnorm", norm_eps=1e-6, positions="none", qk_norm=True,
+        bias=False, tied_head=False, ffn="swiglu",
+        layer_types=[period[i % 4] for i in range(num_layers)],
+        linear_heads=30, linear_key_dim=96, linear_value_dim=192,
+        conv_width=4, allow_neg_eigval=True, norm_position="output")
+
+
 def init(key, cfg):
     return T.init(key, cfg)
 
@@ -50,7 +68,10 @@ def make_loss_fn(cfg, attn_fn=None):
     cross-entropy plus the mean over the expert layers of the
     load-balancing term and of the router z-loss at the configuration's
     coefficients; ``aux`` holds the three terms and the router's
-    statistics under the names of docs/observability.md.
+    statistics under the names of docs/observability.md.  With
+    ``"linear_attention"`` layers it returns the pair too, ``aux`` holding
+    ``gdn.state_absmax``, the largest magnitude of any such layer's final
+    state.
     """
     def loss_fn(params, batch):
         (tokens,) = batch if isinstance(batch, (tuple, list)) else (batch,)
@@ -63,16 +84,22 @@ def make_loss_fn(cfg, attn_fn=None):
             return xent
 
         def over_layers(name, reduce=jnp.mean):
-            return reduce(jnp.stack([s[name] for s in stats]))
+            return reduce(jnp.stack([s[name] for s in stats if name in s]))
 
-        aux = {"xent": xent,
-               "moe.load_balance_loss": over_layers("load_balance"),
-               "moe.router_z_loss": over_layers("z_loss"),
-               "moe.load_max_over_mean": over_layers("load_max_over_mean",
-                                                     jnp.max),
-               "moe.dropped": over_layers("dropped", jnp.sum)}
-        loss = xent + cfg.load_balance_coef * aux["moe.load_balance_loss"] \
-            + cfg.router_z_coef * aux["moe.router_z_loss"]
+        aux, loss = {"xent": xent}, xent
+        if cfg.ffn == "moe":
+            aux.update({
+                "moe.load_balance_loss": over_layers("load_balance"),
+                "moe.router_z_loss": over_layers("z_loss"),
+                "moe.load_max_over_mean": over_layers("load_max_over_mean",
+                                                      jnp.max),
+                "moe.dropped": over_layers("dropped", jnp.sum)})
+            loss = xent \
+                + cfg.load_balance_coef * aux["moe.load_balance_loss"] \
+                + cfg.router_z_coef * aux["moe.router_z_loss"]
+        if any("gdn_state_absmax" in s for s in stats):
+            aux["gdn.state_absmax"] = over_layers("gdn_state_absmax",
+                                                  jnp.max)
         return loss, aux
     return loss_fn
 

@@ -16,6 +16,14 @@ as the head); ``norm="rmsnorm"``, ``positions="rope"``, ``qk_norm``,
 ``bias=False``, ``tied_head=False`` and ``ffn="moe"`` make the
 current decoder block whose feed-forward is a layer of routed experts
 (``parallel/moe.py:dropless_apply``, parameters under ``layer<i>/moe``).
+``layer_types`` chooses each layer's token mixer: ``"full_attention"``
+(``layer<i>/attn``) or ``"linear_attention"``, the gated delta rule
+(``layers.gdn`` over ``ops/gated_delta.py``, parameters under
+``layer<i>/gdn``); ``norm_position="output"`` normalises each sublayer's
+output before the residual add (the Olmo 2 order) where the default
+normalises its input; ``ffn="swiglu"`` is the dense gated MLP
+(``mlp/{gate,up,down}``); ``positions="none"`` gives attention no positions
+at all (the convolutions and decays of the linear layers carry order).
 """
 import jax
 import jax.numpy as jnp
@@ -32,11 +40,15 @@ class TransformerConfig:
                  qk_norm=False, bias=True, tied_head=True,
                  ffn="mlp", num_experts=0, experts_per_token=0,
                  expert_dim=None, norm_topk=True, load_balance_coef=0.0,
-                 router_z_coef=0.0):
+                 router_z_coef=0.0, layer_types=None, linear_heads=0,
+                 linear_key_dim=0, linear_value_dim=0, conv_width=4,
+                 allow_neg_eigval=True, norm_position="pre"):
         for name, value, known in (("norm", norm, ("layernorm", "rmsnorm")),
                                    ("positions", positions,
-                                    ("learned", "rope")),
-                                   ("ffn", ffn, ("mlp", "moe"))):
+                                    ("learned", "rope", "none")),
+                                   ("ffn", ffn, ("mlp", "moe", "swiglu")),
+                                   ("norm_position", norm_position,
+                                    ("pre", "output"))):
             if value not in known:
                 raise ValueError(f"{name} must be one of {known}, got "
                                  f"{value!r}")
@@ -73,6 +85,40 @@ class TransformerConfig:
                 num_experts=num_experts, top_k=experts_per_token,
                 d_model=dim, d_hidden=expert_dim or self.mlp_dim,
                 dtype=dtype, expert="swiglu", norm_topk=norm_topk)
+        # The token mixer of each layer; None is full attention throughout.
+        # A linear layer holds ``linear_heads`` states of ``linear_key_dim``
+        # x ``linear_value_dim`` and convolves q, k and v over
+        # ``conv_width`` positions first.
+        self.norm_position = norm_position
+        self.layer_types = None if layer_types is None else tuple(layer_types)
+        self.linear_heads = linear_heads
+        self.linear_key_dim, self.linear_value_dim = (linear_key_dim,
+                                                      linear_value_dim)
+        self.conv_width, self.allow_neg_eigval = conv_width, allow_neg_eigval
+        if self.layer_types is not None:
+            unknown = set(self.layer_types) - set(LAYER_TYPES)
+            if unknown or len(self.layer_types) != num_layers:
+                raise ValueError(
+                    f"layer_types must name one of {LAYER_TYPES} for each "
+                    f"of the {num_layers} layers, got {layer_types!r}")
+            if scan_layers and LINEAR in self.layer_types:
+                raise NotImplementedError(
+                    "scan_layers stacks one kind of block and does not "
+                    "carry the linear layers' final states out of the scan; "
+                    "build a configuration with 'linear_attention' layers "
+                    "with scan_layers=False")
+            if LINEAR in self.layer_types and not (
+                    linear_heads and linear_key_dim and linear_value_dim):
+                raise ValueError(
+                    "a 'linear_attention' layer needs linear_heads, "
+                    "linear_key_dim and linear_value_dim")
+
+    def layer_type(self, i):
+        return FULL if self.layer_types is None else self.layer_types[i]
+
+
+FULL, LINEAR = LAYER_TYPES = ("full_attention", "linear_attention")
+
 
 def _norm_init(cfg):
     return L.rmsnorm_init(cfg.dim) if cfg.norm == "rmsnorm" \
@@ -84,40 +130,77 @@ def _norm(cfg, p, x):
         else L.layernorm(p, x, cfg.norm_eps)
 
 
-def block_init(key, cfg):
+def block_init(key, cfg, layer_type=FULL):
+    """One block's parameters; ``layer_type`` decides whether it holds
+    ``attn`` or ``gdn``.  ``ln1`` and ``ln2`` are the norms of the mixer's
+    and the feed-forward's sublayer, wherever ``norm_position`` puts them."""
     k1, k2, k3 = jax.random.split(key, 3)
-    p = {
-        "ln1": _norm_init(cfg),
-        "attn": L.mha_init(k1, cfg.dim, cfg.num_heads, cfg.bias,
-                           cfg.qk_norm),
-        "ln2": _norm_init(cfg),
-    }
+    p = {"ln1": _norm_init(cfg)}
+    if layer_type == LINEAR:
+        p["gdn"] = L.gdn_init(k1, cfg.dim, cfg.linear_heads,
+                              cfg.linear_key_dim, cfg.linear_value_dim,
+                              cfg.conv_width)
+    else:
+        p["attn"] = L.mha_init(k1, cfg.dim, cfg.num_heads, cfg.bias,
+                               cfg.qk_norm)
+    p["ln2"] = _norm_init(cfg)
     if cfg.ffn == "moe":
         p["moe"] = moe.init(k2, cfg.moe)
     else:
         p["mlp"] = {"up": L.dense_init(k2, cfg.dim, cfg.mlp_dim, cfg.bias),
                     "down": L.dense_init(k3, cfg.mlp_dim, cfg.dim, cfg.bias)}
+        if cfg.ffn == "swiglu":
+            p["mlp"]["gate"] = L.dense_init(jax.random.fold_in(k2, 1),
+                                            cfg.dim, cfg.mlp_dim, cfg.bias)
     return p
 
 
+def _residual(cfg, norm_p, x, sublayer):
+    """``x`` plus ``sublayer``, which gives ``(y, stats)``: of the
+    normalised ``x`` (``norm_position="pre"``), or itself normalised before
+    the add (``"output"``)."""
+    if cfg.norm_position == "output":
+        y, stats = sublayer(x)
+        return x + _norm(cfg, norm_p, y), stats
+    y, stats = sublayer(_norm(cfg, norm_p, x))
+    return x + y, stats
+
+
 def block_apply(p, x, cfg, mask=None, attn_fn=None, rope=None):
-    """One block: ``(x, stats)``, ``stats`` the expert layer's
-    (``moe.dropless_apply``) and None for an MLP."""
-    # attn/mlp scopes nest under the caller's layer scope, mirroring the
+    """One block: ``(x, stats)``, ``stats`` a dict of what its layers
+    report from inside the step (the expert layer's
+    ``moe.dropless_apply`` statistics, a linear layer's
+    ``gdn_state_absmax``) and None where they report nothing.  The
+    parameters say which mixer the layer holds."""
+    # attn/gdn/mlp scopes nest under the caller's layer scope, mirroring the
     # param paths ("layer<i>/attn/...") for the per-layer profiler.
-    with jax.named_scope("attn"):
-        h = _norm(cfg, p["ln1"], x)
-        x = x + L.mha(p["attn"], h, cfg.num_heads, mask=mask, dtype=cfg.dtype,
-                      attn_fn=attn_fn, rope=rope, norm_eps=cfg.norm_eps)
-    if cfg.ffn == "moe":
-        with jax.named_scope("moe"):
-            y, stats = moe.dropless_apply(p["moe"], cfg.moe,
-                                          _norm(cfg, p["ln2"], x))
-            return x + y, stats
-    with jax.named_scope("mlp"):
-        h = _norm(cfg, p["ln2"], x)
-        h = jax.nn.gelu(L.dense(p["mlp"]["up"], h, cfg.dtype))
-        return x + L.dense(p["mlp"]["down"], h, cfg.dtype), None
+    if "gdn" in p:
+        def mixer(h):
+            y, state = L.gdn(p["gdn"], h, cfg.linear_heads, dtype=cfg.dtype,
+                             allow_neg_eigval=cfg.allow_neg_eigval,
+                             norm_eps=cfg.norm_eps)
+            return y, {"gdn_state_absmax": jnp.max(jnp.abs(
+                jax.lax.stop_gradient(state)))}
+    else:
+        def mixer(h):
+            return L.mha(p["attn"], h, cfg.num_heads, mask=mask,
+                         dtype=cfg.dtype, attn_fn=attn_fn, rope=rope,
+                         norm_eps=cfg.norm_eps), None
+    with jax.named_scope("gdn" if "gdn" in p else "attn"):
+        x, mixed = _residual(cfg, p["ln1"], x, mixer)
+
+    def ffn(h):
+        if cfg.ffn == "moe":
+            return moe.dropless_apply(p["moe"], cfg.moe, h)
+        up = L.dense(p["mlp"]["up"], h, cfg.dtype)
+        if cfg.ffn == "swiglu":
+            h = jax.nn.silu(L.dense(p["mlp"]["gate"], h, cfg.dtype)) * up
+        else:
+            h = jax.nn.gelu(up)
+        return L.dense(p["mlp"]["down"], h, cfg.dtype), None
+    with jax.named_scope("moe" if cfg.ffn == "moe" else "mlp"):
+        x, fed = _residual(cfg, p["ln2"], x, ffn)
+    return x, {**(mixed or {}), **(fed or {})} or None
 
 
 def init(key, cfg):
@@ -135,11 +218,13 @@ def init(key, cfg):
     if cfg.num_segments:
         params["seg_embed"] = L.normal(keys[2], (cfg.num_segments, cfg.dim), 0.02)
     if cfg.scan_layers:
-        params["blocks"] = jax.vmap(lambda k: block_init(k, cfg))(
+        params["blocks"] = jax.vmap(
+            lambda k: block_init(k, cfg, cfg.layer_type(0)))(
             jnp.stack(keys[3:3 + cfg.num_layers]))
     else:
         for i in range(cfg.num_layers):
-            params[f"layer{i}"] = block_init(keys[3 + i], cfg)
+            params[f"layer{i}"] = block_init(keys[3 + i], cfg,
+                                             cfg.layer_type(i))
     return params
 
 
@@ -153,8 +238,9 @@ def encode(params, cfg, ids, segment_ids=None, attn_fn=None):
 
 
 def encode_with_stats(params, cfg, ids, segment_ids=None, attn_fn=None):
-    """:func:`encode` and the expert layers' statistics: ``(hidden,
-    [stats of layer 0, ...])``, the list empty for ``ffn="mlp"``."""
+    """:func:`encode` and what the layers report from inside the step
+    (:func:`block_apply`): ``(hidden, [stats of a layer that has any,
+    ...])``, the list empty for the default block."""
     s = ids.shape[1]
     with jax.named_scope("embed"):
         x = L.embed(params["embed"], ids)
@@ -213,12 +299,15 @@ def logits(params, cfg, hidden):
 
 def _decodable(cfg):
     block = (cfg.norm, cfg.positions, cfg.ffn, cfg.qk_norm, cfg.bias,
-             cfg.tied_head)
-    if block != ("layernorm", "learned", "mlp", False, True, True):
+             cfg.tied_head, cfg.norm_position, cfg.layer_types)
+    if block != ("layernorm", "learned", "mlp", False, True, True, "pre",
+                 None):
         raise NotImplementedError(
             "decoding is implemented for the default block only (LayerNorm, "
             "learned positions, biased projections, an MLP, a tied head); "
-            "through rope, QK-norm or moe it waits for ROADMAP R2")
+            "through rope, QK-norm, moe, output norms or linear-attention "
+            "layers (recurrent state beside a KV cache) it waits for "
+            "ROADMAP R2")
 
 
 def init_cache(cfg, slots, cache_len, dtype=None):

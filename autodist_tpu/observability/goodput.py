@@ -107,6 +107,7 @@ EVENT_CLASS = {
     "divergence-abort": "rollback_ms",
     "emergency-save": "emergency_save_ms",
     "flash": None,
+    "gdn": None,
     "goodput": None,
     "mesh-built": "startup_ms",
     "memory": None,

@@ -236,6 +236,10 @@ BLOCK_SCOPES = ("attn", "mlp")
 #: sub-scopes: ``layer<i>/moe/router`` -> ``moe/router`` (likewise
 #: ``moe/dispatch`` and ``moe/experts``; what sits in none of them is ``moe``).
 MOE_SCOPE = "moe"
+#: So does the gated-delta mixer: ``layer<i>/gdn/scan`` -> ``gdn/scan``
+#: (likewise ``gdn/proj``, ``gdn/conv``, ``gdn/gates``, ``gdn/out``; the
+#: norm of the mixer's output, in none of them, is ``gdn``).
+GDN_SCOPE = "gdn"
 
 _INSTRUCTION_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
 
@@ -252,7 +256,7 @@ def _scope_and_phase(op_name):
             scope = "head"
         elif len(segs) > 1 and segs[1] in BLOCK_SCOPES:
             scope = segs[1]
-        elif len(segs) > 1 and segs[1] == MOE_SCOPE:
+        elif len(segs) > 1 and segs[1] in (MOE_SCOPE, GDN_SCOPE):
             scope = "/".join(segs[1:3])
         elif segs[0] in UPDATE_SCOPES:
             scope = segs[0]

@@ -1,0 +1,156 @@
+"""The chunked gated delta rule (``ops/gated_delta.py``) against the rule one
+position at a time, on the CPU in float32: outputs, final states and the
+gradients of q, k, v, g and beta; lengths of one chunk, of many and of a
+length the chunk does not divide; decays near 0 and near 1; write strengths
+up to 2; rows that must not mix; bf16 operands within a stated tolerance."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from autodist_tpu.ops import gated_delta
+from autodist_tpu.ops.gated_delta import gated_delta_rule
+
+HEADS, D_K, D_V = 3, 8, 12
+
+
+def recurrent_gated_delta_rule(q, k, v, g, beta):
+    """The rule one position at a time, in float32, as the module's
+    docstring writes it: ``(o, final state)``."""
+    b, s, h, d_k = q.shape
+    q, k, v, g, beta = (jnp.moveaxis(t.astype(jnp.float32), 1, 0)
+                        for t in (q, k, v, g, beta))
+
+    def step(state, x):
+        q, k, v, g, beta = x                              # (b, h, ...)
+        state = jnp.exp(g)[..., None, None] * state
+        u = beta[..., None] * (v - jnp.einsum("bhkd,bhk->bhd", state, k))
+        state = state + k[..., :, None] * u[..., None, :]
+        return state, jnp.einsum("bhkd,bhk->bhd", state, q)
+
+    state, o = jax.lax.scan(step, jnp.zeros((b, h, d_k, v.shape[-1])),
+                        (q, k, v, g, beta))
+    return jnp.moveaxis(o, 0, 1), state
+
+
+def _inputs(seed, rows, s, decay=1.0, beta_max=2.0, dtype=jnp.float32):
+    """q and k as the mixer hands them over (unit k, q scaled by
+    d_k^-1/2), log decays of about ``-decay``, beta in (0, beta_max)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q = jax.random.normal(ks[0], (rows, s, HEADS, D_K))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * D_K ** -0.5
+    k = jax.random.normal(ks[1], (rows, s, HEADS, D_K))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (rows, s, HEADS, D_V))
+    g = -decay * jax.nn.softplus(jax.random.normal(ks[3], (rows, s, HEADS)))
+    beta = beta_max * jax.nn.sigmoid(
+        2.0 * jax.random.normal(ks[4], (rows, s, HEADS)))
+    return tuple(t.astype(dtype) for t in (q, k, v)) + (g, beta)
+
+
+def _both(args, chunk):
+    """Outputs, states and the five gradients of a random projection of the
+    output, chunked and one position at a time."""
+    weights = jax.random.normal(jax.random.PRNGKey(9),
+                                args[2].shape, jnp.float32)
+
+    def chunked(*a):
+        o, state = gated_delta_rule(*a, chunk=chunk)
+        return jnp.sum(o.astype(jnp.float32) * weights), (o, state)
+
+    def stepwise(*a):
+        o, state = recurrent_gated_delta_rule(*a)
+        return jnp.sum(o * weights), (o, state)
+
+    with jax.default_matmul_precision("highest"):
+        return [jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2, 3, 4),
+                                           has_aux=True))(*args)
+                for f in (chunked, stepwise)]
+
+
+def _assert_close(got, want, tol):
+    scale = float(jnp.max(jnp.abs(want))) or 1.0
+    np.testing.assert_allclose(np.asarray(got, np.float32) / scale,
+                               np.asarray(want, np.float32) / scale,
+                               atol=tol, rtol=0)
+
+
+@pytest.mark.parametrize("s, chunk", [(16, 16), (64, 16), (50, 16), (64, 64),
+                                      (96, 32), (7, 8)])
+@pytest.mark.parametrize("decay, beta_max", [(1.0, 2.0), (0.01, 2.0),
+                                             (30.0, 1.0)])
+def test_the_chunked_rule_is_the_recurrence(s, chunk, decay, beta_max):
+    """Decay 0.01: alpha near 1, the state grows with beta up to 2; decay
+    30: alpha near 0, exp(gamma_i - gamma_j) underflows to an exact 0 and
+    nothing divides by it."""
+    args = _inputs(s, 2, s, decay, beta_max)
+    ((_, (o, state)), grads), ((_, (o_want, state_want)), grads_want) = \
+        _both(args, chunk)
+    assert o.shape == (2, s, HEADS, D_V) and state.shape == (2, HEADS, D_K,
+                                                             D_V)
+    _assert_close(o, o_want, 5e-6)
+    _assert_close(state, state_want, 5e-6)
+    for name, got, want in zip("q k v g beta".split(), grads, grads_want):
+        assert bool(jnp.isfinite(got).all()), name
+        _assert_close(got, want, 2e-5)
+
+
+def test_rows_do_not_mix():
+    """Two rows together are each row alone: the state starts at zero at
+    each row's first position."""
+    args = _inputs(3, 2, 40)
+    both, _ = gated_delta_rule(*args, chunk=16)
+    for row in range(2):
+        alone, _ = gated_delta_rule(*(t[row:row + 1] for t in args),
+                                    chunk=16)
+        np.testing.assert_allclose(both[row:row + 1], alone, atol=1e-6)
+    # And a row's output does not depend on what follows a position.
+    cut, _ = gated_delta_rule(*(t[:, :24] for t in args), chunk=16)
+    np.testing.assert_allclose(both[:, :24], cut, atol=1e-6)
+
+
+def test_the_padding_decays_nothing_and_writes_nothing():
+    """50 positions in chunks of 16: the final state is the state after
+    position 50, not after 64."""
+    args = _inputs(4, 1, 50)
+    _, state = gated_delta_rule(*args, chunk=16)
+    _, want = recurrent_gated_delta_rule(*args)
+    _assert_close(state, want, 5e-6)
+
+
+def test_bf16_operands_stay_within_their_rounding():
+    """bf16 q, k and v (the train path): every product but T's two takes
+    bf16 operands and accumulates in float32, the state is carried in
+    float32.  Against the float32 recurrence on the same (rounded) inputs
+    the output is within 2e-2 of its largest magnitude: three bf16
+    roundings (2^-9 each) through 16 chunks' worth of state."""
+    args = _inputs(5, 2, 256, decay=0.05, dtype=jnp.bfloat16)
+    o, _ = gated_delta_rule(*args, chunk=16)
+    assert o.dtype == jnp.bfloat16
+    want, _ = recurrent_gated_delta_rule(*args)
+    _assert_close(o, want, 2e-2)
+    grads = jax.grad(lambda *a: jnp.sum(
+        gated_delta_rule(*a, chunk=16)[0].astype(jnp.float32)),
+        argnums=(0, 1, 2, 3, 4))(*args)
+    assert [g.dtype for g in grads] == [jnp.bfloat16] * 3 + [jnp.float32] * 2
+    assert all(bool(jnp.isfinite(g.astype(jnp.float32)).all())
+               for g in grads)
+
+
+def test_the_trace_announces_the_rule_once_a_shape():
+    from autodist_tpu import observability
+    from autodist_tpu.observability import recorder
+    observability.reset()
+    gated_delta._announced.clear()
+    args = _inputs(6, 2, 40)
+    gated_delta_rule(*args, chunk=16)
+    gated_delta_rule(*args, chunk=16)
+    events = [e for e in recorder.events() if e["kind"] == "gdn"]
+    assert len(events) == 1
+    assert "3 chunks of 16 a row" in events[0]["detail"]
+    assert gated_delta.BACKWARD in events[0]["detail"]
+    assert "jax.checkpoint" in gated_delta.BACKWARD
+    gauges = observability.registry().snapshot()["gauges"]
+    assert gauges["gdn.heads"] == HEADS and gauges["gdn.chunk"] == 16
+    assert gauges["gdn.chunks_per_row"] == 3
+    assert gauges["gdn.state_bytes_per_row"] == HEADS * D_K * D_V * 4
