@@ -19,39 +19,44 @@ really writes and ``S_0`` the state the chunk starts from::
     O    = diag(exp gamma) Q S_0 + (M * Q K^T) U          M_ij = exp(gamma_i
     S_C  = exp(gamma_C) S_0 + (exp(gamma_C - gamma) K)^T U      - gamma_j), j <= i
 
-so ``T = (I + A)^-1`` (the WY / UT form: a unit lower-triangular system of
-``chunk`` rows, solved once a chunk and head), ``W = T diag(beta exp gamma)
-K`` and ``U_0 = T diag(beta) V`` are computed for every chunk at once, and
-one ``lax.scan`` over the ``s / chunk`` chunks carries ``S`` in float32
-through three matrix products a step (``U = U_0 - W S``, ``O``, ``S_C``).
+so ``T = (I + A)^-1`` (the WY / UT form: the inverse of a unit
+lower-triangular matrix of ``chunk`` rows, once a chunk and head, by block
+matrix products where ``chunk`` is a power of two: ``unit_lower_inverse``),
+``W = T diag(beta exp gamma) K`` and ``U_0 = T diag(beta) V`` are computed
+for every chunk at once, and one ``lax.scan`` over the ``s / chunk`` chunks
+carries ``S`` in float32 through three matrix products a step (``U = U_0 -
+W S``, ``O``, ``S_C``).
 Decays are differences of logarithms, never quotients of decays, so a
 decay near 0 underflows to an exact 0 and nothing overflows.  Every product
 takes operands in the inputs' dtype (bfloat16 on the train path) and
 accumulates in float32, but the two that ``T`` multiplies, which are float32
-throughout; the decays, the triangular solve and the carried state are
-float32.
+throughout; the decays, the inverse and the carried state are float32.
 
-The backward pass is autodiff through this chunked form with two
-``jax.checkpoint``s: the scan saves each chunk's incoming state (``s /
-chunk`` x heads x d_k x d_v float32 a row: 141 MB at 4,096 positions, 30
-heads of 96 x 192) and the five terms it was given, in the inputs' dtype,
-and its transpose is again one scan over the chunks, backwards, that makes
-a chunk's ``U`` again; the terms' own intermediates (the decay matrices,
-``A``, ``T``: float32, chunk x chunk a chunk and head) are made again from
-q, k, v, g and beta and not kept.  Without the two, one layer of 30 heads
-keeps 0.7 GB at 4,096 positions.
+The backward pass is autodiff through this chunked form (the inverse
+brings its own cotangent, ``dA = -tril(T^T dT T^T, -1)``: two products a
+system) with two ``jax.checkpoint``s: the scan saves each chunk's incoming
+state (``s / chunk`` x heads x d_k x d_v float32 a row: 141 MB at 4,096
+positions, 30 heads of 96 x 192) and the five terms it was given, in the
+inputs' dtype, and its transpose is again one scan over the chunks,
+backwards, that makes a chunk's ``U`` again; the terms' own intermediates
+(the decay matrices, ``A``, ``T``: float32, chunk x chunk a chunk and head)
+are made again from q, k, v, g and beta and not kept.  Without the two, one
+layer of 30 heads keeps 0.7 GB at 4,096 positions.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from autodist_tpu.utils import logging
 
-#: Positions a chunk: the triangular system's size and the scan's stride.
+#: Positions a chunk: the rows of the matrix inverted and the scan's stride.
 CHUNK = 64
-BACKWARD = ("autodiff through the chunked form; each chunk's incoming "
-            "state and the scan's terms saved, the terms' intermediates and "
-            "a chunk's U recomputed (jax.checkpoint)")
+BACKWARD = ("autodiff through the chunked form around the inverse's own "
+            "cotangent; each chunk's incoming state and the scan's terms "
+            "saved, the terms' intermediates (the inverse among them) and a "
+            "chunk's U recomputed (jax.checkpoint)")
 
 _announced = set()
 
@@ -63,7 +68,8 @@ def _announce(rows, s, heads, d_k, d_v, chunk):
     chunks = -(-s // chunk)
     detail = (f"gated delta rule, chunked: ({rows}, {s}, {heads}, {d_k} / "
               f"{d_v}), {chunks} chunks of {chunk} a row, state "
-              f"{heads} x {d_k} x {d_v} float32; backward: {BACKWARD}")
+              f"{heads} x {d_k} x {d_v} float32; inverse: "
+              f"{inverse_form(chunk)}; backward: {BACKWARD}")
     new = detail not in _announced
     _announced.add(detail)
     if new:
@@ -95,8 +101,19 @@ def gated_delta_rule(q, k, v, g, beta, chunk=CHUNK):
     caller).  A length ``chunk`` does not divide is padded inside with
     positions that decay nothing and write nothing."""
     b, s, h, d_k = q.shape
+    _announce(b, s, h, d_k, v.shape[-1], chunk)
+    return _chunked_rule(q, k, v, g, beta, chunk=chunk)
+
+
+@functools.partial(jax.jit, inline=True, static_argnames="chunk")
+def _chunked_rule(q, k, v, g, beta, chunk):
+    """An inlined ``jit``: a model's linear layers make the same call, and
+    every one after the first takes the first's equations from the cache
+    (the benchmark's process spends 5-10 times a clean process's time on
+    tracing, PERF.md section 7, and the inverse's levels are operations more
+    to trace than the solve they replace)."""
+    b, s, h, d_k = q.shape
     d_v = v.shape[-1]
-    _announce(b, s, h, d_k, d_v, chunk)
     dtype = q.dtype
     pad = -s % chunk
     if pad:
@@ -143,10 +160,8 @@ def _chunk_terms(q, k, v, g, beta, dtype):
                               -jnp.inf))                  # M, zero above
     a = jnp.where(jnp.tril(lower, -1), beta[..., None] * decay
                   * _mm("nbhid,nbhjd->nbhij", k, k, dtype), 0.0)
-    eye = jnp.eye(chunk, dtype=a.dtype)
-    t = jax.scipy.linalg.solve_triangular(
-        a + eye, jnp.broadcast_to(eye, a.shape), lower=True,
-        unit_diagonal=True)
+    with jax.named_scope("inverse"):
+        t = unit_lower_inverse(a)
     into = jnp.exp(gamma)[..., None]                      # decay since S_0
     # T's two products stay float32, exact: rounding the inverse to bf16 is
     # the rule's largest error (the reference check read 7.0e-5 with it and
@@ -159,3 +174,95 @@ def _chunk_terms(q, k, v, g, beta, dtype):
     carry = jnp.exp(gamma[..., -1])[..., None, None]      # (n, b, h, 1, 1)
     return tuple(x.astype(dtype) for x in (w, u0, qk, into * q,
                                            to_end * k)) + (carry,)
+
+
+def inverse_form(chunk):
+    """How ``unit_lower_inverse`` computes a chunk's ``T``, for the ``gdn``
+    event and the op's info line."""
+    if chunk & (chunk - 1):
+        return ("triangular solve against the identity, float32 (chunk no "
+                "power of two); its cotangent the solve's own")
+    return ("block products level by level from 1 x 1, float32, vector work "
+            "with the systems along the lanes; its cotangent closed, "
+            "-tril(T^T dT T^T, -1)")
+
+
+def unit_lower_inverse(a):
+    """``(I + a)^-1`` of strictly lower-triangular ``a`` (..., c, c) in
+    float32; what stands on or above the diagonal is never read.  ``c`` a
+    power of two: by halves, ``[[L11, 0], [A21, L22]]^-1 = [[T11, 0], [-T22
+    A21 T11, T22]]``, level by level from the 1 x 1 blocks (inverse 1) up,
+    each level two products batched over every pair of blocks of every
+    system; no row substitution, which on the TPU is ``c`` dependent steps
+    of vector work (a ``custom-call`` that was the fourth operation of the
+    Olmo-Hybrid step).  Not ``(I - a)(I + a^2)(I + a^4)...``: exact on
+    paper, but with a chunk's keys alike the powers of ``a`` grow before
+    they cancel and float32 is off by factors of 1e15.  Any other ``c``:
+    ``solve_triangular`` against the identity."""
+    c = a.shape[-1]
+    if c & (c - 1):
+        eye = jnp.eye(c, dtype=a.dtype)
+        return jax.scipy.linalg.solve_triangular(
+            a + eye, jnp.broadcast_to(eye, a.shape), lower=True,
+            unit_diagonal=True)
+    return _block_inverse(a)
+
+
+@jax.custom_vjp
+def _block_inverse(a):
+    """The levels run with the systems as the minor dimension, (blocks, m,
+    m, systems): of a chunk of 64 a block has 32 rows at most, a quarter of
+    a vector's 128 lanes, and as matrix products blocks that small fill a
+    sliver of the MXU's tile; a layer's 1,920 systems fill the lanes at
+    every level
+    (v5e, the inverse alone at the cell's shape: 0.41 ms against 0.99 ms
+    with the blocks' own rows along the lanes, 1.13 to 1.88 ms with the
+    levels under 16 or 8 rows on the MXU, 3.00 ms for the solve)."""
+    c = a.shape[-1]
+    t = _inverse_of_blocks(jnp.moveaxis(a.reshape((1, -1, c, c)), 1, -1))
+    return jnp.moveaxis(t, -1, 1).reshape(a.shape)
+
+
+def _block_inverse_fwd(a):
+    t = _block_inverse(a)
+    return t, t
+
+
+def _block_inverse_bwd(t, dt):
+    """``dT = -T dA T``, so ``dA = -T^T dT T^T``, cut to what ``a`` is read
+    from: two float32 products a system (``HIGHEST``, as T's other two: the
+    inverse's rounding is the rule's largest error in the reference check),
+    where autodiff through the levels runs two for each of the forward's
+    and keeps every level's blocks (measured 2.3 ms a layer slower on the
+    v5e, and 0.1 GB more at the cell's shape)."""
+    t = jnp.swapaxes(t, -1, -2)
+    da = jnp.matmul(jnp.matmul(t, dt, precision=lax.Precision.HIGHEST), t,
+                    precision=lax.Precision.HIGHEST)
+    return (jnp.tril(-da, -1),)
+
+
+_block_inverse.defvjp(_block_inverse_fwd, _block_inverse_bwd)
+
+
+def _inverse_of_blocks(d):
+    """``(I + d)^-1`` of each diagonal block ``d`` (blocks, m, m, systems),
+    m a power of two: one Python level a halving, every block of every
+    system in one array (blocks x 2, m / 2, m / 2, systems), so a chunk of
+    64 traces six levels and not a tree of 63 products."""
+    blocks, m, _, systems = d.shape
+    if m == 1:
+        return jnp.ones_like(d)
+    half = m // 2
+    x = d.reshape(blocks, 2, half, 2, half, systems)
+    t11, t22 = jnp.split(_inverse_of_blocks(jnp.concatenate(
+        [x[:, 0, :, 0], x[:, 1, :, 1]])), 2)
+    t21 = -_mm_lanes(_mm_lanes(t22, x[:, 1, :, 0]), t11)
+    return jnp.concatenate(
+        [jnp.concatenate([t11, jnp.zeros_like(t11)], axis=2),
+         jnp.concatenate([t21, t22], axis=2)], axis=1)
+
+
+def _mm_lanes(a, b):
+    """``a @ b`` of (blocks, i, k, systems) and (blocks, k, j, systems):
+    multiplies and a sum over k, each over whole vectors of systems."""
+    return jnp.sum(a[:, :, :, None] * b[:, None], axis=2)

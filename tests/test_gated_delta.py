@@ -2,7 +2,10 @@
 position at a time, on the CPU in float32: outputs, final states and the
 gradients of q, k, v, g and beta; lengths of one chunk, of many and of a
 length the chunk does not divide; decays near 0 and near 1; write strengths
-up to 2; rows that must not mix; bf16 operands within a stated tolerance."""
+up to 2; rows that must not mix; bf16 operands within a stated tolerance.
+A chunk's inverse by block products against a float64 inverse and against
+the triangular solve it replaces, its closed cotangent against autodiff
+through the solve, and which of the two a chunk's size takes."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -137,6 +140,89 @@ def test_bf16_operands_stay_within_their_rounding():
                for g in grads)
 
 
+def _alike_systems(c, shared, decay, n=24, d=96, seed=1):
+    """``a`` (n, c, c) float64 as ``_chunk_terms`` makes it, from unit keys
+    that share a direction of weight ``shared`` (the ill-conditioned case:
+    a chunk's keys alike), beta in [1, 2], log decays of about ``-decay``."""
+    r = np.random.default_rng(seed)
+    common = r.standard_normal((n, 1, d))
+    common /= np.linalg.norm(common, axis=-1, keepdims=True)
+    k = r.standard_normal((n, c, d))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    k = shared * common + (1 - shared) * k
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    beta = r.uniform(1.0, 2.0, (n, c))
+    gamma = np.cumsum(-decay * np.log1p(np.exp(r.standard_normal((n, c)))),
+                      -1)
+    a = (beta[..., None] * np.exp(gamma[..., :, None] - gamma[..., None, :])
+         * (k @ np.swapaxes(k, -1, -2)))
+    return np.tril(a, -1)
+
+
+def _solve(a):
+    eye = jnp.eye(a.shape[-1], dtype=a.dtype)
+    return jax.scipy.linalg.solve_triangular(
+        a + eye, jnp.broadcast_to(eye, a.shape), lower=True,
+        unit_diagonal=True)
+
+
+@pytest.mark.parametrize("chunk", [8, 16, 32, 64])
+@pytest.mark.parametrize("shared", [0.7, 0.95])
+@pytest.mark.parametrize("decay", [0.0, 3.0])
+def test_the_block_inverse_is_the_inverse(chunk, shared, decay):
+    """Float32 block products against a float64 inverse, keys alike, where
+    the powers of ``a`` grow before they cancel: within 1e-5 of the
+    inverse's largest entry, and the row substitution it replaces beside
+    it (3e-7 to 6e-7 at 64 rows where the block form reads 1e-6 to 2e-6)."""
+    a = _alike_systems(chunk, shared, decay)
+    want = np.linalg.inv(np.eye(chunk) + a)
+    scale = np.max(np.abs(want))
+    a = jnp.asarray(a, jnp.float32)
+    errors = {name: float(np.max(np.abs(np.asarray(f(a), np.float64) - want))
+                    / scale)
+              for name, f in (("block products",
+                               jax.jit(gated_delta.unit_lower_inverse)),
+                              ("triangular solve", jax.jit(_solve)))}
+    assert errors["block products"] < 1e-5, errors
+    assert errors["triangular solve"] < 1e-5, errors
+
+
+@pytest.mark.parametrize("chunk", [8, 64])
+def test_the_block_inverses_gradient_is_the_solves(chunk):
+    """The closed form ``dA = -tril(T^T dT T^T, -1)`` against autodiff
+    through ``solve_triangular``; nothing on or above the diagonal, which
+    neither form reads."""
+    a = jnp.asarray(_alike_systems(chunk, 0.7, 0.0), jnp.float32)
+    weights = jax.random.normal(jax.random.PRNGKey(2), a.shape)
+    got, want = (jax.jit(jax.grad(lambda a: jnp.sum(f(a) * weights)))(a)
+                 for f in (gated_delta.unit_lower_inverse, _solve))
+    _assert_close(got, want, 1e-5)
+    assert not np.triu(np.asarray(got)).any()
+
+
+def test_a_chunk_that_is_no_power_of_two_takes_the_solve():
+    """96 positions in chunks of 48: the fallback, still the recurrence."""
+    args = _inputs(7, 2, 96)
+    ((_, (o, state)), grads), ((_, (o_want, state_want)), grads_want) = \
+        _both(args, 48)
+    _assert_close(o, o_want, 5e-6)
+    _assert_close(state, state_want, 5e-6)
+    for got, want in zip(grads, grads_want):
+        _assert_close(got, want, 2e-5)
+
+
+@pytest.mark.parametrize("chunk, solves", [(64, False), (48, True)])
+def test_no_triangular_solve_is_traced_for_a_power_of_two(chunk, solves):
+    """Forward, recomputation and backward of the rule at the train path's
+    chunk are products only; a chunk of 48 still solves."""
+    args = _inputs(8, 1, 192)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(gated_delta_rule(*a, chunk=chunk)[0]),
+        argnums=(0, 1, 2, 3, 4)))(*args)
+    assert ("triangular_solve" in str(jaxpr)) == solves
+    assert ("block products" in gated_delta.inverse_form(chunk)) != solves
+
+
 def test_the_trace_announces_the_rule_once_a_shape():
     from autodist_tpu import observability
     from autodist_tpu.observability import recorder
@@ -150,6 +236,11 @@ def test_the_trace_announces_the_rule_once_a_shape():
     assert "3 chunks of 16 a row" in events[0]["detail"]
     assert gated_delta.BACKWARD in events[0]["detail"]
     assert "jax.checkpoint" in gated_delta.BACKWARD
+    assert "the inverse's own cotangent" in gated_delta.BACKWARD
+    assert ("inverse: block products level by level from 1 x 1, float32, "
+            "vector work with the systems along the lanes; its cotangent "
+            "closed" in events[0]["detail"])
+    assert "triangular solve" in gated_delta.inverse_form(48)
     gauges = observability.registry().snapshot()["gauges"]
     assert gauges["gdn.heads"] == HEADS and gauges["gdn.chunk"] == 16
     assert gauges["gdn.chunks_per_row"] == 3

@@ -171,6 +171,10 @@ def _no_delta_term(monkeypatch):
         return (0 * w, (beta[..., None] * v).astype(dtype), qk, q_in, k_out,
                 carry)
     monkeypatch.setattr(gated_delta, "_chunk_terms", without)
+    # The rule is an inlined ``jit``: around its cache, which would hand
+    # this test a sound trace of another's and a later test this one's.
+    monkeypatch.setattr(gated_delta, "_chunked_rule",
+                        gated_delta._chunked_rule.__wrapped__)
     return {}
 
 
@@ -400,6 +404,11 @@ def test_the_step_carries_the_state_absmax_and_names_its_scopes():
     gauges = observability.registry().snapshot()["gauges"]
     assert gauges["gdn.heads"] == 3 and gauges["gdn.chunk"] == 64
     assert gauges["gdn.chunks_per_row"] == 1
+    from autodist_tpu.observability import recorder
+    details = [e["detail"] for e in recorder.events() if e["kind"] == "gdn"]
+    assert details and all(
+        "inverse: block products" in d and gated_delta.BACKWARD in d
+        for d in details)
     scopes = {scope for scope, _ in runner.scope_table().values()}
     assert {"gdn/proj", "gdn/conv", "gdn/gates", "gdn/scan", "gdn/out",
             "attn", "mlp", "head"} <= scopes

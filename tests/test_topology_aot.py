@@ -369,3 +369,41 @@ def test_v5e_compiler_runs_no_head_split_copy_for_the_packed_layout(
         assert not copies, copies
     else:
         assert len(copies) >= 6, copies
+
+
+@pytest.mark.parametrize("chunk, solves", [(64, False), (48, True)])
+def test_v5e_compiler_runs_no_triangular_solve_for_a_chunk_of_64(chunk,
+                                                                 solves):
+    """The gated delta rule at the Olmo-Hybrid cell's own shape (one row of
+    4,096 positions, 30 heads of 96 / 192, bf16), forward and backward,
+    compiled by libtpu for one detached v5e chip.  At the train path's chunk
+    of 64 the chunk's inverse is block products: no ``triangular_solve`` and
+    not the custom call libtpu expands one into (``f32[64,1,30,1,64,64]`` in
+    a trace, 15.4 ms of the step before) is left in the optimized HLO.  A
+    chunk that is no power of two still solves."""
+    why_not = _why_no_detached_topology()
+    if why_not:
+        pytest.skip(why_not)
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from autodist_tpu.ops.gated_delta import gated_delta_rule
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x4")
+    chip = SingleDeviceSharding(topo.devices[0])
+    b, s, heads, d_k, d_v = 1, 4096, 30, 96, 192
+    q, k, v, g, beta = (
+        jax.ShapeDtypeStruct((b, s, heads) + tail, dtype, sharding=chip)
+        for tail, dtype in (((d_k,), jnp.bfloat16), ((d_k,), jnp.bfloat16),
+                            ((d_v,), jnp.bfloat16), ((), jnp.float32),
+                            ((), jnp.float32)))
+
+    def loss(*args):
+        o, state = gated_delta_rule(*args, chunk=chunk)
+        return (o.astype(jnp.float32) ** 2).sum() + state.sum()
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        q, k, v, g, beta).compile().as_text()
+    assert "while(" in text                     # the scan over the chunks
+    named = [m.group(0) for m in re.finditer(
+        r"op_name=\"[^\"]*triangular_solve|"
+        r"custom_call_target=\"[^\"]*Triangular[^\"]*\"", text)]
+    assert bool(named) == solves, sorted(set(named))
