@@ -82,6 +82,82 @@ class VariableItem:
                 f"sparse={self.sparse_access})")
 
 
+#: The reserved entry of a loss's ``aux``: ``{variable name: its value after
+#: this step}`` (:meth:`GraphItem.capture`).
+STATE_UPDATES = "state_updates"
+
+
+def _sub_jaxpr(eqn):
+    """The one jaxpr a call-like equation runs on its own operands (``jit``,
+    ``checkpoint``, ``custom_jvp`` / ``custom_vjp`` calls), else None."""
+    # Closed jaxprs (:func:`_sub_jaxprs`) and plain ones (``checkpoint``).
+    found = list(_sub_jaxprs(eqn)) + [v for v in eqn.params.values()
+                                      if hasattr(v, "eqns")]
+    if len(found) != 1:
+        return None
+    inner = found[0]
+    return inner if len(inner.invars) == len(eqn.invars) \
+        and len(inner.outvars) == len(eqn.outvars) else None
+
+
+def _differentiable_reach(jaxpr, sources):
+    """Whether each outvar of ``jaxpr`` depends on one of the invars flagged
+    in ``sources`` through operations a gradient passes: the dependence ends
+    at ``stop_gradient`` and at every value that is not floating point (an
+    index, a count, a comparison).  An equation with several jaxprs of its
+    own (``scan``, ``while``, ``cond``) is not entered: its floating-point
+    outputs count as reached (docs/usage/state-updates.md)."""
+    reached = {id(v) for v, flagged in zip(jaxpr.invars, sources) if flagged}
+
+    def hit(v):      # a literal is never in the set
+        return id(v) in reached
+
+    for eqn in jaxpr.eqns:
+        flags = [hit(v) for v in eqn.invars]
+        if not any(flags) or eqn.primitive.name == "stop_gradient":
+            continue
+        inner = _sub_jaxpr(eqn)
+        outs = _differentiable_reach(inner, flags) if inner is not None \
+            else [True] * len(eqn.outvars)
+        reached.update(
+            id(v) for v, out in zip(eqn.outvars, outs)
+            if out and jnp.issubdtype(v.aval.dtype, jnp.inexact))
+    return [hit(v) for v in jaxpr.outvars]
+
+
+def _check_state_updates(traced, variables):
+    """The names under ``aux["state_updates"]`` of the traced loss, checked:
+    each is a variable, its value has the variable's shape and dtype, and
+    no gradient of the loss reaches the variable (the optimizer's update of
+    it is then zero and the step's write is the only change)."""
+    closed, out = traced
+    aux = out[1] if isinstance(out, (tuple, list)) and len(out) == 2 else None
+    if not isinstance(aux, dict) or STATE_UPDATES not in aux:
+        return ()
+    updates = aux[STATE_UPDATES]
+    by_name = {v.name: (i, v) for i, v in enumerate(variables)}
+    n_in = len(closed.jaxpr.invars)
+    for name, value in updates.items():
+        if name not in by_name:
+            raise ValueError(
+                f"aux['{STATE_UPDATES}'] names {name!r}, which is no "
+                f"variable of the captured parameters")
+        index, var = by_name[name]
+        if tuple(value.shape) != var.shape or value.dtype != var.dtype:
+            raise ValueError(
+                f"aux['{STATE_UPDATES}'][{name!r}] is {value.dtype}"
+                f"{tuple(value.shape)}; the variable is {var.dtype}"
+                f"{var.shape}")
+        if _differentiable_reach(closed.jaxpr,
+                                 [i == index for i in range(n_in)])[0]:
+            raise ValueError(
+                f"aux['{STATE_UPDATES}'] sets {name!r}, but the loss also "
+                f"has a gradient with respect to it: a variable the step "
+                f"writes must enter the loss through jax.lax.stop_gradient "
+                f"(or through values with no gradient, such as an index)")
+    return tuple(updates)
+
+
 def _trace_loss(loss_fn, params, batch_struct):
     """``(closed jaxpr, output structs)`` of ``loss_fn`` on abstract params
     and batch, or None where it does not trace (capture's reading of the
@@ -304,6 +380,8 @@ class GraphItem:
         self.batch_struct = batch_struct  # ShapeDtypeStruct pytree of the example batch
         self.variables = variables or []
         self.aux_output = aux_output  # loss_fn returns (loss, aux)
+        # Names of the variables aux["state_updates"] sets (capture).
+        self.state_updates = ()
         self.precision = precision  # None (full) | "bf16" (mixed compute)
         self._jaxpr_text = None
         self._flops_estimate = None
@@ -326,6 +404,21 @@ class GraphItem:
                 ``example_batch`` (False without one).  Given, it must
                 agree with that trace: a loss that returns a pair under
                 ``aux_output=False``, or a bare loss under True, raises.
+                ``aux`` is the caller's, returned by every step as
+                ``metrics["aux"]``, but for one reserved entry: a dict
+                ``aux["state_updates"]`` maps a variable's name to the
+                value the variable takes AFTER this step, same shape and
+                dtype, for state that moves by a rule and not by a
+                gradient (a router's selection bias, a running
+                statistic).  The step writes it after the optimizer's
+                update and takes the entry out of ``metrics["aux"]``.
+                Capture raises where a name is no variable, a value has
+                another shape or dtype, or the loss has a gradient with
+                respect to the variable (it must enter the loss through
+                ``jax.lax.stop_gradient``); it needs ``example_batch``.
+                The GSPMD step and its megastep apply it; the explicit
+                ``shard_map`` step raises NotImplementedError
+                (docs/usage/state-updates.md).
             params: parameter pytree (arrays or ShapeDtypeStructs).
             optimizer: optax GradientTransformation.
             example_batch: example batch pytree; first dim is treated as the
@@ -386,6 +479,8 @@ class GraphItem:
                    type(optimizer).__name__ if optimizer is not None else "",
                    aux_output=aux_output, batch_struct=batch_struct,
                    precision=precision)
+        if traced is not None:
+            item.state_updates = _check_state_updates(traced, variables)
         if example_batch is not None:
             # Detection runs on the UNWRAPPED user program: the bf16 cast
             # would interpose convert_element_type between the param invar

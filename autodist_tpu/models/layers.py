@@ -279,6 +279,142 @@ def mha_decode(p, x, num_heads, k_cache, v_cache, pos, dtype=None):
     return dense(p["out"], o, dtype), k_cache, v_cache
 
 
+# -- latent attention ----------------------------------------------------------
+
+def mla_init(key, dim, heads, q_rank, kv_rank, nope_dim, rope_dim, value_dim):
+    """Parameters of one latent-attention mixer (:func:`mla`): the queries'
+    low-rank path ``q_down`` (dim -> q_rank), ``q_norm``, ``q_up`` (q_rank
+    -> heads x (nope_dim + rope_dim), a head's unrotated part first); the
+    keys' and values' ``kv_down`` (dim -> kv_rank + rope_dim: the latent,
+    then the one rotary key of the position), ``kv_norm`` over the latent,
+    ``kv_up`` (kv_rank -> heads x (nope_dim + value_dim), a head's keys
+    first); ``out`` (heads x value_dim -> dim).  No bias anywhere."""
+    ks = jax.random.split(key, 5)
+    return {
+        "q_down": dense_init(ks[0], dim, q_rank, use_bias=False),
+        "q_norm": rmsnorm_init(q_rank),
+        "q_up": dense_init(ks[1], q_rank, heads * (nope_dim + rope_dim),
+                           use_bias=False),
+        "kv_down": dense_init(ks[2], dim, kv_rank + rope_dim, use_bias=False),
+        "kv_norm": rmsnorm_init(kv_rank),
+        "kv_up": dense_init(ks[3], kv_rank, heads * (nope_dim + value_dim),
+                            use_bias=False),
+        "out": dense_init(ks[4], heads * value_dim, dim, use_bias=False),
+    }
+
+
+def rope_pair_tables(seq_len, rope_dim, theta):
+    """``(cos, sin)``, each (seq, rope_dim / 2) in float32: the angle of
+    pair ``i`` at position ``t`` is ``t * theta^(-2i / rope_dim)``."""
+    inv_freq = 1.0 / theta ** (
+        jnp.arange(0, rope_dim, 2, dtype=jnp.float32) / rope_dim)
+    angles = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * inv_freq
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def apply_rope_pairs(x, tables):
+    """Rotate the adjacent pairs ``(2i, 2i + 1)`` of ``x`` (..., seq,
+    rope_dim) by their positions, as a checkpoint with ``rope_interleave``
+    stores them; float32 arithmetic.  The result holds the pairs' first
+    elements in its lower half and their second in its upper: one fixed
+    permutation of the lanes, the same for queries and keys, which no score
+    sees."""
+    cos, sin = tables
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (-1, 2))
+    first, second = pairs[..., 0], pairs[..., 1]
+    return jnp.concatenate([first * cos - second * sin,
+                            second * cos + first * sin],
+                           axis=-1).astype(x.dtype)
+
+
+_mla_announced = set()
+
+
+def _announce_mla(heads, q_rank, kv_rank, nope_dim, rope_dim, value_dim, path):
+    """Gauges ``mla.*`` and one ``mla`` event a distinct shape and path, at
+    trace time."""
+    from autodist_tpu import observability
+    if not observability.enabled():
+        return
+    registry = observability.registry()
+    registry.gauge("mla.heads").set(heads)
+    registry.gauge("mla.q_rank").set(q_rank)
+    registry.gauge("mla.kv_rank").set(kv_rank)
+    registry.gauge("mla.nope_width").set(nope_dim)
+    registry.gauge("mla.rope_width").set(rope_dim)
+    registry.gauge("mla.value_width").set(value_dim)
+    detail = (f"latent attention: {heads} heads, queries through a latent of "
+              f"{q_rank}, keys and values through one of {kv_rank}; scores "
+              f"{nope_dim} + {rope_dim} wide (the rotary key one a position, "
+              f"shared by the heads), values {value_dim}; core: {path}")
+    if detail not in _mla_announced:
+        _mla_announced.add(detail)
+        observability.record_event("mla", detail)
+
+
+def mla(p, x, heads, nope_dim, rope_dim, value_dim, rope, mask=None,
+        dtype=None, attn_fn=None, norm_eps=1e-6, causal=True):
+    """Latent attention (multi-head, DeepSeek-V2's) over ``x`` (batch, s,
+    dim).
+
+    ``c_q = rmsnorm(W_dq x)``; a head's query is ``W_uq c_q`` split into an
+    unrotated part of ``nope_dim`` and a rotary part of ``rope_dim``;
+    ``[c_kv ; k_r] = W_dkv x``, ``c_kv`` normalised, ``k_r`` the ONE rotary
+    key of the position that every head reads; a head's unrotated key and
+    its value (``value_dim``) are ``W_ukv c_kv``; ``rope``
+    (:func:`rope_pair_tables`) rotates the queries' rotary parts and
+    ``k_r``; the score is the sum of the two products over
+    ``sqrt(nope_dim + rope_dim)``, softmax, values, ``W_o``.
+
+    The core is ``attn_fn.two_product`` where the hook has one (the flash
+    kernels' two-product form: ``k_r`` is never broadcast over the heads
+    and nothing is padded to a common width); the same two products in
+    plain jnp (``flash_attention.two_product_reference``) with no hook or
+    with an explicit ``mask`` (``causal`` is the hook's own where it has
+    one).  The five named scopes are rows of the profiler's table under
+    the block's ``attn``."""
+    b, s, _ = x.shape
+    scale = (nope_dim + rope_dim) ** -0.5
+    two_product = getattr(attn_fn, "two_product", None)
+    if attn_fn is not None and two_product is None:
+        raise NotImplementedError(
+            "latent attention's keys and values differ in width and its "
+            "rotary key is shared by the heads: it needs an attention hook "
+            "with .two_product (ops.flash_attention.make_flash_attn_fn), and "
+            "this one (ring, Ulysses or a caller's own) has none")
+    with jax.named_scope("q_latent"):
+        c_q = rmsnorm(p["q_norm"], dense(p["q_down"], x, dtype), norm_eps)
+        q = dense(p["q_up"], c_q, dtype).reshape(b, s, heads, -1) \
+            .transpose(0, 2, 1, 3)
+        q_nope, q_rope = q[..., :nope_dim], q[..., nope_dim:]
+    with jax.named_scope("kv_latent"):
+        down = dense(p["kv_down"], x, dtype)
+        kv_rank = down.shape[-1] - rope_dim
+        c_kv = rmsnorm(p["kv_norm"], down[..., :kv_rank], norm_eps)
+        k_rope = down[..., kv_rank:]
+        kv = dense(p["kv_up"], c_kv, dtype).reshape(b, s, heads, -1) \
+            .transpose(0, 2, 1, 3)
+        k_nope, v = kv[..., :nope_dim], kv[..., nope_dim:]
+    with jax.named_scope("rope"):
+        q_rope = apply_rope_pairs(q_rope, rope)
+        k_rope = apply_rope_pairs(k_rope, rope)
+    with jax.named_scope("core"):
+        fused = two_product is not None and mask is None
+        _announce_mla(heads, p["q_down"]["kernel"].shape[1], kv_rank,
+                      nope_dim, rope_dim, value_dim,
+                      "the hook's two-product form" if fused
+                      else "the two products in plain jnp")
+        if fused:
+            o = two_product(q_nope, q_rope, k_nope, k_rope, v, scale)
+        else:
+            from autodist_tpu.ops.flash_attention import two_product_reference
+            o = two_product_reference(q_nope, q_rope, k_nope, k_rope, v,
+                                      scale, causal and mask is None, mask)
+    with jax.named_scope("out"):
+        o = o.transpose(0, 2, 1, 3).reshape(b, s, heads * value_dim)
+        return dense(p["out"], o, dtype)
+
+
 # -- gated delta rule (linear attention) ---------------------------------------
 
 def gdn_init(key, dim, heads, key_dim, value_dim, conv_width=4):

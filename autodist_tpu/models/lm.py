@@ -56,6 +56,35 @@ def olmo_hybrid_7b(num_layers=32, vocab=100352, dtype=jnp.bfloat16):
         conv_width=4, allow_neg_eigval=True, norm_position="output")
 
 
+def joyai_llm_flash(num_layers=40, vocab=129280, experts_held=None,
+                    dtype=jnp.bfloat16):
+    """JoyAI-LLM-Flash (jdopensource/JoyAI-LLM-Flash ``config.json``;
+    DeepSeek-V3's layer at another size): width 2,048; latent attention with
+    32 heads, queries through a latent of 1,536, keys and values through
+    one of 512, scores 128 + 64 wide (adjacent-pair rotary, theta
+    32,000,000, one rotary key a position), values 128; the first layer's
+    feed-forward a dense SwiGLU of 7,168, every later one 256 routed SwiGLU
+    experts of 768, 8 a token by sigmoid scores plus a selection bias,
+    weights over their sum times 2.5, beside one shared expert; one
+    multi-token-prediction module; RMSNorm eps 1e-6, no bias, an untied
+    head.  ``experts_held = (first, count)`` is one rank's share of each
+    expert layer (``parallel/moe.py``).  Not in ``config.json``, taken from
+    DeepSeek-V3's report (arXiv:2412.19437): the bias moves 0.001 a step,
+    the sequence-wise balance term enters at 1e-4, the module's loss at
+    0.3."""
+    return T.TransformerConfig(
+        vocab=vocab, dim=2048, num_heads=32, num_layers=num_layers,
+        mlp_dim=7168, max_len=131072, causal=True, dtype=dtype,
+        norm="rmsnorm", norm_eps=1e-6, positions="none",
+        rope_theta=32000000.0, bias=False, tied_head=False, ffn="moe",
+        num_experts=256, experts_per_token=8, expert_dim=768, norm_topk=True,
+        load_balance_coef=1e-4, layer_types=[T.LATENT] * num_layers,
+        expert_scoring="sigmoid", route_scale=2.5, shared_experts=1,
+        select_bias=True, bias_update_rate=0.001, experts_held=experts_held,
+        first_dense=1, q_rank=1536, kv_rank=512, nope_dim=128, rope_dim=64,
+        value_dim=128, mtp_depth=1, mtp_coef=0.3)
+
+
 def init(key, cfg):
     return T.init(key, cfg)
 
@@ -72,32 +101,65 @@ def make_loss_fn(cfg, attn_fn=None):
     ``"linear_attention"`` layers it returns the pair too, ``aux`` holding
     ``gdn.state_absmax``, the largest magnitude of any such layer's final
     state.
+
+    With ``cfg.mtp_depth`` a row holds one token more, the inputs are
+    ``tokens[:-2]``, and the prediction module's cross-entropy to the
+    token after next (``aux["mtp.xent"]``) enters at ``cfg.mtp_coef``.
+    Expert layers with a selection bias put its next value under the
+    reserved ``aux["state_updates"]`` (variable name -> value), which the
+    Runner's step writes (``GraphItem.capture``).
     """
+    ahead = 1 + cfg.mtp_depth
+
     def loss_fn(params, batch):
         (tokens,) = batch if isinstance(batch, (tuple, list)) else (batch,)
-        hidden, stats = T.encode_with_stats(params, cfg, tokens[:, :-1],
+        hidden, stats = T.encode_with_stats(params, cfg, tokens[:, :-ahead],
                                             attn_fn=attn_fn)
         with jax.named_scope("lm_head"):
             lg = T.logits(params, cfg, hidden)
-            xent = L.softmax_xent(lg, tokens[:, 1:])
-        if not stats:
+            xent = L.softmax_xent(lg, tokens[:, 1:tokens.shape[1] - ahead + 1])
+        mtp_xent = None
+        if cfg.mtp_depth:
+            predicted, mtp_stats = T.mtp_hidden(
+                params, cfg, hidden, tokens[:, 1:-1], attn_fn=attn_fn)
+            stats = stats + [mtp_stats] if mtp_stats else stats
+            with jax.named_scope("mtp"), jax.named_scope("lm_head"):
+                mtp_xent = L.softmax_xent(T.logits(params, cfg, predicted),
+                                          tokens[:, 2:])
+        if not stats and mtp_xent is None:
             return xent
 
         def over_layers(name, reduce=jnp.mean):
             return reduce(jnp.stack([s[name] for s in stats if name in s]))
 
+        def reported(name):
+            return any(name in s for s in stats)
+
         aux, loss = {"xent": xent}, xent
+        if mtp_xent is not None:
+            aux["mtp.xent"] = mtp_xent
+            loss = loss + cfg.mtp_coef * mtp_xent
         if cfg.ffn == "moe":
-            aux.update({
-                "moe.load_balance_loss": over_layers("load_balance"),
-                "moe.router_z_loss": over_layers("z_loss"),
-                "moe.load_max_over_mean": over_layers("load_max_over_mean",
-                                                      jnp.max),
-                "moe.dropped": over_layers("dropped", jnp.sum)})
-            loss = xent \
-                + cfg.load_balance_coef * aux["moe.load_balance_loss"] \
-                + cfg.router_z_coef * aux["moe.router_z_loss"]
-        if any("gdn_state_absmax" in s for s in stats):
+            aux["moe.load_balance_loss"] = over_layers("load_balance")
+            if reported("z_loss"):
+                aux["moe.router_z_loss"] = over_layers("z_loss")
+            aux["moe.load_max_over_mean"] = over_layers("load_max_over_mean",
+                                                        jnp.max)
+            aux["moe.dropped"] = over_layers("dropped", jnp.sum)
+            loss = loss + cfg.load_balance_coef * aux["moe.load_balance_loss"]
+            if reported("z_loss"):
+                loss = loss + cfg.router_z_coef * aux["moe.router_z_loss"]
+            if reported("held_assignments"):
+                aux["moe.held_assignments"] = over_layers("held_assignments",
+                                                          jnp.sum)
+            if reported("held_output_rms"):
+                aux["moe.held_output_rms"] = over_layers("held_output_rms")
+            if reported("bias_absmax"):
+                aux["moe.bias_absmax"] = over_layers("bias_absmax", jnp.max)
+                aux["state_updates"] = {
+                    name: value for s in stats
+                    for name, value in s.get("state_updates", {}).items()}
+        if reported("gdn_state_absmax"):
             aux["gdn.state_absmax"] = over_layers("gdn_state_absmax",
                                                   jnp.max)
         return loss, aux

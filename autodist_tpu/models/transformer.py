@@ -19,7 +19,13 @@ current decoder block whose feed-forward is a layer of routed experts
 ``layer_types`` chooses each layer's token mixer: ``"full_attention"``
 (``layer<i>/attn``) or ``"linear_attention"``, the gated delta rule
 (``layers.gdn`` over ``ops/gated_delta.py``, parameters under
-``layer<i>/gdn``); ``norm_position="output"`` normalises each sublayer's
+``layer<i>/gdn``) or ``"latent_attention"`` (``layers.mla``: queries, keys
+and values through low-rank latents, one rotary key a position shared by the
+heads, parameters under ``layer<i>/attn``); ``first_dense`` gives the first
+layers of an ``ffn="moe"`` model a dense SwiGLU MLP; ``mtp_depth=1`` adds the
+multi-token-prediction module (``mtp/...``, :func:`mtp_hidden`), one more
+block that predicts the token after next through the same embedding and head;
+``norm_position="output"`` normalises each sublayer's
 output before the residual add (the Olmo 2 order) where the default
 normalises its input; ``ffn="swiglu"`` is the dense gated MLP
 (``mlp/{gate,up,down}``); ``positions="none"`` gives attention no positions
@@ -42,7 +48,11 @@ class TransformerConfig:
                  expert_dim=None, norm_topk=True, load_balance_coef=0.0,
                  router_z_coef=0.0, layer_types=None, linear_heads=0,
                  linear_key_dim=0, linear_value_dim=0, conv_width=4,
-                 allow_neg_eigval=True, norm_position="pre"):
+                 allow_neg_eigval=True, norm_position="pre",
+                 expert_scoring="softmax", route_scale=1.0, shared_experts=0,
+                 select_bias=False, bias_update_rate=0.0, experts_held=None,
+                 first_dense=0, q_rank=0, kv_rank=0, nope_dim=0, rope_dim=0,
+                 value_dim=0, mtp_depth=0, mtp_coef=0.0):
         for name, value, known in (("norm", norm, ("layernorm", "rmsnorm")),
                                    ("positions", positions,
                                     ("learned", "rope", "none")),
@@ -84,7 +94,26 @@ class TransformerConfig:
             self.moe = moe.MoEConfig(
                 num_experts=num_experts, top_k=experts_per_token,
                 d_model=dim, d_hidden=expert_dim or self.mlp_dim,
-                dtype=dtype, expert="swiglu", norm_topk=norm_topk)
+                dtype=dtype, expert="swiglu", norm_topk=norm_topk,
+                scoring=expert_scoring, route_scale=route_scale,
+                shared=shared_experts, select_bias=select_bias,
+                bias_update_rate=bias_update_rate, held=experts_held)
+        # The first ``first_dense`` layers of an ffn="moe" model keep a dense
+        # SwiGLU MLP of ``mlp_dim``.
+        self.first_dense = first_dense
+        # Latent attention (layer type "latent_attention"): the ranks of the
+        # two latents, a head's unrotated and rotary score widths, its
+        # value's width; rotary pairs are adjacent, ``rope_theta`` theirs.
+        self.q_rank, self.kv_rank = q_rank, kv_rank
+        self.nope_dim, self.rope_dim, self.value_dim = (nope_dim, rope_dim,
+                                                        value_dim)
+        # The multi-token-prediction module: 0 or 1 block deep; its loss
+        # enters at ``mtp_coef``.
+        if mtp_depth not in (0, 1):
+            raise NotImplementedError(
+                f"mtp_depth {mtp_depth}: the prediction module is one block "
+                f"deep or absent")
+        self.mtp_depth, self.mtp_coef = mtp_depth, mtp_coef
         # The token mixer of each layer; None is full attention throughout.
         # A linear layer holds ``linear_heads`` states of ``linear_key_dim``
         # x ``linear_value_dim`` and convolves q, k and v over
@@ -112,12 +141,34 @@ class TransformerConfig:
                 raise ValueError(
                     "a 'linear_attention' layer needs linear_heads, "
                     "linear_key_dim and linear_value_dim")
+            if LATENT in self.layer_types and not (
+                    q_rank and kv_rank and nope_dim and rope_dim
+                    and value_dim):
+                raise ValueError(
+                    "a 'latent_attention' layer needs q_rank, kv_rank, "
+                    "nope_dim, rope_dim and value_dim")
+            if scan_layers and LATENT in self.layer_types:
+                raise NotImplementedError(
+                    "scan_layers stacks the default block; build a "
+                    "configuration with 'latent_attention' layers with "
+                    "scan_layers=False")
+        if (first_dense or mtp_depth) and scan_layers:
+            raise NotImplementedError(
+                "scan_layers stacks one kind of block: first_dense and "
+                "mtp_depth need scan_layers=False")
 
     def layer_type(self, i):
         return FULL if self.layer_types is None else self.layer_types[i]
 
+    def layer_ffn(self, i):
+        """The feed-forward of layer ``i``: ``ffn``, but a dense SwiGLU MLP
+        in the ``first_dense`` layers of an expert model."""
+        return "swiglu" if self.ffn == "moe" and i < self.first_dense \
+            else self.ffn
 
-FULL, LINEAR = LAYER_TYPES = ("full_attention", "linear_attention")
+
+FULL, LINEAR, LATENT = LAYER_TYPES = ("full_attention", "linear_attention",
+                                      "latent_attention")
 
 
 def _norm_init(cfg):
@@ -130,26 +181,33 @@ def _norm(cfg, p, x):
         else L.layernorm(p, x, cfg.norm_eps)
 
 
-def block_init(key, cfg, layer_type=FULL):
+def block_init(key, cfg, layer_type=FULL, ffn=None):
     """One block's parameters; ``layer_type`` decides whether it holds
-    ``attn`` or ``gdn``.  ``ln1`` and ``ln2`` are the norms of the mixer's
-    and the feed-forward's sublayer, wherever ``norm_position`` puts them."""
+    ``attn`` (full or latent attention) or ``gdn``, ``ffn`` (the
+    configuration's where None) whether ``moe`` or ``mlp``.  ``ln1`` and
+    ``ln2`` are the norms of the mixer's and the feed-forward's sublayer,
+    wherever ``norm_position`` puts them."""
+    ffn = ffn or cfg.ffn
     k1, k2, k3 = jax.random.split(key, 3)
     p = {"ln1": _norm_init(cfg)}
     if layer_type == LINEAR:
         p["gdn"] = L.gdn_init(k1, cfg.dim, cfg.linear_heads,
                               cfg.linear_key_dim, cfg.linear_value_dim,
                               cfg.conv_width)
+    elif layer_type == LATENT:
+        p["attn"] = L.mla_init(k1, cfg.dim, cfg.num_heads, cfg.q_rank,
+                               cfg.kv_rank, cfg.nope_dim, cfg.rope_dim,
+                               cfg.value_dim)
     else:
         p["attn"] = L.mha_init(k1, cfg.dim, cfg.num_heads, cfg.bias,
                                cfg.qk_norm)
     p["ln2"] = _norm_init(cfg)
-    if cfg.ffn == "moe":
+    if ffn == "moe":
         p["moe"] = moe.init(k2, cfg.moe)
     else:
         p["mlp"] = {"up": L.dense_init(k2, cfg.dim, cfg.mlp_dim, cfg.bias),
                     "down": L.dense_init(k3, cfg.mlp_dim, cfg.dim, cfg.bias)}
-        if cfg.ffn == "swiglu":
+        if ffn == "swiglu":
             p["mlp"]["gate"] = L.dense_init(jax.random.fold_in(k2, 1),
                                             cfg.dim, cfg.mlp_dim, cfg.bias)
     return p
@@ -171,7 +229,8 @@ def block_apply(p, x, cfg, mask=None, attn_fn=None, rope=None):
     report from inside the step (the expert layer's
     ``moe.dropless_apply`` statistics, a linear layer's
     ``gdn_state_absmax``) and None where they report nothing.  The
-    parameters say which mixer the layer holds."""
+    parameters say which mixer and which feed-forward the layer holds;
+    ``rope`` are the tables of the layer's kind (:func:`_rope_tables`)."""
     # attn/gdn/mlp scopes nest under the caller's layer scope, mirroring the
     # param paths ("layer<i>/attn/...") for the per-layer profiler.
     if "gdn" in p:
@@ -181,6 +240,12 @@ def block_apply(p, x, cfg, mask=None, attn_fn=None, rope=None):
                              norm_eps=cfg.norm_eps)
             return y, {"gdn_state_absmax": jnp.max(jnp.abs(
                 jax.lax.stop_gradient(state)))}
+    elif "q_down" in p["attn"]:
+        def mixer(h):
+            return L.mla(p["attn"], h, cfg.num_heads, cfg.nope_dim,
+                         cfg.rope_dim, cfg.value_dim, rope, mask=mask,
+                         dtype=cfg.dtype, attn_fn=attn_fn,
+                         norm_eps=cfg.norm_eps, causal=cfg.causal), None
     else:
         def mixer(h):
             return L.mha(p["attn"], h, cfg.num_heads, mask=mask,
@@ -190,15 +255,15 @@ def block_apply(p, x, cfg, mask=None, attn_fn=None, rope=None):
         x, mixed = _residual(cfg, p["ln1"], x, mixer)
 
     def ffn(h):
-        if cfg.ffn == "moe":
+        if "moe" in p:
             return moe.dropless_apply(p["moe"], cfg.moe, h)
         up = L.dense(p["mlp"]["up"], h, cfg.dtype)
-        if cfg.ffn == "swiglu":
+        if "gate" in p["mlp"]:
             h = jax.nn.silu(L.dense(p["mlp"]["gate"], h, cfg.dtype)) * up
         else:
             h = jax.nn.gelu(up)
         return L.dense(p["mlp"]["down"], h, cfg.dtype), None
-    with jax.named_scope("moe" if cfg.ffn == "moe" else "mlp"):
+    with jax.named_scope("moe" if "moe" in p else "mlp"):
         x, fed = _residual(cfg, p["ln2"], x, ffn)
     return x, {**(mixed or {}), **(fed or {})} or None
 
@@ -224,8 +289,61 @@ def init(key, cfg):
     else:
         for i in range(cfg.num_layers):
             params[f"layer{i}"] = block_init(keys[3 + i], cfg,
-                                             cfg.layer_type(i))
+                                             cfg.layer_type(i),
+                                             cfg.layer_ffn(i))
+    if cfg.mtp_depth:
+        # Its own projection, norms and block; embedding and head are the
+        # model's, used a second time.
+        k1, k2 = jax.random.split(jax.random.fold_in(key, cfg.num_layers))
+        params["mtp"] = {
+            "embed_norm": _norm_init(cfg), "hidden_norm": _norm_init(cfg),
+            "proj": L.dense_init(k1, 2 * cfg.dim, cfg.dim, use_bias=False),
+            "block": block_init(k2, cfg, cfg.layer_type(cfg.num_layers - 1),
+                                cfg.ffn),
+            "ln_f": _norm_init(cfg)}
     return params
+
+
+def _rope_tables(cfg, s):
+    """``{layer type: tables}`` for the kinds of attention the model holds:
+    rotate-half tables of a head's width for full attention under
+    ``positions="rope"``, adjacent-pair tables of ``rope_dim`` for latent
+    attention; a type with no rotation is absent."""
+    tables = {}
+    if cfg.positions == "rope":
+        tables[FULL] = L.rope_tables(s, cfg.dim // cfg.num_heads,
+                                     cfg.rope_theta)
+    if cfg.layer_types is not None and LATENT in cfg.layer_types:
+        tables[LATENT] = L.rope_pair_tables(s, cfg.rope_dim, cfg.rope_theta)
+    return tables
+
+
+def _resolve_attn(cfg, s, attn_fn):
+    """``(attn_fn, mask)`` for a model's blocks (:func:`encode_with_stats`)."""
+    if attn_fn is None:
+        # Strategy-provided attention first (SequenceParallel sets ring/
+        # ulysses through the parallel context at trace time); otherwise the
+        # default encodes causality positionally (no mask tensor).
+        from autodist_tpu.parallel.context import resolve_attn
+        attn_fn = resolve_attn(causal=cfg.causal)
+        if attn_fn is None:
+            from autodist_tpu.ops.flash_attention import make_flash_attn_fn
+            attn_fn = make_flash_attn_fn(causal=cfg.causal)
+        return attn_fn, None
+    # Explicit attn_fns keep the documented mha contract: they receive
+    # the boolean mask (and may ignore it if causality is positional).
+    return attn_fn, L.causal_mask(s) if cfg.causal else None
+
+
+def _named_updates(stats, prefix):
+    """A layer's ``stats`` with its ``state_updates`` keyed by the variables'
+    full names: ``bias`` of the expert layer under ``prefix`` becomes
+    ``<prefix>/moe/bias``."""
+    if stats and "state_updates" in stats:
+        stats = dict(stats, state_updates={
+            f"{prefix}/moe/{name}": value
+            for name, value in stats["state_updates"].items()})
+    return stats
 
 
 def encode(params, cfg, ids, segment_ids=None, attn_fn=None):
@@ -249,22 +367,8 @@ def encode_with_stats(params, cfg, ids, segment_ids=None, attn_fn=None):
         if cfg.num_segments and segment_ids is not None:
             x = x + params["seg_embed"][segment_ids]
         x = x.astype(cfg.dtype)
-    if attn_fn is None:
-        # Strategy-provided attention first (SequenceParallel sets ring/
-        # ulysses through the parallel context at trace time); otherwise the
-        # default encodes causality positionally (no mask tensor).
-        from autodist_tpu.parallel.context import resolve_attn
-        attn_fn = resolve_attn(causal=cfg.causal)
-        if attn_fn is None:
-            from autodist_tpu.ops.flash_attention import make_flash_attn_fn
-            attn_fn = make_flash_attn_fn(causal=cfg.causal)
-        mask = None
-    else:
-        # Explicit attn_fns keep the documented mha contract: they receive
-        # the boolean mask (and may ignore it if causality is positional).
-        mask = L.causal_mask(s) if cfg.causal else None
-    rope = L.rope_tables(s, cfg.dim // cfg.num_heads, cfg.rope_theta) \
-        if cfg.positions == "rope" else None
+    attn_fn, mask = _resolve_attn(cfg, s, attn_fn)
+    rope = _rope_tables(cfg, s)
     stats = []
     if cfg.scan_layers:
         from autodist_tpu.ops import scan_blocks
@@ -272,17 +376,50 @@ def encode_with_stats(params, cfg, ids, segment_ids=None, attn_fn=None):
             x = scan_blocks(params["blocks"],
                             lambda bp, a: block_apply(
                                 bp, a, cfg, mask=mask, attn_fn=attn_fn,
-                                rope=rope)[0], x)
+                                rope=rope.get(FULL))[0], x)
     else:
         for i in range(cfg.num_layers):
             with jax.named_scope(f"layer{i}"):
                 x, layer_stats = block_apply(
                     params[f"layer{i}"], x, cfg, mask=mask, attn_fn=attn_fn,
-                    rope=rope)
+                    rope=rope.get(cfg.layer_type(i)))
             if layer_stats is not None:
-                stats.append(layer_stats)
+                stats.append(_named_updates(layer_stats, f"layer{i}"))
     with jax.named_scope("ln_f"):
         return _norm(cfg, params["ln_f"], x), stats
+
+
+def mtp_hidden(params, cfg, hidden, next_ids, attn_fn=None):
+    """The multi-token-prediction module (DeepSeek-V3's, one block deep):
+    ``(hidden states that predict the token after next, the block's
+    stats)``.  ``hidden`` are the main model's final hidden states of
+    positions ``0..s-1`` (after its last norm) and ``next_ids`` the tokens
+    ``1..s``: ``z_i = W_p [norm_e(Emb(t_(i+1))) ; norm_h(hidden_i)]``, one
+    block of the model's last layer's kind on ``z``, the module's own final
+    norm; the caller applies the model's head.  Everything runs under the
+    scope ``mtp``, whose inner scopes the profiler folds into the generic
+    ones (``mtp/block/attn`` is ``attn``); the gauge ``mtp.depth`` is set
+    where it is traced."""
+    from autodist_tpu import observability
+    if observability.enabled():     # at trace time, as the layers' gauges
+        observability.registry().gauge("mtp.depth").set(cfg.mtp_depth)
+    p, s = params["mtp"], next_ids.shape[1]
+    attn_fn, mask = _resolve_attn(cfg, s, attn_fn)
+    kind = cfg.layer_type(cfg.num_layers - 1)
+    with jax.named_scope("mtp"):
+        with jax.named_scope("embed"):
+            e = L.embed(params["embed"], next_ids).astype(cfg.dtype)
+        with jax.named_scope("proj"):
+            z = L.dense(p["proj"], jnp.concatenate(
+                [_norm(cfg, p["embed_norm"], e),
+                 _norm(cfg, p["hidden_norm"], hidden)], axis=-1), cfg.dtype)
+        with jax.named_scope("block"):
+            z, stats = block_apply(p["block"], z, cfg, mask=mask,
+                                   attn_fn=attn_fn,
+                                   rope=_rope_tables(cfg, s).get(kind))
+        with jax.named_scope("ln_f"):
+            return _norm(cfg, p["ln_f"], z), _named_updates(stats,
+                                                            "mtp/block")
 
 
 def logits(params, cfg, hidden):
@@ -299,15 +436,16 @@ def logits(params, cfg, hidden):
 
 def _decodable(cfg):
     block = (cfg.norm, cfg.positions, cfg.ffn, cfg.qk_norm, cfg.bias,
-             cfg.tied_head, cfg.norm_position, cfg.layer_types)
+             cfg.tied_head, cfg.norm_position, cfg.layer_types,
+             cfg.mtp_depth)
     if block != ("layernorm", "learned", "mlp", False, True, True, "pre",
-                 None):
+                 None, 0):
         raise NotImplementedError(
             "decoding is implemented for the default block only (LayerNorm, "
             "learned positions, biased projections, an MLP, a tied head); "
-            "through rope, QK-norm, moe, output norms or linear-attention "
-            "layers (recurrent state beside a KV cache) it waits for "
-            "ROADMAP R2")
+            "through rope, QK-norm, moe, output norms, linear-attention "
+            "layers (recurrent state beside a KV cache) or latent attention "
+            "(a latent cache) it waits for ROADMAP R2")
 
 
 def init_cache(cfg, slots, cache_len, dtype=None):

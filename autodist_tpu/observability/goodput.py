@@ -111,6 +111,7 @@ EVENT_CLASS = {
     "goodput": None,
     "mesh-built": "startup_ms",
     "memory": None,
+    "mla": None,
     "moe": None,
     "monitor-start": None,
     "oom": None,

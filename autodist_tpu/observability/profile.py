@@ -240,6 +240,13 @@ MOE_SCOPE = "moe"
 #: (likewise ``gdn/proj``, ``gdn/conv``, ``gdn/gates``, ``gdn/out``; the
 #: norm of the mixer's output, in none of them, is ``gdn``).
 GDN_SCOPE = "gdn"
+#: The multi-token-prediction module runs under the top-level scope ``mtp``
+#: and its block's, its head's and its loss's scopes fold into the generic
+#: rows with the model's own (``mtp/block/attn`` -> ``attn``,
+#: ``mtp/block/moe/router`` -> ``moe/router``, ``mtp/lm_head`` -> ``head``);
+#: what is the module's alone keeps its path (``mtp/proj``).
+#: :func:`overlay_table` gives the module's total beside those rows.
+MTP_SCOPE = "mtp"
 
 _INSTRUCTION_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
 
@@ -252,6 +259,8 @@ def _scope_and_phase(op_name):
     scope = UNATTRIBUTED
     if segs:
         scope = collapse("/".join(segs))
+        if segs[0] == MTP_SCOPE:
+            segs = segs[1:] or segs
         if segs[0] in HEAD_SCOPES:
             scope = "head"
         elif len(segs) > 1 and segs[1] in BLOCK_SCOPES:
@@ -275,12 +284,13 @@ _COMPUTATION_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
 _CALLS_RE = re.compile(r"\bcalls=%?([\w.\-]+)")
 
 
-def _parse_scopes(hlo_text):
+def _parse_scopes(hlo_text, place=_scope_and_phase):
     """One pass over a compiled program's text: ``own`` maps every
     instruction to the ``(scope, phase)`` of its own ``op_name`` (None
     without one), ``calls`` maps a fusion to the computation it calls, and
     ``votes`` counts, per computation, the ``(scope, phase)`` of its
-    instructions that carry a named scope."""
+    instructions that carry a named scope.  ``place`` is what reads an
+    ``op_name``."""
     own, calls, votes = {}, {}, {}
     computation = None
     for line in hlo_text.splitlines():
@@ -292,7 +302,7 @@ def _parse_scopes(hlo_text):
         if m is None:
             continue
         op = _OP_NAME_RE.search(line)
-        placed = _scope_and_phase(op.group(1)) if op else None
+        placed = place(op.group(1)) if op else None
         own[m.group(1)] = placed
         called = _CALLS_RE.search(line)
         if called:
@@ -327,7 +337,12 @@ def scope_table(hlo_text):
     of the user's scopes in it, is :data:`UNATTRIBUTED` — surfaced, never
     absorbed.
     """
-    own, calls, votes = _parse_scopes(hlo_text)
+    return _voted(*_parse_scopes(hlo_text))
+
+
+def _voted(own, calls, votes):
+    """:func:`scope_table`'s placement: an instruction's own reading, a
+    fusion's by the vote of what it fused."""
     table = {}
     for name, placed in own.items():
         tally = votes.get(calls.get(name), {})
@@ -337,6 +352,24 @@ def scope_table(hlo_text):
             placed = placed if placed in winners else winners[0]
         table[name] = placed or (UNATTRIBUTED, UNATTRIBUTED)
     return table
+
+
+def overlay_table(hlo_text, top_scope):
+    """``{instruction name: (where, phase)}`` with ``where`` one of
+    ``top_scope``, ``"elsewhere"`` and :data:`UNATTRIBUTED`: which
+    instructions were traced under the top-level named scope ``top_scope``
+    whatever :func:`scope_table` folds them into, a fusion again by the
+    vote of what it fused.  Joined with a trace
+    (:func:`device_time_by_scope`) it gives the module's whole time as an
+    overlay on the generic rows (``mtp``: the prediction module)."""
+    from autodist_tpu.graph_item import scope_path
+
+    def place(op_name):
+        segs = scope_path(op_name).split("/")[:-1]
+        where = UNATTRIBUTED if not segs else \
+            top_scope if segs[0] == top_scope else "elsewhere"
+        return where, _scope_and_phase(op_name)[1]
+    return _voted(*_parse_scopes(hlo_text, place))
 
 
 def mixed_fusions(hlo_text):

@@ -65,6 +65,19 @@ with a leading rows axis (``_for_rows``).  A row's results do not depend on
 kernels' o, dq, dk, dv at d = 64 are bit for bit the split ones' (PERF.md,
 PR 28).
 
+**The two-product form** (``flash_attention_two_product``; latent attention,
+``models.layers.mla``).  The same three kernels take a score that is the sum
+of two products, ``(q . k + q_rope . k_rope) * scale`` with the scale given:
+the second over a width of its own against ONE key a position that all heads
+of a batch row read (its block's index is the program's batch row, a
+``lax.div`` of the grid's), and values, an accumulator and a result of the
+values' own width.  Operands stay apart in HBM as the projections leave them:
+nothing is concatenated to a common key, broadcast over the heads or padded.
+The bodies read the two extra operands where a ``scale`` is passed and are the
+one-product bodies, instruction for instruction, where it is not.  Split
+layout, one (batch, head) row a program; the dk/dv kernel writes each head's
+part of the shared key's gradient and XLA sums the heads.
+
 Off TPU the dense jnp path runs instead (CPU tests use ``interpret=True``
 to exercise the kernels in the Pallas interpreter); every trace logs once,
 at info, which path it took and why.
@@ -240,10 +253,12 @@ class _Layout:
     def stat(self, x):
         return x if self.packed else x.reshape(self.units, x.shape[2], 1)
 
-    def shape(self, length, stat=False):
-        """A result of ``length`` positions, as the kernels write it."""
+    def shape(self, length, stat=False, width=None):
+        """A result of ``length`` positions, as the kernels write it;
+        ``width`` lanes where the result has a width of its own (the split
+        layout's two-product form: values, the rotary part)."""
         if not self.packed:
-            return self.units, length, 1 if stat else self.d
+            return self.units, length, 1 if stat else width or self.d
         if stat:
             return self.batch, self.num_heads, length, 1
         return self.batch, length, self.num_heads * self.d
@@ -261,10 +276,20 @@ class _Layout:
             return (self.heads, length, 1) if self.packed else (length, 1)
         return length, self.lanes
 
-    def spec(self, n, length, seq, stat=False):
+    def spec(self, n, length, seq, stat=False, width=None, per_batch=False):
         """BlockSpec of ``n`` units' blocks whose position along the sequence
-        is the grid's index number ``seq`` (1 or 2)."""
+        is the grid's index number ``seq`` (1 or 2).  ``width`` is an
+        operand's own lanes where they are not ``d`` (split layout only);
+        with ``per_batch`` the operand holds one row a batch row, which all
+        of that row's heads read (one unit a program)."""
         across = self.lane_blocks
+        if width is not None:
+            heads = self.num_heads
+
+            def own(*grid):
+                i = jax.lax.div(grid[0], heads) if per_batch else grid[0]
+                return i, grid[seq], 0
+            return pl.BlockSpec((n, length, width), own)
 
         def index(*grid):
             i, j = grid[0], grid[seq]
@@ -422,14 +447,18 @@ def _scratch(layout, n, shape):
 
 @functools.partial(jax.jit, inline=True, static_argnames=(
     "name", "body", "layout", "n", "grid_tail", "ins", "outs", "scratch",
-    "block_q", "block_k", "causal", "interpret"))
+    "block_q", "block_k", "causal", "interpret", "scale"))
 def _kernel_call(offs, *arrays, name, body, layout, n, grid_tail, ins, outs,
-                 scratch, block_q, block_k, causal, interpret):
+                 scratch, block_q, block_k, causal, interpret, scale=None):
     """One kernel over ``arrays`` in the kernels' own shapes, ``n`` units a
     program.  ``ins`` give each array's block as ``(length, which of the
     grid's indices places it along the sequence, whether it is a row
     statistic)``, ``outs`` each result's the same way and then ``(dtype,
-    whole length)``, ``scratch`` the f32 accumulators' shapes a unit.
+    whole length)``, ``scratch`` the f32 accumulators' shapes a unit; an
+    entry of either may end in the lanes of an operand whose width is its
+    own and, for an input, whether a batch row's heads share it
+    (``_Layout.spec``).  ``scale`` is given for the two-product form, whose
+    bodies read two more operands (``_two_product``).
 
     An inlined ``jit``: a model's layers make the same call, and every one
     after the first takes the first's equations from the cache, the kernel's
@@ -437,18 +466,19 @@ def _kernel_call(offs, *arrays, name, body, layout, n, grid_tail, ins, outs,
     the benchmark's process spends 5-10 times a clean process's time on
     tracing (PERF.md section 7), and a step's attention is most of a model's
     traced operations."""
+    form = {} if scale is None else {"scale": scale}
     return pl.pallas_call(
         functools.partial(body, d=layout.d, block_q=block_q, block_k=block_k,
-                          causal=causal, skip_blocks=not interpret),
+                          causal=causal, skip_blocks=not interpret, **form),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(layout.units // n * layout.lane_blocks,) + grid_tail,
             in_specs=[layout.spec(n, *block) for block in ins],
-            out_specs=[layout.spec(n, *out[:3]) for out in outs],
+            out_specs=[layout.spec(n, *out[:3], *out[5:]) for out in outs],
             scratch_shapes=[_scratch(layout, n, shape) for shape in scratch],
         ),
-        out_shape=[_sds(layout.shape(length, stat), dtype, offs, *arrays)
-                   for _, _, stat, dtype, length in outs],
+        out_shape=[_sds(layout.shape(out[4], out[2], *out[5:]), out[3], offs,
+                        *arrays) for out in outs],
         # Programs of the first two dimensions are independent; only the
         # innermost carries the accumulators.
         compiler_params=pltpu.CompilerParams(
@@ -515,16 +545,33 @@ def _dense_bwd(q, k, v, do, lse, delta, causal, q_offset=0, k_offset=0):
 # forward kernel
 
 
-def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l, *,
-                d, block_q, block_k, causal, skip_blocks):
+def _two_product(refs, n_in, scale):
+    """``(refs as the one-product body names them, q_rope ref, k_rope ref)``.
+
+    With ``scale`` given a kernel is in its two-product form: the score is
+    ``(q . k + q_rope . k_rope) * scale``, the second product over a width
+    of its own against a key that one batch row's heads share (latent
+    attention's rotary part), and the values' width is v's own.  Its two
+    operands follow the ``n_in`` of the one-product form; the program is one
+    (batch, head) row, so ``k_rope``'s block is that row's batch row."""
+    if scale is None:
+        return refs, None, None
+    return refs[:n_in] + refs[n_in + 2:], refs[n_in], refs[n_in + 1]
+
+
+def _fwd_kernel(offs_ref, *refs, d, block_q, block_k, causal, skip_blocks,
+                scale=None):
     """Grid (units / rows a program x lane blocks, q-blocks, k-blocks): k
     innermost, accumulators in VMEM scratch carried across the k dimension,
     each of a program's rows with its own; ``d`` lanes a head."""
+    (q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l), qr_ref, kr_ref = \
+        _two_product(refs, 3, scale)
     rows = q_ref.shape[0]
     iq = pl.program_id(1)
     ik = pl.program_id(2)
     num_kb = pl.num_programs(2)
-    scale = 1.0 / math.sqrt(d)
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
 
     @pl.when(ik == 0)
     def _init():
@@ -552,7 +599,10 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l, *,
             v = v_ref[at]
             keep = _lanes_of(head, d, q)
             q, v = _only(keep, q), _only(keep, v)
-            s = _dot(q, k, -1, -1) * scale
+            s = _dot(q, k, -1, -1)
+            if qr_ref is not None:
+                s = s + _dot(qr_ref[at], kr_ref[0], -1, -1)
+            s = s * scale
             if causal:
                 s = s + causal_bias(block_q, block_k, q_start, k_start)
             m_prev = m[stat]
@@ -633,18 +683,27 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, k_offset,
 # backward kernels (FlashAttention-2: dq over K blocks, dk/dv over Q blocks)
 
 
-def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, dq_acc, *, d, block_q, block_k, causal,
-                   skip_blocks):
+def _bwd_dq_kernel(offs_ref, *refs, d, block_q, block_k, causal, skip_blocks,
+                   scale=None):
+    refs, qr_ref, kr_ref = _two_product(refs, 6, scale)
+    if qr_ref is None:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+         dq_acc) = refs
+    else:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dqr_ref,
+         dq_acc, dqr_acc) = refs
     rows = q_ref.shape[0]
     iq = pl.program_id(1)
     ik = pl.program_id(2)
     num_kb = pl.num_programs(2)
-    scale = 1.0 / math.sqrt(d)
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
 
     @pl.when(ik == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
+        if qr_ref is not None:
+            dqr_acc[:] = jnp.zeros_like(dqr_acc)
 
     q_start = offs_ref[0] + iq * block_q
     k_start = offs_ref[1] + ik * block_k
@@ -661,33 +720,50 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             do = do_ref[at]
             keep = _lanes_of(head, d, q)
             k, v = _only(keep, k), _only(keep, v)
-            s = _dot(q, k, -1, -1) * scale
+            s = _dot(q, k, -1, -1)
+            if qr_ref is not None:
+                kr = kr_ref[0]
+                s = s + _dot(qr_ref[at], kr, -1, -1)
+            s = s * scale
             if causal:
                 s = s + causal_bias(block_q, block_k, q_start, k_start)
             p = jnp.where(s > _NEG_INF / 2, jnp.exp(s - lse_ref[stat]), 0.0)
             dp = _dot(do, v, -1, -1)
             ds = p * (dp - delta_ref[stat]) * scale
             dq_acc[_row_of(dq_acc, at)] += _dot(ds.astype(k.dtype), k, -1, -2)
+            if qr_ref is not None:
+                dqr_acc[:] += _dot(ds.astype(kr.dtype), kr, -1, -2)
         _for_rows(rows, q_ref.shape[-1] // d, block_q * block_k, _rows)
 
     @pl.when(ik == num_kb - 1)
     def _finalize():
         dq_ref[_all_rows(dq_acc)] = dq_acc[:].astype(dq_ref.dtype)
+        if qr_ref is not None:
+            dqr_ref[0] = dqr_acc[:].astype(dqr_ref.dtype)
 
 
-def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, d, block_q, block_k,
-                    causal, skip_blocks):
+def _bwd_dkv_kernel(offs_ref, *refs, d, block_q, block_k, causal,
+                    skip_blocks, scale=None):
+    refs, qr_ref, kr_ref = _two_product(refs, 6, scale)
+    if qr_ref is None:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+         dk_acc, dv_acc) = refs
+    else:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+         dkr_ref, dk_acc, dv_acc, dkr_acc) = refs
     rows = q_ref.shape[0]
     ik = pl.program_id(1)
     iq = pl.program_id(2)
     num_qb = pl.num_programs(2)
-    scale = 1.0 / math.sqrt(d)
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
 
     @pl.when(iq == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+        if qr_ref is not None:
+            dkr_acc[:] = jnp.zeros_like(dkr_acc)
 
     q_start = offs_ref[0] + iq * block_q
     k_start = offs_ref[1] + ik * block_k
@@ -704,7 +780,11 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             do = do_ref[at]
             keep = _lanes_of(head, d, q)
             q, do = _only(keep, q), _only(keep, do)
-            s = _dot(q, k, -1, -1) * scale
+            s = _dot(q, k, -1, -1)
+            if qr_ref is not None:
+                qr = qr_ref[at]
+                s = s + _dot(qr, kr_ref[0], -1, -1)
+            s = s * scale
             if causal:
                 s = s + causal_bias(block_q, block_k, q_start, k_start)
             p = jnp.where(s > _NEG_INF / 2, jnp.exp(s - lse_ref[stat]), 0.0)
@@ -712,6 +792,8 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dp = _dot(do, v, -1, -1)
             ds = p * (dp - delta_ref[stat]) * scale
             dk_acc[row] += _dot(ds.astype(q.dtype), q, -2, -2)     # ds^T q
+            if qr_ref is not None:      # this head's part of the shared key's
+                dkr_acc[:] += _dot(ds.astype(qr.dtype), qr, -2, -2)
         _for_rows(rows, q_ref.shape[-1] // d, block_q * block_k, _rows)
 
     @pl.when(iq == num_qb - 1)
@@ -719,6 +801,8 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         every = _all_rows(dk_acc)
         dk_ref[every] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[every] = dv_acc[:].astype(dv_ref.dtype)
+        if qr_ref is not None:
+            dkr_ref[0] = dkr_acc[:].astype(dkr_ref.dtype)
 
 
 def _flash_bwd(q, k, v, do, lse, delta, causal, block_q, block_k, q_offset,
@@ -766,6 +850,196 @@ def _flash_bwd(q, k, v, do, lse, delta, causal, block_q, block_k, q_offset,
     dk, dv = call("flash_bwd_dkv", _bwd_dkv_kernel,
                   (sk // block_k, sq // block_q), 2, 1, k_block, sk, 2)
     return layout.result(dq), layout.result(dk), layout.result(dv)
+
+
+# ---------------------------------------------------------------------------
+# the two-product form (latent attention: a rotary key shared by the heads)
+
+
+def two_product_reference(q, q_rope, k, k_rope, v, scale, causal=True,
+                          mask=None):
+    """The two-product attention in plain jnp, f32 statistics:
+    ``softmax((q . k + q_rope . k_rope) * scale [+ causal mask]) v`` with
+    ``q``, ``k`` (batch, heads, s, d), ``q_rope`` (batch, heads, s, r),
+    ``k_rope`` (batch, s, r), one key a position for every head, and ``v``
+    (batch, heads, s, value width); ``mask`` (True where a key is seen,
+    broadcast against (batch, heads, q, k)) hides keys besides.  The path
+    off the TPU, and the kernels' oracle; differentiated by autodiff."""
+    s = (jnp.einsum("bhqd,bhkd->bhqk", q, k)
+         + jnp.einsum("bhqr,bkr->bhqk", q_rope, k_rope)).astype(jnp.float32)
+    s = s * scale
+    if causal:
+        s = s + causal_bias(q.shape[2], k.shape[2])
+    if mask is not None:
+        s = jnp.where(mask, s, jnp.finfo(jnp.float32).min)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p,
+                      v.astype(jnp.float32)).astype(q.dtype)
+
+
+def _two_product_plan(q, q_rope, k, k_rope, v, block_q, block_k):
+    """What both passes of the two-product form share: the split layout of
+    ``q``, the kernels' arrays, the blocks, and each operand's block entry
+    (``_kernel_call``'s ``ins``) by the grid index that places it."""
+    layout = _Layout.of(q, packed=False)
+    arrays = tuple(x.reshape(layout.units, x.shape[2], x.shape[3])
+                   for x in (q, q_rope, k, v)) + (k_rope,)
+    sq, sk = q.shape[2], k.shape[2]
+    block_q, block_k = _blocks(sq, sk, block_q, block_k)
+    r, dv = q_rope.shape[-1], v.shape[-1]
+
+    def ins(at_q, at_k):
+        """Blocks of q, k, v and of q_rope, k_rope, q's following the grid's
+        index ``at_q`` and k's ``at_k``."""
+        return ((block_q, at_q, False), (block_k, at_k, False),
+                (block_k, at_k, False, dv)), \
+            ((block_q, at_q, False, r), (block_k, at_k, False, r, True))
+    return layout, arrays, (sq, sk), (block_q, block_k), (r, dv), ins
+
+
+def _announce_two_product(kernel, layout, q, sk, blocks, widths, in_blocks,
+                          out_blocks):
+    r, dv = widths
+    _, vmem = _rows_per_program(
+        1, *blocks, in_blocks + out_blocks,
+        [(shape, jnp.float32) for shape, _ in out_blocks])
+    _announce(f"{kernel} two-product ({layout.d} + {r} lanes a score, one "
+              f"{r}-lane key a position shared by {layout.num_heads} heads, "
+              f"values of {dv})", layout, q, sk, *blocks, 1, vmem)
+
+
+def _flash_fwd2(q, q_rope, k, k_rope, v, scale, causal, block_q, block_k,
+                interpret):
+    """The forward kernel in its two-product form, one (batch, head) row a
+    program: ``(o (b,h,s,value width), lse f32 (b,h,s,1))``."""
+    layout, (qr, qrr, kr, vr, krr), (sq, sk), blocks, (r, dv), ins = \
+        _two_product_plan(q, q_rope, k, k_rope, v, block_q, block_k)
+    block_q, block_k = blocks
+    f32 = jnp.dtype(jnp.float32)
+    main, rope = ins(1, 2)
+    o_block, row_block = (block_q, dv), (block_q, 1)
+    _announce_two_product(
+        "flash_fwd", layout, qr, sk, blocks, (r, dv),
+        [((block_q, layout.d), q.dtype), ((block_k, layout.d), k.dtype),
+         ((block_k, dv), v.dtype), ((block_q, r), q.dtype),
+         ((block_k, r), k_rope.dtype)],
+        [(o_block, q.dtype), (row_block, f32)])
+    out, lse = _kernel_call(
+        jnp.zeros((2,), jnp.int32), qr, kr, vr, qrr, krr, name="flash_fwd",
+        body=_fwd_kernel, layout=layout, n=1,
+        grid_tail=(sq // block_q, sk // block_k), ins=main + rope,
+        outs=((block_q, 1, False, jnp.dtype(q.dtype), sq, dv),
+              (block_q, 1, True, f32, sq)),
+        scratch=(o_block, row_block, row_block), block_q=block_q,
+        block_k=block_k, causal=causal, interpret=interpret, scale=scale)
+    return (out.reshape(q.shape[:3] + (dv,)),
+            layout.result(lse, stat=True))
+
+
+def _flash_bwd2(q, q_rope, k, k_rope, v, do, lse, delta, scale, causal,
+                block_q, block_k, interpret):
+    """The two backward kernels in their two-product form: the gradients of
+    all five operands in their dtypes, ``k_rope``'s summed over the heads
+    (the dk/dv kernel writes each head's part, (batch x heads, s, r); the
+    sum over a batch row's heads is XLA's)."""
+    layout, (qr, qrr, kr, vr, krr), (sq, sk), blocks, (r, dv), ins = \
+        _two_product_plan(q, q_rope, k, k_rope, v, block_q, block_k)
+    block_q, block_k = blocks
+    dor = do.reshape(layout.units, sq, dv)
+    lser, deltar = layout.stat(lse), layout.stat(delta)
+    f32 = jnp.dtype(jnp.float32)
+    dtype = jnp.dtype(q.dtype)
+
+    def call(name, body, grid_tail, at_q, at_k, outs):
+        """One backward kernel; ``outs`` are ``(length, whole length,
+        lanes)`` of each result, all at the grid's second index."""
+        main, rope = ins(at_q, at_k)
+        stat = (block_q, at_q, True)
+        out_blocks = [((length, lanes), dtype) for length, _, lanes in outs]
+        _announce_two_product(
+            name, layout, qr, sk, blocks, (r, dv),
+            [((block_q, layout.d), dtype), ((block_k, layout.d), dtype),
+             ((block_k, dv), dtype), ((block_q, dv), dtype),
+             ((block_q, 1), f32), ((block_q, 1), f32),
+             ((block_q, r), dtype), ((block_k, r), dtype)], out_blocks)
+        return _kernel_call(
+            jnp.zeros((2,), jnp.int32), qr, kr, vr, dor, lser, deltar, qrr,
+            krr, name=name, body=body, layout=layout, n=1,
+            grid_tail=grid_tail,
+            ins=main + ((block_q, at_q, False, dv), stat, stat) + rope,
+            outs=tuple((length, 1, False, dtype, whole, lanes)
+                       for length, whole, lanes in outs),
+            scratch=tuple(shape for shape, _ in out_blocks), block_q=block_q,
+            block_k=block_k, causal=causal, interpret=interpret, scale=scale)
+
+    dq, dq_rope = call("flash_bwd_dq", _bwd_dq_kernel,
+                       (sq // block_q, sk // block_k), 1, 2,
+                       ((block_q, sq, layout.d), (block_q, sq, r)))
+    dk, dv_, dk_rope = call("flash_bwd_dkv", _bwd_dkv_kernel,
+                            (sk // block_k, sq // block_q), 2, 1,
+                            ((block_k, sk, layout.d), (block_k, sk, dv),
+                             (block_k, sk, r)))
+    b, h = layout.batch, layout.num_heads
+    dk_rope = dk_rope.reshape(b, h, sk, r).astype(jnp.float32).sum(1)
+    return (dq.reshape(q.shape), dq_rope.reshape(q_rope.shape),
+            dk.reshape(k.shape), dk_rope.astype(k_rope.dtype),
+            dv_.reshape(v.shape))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _two_product_kernels(q, q_rope, k, k_rope, v, scale, causal, block_q,
+                         block_k, interpret):
+    """:func:`flash_attention_two_product` by the kernels only
+    (``interpret`` True or False)."""
+    return _flash_fwd2(q, q_rope, k, k_rope, v, scale, causal, block_q,
+                       block_k, interpret)[0]
+
+
+def _two_product_fwd_rule(q, q_rope, k, k_rope, v, scale, causal, block_q,
+                          block_k, interpret):
+    o, lse = _flash_fwd2(q, q_rope, k, k_rope, v, scale, causal, block_q,
+                         block_k, interpret)
+    return o, (q, q_rope, k, k_rope, v, o, lse)
+
+
+def _two_product_bwd_rule(scale, causal, block_q, block_k, interpret, res,
+                          do):
+    q, q_rope, k, k_rope, v, o, lse = res
+    delta = (do.astype(jnp.float32) * o.astype(jnp.float32)) \
+        .sum(-1, keepdims=True)
+    return _flash_bwd2(q, q_rope, k, k_rope, v, do, lse, delta, scale,
+                       causal, block_q, block_k, interpret)
+
+
+_two_product_kernels.defvjp(_two_product_fwd_rule, _two_product_bwd_rule)
+
+
+def flash_attention_two_product(q, q_rope, k, k_rope, v, scale, causal=True,
+                                block_q=512, block_k=1024, interpret=None):
+    """Attention whose score is the sum of two products, fused forward and
+    backward: ``softmax((q . k + q_rope . k_rope) * scale [+ causal mask])
+    v``.  ``q``, ``k``: (batch, heads, s, d); ``q_rope``: (batch, heads, s,
+    r); ``k_rope``: (batch, s, r), ONE key a position that every head of the
+    row reads (its block's index is the program's batch row; its gradient is
+    the sum over the heads); ``v``: (batch, heads, s, value width), a width
+    of its own, as the result's.  Nothing is concatenated, broadcast over the
+    heads or padded to a common width in HBM.  The three kernels are
+    :func:`flash_attention`'s (``flash_fwd``, ``flash_bwd_dq``,
+    ``flash_bwd_dkv``) in their two-product form on the split layout, one
+    (batch, head) row a program.  ``interpret=None`` picks the kernels on
+    TPU and :func:`two_product_reference` elsewhere, as it does where the
+    blocks do not divide the sequence."""
+    s = q.shape[2]
+    if s % min(block_q, s) or s % min(block_k, s):
+        _log_path("dense", f"two-product: seq {s} does not divide blocks "
+                           f"({block_q}, {block_k})")
+        interpret = None
+    else:
+        interpret = _pallas_interpret(interpret, q.dtype)
+    if interpret is None:
+        return two_product_reference(q, q_rope, k, k_rope, v, scale, causal)
+    return _two_product_kernels(q, q_rope, k, k_rope, v, scale, causal,
+                                block_q, block_k, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -917,8 +1191,8 @@ def _free_axes():
     return (mesh, am, free) if free else None
 
 
-def _under_full_manual(fn, q, k, v, heads_dim=1):
-    """``fn(q, k, v)`` where a Mosaic kernel can lower on the active mesh.
+def _under_full_manual(fn, *operands, heads_dim=1):
+    """``fn(*operands)`` where a Mosaic kernel can lower on the active mesh.
 
     jax refuses to partition a ``pallas_call`` automatically ("Mosaic
     kernels cannot be automatically partitioned"): on a mesh of several
@@ -926,13 +1200,16 @@ def _under_full_manual(fn, q, k, v, heads_dim=1):
     axis.  The Runner's explicit path over ``{data}`` alone already is one;
     on the GSPMD path, or with further axes left automatic, the call goes
     under a ``shard_map`` over the axes still free — batch split over
-    ``data``, heads (dimension ``heads_dim`` of q/k/v) over ``model``.  Any
-    other axis of size > 1 would run the whole kernel on each of its
-    devices, so it raises instead.
+    ``data``, heads (dimension ``heads_dim`` of the rank-4 operands, the
+    first of which is q) over ``model``; an operand of lower rank has no
+    heads (the two-product form's shared key) and is split over ``data``
+    alone.  Any other axis of size > 1 would run the whole kernel on each of
+    its devices, so it raises instead.
     """
     found = _free_axes()
     if found is None:
-        return fn(q, k, v)
+        return fn(*operands)
+    q = operands[0]
     mesh, am, free = found
     sizes = dict(mesh.shape)
     dim_of = {const.MESH_AXIS_DATA: 0, const.MESH_AXIS_MODEL: heads_dim}
@@ -949,12 +1226,13 @@ def _under_full_manual(fn, q, k, v, heads_dim=1):
                 f"of its {size} devices; pass attn_fn= to the model or "
                 f"pick a strategy without that axis")
         spec[dim] = a
-    spec = P(*spec)
+    specs = tuple(P(*spec) if x.ndim == q.ndim else P(spec[0])
+                  for x in operands)
     _log_path("pallas", f"under shard_map over {list(free)} of mesh {sizes}")
     # Nested in a manual region, jax wants the context's own mesh.
     return jax.shard_map(fn, mesh=am if dict(am.shape) == sizes else mesh,
-                         in_specs=(spec, spec, spec), out_specs=spec,
-                         axis_names=set(free), check_vma=False)(q, k, v)
+                         in_specs=specs, out_specs=specs[0],
+                         axis_names=set(free), check_vma=False)(*operands)
 
 
 def make_flash_attn_fn(causal=False, block_q=512, block_k=1024):
@@ -972,6 +1250,8 @@ def make_flash_attn_fn(causal=False, block_q=512, block_k=1024):
     ``mha`` transposes nothing; or None where heads of that shape keep
     ``(batch, heads, seq, head_dim)`` (``_heads_per_block``; a mesh axis
     that splits the heads) and ``mha`` and the program are as they were.
+    It also carries ``attn_fn.two_product(q, q_rope, k, k_rope, v, scale)``,
+    the two-product form for ``models.layers.mla``.
     """
     from autodist_tpu.models import layers as L
 
@@ -1016,5 +1296,14 @@ def make_flash_attn_fn(causal=False, block_q=512, block_k=1024):
             return None
         return packed
 
+    def two_product(q, q_rope, k, k_rope, v, scale):
+        """:func:`flash_attention_two_product` under the hook's causality and
+        blocks: what ``models.layers.mla`` calls."""
+        return _under_full_manual(
+            lambda *operands: flash_attention_two_product(
+                *operands, scale, causal, block_q, block_k),
+            q, q_rope, k, k_rope, v)
+
     attn_fn.bshd = bshd
+    attn_fn.two_product = two_product
     return attn_fn

@@ -27,6 +27,22 @@ megablox ``gmm`` Pallas kernel and its own gradient), so every assignment
 is computed and none pays for an empty buffer slot.  It is
 the form the language-model block uses (``models/transformer.py``,
 ``ffn="moe"``); :func:`dense_apply` is the oracle of both.
+
+**A share of the experts** (``MoEConfig.held``).  The dropless layer can be
+told that it holds only the experts ``held = (first, count)``, one rank's
+share of an expert-parallel deployment seen from one chip: the stacked
+matrices are ``(count, ...)``, the router keeps all ``num_experts`` outputs
+and a token's ``top_k`` are chosen and their weights normalised over all of
+them, and the layer computes exactly the part of the result that the held
+experts give (plus the shared expert, which every rank holds whole).  What
+the absent experts would add is left out and no code stands in for absent
+chips.  The share is TOLD, not derived from a mesh: on the ``expert`` mesh
+axis of ``EXPERT_RULES`` every chip still computes with all the experts'
+matrices gathered or exchanged by GSPMD, while ``held`` is the form in
+which the experts stay where they are.  Tokens that travel to the rank
+holding their expert, and come back, start from here: the routed parts of
+all the shares add up to the whole layer (``tests/test_moe_held.py``), so
+what is missing is the exchange of the rows, not the arithmetic.
 """
 import math
 
@@ -38,21 +54,46 @@ from autodist_tpu.models import layers as L
 from autodist_tpu.utils import logging
 
 # Sharding rule for ModelParallel-style overlays: expert dim on `expert` axis.
+# These shard the stacked matrices of a layer that holds EVERY expert; a layer
+# told its share (``MoEConfig.held``) holds ``count`` experts' matrices whole
+# on one chip and names no mesh axis (module docstring).
 EXPERT_RULES = (
     (r"moe/(up|down|glu)/kernel$", 0),
     (r"moe/gate/kernel$", 1),
 )
 
 EXPERT_KINDS = ("gelu", "swiglu")
+SCORINGS = ("softmax", "sigmoid")
 
 
 class MoEConfig:
     def __init__(self, num_experts=8, top_k=2, d_model=64, d_hidden=256,
                  dtype=jnp.float32, capacity_factor=1.25, expert="gelu",
-                 norm_topk=True):
+                 norm_topk=True, scoring="softmax", route_scale=1.0,
+                 shared=0, select_bias=False, bias_update_rate=0.0,
+                 held=None):
         if expert not in EXPERT_KINDS:
             raise ValueError(f"expert must be one of {EXPERT_KINDS}, got "
                              f"{expert!r}")
+        if scoring not in SCORINGS:
+            raise ValueError(f"scoring must be one of {SCORINGS}, got "
+                             f"{scoring!r}")
+        if scoring == "softmax" and route_scale != 1.0:
+            raise ValueError("route_scale scales sigmoid scores; with "
+                             f"softmax scoring it must be 1.0, got "
+                             f"{route_scale!r}")
+        if shared and expert != "swiglu":
+            raise ValueError("shared experts are SwiGLU MLPs: expert must "
+                             f"be 'swiglu' with shared={shared!r}, got "
+                             f"{expert!r}")
+        if held is not None:
+            first, count = held
+            if not (0 <= first and count > 0
+                    and first + count <= num_experts):
+                raise ValueError(
+                    f"held = (first, count) must name consecutive experts "
+                    f"among the {num_experts}, got {held!r}")
+            held = (int(first), int(count))
         self.num_experts = num_experts
         self.top_k = top_k
         self.d_model = d_model
@@ -67,22 +108,55 @@ class MoEConfig:
         # Whether a token's top-k weights are rescaled to sum to one, or
         # left as the softmax over all experts gave them.
         self.norm_topk = norm_topk
+        # What follows is read by :func:`dropless_apply` alone.
+        # "softmax" over the experts, or "sigmoid" of each logit on its own
+        # (then ``norm_topk`` divides by the chosen scores' sum).
+        self.scoring = scoring
+        # The chosen experts' weights are multiplied by this (sigmoid
+        # scoring only).
+        self.route_scale = route_scale
+        # Shared experts beside the routed ones (``expert="swiglu"``): one
+        # SwiGLU MLP of ``shared * d_hidden`` that every token passes,
+        # unweighted.
+        self.shared = shared
+        # A per-expert bias added to the scores for the CHOICE of the top_k
+        # only (never to the weights; no gradient); after each step it moves
+        # by ``bias_update_rate`` towards an even load (``stats``'
+        # ``state_updates``, which the Runner writes).
+        self.select_bias = select_bias
+        self.bias_update_rate = bias_update_rate
+        # ``(first, count)``: the consecutive experts this layer holds (the
+        # module docstring); None holds all.
+        self.held = held
+
+    @property
+    def num_held(self):
+        return self.num_experts if self.held is None else self.held[1]
 
 
 def init(key, cfg):
     swiglu = cfg.expert == "swiglu"
     keys = jax.random.split(key, 4 if swiglu else 3)
-    up_shape = (cfg.num_experts, cfg.d_model, cfg.d_hidden)
+    up_shape = (cfg.num_held, cfg.d_model, cfg.d_hidden)
     params = {
         "gate": {"kernel": L.glorot(keys[0], (cfg.d_model, cfg.num_experts))},
         "up": {"kernel": L.glorot(keys[1], up_shape, in_axis=-2, out_axis=-1)},
-        "down": {"kernel": L.glorot(keys[2], (cfg.num_experts, cfg.d_hidden,
+        "down": {"kernel": L.glorot(keys[2], (cfg.num_held, cfg.d_hidden,
                                               cfg.d_model),
                                     in_axis=-2, out_axis=-1)},
     }
     if swiglu:
         params["glu"] = {"kernel": L.glorot(keys[3], up_shape, in_axis=-2,
                                             out_axis=-1)}
+    if cfg.shared:
+        wide = cfg.shared * cfg.d_hidden
+        shared_keys = jax.random.split(jax.random.fold_in(key, 1), 3)
+        params["shared"] = {
+            "up": L.dense_init(shared_keys[0], cfg.d_model, wide, False),
+            "down": L.dense_init(shared_keys[1], wide, cfg.d_model, False),
+            "glu": L.dense_init(shared_keys[2], cfg.d_model, wide, False)}
+    if cfg.select_bias:
+        params["bias"] = jnp.zeros((cfg.num_experts,), jnp.float32)
     return params
 
 
@@ -274,11 +348,19 @@ def _announce(cfg, assignments):
     registry.gauge("moe.experts").set(cfg.num_experts)
     registry.gauge("moe.top_k").set(cfg.top_k)
     registry.gauge("moe.assignments_per_step").set(assignments)
+    registry.gauge("moe.experts_held").set(cfg.num_held)
     detail = (f"dropless: {assignments} assignments a step over "
               f"{cfg.num_experts} {cfg.expert} experts, {cfg.top_k} a token; "
               f"grouped product megablox gmm tiled {GMM_TILING}, "
-              f"({assignments}, {cfg.d_model}) x ({cfg.num_experts}, "
+              f"({assignments}, {cfg.d_model}) x ({cfg.num_held}, "
               f"{cfg.d_model}, {cfg.d_hidden}) and back")
+    if cfg.held is not None or cfg.scoring != "softmax" or cfg.shared:
+        first, count = cfg.held or (0, cfg.num_experts)
+        detail += (f"; {cfg.scoring} scores, experts {first}-"
+                   f"{first + count - 1} held ({count} of {cfg.num_experts}: "
+                   f"the rows of the others are sorted behind and not "
+                   f"visited), {cfg.shared} shared"
+                   + (", selection bias" if cfg.select_bias else ""))
     if detail not in _announced:
         _announced.add(detail)
         observability.record_event("moe", detail)
@@ -334,37 +416,93 @@ def _uncovered(sorted_experts, group_sizes):
     return jnp.sum(given != sorted_experts).astype(jnp.float32)
 
 
+def _route_sigmoid(logits, params, cfg):
+    """``(top_vals, top_idx, gates)`` of sigmoid scoring: each expert's score
+    is the sigmoid of its own logit; the ``top_k`` are the largest of score
+    plus the selection bias (which only chooses: it is not in the weights
+    and has no gradient); the weights are the chosen scores, over their sum
+    with ``norm_topk``, times ``route_scale``.  ``gates`` are the scores
+    over their sum over ALL experts, what the balance term takes for the
+    router's probabilities."""
+    scores = jax.nn.sigmoid(logits)
+    choice = scores + jax.lax.stop_gradient(params["bias"]) \
+        if cfg.select_bias else scores
+    _, top_idx = jax.lax.top_k(choice, cfg.top_k)
+    top_vals = jnp.take_along_axis(scores, top_idx, axis=-1)
+    if cfg.norm_topk:
+        top_vals = top_vals / (top_vals.sum(-1, keepdims=True) + 1e-20)
+    return (top_vals * cfg.route_scale, top_idx,
+            scores / scores.sum(-1, keepdims=True))
+
+
+def _shared_expert(params, cfg, flat_x):
+    """The shared experts' MLP over every token, unweighted: (T, d)."""
+    xc = flat_x.astype(cfg.dtype)
+    hidden = jax.nn.silu(L.dense(params["glu"], xc, cfg.dtype)) \
+        * L.dense(params["up"], xc, cfg.dtype)
+    return L.dense(params["down"], hidden, cfg.dtype)
+
+
 def dropless_apply(params, cfg, x):
     """x: (rows, seq, d_model) -> (moe_out, stats); no assignment dropped.
 
     The router runs in float32; a token's ``top_k`` weights are left as the
-    softmax gave them unless ``cfg.norm_topk``.  The ``T * k`` assignments
+    softmax gave them unless ``cfg.norm_topk`` (``cfg.scoring="sigmoid"``:
+    :func:`_route_sigmoid`).  The ``T * k`` assignments
     are sorted by expert (stable, so an expert's rows keep the tokens'
     order), the tokens' rows gathered in that order, and each of the
     expert matrices applied to its contiguous group of rows by one grouped
     product.  The results go back to the assignments' own order, are
-    weighted, and summed per token.
+    weighted, and summed per token.  ``cfg.shared`` adds the shared experts'
+    MLP of every token (scope ``shared``).
 
-    ``stats`` (all float32 scalars): ``load_balance`` = E * sum_e f_e P_e
-    with f_e the share of a row's ``seq * k`` assignments that expert e
-    got (sums to one over the experts) and P_e the row's mean router
-    probability, computed a row of the batch at a time and averaged over
-    the rows; ``z_loss`` = mean(logsumexp(router logits)^2);
-    ``load_max_over_mean`` = the busiest expert's assignments over the
-    mean, over the whole batch; ``dropped`` = the assignments whose sorted
-    row the grouped products do not put through its own expert
-    (:func:`_uncovered`): 0 while the sort and the group sizes agree.
+    With ``cfg.held = (first, count)`` the layer computes the held experts'
+    part exactly: routing, weights and every statistic but ``dropped`` and
+    ``held_assignments`` are over all ``num_experts``; the assignments sort
+    by held expert with every other assignment behind them, the grouped
+    products (``count`` groups) visit only the tiles of the held rows, and
+    the rows behind them are zero going in and coming out.  The buffers are
+    ``T * k`` rows whatever the routing, so that a token whose ``top_k`` are
+    all held loses none.
+
+    ``stats`` (float32 scalars but ``state_updates``): ``load_balance`` = E
+    * sum_e f_e P_e with f_e the share of a row's ``seq * k`` assignments
+    that expert e got (sums to one over the experts) and P_e the row's mean
+    router probability, computed a row of the batch at a time and averaged
+    over the rows; ``z_loss`` = mean(logsumexp(router logits)^2), softmax
+    scoring only; ``load_max_over_mean`` = the busiest expert's assignments
+    over the mean, over the whole batch and all experts; ``dropped`` = the
+    held assignments whose sorted row the grouped products do not put
+    through its own expert (:func:`_uncovered`): 0 while the sort and the
+    group sizes agree; with ``cfg.held``, ``held_assignments`` = the
+    assignments that chose a held expert and ``held_output_rms`` = the root
+    mean square, over tokens and lanes, of what the held experts add to the
+    output (weighted, before the shared expert: 0 where nothing landed).  With ``cfg.select_bias``: ``bias_absmax`` and
+    ``state_updates = {"bias": the bias after this step}``, each entry
+    moved by ``bias_update_rate`` up where the expert got fewer assignments
+    than the mean, down where more (the loss-free balancing of
+    arXiv:2408.15664); the caller hands it to the Runner under the
+    variable's full name (``aux["state_updates"]``).
     """
     rows, seq, _ = x.shape
     tokens, num_e, top_k = rows * seq, cfg.num_experts, cfg.top_k
     flat_x = x.reshape(tokens, cfg.d_model)
     _announce(cfg, tokens * top_k)
     with jax.named_scope("router"):
-        logits = flat_x.astype(jnp.float32) @ \
-            params["gate"]["kernel"].astype(jnp.float32)
-        gates = jax.nn.softmax(logits, axis=-1)                 # (T, E)
-        # The capacity path's whole-batch balance term is not used here.
-        top_vals, top_idx, _ = _route(gates, cfg)
+        if cfg.scoring == "sigmoid":
+            # Exact float32 products: a selection bias moves by a thousandth
+            # a step, which one bf16 pass of the MXU (what an f32 product is
+            # by default on the TPU) does not resolve in a logit.
+            logits = jnp.dot(flat_x.astype(jnp.float32),
+                             params["gate"]["kernel"].astype(jnp.float32),
+                             precision=jax.lax.Precision.HIGHEST)
+            top_vals, top_idx, gates = _route_sigmoid(logits, params, cfg)
+        else:
+            logits = flat_x.astype(jnp.float32) @ \
+                params["gate"]["kernel"].astype(jnp.float32)
+            gates = jax.nn.softmax(logits, axis=-1)             # (T, E)
+            # The capacity path's whole-batch balance term is not used here.
+            top_vals, top_idx, _ = _route(gates, cfg)
         flat_idx = top_idx.reshape(-1)                          # token-major
         # (rows, E) counts; a row's sum to seq * k.
         counts = jax.vmap(lambda i: jnp.bincount(i, length=num_e))(
@@ -372,21 +510,39 @@ def dropless_apply(params, cfg, x):
         density = counts.astype(jnp.float32) / (seq * top_k)
         mean_gate = gates.reshape(rows, seq, num_e).mean(1)
         group_sizes = counts.sum(0).astype(jnp.int32)
-        stats = {
-            "load_balance": jnp.mean(
-                num_e * jnp.sum(density * mean_gate, axis=-1)),
-            "z_loss": jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2),
-            "load_max_over_mean": group_sizes.max().astype(jnp.float32)
-            * num_e / (tokens * top_k),
-        }
+        stats = {"load_balance": jnp.mean(
+            num_e * jnp.sum(density * mean_gate, axis=-1))}
+        if cfg.scoring == "softmax":
+            stats["z_loss"] = jnp.mean(
+                jax.nn.logsumexp(logits, axis=-1) ** 2)
+        stats["load_max_over_mean"] = group_sizes.max().astype(jnp.float32) \
+            * num_e / (tokens * top_k)
+        if cfg.select_bias:
+            load = group_sizes.astype(jnp.float32)
+            bias = params["bias"] + cfg.bias_update_rate * jnp.sign(
+                load.mean() - load)
+            stats["state_updates"] = {"bias": jax.lax.stop_gradient(bias)}
+            stats["bias_absmax"] = jnp.max(jnp.abs(bias))
     with jax.named_scope("dispatch"):
+        sort_keys, valid = flat_idx, None
+        if cfg.held is not None:
+            # Held experts' assignments first, by expert; the rest behind.
+            first, count = cfg.held
+            local = flat_idx - first
+            sort_keys = jnp.where((local >= 0) & (local < count), local, count)
+            group_sizes = group_sizes[first:first + count]
+            held = group_sizes.sum()
+            valid = (jnp.arange(tokens * top_k) < held)[:, None]
+            stats["held_assignments"] = held.astype(jnp.float32)
         # argsort, keeping the sorted keys the same sort produces.
         sorted_experts, order = jax.lax.sort_key_val(
-            flat_idx, jnp.arange(tokens * top_k, dtype=flat_idx.dtype))
+            sort_keys, jnp.arange(tokens * top_k, dtype=flat_idx.dtype))
         inverse = jnp.argsort(order)
         stats["dropped"] = _uncovered(sorted_experts, group_sizes)
         sorted_x = _sorted_rows(flat_x.astype(cfg.dtype), order, inverse,
                                 top_k)
+        if valid is not None:
+            sorted_x = jnp.where(valid, sorted_x, 0)
     with jax.named_scope("experts"):
         def grouped(lhs, name):
             return grouped_product(
@@ -395,10 +551,18 @@ def dropless_apply(params, cfg, x):
             grouped(sorted_x, "up") if cfg.expert == "swiglu" \
             else jax.nn.gelu(grouped(sorted_x, "up"))
         expert_out = grouped(hidden, "down")                    # (T * k, d)
+        if valid is not None:   # no product visited the rows behind
+            expert_out = jnp.where(valid, expert_out, 0)
     with jax.named_scope("dispatch"):
         y = _unsorted_rows(expert_out, order, inverse) \
             .reshape(tokens, top_k, cfg.d_model)
         out = jnp.sum(y.astype(jnp.float32) * top_vals[..., None], axis=1)
+        if cfg.held is not None:
+            stats["held_output_rms"] = jnp.sqrt(jnp.mean(jnp.square(out)))
+    if cfg.shared:
+        with jax.named_scope("shared"):
+            out = out + _shared_expert(params["shared"], cfg, flat_x) \
+                .astype(jnp.float32)
     return out.reshape(x.shape).astype(x.dtype), stats
 
 

@@ -25,7 +25,7 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec
 
 from autodist_tpu import const, observability
-from autodist_tpu.graph_item import path_to_name
+from autodist_tpu.graph_item import STATE_UPDATES, path_to_name
 from autodist_tpu.kernel.synchronization.ps_synchronizer import PSSynchronizer
 from autodist_tpu.remapper import Remapper
 from autodist_tpu.utils import logging
@@ -593,6 +593,18 @@ class Runner:
 
     # -- step compilation ----------------------------------------------------
 
+    def _write_state_updates(self, params, updates):
+        """``params`` (storage shapes) with the variables that
+        ``aux["state_updates"]`` names set to the values it gives, after the
+        optimizer's update (which is zero for such a variable: capture
+        checked that no gradient reaches it)."""
+        def write(path, leaf):
+            name = path_to_name(path)
+            if name not in updates:
+                return leaf
+            return self._pad_leaf(name, updates[name]).astype(leaf.dtype)
+        return jax.tree_util.tree_map_with_path(write, params)
+
     def _metrics(self, loss, aux):
         """The step's traced outputs: ``loss``, ``aux`` where the loss
         function returns one, and the divergence flag."""
@@ -703,6 +715,11 @@ class Runner:
                 updates, opt_state = opt.update(grads, state.opt_state,
                                                 state.params)
                 params = optax.apply_updates(state.params, updates)
+            if item.state_updates:
+                aux = dict(aux)
+                with jax.named_scope("state_updates"):
+                    params = self._write_state_updates(
+                        params, aux.pop(STATE_UPDATES))
             return (TrainState(state.step + 1, params, opt_state, state.sync_state),
                     self._metrics(loss, aux))
 
@@ -750,6 +767,14 @@ class Runner:
         values; only the schedule position of the AG moves.
         """
         item, prog = self._item, self._program
+        if item.state_updates:
+            raise NotImplementedError(
+                f"the explicit shard_map step does not write "
+                f"aux['state_updates'] ({', '.join(item.state_updates)}): "
+                f"each chip of the data axis would compute the new value "
+                f"from its own rows alone.  Run such a loss on the GSPMD "
+                f"lowering (one chip, or a strategy whose step is jax.jit), "
+                f"whose megastep carries it too (docs/usage/state-updates.md)")
         anchors = prog.parallel_context().op_shardings
         if anchors and not getattr(self, "_anchors_skipped", False):
             self._anchors_skipped = True  # once per Runner, not per trace

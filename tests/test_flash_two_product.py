@@ -1,0 +1,131 @@
+"""The flash kernels in their two-product form (latent attention): a score
+that is the sum of two products, the second against ONE key a position that
+a batch row's heads share, and values of a width of their own.  Interpreted
+Pallas against plain jnp: the forward and all five gradients."""
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+fa = importlib.import_module("autodist_tpu.ops.flash_attention")
+
+
+def _operands(b, h, s, d, r, dv, dtype=jnp.float32, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    shapes = ((b, h, s, d), (b, h, s, r), (b, h, s, d), (b, s, r),
+              (b, h, s, dv), (b, h, s, dv))
+    return [jax.random.normal(k, shape).astype(dtype)
+            for k, shape in zip(ks, shapes)]
+
+
+def _dense_assembled(q, q_rope, k, k_rope, v, scale, causal):
+    """Plain attention on keys assembled as [k ; k_rope broadcast]."""
+    b, h, s, _ = q.shape
+    keys = jnp.concatenate(
+        [k, jnp.broadcast_to(k_rope[:, None], (b, h, s, k_rope.shape[-1]))],
+        axis=-1)
+    scores = jnp.einsum("bhqd,bhkd->bhqk",
+                        jnp.concatenate([q, q_rope], axis=-1), keys) * scale
+    if causal:
+        scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(scores, -1), v)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("shape", [
+    # b, h, s, d, r, dv, block_q, block_k: a value width other than the
+    # scores' in every case; several blocks each way; one block.
+    (2, 4, 256, 32, 16, 48, 64, 128),
+    (1, 3, 128, 16, 8, 8, 128, 128),
+    (2, 2, 256, 128, 64, 128, 128, 64)])
+def test_forward_and_five_gradients_match_dense_attention(shape, causal):
+    b, h, s, d, r, dv, bq, bk = shape
+    *ops, w = _operands(b, h, s, d, r, dv)
+    scale = (d + r) ** -0.5
+
+    def kernels(*a):
+        return fa.flash_attention_two_product(*a, scale, causal, bq, bk, True)
+
+    def dense(*a):
+        return _dense_assembled(*a, scale, causal)
+
+    np.testing.assert_allclose(kernels(*ops), dense(*ops), atol=2e-6)
+    np.testing.assert_allclose(fa.two_product_reference(*ops, scale, causal),
+                               dense(*ops), atol=2e-6)
+    got = jax.grad(lambda *a: jnp.sum(kernels(*a) * w), (0, 1, 2, 3, 4))(*ops)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), (0, 1, 2, 3, 4))(*ops)
+    for g, e, x in zip(got, want, ops):
+        assert g.shape == x.shape and g.dtype == x.dtype
+        np.testing.assert_allclose(g, e, atol=1e-5)
+
+
+def test_the_shared_keys_gradient_is_the_sum_over_the_heads():
+    b, h, s, d, r, dv = 1, 4, 128, 16, 8, 16
+    q, q_rope, k, k_rope, v, w = _operands(b, h, s, d, r, dv)
+    scale = (d + r) ** -0.5
+
+    def shared(kr):
+        return jnp.sum(fa.flash_attention_two_product(
+            q, q_rope, k, kr, v, scale, True, 64, 64, True) * w)
+
+    def per_head(kr4):      # (b, h, s, r): a key of its own for every head
+        full_k = jnp.concatenate([k, kr4], axis=-1)
+        full_q = jnp.concatenate([q, q_rope], axis=-1)
+        scores = jnp.einsum("bhqd,bhkd->bhqk", full_q, full_k) * scale
+        scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -jnp.inf)
+        return jnp.sum(jnp.einsum("bhqk,bhkd->bhqd",
+                                  jax.nn.softmax(scores, -1), v) * w)
+
+    each = jax.grad(per_head)(jnp.broadcast_to(k_rope[:, None],
+                                               (b, h, s, r)))
+    np.testing.assert_allclose(jax.grad(shared)(k_rope), each.sum(1),
+                               atol=1e-5)
+
+
+def test_bf16_operands_give_bf16_results_close_to_f32():
+    b, h, s, d, r, dv = 1, 2, 256, 128, 64, 128
+    *ops, w = _operands(b, h, s, d, r, dv, jnp.bfloat16)
+    scale = (d + r) ** -0.5
+    out = fa.flash_attention_two_product(*ops, scale, True, 128, 128, True)
+    assert out.dtype == jnp.bfloat16 and out.shape == (b, h, s, dv)
+    want = _dense_assembled(*(x.astype(jnp.float32) for x in ops), scale,
+                            True)
+    np.testing.assert_allclose(out.astype(jnp.float32), want, atol=3e-2)
+    grads = jax.grad(lambda *a: jnp.sum(
+        fa.flash_attention_two_product(*a, scale, True, 128, 128, True)
+        .astype(jnp.float32) * w.astype(jnp.float32)), (0, 1, 2, 3, 4))(*ops)
+    assert [g.dtype for g in grads] == [jnp.bfloat16] * 5
+    assert grads[3].shape == (b, s, r)
+
+
+def test_off_the_tpu_the_plain_path_runs_and_blocks_that_do_not_divide():
+    *ops, _ = _operands(1, 2, 96, 16, 8, 24)
+    scale = 24 ** -0.5
+    auto = fa.flash_attention_two_product(*ops, scale)       # interpret=None
+    np.testing.assert_allclose(auto, _dense_assembled(*ops, scale, True),
+                               atol=2e-6)
+    # 96 positions do not divide blocks of 64: the plain path, not an error.
+    odd = fa.flash_attention_two_product(*ops, scale, True, 64, 64, True)
+    np.testing.assert_allclose(odd, auto, atol=2e-6)
+
+
+def test_the_hook_carries_the_two_product_form():
+    hook = fa.make_flash_attn_fn(causal=True)
+    *ops, _ = _operands(1, 2, 64, 16, 8, 24)
+    np.testing.assert_allclose(
+        hook.two_product(*ops, 24 ** -0.5),
+        _dense_assembled(*ops, 24 ** -0.5, True), atol=2e-6)
+
+
+def test_the_one_width_kernels_trace_as_before():
+    """The one-product call passes no scale to the kernels' bodies and reads
+    five operands less: its jaxpr names no two-product operand."""
+    q = jnp.ones((1, 2, 128, 16))
+    text = str(jax.make_jaxpr(lambda q: fa.flash_attention(
+        q, q, q, True, 64, 64, 0, True))(q))
+    assert text.count("flash_fwd") >= 1
+    two = str(jax.make_jaxpr(lambda q: fa.flash_attention_two_product(
+        q, q[..., :8], q, q[:, 0, :, :8], q, 0.2, True, 64, 64, True))(q))
+    assert two.count("flash_fwd") >= 1 and two != text

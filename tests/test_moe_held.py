@@ -1,0 +1,189 @@
+"""``moe.dropless_apply`` told its share of the experts, sigmoid-routed with a
+selection bias beside a shared expert, against the plain reference's layer
+(``chipbench/reference_mla_moe.py``): under even routing, under routing that
+sends every token's choices to held experts (the ``T x k`` worst case:
+nothing dropped) and under routing that sends none; and the shares add up
+to the uncut layer."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from autodist_tpu.parallel import moe
+from chipbench import reference_mla_moe as ref
+
+E, K, D, H, RATE, SCALE = 16, 4, 32, 24, 0.001, 2.5
+
+
+def _cfg(held, **kw):
+    return moe.MoEConfig(num_experts=E, top_k=K, d_model=D, d_hidden=H,
+                         dtype=jnp.float32, expert="swiglu", norm_topk=True,
+                         scoring="sigmoid", route_scale=SCALE, shared=1,
+                         select_bias=True, bias_update_rate=RATE, held=held,
+                         **kw)
+
+
+def _layer(held, bias=None, seed=0, rows=2, seq=64):
+    """Parameters of the WHOLE layer cut to ``held``'s matrices, so that
+    every share of one seed routes alike."""
+    whole = moe.init(jax.random.PRNGKey(seed), _cfg(None))
+    first, count = held or (0, E)
+    p = dict(whole, **{name: {"kernel":
+                              whole[name]["kernel"][first:first + count]}
+                       for name in ("up", "down", "glu")})
+    if bias is not None:
+        p["bias"] = jnp.asarray(bias, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(seed + 1), (rows, seq, D))
+    return p, x
+
+
+def _reference(p, x, held):
+    return ref.experts_layer(p, x, top_k=K, route_scale=SCALE, held=held)
+
+
+def _towards(experts):
+    """A bias that sends every token's K choices to ``experts``."""
+    return jnp.zeros((E,)).at[jnp.asarray(experts)].set(10.0)
+
+
+@pytest.mark.parametrize("routing, bias, held_share", [
+    ("even", None, None),
+    ("all_held", _towards([4, 5, 6, 7]), 1.0),
+    ("none_held", _towards([0, 1, 8, 9]), 0.0)])
+def test_the_held_part_matches_the_reference(routing, bias, held_share):
+    held = (4, 4)
+    p, x = _layer(held, bias)
+    w = jax.random.normal(jax.random.PRNGKey(5), x.shape)
+    with jax.default_matmul_precision("highest"):
+        got, stats = moe.dropless_apply(p, _cfg(held), x)
+        want, balance, counts, routed_rms = _reference(p, x, held)
+        np.testing.assert_allclose(got, want, atol=2e-5)
+        grads = [jax.grad(lambda p, x: jnp.sum(f(p, x) * w), (0, 1))(p, x)
+                 for f in (lambda p, x: moe.dropless_apply(p, _cfg(held),
+                                                           x)[0],
+                           lambda p, x: _reference(p, x, held)[0])]
+    for a, b in zip(*(jax.tree_util.tree_leaves(g) for g in grads)):
+        np.testing.assert_allclose(a, b, atol=1e-4 * float(jnp.abs(b).max())
+                                   + 1e-6)
+    tokens = x.shape[0] * x.shape[1]
+    assert float(stats["dropped"]) == 0.0
+    assert float(stats["held_assignments"]) == float(counts[4:8].sum())
+    np.testing.assert_allclose(stats["held_output_rms"], routed_rms,
+                               rtol=1e-4, atol=1e-7)
+    assert (float(routed_rms) == 0.0) == (held_share == 0.0)
+    if held_share is not None:
+        assert float(stats["held_assignments"]) == held_share * tokens * K
+    np.testing.assert_allclose(stats["load_balance"], balance, rtol=1e-5)
+    assert float(stats["load_max_over_mean"]) == pytest.approx(
+        float(counts.max()) * E / (tokens * K))
+    # The bias has no gradient: it only chooses.
+    assert float(jnp.abs(grads[0][0]["bias"]).max()) == 0.0
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """The routed parts of the four shares plus the shared expert, counted
+    once, are the layer that holds every expert."""
+    whole_p, x = _layer(None)
+    with jax.default_matmul_precision("highest"):
+        whole, whole_stats = moe.dropless_apply(whole_p, _cfg(None), x)
+        shared = moe._shared_expert(whole_p["shared"], _cfg(None),
+                                    x.reshape(-1, D)).reshape(x.shape)
+        routed, held = 0.0, 0.0
+        for first in range(0, E, 4):
+            p, _ = _layer((first, 4))
+            part, stats = moe.dropless_apply(p, _cfg((first, 4)), x)
+            routed = routed + (part - shared)
+            held += float(stats["held_assignments"])
+            assert float(stats["dropped"]) == 0.0
+    np.testing.assert_allclose(routed + shared, whole, atol=3e-5)
+    assert held == x.shape[0] * x.shape[1] * K
+    assert "held_assignments" not in whole_stats
+    # And the uncut layer is the reference's with every expert held.
+    with jax.default_matmul_precision("highest"):
+        want, _, _, _ = _reference(whole_p, x, (0, E))
+    np.testing.assert_allclose(whole, want, atol=3e-5)
+
+
+def test_the_bias_moves_towards_an_even_load_and_is_not_in_the_weights():
+    held = (0, 4)
+    p, x = _layer(held, 0.05 * jnp.arange(E) / E)
+    _, stats = moe.dropless_apply(p, _cfg(held), x)
+    _, _, counts, _ = _reference(p, x, held)
+    moved = stats["state_updates"]["bias"] - p["bias"]
+    mean = x.shape[0] * x.shape[1] * K / E
+    np.testing.assert_allclose(
+        moved, RATE * np.sign(mean - np.asarray(counts)), atol=1e-8)
+    assert set(np.round(np.asarray(moved) / RATE).astype(int)) <= {-1, 0, 1}
+    assert float(stats["bias_absmax"]) == pytest.approx(
+        float(jnp.abs(stats["state_updates"]["bias"]).max()))
+    # Weights: route_scale times the chosen scores over their sum, whatever
+    # the bias; planted faults move the output.
+    want, _, _, _ = _reference(p, x, held)
+    for fault in ("bias_in_weights", "normalised_over_held", "no_shared"):
+        broken_p, cfg = dict(p), _cfg(held)
+        if fault == "no_shared":
+            broken_p["shared"] = jax.tree_util.tree_map(jnp.zeros_like,
+                                                        p["shared"])
+            got, _ = moe.dropless_apply(broken_p, cfg, x)
+        elif fault == "bias_in_weights":
+            weights, chosen, scores = ref.route(p, x, top_k=K,
+                                                route_scale=SCALE)
+            biased = jnp.where(weights > 0, scores + p["bias"], 0.0)
+            biased = SCALE * biased / biased.sum(-1, keepdims=True)
+            got = want + jnp.einsum(
+                "bse,ebsd->bsd", (biased - weights)[..., :4],
+                jnp.stack([_one_expert(p, x, e) for e in range(4)]))
+        else:
+            weights, _, _ = ref.route(p, x, top_k=K, route_scale=SCALE)
+            over_held = weights[..., :4]
+            over_held = SCALE * over_held / jnp.maximum(
+                over_held.sum(-1, keepdims=True), 1e-9)
+            got = want + jnp.einsum(
+                "bse,ebsd->bsd", over_held - weights[..., :4],
+                jnp.stack([_one_expert(p, x, e) for e in range(4)]))
+        assert float(jnp.abs(got - want).max()) > 1e-3, fault
+
+
+def _one_expert(p, x, e):
+    return (jax.nn.silu(x @ p["glu"]["kernel"][e]) * (x @ p["up"]["kernel"][e])
+            ) @ p["down"]["kernel"][e]
+
+
+def test_the_softmax_layer_that_holds_every_expert_is_as_it_was():
+    """OLMoE's call: no new statistic, no shared expert, no bias."""
+    cfg = moe.MoEConfig(num_experts=8, top_k=2, d_model=D, d_hidden=H,
+                        dtype=jnp.float32, expert="swiglu", norm_topk=False)
+    p = moe.init(jax.random.PRNGKey(0), cfg)
+    assert set(p) == {"gate", "up", "down", "glu"}
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, D))
+    out, stats = moe.dropless_apply(p, cfg, x)
+    assert set(stats) == {"load_balance", "z_loss", "load_max_over_mean",
+                          "dropped"}
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(moe.dropless_apply(p, cfg, x)[0],
+                                   moe.dense_apply(p, cfg, x)[0], atol=2e-5)
+
+
+@pytest.mark.parametrize("held", [(0, 0), (14, 4), (-1, 2)])
+def test_a_share_outside_the_experts_is_refused(held):
+    with pytest.raises(ValueError, match="consecutive experts"):
+        _cfg(held)
+
+
+def test_the_event_names_the_share():
+    from autodist_tpu import observability
+    p, x = _layer((8, 4))
+    moe.dropless_apply(p, _cfg((8, 4)), x)
+    gauges = observability.registry().snapshot()["gauges"]
+    assert gauges["moe.experts_held"] == 4 and gauges["moe.experts"] == E
+    assert any("experts 8-11 held" in str(e)
+               for e in observability.tracing.events()
+               if e.get("name") == "moe")
+
+
+@pytest.mark.parametrize("kwargs, message", [
+    (dict(scoring="softmax", route_scale=2.5), "route_scale"),
+    (dict(expert="gelu", shared=1), "shared experts are SwiGLU")])
+def test_a_configuration_no_path_computes_is_refused(kwargs, message):
+    with pytest.raises(ValueError, match=message):
+        moe.MoEConfig(num_experts=E, top_k=K, **kwargs)

@@ -371,6 +371,54 @@ def test_v5e_compiler_runs_no_head_split_copy_for_the_packed_layout(
         assert len(copies) >= 6, copies
 
 
+def test_v5e_compiler_takes_the_two_product_kernels_without_a_padded_key(
+        monkeypatch):
+    """One latent-attention layer at JoyAI-LLM-Flash's widths (32 heads,
+    scores 128 + 64 wide, values 128), forward and backward, compiled by
+    libtpu for one detached v5e chip: the three flash kernels in their
+    two-product form are Mosaic custom calls, and no array in the optimized
+    HLO is a 192-wide key, query, value or output of every head and position
+    (nothing is concatenated, broadcast over the heads or padded in HBM)."""
+    why_not = _why_no_detached_topology()
+    if why_not:
+        pytest.skip(why_not)
+    import importlib
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from autodist_tpu.models import layers as L
+    fa = importlib.import_module("autodist_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_pallas_interpret", lambda *_: False)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x4")
+    chip = SingleDeviceSharding(topo.devices[0])
+    b, s, dim, heads, nope, rope, value = 1, 1024, 2048, 32, 128, 64, 128
+    hook = fa.make_flash_attn_fn(causal=True)
+
+    def loss(p, x):
+        y = L.mla(p, x, heads, nope, rope, value,
+                  L.rope_pair_tables(s, rope, 32000000.0),
+                  dtype=jnp.bfloat16, attn_fn=hook, norm_eps=1e-6)
+        return (y.astype(jnp.float32) ** 2).sum()
+    params = jax.eval_shape(lambda: L.mla_init(
+        jax.random.PRNGKey(0), dim, heads, 1536, 512, nope, rope, value))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        params)
+    x = jax.ShapeDtypeStruct((b, s, dim), jnp.bfloat16, sharding=chip)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    kernels = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(kernels) == 3
+    # Around the kernels every operand and result is 128 or 64 lanes wide:
+    # the only 192-wide arrays of the program are W_uq's output and its
+    # gradient, before the split into the two operands and after it.
+    for line in kernels:
+        call = re.sub(r"(metadata|backend_config)=\{.*", "", line)
+        widths = {int(m.group(1)) for m in
+                  re.finditer(r"(?:bf16|f32)\[[\d,]*?(\d+)\]", call)}
+        assert widths <= {1, 2, rope, nope, value}, (widths, call[:300])
+
+
 @pytest.mark.parametrize("chunk, solves", [(64, False), (48, True)])
 def test_v5e_compiler_runs_no_triangular_solve_for_a_chunk_of_64(chunk,
                                                                  solves):
