@@ -5,7 +5,36 @@ compute of ring attention (``parallel/ring_attention.py``). K/V stream
 through VMEM one block per grid step (3-D grid; online-softmax accumulators
 live in VMEM scratch), so neither the (seq x seq) score matrix nor the full
 K/V sequence is VMEM-resident — the long-context regime stays within the
-~16MB/core budget. Fully-masked causal blocks skip their MXU work.
+~16MB/core budget.
+
+Under ``causal`` a program does for each part of its score tile only what
+that part's place against the diagonal needs.  A block whose every score is
+masked is not run (one test a program).  A block that is run is seen as
+sub-tiles of ``_SUB_TILE`` keys (512; one where the block is no longer than
+that, and then nothing below applies), and the program's own positions
+decide among three forms (``_for_keys``, ``_walk``; the offsets are
+scalar-prefetch operands, so ring attention's traced ones are served by the
+same rule).  Wholly below the diagonal: the block whole, without the mask's
+arithmetic (no iota, compare, bias or ``where``).  Every sub-tile holding a
+seen score: the block whole and masked, as before the walk.  A sub-tile
+wholly above the diagonal (the masked quarter of a 1,024-token row under
+blocks of 512 x 1,024; its scores would have added exact zeros): a loop on
+the device over the sub-tiles that hold a seen score, each masked, the rest
+not run; the dk/dv kernel cuts its k side the same way, a sub-tile of keys
+updating its own slice of the dk and dv accumulators.  A block is kept whole
+wherever nothing in it is skipped because what a step costs beside its tile
+(the forward's row statistics above all: 1.5 us a step of 512 rows on the
+v5e whatever the step's width, PERF.md PR 32) is paid once a step, not once
+a program.  Where the loop runs, the forward's running maximum and rescale
+step once a sub-tile: the same f32 sums in another order, no more.  Not
+causal, or one sub-tile a block, the bodies are those of a program with no
+walk, instruction for instruction.  Interpreted (``interpret=True``, the CPU
+tests) nothing is skipped, the interpreter losing scratch writes under a
+skipped conditional: the loop takes every sub-tile through the masked
+arithmetic, whose ``where`` keeps a masked score's weight exactly zero.  The
+``flash`` event and the gauges ``flash.causal_subtiles_visited`` /
+``flash.causal_subtiles_total`` say what a call's shape and offsets make of
+the walk (``_causal_plan``).
 
 Forward emits per-row logsumexp next to the output; backward is the fused
 FlashAttention-2 pair (a dq kernel accumulating over K blocks and a dk/dv
@@ -154,6 +183,13 @@ _VMEM_BUDGET = 12 * 2 ** 20
 # 1.24 at four; at (768, 512, 64), where one row's tile is four times the
 # registers, two rows a step are no faster than one (PERF.md, PR 26).
 _STEP_TILE = 64 * 1024
+# The keys of one sub-tile of a causal program's walk over its k block, capped
+# by the block.  On the v5e a 512 x 512 tile not run saves 0.5 / 1.4 / 1.3 us
+# a (batch, head) row (fwd / dq / dkv); sub-tiles of 256 visit 10 of a
+# 1,024-token row's 16 and cost more than they save (10.3 / 4.9 / 9.3 us a
+# row against 5.1 / 5.4 / 6.5 with no walk: a step of the forward's running
+# softmax costs 1.5 us whatever its width; PERF.md, PR 32).
+_SUB_TILE = 512
 _announced = set()
 
 
@@ -317,12 +353,29 @@ def _heads_per_block(num_heads, d):
     return heads if heads > 1 and num_heads % heads == 0 else None
 
 
-def _announce(kernel, layout, operand, sk, block_q, block_k, rows, vmem_bytes):
+def _announce(kernel, layout, operand, sk, block_q, block_k, rows, vmem_bytes,
+              offsets=None):
     """One info line a distinct kernel and shape, and with telemetry on the
     gauges ``flash.rows_per_program`` and ``flash.heads_per_block`` and a
     ``flash`` event: which layout the shape gave this call and which program
-    the rule above made of it, read at trace time."""
+    the rule above made of it, read at trace time.  ``offsets`` are a causal
+    call's ``(q_offset, k_offset)``: the line then ends with the sub-tiles a
+    (batch, head) row visits by ``_causal_plan`` (decided on the device where
+    an offset is traced), which the gauges ``flash.causal_subtiles_visited``
+    and ``flash.causal_subtiles_total`` carry; both 0 where not causal."""
     sq = operand.shape[1]
+    total = visited = 0
+    walk = "not causal: every score computed"
+    if offsets is not None:
+        sub = _sub_tile(True, block_k)
+        walk = f"causal: sub-tiles of {block_q} x {sub}"
+        if all(isinstance(offset, int) for offset in offsets):
+            total, visited, masked = _causal_plan(sq, sk, block_q, block_k,
+                                                  sub, *offsets)
+            walk = (f"causal: {visited} of {total} sub-tiles of {block_q} x "
+                    f"{sub} visited, {masked} masked")
+        else:
+            walk += " visited by the offsets on the device"
     programs = (layout.units * layout.heads // rows * layout.lane_blocks
                 * (sq // block_q) * (sk // block_k))
     shape = ",".join(str(n) for n in operand.shape)
@@ -330,16 +383,105 @@ def _announce(kernel, layout, operand, sk, block_q, block_k, rows, vmem_bytes):
               f"over {sk} keys: {layout.name} layout, {layout.heads} heads a "
               f"block of {layout.lanes} lanes, blocks {block_q} x {block_k}, "
               f"G = {rows} (batch, head) rows a program, {programs} programs "
-              f"a call, {vmem_bytes} bytes of VMEM by the padded estimate")
+              f"a call, {vmem_bytes} bytes of VMEM by the padded estimate; "
+              f"{walk}")
     _log_path("pallas", detail)
     from autodist_tpu import observability
     if not observability.enabled():
         return
-    observability.registry().gauge("flash.rows_per_program").set(rows)
-    observability.registry().gauge("flash.heads_per_block").set(layout.heads)
+    registry = observability.registry()
+    registry.gauge("flash.rows_per_program").set(rows)
+    registry.gauge("flash.heads_per_block").set(layout.heads)
+    registry.gauge("flash.causal_subtiles_visited").set(visited)
+    registry.gauge("flash.causal_subtiles_total").set(total)
     if detail not in _announced:
         _announced.add(detail)
         observability.record_event("flash", detail)
+
+
+def _sub_tile(causal, block_k):
+    """The keys a step of a program's walk over its k block takes: under
+    ``causal`` a sub-tile of ``_SUB_TILE`` where the block holds several, the
+    whole block otherwise (and then the bodies are those of a program with no
+    walk, instruction for instruction)."""
+    if causal and block_k > _SUB_TILE and block_k % _SUB_TILE == 0:
+        return _SUB_TILE
+    return block_k
+
+
+def _walk(q_start, k_start, block_q, block_k, sub):
+    """``(below, visited)`` of a causal program whose score tile has its
+    first row at position ``q_start`` and its first key at ``k_start``: of
+    its ``block_k / sub`` sub-tiles of keys, in order, the first ``below`` lie
+    wholly below the diagonal (every score seen: ``k_start + (j + 1) sub - 1
+    <= q_start``), those up to ``visited`` hold a seen score (``q_start +
+    block_q - 1 >= k_start + j sub``), and the rest are wholly masked.  Python
+    integers give integers (``_causal_plan``), the device's values traced
+    ones: the one rule for what a kernel runs and for what it says it ran."""
+    def tiles(keys):
+        """Whole sub-tiles in ``keys`` keys, none for a negative count and no
+        more than the block's."""
+        if isinstance(keys, int):
+            return min(max(keys, 0) // sub, block_k // sub)
+        # ``lax.div`` rounds towards zero: a negative count gives at most 0.
+        return jnp.clip(jax.lax.div(keys, sub), 0, block_k // sub)
+    return (tiles(q_start - k_start + 1),
+            tiles(q_start + block_q - 1 - k_start + sub))
+
+
+def _causal_plan(sq, sk, block_q, block_k, sub, q_offset=0, k_offset=0):
+    """``(sub-tiles a (batch, head) row, those visited, of them those that go
+    through the mask's arithmetic)`` of a causal call with integer offsets,
+    by ``_walk`` and ``_for_keys``' rule: a block wholly below the diagonal
+    runs unmasked, every other visited sub-tile masked."""
+    n = block_k // sub
+    walks = [_walk(q_offset + q, k_offset + k, block_q, block_k, sub)
+             for q in range(0, sq, block_q) for k in range(0, sk, block_k)]
+    return (len(walks) * n, sum(visited for _, visited in walks),
+            sum(visited for below, visited in walks if below < n))
+
+
+def _for_keys(skip_blocks, q_start, k_start, block_q, block_k, sub, run):
+    """``run(keys, k_first, seen)`` over what a causal program's k block
+    holds of seen scores, in the widest steps that hold no wholly masked
+    sub-tile.  ``keys`` indexes a step's keys in a block's positions (None:
+    the whole block), ``k_first`` is the position of its first key, and
+    ``seen`` is True where every score of the step is seen and it needs no
+    mask.  One sub-tile a block: the block, masked, and nothing else is
+    traced.  Else, by the device's ``_walk``, one of three: the block whole
+    and unmasked where it lies wholly below the diagonal; whole and masked
+    where its last sub-tile still holds a seen score (what a step costs
+    beside its tile, the row statistics' arithmetic above all, is then paid
+    once a block, as without the walk); and where it does not, a loop on the
+    device over the sub-tiles that do, each masked (traced once whatever
+    their number), the rest not run.  Without ``skip_blocks`` (the
+    interpreter) that loop takes every sub-tile."""
+    if sub == block_k:
+        run(None, k_start, False)
+        return
+    n = block_k // sub
+
+    def step(j, carry):
+        first = pl.multiple_of(j * sub, sub)
+        run(pl.ds(first, sub), k_start + first, False)
+        return carry
+    if not skip_blocks:
+        jax.lax.fori_loop(0, n, step, 0)
+        return
+    below, visited = _walk(q_start, k_start, block_q, block_k, sub)
+    pl.when(below == n)(lambda: run(None, k_start, True))
+    pl.when(jnp.logical_and(visited == n, below < n))(
+        lambda: run(None, k_start, False))
+
+    @pl.when(visited < n)
+    def _walked():
+        jax.lax.fori_loop(0, visited, step, 0)
+
+
+def _keys_of(at, keys):
+    """The index ``at`` of a program's rows in an operand's block, narrowed
+    to the positions ``keys`` of a sub-tile."""
+    return at if keys is None else (at, keys)
 
 
 def _for_rows(rows, heads, tile, body):
@@ -421,16 +563,19 @@ def _over_lanes(stat, d, like):
     return out
 
 
-def _row_of(ref, at, head=None):
+def _row_of(ref, at, head=None, keys=None):
     """The rows ``at`` of a program's scratch or statistics.  Scratch has no
     rows dimension in a program of one row of the split layout
     (``_scratch``): the long-sequence cells' kernels compile to the same
     Mosaic module whether or not short rows group.  The packed layout's
     statistics are ``(rows, heads, block, 1)`` and give those of ``head``
-    (the one head where a block holds one)."""
+    (the one head where a block holds one).  ``keys`` narrows an accumulator
+    over a k block to a sub-tile's positions."""
     if len(ref.shape) == 4:
         return at, 0 if head is None else head
-    return slice(None) if len(ref.shape) == 2 else at
+    if len(ref.shape) == 2:
+        return slice(None) if keys is None else keys
+    return _keys_of(at, keys)
 
 
 def _all_rows(scratch):
@@ -447,9 +592,10 @@ def _scratch(layout, n, shape):
 
 @functools.partial(jax.jit, inline=True, static_argnames=(
     "name", "body", "layout", "n", "grid_tail", "ins", "outs", "scratch",
-    "block_q", "block_k", "causal", "interpret", "scale"))
+    "block_q", "block_k", "sub", "causal", "interpret", "scale"))
 def _kernel_call(offs, *arrays, name, body, layout, n, grid_tail, ins, outs,
-                 scratch, block_q, block_k, causal, interpret, scale=None):
+                 scratch, block_q, block_k, sub, causal, interpret,
+                 scale=None):
     """One kernel over ``arrays`` in the kernels' own shapes, ``n`` units a
     program.  ``ins`` give each array's block as ``(length, which of the
     grid's indices places it along the sequence, whether it is a row
@@ -458,7 +604,9 @@ def _kernel_call(offs, *arrays, name, body, layout, n, grid_tail, ins, outs,
     entry of either may end in the lanes of an operand whose width is its
     own and, for an input, whether a batch row's heads share it
     (``_Layout.spec``).  ``scale`` is given for the two-product form, whose
-    bodies read two more operands (``_two_product``).
+    bodies read two more operands (``_two_product``); ``sub`` is the keys a
+    sub-tile of a causal program's k block (``_sub_tile``: the caller reads
+    the module's constant, so that this function's cache is keyed by it).
 
     An inlined ``jit``: a model's layers make the same call, and every one
     after the first takes the first's equations from the cache, the kernel's
@@ -469,7 +617,8 @@ def _kernel_call(offs, *arrays, name, body, layout, n, grid_tail, ins, outs,
     form = {} if scale is None else {"scale": scale}
     return pl.pallas_call(
         functools.partial(body, d=layout.d, block_q=block_q, block_k=block_k,
-                          causal=causal, skip_blocks=not interpret, **form),
+                          sub=sub, causal=causal, skip_blocks=not interpret,
+                          **form),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(layout.units // n * layout.lane_blocks,) + grid_tail,
@@ -559,11 +708,12 @@ def _two_product(refs, n_in, scale):
     return refs[:n_in] + refs[n_in + 2:], refs[n_in], refs[n_in + 1]
 
 
-def _fwd_kernel(offs_ref, *refs, d, block_q, block_k, causal, skip_blocks,
-                scale=None):
+def _fwd_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
+                skip_blocks, scale=None):
     """Grid (units / rows a program x lane blocks, q-blocks, k-blocks): k
     innermost, accumulators in VMEM scratch carried across the k dimension,
-    each of a program's rows with its own; ``d`` lanes a head."""
+    each of a program's rows with its own; ``d`` lanes a head; ``sub`` keys a
+    step of the walk over the k block (``_for_keys``)."""
     (q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l), qr_ref, kr_ref = \
         _two_product(refs, 3, scale)
     rows = q_ref.shape[0]
@@ -582,7 +732,8 @@ def _fwd_kernel(offs_ref, *refs, d, block_q, block_k, causal, skip_blocks,
     q_start = offs_ref[0] + iq * block_q
     k_start = offs_ref[1] + ik * block_k
     # A causal block is fully masked iff its largest q position is still
-    # left of its smallest k position — skip the MXU work entirely.
+    # left of its smallest k position — skip the MXU work entirely; inside
+    # a block that is not, ``_for_keys`` makes the same test a sub-tile.
     # ``skip_blocks`` is off in interpret mode (the Pallas interpreter's
     # state discharge loses multi-scratch writes under a skipped
     # runtime-conditional); the p-masking below keeps skipped-block
@@ -592,32 +743,36 @@ def _fwd_kernel(offs_ref, *refs, d, block_q, block_k, causal, skip_blocks,
 
     @pl.when(visible)
     def _block():
-        def _rows(at, head):
-            row, stat = _row_of(acc, at), _row_of(m, at, head)
-            q = q_ref[at]
-            k = k_ref[at]
-            v = v_ref[at]
-            keep = _lanes_of(head, d, q)
-            q, v = _only(keep, q), _only(keep, v)
-            s = _dot(q, k, -1, -1)
-            if qr_ref is not None:
-                s = s + _dot(qr_ref[at], kr_ref[0], -1, -1)
-            s = s * scale
-            if causal:
-                s = s + causal_bias(block_q, block_k, q_start, k_start)
-            m_prev = m[stat]
-            m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            # Masked entries contribute EXACTLY zero (not exp(-1e30 - m)): in
-            # a fully-masked block m_new stays at the sentinel and
-            # s - m_new = 0.
-            p = jnp.where(s > _NEG_INF / 2, jnp.exp(s - m_new), 0.0)
-            l[stat] = l[stat] * alpha + p.sum(-1, keepdims=True)
-            if keep is not None:    # the other heads' lanes keep theirs
-                alpha = jnp.where(keep, alpha, 1.0)
-            acc[row] = acc[row] * alpha + _dot(p.astype(v.dtype), v, -1, -2)
-            m[stat] = m_new
-        _for_rows(rows, q_ref.shape[-1] // d, block_q * block_k, _rows)
+        def _run(keys, k_first, seen):
+            def _rows(at, head):
+                row, stat = _row_of(acc, at), _row_of(m, at, head)
+                q = q_ref[at]
+                k = k_ref[_keys_of(at, keys)]
+                v = v_ref[_keys_of(at, keys)]
+                keep = _lanes_of(head, d, q)
+                q, v = _only(keep, q), _only(keep, v)
+                s = _dot(q, k, -1, -1)
+                if qr_ref is not None:
+                    s = s + _dot(qr_ref[at], kr_ref[_keys_of(0, keys)], -1, -1)
+                s = s * scale
+                if causal and not seen:
+                    s = s + causal_bias(block_q, k.shape[-2], q_start, k_first)
+                m_prev = m[stat]
+                m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                # Masked entries contribute EXACTLY zero (not exp(-1e30 -
+                # m)): in a fully-masked block m_new stays at the sentinel
+                # and s - m_new = 0.
+                p = jnp.exp(s - m_new) if seen else jnp.where(
+                    s > _NEG_INF / 2, jnp.exp(s - m_new), 0.0)
+                l[stat] = l[stat] * alpha + p.sum(-1, keepdims=True)
+                if keep is not None:    # the other heads' lanes keep theirs
+                    alpha = jnp.where(keep, alpha, 1.0)
+                acc[row] = acc[row] * alpha + _dot(p.astype(v.dtype), v, -1,
+                                                   -2)
+                m[stat] = m_new
+            _for_rows(rows, q_ref.shape[-1] // d, block_q * block_k, _rows)
+        _for_keys(skip_blocks, q_start, k_start, block_q, block_k, sub, _run)
 
     @pl.when(ik == num_kb - 1)
     def _finalize():
@@ -666,7 +821,8 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, k_offset,
         [(q_block, q.dtype), (k_block, k.dtype), (k_block, v.dtype),
          (q_block, out_dtype), (row_block, f32)],
         [(shape, f32) for shape in scratch], layout.heads)
-    _announce("flash_fwd", layout, qr, sk, block_q, block_k, g, vmem)
+    _announce("flash_fwd", layout, qr, sk, block_q, block_k, g, vmem,
+              (q_offset, k_offset) if causal else None)
     # q's blocks follow the grid's second index, k's and v's its third.
     out, lse = _kernel_call(
         offs, qr, kr, vr, name="flash_fwd", body=_fwd_kernel, layout=layout,
@@ -674,8 +830,8 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, k_offset,
         ins=((block_q, 1, False), (block_k, 2, False), (block_k, 2, False)),
         outs=((block_q, 1, False, out_dtype, sq),
               (block_q, 1, True, f32, sq)),
-        scratch=scratch, block_q=block_q, block_k=block_k, causal=causal,
-        interpret=interpret)
+        scratch=scratch, block_q=block_q, block_k=block_k,
+        sub=_sub_tile(causal, block_k), causal=causal, interpret=interpret)
     return layout.result(out), layout.result(lse, stat=True)
 
 
@@ -683,8 +839,8 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, k_offset,
 # backward kernels (FlashAttention-2: dq over K blocks, dk/dv over Q blocks)
 
 
-def _bwd_dq_kernel(offs_ref, *refs, d, block_q, block_k, causal, skip_blocks,
-                   scale=None):
+def _bwd_dq_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
+                   skip_blocks, scale=None):
     refs, qr_ref, kr_ref = _two_product(refs, 6, scale)
     if qr_ref is None:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -712,28 +868,32 @@ def _bwd_dq_kernel(offs_ref, *refs, d, block_q, block_k, causal, skip_blocks,
 
     @pl.when(visible)
     def _block():
-        def _rows(at, head):
-            stat = _row_of(lse_ref, at, head)
-            q = q_ref[at]
-            k = k_ref[at]
-            v = v_ref[at]
-            do = do_ref[at]
-            keep = _lanes_of(head, d, q)
-            k, v = _only(keep, k), _only(keep, v)
-            s = _dot(q, k, -1, -1)
-            if qr_ref is not None:
-                kr = kr_ref[0]
-                s = s + _dot(qr_ref[at], kr, -1, -1)
-            s = s * scale
-            if causal:
-                s = s + causal_bias(block_q, block_k, q_start, k_start)
-            p = jnp.where(s > _NEG_INF / 2, jnp.exp(s - lse_ref[stat]), 0.0)
-            dp = _dot(do, v, -1, -1)
-            ds = p * (dp - delta_ref[stat]) * scale
-            dq_acc[_row_of(dq_acc, at)] += _dot(ds.astype(k.dtype), k, -1, -2)
-            if qr_ref is not None:
-                dqr_acc[:] += _dot(ds.astype(kr.dtype), kr, -1, -2)
-        _for_rows(rows, q_ref.shape[-1] // d, block_q * block_k, _rows)
+        def _run(keys, k_first, seen):
+            def _rows(at, head):
+                stat = _row_of(lse_ref, at, head)
+                q = q_ref[at]
+                k = k_ref[_keys_of(at, keys)]
+                v = v_ref[_keys_of(at, keys)]
+                do = do_ref[at]
+                keep = _lanes_of(head, d, q)
+                k, v = _only(keep, k), _only(keep, v)
+                s = _dot(q, k, -1, -1)
+                if qr_ref is not None:
+                    kr = kr_ref[_keys_of(0, keys)]
+                    s = s + _dot(qr_ref[at], kr, -1, -1)
+                s = s * scale
+                if causal and not seen:
+                    s = s + causal_bias(block_q, k.shape[-2], q_start, k_first)
+                p = jnp.exp(s - lse_ref[stat]) if seen else jnp.where(
+                    s > _NEG_INF / 2, jnp.exp(s - lse_ref[stat]), 0.0)
+                dp = _dot(do, v, -1, -1)
+                ds = p * (dp - delta_ref[stat]) * scale
+                dq_acc[_row_of(dq_acc, at)] += _dot(ds.astype(k.dtype), k, -1,
+                                                    -2)
+                if qr_ref is not None:
+                    dqr_acc[:] += _dot(ds.astype(kr.dtype), kr, -1, -2)
+            _for_rows(rows, q_ref.shape[-1] // d, block_q * block_k, _rows)
+        _for_keys(skip_blocks, q_start, k_start, block_q, block_k, sub, _run)
 
     @pl.when(ik == num_kb - 1)
     def _finalize():
@@ -742,7 +902,7 @@ def _bwd_dq_kernel(offs_ref, *refs, d, block_q, block_k, causal, skip_blocks,
             dqr_ref[0] = dqr_acc[:].astype(dqr_ref.dtype)
 
 
-def _bwd_dkv_kernel(offs_ref, *refs, d, block_q, block_k, causal,
+def _bwd_dkv_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
                     skip_blocks, scale=None):
     refs, qr_ref, kr_ref = _two_product(refs, 6, scale)
     if qr_ref is None:
@@ -772,29 +932,35 @@ def _bwd_dkv_kernel(offs_ref, *refs, d, block_q, block_k, causal,
 
     @pl.when(visible)
     def _block():
-        def _rows(at, head):
-            row, stat = _row_of(dk_acc, at), _row_of(lse_ref, at, head)
-            q = q_ref[at]
-            k = k_ref[at]
-            v = v_ref[at]
-            do = do_ref[at]
-            keep = _lanes_of(head, d, q)
-            q, do = _only(keep, q), _only(keep, do)
-            s = _dot(q, k, -1, -1)
-            if qr_ref is not None:
-                qr = qr_ref[at]
-                s = s + _dot(qr, kr_ref[0], -1, -1)
-            s = s * scale
-            if causal:
-                s = s + causal_bias(block_q, block_k, q_start, k_start)
-            p = jnp.where(s > _NEG_INF / 2, jnp.exp(s - lse_ref[stat]), 0.0)
-            dv_acc[row] += _dot(p.astype(do.dtype), do, -2, -2)    # p^T do
-            dp = _dot(do, v, -1, -1)
-            ds = p * (dp - delta_ref[stat]) * scale
-            dk_acc[row] += _dot(ds.astype(q.dtype), q, -2, -2)     # ds^T q
-            if qr_ref is not None:      # this head's part of the shared key's
-                dkr_acc[:] += _dot(ds.astype(qr.dtype), qr, -2, -2)
-        _for_rows(rows, q_ref.shape[-1] // d, block_q * block_k, _rows)
+        def _run(keys, k_first, seen):
+            def _rows(at, head):
+                # A sub-tile of keys updates its own slice of dk and dv.
+                row = _row_of(dk_acc, at, keys=keys)
+                stat = _row_of(lse_ref, at, head)
+                q = q_ref[at]
+                k = k_ref[_keys_of(at, keys)]
+                v = v_ref[_keys_of(at, keys)]
+                do = do_ref[at]
+                keep = _lanes_of(head, d, q)
+                q, do = _only(keep, q), _only(keep, do)
+                s = _dot(q, k, -1, -1)
+                if qr_ref is not None:
+                    qr = qr_ref[at]
+                    s = s + _dot(qr, kr_ref[_keys_of(0, keys)], -1, -1)
+                s = s * scale
+                if causal and not seen:
+                    s = s + causal_bias(block_q, k.shape[-2], q_start, k_first)
+                p = jnp.exp(s - lse_ref[stat]) if seen else jnp.where(
+                    s > _NEG_INF / 2, jnp.exp(s - lse_ref[stat]), 0.0)
+                dv_acc[row] += _dot(p.astype(do.dtype), do, -2, -2)  # p^T do
+                dp = _dot(do, v, -1, -1)
+                ds = p * (dp - delta_ref[stat]) * scale
+                dk_acc[row] += _dot(ds.astype(q.dtype), q, -2, -2)   # ds^T q
+                if qr_ref is not None:  # this head's part of the shared key's
+                    dkr_acc[_row_of(dkr_acc, at, keys=keys)] += _dot(
+                        ds.astype(qr.dtype), qr, -2, -2)
+            _for_rows(rows, q_ref.shape[-1] // d, block_q * block_k, _rows)
+        _for_keys(skip_blocks, q_start, k_start, block_q, block_k, sub, _run)
 
     @pl.when(iq == num_qb - 1)
     def _finalize():
@@ -832,7 +998,8 @@ def _flash_bwd(q, k, v, do, lse, delta, causal, block_q, block_k, q_offset,
             layout.units, block_q, block_k,
             in_blocks + [(out_block, out_dtype)] * n_out,
             [(out_block, f32)] * n_out, layout.heads)
-        _announce(name, layout, qr, sk, block_q, block_k, g, vmem)
+        _announce(name, layout, qr, sk, block_q, block_k, g, vmem,
+                  (q_offset, k_offset) if causal else None)
         at_q, at_k = (block_q, at_q, False), (block_k, at_k, False)
         return _kernel_call(
             offs, qr, kr, vr, dor, lser, deltar, name=name, body=body,
@@ -841,7 +1008,8 @@ def _flash_bwd(q, k, v, do, lse, delta, causal, block_q, block_k, q_offset,
                  at_q[:2] + (True,)),
             outs=((out_block[0], 1, False, out_dtype, out_len),) * n_out,
             scratch=(out_block,) * n_out, block_q=block_q, block_k=block_k,
-            causal=causal, interpret=interpret)
+            sub=_sub_tile(causal, block_k), causal=causal,
+            interpret=interpret)
 
     # dq: q blocks outside, accumulated over the k blocks inside; dk and dv:
     # k blocks outside, accumulated over the q blocks inside.
@@ -898,14 +1066,15 @@ def _two_product_plan(q, q_rope, k, k_rope, v, block_q, block_k):
 
 
 def _announce_two_product(kernel, layout, q, sk, blocks, widths, in_blocks,
-                          out_blocks):
+                          out_blocks, causal):
     r, dv = widths
     _, vmem = _rows_per_program(
         1, *blocks, in_blocks + out_blocks,
         [(shape, jnp.float32) for shape, _ in out_blocks])
     _announce(f"{kernel} two-product ({layout.d} + {r} lanes a score, one "
               f"{r}-lane key a position shared by {layout.num_heads} heads, "
-              f"values of {dv})", layout, q, sk, *blocks, 1, vmem)
+              f"values of {dv})", layout, q, sk, *blocks, 1, vmem,
+              (0, 0) if causal else None)
 
 
 def _flash_fwd2(q, q_rope, k, k_rope, v, scale, causal, block_q, block_k,
@@ -923,7 +1092,7 @@ def _flash_fwd2(q, q_rope, k, k_rope, v, scale, causal, block_q, block_k,
         [((block_q, layout.d), q.dtype), ((block_k, layout.d), k.dtype),
          ((block_k, dv), v.dtype), ((block_q, r), q.dtype),
          ((block_k, r), k_rope.dtype)],
-        [(o_block, q.dtype), (row_block, f32)])
+        [(o_block, q.dtype), (row_block, f32)], causal)
     out, lse = _kernel_call(
         jnp.zeros((2,), jnp.int32), qr, kr, vr, qrr, krr, name="flash_fwd",
         body=_fwd_kernel, layout=layout, n=1,
@@ -931,7 +1100,8 @@ def _flash_fwd2(q, q_rope, k, k_rope, v, scale, causal, block_q, block_k,
         outs=((block_q, 1, False, jnp.dtype(q.dtype), sq, dv),
               (block_q, 1, True, f32, sq)),
         scratch=(o_block, row_block, row_block), block_q=block_q,
-        block_k=block_k, causal=causal, interpret=interpret, scale=scale)
+        block_k=block_k, sub=_sub_tile(causal, block_k), causal=causal,
+        interpret=interpret, scale=scale)
     return (out.reshape(q.shape[:3] + (dv,)),
             layout.result(lse, stat=True))
 
@@ -961,7 +1131,8 @@ def _flash_bwd2(q, q_rope, k, k_rope, v, do, lse, delta, scale, causal,
             [((block_q, layout.d), dtype), ((block_k, layout.d), dtype),
              ((block_k, dv), dtype), ((block_q, dv), dtype),
              ((block_q, 1), f32), ((block_q, 1), f32),
-             ((block_q, r), dtype), ((block_k, r), dtype)], out_blocks)
+             ((block_q, r), dtype), ((block_k, r), dtype)], out_blocks,
+            causal)
         return _kernel_call(
             jnp.zeros((2,), jnp.int32), qr, kr, vr, dor, lser, deltar, qrr,
             krr, name=name, body=body, layout=layout, n=1,
@@ -970,7 +1141,8 @@ def _flash_bwd2(q, q_rope, k, k_rope, v, do, lse, delta, scale, causal,
             outs=tuple((length, 1, False, dtype, whole, lanes)
                        for length, whole, lanes in outs),
             scratch=tuple(shape for shape, _ in out_blocks), block_q=block_q,
-            block_k=block_k, causal=causal, interpret=interpret, scale=scale)
+            block_k=block_k, sub=_sub_tile(causal, block_k), causal=causal,
+            interpret=interpret, scale=scale)
 
     dq, dq_rope = call("flash_bwd_dq", _bwd_dq_kernel,
                        (sq // block_q, sk // block_k), 1, 2,

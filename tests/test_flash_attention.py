@@ -656,3 +656,233 @@ def test_flash_event_and_gauges_carry_the_program_at_trace_time(
         programs = 1 if layout == "split" else 2
         assert f" {programs} programs a call" in detail and "VMEM" in detail
         assert f"flash_attention: pallas path ({detail})" in lines
+
+
+# ---------------------------------------------------------------------------
+# the causal walk: a program takes its k block in sub-tiles and runs those
+# that hold a seen score
+
+
+# The JoyAI cell's kernel call (two-product, split layout) beside the others.
+_JOYAI = (1, 32, 4096, 128)
+
+
+@pytest.mark.parametrize("shape,causal,want", [
+    (_CELL_SHAPES["gpt2-medium.train-s1024"][0], True, (4, 3, 3)),
+    (_CELL_SHAPES["gpt2-xl.train-s1024-x4"][0], True, (4, 3, 3)),
+    (_CELL_SHAPES["olmoe-1b-7b.train-s4096"][0], True, (64, 36, 12)),
+    (_JOYAI, True, (64, 36, 12)),
+    (_CELL_SHAPES["bert-base.mlm-s512"][0], True, (1, 1, 1)),
+    (_CELL_SHAPES["bert-base.mlm-s128"][0], True, (1, 1, 1)),
+    (_CELL_SHAPES["bert-base.mlm-s512"][0], False, (0, 0, 0)),
+    (_CELL_SHAPES["bert-base.mlm-s128"][0], False, (0, 0, 0)),
+], ids=["gpt2-medium", "gpt2-xl", "olmoe", "joyai", "s512-causal",
+        "s128-causal", "bert-s512", "bert-s128"])
+def test_causal_plan_at_the_cells_shapes(shape, causal, want, monkeypatch):
+    """Three of four 512 x 512 sub-tiles of a causal 1,024-token row are
+    visited (all three through the mask: no block of such a row lies wholly
+    below the diagonal), 36 of 64 at 4,096 (12 through the mask: a row's
+    twelve blocks wholly below the diagonal run their 24 without it), the
+    one tile of a row of 512 or 128; a call that is not causal has no walk,
+    and its line and gauges say so."""
+    from autodist_tpu import observability
+    from autodist_tpu.observability import recorder
+    _, _, s, _ = shape
+    block_q, block_k = min(s, 512), min(s, 1024)
+    sub = fa._sub_tile(causal, block_k)
+    assert sub == min(block_k, fa._SUB_TILE) == min(s, 512)
+    plan = fa._causal_plan(s, s, block_q, block_k, sub) if causal \
+        else (0, 0, 0)
+    assert plan == want
+    observability.reset()
+    monkeypatch.setattr(fa, "_logged_paths", set())
+    monkeypatch.setattr(fa, "_announced", set())
+    layout = fa._Layout(False, 1, 1, 64)
+    fa._announce("flash_fwd", layout, jax.ShapeDtypeStruct((1, s, 64),
+                                                           jnp.bfloat16),
+                 s, block_q, block_k, 1, 0, (0, 0) if causal else None)
+    (detail,) = [e["detail"] for e in recorder.events()
+                 if e["kind"] == "flash"]
+    total, visited, masked = want
+    assert detail.endswith(
+        f"; causal: {visited} of {total} sub-tiles of {block_q} x {sub} "
+        f"visited, {masked} masked" if causal
+        else "; not causal: every score computed"), detail
+    gauges = observability.registry().snapshot()["gauges"]
+    assert gauges["flash.causal_subtiles_visited"] == visited
+    assert gauges["flash.causal_subtiles_total"] == total
+
+
+@pytest.mark.parametrize("q_offset,k_offset,want", [
+    (0, 1024, (2, 0, 0)),        # a block wholly above the diagonal
+    (0, 512, (2, 0, 0)),         # its last row still left of the first key
+    (1024, 0, (2, 2, 0)),        # wholly below: all visited, none masked
+    (1022, 0, (2, 2, 2)),        # one row short of that: the block is masked
+    (512, 0, (2, 2, 2)),         # straddling: one below, one on the diagonal
+    (0, 0, (2, 1, 1)),
+    (512, 256, (2, 2, 2)),       # keys offset by half a sub-tile: both masked
+    (0, -1024, (2, 2, 0)),
+], ids=["above", "just-above", "below", "nearly-below", "straddling",
+        "diagonal", "half-a-sub-tile", "keys-before"])
+def test_causal_plan_follows_the_offsets(q_offset, k_offset, want):
+    """One 512 x 1,024 program of ring attention's hop, by its offsets; the
+    device's rule (traced integers through ``lax.div``) counts what the
+    Python one does, and both count the sub-tiles that hold a seen score."""
+    assert fa._causal_plan(512, 1024, 512, 1024, 512, q_offset,
+                           k_offset) == want
+    below, visited = jax.jit(lambda q, k: fa._walk(q, k, 512, 1024, 512))(
+        jnp.int32(q_offset), jnp.int32(k_offset))
+    assert (2, int(visited), 0 if int(below) == 2 else int(visited)) == want
+    q_pos = q_offset + np.arange(512)[:, None]
+    k_pos = k_offset + np.arange(1024)[None, :]
+    seen = (q_pos >= k_pos).reshape(512, 2, 512)
+    assert [bool(seen[:, j].any()) for j in range(2)] == \
+        [j < int(visited) for j in range(2)]
+    assert [bool(seen[:, j].all()) for j in range(2)] == \
+        [j < int(below) for j in range(2)]
+
+
+def _masked_dense(q, k, v, q_offset, k_offset):
+    """Causal dense attention of (batch, heads, s, d) operands at the given
+    offsets with the kernels' convention for a row that sees no key: o = 0
+    and lse at the finite sentinel."""
+    with jax.default_matmul_precision("highest"):
+        o, lse = fa._dense_fwd(q, k, v, True, q_offset, k_offset)
+    empty = lse <= fa._NEG_INF / 2
+    return jnp.where(empty, 0.0, o), jnp.where(empty, fa._NEG_INF, lse)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("q_offset,k_offset", [(0, 0), (0, 16), (64, 0)],
+                         ids=["diagonal", "a-q-block-sees-nothing",
+                              "wholly-below"])
+@pytest.mark.parametrize("packed,units", [(False, 1), (False, 4), (True, 1),
+                                          (True, 2)],
+                         ids=["split-one-row", "split-four-rows",
+                              "packed-one-unit", "packed-two-units"])
+@pytest.mark.parametrize("sub", [32, 16], ids=["two-sub-tiles",
+                                               "four-sub-tiles"])
+def test_sub_tiles_of_a_causal_block_match_dense(sub, packed, units, q_offset,
+                                                 k_offset, dtype,
+                                                 monkeypatch):
+    """A 16 x 64 block walked in two and in four sub-tiles (the module's
+    constant steered, as the rule for the rows is: no argument selects it),
+    interpreted, against the dense reference in f32: o, lse and the three
+    gradients, both layouts, one and several rows a program, and offsets
+    that leave the first q block with no key to see (its rows' o is 0, their
+    lse the finite sentinel, and they add nothing to dk and dv)."""
+    monkeypatch.setattr(fa, "_SUB_TILE", sub)
+    b, h, sq, sk, d = 2, 2, 32, 64, 64 if packed else 16
+    _force_rows(monkeypatch, units * (2 if packed else 1))
+    ks = jax.random.split(jax.random.PRNGKey(sub + q_offset + k_offset), 4)
+    q, do = (jax.random.normal(kk, (b, h, sq, d)).astype(dtype)
+             for kk in ks[:2])
+    k, v = (jax.random.normal(kk, (b, h, sk, d)).astype(dtype)
+            for kk in ks[2:])
+    lay = _swap if packed else (lambda x: x)
+    assert fa._sub_tile(True, 64) == sub
+    o, lse = fa._flash_fwd(lay(q), lay(k), lay(v), True, 16, 64, q_offset,
+                           k_offset, True, packed=packed)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    want_o, want_lse = _masked_dense(*f32, q_offset, k_offset)
+    tol = dict(rtol=2e-2, atol=2e-2) if dtype == jnp.bfloat16 \
+        else dict(rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lay(o), np.float32),
+                               np.asarray(want_o), **tol)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                               rtol=2e-5 if dtype == jnp.float32 else 2e-2)
+    if (q_offset, k_offset) == (0, 16):
+        assert np.all(np.asarray(lse)[:, :, :16] == fa._NEG_INF)
+        assert np.all(np.asarray(lay(o), np.float32)[:, :, :16] == 0)
+    delta = (do.astype(jnp.float32) * lay(o).astype(jnp.float32)) \
+        .sum(-1, keepdims=True)
+    got = fa._flash_bwd(lay(q), lay(k), lay(v), lay(do), lse, delta, True,
+                        16, 64, q_offset, k_offset, True, packed=packed)
+    want = jax.grad(lambda *x: (_masked_dense(*x, q_offset, k_offset)[0]
+                                * do.astype(jnp.float32)).sum(),
+                    argnums=(0, 1, 2))(*f32)
+    scale = 8 if dtype == jnp.bfloat16 else 1
+    for g, e, x in zip(got, want, (q, k, v)):
+        assert g.dtype == dtype and lay(g).shape == x.shape
+        np.testing.assert_allclose(np.asarray(lay(g), np.float32),
+                                   np.asarray(e), rtol=tol["rtol"],
+                                   atol=scale * tol["atol"])
+
+
+def _count(jaxpr):
+    """Equations of a jaxpr and of every jaxpr under them, each once."""
+    n = len(jaxpr.eqns)
+    for eqn in jaxpr.eqns:
+        for param in eqn.params.values():
+            for x in param if isinstance(param, (list, tuple)) else (param,):
+                inner = getattr(x, "jaxpr", x)
+                if hasattr(inner, "eqns"):
+                    n += _count(inner)
+    return n
+
+
+def _traced_kernels(shape, causal, interpret=False):
+    """``{kernel: (equations, hash of the body's text)}`` of the three
+    kernels' traced bodies at a cell's shape, through the hook, in the form
+    the chip runs (``interpret`` False: sub-tiles skipped by the device's
+    offsets) or the interpreter's; nothing is lowered or run."""
+    import hashlib
+    b, h, s, d = shape
+    resolve = fa._pallas_interpret
+    fa._pallas_interpret = lambda *_: interpret
+    try:
+        hook = fa.make_flash_attn_fn(causal)
+        x = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda q, k, v: (_attend(hook, q, k, v).astype(jnp.float32)
+                             ** 2).sum(), argnums=(0, 1, 2)))(x, x, x)
+    finally:
+        fa._pallas_interpret = resolve
+    return {name: (_count(eqn.params["jaxpr"]), hashlib.sha1(
+        str(eqn.params["jaxpr"]).encode()).hexdigest()[:16])
+        for name, eqn in _kernels(jaxpr.jaxpr).items()}
+
+
+# What the commit before the walk (c556520, PR 31) traced: each kernel's
+# equations, nested ones counted once, and where the walk must change
+# nothing the hash of the body's text.
+_BEFORE_THE_WALK = {
+    "bert-base.mlm-s512": {"flash_fwd": (113, "b584a10a3c262514"),
+                           "flash_bwd_dq": (70, "a2c9955b45875af7"),
+                           "flash_bwd_dkv": (80, "e416c5bb716a954f")},
+    "bert-base.mlm-s128": {"flash_fwd": (115, "6501763fbc08426b"),
+                           "flash_bwd_dq": (72, "f65bbc359df74fec"),
+                           "flash_bwd_dkv": (82, "1b2a104d4e381bd7")},
+    "gpt2-medium.train-s1024": {"flash_fwd": (124, None),
+                                "flash_bwd_dq": (81, None),
+                                "flash_bwd_dkv": (91, None)},
+    "gpt2-xl.train-s1024-x4": {"flash_fwd": (86, None),
+                               "flash_bwd_dq": (60, None),
+                               "flash_bwd_dkv": (70, None)},
+    "olmoe-1b-7b.train-s4096": {"flash_fwd": (86, None),
+                                "flash_bwd_dq": (60, None),
+                                "flash_bwd_dkv": (70, None)},
+}
+
+
+@pytest.mark.parametrize("cell", list(_BEFORE_THE_WALK))
+def test_the_walk_leaves_a_program_of_one_sub_tile_as_it_was(cell):
+    """Not causal (both BERT cells), the kernels' bodies are the text they
+    were before the walk.  Causal with two sub-tiles a block, the chip's form
+    holds a tile's arithmetic three times (the block whole without the mask,
+    whole with it, and a sub-tile's in the loop's body) beside the walk's own
+    few equations, so what a step's tracing costs stays within three times
+    what it was; the interpreter's form, one loop over every sub-tile, adds
+    the loop's few equations to what the body was."""
+    before = _BEFORE_THE_WALK[cell]
+    causal = "bert" not in cell
+    now = _traced_kernels(_CELL_SHAPES[cell][0], causal)
+    assert list(now) == list(before)
+    interpreted = _traced_kernels(_CELL_SHAPES[cell][0], causal, True)
+    for name, (count, text) in before.items():
+        if not causal:
+            assert now[name] == (count, text), (name, now[name])
+            continue
+        assert 2 * count <= now[name][0] <= 3 * count, (name, now[name])
+        assert count < interpreted[name][0] <= count + 10, interpreted

@@ -129,3 +129,55 @@ def test_the_one_width_kernels_trace_as_before():
     two = str(jax.make_jaxpr(lambda q: fa.flash_attention_two_product(
         q, q[..., :8], q, q[:, 0, :, :8], q, 0.2, True, 64, 64, True))(q))
     assert two.count("flash_fwd") >= 1 and two != text
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("sub,block_q,block_k", [
+    (32, 32, 64), (16, 16, 64), (16, 32, 128)],
+    ids=["two-sub-tiles", "four-sub-tiles", "eight-sub-tiles-two-k-blocks"])
+def test_sub_tiles_of_a_causal_block_match_dense(sub, block_q, block_k,
+                                                 dtype, monkeypatch):
+    """The causal walk in the two-product form: a k block taken in two, four
+    and eight sub-tiles (the module's constant steered; no argument selects
+    it), interpreted, against dense attention on assembled keys: the forward
+    and all five gradients, the shared key and its gradient sliced a
+    sub-tile like the head's own."""
+    monkeypatch.setattr(fa, "_SUB_TILE", sub)
+    assert fa._sub_tile(True, block_k) == sub
+    assert fa._sub_tile(False, block_k) == block_k
+    b, h, s, d, r, dv = 2, 2, 256, 32, 16, 48
+    *ops, w = _operands(b, h, s, d, r, dv, dtype, seed=sub)
+    scale = (d + r) ** -0.5
+    f32 = [x.astype(jnp.float32) for x in ops]
+
+    def kernels(*a):
+        return fa.flash_attention_two_product(*a, scale, True, block_q,
+                                              block_k, True)
+
+    def dense(*a):
+        return _dense_assembled(*a, scale, True)
+
+    tol = 2e-6 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(kernels(*ops).astype(jnp.float32),
+                               dense(*f32), atol=tol)
+    got = jax.grad(lambda *a: jnp.sum(kernels(*a).astype(jnp.float32)
+                                      * w.astype(jnp.float32)),
+                   (0, 1, 2, 3, 4))(*ops)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * w.astype(jnp.float32)),
+                    (0, 1, 2, 3, 4))(*f32)
+    for g, e, x in zip(got, want, ops):
+        assert g.shape == x.shape and g.dtype == x.dtype
+        np.testing.assert_allclose(g.astype(jnp.float32), e,
+                                   atol=1e-5 if dtype == jnp.float32
+                                   else 0.25)
+
+
+def test_causal_plan_at_the_joyai_cells_shape():
+    """The JoyAI cell's call (32 heads of 4,096 positions, blocks 512 x
+    1,024): 36 of a row's 64 sub-tiles of 512 x 512 are visited, 12 of them
+    through the mask (the 24 of its twelve blocks wholly below the diagonal
+    without it); the whole-block rule visited 20 of 32 blocks, 40 of 64."""
+    assert fa._sub_tile(True, 1024) == 512
+    assert fa._causal_plan(4096, 4096, 512, 1024, 512) == (64, 36, 12)
+    assert fa._causal_plan(4096, 4096, 512, 1024, 1024) == (32, 20, 8)

@@ -419,6 +419,47 @@ def test_v5e_compiler_takes_the_two_product_kernels_without_a_padded_key(
         assert widths <= {1, 2, rope, nope, value}, (widths, call[:300])
 
 
+@pytest.mark.parametrize("heads,s", [(4, 1024), (5, 1024), (4, 2048)],
+                         ids=["packed-s1024", "split-s1024", "packed-s2048"])
+def test_v5e_compiler_takes_the_causal_walk(heads, s, monkeypatch):
+    """The three causal flash kernels in the form the chip runs (a k block
+    of 1,024 keys in two sub-tiles: whole where both hold a seen score, the
+    one that does in a loop on the device otherwise, by the program's own
+    positions), forward and backward, compiled by libtpu for one detached
+    v5e chip at the GPT-2 cells' block shape: two heads of 64 a 128-lane
+    block (packed) and an odd head count (split).  Mosaic takes the
+    conditionals, the loop with its bounds from the device and the dynamic
+    slices of k, v and the dk / dv accumulators; the CPU tests run the same
+    bodies interpreted, where nothing is skipped."""
+    why_not = _why_no_detached_topology()
+    if why_not:
+        pytest.skip(why_not)
+    import importlib
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    fa = importlib.import_module("autodist_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_pallas_interpret", lambda *_: False)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x4")
+    chip = SingleDeviceSharding(topo.devices[0])
+    hook = fa.make_flash_attn_fn(causal=True)
+    packed = hook.bshd(heads, 64)
+    assert (packed is not None) == (heads % 2 == 0)
+    assert fa._sub_tile(True, 1024) == 512
+    x = jax.ShapeDtypeStruct((2, s, heads, 64) if packed else
+                             (2, heads, s, 64), jnp.bfloat16, sharding=chip)
+    attend = packed or hook
+
+    def loss(q, k, v):
+        return (attend(q, k, v).astype(jnp.float32) ** 2).sum()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    assert sum("tpu_custom_call" in line and name in line
+               for line in text.splitlines()
+               for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")) \
+        == 3
+
+
 @pytest.mark.parametrize("chunk, solves", [(64, False), (48, True)])
 def test_v5e_compiler_runs_no_triangular_solve_for_a_chunk_of_64(chunk,
                                                                  solves):
