@@ -1,7 +1,9 @@
 """Latency-hiding collective scheduler: plan + flags + exposed-comms model.
 
 Three pieces, shared by the Runner (issue order), the tuner cost model
-(pricing), and the report/bench surface (measurement):
+(pricing), and the report (the predicted figure; the text parser below also
+serves ``observability/profile.comm_table``, whose join with a trace is the
+measured one):
 
 * **Bucket plan** — gradient reductions are bucketed by strategy
   ``(group, compressor, dtype)`` and split at ``AUTODIST_AR_BUCKET_MB``;
@@ -24,7 +26,7 @@ Three pieces, shared by the Runner (issue order), the tuner cost model
   ``-start``/``-done`` pair on the topology's link seeds, and subtracts
   an HBM-roofline estimate of the compute scheduled inside each pair's
   window: what is left is communication the schedule could not hide —
-  ``comms_exposed_ms_per_step`` in telemetry/bench.
+  the gauge ``comms.exposed_ms_per_step``.
 """
 import hashlib
 import os
@@ -194,32 +196,52 @@ _DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2,
                 "f64": 8, "c64": 8, "c128": 16}
 
 _SHAPE_RE = re.compile(r"\b([a-z]+\d+|pred)\[([\d,]*)\]")
+#: The collective opcodes; each also comes as a ``-start`` / ``-done`` pair.
+_COLLECTIVES = ("all-reduce|all-gather|reduce-scatter|collective-permute|"
+                "all-to-all")
 _START_RE = re.compile(
     r"%?([\w.-]+)\s*=\s*(\([^=]*?\)|\S+)\s*"
-    r"((?:all-reduce|all-gather|reduce-scatter|collective-permute|"
-    r"all-to-all)-start)\(")
-_DONE_RE = re.compile(
-    r"(?:all-reduce|all-gather|reduce-scatter|collective-permute|"
-    r"all-to-all)-done\(\s*%?([\w.-]+)")
+    rf"((?:{_COLLECTIVES})-start)\(")
+_DONE_RE = re.compile(rf"(?:{_COLLECTIVES})-done\(\s*%?([\w.-]+)")
+#: An instruction's opcode where it is a collective: ``(kind, half)`` with
+#: ``half`` one of ``-start``, ``-done`` or None.  Operands are names and
+#: metadata spells its primitives with underscores, so a line has at most
+#: one match.
+_COLLECTIVE_RE = re.compile(rf"\s({_COLLECTIVES})(-start|-done)?\(")
 _COMPUTE_RE = re.compile(
     r"=\s*(\([^=]*?\)|\S+)\s*(?:fusion|dot|convolution|custom-call)\(")
 _GROUP_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
 _GROUP_BRACE_RE = re.compile(r"replica_groups=\{\{([^}]*)\}")
 
 
-def _shape_bytes(text):
-    """Max tensor byte-size among the shape tokens in ``text`` (async
-    starts return tuples holding operand and result aliases — the payload
-    is the largest member)."""
-    best = 0
+def _each_shape_bytes(text):
+    """Byte-size of every shape token in ``text``, in order."""
     for m in _SHAPE_RE.finditer(text):
         n = 1
         dims = m.group(2)
         if dims:
             for d in dims.split(","):
                 n *= int(d)
-        best = max(best, n * _DTYPE_BYTES.get(m.group(1), 4))
-    return best
+        yield n * _DTYPE_BYTES.get(m.group(1), 4)
+
+
+def _shape_bytes(text):
+    """Max tensor byte-size among the shape tokens in ``text`` (async
+    starts return tuples holding operand and result aliases — the payload
+    is the largest member)."""
+    return max(_each_shape_bytes(text), default=0)
+
+
+def _payload_bytes(kind, half, result, group):
+    """Bytes of the whole array a collective reduces, gathers or moves, from
+    its result type: a synchronous instruction's results all count (a
+    combined all-reduce is a tuple of what it reduces; a reduce-scatter
+    returns one shard of ``group``), a ``-start`` half's tuple holds
+    operand and result aliases, so the largest member is the payload."""
+    if half:
+        return _shape_bytes(result)
+    total = sum(_each_shape_bytes(result))
+    return total * group if kind == "reduce-scatter" else total
 
 
 def _group_size(line):

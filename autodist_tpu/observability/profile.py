@@ -226,8 +226,14 @@ def hlo_scope_costs(hlo_text, known_scopes, topology=None, unroll=1):
 
 
 #: The parts of a train step the Runner names (``runner.py``); their work
-#: is neither forward nor backward.
-UPDATE_SCOPES = ("optimizer", "grad_sync", "param_gather")
+#: is neither forward nor backward.  ``loss_sync`` is the explicit step's
+#: mean of loss and ``aux`` over the data axis: a reduction that is no
+#: gradient's.
+UPDATE_SCOPES = ("optimizer", "grad_sync", "param_gather", "loss_sync")
+#: Where a communication instruction goes that carries no named scope (the
+#: compiler's rewrites drop the ``op_name``): by what it does.
+COMM_SCOPE_OF_KIND = {"all-reduce": "grad_sync", "reduce-scatter": "grad_sync",
+                      "all-gather": "param_gather"}
 #: Model scopes that fold into one row: the output projection and the loss.
 HEAD_SCOPES = ("logits", "lm_head", "mlm_head")
 #: Block sub-scopes that fold over every layer (``layer<i>/attn`` -> ``attn``).
@@ -282,35 +288,158 @@ def _scope_and_phase(op_name):
 
 _COMPUTATION_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
 _CALLS_RE = re.compile(r"\bcalls=%?([\w.\-]+)")
+_TO_APPLY_RE = re.compile(r"\bto_apply=%?([\w.\-]+)")
+_OPCODE_RE = re.compile(r"\s([a-z][a-z\-]*)\(")
+_OPERAND_RE = re.compile(r"\(\s*%?([\w.\-]+)")
+#: The XLA TPU compiler's own asynchronous form: the collective sits in a
+#: fusion beside one of these custom calls, ``...Start`` in the fusion that
+#: begins it and ``...Done`` in the one that waits for it.
+_ASYNC_MARK_RE = re.compile(r'custom_call_target="AsyncCollective(Start|Done)"')
+#: Work of a computation's own (``kernel/overlap._COMPUTE_RE``'s opcodes and
+#: the two that run computations of their own): a fusion or call that holds
+#: one of these beside a collective is compute.
+_COMPUTE_OPCODES = ("fusion", "dot", "convolution", "custom-call", "while",
+                    "conditional")
 
 
 def _parse_scopes(hlo_text, place=_scope_and_phase):
     """One pass over a compiled program's text: ``own`` maps every
     instruction to the ``(scope, phase)`` of its own ``op_name`` (None
-    without one), ``calls`` maps a fusion to the computation it calls, and
-    ``votes`` counts, per computation, the ``(scope, phase)`` of its
-    instructions that carry a named scope.  ``place`` is what reads an
-    ``op_name``."""
-    own, calls, votes = {}, {}, {}
-    computation = None
+    without one), ``calls`` maps a fusion (or an ``async-*`` wrapper) to the
+    computation it calls, and ``votes`` counts, per computation, the
+    ``(scope, phase)`` of its instructions that carry a named scope.
+    ``place`` is what reads an ``op_name``.
+
+    ``held`` says, per computation, what :func:`_communication` needs:
+    ``seq`` lists in schedule order its collectives and the instructions
+    that call a computation, each as ``(name, opcode, line, called
+    computation or None, collective or None)``, a collective as ``(kind,
+    half, payload bytes, group)`` with ``half`` one of ``-start``, ``-done``
+    or None; ``busy`` is whether it holds work of its own
+    (:data:`_COMPUTE_OPCODES`, the asynchronous markers apart), ``slice``
+    whether it holds a ``dynamic-slice``, ``mark`` the half of the TPU
+    compiler's asynchronous pair it holds, if any."""
+    from autodist_tpu.kernel import overlap as ov
+    own, calls, votes, held = {}, {}, {}, {}
+    computation = rec = None
     for line in hlo_text.splitlines():
         if line[:1] not in (" ", "\t"):
             header = _COMPUTATION_RE.match(line)
             computation = header.group(1) if header else None
+            rec = held.setdefault(computation, {
+                "seq": [], "busy": False, "slice": False, "mark": None})
             continue
         m = _INSTRUCTION_RE.match(line)
         if m is None:
             continue
+        name = m.group(1)
         op = _OP_NAME_RE.search(line)
         placed = place(op.group(1)) if op else None
-        own[m.group(1)] = placed
+        own[name] = placed
         called = _CALLS_RE.search(line)
         if called:
-            calls[m.group(1)] = called.group(1)
+            calls[name] = called.group(1)
         if placed and placed[0] != UNATTRIBUTED and computation:
             tally = votes.setdefault(computation, {})
             tally[placed] = tally.get(placed, 0) + 1
-    return own, calls, votes
+        found = _OPCODE_RE.search(line, m.end() - 1)
+        if found is None:
+            continue
+        opcode = found.group(1)
+        collective = ov._COLLECTIVE_RE.match(line, found.start())
+        if collective:
+            kind, half = collective.groups()
+            group = ov._group_size(line)
+            nbytes = ov._payload_bytes(
+                kind, half, line[m.end():found.start()], group)
+            rec["seq"].append((name, opcode, line, None,
+                               (kind, half, nbytes, group)))
+        elif called or opcode == "call":
+            target = called or _TO_APPLY_RE.search(line)
+            if target:
+                rec["seq"].append((name, opcode, line, target.group(1),
+                                   None))
+        elif opcode == "custom-call":
+            mark = _ASYNC_MARK_RE.search(line)
+            if mark:
+                rec["mark"] = mark.group(1).lower()
+            else:
+                rec["busy"] = True
+        elif opcode in _COMPUTE_OPCODES:
+            rec["busy"] = True
+        elif opcode == "dynamic-slice":
+            rec["slice"] = True
+    return own, calls, votes, held
+
+
+def _communication(calls, held):
+    """``{instruction: {"kind", "bytes", "group", "async"}}`` for the
+    communication instructions of a parsed program (:func:`comm_table`
+    says what each key means): the collectives, and the fusions, calls and
+    ``async-*`` wrappers whose computation holds collectives, directly or
+    through such instructions of its own, and no work beside them.  An
+    instruction inside a fused or wrapped computation never runs by itself
+    and has no row."""
+    carried = {}
+
+    def carries(computation):
+        """``[(kind, bytes, group)]`` of the collectives a computation
+        holds where it does nothing else; ``()`` otherwise."""
+        if computation not in carried:
+            carried[computation] = ()       # a cycle carries nothing
+            rec, found = held.get(computation), []
+            for _, _, _, target, collective in \
+                    rec["seq"] if rec and not rec["busy"] else ():
+                if target:
+                    inner = carries(target)
+                    if not inner:
+                        found = []          # a fusion of its own: compute
+                        break
+                    found += inner
+                elif collective[1] != "-done":
+                    kind, _, nbytes, group = collective
+                    found.append((kind, nbytes, group))
+            carried[computation] = tuple(found)
+        return carried[computation]
+
+    table, inlined = {}, set(calls.values())
+    for computation, rec in held.items():
+        if computation in inlined:
+            continue
+        begun = []      # the TPU compiler's pairs: starts not yet waited for
+        for name, opcode, line, target, collective in rec["seq"]:
+            mark = None
+            if target is None:
+                kind, half, nbytes, group = collective
+            else:
+                inner = carries(target)
+                if not inner:
+                    continue
+                kind, _, group = max(inner, key=lambda c: c[1])
+                nbytes = sum(c[1] for c in inner)
+                if kind == "all-reduce" and held[target]["slice"]:
+                    kind = "reduce-scatter"     # the compiler's fused form
+                mark = held[target]["mark"]
+                half = "-start" if opcode.endswith("-start") else \
+                    "-done" if opcode.startswith("async-") else None
+            pair = False
+            if half == "-start" or mark == "start":
+                pair = name
+                if mark:
+                    begun.append(name)
+            elif mark == "done" and begun:
+                pair = begun.pop()
+            elif half == "-done":
+                # Named by its operand; an ``async-update`` passes it on.
+                pair = _OPERAND_RE.search(line, line.index(opcode + "("))
+                pair = pair.group(1) if pair else name
+                pair = table.get(pair, {}).get("async") or pair
+            if pair and pair != name:
+                # The half that began it carries the bytes and the group.
+                nbytes, group = 0, table.get(pair, {}).get("group", group)
+            table[name] = {"kind": kind, "bytes": nbytes, "group": group,
+                           "async": pair}
+    return table
 
 
 def scope_table(hlo_text):
@@ -336,8 +465,90 @@ def scope_table(hlo_text):
     with no scope in it.  An instruction with no ``op_name``, or with none
     of the user's scopes in it, is :data:`UNATTRIBUTED` — surfaced, never
     absorbed.
+
+    Communication is placed by what the instruction is, where its name says
+    nothing.  A communication instruction (a row of :func:`comm_table`: a
+    collective, or a fusion, call or ``async-*`` wrapper whose computation
+    holds collectives and no work beside them) that has a named scope by
+    the reading above keeps it.  The compiler's rewrites (a combined
+    ``all-reduce``, the ``all-reduce`` + ``dynamic-slice`` fusion a TPU
+    makes of a reduce-scatter) drop the ``op_name``, and one with no named
+    scope goes by :data:`COMM_SCOPE_OF_KIND`: ``grad_sync`` if it reduces
+    (all-reduce, reduce-scatter and that fused form), ``param_gather`` if
+    it gathers (all-gather), phase ``update``; a scope-less
+    ``collective-permute`` or ``all-to-all`` stays :data:`UNATTRIBUTED`.
+    On the Runner's explicit step the rule is exact: every reduction there
+    is a gradient's but the mean of loss and ``aux``, which runs under
+    ``loss_sync`` and keeps that name.  Nothing else moves: every
+    instruction that has a scope by its ``op_name`` or by the vote has the
+    same one with this rule as without.
     """
-    return _voted(*_parse_scopes(hlo_text))
+    return _tables(hlo_text)[0]
+
+
+def _tables(hlo_text):
+    """``(scope table, communication rows without their scopes)`` of one
+    parse."""
+    own, calls, votes, held = _parse_scopes(hlo_text)
+    table, comm = _voted(own, calls, votes), _communication(calls, held)
+    for name, row in comm.items():
+        if table[name][0] == UNATTRIBUTED and \
+                row["kind"] in COMM_SCOPE_OF_KIND:
+            table[name] = (COMM_SCOPE_OF_KIND[row["kind"]], "update")
+    return table, comm
+
+
+def comm_table(hlo_text):
+    """``{instruction name: {"kind", "bytes", "group", "async", "scope"}}``
+    for every communication instruction of a compiled program's text.  Pure.
+
+    A communication instruction is a collective (``all-reduce``,
+    ``reduce-scatter``, ``all-gather``, ``collective-permute``,
+    ``all-to-all``, their ``-start`` and ``-done`` halves) or a fusion,
+    call or ``async-*`` wrapper whose computation holds collectives,
+    directly or through such instructions of its own, and no work beside
+    them.  A fusion that holds a collective AND work of its own (the TPU
+    compiler's ``async_collective_fusion``: a matrix product with a step of
+    an all-gather riding in it) is compute and has no row; the gather it
+    carries is in flight between the two fusions that hold the
+    ``AsyncCollectiveStart`` and ``AsyncCollectiveDone`` markers, which have.
+
+    ``kind`` is the collective's (``reduce-scatter`` for a fusion that holds
+    an ``all-reduce`` and a ``dynamic-slice``, the form a TPU gives it).
+    ``bytes`` is the payload, the whole array reduced, gathered or moved,
+    from the instruction's shapes (a fusion's: its collectives' summed;
+    padding the compiler added is in it, being on the wire).  ``group`` is
+    the replica group's size.  ``async`` is False where the instruction
+    runs synchronously on the core; for a half of an asynchronous pair it is
+    the name of the instruction that began the pair (its own name for that
+    one), and the half that began it carries the bytes: a ``-done`` half's
+    are 0, so that the rows sum to the step's traffic.  ``scope`` is where
+    :func:`scope_table` places the instruction.
+
+    Built on ``kernel/overlap``'s expressions and helpers (which price the
+    same text on a cost model for ``comms.exposed_ms_per_step``: the
+    prediction; :func:`comm_time` over a trace is the measurement).
+    """
+    table, comm = _tables(hlo_text)
+    return {name: dict(row, scope=table[name][0])
+            for name, row in comm.items()}
+
+
+def comm_wire_bytes(table, by="kind"):
+    """``{kind: bytes}`` a chip sends in one run of the program, by a
+    :func:`comm_table` (``by="scope"``: ``{scope: bytes}``): in a ring over
+    ``group`` chips a reduce-scatter, an all-gather or an all-to-all sends
+    ``(group - 1) / group`` of its payload, an all-reduce (a reduce-scatter
+    then an all-gather) twice that, and a collective-permute its payload
+    once."""
+    out = {}
+    for row in table.values():
+        ring = 1.0 if row["kind"] == "collective-permute" else \
+            (row["group"] - 1) / max(1, row["group"])
+        if row["kind"] == "all-reduce":
+            ring *= 2
+        out[row[by]] = out.get(row[by], 0.0) + ring * row["bytes"]
+    return out
 
 
 def _voted(own, calls, votes):
@@ -369,14 +580,14 @@ def overlay_table(hlo_text, top_scope):
         where = UNATTRIBUTED if not segs else \
             top_scope if segs[0] == top_scope else "elsewhere"
         return where, _scope_and_phase(op_name)[1]
-    return _voted(*_parse_scopes(hlo_text, place))
+    return _voted(*_parse_scopes(hlo_text, place)[:3])
 
 
 def mixed_fusions(hlo_text):
     """``{fusion name: {scope: scoped instructions}}`` for the fusions whose
     computation holds instructions of more than one scope: what
     :func:`scope_table` had to place by a vote."""
-    _, calls, votes = _parse_scopes(hlo_text)
+    _, calls, votes, _ = _parse_scopes(hlo_text)
     mixed = {}
     for name, computation in calls.items():
         scopes = {}
@@ -398,6 +609,62 @@ def device_time_by_scope(events, table):
         scope, phase = table.get(name, missing)
         out["scope"][scope] = out["scope"].get(scope, 0.0) + (end - start)
         out["phase"][phase] = out["phase"].get(phase, 0.0) + (end - start)
+    return out
+
+
+def _union_s(intervals):
+    """Seconds the ``(start, end)`` intervals cover together."""
+    covered, reach = 0.0, None
+    for lo, hi in sorted(intervals):
+        if reach is None or lo > reach:
+            covered, reach = covered + (hi - lo), hi
+        elif hi > reach:
+            covered, reach = covered + (hi - reach), hi
+    return covered
+
+
+def comm_time(ops, async_ops, table):
+    """One chip's communication time from its trace, by a
+    :func:`comm_table`: ``{"comm_s", "exposed_s", "by_kind": {kind:
+    seconds}, "by_scope": {scope: seconds}}``.  Pure.
+
+    ``ops`` are the ``(instruction name, start, end)`` events of the chip's
+    line of operations, ``async_ops`` those of its line of asynchronous
+    operations (an event there is named by the pair's first half).  A
+    synchronous communication instruction is busy for its event.  A pair is
+    in flight from the start of its first half to the end of its last: by
+    its event among ``async_ops`` where the profiler wrote one, and by
+    joining its halves' events among ``ops`` in order (so a chip whose
+    asynchronous line is missing, or a pair the profiler keeps off it, is
+    still whole).  ``comm_s`` is the union of all of these; ``exposed_s``
+    the part of that union during which no instruction outside the table
+    runs on the chip, so a synchronous collective, alone on the core, is
+    exposed whole; ``by_kind`` and ``by_scope`` are the unions of each
+    kind's and each scope's.
+    """
+    found, other, began = [], [], {}
+    for name, lo, hi in sorted(ops, key=lambda e: e[1]):
+        row = table.get(name)
+        if row is None:
+            other.append((lo, hi))
+        elif not row["async"]:
+            found.append((row, lo, hi))
+        elif row["async"] == name:
+            began[name] = lo
+            found.append((row, lo, hi))
+        else:
+            found.append((row, began.pop(row["async"], lo), hi))
+    found += [(table[name], lo, hi) for name, lo, hi in async_ops
+              if name in table]
+    out = {"comm_s": _union_s((lo, hi) for _, lo, hi in found),
+           "by_kind": {}, "by_scope": {}}
+    for key in ("kind", "scope"):
+        for value in {row[key] for row, _, _ in found}:
+            out["by_" + key][value] = _union_s(
+                (lo, hi) for row, lo, hi in found if row[key] == value)
+    # |comm and not other| = |comm or other| - |other|.
+    out["exposed_s"] = _union_s([(lo, hi) for _, lo, hi in found] + other) \
+        - _union_s(other)
     return out
 
 

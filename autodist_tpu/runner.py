@@ -1052,9 +1052,10 @@ class Runner:
                 lambda x, nm: x[None] if _is_stale(nm) else x,
                 opt_local, opt_names)
 
-            loss = jax.lax.pmean(loss, axis)
-            if aux is not None:
-                aux = jax.lax.pmean(aux, axis)
+            with jax.named_scope("loss_sync"):
+                loss = jax.lax.pmean(loss, axis)
+                if aux is not None:
+                    aux = jax.lax.pmean(aux, axis)
             new_sync = jax.tree_util.tree_map(lambda x: x[None], sync_local)
             new_state = TrainState(state.step + 1, new_params, new_opt,
                                    new_sync)
@@ -1148,6 +1149,15 @@ class Runner:
         from autodist_tpu.observability import profile
         return profile.scope_table(self.step_text())
 
+    def comm_table(self):
+        """``{instruction name: {"kind", "bytes", "group", "async",
+        "scope"}}`` of the compiled step's communication instructions
+        (``observability.profile.comm_table`` of :meth:`step_text`), for
+        ``profile.comm_wire_bytes`` and, with a device trace,
+        ``profile.comm_time``."""
+        from autodist_tpu.observability import profile
+        return profile.comm_table(self.step_text())
+
     def _record_wire_split(self):
         """Per-leg (ICI/DCN) wire-byte gauges for this program's gradient
         reductions — the predicted per-device bytes per step each leg
@@ -1212,7 +1222,9 @@ class Runner:
         *scheduled* HLO: price each async ``-start``/``-done`` pair and
         subtract the HBM-roofline estimate of the compute scheduled in
         its window (``kernel/overlap.exposed_collective_ms``) — the
-        ``comms.exposed_ms_per_step`` gauge Telemetry and bench read.
+        ``comms.exposed_ms_per_step`` gauge the report's Telemetry section
+        reads.  Predicted from the schedule; the measured figure is
+        ``observability.profile.comm_time`` under a trace.
         Fail-open: a text the parser cannot read just skips the gauge."""
         obs = self._obs
         dump = const.ENV.AUTODIST_DUMP_GRAPHS.val

@@ -275,7 +275,7 @@ def test_scope_table_names_the_whole_step(lowering, monkeypatch):
             UNATTRIBUTED} <= scopes
     assert not [s for s in scopes if s.startswith("layer")]
     if lowering == "explicit":
-        assert {"grad_sync", "param_gather"} <= scopes
+        assert {"grad_sync", "param_gather", "loss_sync"} <= scopes
     assert {("attn", "forward"), ("attn", "backward"), ("mlp", "forward"),
             ("mlp", "backward"), ("head", "forward"), ("head", "backward"),
             ("optimizer", "update")} <= set(table.values())
@@ -283,7 +283,7 @@ def test_scope_table_names_the_whole_step(lowering, monkeypatch):
             if scope in profile.UPDATE_SCOPES} == {"update"}
     with_scopes = _opcode_counts(_compiled_text(runner))
 
-    # The same step without the Runner's three scopes: names only, so the
+    # The same step without the Runner's four scopes: names only, so the
     # program has the same instructions of each kind.
     real = jax.named_scope
     monkeypatch.setattr(
@@ -293,8 +293,12 @@ def test_scope_table_names_the_whole_step(lowering, monkeypatch):
     _, bare, batch = _session(lowering)
     bare.step(bare.create_state(), batch)
     bare_table = bare.scope_table()
-    assert not {scope for scope, _ in bare_table.values()} \
-        & set(profile.UPDATE_SCOPES)
+    # Without its names the step's communication is still placed, by what
+    # each instruction is (the table's rule); nothing else is.
+    placed = {name: scope for name, (scope, _) in bare_table.items()
+              if scope in profile.UPDATE_SCOPES}
+    assert set(placed) <= set(bare.comm_table())
+    assert set(placed.values()) <= {"grad_sync", "param_gather"}
     assert _opcode_counts(_compiled_text(bare)) == with_scopes
 
 
