@@ -224,13 +224,35 @@ def _residual(cfg, norm_p, x, sublayer):
     return x + y, stats
 
 
+_MIXER_KEYS = ("ln1", "attn", "gdn")
+
+
+def _halves(p):
+    """A block's parameters as ``(the mixer sublayer's, the feed-forward
+    sublayer's)``: what :func:`mixer_sublayer` and :func:`ffn_sublayer`
+    read of them."""
+    return ({k: v for k, v in p.items() if k in _MIXER_KEYS},
+            {k: v for k, v in p.items() if k not in _MIXER_KEYS})
+
+
 def block_apply(p, x, cfg, mask=None, attn_fn=None, rope=None):
     """One block: ``(x, stats)``, ``stats`` a dict of what its layers
     report from inside the step (the expert layer's
     ``moe.dropless_apply`` statistics, a linear layer's
     ``gdn_state_absmax``) and None where they report nothing.  The
     parameters say which mixer and which feed-forward the layer holds;
-    ``rope`` are the tables of the layer's kind (:func:`_rope_tables`)."""
+    ``rope`` are the tables of the layer's kind (:func:`_rope_tables`).
+    The block is :func:`mixer_sublayer` then :func:`ffn_sublayer`."""
+    x, mixed = mixer_sublayer(p, x, cfg, mask=mask, attn_fn=attn_fn,
+                              rope=rope)
+    x, fed = ffn_sublayer(p, x, cfg)
+    return x, {**(mixed or {}), **(fed or {})} or None
+
+
+def mixer_sublayer(p, x, cfg, mask=None, attn_fn=None, rope=None):
+    """The first half of a block, ``(x, stats)``: the token mixer the
+    parameters hold (``attn``, full or latent, or ``gdn``) with its norm
+    and residual."""
     # attn/gdn/mlp scopes nest under the caller's layer scope, mirroring the
     # param paths ("layer<i>/attn/...") for the per-layer profiler.
     if "gdn" in p:
@@ -252,8 +274,13 @@ def block_apply(p, x, cfg, mask=None, attn_fn=None, rope=None):
                          dtype=cfg.dtype, attn_fn=attn_fn, rope=rope,
                          norm_eps=cfg.norm_eps), None
     with jax.named_scope("gdn" if "gdn" in p else "attn"):
-        x, mixed = _residual(cfg, p["ln1"], x, mixer)
+        return _residual(cfg, p["ln1"], x, mixer)
 
+
+def ffn_sublayer(p, x, cfg):
+    """The second half of a block, ``(x, stats)``: the feed-forward the
+    parameters hold (``mlp``, plain or gated, or ``moe``) with its norm and
+    residual."""
     def ffn(h):
         if "moe" in p:
             return moe.dropless_apply(p["moe"], cfg.moe, h)
@@ -264,8 +291,7 @@ def block_apply(p, x, cfg, mask=None, attn_fn=None, rope=None):
             h = jax.nn.gelu(up)
         return L.dense(p["mlp"]["down"], h, cfg.dtype), None
     with jax.named_scope("moe" if "moe" in p else "mlp"):
-        x, fed = _residual(cfg, p["ln2"], x, ffn)
-    return x, {**(mixed or {}), **(fed or {})} or None
+        return _residual(cfg, p["ln2"], x, ffn)
 
 
 def init(key, cfg):
@@ -378,11 +404,30 @@ def encode_with_stats(params, cfg, ids, segment_ids=None, attn_fn=None):
                                 bp, a, cfg, mask=mask, attn_fn=attn_fn,
                                 rope=rope.get(FULL))[0], x)
     else:
+        from autodist_tpu.parallel.context import layer_boundary
+        last = cfg.num_layers - 1
+        mixers, ffns = (list(half) for half in zip(*(
+            _halves(params[f"layer{i}"]) for i in range(cfg.num_layers))))
+        # The parameters of the layer ahead meet this layer's activations,
+        # a half a boundary: the mixer's where this layer begins, the
+        # feed-forward's where this layer's feed-forward begins (layer 0's,
+        # which has no layer below, where it begins itself).  Plain
+        # arguments back wherever no strategy shards them
+        # (``layer_boundary``); where one does, each half's gradients
+        # travel during the backward pass of the half-layers below it.
+        (mixers[0], ffns[0]), x = layer_boundary((mixers[0], ffns[0]), x)
         for i in range(cfg.num_layers):
+            if i < last:
+                mixers[i + 1], x = layer_boundary(mixers[i + 1], x)
             with jax.named_scope(f"layer{i}"):
-                x, layer_stats = block_apply(
-                    params[f"layer{i}"], x, cfg, mask=mask, attn_fn=attn_fn,
+                x, mixed = mixer_sublayer(
+                    mixers[i], x, cfg, mask=mask, attn_fn=attn_fn,
                     rope=rope.get(cfg.layer_type(i)))
+            if i < last:
+                ffns[i + 1], x = layer_boundary(ffns[i + 1], x)
+            with jax.named_scope(f"layer{i}"):
+                x, fed = ffn_sublayer(ffns[i], x, cfg)
+            layer_stats = {**(mixed or {}), **(fed or {})} or None
             if layer_stats is not None:
                 stats.append(_named_updates(layer_stats, f"layer{i}"))
     with jax.named_scope("ln_f"):

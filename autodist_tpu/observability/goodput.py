@@ -109,6 +109,7 @@ EVENT_CLASS = {
     "flash": None,
     "gdn": None,
     "goodput": None,
+    "grad_sync": None,
     "mesh-built": "startup_ms",
     "memory": None,
     "mla": None,

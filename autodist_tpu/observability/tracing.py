@@ -199,18 +199,24 @@ def _drop_nested_tail(name, ts_us, slack_us=20.0):
     ``jax-trace`` events a layer), and reports the inner ones first.  Only
     the outermost is kept: the events of this name and thread at the
     ring's tail that began after ``ts_us`` lie inside the one about to be
-    recorded, and would otherwise push the run's phases out of the ring."""
+    recorded, and would otherwise push the run's phases out of the ring.
+    An instant recorded while the outer one ran (a ``flash`` or
+    ``grad_sync`` event at trace time) stays and does not end the walk."""
     tid = threading.get_ident() & 0xFFFF
     with _lock:
-        while _events:
-            ev = _events[-1]
-            if ev["name"] != name or ev["tid"] != tid \
-                    or ev["ts"] < ts_us - slack_us:
+        i = len(_events) - 1
+        while i >= 0:
+            ev = _events[i]
+            if ev["ts"] < ts_us - slack_us:
                 break
-            _events.pop()
-            acc = _phase[name]
-            acc[1] -= ev["dur"]
-            acc[2] -= 1
+            if ev["ph"] == "X":
+                if ev["name"] != name or ev["tid"] != tid:
+                    break
+                del _events[i]
+                acc = _phase[name]
+                acc[1] -= ev["dur"]
+                acc[2] -= 1
+            i -= 1
 
 
 def _on_jax_event(event, **kwargs):

@@ -6,10 +6,11 @@ TF GraphDef to get there.  A jaxpr cannot be usefully edited the same way,
 so the TPU-native equivalent is a *dispatch context*: the Runner activates
 a :class:`ParallelContext` (built from the strategy proto's GraphConfig)
 around the user's loss function **at trace time**, and the framework's
-model-level ops — the attention resolver (``models/transformer.py``) and
-:func:`autodist_tpu.ops.scan_blocks` — consult it to pick the distributed
-lowering.  With no context (or a trivial mesh) the same ops keep their
-single-device semantics, so models remain runnable as plain JAX programs.
+model-level ops — the attention resolver (``models/transformer.py``),
+:func:`autodist_tpu.ops.scan_blocks` and the layer loop's
+:func:`layer_boundary` — consult it to pick the distributed lowering.  With
+no context (or a trivial mesh) the same ops keep their single-device
+semantics, so models remain runnable as plain JAX programs.
 """
 import contextlib
 import contextvars
@@ -59,6 +60,11 @@ class ParallelContext:
         # sequence dim would silently compute block-diagonal attention.
         self.attn_hook_in_use = False
         self._attn_cache = {}
+        # The explicit step's account of its ``fsdp`` leaves for the trace
+        # in progress (``kernel/synchronization/grad_scatter.GradScatter``),
+        # which :func:`layer_boundary` hands the layers ahead; None on every
+        # other lowering.
+        self.grad_scatter = None
 
     def attn_fn(self, causal):
         """The strategy's attention hook, or None for default attention.
@@ -110,3 +116,24 @@ def resolve_attn(causal=False):
     """Strategy-provided ``attn_fn(q, k, v, mask)`` or None (use default)."""
     ctx = current()
     return ctx.attn_fn(causal) if ctx is not None else None
+
+
+def layer_boundary(params_ahead, x):
+    """The op an unrolled layer loop calls where a layer (or a half of
+    one) begins, with parameters it will use further on and the activation
+    that enters here: ``(params_ahead, x)``, unchanged in value.
+
+    On the Runner's explicit step with ``fsdp`` variables and a data axis
+    above 1, the leaves of ``params_ahead`` whose bytes make bandwidth their
+    cost are gathered here, and backward their gradients' reduce-scatter
+    (asynchronous permutes, ``grad_scatter.exchange_scatter``) must be done
+    before ``x``'s cotangent goes on below this point: the scatter is in
+    flight during the backward pass between the parameters' use and here.
+    With no such context (one chip, GSPMD, plain JAX) the arguments come
+    back untouched at trace time: no ``custom_vjp``, no barrier, the
+    caller's jaxpr.
+    """
+    ctx = current()
+    if ctx is None or ctx.grad_scatter is None:
+        return params_ahead, x
+    return ctx.grad_scatter.boundary(params_ahead, x)

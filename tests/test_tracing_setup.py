@@ -418,3 +418,28 @@ def test_goodput_sees_a_compile_inside_a_step_loop_that_is_still_open():
                                            tracing.open_spans())
     assert set(closed) == {"compile", "emergency-save"}
     assert closed["compile"] == pytest.approx(inside["compile"])
+
+
+@pytest.mark.parametrize("instant_between", [False, True])
+def test_only_the_outermost_jax_span_is_kept(instant_between, monkeypatch):
+    """JAX reports the inner traces first; the outer one drops them from
+    the ring's tail, also where an event recorded at trace time (the
+    ``grad_sync`` or ``flash`` event) stands among them."""
+    monkeypatch.setattr(tracing, "_telemetry_on", lambda: True)
+    now = [0.0]                                 # the clock, in us
+    monkeypatch.setattr(tracing, "_now_us", lambda: now[0])
+    event = next(k for k, v in tracing._JAX_DURATION_SPANS.items()
+                 if v == "jax-trace")
+    now[0] = 10.0
+    tracing._on_jax_duration(event, 5e-6, fun_name="inner_a")
+    if instant_between:
+        now[0] = 15.0
+        tracing.record_instant("grad_sync")
+    now[0] = 30.0
+    tracing._on_jax_duration(event, 5e-6, fun_name="inner_b")
+    now[0] = 100.0
+    tracing._on_jax_duration(event, 99e-6, fun_name="outer")
+    kept = [e for e in tracing.events() if e["name"] == "jax-trace"]
+    assert [e["args"]["fun_name"] for e in kept] == ["outer"]
+    assert len([e for e in tracing.events()
+                if e["name"] == "grad_sync"]) == int(instant_between)
