@@ -139,16 +139,27 @@ def embed(p, ids):
 
 # -- attention ---------------------------------------------------------------
 
-def mha_init(key, dim, num_heads, use_bias=True, qk_norm=False):
+def mha_init(key, dim, num_heads, use_bias=True, qk_norm=False, head_dim=None,
+             kv_heads=None, gate=False):
+    """Parameters of :func:`mha`: ``query`` (dim -> num_heads x head_dim),
+    ``key`` and ``value`` (dim -> kv_heads x head_dim), ``out`` (num_heads x
+    head_dim -> dim); ``head_dim`` is ``dim / num_heads`` and ``kv_heads``
+    is ``num_heads`` where not given, and then all four are dim x dim.
+    ``gate`` adds ``gate`` (dim -> num_heads, no bias): one scalar a head."""
+    head_dim = head_dim or dim // num_heads
+    wide, kv_wide = num_heads * head_dim, (kv_heads or num_heads) * head_dim
     ks = jax.random.split(key, 4)
     p = {
-        "query": dense_init(ks[0], dim, dim, use_bias),
-        "key": dense_init(ks[1], dim, dim, use_bias),
-        "value": dense_init(ks[2], dim, dim, use_bias),
-        "out": dense_init(ks[3], dim, dim, use_bias),
+        "query": dense_init(ks[0], dim, wide, use_bias),
+        "key": dense_init(ks[1], dim, kv_wide, use_bias),
+        "value": dense_init(ks[2], dim, kv_wide, use_bias),
+        "out": dense_init(ks[3], wide, dim, use_bias),
     }
     if qk_norm:
-        p["q_norm"], p["k_norm"] = rmsnorm_init(dim), rmsnorm_init(dim)
+        p["q_norm"], p["k_norm"] = rmsnorm_init(wide), rmsnorm_init(kv_wide)
+    if gate:
+        p["gate"] = dense_init(jax.random.fold_in(key, 4), dim, num_heads,
+                               use_bias=False)
     return p
 
 
@@ -156,26 +167,97 @@ def rope_tables(seq_len, head_dim, theta=10000.0):
     """``(cos, sin)``, each (seq, head_dim) in float32, of the rotate-half
     rotary embedding: ``inv_freq_i = theta^(-2i / head_dim)`` and the
     angles repeated over both halves of a head."""
-    inv_freq = 1.0 / theta ** (
-        jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+    return _angle_tables(seq_len, 1.0 / theta ** (
+        jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+
+
+def _angle_tables(seq_len, inv_freq):
+    """Cos and sin of ``position x inv_freq``, the angles repeated over
+    both halves of the rotated lanes."""
     angles = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * inv_freq
     angles = jnp.concatenate([angles, angles], axis=-1)
     return jnp.cos(angles), jnp.sin(angles)
 
 
+def yarn_rope_tables(seq_len, lanes, theta, factor, original_len, beta_fast,
+                     beta_slow, attention_factor):
+    """``(cos, sin)``, each (seq, lanes) in float32, of the rotate-half
+    rotary embedding over ``lanes`` lanes with YaRN's frequencies
+    (arXiv:2309.00071, as the transformers library computes them): pair
+    ``i`` of the ``lanes / 2`` turns by ``inv_freq_i = (1 - r_i) / (factor
+    theta^(2i / lanes)) + r_i / theta^(2i / lanes)``, ``r_i = 1 - clip((i -
+    low) / (high - low), 0, 1)``, ``low`` / ``high`` the floor / ceiling of
+    ``lanes ln(original_len / (2 pi beta)) / (2 ln theta)`` at ``beta_fast``
+    / ``beta_slow``, clamped to ``[0, lanes - 1]``: the fast pairs keep their
+    frequency, the slow ones are stretched ``factor`` times.  Cos and sin
+    carry ``attention_factor``.  Static: the same table at every length."""
+    def correction(beta):
+        return lanes * math.log(original_len / (beta * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(correction(beta_fast)), 0)
+    high = min(math.ceil(correction(beta_slow)), lanes - 1)
+    if low == high:
+        high += 0.001       # the library's guard against a ramp of no width
+    pair = jnp.arange(lanes // 2, dtype=jnp.float32)
+    keep = 1.0 - jnp.clip((pair - low) / (high - low), 0.0, 1.0)
+    freq = theta ** (2.0 * pair / lanes)
+    cos, sin = _angle_tables(
+        seq_len, (1.0 - keep) / (factor * freq) + keep / freq)
+    return cos * attention_factor, sin * attention_factor
+
+
 def apply_rope(x, tables):
     """Rotate ``x`` (..., head_dim) by its positions, ``tables`` broadcasting
     against it ((seq, head_dim) for (batch, heads, seq, head_dim)): element
-    i pairs with element i + head_dim / 2 (the rotate-half form)."""
+    i pairs with element i + head_dim / 2 (the rotate-half form).  Tables
+    narrower than a head rotate its first lanes and pass the rest."""
     cos, sin = tables
+    lanes = cos.shape[-1]
+    if lanes < x.shape[-1]:
+        return jnp.concatenate([apply_rope(x[..., :lanes], tables),
+                                x[..., lanes:]], axis=-1)
     xf = x.astype(jnp.float32)
     x1, x2 = jnp.split(xf, 2, axis=-1)
     rotated = jnp.concatenate([-x2, x1], axis=-1)
     return (xf * cos + rotated * sin).astype(x.dtype)
 
 
+_mha_announced = set()
+
+
+def _announce_mha(heads, kv_heads, head_dim, window, rope, gate):
+    """Gauges ``attn.*`` and one ``attn`` event a distinct shape, at trace
+    time, for a mixer that is more than heads of ``dim / heads`` all the way
+    (grouped key-value heads, a window, a gate, a part of the lanes
+    rotated): full layers set ``attn.heads_full`` and
+    ``attn.rotary_lanes_full``, window layers ``attn.heads_window`` and
+    ``attn.window``."""
+    from autodist_tpu import observability
+    if not observability.enabled():
+        return
+    registry = observability.registry()
+    lanes = 0 if rope is None else rope[0].shape[-1]
+    registry.gauge("attn.kv_heads").set(kv_heads)
+    if window is None:
+        registry.gauge("attn.heads_full").set(heads)
+        registry.gauge("attn.rotary_lanes_full").set(lanes)
+    else:
+        registry.gauge("attn.heads_window").set(heads)
+        registry.gauge("attn.window").set(window)
+    detail = (f"attention: {heads} heads of {head_dim} read {kv_heads} "
+              f"key-value heads ({heads // kv_heads} a group), "
+              + ("every key behind the diagonal" if window is None
+                 else f"a window of {window} keys")
+              + f", {lanes} of a head's {head_dim} lanes rotated, "
+              + ("a sigmoid gate a head on the output" if gate
+                 else "no gate"))
+    if detail not in _mha_announced:
+        _mha_announced.add(detail)
+        observability.record_event("attn", detail)
+
+
 def mha(p, x, num_heads, mask=None, dtype=None, attn_fn=None, rope=None,
-        norm_eps=1e-5):
+        norm_eps=1e-5, kv_heads=None, window=None):
     """Multi-head self-attention.
 
     ``attn_fn(q, k, v, mask)`` may override the inner attention computation
@@ -188,49 +270,104 @@ def mha(p, x, num_heads, mask=None, dtype=None, attn_fn=None, rope=None,
     that layout, ``ops/flash_attention.py``).  Where the parameters hold
     ``q_norm`` / ``k_norm`` (QK-norm), q and k are RMS-normalised over the
     whole projected vector before the split into heads; ``rope``
-    (:func:`rope_tables`) rotates q and k after it.
+    (:func:`rope_tables`, :func:`yarn_rope_tables`; tables narrower than a
+    head rotate its first lanes) rotates q and k after it.
+
+    The head width is the parameters' (``query`` is dim -> num_heads x
+    head_dim).  ``kv_heads`` < ``num_heads``: k and v are (batch, kv_heads,
+    seq, head_dim), query head h reads key-value head ``h // (num_heads /
+    kv_heads)``, and they reach the core that wide, never repeated.
+    ``window``: position t sees the keys s with ``t - window < s <= t``
+    (under the hook's causality; an explicit ``mask`` has to hold it).  A
+    hook serves either only if it says so (``attn_fn.grouped``,
+    ``attn_fn.windowed``, ``make_flash_attn_fn``'s).  Where the parameters
+    hold ``gate``, head h's output is multiplied by ``sigmoid(W_g x)_h``
+    (float32) before ``out``.  The named scopes ``qkv``, ``rope``, ``core``
+    (``window_core`` under a window), ``gate`` and ``out`` are rows of the
+    profiler's table under the block's ``attn``.
     """
     b, s, _ = x.shape
-    bshd = getattr(attn_fn, "bshd", None)
+    kv_heads = kv_heads or num_heads
+    head_dim = p["query"]["kernel"].shape[1] // num_heads
+    plain = kv_heads == num_heads and window is None
+    for what, need in (("grouped", kv_heads != num_heads),
+                       ("windowed", window is not None)):
+        if need and attn_fn is not None and not getattr(attn_fn, what, False):
+            raise NotImplementedError(
+                f"attention with {kv_heads} key-value heads for {num_heads} "
+                f"query heads and window {window} needs an attention hook "
+                f"that is .{what} (ops.flash_attention.make_flash_attn_fn), "
+                f"and this one (ring, Ulysses or a caller's own) is not")
+    if not plain or "gate" in p or (
+            rope is not None and rope[0].shape[-1] != head_dim):
+        _announce_mha(num_heads, kv_heads, head_dim, window, rope,
+                      "gate" in p)
+    bshd = getattr(attn_fn, "bshd", None) if plain else None
     if bshd is not None:
-        bshd = bshd(num_heads, p["query"]["kernel"].shape[1] // num_heads)
+        bshd = bshd(num_heads, head_dim)
 
-    def project(name, norm=None):
+    def project(name, norm=None, heads=num_heads):
         t = dense(p[name], x, dtype)
         if norm in p:
             t = rmsnorm(p[norm], t, norm_eps)
-        t = t.reshape(b, s, num_heads, -1)
+        t = t.reshape(b, s, heads, -1)
         return t if bshd else t.transpose(0, 2, 1, 3)
 
-    q, k = project("query", "q_norm"), project("key", "k_norm")
-    v = project("value")
+    with jax.named_scope("qkv"):
+        q = project("query", "q_norm")
+        k, v = project("key", "k_norm", kv_heads), project("value",
+                                                           heads=kv_heads)
     if rope is not None:
-        if bshd:    # (seq, head_dim) against (batch, seq, heads, head_dim)
-            rope = tuple(t[:, None] for t in rope)
-        q, k = apply_rope(q, rope), apply_rope(k, rope)
-    if bshd:
-        return dense(p["out"], bshd(q, k, v, mask).reshape(b, s, -1), dtype)
-    if attn_fn is not None:
-        o = attn_fn(q, k, v, mask)
-    else:
-        o = dot_product_attention(q, k, v, mask)
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, -1)
-    return dense(p["out"], o, dtype)
+        with jax.named_scope("rope"):
+            if bshd:    # (seq, lanes) against (batch, seq, heads, head_dim)
+                rope = tuple(t[:, None] for t in rope)
+            q, k = apply_rope(q, rope), apply_rope(k, rope)
+    with jax.named_scope("core" if window is None else "window_core"):
+        extra = {} if window is None else {"window": window}
+        if bshd:
+            o = bshd(q, k, v, mask)
+        elif attn_fn is not None:
+            o = attn_fn(q, k, v, mask, **extra)
+        else:
+            o = dot_product_attention(q, k, v, mask)
+    if "gate" in p:
+        with jax.named_scope("gate"):
+            g = jax.nn.sigmoid(dense(p["gate"], x, dtype).astype(jnp.float32))
+            g = g[..., None] if bshd else g.transpose(0, 2, 1)[..., None]
+            o = (o.astype(jnp.float32) * g).astype(o.dtype)
+    with jax.named_scope("out"):
+        if not bshd:
+            o = o.transpose(0, 2, 1, 3)
+        return dense(p["out"], o.reshape(b, s, -1), dtype)
 
 
 def dot_product_attention(q, k, v, mask=None):
-    """Reference attention: softmax(qk^T/sqrt(d))v with f32 softmax."""
+    """Reference attention: softmax(qk^T/sqrt(d))v with f32 softmax.  k and
+    v may hold fewer heads than q: query head h reads key-value head ``h //
+    group`` (the group is the einsums' ``...``; nothing is repeated)."""
     hd = q.shape[-1]
-    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32)
+    heads, kv_heads = q.shape[1], k.shape[1]
+    if kv_heads != heads:
+        q = q.reshape((q.shape[0], kv_heads, heads // kv_heads) + q.shape[2:])
+    logits = jnp.einsum("bh...qd,bhkd->bh...qk", q, k).astype(jnp.float32)
     logits = logits / math.sqrt(hd)
     if mask is not None:
+        if logits.ndim == 5:    # (batch, 1 or heads, sq, sk) beside the group
+            mask = mask[:, :, None] if mask.shape[1] == 1 else mask.reshape(
+                mask.shape[0], kv_heads, -1, *mask.shape[2:])
         logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", probs, v.astype(jnp.float32)).astype(q.dtype)
+    out = jnp.einsum("bh...qk,bhkd->bh...qd", probs, v.astype(jnp.float32))
+    return out.reshape(out.shape[0], heads, *out.shape[-2:]).astype(q.dtype)
 
 
-def causal_mask(seq_len):
-    return jnp.tril(jnp.ones((1, 1, seq_len, seq_len), bool))
+def causal_mask(seq_len, window=None):
+    """True where a key is seen, (1, 1, seq, seq): ``s <= t`` and, under a
+    ``window``, ``t - window < s``."""
+    mask = jnp.tril(jnp.ones((1, 1, seq_len, seq_len), bool))
+    if window is not None:
+        mask = jnp.logical_and(mask, jnp.triu(mask, 1 - window))
+    return mask
 
 
 def mha_decode(p, x, num_heads, k_cache, v_cache, pos, dtype=None):

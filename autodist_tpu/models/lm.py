@@ -85,6 +85,43 @@ def joyai_llm_flash(num_layers=40, vocab=129280, experts_held=None,
         value_dim=128, mtp_depth=1, mtp_coef=0.3)
 
 
+def laguna_s_2_1(num_layers=48, vocab=100352, experts_held=None,
+                 dtype=jnp.bfloat16):
+    """Laguna-S-2.1 (poolside/Laguna-S-2.1 ``config.json``): width 3,072;
+    a period of four layers, full attention then three sliding ones; heads
+    of 128 over 8 key-value heads, 48 query heads in a full layer and 72 in
+    a sliding one, whose window is 512 keys; sliding layers rotate all 128
+    lanes (rotate-half, theta 10,000), full layers the first 64 by YaRN
+    (theta 500,000, factor 128 over 8,192, beta 32 / 1, cos and sin times
+    1.4852...); a sigmoid gate a head on attention's output; the first
+    layer's feed-forward a dense SwiGLU of 12,288, every later one 256
+    routed SwiGLU experts of 1,024, 10 a token by softmax scores, weights
+    over their sum times 2.5, beside one shared expert; RMSNorm eps 1e-6,
+    no bias, an untied head.  ``num_layers`` keeps the pattern's first
+    layers; ``experts_held = (first, count)`` is one rank's share of each
+    expert layer (``parallel/moe.py``).  Not in ``config.json`` (the
+    benchmark's configuration file lists each with its reason): the gate's
+    form, softmax scoring, no QK-norm, no gate on the shared expert, the
+    load-balancing term at 0.001."""
+    period = (T.FULL,) + (T.SLIDING,) * 3
+    kinds = [period[i % 4] for i in range(num_layers)]
+    return T.TransformerConfig(
+        vocab=vocab, dim=3072, num_heads=48, num_layers=num_layers,
+        mlp_dim=12288, max_len=1048576, causal=True, dtype=dtype,
+        norm="rmsnorm", norm_eps=1e-6, positions="rope", bias=False,
+        tied_head=False, ffn="moe", num_experts=256, experts_per_token=10,
+        expert_dim=1024, norm_topk=True, load_balance_coef=0.001,
+        layer_types=kinds, expert_scoring="softmax", route_scale=2.5,
+        shared_experts=1, experts_held=experts_held, first_dense=1,
+        head_dim=128, kv_heads=8,
+        heads_by_layer=[48 if kind == T.FULL else 72 for kind in kinds],
+        window=512, attn_gate=True, rope_by_type={
+            T.FULL: {"theta": 500000.0, "lanes": 64, "yarn": dict(
+                factor=128.0, original_len=8192, beta_fast=32.0,
+                beta_slow=1.0, attention_factor=1.4852030263919618)},
+            T.SLIDING: {"theta": 10000.0, "lanes": None, "yarn": None}})
+
+
 def init(key, cfg):
     return T.init(key, cfg)
 

@@ -21,7 +21,13 @@ current decoder block whose feed-forward is a layer of routed experts
 (``layers.gdn`` over ``ops/gated_delta.py``, parameters under
 ``layer<i>/gdn``) or ``"latent_attention"`` (``layers.mla``: queries, keys
 and values through low-rank latents, one rotary key a position shared by the
-heads, parameters under ``layer<i>/attn``); ``first_dense`` gives the first
+heads, parameters under ``layer<i>/attn``) or ``"sliding_attention"`` (full
+attention's mixer behind a ``window``: position t sees the keys ``t -
+window < s <= t``).  ``head_dim`` is a head's width where it is not ``dim /
+num_heads``, ``kv_heads`` the key-value heads that groups of query heads
+share, ``heads_by_layer`` each layer's query heads, ``attn_gate`` a sigmoid
+gate a head on attention's output, ``rope_by_type`` the rotary tables of
+each kind of layer (:func:`_rope_tables`); ``first_dense`` gives the first
 layers of an ``ffn="moe"`` model a dense SwiGLU MLP; ``mtp_depth=1`` adds the
 multi-token-prediction module (``mtp/...``, :func:`mtp_hidden`), one more
 block that predicts the token after next through the same embedding and head;
@@ -52,7 +58,9 @@ class TransformerConfig:
                  expert_scoring="softmax", route_scale=1.0, shared_experts=0,
                  select_bias=False, bias_update_rate=0.0, experts_held=None,
                  first_dense=0, q_rank=0, kv_rank=0, nope_dim=0, rope_dim=0,
-                 value_dim=0, mtp_depth=0, mtp_coef=0.0):
+                 value_dim=0, mtp_depth=0, mtp_coef=0.0, head_dim=None,
+                 kv_heads=None, heads_by_layer=None, window=None,
+                 attn_gate=False, rope_by_type=None):
         for name, value, known in (("norm", norm, ("layernorm", "rmsnorm")),
                                    ("positions", positions,
                                     ("learned", "rope", "none")),
@@ -124,6 +132,40 @@ class TransformerConfig:
         self.linear_key_dim, self.linear_value_dim = (linear_key_dim,
                                                       linear_value_dim)
         self.conv_width, self.allow_neg_eigval = conv_width, allow_neg_eigval
+        # Full and sliding attention (``layers.mha``): a head's width, the
+        # key-value heads (None: as many as query heads), each layer's query
+        # heads (None: ``num_heads`` throughout), the sliding layers' window,
+        # the gate, and ``{layer type: {"theta", "lanes" (None: a head's
+        # width), "yarn" (None, or ``layers.yarn_rope_tables``' factor,
+        # original_len, beta_fast, beta_slow, attention_factor)}}`` under
+        # ``positions="rope"`` (None: ``rope_theta`` over the whole head for
+        # every kind).
+        self._head_dim = head_dim
+        self.kv_heads, self.window, self.attn_gate = kv_heads, window, \
+            attn_gate
+        self.heads_by_layer = None if heads_by_layer is None \
+            else tuple(heads_by_layer)
+        self.rope_by_type = rope_by_type
+        if self.heads_by_layer is not None and (
+                len(self.heads_by_layer) != num_layers):
+            raise ValueError(
+                f"heads_by_layer must give each of the {num_layers} layers "
+                f"its query heads, got {heads_by_layer!r}")
+        if kv_heads and any(h % kv_heads for h in
+                            self.heads_by_layer or (num_heads,)):
+            raise ValueError(
+                f"{kv_heads} key-value heads do not group the query heads "
+                f"{self.heads_by_layer or num_heads}")
+        sliding = self.layer_types is not None and SLIDING in self.layer_types
+        if sliding != (window is not None):
+            raise ValueError(
+                "a 'sliding_attention' layer needs window, and a window "
+                f"needs such a layer; got window={window!r} with layer_types "
+                f"{layer_types!r}")
+        if scan_layers and (sliding or self.heads_by_layer is not None):
+            raise NotImplementedError(
+                "scan_layers stacks one kind of block: 'sliding_attention' "
+                "layers and heads_by_layer need scan_layers=False")
         if self.layer_types is not None:
             unknown = set(self.layer_types) - set(LAYER_TYPES)
             if unknown or len(self.layer_types) != num_layers:
@@ -160,6 +202,18 @@ class TransformerConfig:
     def layer_type(self, i):
         return FULL if self.layer_types is None else self.layer_types[i]
 
+    @property
+    def head_dim(self):
+        """A head's width: its own where given, else ``dim / num_heads`` of
+        the configuration as it stands (callers set ``dim`` and ``num_heads``
+        on a built configuration)."""
+        return self._head_dim or self.dim // self.num_heads
+
+    def layer_heads(self, i):
+        """The query heads of layer ``i``'s attention."""
+        return self.num_heads if self.heads_by_layer is None \
+            else self.heads_by_layer[i]
+
     def layer_ffn(self, i):
         """The feed-forward of layer ``i``: ``ffn``, but a dense SwiGLU MLP
         in the ``first_dense`` layers of an expert model."""
@@ -167,8 +221,9 @@ class TransformerConfig:
             else self.ffn
 
 
-FULL, LINEAR, LATENT = LAYER_TYPES = ("full_attention", "linear_attention",
-                                      "latent_attention")
+FULL, LINEAR, LATENT, SLIDING = LAYER_TYPES = (
+    "full_attention", "linear_attention", "latent_attention",
+    "sliding_attention")
 
 
 def _norm_init(cfg):
@@ -181,12 +236,13 @@ def _norm(cfg, p, x):
         else L.layernorm(p, x, cfg.norm_eps)
 
 
-def block_init(key, cfg, layer_type=FULL, ffn=None):
+def block_init(key, cfg, layer_type=FULL, ffn=None, heads=None):
     """One block's parameters; ``layer_type`` decides whether it holds
-    ``attn`` (full or latent attention) or ``gdn``, ``ffn`` (the
-    configuration's where None) whether ``moe`` or ``mlp``.  ``ln1`` and
-    ``ln2`` are the norms of the mixer's and the feed-forward's sublayer,
-    wherever ``norm_position`` puts them."""
+    ``attn`` (full, sliding or latent attention) or ``gdn``, ``ffn`` (the
+    configuration's where None) whether ``moe`` or ``mlp``, ``heads`` (the
+    configuration's ``num_heads`` where None) its attention's query heads.
+    ``ln1`` and ``ln2`` are the norms of the mixer's and the feed-forward's
+    sublayer, wherever ``norm_position`` puts them."""
     ffn = ffn or cfg.ffn
     k1, k2, k3 = jax.random.split(key, 3)
     p = {"ln1": _norm_init(cfg)}
@@ -199,8 +255,9 @@ def block_init(key, cfg, layer_type=FULL, ffn=None):
                                cfg.kv_rank, cfg.nope_dim, cfg.rope_dim,
                                cfg.value_dim)
     else:
-        p["attn"] = L.mha_init(k1, cfg.dim, cfg.num_heads, cfg.bias,
-                               cfg.qk_norm)
+        p["attn"] = L.mha_init(k1, cfg.dim, heads or cfg.num_heads, cfg.bias,
+                               cfg.qk_norm, cfg.head_dim, cfg.kv_heads,
+                               cfg.attn_gate)
     p["ln2"] = _norm_init(cfg)
     if ffn == "moe":
         p["moe"] = moe.init(k2, cfg.moe)
@@ -235,24 +292,28 @@ def _halves(p):
             {k: v for k, v in p.items() if k not in _MIXER_KEYS})
 
 
-def block_apply(p, x, cfg, mask=None, attn_fn=None, rope=None):
+def block_apply(p, x, cfg, mask=None, attn_fn=None, rope=None, window=None):
     """One block: ``(x, stats)``, ``stats`` a dict of what its layers
     report from inside the step (the expert layer's
     ``moe.dropless_apply`` statistics, a linear layer's
     ``gdn_state_absmax``) and None where they report nothing.  The
     parameters say which mixer and which feed-forward the layer holds;
-    ``rope`` are the tables of the layer's kind (:func:`_rope_tables`).
-    The block is :func:`mixer_sublayer` then :func:`ffn_sublayer`."""
+    ``rope`` are the tables of the layer's kind (:func:`_rope_tables`),
+    ``window`` a sliding layer's.  The block is :func:`mixer_sublayer` then
+    :func:`ffn_sublayer`."""
     x, mixed = mixer_sublayer(p, x, cfg, mask=mask, attn_fn=attn_fn,
-                              rope=rope)
+                              rope=rope, window=window)
     x, fed = ffn_sublayer(p, x, cfg)
     return x, {**(mixed or {}), **(fed or {})} or None
 
 
-def mixer_sublayer(p, x, cfg, mask=None, attn_fn=None, rope=None):
+def mixer_sublayer(p, x, cfg, mask=None, attn_fn=None, rope=None,
+                   window=None):
     """The first half of a block, ``(x, stats)``: the token mixer the
     parameters hold (``attn``, full or latent, or ``gdn``) with its norm
-    and residual."""
+    and residual.  The parameters say how many query heads full attention
+    has (``query`` is dim -> heads x ``cfg.head_dim``); ``window`` makes it
+    sliding, and an explicit ``mask`` is then narrowed to the window."""
     # attn/gdn/mlp scopes nest under the caller's layer scope, mirroring the
     # param paths ("layer<i>/attn/...") for the per-layer profiler.
     if "gdn" in p:
@@ -269,10 +330,15 @@ def mixer_sublayer(p, x, cfg, mask=None, attn_fn=None, rope=None):
                          dtype=cfg.dtype, attn_fn=attn_fn,
                          norm_eps=cfg.norm_eps, causal=cfg.causal), None
     else:
+        heads = p["attn"]["query"]["kernel"].shape[1] // cfg.head_dim
+        if window is not None and mask is not None:
+            mask = jnp.logical_and(mask, L.causal_mask(x.shape[1], window))
+
         def mixer(h):
-            return L.mha(p["attn"], h, cfg.num_heads, mask=mask,
+            return L.mha(p["attn"], h, heads, mask=mask,
                          dtype=cfg.dtype, attn_fn=attn_fn, rope=rope,
-                         norm_eps=cfg.norm_eps), None
+                         norm_eps=cfg.norm_eps, kv_heads=cfg.kv_heads,
+                         window=window), None
     with jax.named_scope("gdn" if "gdn" in p else "attn"):
         return _residual(cfg, p["ln1"], x, mixer)
 
@@ -316,7 +382,8 @@ def init(key, cfg):
         for i in range(cfg.num_layers):
             params[f"layer{i}"] = block_init(keys[3 + i], cfg,
                                              cfg.layer_type(i),
-                                             cfg.layer_ffn(i))
+                                             cfg.layer_ffn(i),
+                                             cfg.layer_heads(i))
     if cfg.mtp_depth:
         # Its own projection, norms and block; embedding and head are the
         # model's, used a second time.
@@ -332,13 +399,20 @@ def init(key, cfg):
 
 def _rope_tables(cfg, s):
     """``{layer type: tables}`` for the kinds of attention the model holds:
-    rotate-half tables of a head's width for full attention under
-    ``positions="rope"``, adjacent-pair tables of ``rope_dim`` for latent
-    attention; a type with no rotation is absent."""
+    rotate-half tables for full and sliding attention under
+    ``positions="rope"`` (``rope_theta`` over a head's width, or each kind's
+    own by ``cfg.rope_by_type``: its theta, the lanes it rotates, YaRN's
+    frequencies), adjacent-pair tables of ``rope_dim`` for latent attention;
+    a type with no rotation is absent."""
     tables = {}
-    if cfg.positions == "rope":
-        tables[FULL] = L.rope_tables(s, cfg.dim // cfg.num_heads,
-                                     cfg.rope_theta)
+    if cfg.positions == "rope" and cfg.rope_by_type is None:
+        tables[FULL] = L.rope_tables(s, cfg.head_dim, cfg.rope_theta)
+    elif cfg.positions == "rope":
+        for kind, rope in cfg.rope_by_type.items():
+            lanes = rope.get("lanes") or cfg.head_dim
+            tables[kind] = L.rope_tables(s, lanes, rope["theta"]) \
+                if rope.get("yarn") is None else L.yarn_rope_tables(
+                    s, lanes, rope["theta"], **rope["yarn"])
     if cfg.layer_types is not None and LATENT in cfg.layer_types:
         tables[LATENT] = L.rope_pair_tables(s, cfg.rope_dim, cfg.rope_theta)
     return tables
@@ -420,9 +494,11 @@ def encode_with_stats(params, cfg, ids, segment_ids=None, attn_fn=None):
             if i < last:
                 mixers[i + 1], x = layer_boundary(mixers[i + 1], x)
             with jax.named_scope(f"layer{i}"):
+                kind = cfg.layer_type(i)
                 x, mixed = mixer_sublayer(
                     mixers[i], x, cfg, mask=mask, attn_fn=attn_fn,
-                    rope=rope.get(cfg.layer_type(i)))
+                    rope=rope.get(kind),
+                    window=cfg.window if kind == SLIDING else None)
             if i < last:
                 ffns[i + 1], x = layer_boundary(ffns[i + 1], x)
             with jax.named_scope(f"layer{i}"):
@@ -459,9 +535,10 @@ def mtp_hidden(params, cfg, hidden, next_ids, attn_fn=None):
                 [_norm(cfg, p["embed_norm"], e),
                  _norm(cfg, p["hidden_norm"], hidden)], axis=-1), cfg.dtype)
         with jax.named_scope("block"):
-            z, stats = block_apply(p["block"], z, cfg, mask=mask,
-                                   attn_fn=attn_fn,
-                                   rope=_rope_tables(cfg, s).get(kind))
+            z, stats = block_apply(
+                p["block"], z, cfg, mask=mask, attn_fn=attn_fn,
+                rope=_rope_tables(cfg, s).get(kind),
+                window=cfg.window if kind == SLIDING else None)
         with jax.named_scope("ln_f"):
             return _norm(cfg, p["ln_f"], z), _named_updates(stats,
                                                             "mtp/block")
@@ -480,6 +557,19 @@ def logits(params, cfg, hidden):
 # -- autoregressive decode (KV cache) ----------------------------------------
 
 def _decodable(cfg):
+    attention = {"grouped key-value heads (kv_heads)": cfg.kv_heads,
+                 "a window (sliding_attention layers)": cfg.window,
+                 "heads by layer (heads_by_layer)": cfg.heads_by_layer,
+                 "a gate on attention's output (attn_gate)": cfg.attn_gate,
+                 "a head width that is not dim / heads (head_dim)":
+                     cfg.head_dim != cfg.dim // cfg.num_heads}
+    refused = [name for name, value in attention.items() if value]
+    if refused:
+        raise NotImplementedError(
+            "decoding keeps one full-length cache of dim / heads wide heads "
+            "for every query head of every layer, and this configuration "
+            "has " + "; ".join(refused) + ": a cache by key-value head, cut "
+            "at a window layer's window, waits for ROADMAP R3")
     block = (cfg.norm, cfg.positions, cfg.ffn, cfg.qk_norm, cfg.bias,
              cfg.tied_head, cfg.norm_position, cfg.layer_types,
              cfg.mtp_depth)

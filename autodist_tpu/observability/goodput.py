@@ -92,6 +92,7 @@ BADPUT_CLASSES = (
 EVENT_CLASS = {
     "anchors-skipped": None,
     "anomaly": None,
+    "attn": None,
     "attribution": None,
     "automap": None,
     "chaos:ckpt-truncate": None,

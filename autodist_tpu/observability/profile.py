@@ -583,6 +583,30 @@ def overlay_table(hlo_text, top_scope):
     return _voted(*_parse_scopes(hlo_text, place)[:3])
 
 
+def subscope_table(hlo_text, block_scope):
+    """``{instruction name: (where, phase)}`` with ``where`` the block
+    sub-scope ``block_scope`` one level further down than
+    :func:`scope_table` folds it: ``layer<i>/attn/window_core`` of every
+    ``i`` (and the prediction module's block's) is ``attn/window_core``
+    for ``block_scope="attn"``; what is traced under ``block_scope`` and
+    in none of its inner scopes is ``block_scope``, everything else
+    ``"elsewhere"`` or :data:`UNATTRIBUTED`; a fusion again by the vote of
+    what it fused.  Joined with a trace (:func:`device_time_by_scope`) it
+    splits one row of the generic table (``attn``: ``qkv``, ``rope``,
+    ``core`` / ``window_core``, ``gate``, ``out`` of ``layers.mha``)."""
+    from autodist_tpu.graph_item import scope_path
+
+    def place(op_name):
+        segs = scope_path(op_name).split("/")[:-1]
+        if segs and segs[0] == MTP_SCOPE:
+            segs = segs[1:] or segs
+        where = UNATTRIBUTED if not segs else "elsewhere"
+        if len(segs) > 1 and segs[1] == block_scope:
+            where = "/".join(segs[1:3])
+        return where, _scope_and_phase(op_name)[1]
+    return _voted(*_parse_scopes(hlo_text, place)[:3])
+
+
 def mixed_fusions(hlo_text):
     """``{fusion name: {scope: scoped instructions}}`` for the fusions whose
     computation holds instructions of more than one scope: what

@@ -39,7 +39,7 @@ _CAPACITY = 2048
 #: sites AND the docs table, so a new event type cannot ship
 #: unregistered, undocumented, or outside the goodput taxonomy.
 EVENT_TYPES = frozenset({
-    "anchors-skipped", "anomaly", "attribution", "automap",
+    "anchors-skipped", "anomaly", "attn", "attribution", "automap",
     "chaos:ckpt-truncate", "chaos:kill",
     "chaos:kv-delay", "chaos:nan", "chaos:oom", "chaos:slow-host",
     "checkpoint-restore", "checkpoint-save",

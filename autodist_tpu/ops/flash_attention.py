@@ -284,7 +284,8 @@ class _Layout:
         """``x`` (q, k, v, do) as the kernels read it: a reshape, no copy."""
         if self.packed:
             return x.reshape(x.shape[0], x.shape[1], -1)
-        return x.reshape(self.units, x.shape[2], self.d)
+        # -1: keys and values may hold fewer heads than q (grouped heads).
+        return x.reshape(-1, x.shape[2], self.d)
 
     def stat(self, x):
         return x if self.packed else x.reshape(self.units, x.shape[2], 1)
@@ -312,18 +313,31 @@ class _Layout:
             return (self.heads, length, 1) if self.packed else (length, 1)
         return length, self.lanes
 
-    def spec(self, n, length, seq, stat=False, width=None, per_batch=False):
+    def spec(self, n, length, seq, stat=False, width=None, shared=False):
         """BlockSpec of ``n`` units' blocks whose position along the sequence
         is the grid's index number ``seq`` (1 or 2).  ``width`` is an
-        operand's own lanes where they are not ``d`` (split layout only);
-        with ``per_batch`` the operand holds one row a batch row, which all
-        of that row's heads read (one unit a program)."""
+        operand's own lanes where they are not ``d`` (split layout only,
+        one unit a program).  ``shared`` says which of the operand's rows a
+        program reads where that is not its own unit's: True, the operand
+        holds one row a batch row, which all of that row's heads read; an
+        integer ``g``, one row for ``g`` consecutive units (a key-value
+        head's query heads); ``(g, blocks)``, the grid's first index counts
+        key-value heads and its index ``seq`` runs over the ``g`` query
+        heads of one, ``blocks`` blocks each (the dk/dv kernel's q side)."""
         across = self.lane_blocks
-        if width is not None:
-            heads = self.num_heads
+        if width is not None or shared:
+            width = width or (1 if stat else self.d)
+            if isinstance(shared, tuple):
+                group, blocks = shared
+
+                def fanned(*grid):
+                    return (grid[0] * group + jax.lax.div(grid[seq], blocks),
+                            jax.lax.rem(grid[seq], blocks), 0)
+                return pl.BlockSpec((n, length, width), fanned)
+            share = self.num_heads if shared is True else shared
 
             def own(*grid):
-                i = jax.lax.div(grid[0], heads) if per_batch else grid[0]
+                i = jax.lax.div(grid[0], share) if share else grid[0]
                 return i, grid[seq], 0
             return pl.BlockSpec((n, length, width), own)
 
@@ -354,7 +368,7 @@ def _heads_per_block(num_heads, d):
 
 
 def _announce(kernel, layout, operand, sk, block_q, block_k, rows, vmem_bytes,
-              offsets=None):
+              offsets=None, group=1, window=None):
     """One info line a distinct kernel and shape, and with telemetry on the
     gauges ``flash.rows_per_program`` and ``flash.heads_per_block`` and a
     ``flash`` event: which layout the shape gave this call and which program
@@ -362,9 +376,13 @@ def _announce(kernel, layout, operand, sk, block_q, block_k, rows, vmem_bytes,
     call's ``(q_offset, k_offset)``: the line then ends with the sub-tiles a
     (batch, head) row visits by ``_causal_plan`` (decided on the device where
     an offset is traced), which the gauges ``flash.causal_subtiles_visited``
-    and ``flash.causal_subtiles_total`` carry; both 0 where not causal."""
+    and ``flash.causal_subtiles_total`` carry; both 0 where not causal.
+    With ``group`` query heads a key-value head the line says so, and under a
+    ``window`` it gives the window's own walk beside the causal one's (the
+    gauges ``flash.window_subtiles_visited`` / ``_total``; 0 with no
+    window)."""
     sq = operand.shape[1]
-    total = visited = 0
+    total = visited = in_window = 0
     walk = "not causal: every score computed"
     if offsets is not None:
         sub = _sub_tile(True, block_k)
@@ -374,8 +392,19 @@ def _announce(kernel, layout, operand, sk, block_q, block_k, rows, vmem_bytes,
                                                   sub, *offsets)
             walk = (f"causal: {visited} of {total} sub-tiles of {block_q} x "
                     f"{sub} visited, {masked} masked")
+            if window is not None:
+                _, in_window, _ = _causal_plan(sq, sk, block_q, block_k, sub,
+                                               *offsets, window=window)
+                walk = (f"a window of {window} keys: {in_window} of {total} "
+                        f"sub-tiles of {block_q} x {sub} visited, where the "
+                        f"causal walk visits {visited}")
         else:
             walk += " visited by the offsets on the device"
+            if window is not None:
+                walk += f", inside a window of {window} keys"
+    if group > 1:
+        walk += (f"; {group} query heads read one key-value head, "
+                 f"{layout.num_heads // group} key-value heads in HBM")
     programs = (layout.units * layout.heads // rows * layout.lane_blocks
                 * (sq // block_q) * (sk // block_k))
     shape = ",".join(str(n) for n in operand.shape)
@@ -394,6 +423,10 @@ def _announce(kernel, layout, operand, sk, block_q, block_k, rows, vmem_bytes,
     registry.gauge("flash.heads_per_block").set(layout.heads)
     registry.gauge("flash.causal_subtiles_visited").set(visited)
     registry.gauge("flash.causal_subtiles_total").set(total)
+    registry.gauge("flash.window_subtiles_visited").set(in_window)
+    registry.gauge("flash.window_subtiles_total").set(
+        total if window is not None else 0)
+    registry.gauge("flash.group").set(group)
     if detail not in _announced:
         _announced.add(detail)
         observability.record_event("flash", detail)
@@ -409,7 +442,7 @@ def _sub_tile(causal, block_k):
     return block_k
 
 
-def _walk(q_start, k_start, block_q, block_k, sub):
+def _walk(q_start, k_start, block_q, block_k, sub, window=None):
     """``(below, visited)`` of a causal program whose score tile has its
     first row at position ``q_start`` and its first key at ``k_start``: of
     its ``block_k / sub`` sub-tiles of keys, in order, the first ``below`` lie
@@ -417,7 +450,11 @@ def _walk(q_start, k_start, block_q, block_k, sub):
     <= q_start``), those up to ``visited`` hold a seen score (``q_start +
     block_q - 1 >= k_start + j sub``), and the rest are wholly masked.  Python
     integers give integers (``_causal_plan``), the device's values traced
-    ones: the one rule for what a kernel runs and for what it says it ran."""
+    ones: the one rule for what a kernel runs and for what it says it ran.
+    Under a ``window`` (position t sees the keys s with ``t - window < s <=
+    t``) a third count: the first ``behind`` sub-tiles lie wholly behind the
+    window of every row (``k_start + (j + 1) sub - 1 <= q_start - window``)
+    and are not run; a visible block has ``behind < visited``."""
     def tiles(keys):
         """Whole sub-tiles in ``keys`` keys, none for a negative count and no
         more than the block's."""
@@ -425,23 +462,36 @@ def _walk(q_start, k_start, block_q, block_k, sub):
             return min(max(keys, 0) // sub, block_k // sub)
         # ``lax.div`` rounds towards zero: a negative count gives at most 0.
         return jnp.clip(jax.lax.div(keys, sub), 0, block_k // sub)
-    return (tiles(q_start - k_start + 1),
+    walk = (tiles(q_start - k_start + 1),
             tiles(q_start + block_q - 1 - k_start + sub))
+    if window is None:
+        return walk
+    return walk + (tiles(q_start - window - k_start + 1),)
 
 
-def _causal_plan(sq, sk, block_q, block_k, sub, q_offset=0, k_offset=0):
+def _causal_plan(sq, sk, block_q, block_k, sub, q_offset=0, k_offset=0,
+                 window=None):
     """``(sub-tiles a (batch, head) row, those visited, of them those that go
     through the mask's arithmetic)`` of a causal call with integer offsets,
     by ``_walk`` and ``_for_keys``' rule: a block wholly below the diagonal
-    runs unmasked, every other visited sub-tile masked."""
+    runs unmasked, every other visited sub-tile masked.  Under a ``window``
+    the sub-tiles wholly behind it are not visited, and a block runs
+    unmasked only where it also lies wholly inside every row's window."""
     n = block_k // sub
-    walks = [_walk(q_offset + q, k_offset + k, block_q, block_k, sub)
-             for q in range(0, sq, block_q) for k in range(0, sk, block_k)]
-    return (len(walks) * n, sum(visited for _, visited in walks),
-            sum(visited for below, visited in walks if below < n))
+    starts = [(q_offset + q, k_offset + k)
+              for q in range(0, sq, block_q) for k in range(0, sk, block_k)]
+    visited = masked = 0
+    for q, k in starts:
+        below, seen, *behind = _walk(q, k, block_q, block_k, sub, window)
+        run = max(seen - sum(behind), 0)
+        inside = window is None or k >= q + block_q - window
+        visited += run
+        masked += 0 if below == n and inside else run
+    return len(starts) * n, visited, masked
 
 
-def _for_keys(skip_blocks, q_start, k_start, block_q, block_k, sub, run):
+def _for_keys(skip_blocks, q_start, k_start, block_q, block_k, sub, run,
+              window=None):
     """``run(keys, k_first, seen)`` over what a causal program's k block
     holds of seen scores, in the widest steps that hold no wholly masked
     sub-tile.  ``keys`` indexes a step's keys in a block's positions (None:
@@ -455,7 +505,11 @@ def _for_keys(skip_blocks, q_start, k_start, block_q, block_k, sub, run):
     once a block, as without the walk); and where it does not, a loop on the
     device over the sub-tiles that do, each masked (traced once whatever
     their number), the rest not run.  Without ``skip_blocks`` (the
-    interpreter) that loop takes every sub-tile."""
+    interpreter) that loop takes every sub-tile.  Under a ``window`` the
+    block runs whole and unmasked only where it also lies wholly inside the
+    window of the program's every row, whole and masked only where no
+    sub-tile lies wholly behind a window either, and the loop starts behind
+    the sub-tiles that do (``_walk``'s third count)."""
     if sub == block_k:
         run(None, k_start, False)
         return
@@ -467,6 +521,20 @@ def _for_keys(skip_blocks, q_start, k_start, block_q, block_k, sub, run):
         return carry
     if not skip_blocks:
         jax.lax.fori_loop(0, n, step, 0)
+        return
+    if window is not None:
+        below, visited, behind = _walk(q_start, k_start, block_q, block_k,
+                                       sub, window)
+        seen = jnp.logical_and(below == n,
+                               k_start >= q_start + block_q - window)
+        whole = jnp.logical_and(visited == n, behind == 0)
+        pl.when(seen)(lambda: run(None, k_start, True))
+        pl.when(jnp.logical_and(whole, jnp.logical_not(seen)))(
+            lambda: run(None, k_start, False))
+
+        @pl.when(jnp.logical_not(whole))
+        def _windowed():
+            jax.lax.fori_loop(behind, visited, step, 0)
         return
     below, visited = _walk(q_start, k_start, block_q, block_k, sub)
     pl.when(below == n)(lambda: run(None, k_start, True))
@@ -592,10 +660,11 @@ def _scratch(layout, n, shape):
 
 @functools.partial(jax.jit, inline=True, static_argnames=(
     "name", "body", "layout", "n", "grid_tail", "ins", "outs", "scratch",
-    "block_q", "block_k", "sub", "causal", "interpret", "scale"))
+    "block_q", "block_k", "sub", "causal", "interpret", "scale", "window",
+    "group"))
 def _kernel_call(offs, *arrays, name, body, layout, n, grid_tail, ins, outs,
                  scratch, block_q, block_k, sub, causal, interpret,
-                 scale=None):
+                 scale=None, window=None, group=1):
     """One kernel over ``arrays`` in the kernels' own shapes, ``n`` units a
     program.  ``ins`` give each array's block as ``(length, which of the
     grid's indices places it along the sequence, whether it is a row
@@ -607,6 +676,9 @@ def _kernel_call(offs, *arrays, name, body, layout, n, grid_tail, ins, outs,
     bodies read two more operands (``_two_product``); ``sub`` is the keys a
     sub-tile of a causal program's k block (``_sub_tile``: the caller reads
     the module's constant, so that this function's cache is keyed by it).
+    ``window`` and ``group`` reach a body only where there is a window or
+    several query heads a key-value head: a call with neither builds the
+    body it built before they existed.
 
     An inlined ``jit``: a model's layers make the same call, and every one
     after the first takes the first's equations from the cache, the kernel's
@@ -615,6 +687,10 @@ def _kernel_call(offs, *arrays, name, body, layout, n, grid_tail, ins, outs,
     tracing (PERF.md section 7), and a step's attention is most of a model's
     traced operations."""
     form = {} if scale is None else {"scale": scale}
+    if window is not None:
+        form["window"] = window
+    if group > 1:
+        form["group"] = group
     return pl.pallas_call(
         functools.partial(body, d=layout.d, block_q=block_q, block_k=block_k,
                           sub=sub, causal=causal, skip_blocks=not interpret,
@@ -637,57 +713,91 @@ def _kernel_call(offs, *arrays, name, body, layout, n, grid_tail, ins, outs,
     )(offs, *arrays)
 
 
-def causal_bias(sq, sk, q_offset=0, k_offset=0):
+def causal_bias(sq, sk, q_offset=0, k_offset=0, window=None):
     """Additive causal bias (0 where visible, -inf where masked) for a
     (sq, sk) score block whose rows/cols sit at the given global offsets
     (offsets may be traced scalars). The single definition of causal
     masking shared by the dense reference, the Pallas kernels, and the
-    ring/Ulysses SP paths."""
+    ring/Ulysses SP paths.  Under a ``window`` position t sees the keys s
+    with ``t - window < s <= t``."""
     q_pos = q_offset + jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
     k_pos = k_offset + jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
-    return jnp.where(q_pos >= k_pos, 0.0, _NEG_INF)
+    if window is None:
+        return jnp.where(q_pos >= k_pos, 0.0, _NEG_INF)
+    return jnp.where(jnp.logical_and(q_pos >= k_pos, k_pos > q_pos - window),
+                     0.0, _NEG_INF)
 
 
 # ---------------------------------------------------------------------------
 # dense reference (CPU fallback and numerics oracle)
 
 
-def _dense_fwd(q, k, v, causal, q_offset=0, k_offset=0):
-    """Returns (o f32, lse f32 (..., sq, 1))."""
+def _group_of(q, k, heads_dim=1):
+    """Query heads a key-value head; the heads are dimension ``heads_dim``."""
+    heads, kv_heads = q.shape[heads_dim], k.shape[heads_dim]
+    assert heads % kv_heads == 0, \
+        f"{heads} query heads do not group over {kv_heads} key-value heads"
+    return heads // kv_heads
+
+
+def _by_kv_head(x, k):
+    """A q-side operand ``x`` (batch, heads, sq, ...) of the dense path as
+    (batch, key-value heads, group, sq, ...) where ``k`` holds fewer heads
+    than it (query head h reads key-value head ``h // group``); as it is
+    where they hold as many.  The einsums below let ``...`` stand for the
+    group, so keys and values are never repeated over the query heads."""
+    if x.shape[1] == k.shape[1]:
+        return x
+    return x.reshape((x.shape[0], k.shape[1], _group_of(x, k)) + x.shape[2:])
+
+
+def _dense_scores(q, k, causal, q_offset, k_offset, window):
+    """The dense path's scaled, masked f32 scores (batch, key-value heads,
+    [group,] sq, sk) and the scale."""
     scale = 1.0 / math.sqrt(q.shape[-1])
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
+    s = jnp.einsum("bh...qd,bhkd->bh...qk", _by_kv_head(q, k), k) \
+        .astype(jnp.float32) * scale
     if causal:
-        s = s + causal_bias(q.shape[2], k.shape[2], q_offset, k_offset)
+        s = s + causal_bias(q.shape[2], k.shape[2], q_offset, k_offset,
+                            window)
+    return s, scale
+
+
+def _dense_fwd(q, k, v, causal, q_offset=0, k_offset=0, window=None):
+    """Returns (o f32, lse f32 (..., sq, 1)); k and v may hold fewer heads
+    than q (:func:`_by_kv_head`)."""
+    s, _ = _dense_scores(q, k, causal, q_offset, k_offset, window)
     m = s.max(-1, keepdims=True)
     p = jnp.exp(s - m)
     l = p.sum(-1, keepdims=True)
     lse = m + jnp.log(l)
-    o = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)) / l
-    return o, lse
+    o = jnp.einsum("bh...qk,bhkd->bh...qd", p, v.astype(jnp.float32)) / l
+    return o.reshape(q.shape[:3] + o.shape[-1:]), \
+        lse.reshape(q.shape[:3] + (1,))
 
 
-def _dense_reference(q, k, v, causal, q_offset=0):
-    o, _ = _dense_fwd(q, k, v, causal, q_offset)
+def _dense_reference(q, k, v, causal, q_offset=0, window=None):
+    o, _ = _dense_fwd(q, k, v, causal, q_offset, window=window)
     return o.astype(q.dtype)
 
 
-def _dense_bwd(q, k, v, do, lse, delta, causal, q_offset=0, k_offset=0):
+def _dense_bwd(q, k, v, do, lse, delta, causal, q_offset=0, k_offset=0,
+               window=None):
     """FA2-style dense backward from the saved lse: p = exp(s - lse).
 
-    delta = rowsum(do * o); returns (dq, dk, dv) in f32.
+    delta = rowsum(do * o); returns (dq, dk, dv) in f32, dk and dv summed
+    over the query heads of a key-value head where k and v hold fewer heads.
     """
-    scale = 1.0 / math.sqrt(q.shape[-1])
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
-    if causal:
-        s = s + causal_bias(q.shape[2], k.shape[2], q_offset, k_offset)
-    p = jnp.exp(s - lse)                       # (..., sq, sk); masked -> 0
-    dof = do.astype(jnp.float32)
-    dv = jnp.einsum("bhqk,bhqd->bhkd", p, dof)
-    dp = jnp.einsum("bhqd,bhkd->bhqk", dof, v.astype(jnp.float32))
-    ds = p * (dp - delta) * scale
-    dq = jnp.einsum("bhqk,bhkd->bhqd", ds, k.astype(jnp.float32))
-    dk = jnp.einsum("bhqk,bhqd->bhkd", ds, q.astype(jnp.float32))
-    return dq, dk, dv
+    s, scale = _dense_scores(q, k, causal, q_offset, k_offset, window)
+    p = jnp.exp(s - _by_kv_head(lse, k))       # (..., sq, sk); masked -> 0
+    dof = _by_kv_head(do.astype(jnp.float32), k)
+    dv = jnp.einsum("bh...qk,bh...qd->bhkd", p, dof)
+    dp = jnp.einsum("bh...qd,bhkd->bh...qk", dof, v.astype(jnp.float32))
+    ds = p * (dp - _by_kv_head(delta, k)) * scale
+    dq = jnp.einsum("bh...qk,bhkd->bh...qd", ds, k.astype(jnp.float32))
+    dk = jnp.einsum("bh...qk,bh...qd->bhkd", ds,
+                    _by_kv_head(q.astype(jnp.float32), k))
+    return dq.reshape(q.shape), dk, dv
 
 
 # ---------------------------------------------------------------------------
@@ -708,12 +818,32 @@ def _two_product(refs, n_in, scale):
     return refs[:n_in] + refs[n_in + 2:], refs[n_in], refs[n_in + 1]
 
 
+def _visible(causal, skip_blocks, q_start, k_start, block_q, block_k, window):
+    """Whether a program's score tile holds a seen score.  A causal block is
+    fully masked iff its largest q position is still left of its smallest k
+    position, and under a ``window`` iff besides its largest k position is
+    not behind the window of its smallest q position: skip the MXU work
+    entirely; inside a block that is not, ``_for_keys`` makes the same test
+    a sub-tile.  ``skip_blocks`` is off in interpret mode (the Pallas
+    interpreter's state discharge loses multi-scratch writes under a skipped
+    runtime-conditional); the p-masking keeps skipped-block contributions
+    exactly zero either way."""
+    visible = jnp.logical_or(not (causal and skip_blocks),
+                             q_start + block_q - 1 >= k_start)
+    if window is None or not skip_blocks:
+        return visible
+    return jnp.logical_and(visible,
+                           k_start + block_k - 1 > q_start - window)
+
+
 def _fwd_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
-                skip_blocks, scale=None):
+                skip_blocks, scale=None, window=None, group=1):
     """Grid (units / rows a program x lane blocks, q-blocks, k-blocks): k
     innermost, accumulators in VMEM scratch carried across the k dimension,
     each of a program's rows with its own; ``d`` lanes a head; ``sub`` keys a
-    step of the walk over the k block (``_for_keys``)."""
+    step of the walk over the k block (``_for_keys``).  With ``group`` query
+    heads a key-value head the body is the same: the k and v blocks' index
+    maps pick the head (``_Layout.spec``)."""
     (q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l), qr_ref, kr_ref = \
         _two_product(refs, 3, scale)
     rows = q_ref.shape[0]
@@ -731,15 +861,8 @@ def _fwd_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
 
     q_start = offs_ref[0] + iq * block_q
     k_start = offs_ref[1] + ik * block_k
-    # A causal block is fully masked iff its largest q position is still
-    # left of its smallest k position — skip the MXU work entirely; inside
-    # a block that is not, ``_for_keys`` makes the same test a sub-tile.
-    # ``skip_blocks`` is off in interpret mode (the Pallas interpreter's
-    # state discharge loses multi-scratch writes under a skipped
-    # runtime-conditional); the p-masking below keeps skipped-block
-    # contributions exactly zero either way.
-    visible = jnp.logical_or(not (causal and skip_blocks),
-                             q_start + block_q - 1 >= k_start)
+    visible = _visible(causal, skip_blocks, q_start, k_start, block_q,
+                       block_k, window)
 
     @pl.when(visible)
     def _block():
@@ -756,7 +879,8 @@ def _fwd_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
                     s = s + _dot(qr_ref[at], kr_ref[_keys_of(0, keys)], -1, -1)
                 s = s * scale
                 if causal and not seen:
-                    s = s + causal_bias(block_q, k.shape[-2], q_start, k_first)
+                    s = s + causal_bias(block_q, k.shape[-2], q_start, k_first,
+                                        window)
                 m_prev = m[stat]
                 m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
                 alpha = jnp.exp(m_prev - m_new)
@@ -772,7 +896,8 @@ def _fwd_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
                                                    -2)
                 m[stat] = m_new
             _for_rows(rows, q_ref.shape[-1] // d, block_q * block_k, _rows)
-        _for_keys(skip_blocks, q_start, k_start, block_q, block_k, sub, _run)
+        _for_keys(skip_blocks, q_start, k_start, block_q, block_k, sub, _run,
+                  window)
 
     @pl.when(ik == num_kb - 1)
     def _finalize():
@@ -797,12 +922,37 @@ def _blocks(sq, sk, block_q, block_k):
     return block_q, block_k
 
 
+def _grouping(q, k, packed, causal, window):
+    """Query heads a key-value head of a kernel call, and the checks of what
+    the kernels serve: grouped heads on the split layout, a window under
+    ``causal``."""
+    group = _group_of(q, k, 2 if packed else 1)
+    if group > 1 and packed:
+        raise NotImplementedError(
+            "the flash kernels read grouped key-value heads on the split "
+            "layout (batch, heads, s, d) only")
+    if window is not None and not (causal and window > 0):
+        raise ValueError(f"a window ({window!r}) is a positive number of "
+                         f"keys behind a causal diagonal")
+    return group
+
+
+def _kv_block(block_k, at, group):
+    """``_kernel_call``'s entry of a k or v block that follows the grid's
+    index ``at``: a unit's own, or one for the ``group`` query heads of its
+    key-value head."""
+    return (block_k, at, False) if group == 1 else \
+        (block_k, at, False, None, group)
+
+
 def _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, k_offset,
-               interpret, out_dtype=None, packed=False):
+               interpret, out_dtype=None, packed=False, window=None):
     """Fused forward. Returns (o out_dtype in q's layout, lse f32
-    (b,h,sq,1)): q/k/v are (b,h,s,d), or with ``packed`` (b,s,h,d).
+    (b,h,sq,1)): q/k/v are (b,h,s,d), or with ``packed`` (b,s,h,d); k and v
+    may hold fewer heads than q (split layout).
 
     ``q_offset``/``k_offset`` may be traced scalars (scalar-prefetch)."""
+    group = _grouping(q, k, packed, causal, window)
     layout = _Layout.of(q, packed)
     qr, kr, vr = layout.array(q), layout.array(k), layout.array(v)
     sq, sk = qr.shape[1], kr.shape[1]
@@ -816,22 +966,26 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, k_offset,
     q_block, k_block = layout.block(block_q), layout.block(block_k)
     row_block = layout.block(block_q, stat=True)
     scratch = (q_block, row_block, row_block)
+    # With grouped heads a program is one row: its k block is one
+    # key-value head's.
     g, vmem = _rows_per_program(
-        layout.units, block_q, block_k,
+        layout.units if group == 1 else 1, block_q, block_k,
         [(q_block, q.dtype), (k_block, k.dtype), (k_block, v.dtype),
          (q_block, out_dtype), (row_block, f32)],
         [(shape, f32) for shape in scratch], layout.heads)
     _announce("flash_fwd", layout, qr, sk, block_q, block_k, g, vmem,
-              (q_offset, k_offset) if causal else None)
+              (q_offset, k_offset) if causal else None, group, window)
     # q's blocks follow the grid's second index, k's and v's its third.
     out, lse = _kernel_call(
         offs, qr, kr, vr, name="flash_fwd", body=_fwd_kernel, layout=layout,
         n=g // layout.heads, grid_tail=(sq // block_q, sk // block_k),
-        ins=((block_q, 1, False), (block_k, 2, False), (block_k, 2, False)),
+        ins=((block_q, 1, False), _kv_block(block_k, 2, group),
+             _kv_block(block_k, 2, group)),
         outs=((block_q, 1, False, out_dtype, sq),
               (block_q, 1, True, f32, sq)),
         scratch=scratch, block_q=block_q, block_k=block_k,
-        sub=_sub_tile(causal, block_k), causal=causal, interpret=interpret)
+        sub=_sub_tile(causal, block_k), causal=causal, interpret=interpret,
+        window=window, group=group)
     return layout.result(out), layout.result(lse, stat=True)
 
 
@@ -840,7 +994,7 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, k_offset,
 
 
 def _bwd_dq_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
-                   skip_blocks, scale=None):
+                   skip_blocks, scale=None, window=None, group=1):
     refs, qr_ref, kr_ref = _two_product(refs, 6, scale)
     if qr_ref is None:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -863,8 +1017,8 @@ def _bwd_dq_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
 
     q_start = offs_ref[0] + iq * block_q
     k_start = offs_ref[1] + ik * block_k
-    visible = jnp.logical_or(not (causal and skip_blocks),
-                             q_start + block_q - 1 >= k_start)
+    visible = _visible(causal, skip_blocks, q_start, k_start, block_q,
+                       block_k, window)
 
     @pl.when(visible)
     def _block():
@@ -883,7 +1037,8 @@ def _bwd_dq_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
                     s = s + _dot(qr_ref[at], kr, -1, -1)
                 s = s * scale
                 if causal and not seen:
-                    s = s + causal_bias(block_q, k.shape[-2], q_start, k_first)
+                    s = s + causal_bias(block_q, k.shape[-2], q_start, k_first,
+                                        window)
                 p = jnp.exp(s - lse_ref[stat]) if seen else jnp.where(
                     s > _NEG_INF / 2, jnp.exp(s - lse_ref[stat]), 0.0)
                 dp = _dot(do, v, -1, -1)
@@ -893,7 +1048,8 @@ def _bwd_dq_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
                 if qr_ref is not None:
                     dqr_acc[:] += _dot(ds.astype(kr.dtype), kr, -1, -2)
             _for_rows(rows, q_ref.shape[-1] // d, block_q * block_k, _rows)
-        _for_keys(skip_blocks, q_start, k_start, block_q, block_k, sub, _run)
+        _for_keys(skip_blocks, q_start, k_start, block_q, block_k, sub, _run,
+                  window)
 
     @pl.when(ik == num_kb - 1)
     def _finalize():
@@ -903,7 +1059,13 @@ def _bwd_dq_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
 
 
 def _bwd_dkv_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
-                    skip_blocks, scale=None):
+                    skip_blocks, scale=None, window=None, group=1):
+    """Grid (units, k-blocks, q-blocks): q innermost, the dk and dv
+    accumulators carried across it.  With ``group`` query heads a key-value
+    head the grid's first index counts key-value heads and its innermost
+    runs over the q blocks of the group's query heads one after another
+    (``_Layout.spec``'s ``(group, blocks)``), so one head's dk and dv are
+    summed over its query heads where they are accumulated."""
     refs, qr_ref, kr_ref = _two_product(refs, 6, scale)
     if qr_ref is None:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
@@ -913,12 +1075,14 @@ def _bwd_dkv_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
          dkr_ref, dk_acc, dv_acc, dkr_acc) = refs
     rows = q_ref.shape[0]
     ik = pl.program_id(1)
-    iq = pl.program_id(2)
+    step = iq = pl.program_id(2)
     num_qb = pl.num_programs(2)
     if scale is None:
         scale = 1.0 / math.sqrt(d)
+    if group > 1:       # the q block within its query head
+        iq = jax.lax.rem(step, num_qb // group)
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -927,8 +1091,8 @@ def _bwd_dkv_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
 
     q_start = offs_ref[0] + iq * block_q
     k_start = offs_ref[1] + ik * block_k
-    visible = jnp.logical_or(not (causal and skip_blocks),
-                             q_start + block_q - 1 >= k_start)
+    visible = _visible(causal, skip_blocks, q_start, k_start, block_q,
+                       block_k, window)
 
     @pl.when(visible)
     def _block():
@@ -949,7 +1113,8 @@ def _bwd_dkv_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
                     s = s + _dot(qr, kr_ref[_keys_of(0, keys)], -1, -1)
                 s = s * scale
                 if causal and not seen:
-                    s = s + causal_bias(block_q, k.shape[-2], q_start, k_first)
+                    s = s + causal_bias(block_q, k.shape[-2], q_start, k_first,
+                                        window)
                 p = jnp.exp(s - lse_ref[stat]) if seen else jnp.where(
                     s > _NEG_INF / 2, jnp.exp(s - lse_ref[stat]), 0.0)
                 dv_acc[row] += _dot(p.astype(do.dtype), do, -2, -2)  # p^T do
@@ -960,9 +1125,10 @@ def _bwd_dkv_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
                     dkr_acc[_row_of(dkr_acc, at, keys=keys)] += _dot(
                         ds.astype(qr.dtype), qr, -2, -2)
             _for_rows(rows, q_ref.shape[-1] // d, block_q * block_k, _rows)
-        _for_keys(skip_blocks, q_start, k_start, block_q, block_k, sub, _run)
+        _for_keys(skip_blocks, q_start, k_start, block_q, block_k, sub, _run,
+                  window)
 
-    @pl.when(iq == num_qb - 1)
+    @pl.when(step == num_qb - 1)
     def _finalize():
         every = _all_rows(dk_acc)
         dk_ref[every] = dk_acc[:].astype(dk_ref.dtype)
@@ -972,10 +1138,15 @@ def _bwd_dkv_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
 
 
 def _flash_bwd(q, k, v, do, lse, delta, causal, block_q, block_k, q_offset,
-               k_offset, interpret, out_dtype=None, packed=False):
+               k_offset, interpret, out_dtype=None, packed=False,
+               window=None):
     """Fused backward. Returns (dq, dk, dv) in out_dtype and the layout of
     q, k, v: the f32 accumulators are rounded once, by the kernels' last
-    step.  ``lse`` and ``delta`` are (b,h,sq,1) in either layout."""
+    step.  ``lse`` and ``delta`` are (b,h,sq,1) in either layout.  Where k
+    and v hold fewer heads than q, dk and dv are theirs: the dk/dv kernel's
+    grid counts key-value heads and sums a head's query heads where it
+    accumulates."""
+    group = _grouping(q, k, packed, causal, window)
     layout = _Layout.of(q, packed)
     qr, kr, vr, dor = (layout.array(x) for x in (q, k, v, do))
     sq, sk = qr.shape[1], kr.shape[1]
@@ -990,34 +1161,50 @@ def _flash_bwd(q, k, v, do, lse, delta, causal, block_q, block_k, q_offset,
     in_blocks = [(q_block, q.dtype), (k_block, k.dtype), (k_block, v.dtype),
                  (q_block, do.dtype), (row_block, f32), (row_block, f32)]
 
-    def call(name, body, grid_tail, at_q, at_k, out_block, out_len, n_out):
+    def call(name, body, grid_tail, at_q, at_k, out_block, out_len, n_out,
+             of=layout, fan=None):
         """One backward kernel: ``n_out`` results of ``out_block`` a unit at
         the grid's second index, each with its f32 accumulator in scratch;
-        q's blocks follow the grid's index ``at_q``, k's ``at_k``."""
+        q's blocks follow the grid's index ``at_q``, k's ``at_k``.  ``of``
+        is the layout whose units the grid's first index counts; with
+        ``fan = (group, q blocks)`` those are key-value heads and the q side
+        runs over a head's query heads."""
         g, vmem = _rows_per_program(
-            layout.units, block_q, block_k,
+            of.units if group == 1 else 1, block_q, block_k,
             in_blocks + [(out_block, out_dtype)] * n_out,
-            [(out_block, f32)] * n_out, layout.heads)
+            [(out_block, f32)] * n_out, of.heads)
         _announce(name, layout, qr, sk, block_q, block_k, g, vmem,
-                  (q_offset, k_offset) if causal else None)
-        at_q, at_k = (block_q, at_q, False), (block_k, at_k, False)
+                  (q_offset, k_offset) if causal else None, group, window)
+        if fan is None:
+            at_k = _kv_block(block_k, at_k, group)
+            at_q = (block_q, at_q, False)
+            stat = at_q[:2] + (True,)
+        else:
+            at_k = (block_k, at_k, False)
+            at_q = (block_q, at_q, False, None, fan)
+            stat = (block_q, at_q[1], True, None, fan)
         return _kernel_call(
             offs, qr, kr, vr, dor, lser, deltar, name=name, body=body,
-            layout=layout, n=g // layout.heads, grid_tail=grid_tail,
-            ins=(at_q, at_k, at_k, at_q, at_q[:2] + (True,),
-                 at_q[:2] + (True,)),
+            layout=of, n=g // of.heads, grid_tail=grid_tail,
+            ins=(at_q, at_k, at_k, at_q, stat, stat),
             outs=((out_block[0], 1, False, out_dtype, out_len),) * n_out,
             scratch=(out_block,) * n_out, block_q=block_q, block_k=block_k,
             sub=_sub_tile(causal, block_k), causal=causal,
-            interpret=interpret)
+            interpret=interpret, window=window, group=group)
 
     # dq: q blocks outside, accumulated over the k blocks inside; dk and dv:
     # k blocks outside, accumulated over the q blocks inside.
     dq, = call("flash_bwd_dq", _bwd_dq_kernel,
                (sq // block_q, sk // block_k), 1, 2, q_block, sq, 1)
+    if group == 1:
+        dk, dv = call("flash_bwd_dkv", _bwd_dkv_kernel,
+                      (sk // block_k, sq // block_q), 2, 1, k_block, sk, 2)
+        return layout.result(dq), layout.result(dk), layout.result(dv)
+    kv = _Layout.of(k, packed)
     dk, dv = call("flash_bwd_dkv", _bwd_dkv_kernel,
-                  (sk // block_k, sq // block_q), 2, 1, k_block, sk, 2)
-    return layout.result(dq), layout.result(dk), layout.result(dv)
+                  (sk // block_k, group * (sq // block_q)), 2, 1, k_block,
+                  sk, 2, of=kv, fan=(group, sq // block_q))
+    return layout.result(dq), kv.result(dk), kv.result(dv)
 
 
 # ---------------------------------------------------------------------------
@@ -1230,7 +1417,7 @@ def _use_pallas(q, k, block_q, block_k, interpret):
 
 
 def block_attn_fwd(q, k, v, causal, q_offset, k_offset, block_q=512,
-                   block_k=1024, interpret=False):
+                   block_k=1024, interpret=False, window=None):
     """One attention block: (o f32, lse f32 (..., sq, 1)).
 
     Offsets may be traced scalars (ring hop positions). Rows with no
@@ -1238,8 +1425,9 @@ def block_attn_fwd(q, k, v, causal, q_offset, k_offset, block_q=512,
     logsumexp-combine treats as an empty partial."""
     if _use_pallas(q, k, block_q, block_k, interpret):
         return _flash_fwd(q, k, v, causal, block_q, block_k, q_offset,
-                          k_offset, interpret, out_dtype=jnp.float32)
-    o, lse = _dense_fwd(q, k, v, causal, q_offset, k_offset)
+                          k_offset, interpret, out_dtype=jnp.float32,
+                          window=window)
+    o, lse = _dense_fwd(q, k, v, causal, q_offset, k_offset, window)
     if causal:
         # Match the kernel's fully-masked-row convention: the dense softmax
         # spreads weight uniformly over masked keys instead; zero it.
@@ -1250,15 +1438,16 @@ def block_attn_fwd(q, k, v, causal, q_offset, k_offset, block_q=512,
 
 
 def block_attn_bwd(q, k, v, do, lse, delta, causal, q_offset, k_offset,
-                   block_q=512, block_k=1024, interpret=False):
+                   block_q=512, block_k=1024, interpret=False, window=None):
     """Fused per-block backward vs the GLOBAL lse (FA2 cross-block form):
     p = exp(s - lse) are the true softmax probabilities even when this block
     is one hop of a longer ring. Returns (dq, dk, dv) f32."""
     if _use_pallas(q, k, block_q, block_k, interpret):
         return _flash_bwd(q, k, v, do, lse, delta, causal, block_q, block_k,
                           q_offset, k_offset, interpret,
-                          out_dtype=jnp.float32)
-    return _dense_bwd(q, k, v, do, lse, delta, causal, q_offset, k_offset)
+                          out_dtype=jnp.float32, window=window)
+    return _dense_bwd(q, k, v, do, lse, delta, causal, q_offset, k_offset,
+                      window)
 
 
 def combine_blocks(o_a, lse_a, o_b, lse_b):
@@ -1274,35 +1463,42 @@ def combine_blocks(o_a, lse_a, o_b, lse_b):
 # public fused attention
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal=False, block_q=512, block_k=1024,
-                    q_offset=0, interpret=None):
+                    q_offset=0, interpret=None, window=None):
     """softmax(qk^T/sqrt(d) [+ causal mask]) v, fused fwd AND bwd.
 
-    q/k/v: (batch, heads, seq, head_dim). ``q_offset`` shifts q's global
+    q: (batch, heads, seq, head_dim); k/v the same, or with fewer heads
+    (batch, key-value heads, seq, head_dim): query head h then reads
+    key-value head ``h // (heads / key-value heads)`` from where it lies,
+    and dk and dv come back that wide. ``q_offset`` shifts q's global
     positions for causal masking (used when q is a shard of a longer
-    sequence); it must be a multiple of ``block_q``. ``interpret=None``
-    picks the Pallas kernels on TPU and the dense path elsewhere.
+    sequence); it must be a multiple of ``block_q``. Under ``causal`` a
+    ``window`` lets position t see the keys s with ``t - window < s <= t``
+    only. ``interpret=None`` picks the Pallas kernels on TPU and the dense
+    path elsewhere.
     """
     interpret = _pallas_interpret(interpret, q.dtype)
     if interpret is None:
-        return _dense_reference(q, k, v, causal, q_offset)
+        return _dense_reference(q, k, v, causal, q_offset, window)
     o, _ = _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, 0,
-                      interpret)
+                      interpret, window=window)
     return o
 
 
-def _fwd_rule(q, k, v, causal, block_q, block_k, q_offset, interpret):
+def _fwd_rule(q, k, v, causal, block_q, block_k, q_offset, interpret,
+              window):
     interpret = _pallas_interpret(interpret, q.dtype)
     if interpret is None:
-        o, lse = _dense_fwd(q, k, v, causal, q_offset)
+        o, lse = _dense_fwd(q, k, v, causal, q_offset, window=window)
         return o.astype(q.dtype), (q, k, v, o.astype(q.dtype), lse)
     o, lse = _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, 0,
-                        interpret)
+                        interpret, window=window)
     return o, (q, k, v, o, lse)
 
 
-def _bwd_rule(causal, block_q, block_k, q_offset, interpret, res, do):
+def _bwd_rule(causal, block_q, block_k, q_offset, interpret, window, res,
+              do):
     q, k, v, o, lse = res
     delta = (do.astype(jnp.float32) * o.astype(jnp.float32)) \
         .sum(-1, keepdims=True)
@@ -1312,10 +1508,11 @@ def _bwd_rule(causal, block_q, block_k, q_offset, interpret, res, do):
     # default TPU transformer path the O(s^2) dense backward.
     interpret = _pallas_interpret(interpret, q.dtype)
     if interpret is None:
-        dq, dk, dv = _dense_bwd(q, k, v, do, lse, delta, causal, q_offset)
+        dq, dk, dv = _dense_bwd(q, k, v, do, lse, delta, causal, q_offset,
+                                window=window)
         return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
     return _flash_bwd(q, k, v, do, lse, delta, causal, block_q, block_k,
-                      q_offset, 0, interpret)
+                      q_offset, 0, interpret, window=window)
 
 
 flash_attention.defvjp(_fwd_rule, _bwd_rule)
@@ -1390,7 +1587,8 @@ def _under_full_manual(fn, *operands, heads_dim=1):
         if size == 1:
             continue
         dim = dim_of.get(a)
-        if dim is None or q.shape[dim] % size:
+        if dim is None or any(x.shape[dim] % size for x in operands
+                              if x.ndim == q.ndim):
             raise NotImplementedError(
                 f"flash attention on mesh {sizes}: axis {a!r} cannot split "
                 f"q {q.shape} (batch over 'data', heads over 'model'), and "
@@ -1423,7 +1621,11 @@ def make_flash_attn_fn(causal=False, block_q=512, block_k=1024):
     ``(batch, heads, seq, head_dim)`` (``_heads_per_block``; a mesh axis
     that splits the heads) and ``mha`` and the program are as they were.
     It also carries ``attn_fn.two_product(q, q_rope, k, k_rope, v, scale)``,
-    the two-product form for ``models.layers.mla``.
+    the two-product form for ``models.layers.mla``, and says by
+    ``attn_fn.grouped`` and ``attn_fn.windowed`` that it reads keys and
+    values of fewer heads than q where they lie (the split layout) and
+    takes ``window=`` (position t sees the keys s, ``t - window < s <= t``;
+    an explicit ``mask`` then has to hold the window itself).
     """
     from autodist_tpu.models import layers as L
 
@@ -1441,15 +1643,16 @@ def make_flash_attn_fn(causal=False, block_q=512, block_k=1024):
         interpret = _pallas_interpret(None, dtype)
         return None if interpret is None else (bq, bk, interpret)
 
-    def attn_fn(q, k, v, mask=None):
+    def attn_fn(q, k, v, mask=None, window=None):
         plan = kernels(q.shape[2], q.dtype, mask)
         if plan is None:
             return (L.dot_product_attention(q, k, v, mask) if mask is not None
-                    else _dense_reference(q, k, v, causal))
+                    else _dense_reference(q, k, v, causal, window=window))
         bq, bk, interpret = plan
         return _under_full_manual(
             lambda ql, kl, vl: flash_attention(ql, kl, vl, causal, bq, bk,
-                                               0, interpret), q, k, v)
+                                               0, interpret, window),
+            q, k, v)
 
     def packed(q, k, v, mask=None):
         plan = kernels(q.shape[1], q.dtype, mask)
@@ -1478,4 +1681,7 @@ def make_flash_attn_fn(causal=False, block_q=512, block_k=1024):
 
     attn_fn.bshd = bshd
     attn_fn.two_product = two_product
+    # ``models.layers.mha`` reads these: the hook takes keys and values of
+    # fewer heads than q as they are, and a ``window`` argument.
+    attn_fn.grouped = attn_fn.windowed = True
     return attn_fn

@@ -78,10 +78,6 @@ class MoEConfig:
         if scoring not in SCORINGS:
             raise ValueError(f"scoring must be one of {SCORINGS}, got "
                              f"{scoring!r}")
-        if scoring == "softmax" and route_scale != 1.0:
-            raise ValueError("route_scale scales sigmoid scores; with "
-                             f"softmax scoring it must be 1.0, got "
-                             f"{route_scale!r}")
         if shared and expert != "swiglu":
             raise ValueError("shared experts are SwiGLU MLPs: expert must "
                              f"be 'swiglu' with shared={shared!r}, got "
@@ -112,8 +108,8 @@ class MoEConfig:
         # "softmax" over the experts, or "sigmoid" of each logit on its own
         # (then ``norm_topk`` divides by the chosen scores' sum).
         self.scoring = scoring
-        # The chosen experts' weights are multiplied by this (sigmoid
-        # scoring only).
+        # The chosen experts' weights are multiplied by this, under either
+        # scoring (:func:`dropless_apply`; the capacity path has no scale).
         self.route_scale = route_scale
         # Shared experts beside the routed ones (``expert="swiglu"``): one
         # SwiGLU MLP of ``shared * d_hidden`` that every token passes,
@@ -349,6 +345,7 @@ def _announce(cfg, assignments):
     registry.gauge("moe.top_k").set(cfg.top_k)
     registry.gauge("moe.assignments_per_step").set(assignments)
     registry.gauge("moe.experts_held").set(cfg.num_held)
+    registry.gauge("moe.softmax_scoring").set(int(cfg.scoring == "softmax"))
     detail = (f"dropless: {assignments} assignments a step over "
               f"{cfg.num_experts} {cfg.expert} experts, {cfg.top_k} a token; "
               f"grouped product megablox gmm tiled {GMM_TILING}, "
@@ -447,8 +444,9 @@ def dropless_apply(params, cfg, x):
     """x: (rows, seq, d_model) -> (moe_out, stats); no assignment dropped.
 
     The router runs in float32; a token's ``top_k`` weights are left as the
-    softmax gave them unless ``cfg.norm_topk`` (``cfg.scoring="sigmoid"``:
-    :func:`_route_sigmoid`).  The ``T * k`` assignments
+    softmax gave them unless ``cfg.norm_topk``, then times
+    ``cfg.route_scale`` (``cfg.scoring="sigmoid"``: :func:`_route_sigmoid`).
+    The ``T * k`` assignments
     are sorted by expert (stable, so an expert's rows keep the tokens'
     order), the tokens' rows gathered in that order, and each of the
     expert matrices applied to its contiguous group of rows by one grouped
@@ -503,6 +501,8 @@ def dropless_apply(params, cfg, x):
             gates = jax.nn.softmax(logits, axis=-1)             # (T, E)
             # The capacity path's whole-batch balance term is not used here.
             top_vals, top_idx, _ = _route(gates, cfg)
+            if cfg.route_scale != 1.0:
+                top_vals = top_vals * cfg.route_scale
         flat_idx = top_idx.reshape(-1)                          # token-major
         # (rows, E) counts; a row's sum to seq * k.
         counts = jax.vmap(lambda i: jnp.bincount(i, length=num_e))(

@@ -1,9 +1,11 @@
-"""``moe.dropless_apply`` told its share of the experts, sigmoid-routed with a
-selection bias beside a shared expert, against the plain reference's layer
-(``chipbench/reference_mla_moe.py``): under even routing, under routing that
-sends every token's choices to held experts (the ``T x k`` worst case:
-nothing dropped) and under routing that sends none; and the shares add up
-to the uncut layer."""
+"""``moe.dropless_apply`` told its share of the experts beside a shared
+expert, in its two families: sigmoid-routed with a selection bias, 4 of 16 a
+token (``chipbench/reference_mla_moe.py``'s layer), and softmax-routed, 10 of
+64 a token, weights times 2.5 (``chipbench/reference_swa_moe.py``'s); each
+against its plain reference under even routing, under routing that sends
+every token's choices to held experts (the ``T x k`` worst case: nothing
+dropped) and under routing that sends none; and the shares (4 of the one, 32
+of the other) add up to the uncut layer."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,11 +13,21 @@ import pytest
 
 from autodist_tpu.parallel import moe
 from chipbench import reference_mla_moe as ref
+from chipbench import reference_swa_moe
 
 E, K, D, H, RATE, SCALE = 16, 4, 32, 24, 0.001, 2.5
+# The softmax family (Laguna's layer): 10 of 64 a token, no bias, a share of
+# 2 in the sum over 32 shares and of 12 where every choice has to fit it.
+SOFTMAX = "softmax"
+FAMILIES = ["sigmoid", SOFTMAX]
 
 
-def _cfg(held, **kw):
+def _cfg(held, family="sigmoid", **kw):
+    if family == SOFTMAX:
+        return moe.MoEConfig(num_experts=64, top_k=10, d_model=D, d_hidden=H,
+                             dtype=jnp.float32, expert="swiglu",
+                             norm_topk=True, scoring="softmax",
+                             route_scale=SCALE, shared=1, held=held, **kw)
     return moe.MoEConfig(num_experts=E, top_k=K, d_model=D, d_hidden=H,
                          dtype=jnp.float32, expert="swiglu", norm_topk=True,
                          scoring="sigmoid", route_scale=SCALE, shared=1,
@@ -23,21 +35,33 @@ def _cfg(held, **kw):
                          **kw)
 
 
-def _layer(held, bias=None, seed=0, rows=2, seq=64):
+def _layer(held, bias=None, seed=0, rows=2, seq=64, family="sigmoid",
+           towards=None):
     """Parameters of the WHOLE layer cut to ``held``'s matrices, so that
-    every share of one seed routes alike."""
-    whole = moe.init(jax.random.PRNGKey(seed), _cfg(None))
-    first, count = held or (0, E)
+    every share of one seed routes alike.  ``towards`` sends every token's
+    choices to those experts: by the selection bias where the family has
+    one, else by a constant input lane that the router's matrix weighs."""
+    whole = moe.init(jax.random.PRNGKey(seed), _cfg(None, family))
+    first, count = held or (0, _cfg(None, family).num_experts)
     p = dict(whole, **{name: {"kernel":
                               whole[name]["kernel"][first:first + count]}
                        for name in ("up", "down", "glu")})
     if bias is not None:
         p["bias"] = jnp.asarray(bias, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(seed + 1), (rows, seq, D))
+    if towards is not None and family == SOFTMAX:
+        x = x.at[..., -1].set(1.0)
+        p["gate"] = {"kernel": p["gate"]["kernel"].at[
+            -1, jnp.asarray(towards)].add(30.0)}
+    elif towards is not None:
+        p["bias"] = _towards(towards)
     return p, x
 
 
-def _reference(p, x, held):
+def _reference(p, x, held, family="sigmoid"):
+    if family == SOFTMAX:
+        return reference_swa_moe.experts_layer(
+            p, x, top_k=10, route_scale=SCALE, held=held)
     return ref.experts_layer(p, x, top_k=K, route_scale=SCALE, held=held)
 
 
@@ -46,61 +70,77 @@ def _towards(experts):
     return jnp.zeros((E,)).at[jnp.asarray(experts)].set(10.0)
 
 
-@pytest.mark.parametrize("routing, bias, held_share", [
-    ("even", None, None),
-    ("all_held", _towards([4, 5, 6, 7]), 1.0),
-    ("none_held", _towards([0, 1, 8, 9]), 0.0)])
-def test_the_held_part_matches_the_reference(routing, bias, held_share):
-    held = (4, 4)
-    p, x = _layer(held, bias)
+# ``(held, the experts an all-on-held routing chooses, those a none-on-held
+# routing chooses)`` of each family: every one of a token's choices fits.
+_STEERED = {"sigmoid": ((4, 4), [4, 5, 6, 7], [0, 1, 8, 9]),
+            SOFTMAX: ((20, 12), list(range(21, 31)), list(range(40, 50)))}
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+@pytest.mark.parametrize("routing, held_share", [
+    ("even", None), ("all_held", 1.0), ("none_held", 0.0)])
+def test_the_held_part_matches_the_reference(routing, held_share, family):
+    held, on_held, off_held = _STEERED[family]
+    towards = {"even": None, "all_held": on_held,
+               "none_held": off_held}[routing]
+    p, x = _layer(held, family=family, towards=towards)
+    cfg = _cfg(held, family)
     w = jax.random.normal(jax.random.PRNGKey(5), x.shape)
     with jax.default_matmul_precision("highest"):
-        got, stats = moe.dropless_apply(p, _cfg(held), x)
-        want, balance, counts, routed_rms = _reference(p, x, held)
+        got, stats = moe.dropless_apply(p, cfg, x)
+        want, balance, counts, routed_rms = _reference(p, x, held, family)
         np.testing.assert_allclose(got, want, atol=2e-5)
         grads = [jax.grad(lambda p, x: jnp.sum(f(p, x) * w), (0, 1))(p, x)
-                 for f in (lambda p, x: moe.dropless_apply(p, _cfg(held),
-                                                           x)[0],
-                           lambda p, x: _reference(p, x, held)[0])]
+                 for f in (lambda p, x: moe.dropless_apply(p, cfg, x)[0],
+                           lambda p, x: _reference(p, x, held, family)[0])]
     for a, b in zip(*(jax.tree_util.tree_leaves(g) for g in grads)):
         np.testing.assert_allclose(a, b, atol=1e-4 * float(jnp.abs(b).max())
                                    + 1e-6)
     tokens = x.shape[0] * x.shape[1]
     assert float(stats["dropped"]) == 0.0
-    assert float(stats["held_assignments"]) == float(counts[4:8].sum())
+    assert float(stats["held_assignments"]) == float(
+        counts[held[0]:held[0] + held[1]].sum())
     np.testing.assert_allclose(stats["held_output_rms"], routed_rms,
                                rtol=1e-4, atol=1e-7)
     assert (float(routed_rms) == 0.0) == (held_share == 0.0)
     if held_share is not None:
-        assert float(stats["held_assignments"]) == held_share * tokens * K
+        assert float(stats["held_assignments"]) \
+            == held_share * tokens * cfg.top_k
     np.testing.assert_allclose(stats["load_balance"], balance, rtol=1e-5)
     assert float(stats["load_max_over_mean"]) == pytest.approx(
-        float(counts.max()) * E / (tokens * K))
-    # The bias has no gradient: it only chooses.
-    assert float(jnp.abs(grads[0][0]["bias"]).max()) == 0.0
+        float(counts.max()) * cfg.num_experts / (tokens * cfg.top_k))
+    if family == "sigmoid":     # the bias has no gradient: it only chooses
+        assert float(jnp.abs(grads[0][0]["bias"]).max()) == 0.0
+    else:
+        assert "bias" not in p and "z_loss" in stats
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
-    """The routed parts of the four shares plus the shared expert, counted
-    once, are the layer that holds every expert."""
-    whole_p, x = _layer(None)
+@pytest.mark.parametrize("family, count", [("sigmoid", 4), (SOFTMAX, 2)],
+                         ids=["sigmoid-4-shares", "softmax-32-shares"])
+def test_the_shares_add_up_to_the_uncut_layer(family, count):
+    """The routed parts of the shares (four of 4 experts; thirty-two of 2,
+    10 a token, weights times 2.5) plus the shared expert, counted once, are
+    the layer that holds every expert."""
+    whole_p, x = _layer(None, family=family)
+    cfg = _cfg(None, family)
     with jax.default_matmul_precision("highest"):
-        whole, whole_stats = moe.dropless_apply(whole_p, _cfg(None), x)
-        shared = moe._shared_expert(whole_p["shared"], _cfg(None),
+        whole, whole_stats = moe.dropless_apply(whole_p, cfg, x)
+        shared = moe._shared_expert(whole_p["shared"], cfg,
                                     x.reshape(-1, D)).reshape(x.shape)
         routed, held = 0.0, 0.0
-        for first in range(0, E, 4):
-            p, _ = _layer((first, 4))
-            part, stats = moe.dropless_apply(p, _cfg((first, 4)), x)
+        for first in range(0, cfg.num_experts, count):
+            p, _ = _layer((first, count), family=family)
+            part, stats = moe.dropless_apply(
+                p, _cfg((first, count), family), x)
             routed = routed + (part - shared)
             held += float(stats["held_assignments"])
             assert float(stats["dropped"]) == 0.0
     np.testing.assert_allclose(routed + shared, whole, atol=3e-5)
-    assert held == x.shape[0] * x.shape[1] * K
+    assert held == x.shape[0] * x.shape[1] * cfg.top_k
     assert "held_assignments" not in whole_stats
     # And the uncut layer is the reference's with every expert held.
     with jax.default_matmul_precision("highest"):
-        want, _, _, _ = _reference(whole_p, x, (0, E))
+        want, _, _, _ = _reference(whole_p, x, (0, cfg.num_experts), family)
     np.testing.assert_allclose(whole, want, atol=3e-5)
 
 
@@ -182,7 +222,7 @@ def test_the_event_names_the_share():
 
 
 @pytest.mark.parametrize("kwargs, message", [
-    (dict(scoring="softmax", route_scale=2.5), "route_scale"),
+    (dict(scoring="tanh", route_scale=2.5), "scoring must be one of"),
     (dict(expert="gelu", shared=1), "shared experts are SwiGLU")])
 def test_a_configuration_no_path_computes_is_refused(kwargs, message):
     with pytest.raises(ValueError, match=message):

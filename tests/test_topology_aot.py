@@ -460,6 +460,63 @@ def test_v5e_compiler_takes_the_causal_walk(heads, s, monkeypatch):
         == 3
 
 
+@pytest.mark.parametrize("heads,window", [(18, 512), (12, None)],
+                         ids=["window-group-9", "full-group-6"])
+def test_v5e_compiler_takes_grouped_heads_and_the_windows_walk(
+        heads, window, monkeypatch):
+    """One attention layer of the Laguna cell's kinds at its head width and
+    groups (2 key-value heads of 128 for 18 query heads behind a 512-key
+    window, or for 12 with none; a gate; rotary), forward and backward,
+    compiled by libtpu for one detached v5e chip.  Mosaic takes the index
+    maps that pick a key-value head by the grid's index over the group, the
+    dk/dv kernel's innermost dimension over a group's query heads, and the
+    window's walk with both loop bounds from the device; and keys, values,
+    dk and dv stay ``kv_heads`` wide at the kernels: no operand or result of
+    a kernel that is a key's is as wide as the query heads."""
+    why_not = _why_no_detached_topology()
+    if why_not:
+        pytest.skip(why_not)
+    import importlib
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from autodist_tpu.models import layers as L
+    fa = importlib.import_module("autodist_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_pallas_interpret", lambda *_: False)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x4")
+    chip = SingleDeviceSharding(topo.devices[0])
+    b, s, dim, d, kv = 1, 2048, 512, 128, 2
+    hook = fa.make_flash_attn_fn(causal=True)
+    rope = L.rope_tables(s, d, 1e4) if window else L.yarn_rope_tables(
+        s, d // 2, 5e5, 128.0, 8192, 32.0, 1.0, 1.4852)
+
+    def loss(p, x):
+        y = L.mha(p, x, heads, dtype=jnp.bfloat16, attn_fn=hook, rope=rope,
+                  norm_eps=1e-6, kv_heads=kv, window=window)
+        return (y.astype(jnp.float32) ** 2).sum()
+    params = jax.eval_shape(lambda: L.mha_init(
+        jax.random.PRNGKey(0), dim, heads, False, False, d, kv, True))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        params)
+    x = jax.ShapeDtypeStruct((b, s, dim), jnp.bfloat16, sharding=chip)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    kernels = {name: line for line in text.splitlines()
+               for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+               if "tpu_custom_call" in line and f"%{name}" in line}
+    assert len(kernels) == 3
+    wide, narrow = f"bf16[{heads},{s},{d}]", f"bf16[{kv},{s},{d}]"
+    for name, line in kernels.items():
+        operands = re.search(r"operand_layout_constraints=\{(.*?\})\}, ",
+                             line).group(1)
+        # q (and do) a query head, k and v a key-value head.
+        assert operands.count(narrow) == 2, (name, operands)
+        assert operands.count(wide) == (1 if name == "flash_fwd" else 2)
+    results = kernels["flash_bwd_dkv"].split(" custom-call(")[0]
+    assert results.count(f"bf16[{kv},{s},{d}]") == 2 and wide not in results
+
+
 @pytest.mark.parametrize("chunk, solves", [(64, False), (48, True)])
 def test_v5e_compiler_runs_no_triangular_solve_for_a_chunk_of_64(chunk,
                                                                  solves):
