@@ -328,6 +328,12 @@ UNATTRIBUTED = "(unattributed)"
 _SCOPE_WRAP_RE = re.compile(
     r"\b(?:jvp|vjp|transpose|vmap|pmap|remat|checkpoint|custom_jvp|"
     r"custom_vjp|scan|while|cond)\(([^()]*)\)")
+# The frames a loop or a conditional lowers its body's instructions under
+# (``.../moe/while/body/dispatch/...``, ``.../cond/branch_1_fun/...``):
+# machinery between the user's scopes.  The loop or conditional itself
+# (``.../moe/while``) keeps its name.
+_BODY_FRAME_RE = re.compile(
+    r"(?<![^/])(?:while/(?:body|cond)|cond/branch_\d+_fun)/")
 
 
 def scope_path(name_stack_text):
@@ -348,6 +354,7 @@ def scope_path(name_stack_text):
     while prev != text:
         prev = text
         text = _SCOPE_WRAP_RE.sub(r"\1", text)
+    text = _BODY_FRAME_RE.sub("", text)
     segments = []
     for seg in text.split("/"):
         seg = seg.strip()

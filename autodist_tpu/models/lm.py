@@ -189,6 +189,8 @@ def make_loss_fn(cfg, attn_fn=None):
             if reported("held_assignments"):
                 aux["moe.held_assignments"] = over_layers("held_assignments",
                                                           jnp.sum)
+            if reported("held_buffer_rows"):
+                aux["moe.held_buffer_rows"] = over_layers("held_buffer_rows")
             if reported("held_output_rms"):
                 aux["moe.held_output_rms"] = over_layers("held_output_rms")
             if reported("bias_absmax"):

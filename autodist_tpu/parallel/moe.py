@@ -42,8 +42,12 @@ matrices gathered or exchanged by GSPMD, while ``held`` is the form in
 which the experts stay where they are.  Tokens that travel to the rank
 holding their expert, and come back, start from here: the routed parts of
 all the shares add up to the whole layer (``tests/test_moe_held.py``), so
-what is missing is the exchange of the rows, not the arithmetic.
+what is missing is the exchange of the rows, not the arithmetic.  Such a
+layer moves only the rows that meet a held expert, as a rank's receive
+buffer would hold them, a chunk at a time and as many chunks as the step's
+held count takes (:func:`held_rungs`, :func:`_held_part`).
 """
+import functools
 import math
 
 import jax
@@ -293,6 +297,26 @@ def apply(params, cfg, x):
 #: 1,024 rows or 2,048 deep does not fit the kernel's memory (PERF.md, PR 25).
 GMM_TILING = (512, 1024, 1024)
 
+#: A held layer works through its held rows in chunks of a sixteenth of the
+#: step's ``T * k`` assignments (:func:`held_chunk_rows`).
+_HELD_CHUNKS = 16
+
+
+def held_chunk_rows(assignments):
+    """The rows of one chunk of a held layer's buffers: a sixteenth of
+    ``assignments`` rounded up to whole row tiles of the grouped product."""
+    tile = GMM_TILING[0]
+    return min(assignments, -(-assignments // (_HELD_CHUNKS * tile)) * tile)
+
+
+def held_rungs(assignments):
+    """The static ladder of a held layer's buffer sizes in rows, ascending:
+    the multiples of :func:`held_chunk_rows` up to the first that holds all
+    ``assignments``.  A step takes the smallest rung that is at least its
+    held count, by running that many chunks."""
+    chunk = held_chunk_rows(assignments)
+    return tuple(range(0, assignments + chunk, chunk))
+
 
 def grouped_product(lhs, rhs, group_sizes):
     """``lhs`` (m, k) rows, sorted into ``len(group_sizes)`` contiguous
@@ -346,6 +370,8 @@ def _announce(cfg, assignments):
     registry.gauge("moe.assignments_per_step").set(assignments)
     registry.gauge("moe.experts_held").set(cfg.num_held)
     registry.gauge("moe.softmax_scoring").set(int(cfg.scoring == "softmax"))
+    rungs = held_rungs(assignments) if cfg.held is not None else ()
+    registry.gauge("moe.held_buffer_rungs").set(len(rungs))
     detail = (f"dropless: {assignments} assignments a step over "
               f"{cfg.num_experts} {cfg.expert} experts, {cfg.top_k} a token; "
               f"grouped product megablox gmm tiled {GMM_TILING}, "
@@ -354,9 +380,11 @@ def _announce(cfg, assignments):
     if cfg.held is not None or cfg.scoring != "softmax" or cfg.shared:
         first, count = cfg.held or (0, cfg.num_experts)
         detail += (f"; {cfg.scoring} scores, experts {first}-"
-                   f"{first + count - 1} held ({count} of {cfg.num_experts}: "
-                   f"the rows of the others are sorted behind and not "
-                   f"visited), {cfg.shared} shared"
+                   f"{first + count - 1} held ({count} of {cfg.num_experts}"
+                   + (f": held rows in chunks of {rungs[1]} rows, as many "
+                      f"as the step's count takes, {len(rungs) - 1} at most"
+                      if rungs else "")
+                   + f"), {cfg.shared} shared"
                    + (", selection bias" if cfg.select_bias else ""))
     if detail not in _announced:
         _announced.add(detail)
@@ -440,6 +468,166 @@ def _shared_expert(params, cfg, flat_x):
     return L.dense(params["down"], hidden, cfg.dtype)
 
 
+def _experts(cfg, kernel, rows, group_sizes):
+    """The experts' MLP of ``rows`` (m, d), sorted into ``group_sizes``'
+    contiguous groups: (m, d).  ``kernel(name)`` gives the stacked matrix
+    ``up``, ``down`` or ``glu`` in ``cfg.dtype``."""
+    def grouped(lhs, name):
+        return grouped_product(lhs, kernel(name), group_sizes)
+    hidden = jax.nn.silu(grouped(rows, "glu")) * grouped(rows, "up") \
+        if cfg.expert == "swiglu" else jax.nn.gelu(grouped(rows, "up"))
+    return grouped(hidden, "down")
+
+
+def _chunk(cfg, chunk, i, x, top_vals, where):
+    """Chunk ``i`` of the held rows, ``chunk`` rows of the sorted order:
+    ``(the assignment in each row, its token, whether the row is held, its
+    weight, the tokens' rows (chunk, d), the experts' group sizes within the
+    chunk)``."""
+    first = i * chunk
+    assignment = jax.lax.dynamic_slice(where["order"], (first,), (chunk,))
+    token = assignment // cfg.top_k
+    valid = (first + jnp.arange(chunk) < where["held"])[:, None]
+    ends = jnp.cumsum(where["group_sizes"])
+    inside = jnp.clip(jnp.stack([ends - where["group_sizes"], ends]),
+                      first, first + chunk)
+    return (assignment, token, valid, top_vals.reshape(-1)[assignment],
+            x[token], inside[1] - inside[0])
+
+
+def _chunk_experts(cfg, valid, group_sizes):
+    """The held experts' MLP of a chunk's rows; no product visits the rows
+    behind the held ones, which are zero going in and coming out."""
+    def run(rows_in, kernels):
+        rows_in = jnp.where(valid, rows_in, 0)
+        return jnp.where(valid, _experts(cfg, kernels.__getitem__, rows_in,
+                                         group_sizes), 0)
+    return run
+
+
+def _cast(cfg, kernels):
+    """The stacked matrices in ``cfg.dtype``, as the products take them."""
+    with jax.named_scope("experts"):
+        return {name: k.astype(cfg.dtype) for name, k in kernels.items()}
+
+
+# The two loops are inlined ``jit``s: a model's held layers make the same
+# call, and every one after the first takes the first's equations from the
+# cache (the benchmark's process traces slowly: PERF.md section 7).
+@functools.partial(jax.jit, static_argnums=(0, 1), inline=True)
+def _held_forward(cfg, chunk, x, top_vals, kernels, where):
+    """What the held experts add to each token, (T, d) float32: chunk by
+    chunk, each chunk's rows gathered, put through the experts, weighted and
+    added to their tokens' rows."""
+    kernels = _cast(cfg, kernels)
+
+    def body(i, out):
+        with jax.named_scope("dispatch"):
+            _, token, valid, weight, rows_in, sizes = _chunk(
+                cfg, chunk, i, x, top_vals, where)
+        with jax.named_scope("experts"):
+            expert_out = _chunk_experts(cfg, valid, sizes)(rows_in, kernels)
+        with jax.named_scope("dispatch"):
+            return out.at[token].add(
+                expert_out.astype(jnp.float32) * weight[:, None])
+    return jax.lax.fori_loop(
+        0, where["chunks"], body,
+        jnp.zeros((x.shape[0], cfg.d_model), jnp.float32))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1), inline=True)
+def _held_backward(cfg, chunk, x, top_vals, kernels, where, g):
+    """The gradients of ``x``, ``top_vals`` and ``kernels`` for ``g``, that
+    of :func:`_held_forward`'s result; each chunk's grouped products are
+    computed again, and the sums over the chunks are float32."""
+    cast = _cast(cfg, kernels)
+
+    def body(i, sums):
+        d_x, d_top_vals, d_kernels = sums
+        with jax.named_scope("dispatch"):
+            assignment, token, valid, weight, rows_in, sizes = _chunk(
+                cfg, chunk, i, x, top_vals, where)
+            g_rows = g[token]
+        with jax.named_scope("experts"):
+            expert_out, pull = jax.vjp(_chunk_experts(cfg, valid, sizes),
+                                       rows_in, cast)
+            d_rows_in, d_cast = pull(
+                (g_rows * weight[:, None]).astype(cfg.dtype))
+            d_kernels = jax.tree_util.tree_map(
+                lambda total, d: total + d.astype(total.dtype),
+                d_kernels, d_cast)
+        with jax.named_scope("dispatch"):
+            d_weight = jnp.sum(g_rows * expert_out.astype(jnp.float32), 1)
+            return (d_x.at[token].add(d_rows_in.astype(jnp.float32)),
+                    d_top_vals.at[assignment].add(
+                        jnp.where(valid[:, 0], d_weight, 0)),
+                    d_kernels)
+    d_x, d_top_vals, d_kernels = jax.lax.fori_loop(
+        0, where["chunks"], body,
+        (jnp.zeros(x.shape, jnp.float32),
+         jnp.zeros((top_vals.size,), top_vals.dtype),
+         jax.tree_util.tree_map(jnp.zeros_like, kernels)))
+    return (d_x.astype(x.dtype), d_top_vals.reshape(top_vals.shape),
+            d_kernels)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_rows(cfg, chunk, x, top_vals, kernels, where):
+    """:func:`_held_forward`, with :func:`_held_backward` for its gradient:
+    both loop over the same chunks, and nothing is kept between them but
+    the inputs."""
+    return _held_forward(cfg, chunk, x, top_vals, kernels, where)
+
+
+def _held_rows_fwd(cfg, chunk, *inputs):
+    return _held_forward(cfg, chunk, *inputs), inputs
+
+
+def _held_rows_bwd(cfg, chunk, inputs, g):
+    return _held_backward(cfg, chunk, *inputs, g) + (None,)
+
+
+_held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
+
+
+def _held_part(params, cfg, flat_x, top_vals, flat_idx, group_sizes, stats):
+    """What the held experts add to each token, (T, d) float32, and their
+    statistics into ``stats``: :func:`dropless_apply` with ``cfg.held``.
+
+    The held assignments sort first, by held expert and stable in the
+    token, and only they are moved: a loop on the device takes them a chunk
+    of :func:`held_chunk_rows` rows at a time, as many chunks as hold the
+    step's held count (:func:`held_rungs`), and every array between the
+    gather of a chunk's token rows and their sum into the tokens' rows has
+    a chunk's rows.  At most all ``T * k`` assignments are held, in
+    ``_HELD_CHUNKS`` chunks: a token whose ``top_k`` are all held loses
+    none, and the result is exact at every count."""
+    (tokens, top_k), (first, count) = top_vals.shape, cfg.held
+    chunk = held_chunk_rows(tokens * top_k)
+    with jax.named_scope("dispatch"):
+        local = flat_idx - first
+        group_sizes = group_sizes[first:first + count]
+        held = group_sizes.sum()
+        # argsort, keeping the sorted keys the same sort produces.
+        sorted_experts, order = jax.lax.sort_key_val(
+            jnp.where((local >= 0) & (local < count), local, count),
+            jnp.arange(tokens * top_k, dtype=flat_idx.dtype))
+        chunks = -(-held // chunk)
+        # The last chunk may end behind the assignments: rows never held.
+        order = jnp.pad(order, (0, -(tokens * top_k) % chunk))
+        stats["dropped"] = _uncovered(sorted_experts, group_sizes)
+        stats["held_assignments"] = held.astype(jnp.float32)
+        stats["held_buffer_rows"] = (chunks * chunk).astype(jnp.float32)
+    out = _held_rows(
+        cfg, chunk, flat_x.astype(cfg.dtype), top_vals,
+        {name: params[name]["kernel"]
+         for name in ("glu", "up", "down") if name in params},
+        {"chunks": chunks, "held": held, "order": order,
+         "group_sizes": group_sizes})
+    stats["held_output_rms"] = jnp.sqrt(jnp.mean(jnp.square(out)))
+    return out
+
+
 def dropless_apply(params, cfg, x):
     """x: (rows, seq, d_model) -> (moe_out, stats); no assignment dropped.
 
@@ -455,13 +643,22 @@ def dropless_apply(params, cfg, x):
     MLP of every token (scope ``shared``).
 
     With ``cfg.held = (first, count)`` the layer computes the held experts'
-    part exactly: routing, weights and every statistic but ``dropped`` and
-    ``held_assignments`` are over all ``num_experts``; the assignments sort
-    by held expert with every other assignment behind them, the grouped
-    products (``count`` groups) visit only the tiles of the held rows, and
-    the rows behind them are zero going in and coming out.  The buffers are
-    ``T * k`` rows whatever the routing, so that a token whose ``top_k`` are
-    all held loses none.
+    part exactly (:func:`_held_part`): routing, weights and every statistic
+    but ``dropped``, ``held_assignments`` and ``held_buffer_rows`` are over
+    all ``num_experts``; the assignments sort by held expert with every
+    other assignment behind them, and only the held ones are moved: a loop
+    on the device takes them a chunk at a time (:func:`held_chunk_rows`: a
+    sixteenth of the ``T * k`` assignments, in whole row tiles of the
+    grouped product) and runs as many chunks as hold the step's held count,
+    so the buffers are a chunk's rows and the work follows the count; all
+    sixteen chunks are ``T * k`` rows, so that a token whose ``top_k`` are
+    all held loses none, and the result is exact at every count.  ``stats``'
+    ``held_buffer_rows`` is the rows of the chunks run (a rung of
+    :func:`held_rungs`): ``held_assignments`` over it is the buffers' fill,
+    and a step that ran every chunk moved every row, as a layer that sizes
+    its buffers for the worst case would.  The chunk is in the ``moe``
+    event's line and the ladder's length in the gauge
+    ``moe.held_buffer_rungs``.
 
     ``stats`` (float32 scalars but ``state_updates``): ``load_balance`` = E
     * sum_e f_e P_e with f_e the share of a row's ``seq * k`` assignments
@@ -473,9 +670,11 @@ def dropless_apply(params, cfg, x):
     held assignments whose sorted row the grouped products do not put
     through its own expert (:func:`_uncovered`): 0 while the sort and the
     group sizes agree; with ``cfg.held``, ``held_assignments`` = the
-    assignments that chose a held expert and ``held_output_rms`` = the root
+    assignments that chose a held expert, ``held_buffer_rows`` = the rows
+    of the chunks they were moved in, and ``held_output_rms`` = the root
     mean square, over tokens and lanes, of what the held experts add to the
-    output (weighted, before the shared expert: 0 where nothing landed).  With ``cfg.select_bias``: ``bias_absmax`` and
+    output (weighted, before the shared expert: 0 where nothing landed).
+    With ``cfg.select_bias``: ``bias_absmax`` and
     ``state_updates = {"bias": the bias after this step}``, each entry
     moved by ``bias_update_rate`` up where the expert got fewer assignments
     than the mean, down where more (the loss-free balancing of
@@ -523,42 +722,26 @@ def dropless_apply(params, cfg, x):
                 load.mean() - load)
             stats["state_updates"] = {"bias": jax.lax.stop_gradient(bias)}
             stats["bias_absmax"] = jnp.max(jnp.abs(bias))
-    with jax.named_scope("dispatch"):
-        sort_keys, valid = flat_idx, None
-        if cfg.held is not None:
-            # Held experts' assignments first, by expert; the rest behind.
-            first, count = cfg.held
-            local = flat_idx - first
-            sort_keys = jnp.where((local >= 0) & (local < count), local, count)
-            group_sizes = group_sizes[first:first + count]
-            held = group_sizes.sum()
-            valid = (jnp.arange(tokens * top_k) < held)[:, None]
-            stats["held_assignments"] = held.astype(jnp.float32)
-        # argsort, keeping the sorted keys the same sort produces.
-        sorted_experts, order = jax.lax.sort_key_val(
-            sort_keys, jnp.arange(tokens * top_k, dtype=flat_idx.dtype))
-        inverse = jnp.argsort(order)
-        stats["dropped"] = _uncovered(sorted_experts, group_sizes)
-        sorted_x = _sorted_rows(flat_x.astype(cfg.dtype), order, inverse,
-                                top_k)
-        if valid is not None:
-            sorted_x = jnp.where(valid, sorted_x, 0)
-    with jax.named_scope("experts"):
-        def grouped(lhs, name):
-            return grouped_product(
-                lhs, params[name]["kernel"].astype(cfg.dtype), group_sizes)
-        hidden = jax.nn.silu(grouped(sorted_x, "glu")) * \
-            grouped(sorted_x, "up") if cfg.expert == "swiglu" \
-            else jax.nn.gelu(grouped(sorted_x, "up"))
-        expert_out = grouped(hidden, "down")                    # (T * k, d)
-        if valid is not None:   # no product visited the rows behind
-            expert_out = jnp.where(valid, expert_out, 0)
-    with jax.named_scope("dispatch"):
-        y = _unsorted_rows(expert_out, order, inverse) \
-            .reshape(tokens, top_k, cfg.d_model)
-        out = jnp.sum(y.astype(jnp.float32) * top_vals[..., None], axis=1)
-        if cfg.held is not None:
-            stats["held_output_rms"] = jnp.sqrt(jnp.mean(jnp.square(out)))
+    if cfg.held is not None:
+        out = _held_part(params, cfg, flat_x, top_vals, flat_idx,
+                         group_sizes, stats)
+    else:
+        with jax.named_scope("dispatch"):
+            # argsort, keeping the sorted keys the same sort produces.
+            sorted_experts, order = jax.lax.sort_key_val(
+                flat_idx, jnp.arange(tokens * top_k, dtype=flat_idx.dtype))
+            inverse = jnp.argsort(order)
+            stats["dropped"] = _uncovered(sorted_experts, group_sizes)
+            sorted_x = _sorted_rows(flat_x.astype(cfg.dtype), order, inverse,
+                                    top_k)
+        with jax.named_scope("experts"):
+            expert_out = _experts(
+                cfg, lambda name: params[name]["kernel"].astype(cfg.dtype),
+                sorted_x, group_sizes)                          # (T * k, d)
+        with jax.named_scope("dispatch"):
+            y = _unsorted_rows(expert_out, order, inverse) \
+                .reshape(tokens, top_k, cfg.d_model)
+            out = jnp.sum(y.astype(jnp.float32) * top_vals[..., None], axis=1)
     if cfg.shared:
         with jax.named_scope("shared"):
             out = out + _shared_expert(params["shared"], cfg, flat_x) \

@@ -149,7 +149,8 @@ def test_three_adam_steps_through_the_runner_match_the_reference(steps=3):
     assert float(np.abs(mu["layer1"]["moe"]["bias"]).max()) == 0.0
     assert sorted(runner.last_aux) == [
         "moe.bias_absmax", "moe.dropped", "moe.held_assignments",
-        "moe.held_output_rms", "moe.load_balance_loss", "moe.load_max_over_mean", "mtp.xent",
+        "moe.held_buffer_rows", "moe.held_output_rms", "moe.load_balance_loss",
+        "moe.load_max_over_mean", "mtp.xent",
         "xent"]
     _reset_default()
 

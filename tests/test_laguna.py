@@ -88,7 +88,8 @@ def test_the_loss_and_every_leafs_gradient_match_the_reference(core,
     assert float(aux["moe.dropped"]) == 0.0
     assert 0 < float(aux["moe.held_assignments"]) < 7 * 2 * 32 * 5
     assert sorted(aux) == [
-        "moe.dropped", "moe.held_assignments", "moe.held_output_rms",
+        "moe.dropped", "moe.held_assignments", "moe.held_buffer_rows",
+        "moe.held_output_rms",
         "moe.load_balance_loss", "moe.load_max_over_mean",
         "moe.router_z_loss", "xent"]
 
@@ -175,7 +176,8 @@ def test_three_adam_steps_through_the_runner_match_the_reference(steps=3):
     np.testing.assert_allclose(got, want, rtol=1e-5)
     assert got[-1] < got[0]
     assert sorted(runner.last_aux) == [
-        "moe.dropped", "moe.held_assignments", "moe.held_output_rms",
+        "moe.dropped", "moe.held_assignments", "moe.held_buffer_rows",
+        "moe.held_output_rms",
         "moe.load_balance_loss", "moe.load_max_over_mean",
         "moe.router_z_loss", "xent"]
     _reset_default()

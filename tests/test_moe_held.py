@@ -4,8 +4,10 @@ token (``chipbench/reference_mla_moe.py``'s layer), and softmax-routed, 10 of
 64 a token, weights times 2.5 (``chipbench/reference_swa_moe.py``'s); each
 against its plain reference under even routing, under routing that sends
 every token's choices to held experts (the ``T x k`` worst case: nothing
-dropped) and under routing that sends none; and the shares (4 of the one, 32
-of the other) add up to the uncut layer."""
+dropped) and under routing that sends none; at held counts on, beside and
+between the rungs of the buffers' ladder (``moe.held_rungs``), from no
+chunk to every one; and the shares (4 of the one, 32 of the other) add up to
+the uncut layer."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -36,11 +38,15 @@ def _cfg(held, family="sigmoid", **kw):
 
 
 def _layer(held, bias=None, seed=0, rows=2, seq=64, family="sigmoid",
-           towards=None):
+           towards=None, held_count=None):
     """Parameters of the WHOLE layer cut to ``held``'s matrices, so that
     every share of one seed routes alike.  ``towards`` sends every token's
     choices to those experts: by the selection bias where the family has
-    one, else by a constant input lane that the router's matrix weighs."""
+    one, else by a constant input lane that the router's matrix weighs.
+    ``held_count`` sends exactly that many of the assignments to held
+    experts: three input lanes, each one for some tokens and nought for the
+    others, that the router's matrix weighs towards ``top_k`` held experts,
+    towards one held expert and ``top_k - 1`` others, and towards none."""
     whole = moe.init(jax.random.PRNGKey(seed), _cfg(None, family))
     first, count = held or (0, _cfg(None, family).num_experts)
     p = dict(whole, **{name: {"kernel":
@@ -55,6 +61,20 @@ def _layer(held, bias=None, seed=0, rows=2, seq=64, family="sigmoid",
             -1, jnp.asarray(towards)].add(30.0)}
     elif towards is not None:
         p["bias"] = _towards(towards)
+    if held_count is not None:
+        _, on_held, off_held = _STEERED[family]
+        top_k = len(on_held)
+        every, one = divmod(held_count, top_k)
+        token = jnp.arange(rows * seq).reshape(rows, seq)
+        lanes = jnp.stack([token >= every + one,
+                           (token >= every) & (token < every + one),
+                           token < every], axis=-1)
+        x = x.at[..., -3:].set(lanes.astype(x.dtype))
+        gate = p["gate"]["kernel"]
+        for lane, experts in ((-3, off_held), (-2, on_held[:1] + off_held[1:]),
+                              (-1, on_held)):
+            gate = gate.at[lane, jnp.asarray(experts)].add(30.0)
+        p["gate"] = {"kernel": gate}
     return p, x
 
 
@@ -76,15 +96,38 @@ _STEERED = {"sigmoid": ((4, 4), [4, 5, 6, 7], [0, 1, 8, 9]),
             SOFTMAX: ((20, 12), list(range(21, 31)), list(range(40, 50)))}
 
 
+# A held count of each case that steers by ``held_count``, from the ladder's
+# rungs and the assignments (the grouped product's row tile cut to 16, so
+# that a chunk is 32 or 80 rows and 512 or 1,280 assignments take sixteen):
+# no chunk, one, two, three and all sixteen.
+_COUNTS = {"no_row": lambda rungs, every: 0,
+           "one_under_a_rung": lambda rungs, every: rungs[1] - 1,
+           "a_rung": lambda rungs, every: rungs[2],
+           "one_over_a_rung": lambda rungs, every: rungs[2] + 1,
+           "every_row": lambda rungs, every: every}
+
+
 @pytest.mark.parametrize("family", FAMILIES)
 @pytest.mark.parametrize("routing, held_share", [
-    ("even", None), ("all_held", 1.0), ("none_held", 0.0)])
-def test_the_held_part_matches_the_reference(routing, held_share, family):
+    ("even", None), ("all_held", 1.0), ("none_held", 0.0)]
+    + [(case, None) for case in _COUNTS])
+def test_the_held_part_matches_the_reference(routing, held_share, family,
+                                             monkeypatch):
     held, on_held, off_held = _STEERED[family]
-    towards = {"even": None, "all_held": on_held,
-               "none_held": off_held}[routing]
-    p, x = _layer(held, family=family, towards=towards)
     cfg = _cfg(held, family)
+    assignments = 2 * 64 * cfg.top_k
+    if routing in _COUNTS:
+        monkeypatch.setattr(moe, "GMM_TILING", (16,) + moe.GMM_TILING[1:])
+        rungs = moe.held_rungs(assignments)
+        assert len(rungs) == 17 and rungs[-1] == assignments
+        count = _COUNTS[routing](rungs, assignments)
+        held_share = count / assignments
+        p, x = _layer(held, family=family, held_count=count)
+    else:
+        rungs = moe.held_rungs(assignments)
+        towards = {"even": None, "all_held": on_held,
+                   "none_held": off_held}[routing]
+        p, x = _layer(held, family=family, towards=towards)
     w = jax.random.normal(jax.random.PRNGKey(5), x.shape)
     with jax.default_matmul_precision("highest"):
         got, stats = moe.dropless_apply(p, cfg, x)
@@ -100,6 +143,9 @@ def test_the_held_part_matches_the_reference(routing, held_share, family):
     assert float(stats["dropped"]) == 0.0
     assert float(stats["held_assignments"]) == float(
         counts[held[0]:held[0] + held[1]].sum())
+    # The smallest rung that holds the step's held rows.
+    assert float(stats["held_buffer_rows"]) == min(
+        r for r in rungs if r >= float(stats["held_assignments"]))
     np.testing.assert_allclose(stats["held_output_rms"], routed_rms,
                                rtol=1e-4, atol=1e-7)
     assert (float(routed_rms) == 0.0) == (held_share == 0.0)
@@ -216,9 +262,29 @@ def test_the_event_names_the_share():
     moe.dropless_apply(p, _cfg((8, 4)), x)
     gauges = observability.registry().snapshot()["gauges"]
     assert gauges["moe.experts_held"] == 4 and gauges["moe.experts"] == E
+    assert gauges["moe.held_buffer_rungs"] == len(moe.held_rungs(512)) == 2
     assert any("experts 8-11 held" in str(e)
                for e in observability.tracing.events()
                if e.get("name") == "moe")
+    # The flight recorder keeps the whole line: the ladder is in it.
+    assert any("held (4 of 16: held rows in chunks of 512 rows, as many as "
+               "the step's count takes, 1 at most), 1 shared, selection "
+               "bias" in e["detail"]
+               for e in observability.recorder.events() if e["kind"] == "moe")
+
+
+@pytest.mark.parametrize("assignments, chunk, rungs", [
+    (40960, 2560, 17), (32768, 2048, 17), (1280, 512, 4), (512, 512, 2),
+    (10, 10, 2)])
+def test_the_ladder_is_whole_chunks_up_to_every_assignment(assignments, chunk,
+                                                          rungs):
+    """Laguna's and JoyAI's layers, and sizes under a tile: a chunk is a
+    sixteenth of the assignments in whole tiles of ``GMM_TILING[0]`` rows,
+    the rungs its multiples from none to the first that holds them all."""
+    assert moe.held_chunk_rows(assignments) == chunk
+    ladder = moe.held_rungs(assignments)
+    assert ladder == tuple(chunk * i for i in range(rungs))
+    assert ladder[-2] < assignments <= ladder[-1]
 
 
 @pytest.mark.parametrize("kwargs, message", [

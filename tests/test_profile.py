@@ -57,6 +57,29 @@ def test_scope_path_unwraps_transform_frames():
     assert scope_path("") == ""
 
 
+@pytest.mark.parametrize("op_name, path, scope", [
+    ("jit(step)/jvp(layer1)/moe/while/body/dispatch/gather",
+     "layer1/moe/dispatch/gather", ("moe/dispatch", "forward")),
+    ("jit(step)/transpose(jvp(layer3))/moe/while/body/experts/"
+     "transpose(experts)/jvp(jit(gmm))/pallas_call",
+     "layer3/moe/experts/experts/pallas_call", ("moe/experts", "backward")),
+    ("jit(step)/jvp(layer1)/moe/while/cond/lt", "layer1/moe/lt",
+     ("moe", "forward")),
+    # The loop itself keeps its name: the layer's, in no sub-scope.
+    ("jit(step)/jvp(layer1)/moe/while", "layer1/moe/while",
+     ("moe", "forward")),
+    ("jit(f)/outer/cond/branch_1_fun/while/body/inner/mul",
+     "outer/inner/mul", ("outer/inner", profile.UNATTRIBUTED))])
+def test_a_loops_and_a_conditionals_frames_are_not_scopes(op_name, path,
+                                                         scope):
+    """``lax.fori_loop`` / ``lax.cond`` lower a body's instructions under
+    ``while/body`` / ``cond/branch_<i>_fun``: what is traced inside folds
+    into the user's scopes as if there were no loop (the held expert
+    layers' ``dispatch`` and ``experts`` live in one)."""
+    assert scope_path(op_name) == path
+    assert profile._scope_and_phase(op_name) == scope
+
+
 def test_op_provenance_scopes_and_flops_sum_to_estimate():
     params, loss_fn, batch = mlp.tiny_fixture()
     item = GraphItem.capture(loss_fn, params, optax.sgd(0.1),
