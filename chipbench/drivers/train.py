@@ -242,9 +242,12 @@ def run(cell, *, seed, seconds, trace, catalog, clock0):
 
     feed = DevicePrefetcher(itertools.cycle(pool), runner.remapper, depth=2)
 
+    held = []   # a step's rows that met a held expert, where it says
+
     def step(batch):
         nonlocal state
         state, metrics = runner.step(state, batch, shard_inputs=False)
+        held.append((metrics.get("aux") or {}).get("moe.held_assignments"))
         return metrics["loss"]
 
     loop = StepLoop(step, feed, mix["lag_steps"], spans.annotate)
@@ -273,6 +276,10 @@ def run(cell, *, seed, seconds, trace, catalog, clock0):
           "compiler); memory_stats " + json.dumps(memory), flush=True)
     window_losses = [float(x) for x in losses[first:]]
     n_steps = len(step_ms)
+    if held[first] is not None:
+        print("chipbench: moe.held_assignments of the window's first and "
+              f"last step {float(held[first]):.0f} {float(held[-1]):.0f}",
+              flush=True)
     print(f"chipbench: {n_steps} steps in a window of {window_s:.3f} s; "
           f"step ms median {np.median(step_ms):.3f}, p90 from {n_steps} "
           f"samples" + ("" if n_steps >= MIN_STEPS_FOR_P90 else

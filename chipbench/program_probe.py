@@ -190,19 +190,26 @@ def join(trace, table, by_scope, mixed=(), skip_programs=2):
     the slice's idle gaps by the ``autodist.*`` annotation that covers most
     of each; means over the chips.  ``by_scope`` is the program's
     ``device_time_by_scope``; the slice is the one ``trace_reduce.reduce``
-    takes."""
+    takes, and like it this adds up ``trace_reduce.leaves`` of a chip's
+    line: a ``while`` event spans its body's events and is none of them
+    (``inside_containers_s``: what of the busy time only such an event
+    covers, the loops' cost between their bodies' operations)."""
     chips = {n: c for n, c in trace["chips"].items() if c["ops"]}
     own = [e for e in trace["host"] if e[0].startswith("autodist.")]
     out = {"scope": collections.Counter(), "phase": collections.Counter(),
            "gaps": collections.Counter(), "unplaced": collections.Counter(),
            "mixed": collections.Counter(), "busy_s": 0.0,
-           "chips": len(chips)}
+           "inside_containers_s": 0.0, "chips": len(chips)}
     for chip in chips.values():
         lo, hi, _ = trace_reduce._slice_of(chip, skip_programs)
         ops = [(name, max(a, lo), min(b, hi)) for name, a, b in chip["ops"]
                if min(b, hi) > max(a, lo)]
         busy = trace_reduce.union((a, b) for _, a, b in ops)
         out["busy_s"] += trace_reduce.total(busy) / len(chips)
+        ops = trace_reduce.leaves(ops)
+        out["inside_containers_s"] += (
+            trace_reduce.total(busy) - trace_reduce.total(trace_reduce.union(
+                (a, b) for _, a, b in ops))) / len(chips)
         seconds = by_scope(ops, table)
         for kind in ("scope", "phase"):
             for key, value in seconds[kind].items():
@@ -272,6 +279,9 @@ def _by_scope(path, _mtime):
     _say("busy time no scope claims, by kind of instruction, %: "
          + json.dumps({k: round(100.0 * v / busy, 3) for k, v
                        in joined["unplaced"].most_common(6)}))
+    _say("busy time that only a while or conditional event covers (left "
+         "out of every share), %: "
+         f"{100.0 * joined['inside_containers_s'] / busy:.3f}")
     _say("idle gaps of the slice by the program's annotation, us: "
          + json.dumps({k: round(v * 1e6, 1) for k, v
                        in joined["gaps"].most_common(5)}))

@@ -19,7 +19,9 @@ from chipbench.catalog import ROOT, Catalog
 
 def result_line(catalog, cell, run, device, trace):
     """The contract's last line from a driver's record: the cell's
-    end-to-end metrics untraced, its per-layer metrics traced."""
+    end-to-end metrics untraced, its per-layer metrics traced, and last
+    what decided ``correct``: each check by name, with the numbers it
+    compared and their limits."""
     if trace:
         wanted = {m["name"] for m in
                   catalog.metric_specs("per_layer", cell["name"])}
@@ -32,9 +34,10 @@ def result_line(catalog, cell, run, device, trace):
         metrics = {m["name"]: {"value": run["end_to_end"][m["name"]],
                                "unit": m["unit"]}
                    for m in catalog.metric_specs("end_to_end", cell["name"])}
-    for name, (ok, detail) in run["checks"].items():
-        print(f"chipbench: check {name}: {'ok' if ok else 'FAILED'}: "
-              f"{detail}", flush=True)
+    checks = {name: f"{'ok' if ok else 'FAILED'}: {detail}"
+              for name, (ok, detail) in run["checks"].items()}
+    for name, said in checks.items():
+        print(f"chipbench: check {name}: {said}", flush=True)
     device = dict(device, memory_peak_bytes=run["memory_peak_bytes"])
     line = {"correct": all(ok for ok, _ in run["checks"].values()),
             "attempted": run["attempted"], "failed": run["failed"],
@@ -45,6 +48,7 @@ def result_line(catalog, cell, run, device, trace):
         line["breakdown"] = {
             "device_ops": [list(x) for x in reduced["op_seconds"]],
             "idle_gaps": [list(x) for x in reduced["longest_gaps"]]}
+    line["checks"] = checks
     return line
 
 
@@ -93,6 +97,9 @@ def main(argv=None):
     line = run_cell(catalog, cell, seed=args.seed, seconds=args.seconds,
                     trace=bool(args.trace), clock0=clock0)
     print(json.dumps(line), flush=True)
+    # The same again where a record of a run that is not correct keeps it.
+    for name, said in line["checks"].items():
+        print(f"chipbench: check {name}: {said}", file=sys.stderr, flush=True)
 
 
 if __name__ == "__main__":

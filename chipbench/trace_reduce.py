@@ -13,8 +13,12 @@ Pallas kernel keeps its name (``%flash_fwd.7 = ...``).  ``XLA Modules`` holds
 one event per executed program.  ``Async XLA Ops`` holds one event per
 asynchronous instruction (``copy-start``, ``all-gather-start``, ...) for as
 long as it is in flight, while its ``-start`` and ``-done`` halves are short
-events on ``XLA Ops``.  The host's ``TraceAnnotation`` spans are on the plane
-``/host:CPU``, on the line of the thread that made them, on the same clock.
+events on ``XLA Ops``.  A ``while`` or ``conditional`` instruction has an
+event on ``XLA Ops`` too, which lasts from its first iteration's or its
+branch's first event to the last one's end, beside those events: ``leaves``
+takes such a container out before lengths are added up.  The host's
+``TraceAnnotation`` spans are on the plane ``/host:CPU``, on the line of the
+thread that made them, on the same clock.
 """
 import collections
 import functools
@@ -26,6 +30,7 @@ OPS_LINE = "XLA Ops"
 ASYNC_LINE = "Async XLA Ops"
 MODULES_LINE = "XLA Modules"
 HOST_PLANE = "/host:CPU"
+CONTAINER = re.compile(r"^(while|conditional)$")
 COLLECTIVE = re.compile(
     r"^(all-gather|all-reduce|reduce-scatter|collective-permute|all-to-all)")
 
@@ -78,6 +83,27 @@ def gaps(busy, lo, hi):
     return out
 
 
+def leaves(events):
+    """The ``(name, start, end)`` events of one line without its ``while``
+    and ``conditional`` events that contain another event of it, in their
+    order.  Such an event lasts as long as the events of its body together,
+    so lengths added up with it count that time twice; the union of the
+    line is the same with and without it but for what the loop costs
+    between its body's events.  An event of any other kind stays, whatever
+    lies inside its span (a fusion with a ``-start`` event in it is work),
+    and an event of no length (the trace has some) is nobody's body."""
+    order = sorted((i for i, e in enumerate(events) if e[2] > e[1]),
+                   key=lambda i: (events[i][1], -events[i][2]))
+    containers, open_ = set(), []
+    for i in order:
+        _, lo, hi = events[i]
+        open_ = [j for j in open_ if events[j][2] > lo]
+        containers.update(j for j in open_ if hi <= events[j][2])
+        if CONTAINER.match(op_kind(events[i][0])):
+            open_.append(i)
+    return [e for i, e in enumerate(events) if i not in containers]
+
+
 # -- reading -----------------------------------------------------------------
 
 @functools.lru_cache(maxsize=1 << 17)
@@ -85,6 +111,16 @@ def op_name(event_name):
     """The HLO instruction's name: ``%fusion.12 = bf16[..] fusion(..)`` and
     ``fusion.12`` both give ``fusion.12``."""
     return event_name.split(" = ", 1)[0].strip().lstrip("%")
+
+
+@functools.lru_cache(maxsize=1 << 17)
+def op_kind(event_name):
+    """The instruction's opcode where the event gives its text
+    (``%x.5 = (s32[], f32[8]{0}) while(%t), body=...`` gives ``while``), and
+    the group of its name where it gives the name alone."""
+    name, _, rest = event_name.partition(" = ")
+    called = re.search(r"\s([\w-]+)\(", re.sub(r"\{[^{}]*\}", "", rest))
+    return called.group(1) if called else op_group(op_name(name))
 
 
 def op_group(name):
@@ -165,7 +201,9 @@ def _slice_of(chip, skip_programs):
 
 
 def reduce(trace, kernels=(), skip_programs=2, top=10, longest=5):
-    """The numbers the per-layer metrics read, means over the chips."""
+    """The numbers the per-layer metrics read, means over the chips.  Busy
+    time is the union of every event; an operation's or a kernel's seconds
+    are summed over ``leaves`` of the line."""
     chips = {n: c for n, c in trace["chips"].items() if c["ops"]}
     if not chips:
         raise ValueError("the trace holds no device plane with operations "
@@ -194,7 +232,7 @@ def reduce(trace, kernels=(), skip_programs=2, top=10, longest=5):
         in_flight[number] = total(coll_union)
         out["collective_exposed_s"] += (
             total(coll_union) - total(intersect(coll_union, compute))) / n
-        for name, a, b in inside:
+        for name, a, b in leaves(inside):
             op_seconds[op_label(name)] += (b - a) / n
             for kernel in kernels:
                 if kernel in op_name(name):

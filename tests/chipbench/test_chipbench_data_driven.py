@@ -124,8 +124,8 @@ def test_names_units_and_lines_use_the_allowed_characters(bench):
         assert len(c["reduced"]) <= 16
         # No width is ever cut.
         for key in c["reduced"]:
-            assert not re.search(r"(_dim|_rank|hidden|intermediate|n_embd|"
-                                 r"n_head|head|n_inner)", key), key
+            assert not re.search(r"(_dim|_rank|hidden_size|intermediate|"
+                                 r"n_embd|n_head|head|n_inner)", key), key
 
 
 def test_every_cell_names_files_that_exist(bench):

@@ -40,8 +40,10 @@ def recorded_trace(monkeypatch):
                                        "tiny-mlm.mlm-s32"])
 def test_untraced_run_prints_the_end_to_end_metrics(toy_root, cell_name):
     line = _run(toy_root, cell_name, trace=False)
-    assert set(line) == {"correct", "attempted", "failed", "metrics",
-                         "device"}
+    assert list(line) == ["correct", "attempted", "failed", "metrics",
+                          "device", "checks"]
+    assert set(line["checks"]) == {"reference", "losses", "clock"}
+    assert all(said.startswith("ok: ") for said in line["checks"].values())
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] >= 1
     assert {k: v["unit"] for k, v in line["metrics"].items()} == END_TO_END
@@ -56,8 +58,8 @@ def test_untraced_run_prints_the_end_to_end_metrics(toy_root, cell_name):
 
 def test_traced_run_prints_the_per_layer_metrics(toy_root, recorded_trace):
     line = _run(toy_root, "tiny-lm.train-s32", trace=True)
-    assert set(line) == {"correct", "attempted", "failed", "metrics",
-                         "device", "breakdown"}
+    assert list(line) == ["correct", "attempted", "failed", "metrics",
+                          "device", "breakdown", "checks"]
     assert not set(line["metrics"]) & set(END_TO_END)
     # The toy cell is not among the cells the collective metrics list, and
     # the CPU reports no memory: a reader with nothing to read is left out.

@@ -130,7 +130,10 @@ def test_the_cell_and_its_configuration_are_declared():
     for line in (cells[CELL]["why"], configs[CONFIG]["why"],
                  configs[CONFIG]["source"]):
         assert 1 <= len(line) <= 200 and "\n" not in line and "\t" not in line
-    assert len(cells) == 6
+    # A quarter of the cells, rounded down, and always one, may take four.
+    assert len(cells) >= 6
+    assert sum(w["chips"] == 4 for w in cells.values()) <= max(
+        1, len(cells) // 4)
     assert [w["name"] for w in cells.values() if w["chips"] == 4] == [
         "gpt2-xl.train-s1024-x4"]
     assert len((ROOT / "BENCHMARK.json").read_bytes()) <= 64 * 1024

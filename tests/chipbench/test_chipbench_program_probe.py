@@ -116,6 +116,34 @@ def test_join_places_the_busy_time_of_the_slice(recorded):
         joined["busy_s"])}
 
 
+def test_join_places_a_loops_body_and_not_the_loop_around_it():
+    """A ``while`` event spans its body's events: the scopes and phases add
+    up to the body's seconds, busy time stays the union of every event,
+    and what only the loop's own event covers is kept apart."""
+    ops = [("fusion.1", 0.0, 2.0), ("while.5", 2.0, 9.0),
+           ("gmm.3", 2.0, 4.0), ("fusion.7", 4.0, 5.0),
+           ("gmm.3", 5.5, 7.5), ("fusion.7", 7.5, 8.5), ("fusion.9", 9.0, 10.0)]
+    trace = {"chips": {0: {"ops": ops, "modules": [("jit_step", 0.0, 10.0)]}},
+             "host": []}
+    table = {"fusion.1": ("attn", "forward"), "while.5": ("moe", "backward"),
+             "gmm.3": ("moe/experts", "backward"),
+             "fusion.7": ("moe/dispatch", "backward"),
+             "fusion.9": ("optimizer", "update")}
+    joined = probe.join(trace, table, profile.device_time_by_scope,
+                        skip_programs=0)
+    assert joined["busy_s"] == pytest.approx(10.0)
+    assert dict(joined["scope"]) == {
+        "attn": pytest.approx(2.0), "moe/experts": pytest.approx(4.0),
+        "moe/dispatch": pytest.approx(2.0), "optimizer": pytest.approx(1.0)}
+    assert dict(joined["phase"]) == {
+        "forward": pytest.approx(2.0), "backward": pytest.approx(6.0),
+        "update": pytest.approx(1.0)}
+    assert joined["inside_containers_s"] == pytest.approx(1.0)
+    assert sum(joined["scope"].values()) + joined["inside_containers_s"] \
+        == pytest.approx(joined["busy_s"])
+    assert not joined["gaps"] and not joined["unplaced"]
+
+
 @pytest.mark.parametrize("name", sorted(SHARES))
 def test_share_readers_on_the_recorded_pair(name, recorded, monkeypatch,
                                             tmp_path):
@@ -253,7 +281,10 @@ def test_every_new_metric_is_declared_with_its_source():
     source = dict.fromkeys(SETUP, "program_span")
     source["compiles_in_window"] = "program_counter"
     source.update(dict.fromkeys(SHARES, "device_trace"))
-    assert [m["name"] for m in bench["per_layer"]][-len(NEW):] == list(NEW)
+    # Later PRs append: PR 23's stand together, in their order, wherever.
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(NEW[0])
+    assert names[first:first + len(NEW)] == list(NEW)
     for name in NEW:
         assert declared[name]["source"] == source[name]
         assert declared[name]["better"] == "lower"

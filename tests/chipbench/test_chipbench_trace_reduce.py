@@ -35,6 +35,15 @@ def test_names():
     assert tr.op_group("fusion.12") == "fusion"
     assert tr.op_group("all-gather-start.3.1") == "all-gather-start"
     assert tr.op_group("flash_bwd_dkv") == "flash_bwd_dkv"
+    # The opcode where the event has the instruction's text, whatever its
+    # name and whatever it reads; the name's group where it has not.
+    assert tr.op_kind(text) == "fusion"
+    assert tr.op_kind("%loop_body.3 = (s32[], f32[8]{0:T(8)S(1)}) while(%t), "
+                      "condition=%c, body=%b") == "while"
+    assert tr.op_kind("%fusion.5 = f32[8]{0} fusion(%while.3), "
+                      "calls=%conditional_branch") == "fusion"
+    assert tr.op_kind("conditional.4") == "conditional"
+    assert tr.op_kind("%all-gather-start.1 = ...") == "all-gather-start"
 
 
 def _hand_built():
@@ -43,7 +52,8 @@ def _hand_built():
     Chip 0: a matmul 10-13, an asynchronous all-gather in flight 12-16
     (start 12-12.5, done 15-16, the whole on the line of asynchronous
     operations) with a kernel 13-14 under it, idle 14-15 and 16-17, a
-    synchronous all-reduce 17-19, idle 19-20.
+    synchronous all-reduce 17-19, idle 19-20.  The all-gather's start lies
+    inside the matmul's span, and the matmul is no loop: it keeps its 3 s.
     Chip 1: busy 10-20 with one fusion, no collectives.
     Names are whole instructions, as the chip's trace gives them.
     """
@@ -96,6 +106,63 @@ def test_reduce_on_hand_built_intervals():
     assert dict(out["gap_seconds_by_owner"]) == {
         "chipbench.block": pytest.approx(1.0),
         "chipbench.dispatch": pytest.approx(0.5)}
+
+
+def _line_with_a_loop():
+    """One chip's line: a fusion 0-2, a ``while`` 2-9 whose body runs
+    twice (a kernel 2-4 and 5.5-7.5, a fusion 4-5 and 7.5-8.5, so that the
+    loop itself costs 5-5.5 and 8.5-9), inside it a ``conditional`` 4-5
+    around the first of those fusions, a neighbour 9-10, an event of no
+    length inside the neighbour."""
+    loop = ("%while.5 = (s32[], f32[4096,3072]{1,0}) while(%tuple.1), "
+            "condition=%cond, body=%body")
+    kernel = "%jvp_jit_gmm_.3 = bf16[2560,1024]{1,0} custom-call(%a, %b)"
+    return [("fusion.1", 0, 2), (loop, 2, 9), (kernel, 2, 4),
+            ("%conditional.2 = f32[8]{0} conditional(%p)", 4, 5),
+            ("fusion.7", 4, 5), (kernel, 5.5, 7.5), ("fusion.7", 7.5, 8.5),
+            ("fusion.9", 9, 10), ("custom-call.4", 9.5, 9.5)]
+
+
+def test_leaves_drops_a_loop_that_contains_others_of_its_line():
+    line = _line_with_a_loop()
+    kept = tr.leaves(line)
+    assert [tr.op_group(tr.op_name(n)) for n, _, _ in kept] == [
+        "fusion", "jvp_jit_gmm_", "fusion", "jvp_jit_gmm_", "fusion",
+        "fusion", "custom-call"]
+    assert sum(b - a for _, a, b in kept) == 9
+    assert sum(b - a for _, a, b in line) == 17
+    # Events that only touch, or overlap without one holding the other,
+    # are nobody's body.
+    line = [("while.1", 0, 1), ("b", 1, 2), ("while.2", 1.5, 3), ("c", 2, 4)]
+    assert tr.leaves(line) == line
+    assert tr.leaves([]) == []
+    assert tr.leaves([("while.3", 0, 4), ("b", 3, 4), ("c", 5, 5)]) == [
+        ("b", 3, 4), ("c", 5, 5)]
+    # Only a loop or a conditional is a container: a fusion with an
+    # asynchronous start inside its span is work, and keeps its length.
+    line = [("fusion.1", 0, 4), ("all-gather-start.2", 3, 3.5),
+            ("%copy.3 = f32[8]{0} copy(%while.3)", 5, 7), ("d", 6, 7)]
+    assert tr.leaves(line) == line
+
+
+def test_reduce_sums_the_body_and_not_the_loop_around_it():
+    modules = [("jit_step", 0, 10)]
+    trace = {"chips": {0: {"ops": _line_with_a_loop(), "modules": modules,
+                           "async": []}}, "host": []}
+    out = tr.reduce(trace, kernels=("gmm",), skip_programs=0)
+    # Busy time is the union of every event, the loop's own cost between
+    # its body's operations with it: no idle gap opens at 5-5.5 or 8.5-9.
+    assert out["busy_s"] == pytest.approx(10) and out["window_s"] == 10
+    assert out["idle_share"] == pytest.approx(0.0)
+    assert out["longest_gaps"] == []
+    ops = dict(out["op_seconds"])
+    assert not [name for name in ops if name.startswith(("while",
+                                                         "conditional"))]
+    assert ops == {"fusion": pytest.approx(5.0),
+                   "jvp_jit_gmm_ bf16[2560,1024]": pytest.approx(4.0)}
+    assert sum(ops.values()) == pytest.approx(9.0)
+    assert out["kernel_seconds"] == {"gmm": pytest.approx(4.0)}
+    assert out["kernel_calls"] == {"gmm": pytest.approx(2)}
 
 
 def test_collective_intervals_take_both_lines():
