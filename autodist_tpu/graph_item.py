@@ -334,6 +334,15 @@ _SCOPE_WRAP_RE = re.compile(
 # (``.../moe/while``) keeps its name.
 _BODY_FRAME_RE = re.compile(
     r"(?<![^/])(?:while/(?:body|cond)|cond/branch_\d+_fun)/")
+# ``jax.checkpoint``'s frames: what the backward pass computes of its
+# function carries ``checkpoint/``, what it computes again
+# ``checkpoint/rematted_computation/``, both behind a second copy of the
+# scopes the call stood in (``transpose(jvp(layer0))/jvp(layer0)/checkpoint/
+# gdn/...``, the call itself ``.../jvp(layer0)/remat2``); the instruction
+# belongs where the call stood.
+_REMAT_FRAME_RE = re.compile(
+    r"(transpose\(jvp\(([^()]*)\)\)/)jvp\(\2\)/"
+    r"(?:checkpoint/(?:rematted_computation/)?|(?=remat2$))")
 
 
 def scope_path(name_stack_text):
@@ -350,7 +359,7 @@ def scope_path(name_stack_text):
         text = str(name_stack_text)
     except Exception:  # noqa: BLE001 - an unprintable stack is unattributed
         return ""
-    prev = None
+    prev, text = None, _REMAT_FRAME_RE.sub(r"\1", text)
     while prev != text:
         prev = text
         text = _SCOPE_WRAP_RE.sub(r"\1", text)

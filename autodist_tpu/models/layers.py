@@ -145,7 +145,16 @@ def mha_init(key, dim, num_heads, use_bias=True, qk_norm=False, head_dim=None,
     ``key`` and ``value`` (dim -> kv_heads x head_dim), ``out`` (num_heads x
     head_dim -> dim); ``head_dim`` is ``dim / num_heads`` and ``kv_heads``
     is ``num_heads`` where not given, and then all four are dim x dim.
-    ``gate`` adds ``gate`` (dim -> num_heads, no bias): one scalar a head."""
+    ``qk_norm`` adds ``q_norm`` and ``k_norm``: scales over the whole
+    projected vector, or with ``"head"`` one ``head_dim``-wide scale each
+    that the heads share.  ``gate`` adds ``gate`` (no bias): dim ->
+    num_heads, one scalar a head, or with ``"lane"`` dim -> num_heads x
+    head_dim, one a lane."""
+    for name, value, form in (("qk_norm", qk_norm, "head"),
+                              ("gate", gate, "lane")):
+        if value not in (False, True, form):
+            raise ValueError(f"{name} must be False, True or {form!r}, got "
+                             f"{value!r}")
     head_dim = head_dim or dim // num_heads
     wide, kv_wide = num_heads * head_dim, (kv_heads or num_heads) * head_dim
     ks = jax.random.split(key, 4)
@@ -155,10 +164,14 @@ def mha_init(key, dim, num_heads, use_bias=True, qk_norm=False, head_dim=None,
         "value": dense_init(ks[2], dim, kv_wide, use_bias),
         "out": dense_init(ks[3], wide, dim, use_bias),
     }
-    if qk_norm:
+    if qk_norm == "head":
+        p["q_norm"], p["k_norm"] = rmsnorm_init(head_dim), \
+            rmsnorm_init(head_dim)
+    elif qk_norm:
         p["q_norm"], p["k_norm"] = rmsnorm_init(wide), rmsnorm_init(kv_wide)
     if gate:
-        p["gate"] = dense_init(jax.random.fold_in(key, 4), dim, num_heads,
+        p["gate"] = dense_init(jax.random.fold_in(key, 4), dim,
+                               wide if gate == "lane" else num_heads,
                                use_bias=False)
     return p
 
@@ -225,13 +238,18 @@ def apply_rope(x, tables):
 _mha_announced = set()
 
 
-def _announce_mha(heads, kv_heads, head_dim, window, rope, gate):
+def _announce_mha(heads, kv_heads, head_dim, window, rope, gate_lanes,
+                  norm_lanes):
     """Gauges ``attn.*`` and one ``attn`` event a distinct shape, at trace
     time, for a mixer that is more than heads of ``dim / heads`` all the way
     (grouped key-value heads, a window, a gate, a part of the lanes
-    rotated): full layers set ``attn.heads_full`` and
+    rotated, a norm a head): full layers set ``attn.heads_full`` and
     ``attn.rotary_lanes_full``, window layers ``attn.heads_window`` and
-    ``attn.window``."""
+    ``attn.window``.  ``gate_lanes`` are the gate's outputs a head (0: no
+    gate, 1: a scalar a head, ``head_dim``: one a lane), ``norm_lanes`` the
+    lanes one q / k norm's scale spans where it is a head's (0 elsewhere);
+    the gauges ``attn.gate_lanes`` and ``attn.qk_norm_lanes`` are set where
+    they are a head's width."""
     from autodist_tpu import observability
     if not observability.enabled():
         return
@@ -244,13 +262,20 @@ def _announce_mha(heads, kv_heads, head_dim, window, rope, gate):
     else:
         registry.gauge("attn.heads_window").set(heads)
         registry.gauge("attn.window").set(window)
+    if gate_lanes > 1:
+        registry.gauge("attn.gate_lanes").set(gate_lanes)
+    if norm_lanes:
+        registry.gauge("attn.qk_norm_lanes").set(norm_lanes)
     detail = (f"attention: {heads} heads of {head_dim} read {kv_heads} "
               f"key-value heads ({heads // kv_heads} a group), "
               + ("every key behind the diagonal" if window is None
                  else f"a window of {window} keys")
               + f", {lanes} of a head's {head_dim} lanes rotated, "
-              + ("a sigmoid gate a head on the output" if gate
-                 else "no gate"))
+              + {0: "no gate", 1: "a sigmoid gate a head on the output"}.get(
+                  gate_lanes, f"a sigmoid gate a lane ({gate_lanes} a head) "
+                              f"on the output")
+              + (f", q and k RMS-normalised a head over {norm_lanes} lanes"
+                 if norm_lanes else ""))
     if detail not in _mha_announced:
         _mha_announced.add(detail)
         observability.record_event("attn", detail)
@@ -269,7 +294,9 @@ def mha(p, x, num_heads, mask=None, dtype=None, attn_fn=None, rope=None,
     q, k, v nor the result is transposed (the flash kernels read and write
     that layout, ``ops/flash_attention.py``).  Where the parameters hold
     ``q_norm`` / ``k_norm`` (QK-norm), q and k are RMS-normalised over the
-    whole projected vector before the split into heads; ``rope``
+    whole projected vector before the split into heads, or, where the
+    scales are a head wide (``mha_init(qk_norm="head")``), each head over
+    its own lanes after it, one scale shared by the heads; ``rope``
     (:func:`rope_tables`, :func:`yarn_rope_tables`; tables narrower than a
     head rotate its first lanes) rotates q and k after it.
 
@@ -282,7 +309,9 @@ def mha(p, x, num_heads, mask=None, dtype=None, attn_fn=None, rope=None,
     hook serves either only if it says so (``attn_fn.grouped``,
     ``attn_fn.windowed``, ``make_flash_attn_fn``'s).  Where the parameters
     hold ``gate``, head h's output is multiplied by ``sigmoid(W_g x)_h``
-    (float32) before ``out``.  The named scopes ``qkv``, ``rope``, ``core``
+    (float32) before ``out``: one scalar a head, or where ``gate`` is dim ->
+    num_heads x head_dim (``mha_init(gate="lane")``) one a lane.  The named
+    scopes ``qkv``, ``rope``, ``core``
     (``window_core`` under a window), ``gate`` and ``out`` are rows of the
     profiler's table under the block's ``attn``.
     """
@@ -298,19 +327,27 @@ def mha(p, x, num_heads, mask=None, dtype=None, attn_fn=None, rope=None,
                 f"query heads and window {window} needs an attention hook "
                 f"that is .{what} (ops.flash_attention.make_flash_attn_fn), "
                 f"and this one (ring, Ulysses or a caller's own) is not")
-    if not plain or "gate" in p or (
+    gate_lanes = p["gate"]["kernel"].shape[1] // num_heads \
+        if "gate" in p else 0
+    # A scale a head wide is a head's norm (with one head the two forms are
+    # the same numbers).
+    head_norm = "q_norm" in p and num_heads > 1 \
+        and p["q_norm"]["scale"].shape[0] == head_dim
+    if not plain or gate_lanes or head_norm or (
             rope is not None and rope[0].shape[-1] != head_dim):
         _announce_mha(num_heads, kv_heads, head_dim, window, rope,
-                      "gate" in p)
+                      gate_lanes, head_dim if head_norm else 0)
     bshd = getattr(attn_fn, "bshd", None) if plain else None
     if bshd is not None:
         bshd = bshd(num_heads, head_dim)
 
     def project(name, norm=None, heads=num_heads):
         t = dense(p[name], x, dtype)
-        if norm in p:
+        if norm in p and not head_norm:
             t = rmsnorm(p[norm], t, norm_eps)
         t = t.reshape(b, s, heads, -1)
+        if norm in p and head_norm:
+            t = rmsnorm(p[norm], t, norm_eps)
         return t if bshd else t.transpose(0, 2, 1, 3)
 
     with jax.named_scope("qkv"):
@@ -330,15 +367,21 @@ def mha(p, x, num_heads, mask=None, dtype=None, attn_fn=None, rope=None,
             o = attn_fn(q, k, v, mask, **extra)
         else:
             o = dot_product_attention(q, k, v, mask)
+    def merged(o):      # the heads' outputs as ``out`` reads them
+        return (o if bshd else o.transpose(0, 2, 1, 3)).reshape(b, s, -1)
+
     if "gate" in p:
         with jax.named_scope("gate"):
             g = jax.nn.sigmoid(dense(p["gate"], x, dtype).astype(jnp.float32))
-            g = g[..., None] if bshd else g.transpose(0, 2, 1)[..., None]
+            if gate_lanes > 1:
+                # A gate a lane meets the outputs merged, (batch, s, heads x
+                # head_dim): the gate itself is never transposed.
+                o = merged(o)
+            else:
+                g = g[..., None] if bshd else g.transpose(0, 2, 1)[..., None]
             o = (o.astype(jnp.float32) * g).astype(o.dtype)
     with jax.named_scope("out"):
-        if not bshd:
-            o = o.transpose(0, 2, 1, 3)
-        return dense(p["out"], o.reshape(b, s, -1), dtype)
+        return dense(p["out"], o if o.ndim == 3 else merged(o), dtype)
 
 
 def dot_product_attention(q, k, v, mask=None):
@@ -554,18 +597,25 @@ def mla(p, x, heads, nope_dim, rope_dim, value_dim, rope, mask=None,
 
 # -- gated delta rule (linear attention) ---------------------------------------
 
-def gdn_init(key, dim, heads, key_dim, value_dim, conv_width=4):
+def gdn_init(key, dim, heads, key_dim, value_dim, conv_width=4,
+             key_heads=None):
     """Parameters of one gated-delta mixer (:func:`gdn`): six projections
-    in (``q``, ``k`` of heads x key_dim, ``v`` and the output gate ``z`` of
-    heads x value_dim, the decay's ``a`` and the write strength's ``b`` of
-    heads), one depthwise convolution kernel (conv_width, 2 heads key_dim +
-    heads value_dim) over q, k and v together, ``A_log`` and ``dt_bias`` a
+    in (``q``, ``k`` of key_heads x key_dim, ``v`` and the output gate ``z``
+    of heads x value_dim, the decay's ``a`` and the write strength's ``b`` of
+    heads), one depthwise convolution kernel (conv_width, 2 key_heads key_dim
+    + heads value_dim) over q, k and v together, ``A_log`` and ``dt_bias`` a
     head, one norm scale of value_dim shared by the heads, and ``out``.
+    ``key_heads`` (None: ``heads``) divides ``heads``: value head ``h`` reads
+    the queries and keys of key head ``h // (heads / key_heads)``.
     No bias anywhere.  ``A_log`` is the log of uniform(0, 16) and
     ``dt_bias`` the inverse softplus of a step drawn log-uniformly in
     [1e-3, 1e-1], as the Gated DeltaNet paper's code draws them."""
+    key_heads = key_heads or heads
+    if heads % key_heads:
+        raise ValueError(f"{key_heads} key heads do not group the {heads} "
+                         f"heads of a gated-delta mixer")
     ks = jax.random.split(key, 10)
-    qk, vz = heads * key_dim, heads * value_dim
+    qk, vz = key_heads * key_dim, heads * value_dim
     step = jnp.exp(jax.random.uniform(ks[8], (heads,), minval=math.log(1e-3),
                                       maxval=math.log(1e-1)))
     return {
@@ -639,13 +689,16 @@ def gated_rmsnorm(p, x, gate, eps=1e-6):
     return rmsnorm(p, x, eps) * jax.nn.silu(gate)
 
 
-def gdn(p, x, heads, dtype=None, allow_neg_eigval=True, norm_eps=1e-6):
+def gdn(p, x, heads, dtype=None, allow_neg_eigval=True, norm_eps=1e-6,
+        key_heads=None):
     """One gated-delta mixer over ``x`` (batch, s, dim): ``(y, final
     state)``, the state (batch, heads, key_dim, value_dim) in float32.
 
     ``q~, k~, v~, z, a, b`` are projections of ``x``; each channel of q~,
     k~, v~ is convolved causally over time with its own taps, then
-    ``silu``; per head q and k are L2-normalised (eps 1e-6 inside the root;
+    ``silu``; per key head (``key_heads``, None: ``heads``; value head ``h``
+    reads key head ``h // (heads / key_heads)``, and the rule takes q and k
+    that wide) q and k are L2-normalised (eps 1e-6 inside the root;
     q also scaled by key_dim^-1/2); ``beta = sigmoid(b)`` (twice that with
     ``allow_neg_eigval``, so that the transition's eigenvalues reach -1),
     ``g = -exp(A_log) softplus(a + dt_bias)`` in float32; the chunked rule
@@ -664,10 +717,11 @@ def gdn(p, x, heads, dtype=None, allow_neg_eigval=True, norm_eps=1e-6):
                    for t, lo, hi in ((q, 0, qk), (k, qk, 2 * qk),
                                      (v, 2 * qk, kernel.shape[1])))
     with jax.named_scope("gates"):
-        key_dim = qk // heads
-        q = (l2_unit(q.reshape(b, s, heads, key_dim))
+        key_heads = key_heads or heads
+        key_dim = qk // key_heads
+        q = (l2_unit(q.reshape(b, s, key_heads, key_dim))
              * key_dim ** -0.5).astype(q.dtype)
-        k = l2_unit(k.reshape(b, s, heads, key_dim)).astype(k.dtype)
+        k = l2_unit(k.reshape(b, s, key_heads, key_dim)).astype(k.dtype)
         beta = jax.nn.sigmoid(beta.astype(jnp.float32))
         if allow_neg_eigval:
             beta = 2.0 * beta

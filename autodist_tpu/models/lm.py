@@ -122,6 +122,42 @@ def laguna_s_2_1(num_layers=48, vocab=100352, experts_held=None,
             T.SLIDING: {"theta": 10000.0, "lanes": None, "yarn": None}})
 
 
+def qwen3_next_80b_a3b(num_layers=48, vocab=151936, experts_held=None,
+                       experts_held_chunks=None, dtype=jnp.bfloat16):
+    """Qwen3-Next-80B-A3B (Qwen/Qwen3-Next-80B-A3B-Instruct ``config.json``):
+    width 2,048; a period of four layers, three gated-delta-rule layers (32
+    value heads of 128 reading 16 key heads of 128, a 4-tap convolution,
+    ``beta = sigmoid(b)``) then full attention (16 heads of 256 over 2
+    key-value heads, an RMSNorm over each head's lanes for q and for k, 64 of
+    a head's 256 lanes rotated at theta 10,000,000, a sigmoid gate a lane on
+    the output); every layer's feed-forward 512 routed SwiGLU experts of 512,
+    10 a token by softmax scores, weights over their sum, beside one shared
+    expert times the sigmoid of a scalar a token; pre-RMSNorm eps 1e-6, no
+    bias, an untied head.  ``num_layers`` keeps the pattern's first layers;
+    ``experts_held = (first, count)`` is one rank's share of each expert
+    layer (``parallel/moe.py``) and ``experts_held_chunks`` the chunks it
+    takes a step's assignments in (a rank of sixteen names fewer than
+    sixteen, so that its even share sits inside a chunk).  Not in the
+    catalog's ``config``: the
+    load-balancing term at 0.001 (the family's ``router_aux_loss_coef``
+    default) and the multi-token-prediction module, which is not built."""
+    period = (T.LINEAR,) * 3 + (T.FULL,)
+    return T.TransformerConfig(
+        vocab=vocab, dim=2048, num_heads=16, num_layers=num_layers,
+        mlp_dim=5120, max_len=262144, causal=True, dtype=dtype,
+        norm="rmsnorm", norm_eps=1e-6, positions="rope", qk_norm="head",
+        bias=False, tied_head=False, ffn="moe", num_experts=512,
+        experts_per_token=10, expert_dim=512, norm_topk=True,
+        load_balance_coef=0.001,
+        layer_types=[period[i % 4] for i in range(num_layers)],
+        linear_heads=32, linear_key_heads=16, linear_key_dim=128,
+        linear_value_dim=128, conv_width=4, allow_neg_eigval=False,
+        expert_scoring="softmax", shared_experts=1, shared_gate=True,
+        experts_held=experts_held, experts_held_chunks=experts_held_chunks,
+        head_dim=256, kv_heads=2, attn_gate="lane",
+        rope_by_type={T.FULL: {"theta": 1e7, "lanes": 64, "yarn": None}})
+
+
 def init(key, cfg):
     return T.init(key, cfg)
 
@@ -137,7 +173,10 @@ def make_loss_fn(cfg, attn_fn=None):
     statistics under the names of docs/observability.md.  With
     ``"linear_attention"`` layers it returns the pair too, ``aux`` holding
     ``gdn.state_absmax``, the largest magnitude of any such layer's final
-    state.
+    state.  With ``cfg.mixer_stats`` it returns the pair, ``aux`` holding
+    ``attn.output_std`` and ``gdn.output_std``: the mean over the layers of
+    each kind of the root mean square of the mixer's output about its mean
+    over a row's positions.
 
     With ``cfg.mtp_depth`` a row holds one token more, the inputs are
     ``tokens[:-2]``, and the prediction module's cross-entropy to the
@@ -201,6 +240,10 @@ def make_loss_fn(cfg, attn_fn=None):
         if reported("gdn_state_absmax"):
             aux["gdn.state_absmax"] = over_layers("gdn_state_absmax",
                                                   jnp.max)
+        for mixer in ("attn", "gdn"):
+            if reported(f"{mixer}_output_std"):
+                aux[f"{mixer}.output_std"] = over_layers(
+                    f"{mixer}_output_std")
         return loss, aux
     return loss_fn
 

@@ -23,10 +23,21 @@ current decoder block whose feed-forward is a layer of routed experts
 and values through low-rank latents, one rotary key a position shared by the
 heads, parameters under ``layer<i>/attn``) or ``"sliding_attention"`` (full
 attention's mixer behind a ``window``: position t sees the keys ``t -
-window < s <= t``).  ``head_dim`` is a head's width where it is not ``dim /
-num_heads``, ``kv_heads`` the key-value heads that groups of query heads
-share, ``heads_by_layer`` each layer's query heads, ``attn_gate`` a sigmoid
-gate a head on attention's output, ``rope_by_type`` the rotary tables of
+window < s <= t``).  A layer's mixer and its feed-forward are chosen apart:
+a ``"linear_attention"`` layer of an ``ffn="moe"`` model holds ``gdn`` and
+``moe`` (``linear_key_heads``: fewer key heads than ``linear_heads``, each
+read by a group of value heads).  ``head_dim`` is a head's width where it is
+not ``dim / num_heads``, ``kv_heads`` the key-value heads that groups of
+query heads share, ``heads_by_layer`` each layer's query heads, ``attn_gate``
+a sigmoid gate on attention's output (a scalar a head, or ``"lane"``: one a
+lane), ``qk_norm="head"`` a q / k norm over each head's own lanes where True
+normalises the whole projected vector, ``shared_gate`` the sigmoid of a
+scalar a token on the shared expert's output, ``experts_held_chunks`` the
+chunks a layer told its share of the experts splits a step's assignments into
+where that is not sixteen (``MoEConfig.held_chunks``), ``recompute="linear_mixer"``
+has the backward pass compute the linear layers' mixer sublayer again
+(``jax.checkpoint``), ``mixer_stats`` every mixer report its output's
+standard deviation over positions, ``rope_by_type`` the rotary tables of
 each kind of layer (:func:`_rope_tables`); ``first_dense`` gives the first
 layers of an ``ffn="moe"`` model a dense SwiGLU MLP; ``mtp_depth=1`` adds the
 multi-token-prediction module (``mtp/...``, :func:`mtp_hidden`), one more
@@ -37,6 +48,8 @@ normalises its input; ``ffn="swiglu"`` is the dense gated MLP
 (``mlp/{gate,up,down}``); ``positions="none"`` gives attention no positions
 at all (the convolutions and decays of the linear layers carry order).
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -60,10 +73,14 @@ class TransformerConfig:
                  first_dense=0, q_rank=0, kv_rank=0, nope_dim=0, rope_dim=0,
                  value_dim=0, mtp_depth=0, mtp_coef=0.0, head_dim=None,
                  kv_heads=None, heads_by_layer=None, window=None,
-                 attn_gate=False, rope_by_type=None):
+                 attn_gate=False, rope_by_type=None, linear_key_heads=None,
+                 shared_gate=False, recompute=None, mixer_stats=False,
+                 experts_held_chunks=None):
         for name, value, known in (("norm", norm, ("layernorm", "rmsnorm")),
                                    ("positions", positions,
                                     ("learned", "rope", "none")),
+                                   ("recompute", recompute,
+                                    (None, "linear_mixer")),
                                    ("ffn", ffn, ("mlp", "moe", "swiglu")),
                                    ("norm_position", norm_position,
                                     ("pre", "output"))):
@@ -105,7 +122,8 @@ class TransformerConfig:
                 dtype=dtype, expert="swiglu", norm_topk=norm_topk,
                 scoring=expert_scoring, route_scale=route_scale,
                 shared=shared_experts, select_bias=select_bias,
-                bias_update_rate=bias_update_rate, held=experts_held)
+                bias_update_rate=bias_update_rate, held=experts_held,
+                shared_gate=shared_gate, held_chunks=experts_held_chunks)
         # The first ``first_dense`` layers of an ffn="moe" model keep a dense
         # SwiGLU MLP of ``mlp_dim``.
         self.first_dense = first_dense
@@ -125,18 +143,29 @@ class TransformerConfig:
         # The token mixer of each layer; None is full attention throughout.
         # A linear layer holds ``linear_heads`` states of ``linear_key_dim``
         # x ``linear_value_dim`` and convolves q, k and v over
-        # ``conv_width`` positions first.
+        # ``conv_width`` positions first; ``linear_key_heads`` (None: as many)
+        # are the heads of its queries and keys, a group of states each.
         self.norm_position = norm_position
         self.layer_types = None if layer_types is None else tuple(layer_types)
         self.linear_heads = linear_heads
+        self.linear_key_heads = linear_key_heads
         self.linear_key_dim, self.linear_value_dim = (linear_key_dim,
                                                       linear_value_dim)
         self.conv_width, self.allow_neg_eigval = conv_width, allow_neg_eigval
+        # "linear_mixer": the backward pass computes a linear layer's mixer
+        # sublayer again from its input (``jax.checkpoint``) and keeps none
+        # of what lies inside; None keeps what autodiff keeps.
+        self.recompute = recompute
+        # Every mixer reports how far what it adds to the residual stream
+        # stands from its mean over a row's positions, as a root mean square
+        # (``aux["attn.output_std"]``, ``aux["gdn.output_std"]``).
+        self.mixer_stats = mixer_stats
         # Full and sliding attention (``layers.mha``): a head's width, the
         # key-value heads (None: as many as query heads), each layer's query
         # heads (None: ``num_heads`` throughout), the sliding layers' window,
-        # the gate, and ``{layer type: {"theta", "lanes" (None: a head's
-        # width), "yarn" (None, or ``layers.yarn_rope_tables``' factor,
+        # the gate (True: a scalar a head; "lane": one a lane), and ``{layer
+        # type: {"theta", "lanes" (None: a head's width), "yarn" (None, or
+        # ``layers.yarn_rope_tables``' factor,
         # original_len, beta_fast, beta_slow, attention_factor)}}`` under
         # ``positions="rope"`` (None: ``rope_theta`` over the whole head for
         # every kind).
@@ -249,7 +278,7 @@ def block_init(key, cfg, layer_type=FULL, ffn=None, heads=None):
     if layer_type == LINEAR:
         p["gdn"] = L.gdn_init(k1, cfg.dim, cfg.linear_heads,
                               cfg.linear_key_dim, cfg.linear_value_dim,
-                              cfg.conv_width)
+                              cfg.conv_width, cfg.linear_key_heads)
     elif layer_type == LATENT:
         p["attn"] = L.mla_init(k1, cfg.dim, cfg.num_heads, cfg.q_rank,
                                cfg.kv_rank, cfg.nope_dim, cfg.rope_dim,
@@ -320,7 +349,8 @@ def mixer_sublayer(p, x, cfg, mask=None, attn_fn=None, rope=None,
         def mixer(h):
             y, state = L.gdn(p["gdn"], h, cfg.linear_heads, dtype=cfg.dtype,
                              allow_neg_eigval=cfg.allow_neg_eigval,
-                             norm_eps=cfg.norm_eps)
+                             norm_eps=cfg.norm_eps,
+                             key_heads=cfg.linear_key_heads)
             return y, {"gdn_state_absmax": jnp.max(jnp.abs(
                 jax.lax.stop_gradient(state)))}
     elif "q_down" in p["attn"]:
@@ -339,7 +369,17 @@ def mixer_sublayer(p, x, cfg, mask=None, attn_fn=None, rope=None,
                          dtype=cfg.dtype, attn_fn=attn_fn, rope=rope,
                          norm_eps=cfg.norm_eps, kv_heads=cfg.kv_heads,
                          window=window), None
-    with jax.named_scope("gdn" if "gdn" in p else "attn"):
+    scope = "gdn" if "gdn" in p else "attn"
+    if cfg.mixer_stats:
+        plain = mixer
+
+        def mixer(h):
+            y, stats = plain(h)
+            out = jax.lax.stop_gradient(y).astype(jnp.float32)
+            std = jnp.sqrt(jnp.mean(jnp.square(
+                out - out.mean(axis=1, keepdims=True))))
+            return y, {**(stats or {}), f"{scope}_output_std": std}
+    with jax.named_scope(scope):
         return _residual(cfg, p["ln1"], x, mixer)
 
 
@@ -495,10 +535,13 @@ def encode_with_stats(params, cfg, ids, segment_ids=None, attn_fn=None):
                 mixers[i + 1], x = layer_boundary(mixers[i + 1], x)
             with jax.named_scope(f"layer{i}"):
                 kind = cfg.layer_type(i)
-                x, mixed = mixer_sublayer(
-                    mixers[i], x, cfg, mask=mask, attn_fn=attn_fn,
+                sublayer = functools.partial(
+                    mixer_sublayer, cfg=cfg, mask=mask, attn_fn=attn_fn,
                     rope=rope.get(kind),
                     window=cfg.window if kind == SLIDING else None)
+                if kind == LINEAR and cfg.recompute == "linear_mixer":
+                    sublayer = jax.checkpoint(sublayer)
+                x, mixed = sublayer(mixers[i], x)
             if i < last:
                 ffns[i + 1], x = layer_boundary(ffns[i + 1], x)
             with jax.named_scope(f"layer{i}"):

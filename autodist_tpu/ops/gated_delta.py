@@ -32,6 +32,18 @@ takes operands in the inputs' dtype (bfloat16 on the train path) and
 accumulates in float32, but the two that ``T`` multiplies, which are float32
 throughout; the decays, the inverse and the carried state are float32.
 
+**Fewer key heads than value heads** (``q``, ``k`` of ``H_k`` heads, ``v``,
+``g``, ``beta`` of ``H_v = r H_k``): value head ``h`` reads the queries and
+keys of key head ``h // r``.  A chunk's ``K K^T`` and ``Q K^T`` belong to a
+key head and are computed once for it; the decays, ``beta``, ``A``, the
+inverse, ``W``, ``U_0`` and the state belong to a value head, so the two
+products meet their value heads' decays as a broadcast over the ``r`` heads of
+a group (``of_value_heads`` in ``_chunk_terms``), and so do ``q`` and ``k``
+where a value head's decay scales them (``diag(exp gamma) Q``,
+``diag(exp(gamma_C - gamma)) K``, ``diag(beta exp gamma) K``): elementwise,
+fused with the multiply.  With ``r`` = 1 nothing is broadcast and the
+equations are those of equal heads.
+
 The backward pass is autodiff through this chunked form (the inverse
 brings its own cotangent, ``dA = -tril(T^T dT T^T, -1)``: two products a
 system) with two ``jax.checkpoint``s: the scan saves each chunk's incoming
@@ -61,13 +73,16 @@ BACKWARD = ("autodiff through the chunked form around the inverse's own "
 _announced = set()
 
 
-def _announce(rows, s, heads, d_k, d_v, chunk):
+def _announce(rows, s, heads, key_heads, d_k, d_v, chunk):
     """Gauges and a ``gdn`` event for the rule being traced; the event and
     the log line are written once a process for each shape traced."""
     from autodist_tpu import observability
     chunks = -(-s // chunk)
+    grouped = "" if key_heads == heads else (
+        f", {heads // key_heads} value heads a key head ({key_heads} key "
+        f"heads: K K^T and Q K^T once a key head)")
     detail = (f"gated delta rule, chunked: ({rows}, {s}, {heads}, {d_k} / "
-              f"{d_v}), {chunks} chunks of {chunk} a row, state "
+              f"{d_v}){grouped}, {chunks} chunks of {chunk} a row, state "
               f"{heads} x {d_k} x {d_v} float32; inverse: "
               f"{inverse_form(chunk)}; backward: {BACKWARD}")
     new = detail not in _announced
@@ -77,6 +92,7 @@ def _announce(rows, s, heads, d_k, d_v, chunk):
     if observability.enabled():
         registry = observability.registry()
         registry.gauge("gdn.heads").set(heads)
+        registry.gauge("gdn.key_heads").set(key_heads)
         registry.gauge("gdn.chunk").set(chunk)
         registry.gauge("gdn.chunks_per_row").set(chunks)
         registry.gauge("gdn.state_bytes_per_row").set(heads * d_k * d_v * 4)
@@ -94,14 +110,23 @@ def _mm(spec, a, b, dtype):
 def gated_delta_rule(q, k, v, g, beta, chunk=CHUNK):
     """``(o, state)`` of the gated delta rule: ``o`` (batch, s, heads, d_v)
     and each row's final state (batch, heads, d_k, d_v) in float32, over
-    ``q``, ``k`` (batch, s, heads, d_k), ``v`` (batch, s, heads, d_v), the
-    log decays ``g`` <= 0 and the write strengths ``beta`` (batch, s,
-    heads); one document a row, the state zero at its start.  ``q`` and
-    ``k`` come as the rule takes them (normalised and scaled by the
-    caller).  A length ``chunk`` does not divide is padded inside with
-    positions that decay nothing and write nothing."""
-    b, s, h, d_k = q.shape
-    _announce(b, s, h, d_k, v.shape[-1], chunk)
+    ``q``, ``k`` (batch, s, key heads, d_k), ``v`` (batch, s, heads, d_v),
+    the log decays ``g`` <= 0 and the write strengths ``beta`` (batch, s,
+    heads); one document a row, the state zero at its start.  The key heads
+    are ``heads`` or a divisor of them: value head ``h`` reads key head ``h
+    // (heads / key heads)`` (the module docstring).  ``q`` and ``k`` come
+    as the rule takes them (normalised and scaled by the caller).  A length
+    ``chunk`` does not divide is padded inside with positions that decay
+    nothing and write nothing."""
+    b, s, key_heads, d_k = q.shape
+    heads = v.shape[2]
+    if heads % key_heads or k.shape != q.shape \
+            or g.shape != v.shape[:3] or beta.shape != g.shape:
+        raise ValueError(
+            f"q and k {q.shape} / {k.shape} must hold key heads that divide "
+            f"the {heads} heads of v {v.shape}, g {g.shape} and beta "
+            f"{beta.shape}")
+    _announce(b, s, heads, key_heads, d_k, v.shape[-1], chunk)
     return _chunked_rule(q, k, v, g, beta, chunk=chunk)
 
 
@@ -112,8 +137,8 @@ def _chunked_rule(q, k, v, g, beta, chunk):
     (the benchmark's process spends 5-10 times a clean process's time on
     tracing, PERF.md section 7, and the inverse's levels are operations more
     to trace than the solve they replace)."""
-    b, s, h, d_k = q.shape
-    d_v = v.shape[-1]
+    b, s, _, d_k = q.shape
+    h, d_v = v.shape[2:]
     dtype = q.dtype
     pad = -s % chunk
     if pad:
@@ -150,16 +175,23 @@ def _chunked_rule(q, k, v, g, beta, chunk):
 def _chunk_terms(q, k, v, g, beta, dtype):
     """What the scan over the chunks takes, for every chunk at once: ``W``,
     ``U_0``, ``M * Q K^T``, ``diag(exp gamma) Q`` and ``diag(exp(gamma_C -
-    gamma)) K`` in ``dtype``, and ``exp(gamma_C)`` in float32.  ``q``, ``k``,
-    ``v`` are (n, b, h, chunk, d), ``g`` and ``beta`` (n, b, h, chunk) in
-    float32."""
+    gamma)) K`` in ``dtype``, and ``exp(gamma_C)`` in float32.  ``v`` is (n,
+    b, h, chunk, d_v), ``g`` and ``beta`` (n, b, h, chunk) in float32, ``q``
+    and ``k`` (n, b, key heads, chunk, d_k): what is computed from them alone
+    is computed a key head and met by its value heads as a broadcast."""
     chunk = g.shape[-1]
+    group = v.shape[2] // k.shape[2]
+
+    def of_value_heads(x):      # (n, b, key heads, ...) -> (n, b, h, ...)
+        return x if group == 1 else jnp.repeat(x, group, axis=2)
+
     gamma = jnp.cumsum(g, axis=-1)
     lower = jnp.tril(jnp.ones((chunk, chunk), bool))
     decay = jnp.exp(jnp.where(lower, gamma[..., :, None] - gamma[..., None, :],
                               -jnp.inf))                  # M, zero above
     a = jnp.where(jnp.tril(lower, -1), beta[..., None] * decay
-                  * _mm("nbhid,nbhjd->nbhij", k, k, dtype), 0.0)
+                  * of_value_heads(_mm("nbhid,nbhjd->nbhij", k, k, dtype)),
+                  0.0)
     with jax.named_scope("inverse"):
         t = unit_lower_inverse(a)
     into = jnp.exp(gamma)[..., None]                      # decay since S_0
@@ -168,12 +200,14 @@ def _chunk_terms(q, k, v, g, beta, dtype):
     # 4.4e-5 without), and at chunk x chunk a chunk and head they are cheap.
     w, u0 = (jnp.einsum("nbhij,nbhjd->nbhid", t, rhs,
                         precision=lax.Precision.HIGHEST)
-             for rhs in ((beta[..., None] * into) * k, beta[..., None] * v))
-    qk = decay * _mm("nbhid,nbhjd->nbhij", q, k, dtype)
+             for rhs in ((beta[..., None] * into) * of_value_heads(k),
+                         beta[..., None] * v))
+    qk = decay * of_value_heads(_mm("nbhid,nbhjd->nbhij", q, k, dtype))
     to_end = jnp.exp(gamma[..., -1:] - gamma)[..., None]  # decay to S_C
     carry = jnp.exp(gamma[..., -1])[..., None, None]      # (n, b, h, 1, 1)
-    return tuple(x.astype(dtype) for x in (w, u0, qk, into * q,
-                                           to_end * k)) + (carry,)
+    return tuple(x.astype(dtype) for x in (
+        w, u0, qk, into * of_value_heads(q),
+        to_end * of_value_heads(k))) + (carry,)
 
 
 def inverse_form(chunk):

@@ -69,13 +69,18 @@ EXPERT_RULES = (
 EXPERT_KINDS = ("gelu", "swiglu")
 SCORINGS = ("softmax", "sigmoid")
 
+#: A held layer works through its held rows in chunks of a sixteenth of the
+#: step's ``T * k`` assignments (:func:`held_chunk_rows`), unless its
+#: configuration names another count (``MoEConfig.held_chunks``).
+_HELD_CHUNKS = 16
+
 
 class MoEConfig:
     def __init__(self, num_experts=8, top_k=2, d_model=64, d_hidden=256,
                  dtype=jnp.float32, capacity_factor=1.25, expert="gelu",
                  norm_topk=True, scoring="softmax", route_scale=1.0,
                  shared=0, select_bias=False, bias_update_rate=0.0,
-                 held=None):
+                 held=None, shared_gate=False, held_chunks=None):
         if expert not in EXPERT_KINDS:
             raise ValueError(f"expert must be one of {EXPERT_KINDS}, got "
                              f"{expert!r}")
@@ -86,6 +91,9 @@ class MoEConfig:
             raise ValueError("shared experts are SwiGLU MLPs: expert must "
                              f"be 'swiglu' with shared={shared!r}, got "
                              f"{expert!r}")
+        if shared_gate and not shared:
+            raise ValueError("shared_gate weighs the shared experts' output: "
+                             "it needs shared >= 1")
         if held is not None:
             first, count = held
             if not (0 <= first and count > 0
@@ -94,6 +102,11 @@ class MoEConfig:
                     f"held = (first, count) must name consecutive experts "
                     f"among the {num_experts}, got {held!r}")
             held = (int(first), int(count))
+        if held_chunks is not None and (held is None or held_chunks < 1):
+            raise ValueError(
+                f"held_chunks splits a held layer's assignments: it needs "
+                f"held and a count >= 1, got {held_chunks!r} with held = "
+                f"{held!r}")
         self.num_experts = num_experts
         self.top_k = top_k
         self.d_model = d_model
@@ -117,8 +130,10 @@ class MoEConfig:
         self.route_scale = route_scale
         # Shared experts beside the routed ones (``expert="swiglu"``): one
         # SwiGLU MLP of ``shared * d_hidden`` that every token passes,
-        # unweighted.
+        # unweighted, or with ``shared_gate`` times ``sigmoid(w . x)``, one
+        # scalar a token from a ``(d_model, 1)`` matrix (Qwen2-MoE's).
         self.shared = shared
+        self.shared_gate = shared_gate
         # A per-expert bias added to the scores for the CHOICE of the top_k
         # only (never to the weights; no gradient); after each step it moves
         # by ``bias_update_rate`` towards an even load (``stats``'
@@ -128,6 +143,14 @@ class MoEConfig:
         # ``(first, count)``: the consecutive experts this layer holds (the
         # module docstring); None holds all.
         self.held = held
+        # A held layer's rows go through its experts in chunks of ``T * k /
+        # held_chunks`` rows (:func:`held_chunk_rows`; None: a sixteenth).
+        # A chunk that is the layer's even share (``count / num_experts`` of
+        # the assignments) leaves the router's draw to decide between one
+        # chunk and two, so a deployment whose share is a sixteenth names a
+        # count that puts its share well inside a chunk.
+        self.held_chunks = (_HELD_CHUNKS if held_chunks is None
+                            else int(held_chunks))
 
     @property
     def num_held(self):
@@ -155,6 +178,9 @@ def init(key, cfg):
             "up": L.dense_init(shared_keys[0], cfg.d_model, wide, False),
             "down": L.dense_init(shared_keys[1], wide, cfg.d_model, False),
             "glu": L.dense_init(shared_keys[2], cfg.d_model, wide, False)}
+        if cfg.shared_gate:
+            params["shared_gate"] = L.dense_init(
+                jax.random.fold_in(key, 2), cfg.d_model, 1, False)
     if cfg.select_bias:
         params["bias"] = jnp.zeros((cfg.num_experts,), jnp.float32)
     return params
@@ -297,24 +323,20 @@ def apply(params, cfg, x):
 #: 1,024 rows or 2,048 deep does not fit the kernel's memory (PERF.md, PR 25).
 GMM_TILING = (512, 1024, 1024)
 
-#: A held layer works through its held rows in chunks of a sixteenth of the
-#: step's ``T * k`` assignments (:func:`held_chunk_rows`).
-_HELD_CHUNKS = 16
-
-
-def held_chunk_rows(assignments):
-    """The rows of one chunk of a held layer's buffers: a sixteenth of
-    ``assignments`` rounded up to whole row tiles of the grouped product."""
+def held_chunk_rows(assignments, chunks=_HELD_CHUNKS):
+    """The rows of one chunk of a held layer's buffers: ``assignments /
+    chunks`` (a sixteenth unless ``MoEConfig.held_chunks`` says otherwise)
+    rounded up to whole row tiles of the grouped product."""
     tile = GMM_TILING[0]
-    return min(assignments, -(-assignments // (_HELD_CHUNKS * tile)) * tile)
+    return min(assignments, -(-assignments // (chunks * tile)) * tile)
 
 
-def held_rungs(assignments):
+def held_rungs(assignments, chunks=_HELD_CHUNKS):
     """The static ladder of a held layer's buffer sizes in rows, ascending:
     the multiples of :func:`held_chunk_rows` up to the first that holds all
     ``assignments``.  A step takes the smallest rung that is at least its
     held count, by running that many chunks."""
-    chunk = held_chunk_rows(assignments)
+    chunk = held_chunk_rows(assignments, chunks)
     return tuple(range(0, assignments + chunk, chunk))
 
 
@@ -370,8 +392,11 @@ def _announce(cfg, assignments):
     registry.gauge("moe.assignments_per_step").set(assignments)
     registry.gauge("moe.experts_held").set(cfg.num_held)
     registry.gauge("moe.softmax_scoring").set(int(cfg.scoring == "softmax"))
-    rungs = held_rungs(assignments) if cfg.held is not None else ()
+    registry.gauge("moe.shared_gate").set(int(cfg.shared_gate))
+    rungs = (held_rungs(assignments, cfg.held_chunks)
+             if cfg.held is not None else ())
     registry.gauge("moe.held_buffer_rungs").set(len(rungs))
+    registry.gauge("moe.held_chunk_rows").set(rungs[1] if rungs else 0)
     detail = (f"dropless: {assignments} assignments a step over "
               f"{cfg.num_experts} {cfg.expert} experts, {cfg.top_k} a token; "
               f"grouped product megablox gmm tiled {GMM_TILING}, "
@@ -385,6 +410,8 @@ def _announce(cfg, assignments):
                       f"as the step's count takes, {len(rungs) - 1} at most"
                       if rungs else "")
                    + f"), {cfg.shared} shared"
+                   + (" times the sigmoid of a scalar a token"
+                      if cfg.shared_gate else "")
                    + (", selection bias" if cfg.select_bias else ""))
     if detail not in _announced:
         _announced.add(detail)
@@ -460,12 +487,18 @@ def _route_sigmoid(logits, params, cfg):
             scores / scores.sum(-1, keepdims=True))
 
 
-def _shared_expert(params, cfg, flat_x):
-    """The shared experts' MLP over every token, unweighted: (T, d)."""
+def _shared_expert(params, cfg, flat_x, gate=None):
+    """The shared experts' MLP over every token, (T, d) float32: unweighted,
+    or times ``sigmoid(x . w)`` of ``gate``'s ``(d, 1)`` matrix, the sigmoid
+    and the product in float32."""
     xc = flat_x.astype(cfg.dtype)
     hidden = jax.nn.silu(L.dense(params["glu"], xc, cfg.dtype)) \
         * L.dense(params["up"], xc, cfg.dtype)
-    return L.dense(params["down"], hidden, cfg.dtype)
+    out = L.dense(params["down"], hidden, cfg.dtype).astype(jnp.float32)
+    if gate is None:
+        return out
+    return out * jax.nn.sigmoid(L.dense(gate, xc, cfg.dtype)
+                                .astype(jnp.float32))
 
 
 def _experts(cfg, kernel, rows, group_sizes):
@@ -600,10 +633,10 @@ def _held_part(params, cfg, flat_x, top_vals, flat_idx, group_sizes, stats):
     step's held count (:func:`held_rungs`), and every array between the
     gather of a chunk's token rows and their sum into the tokens' rows has
     a chunk's rows.  At most all ``T * k`` assignments are held, in
-    ``_HELD_CHUNKS`` chunks: a token whose ``top_k`` are all held loses
+    ``cfg.held_chunks`` chunks: a token whose ``top_k`` are all held loses
     none, and the result is exact at every count."""
     (tokens, top_k), (first, count) = top_vals.shape, cfg.held
-    chunk = held_chunk_rows(tokens * top_k)
+    chunk = held_chunk_rows(tokens * top_k, cfg.held_chunks)
     with jax.named_scope("dispatch"):
         local = flat_idx - first
         group_sizes = group_sizes[first:first + count]
@@ -640,7 +673,8 @@ def dropless_apply(params, cfg, x):
     expert matrices applied to its contiguous group of rows by one grouped
     product.  The results go back to the assignments' own order, are
     weighted, and summed per token.  ``cfg.shared`` adds the shared experts'
-    MLP of every token (scope ``shared``).
+    MLP of every token (scope ``shared``), with ``cfg.shared_gate`` times the
+    sigmoid of one scalar a token (``shared_gate/kernel``).
 
     With ``cfg.held = (first, count)`` the layer computes the held experts'
     part exactly (:func:`_held_part`): routing, weights and every statistic
@@ -648,17 +682,18 @@ def dropless_apply(params, cfg, x):
     all ``num_experts``; the assignments sort by held expert with every
     other assignment behind them, and only the held ones are moved: a loop
     on the device takes them a chunk at a time (:func:`held_chunk_rows`: a
-    sixteenth of the ``T * k`` assignments, in whole row tiles of the
-    grouped product) and runs as many chunks as hold the step's held count,
-    so the buffers are a chunk's rows and the work follows the count; all
-    sixteen chunks are ``T * k`` rows, so that a token whose ``top_k`` are
-    all held loses none, and the result is exact at every count.  ``stats``'
+    sixteenth of the ``T * k`` assignments, or ``1 / cfg.held_chunks`` of
+    them, in whole row tiles of the grouped product) and runs as many chunks
+    as hold the step's held count, so the buffers are a chunk's rows and the
+    work follows the count; all the chunks are ``T * k`` rows, so that a
+    token whose ``top_k`` are all held loses none, and the result is exact
+    at every count.  ``stats``'
     ``held_buffer_rows`` is the rows of the chunks run (a rung of
     :func:`held_rungs`): ``held_assignments`` over it is the buffers' fill,
     and a step that ran every chunk moved every row, as a layer that sizes
     its buffers for the worst case would.  The chunk is in the ``moe``
-    event's line and the ladder's length in the gauge
-    ``moe.held_buffer_rungs``.
+    event's line and the gauge ``moe.held_chunk_rows``, the ladder's length
+    in the gauge ``moe.held_buffer_rungs``.
 
     ``stats`` (float32 scalars but ``state_updates``): ``load_balance`` = E
     * sum_e f_e P_e with f_e the share of a row's ``seq * k`` assignments
@@ -744,8 +779,8 @@ def dropless_apply(params, cfg, x):
             out = jnp.sum(y.astype(jnp.float32) * top_vals[..., None], axis=1)
     if cfg.shared:
         with jax.named_scope("shared"):
-            out = out + _shared_expert(params["shared"], cfg, flat_x) \
-                .astype(jnp.float32)
+            out = out + _shared_expert(params["shared"], cfg, flat_x,
+                                       params.get("shared_gate"))
     return out.reshape(x.shape).astype(x.dtype), stats
 
 

@@ -335,3 +335,54 @@ def test_partial_rotary_passes_the_other_lanes():
                                L.apply_rope(x[..., :8], tables), atol=0)
     half = tuple(t[:, :4] for t in tables)
     np.testing.assert_allclose(turned, ref.rotate(x, half), atol=1e-6)
+
+
+# -- 256 lanes, eight query heads a key-value head ------------------------------
+
+def test_256_lanes_at_a_group_of_eight_match_the_reference(monkeypatch):
+    """The Qwen3-Next cell's full layer in small: 16 query heads of 256 lanes
+    over 2 key-value heads, no window; o, dq and the key-value-head-wide dk
+    and dv through the interpreted kernels (blocks 16 x 32 in two
+    sub-tiles: the dk/dv program accumulates over 8 x 4 query blocks)."""
+    monkeypatch.setattr(fa, "_SUB_TILE", SUB)
+    q, k, v, do = _operands(8, d=256)
+    assert q.shape == (1, 16, 64, 256) and k.shape == (1, 2, 64, 256)
+
+    def attend(q, k, v):
+        return fa.flash_attention(q, k, v, True, 16, 32, 0, True)
+    np.testing.assert_allclose(attend(q, k, v), _reference(q, k, v, None),
+                               atol=3e-6)
+    for g, e, x in zip(_grads(attend, q, k, v, do),
+                       _grads(lambda *x: _reference(*x, None), q, k, v, do),
+                       (q, k, v)):
+        assert g.shape == x.shape
+        np.testing.assert_allclose(g, e, atol=1e-4)
+
+
+def test_the_256_lane_programs_of_8192_keys_fit_the_vmem_reckoning(
+        monkeypatch):
+    """The three kernels as the chip runs them at the cell's own shape (16
+    heads of 256 over 2 key-value heads, 8,192 keys, bf16: traced, not run):
+    one row a program on the split layout at the default blocks, 512 x 1,024,
+    and the padded estimate of each under the budget (the dk/dv program's two
+    (1,024 x 256) f32 accumulators with it); Mosaic's own verdict is
+    ``tests/test_topology_aot.py``'s."""
+    from autodist_tpu.observability import recorder
+    monkeypatch.setattr(fa, "_pallas_interpret", lambda *_: False)
+    q = jax.ShapeDtypeStruct((1, 16, 8192, 256), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 2, 8192, 256), jnp.bfloat16)
+    jax.make_jaxpr(jax.grad(
+        lambda q, k, v: fa.flash_attention(q, k, v, True)
+        .astype(jnp.float32).sum(), argnums=(0, 1, 2)))(q, kv, kv)
+    said = {e["detail"].split()[0]: e["detail"] for e in recorder.events()
+            if e["kind"] == "flash" and "bfloat16[16,8192,256]" in e["detail"]}
+    assert set(said) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    for kernel, detail in said.items():
+        assert "split layout, 1 heads a block of 256 lanes, blocks 512 x " \
+            "1024, G = 1 (batch, head) rows a program" in detail, detail
+        assert "8 query heads read one key-value head, 2 key-value heads " \
+            "in HBM" in detail
+        vmem = int(detail.split(" bytes of VMEM")[0].split()[-1])
+        assert 2 * 2 ** 20 < vmem <= fa._VMEM_BUDGET, (kernel, vmem)
+    # 36 of a row's 16 x 8 = 128 sub-tiles hold a seen score... of 136.
+    assert "136 of 256 sub-tiles of 512 x 512 visited" in said["flash_fwd"]

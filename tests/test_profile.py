@@ -80,6 +80,26 @@ def test_a_loops_and_a_conditionals_frames_are_not_scopes(op_name, path,
     assert profile._scope_and_phase(op_name) == scope
 
 
+@pytest.mark.parametrize("op_name, path, scope", [
+    ("jit(step)/transpose(jvp(layer1))/jvp(layer1)/checkpoint/gdn/conv/mul",
+     "layer1/gdn/conv/mul", ("gdn/conv", "backward")),
+    ("jit(step)/transpose(jvp(layer1))/jvp(layer1)/checkpoint/"
+     "rematted_computation/gdn/scan/while/body/dot_general",
+     "layer1/gdn/scan/dot_general", ("gdn/scan", "backward")),
+    ("jit(step)/transpose(jvp(layer1))/jvp(layer1)/remat2", "layer1/remat2",
+     ("layer1", "backward")),
+    # A checkpoint inside a scope stays what it was: one more segment.
+    ("jit(step)/jvp(layer0)/gdn/scan/checkpoint/inverse/mul",
+     "layer0/gdn/scan/checkpoint/inverse/mul", ("gdn/scan", "forward"))])
+def test_a_recomputed_sublayers_frames_are_not_scopes(op_name, path, scope):
+    """``jax.checkpoint`` around a sublayer (``TransformerConfig.recompute``)
+    puts the backward pass's instructions behind a second copy of the call's
+    scopes and ``checkpoint/`` or ``checkpoint/rematted_computation/``: they
+    keep the rows they had."""
+    assert scope_path(op_name) == path
+    assert profile._scope_and_phase(op_name) == scope
+
+
 def test_op_provenance_scopes_and_flops_sum_to_estimate():
     params, loss_fn, batch = mlp.tiny_fixture()
     item = GraphItem.capture(loss_fn, params, optax.sgd(0.1),

@@ -517,6 +517,58 @@ def test_v5e_compiler_takes_grouped_heads_and_the_windows_walk(
     assert results.count(f"bf16[{kv},{s},{d}]") == 2 and wide not in results
 
 
+def test_v5e_compiler_takes_256_lanes_at_a_group_of_eight(monkeypatch):
+    """The Qwen3-Next cell's full-attention layer at its own shape (one row of
+    8,192 positions, 16 query heads of 256 lanes over 2 key-value heads, a
+    norm a head on q and k, 64 lanes rotated, a sigmoid gate a lane), forward
+    and backward, compiled by libtpu for one detached v5e chip: Mosaic takes
+    the three kernels at 256 lanes and blocks of 512 x 1,024 (the dk/dv
+    program holds two (1,024 x 256) f32 accumulators over 8 x 16 query
+    blocks) inside its scoped VMEM, and keys, values, dk and dv stay 2 heads
+    wide at the kernels."""
+    why_not = _why_no_detached_topology()
+    if why_not:
+        pytest.skip(why_not)
+    import importlib
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from autodist_tpu.models import layers as L
+    fa = importlib.import_module("autodist_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_pallas_interpret", lambda *_: False)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x4")
+    chip = SingleDeviceSharding(topo.devices[0])
+    b, s, dim, heads, d, kv = 1, 8192, 2048, 16, 256, 2
+    hook = fa.make_flash_attn_fn(causal=True)
+    rope = L.rope_tables(s, d // 4, 1e7)
+
+    def loss(p, x):
+        y = L.mha(p, x, heads, dtype=jnp.bfloat16, attn_fn=hook, rope=rope,
+                  norm_eps=1e-6, kv_heads=kv)
+        return (y.astype(jnp.float32) ** 2).sum()
+    params = jax.eval_shape(lambda: L.mha_init(
+        jax.random.PRNGKey(0), dim, heads, False, "head", d, kv, "lane"))
+    assert params["gate"]["kernel"].shape == (dim, heads * d)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        params)
+    x = jax.ShapeDtypeStruct((b, s, dim), jnp.bfloat16, sharding=chip)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    kernels = {name: line for line in text.splitlines()
+               for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+               if "tpu_custom_call" in line and f"%{name}" in line}
+    assert len(kernels) == 3
+    wide, narrow = f"bf16[{heads},{s},{d}]", f"bf16[{kv},{s},{d}]"
+    for name, line in kernels.items():
+        operands = re.search(r"operand_layout_constraints=\{(.*?\})\}, ",
+                             line).group(1)
+        assert operands.count(narrow) == 2, (name, operands)
+        assert operands.count(wide) == (1 if name == "flash_fwd" else 2)
+    results = kernels["flash_bwd_dkv"].split(" custom-call(")[0]
+    assert results.count(narrow) == 2 and wide not in results
+
+
 @pytest.mark.parametrize("chunk, solves", [(64, False), (48, True)])
 def test_v5e_compiler_runs_no_triangular_solve_for_a_chunk_of_64(chunk,
                                                                  solves):
