@@ -5,7 +5,9 @@ length the chunk does not divide; decays near 0 and near 1; write strengths
 up to 2; rows that must not mix; bf16 operands within a stated tolerance.
 A chunk's inverse by block products against a float64 inverse and against
 the triangular solve it replaces, its closed cotangent against autodiff
-through the solve, and which of the two a chunk's size takes."""
+through the solve, and which of the two a chunk's size takes.  The walk over
+the chunks as Pallas kernels, interpreted, against the ``lax.scan``: outputs,
+final state and the five gradients with a cotangent on the final state."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -36,18 +38,21 @@ def recurrent_gated_delta_rule(q, k, v, g, beta):
     return jnp.moveaxis(o, 0, 1), state
 
 
-def _inputs(seed, rows, s, decay=1.0, beta_max=2.0, dtype=jnp.float32):
+def _inputs(seed, rows, s, decay=1.0, beta_max=2.0, dtype=jnp.float32,
+            heads=HEADS, key_heads=None, d_k=D_K, d_v=D_V):
     """q and k as the mixer hands them over (unit k, q scaled by
-    d_k^-1/2), log decays of about ``-decay``, beta in (0, beta_max)."""
+    d_k^-1/2; ``key_heads`` of them, None: ``heads``), log decays of about
+    ``-decay``, beta in (0, beta_max)."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    q = jax.random.normal(ks[0], (rows, s, HEADS, D_K))
-    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * D_K ** -0.5
-    k = jax.random.normal(ks[1], (rows, s, HEADS, D_K))
+    key_heads = key_heads or heads
+    q = jax.random.normal(ks[0], (rows, s, key_heads, d_k))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * d_k ** -0.5
+    k = jax.random.normal(ks[1], (rows, s, key_heads, d_k))
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    v = jax.random.normal(ks[2], (rows, s, HEADS, D_V))
-    g = -decay * jax.nn.softplus(jax.random.normal(ks[3], (rows, s, HEADS)))
+    v = jax.random.normal(ks[2], (rows, s, heads, d_v))
+    g = -decay * jax.nn.softplus(jax.random.normal(ks[3], (rows, s, heads)))
     beta = beta_max * jax.nn.sigmoid(
-        2.0 * jax.random.normal(ks[4], (rows, s, HEADS)))
+        2.0 * jax.random.normal(ks[4], (rows, s, heads)))
     return tuple(t.astype(dtype) for t in (q, k, v)) + (g, beta)
 
 
@@ -221,6 +226,118 @@ def test_no_triangular_solve_is_traced_for_a_power_of_two(chunk, solves):
         argnums=(0, 1, 2, 3, 4)))(*args)
     assert ("triangular_solve" in str(jaxpr)) == solves
     assert ("block products" in gated_delta.inverse_form(chunk)) != solves
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize(
+    "rows, s, chunk, heads, key_heads, d_k, d_v, head_block",
+    [(1, 64, 16, 4, 4, 8, 8, 4),        # equal heads
+     (1, 64, 16, 4, 2, 8, 8, 4),        # two value heads a key head
+     (1, 48, 16, 6, 6, 8, 8, 3),        # heads no multiple of 8, 3 a program
+     (1, 64, 16, 4, 4, 8, 24, 2),       # d_k != d_v
+     (1, 50, 16, 4, 2, 16, 8, 4),       # a length the chunk does not divide
+     (2, 40, 8, 6, 3, 8, 12, 6)],       # two rows
+    ids=["equal-heads", "grouped", "six-heads-by-three", "dk-not-dv",
+         "padded-length", "two-rows"])
+def test_the_interpreted_kernels_are_the_scan(
+        rows, s, chunk, heads, key_heads, d_k, d_v, head_block, dtype,
+        monkeypatch):
+    """The two kernels under the Pallas interpreter against the ``lax.scan``
+    they replace on a TPU, on the same inputs: ``o``, the final state and
+    the gradients of q, k, v, g and beta of a loss that reads both, so that
+    the final state's cotangent is not zero.  In float32 the two do the same
+    arithmetic; in bfloat16 the scan's transpose rounds each of a step's
+    additions to the state's cotangent to bfloat16 and the kernel sums them
+    in float32, so the gradients agree within bfloat16's rounding."""
+    if head_block != heads:     # what the budget gives at the cells' widths
+        monkeypatch.setattr(gated_delta, "_head_block",
+                            lambda *_: head_block)
+    args = _inputs(s + heads, rows, s, dtype=dtype, heads=heads,
+                   key_heads=key_heads, d_k=d_k, d_v=d_v)
+    assert gated_delta.walk_form(True, heads, chunk, d_k, d_v, dtype)[:2] \
+        == (True, head_block)
+    w_o, w_s = (jax.random.normal(jax.random.PRNGKey(i), shape)
+                for i, shape in ((1, (rows, s, heads, d_v)),
+                                 (2, (rows, heads, d_k, d_v))))
+
+    def both(interpret):
+        def loss(*a):
+            o, state = gated_delta_rule(*a, chunk=chunk, interpret=interpret)
+            return (jnp.sum(o.astype(jnp.float32) * w_o)
+                    + jnp.sum(state * w_s)), (o, state)
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+
+    ((_, (o, state)), grads), ((_, (o_want, state_want)), grads_want) = \
+        both(True), both(None)
+    assert o.shape == (rows, s, heads, d_v) and o.dtype == dtype
+    assert state.shape == (rows, heads, d_k, d_v)
+    _assert_close(o, o_want, 1e-6)
+    _assert_close(state, state_want, 1e-6)
+    for name, got, want in zip("q k v g beta".split(), grads, grads_want):
+        assert got.dtype == want.dtype and got.shape == want.shape, name
+        _assert_close(got, want, 1e-6 if dtype == jnp.float32 else 2e-2)
+
+
+def test_a_trace_takes_both_kernels_and_no_scan_where_they_engage():
+    """``grad`` of the rule with the kernels: two ``pallas_call``s (a third
+    under ``jax.checkpoint``, which runs the walk twice) and no ``scan``;
+    off TPU with nothing asked, the scan and no kernel; a chunk of no whole
+    8-row tiles declines."""
+    args = _inputs(8, 1, 64)
+
+    def traced(**kw):
+        return str(jax.make_jaxpr(jax.grad(
+            lambda *a: jnp.sum(gated_delta_rule(*a, **kw)[0]),
+            argnums=(0, 1, 2, 3, 4)))(*args))
+    kernels, scan = traced(chunk=16, interpret=True), traced(chunk=16)
+    assert "gdn_walk_fwd" in kernels and "gdn_walk_bwd" in kernels
+    assert "scan[" not in kernels
+    # How many arrays each call writes: o, the chunks' states, the final
+    # state forward; the six cotangents transposed.  Under jax.checkpoint
+    # (``TransformerConfig(recompute="linear_mixer")``) the forward pass
+    # proper saves no states: they are written when the backward pass runs
+    # the walk again (``optimize_remat``).
+    def writes(jaxpr):
+        return [line.count("ShapedArray") for line in jaxpr.splitlines()
+                if "out_avals=" in line]
+    assert writes(kernels) == [3, 6]
+    assert writes(str(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(jax.checkpoint(
+            lambda *a: gated_delta_rule(*a, chunk=16, interpret=True))(*a)[0]),
+        argnums=(0, 1, 2, 3, 4)))(*args))) == [2, 3, 6]
+    assert "scan[" in scan and "pallas_call" not in scan
+    assert "pallas_call" not in traced(chunk=12, interpret=True)
+    assert gated_delta.walk_form(None, HEADS, 16, D_K, D_V, jnp.float32)[:2] \
+        == (None, 0)
+
+
+@pytest.mark.parametrize("heads, d_k, d_v, want",
+                         [(32, 128, 128, 16), (30, 96, 192, 10),
+                          (6, 8, 12, 6), (1, 2048, 2048, 0)])
+def test_heads_a_program_follow_the_shapes(heads, d_k, d_v, want):
+    """The two cells' shapes, a toy's, and a head whose blocks pass the
+    budget alone (the scan then)."""
+    assert gated_delta._head_block(heads, 64, d_k, d_v, jnp.bfloat16) == want
+    assert want == 0 or heads % want == 0
+
+
+@pytest.mark.parametrize("interpret, kernel", [(True, 1), (None, 0)])
+def test_the_gauges_and_the_event_name_the_form_that_ran(interpret, kernel):
+    from autodist_tpu import observability
+    from autodist_tpu.observability import recorder
+    observability.reset()
+    gated_delta._announced.clear()
+    gated_delta_rule(*_inputs(6, 2, 40), chunk=16, interpret=interpret)
+    (event,) = [e for e in recorder.events() if e["kind"] == "gdn"]
+    gauges = observability.registry().snapshot()["gauges"]
+    assert gauges["gdn.scan_kernel"] == kernel
+    assert gauges["gdn.scan_head_block"] == (HEADS if kernel else 0)
+    assert ("walk over the chunks: Pallas kernels, 3 heads a program, the "
+            "state in VMEM (interpret=True requested)" if kernel else
+            "walk over the chunks: lax.scan (backend is cpu; the kernels "
+            "compile for tpu)") in event["detail"]
 
 
 def test_the_trace_announces_the_rule_once_a_shape():

@@ -569,16 +569,25 @@ def test_v5e_compiler_takes_256_lanes_at_a_group_of_eight(monkeypatch):
     assert results.count(narrow) == 2 and wide not in results
 
 
-@pytest.mark.parametrize("chunk, solves", [(64, False), (48, True)])
-def test_v5e_compiler_runs_no_triangular_solve_for_a_chunk_of_64(chunk,
+@pytest.mark.parametrize("shape, chunk, solves", [
+    ((4096, 30, 30, 96, 192), 64, False), ((4096, 30, 30, 96, 192), 48, True),
+    ((8192, 32, 16, 128, 128), 64, False)],
+    ids=["olmo-hybrid-64", "olmo-hybrid-48", "qwen3-next-64"])
+def test_v5e_compiler_runs_no_triangular_solve_for_a_chunk_of_64(shape, chunk,
                                                                  solves):
     """The gated delta rule at the Olmo-Hybrid cell's own shape (one row of
-    4,096 positions, 30 heads of 96 / 192, bf16), forward and backward,
-    compiled by libtpu for one detached v5e chip.  At the train path's chunk
-    of 64 the chunk's inverse is block products: no ``triangular_solve`` and
-    not the custom call libtpu expands one into (``f32[64,1,30,1,64,64]`` in
-    a trace, 15.4 ms of the step before) is left in the optimized HLO.  A
-    chunk that is no power of two still solves."""
+    4,096 positions, 30 heads of 96 / 192, bf16) and at the Qwen3-Next
+    cell's (8,192 positions, 32 value heads on 16 key heads, 128 / 128),
+    forward and backward, compiled by libtpu for one detached v5e chip as a
+    TPU process traces it: the walk over the chunks is the two Mosaic
+    kernels (``gdn_walk_fwd``, ``gdn_walk_bwd``: Mosaic takes the 96- and
+    192-lane blocks as they are) and no ``while`` is left, where the
+    ``lax.scan`` compiles to two; the step's program holds no more with the
+    kernels than with the scan.  At the train path's chunk of 64 the chunk's
+    inverse is block products: no ``triangular_solve`` and not the custom
+    call libtpu expands one into (``f32[64,1,30,1,64,64]`` in a trace, 15.4
+    ms of the step before) is left in the optimized HLO.  A chunk that is no
+    power of two still solves, and walks in the kernels all the same."""
     why_not = _why_no_detached_topology()
     if why_not:
         pytest.skip(why_not)
@@ -588,23 +597,41 @@ def test_v5e_compiler_runs_no_triangular_solve_for_a_chunk_of_64(chunk,
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x4")
     chip = SingleDeviceSharding(topo.devices[0])
-    b, s, heads, d_k, d_v = 1, 4096, 30, 96, 192
+    b = 1
+    s, heads, key_heads, d_k, d_v = shape
     q, k, v, g, beta = (
-        jax.ShapeDtypeStruct((b, s, heads) + tail, dtype, sharding=chip)
-        for tail, dtype in (((d_k,), jnp.bfloat16), ((d_k,), jnp.bfloat16),
-                            ((d_v,), jnp.bfloat16), ((), jnp.float32),
-                            ((), jnp.float32)))
+        jax.ShapeDtypeStruct((b, s) + tail, dtype, sharding=chip)
+        for tail, dtype in (((key_heads, d_k), jnp.bfloat16),
+                            ((key_heads, d_k), jnp.bfloat16),
+                            ((heads, d_v), jnp.bfloat16),
+                            ((heads,), jnp.float32), ((heads,), jnp.float32)))
 
-    def loss(*args):
-        o, state = gated_delta_rule(*args, chunk=chunk)
-        return (o.astype(jnp.float32) ** 2).sum() + state.sum()
-    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-        q, k, v, g, beta).compile().as_text()
-    assert "while(" in text                     # the scan over the chunks
-    named = [m.group(0) for m in re.finditer(
-        r"op_name=\"[^\"]*triangular_solve|"
-        r"custom_call_target=\"[^\"]*Triangular[^\"]*\"", text)]
-    assert bool(named) == solves, sorted(set(named))
+    def compiled(interpret):
+        def loss(*args):
+            o, state = gated_delta_rule(*args, chunk=chunk,
+                                        interpret=interpret)
+            return (o.astype(jnp.float32) ** 2).sum() + state.sum()
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4))).lower(q, k, v, g, beta).compile()
+    # False: the kernels, compiled (what a TPU backend picks); None in this
+    # CPU process: the scan.
+    kernels, scan = compiled(False), compiled(None)
+    text = kernels.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 2 and " while(" not in text
+    assert any("gdn_walk_fwd" in c for c in calls) \
+        and any("gdn_walk_bwd" in c for c in calls)
+    assert scan.as_text().count(" while(") == 2     # the scan, each way
+    held = [sum(getattr(exe.memory_analysis(), f"{part}_size_in_bytes")
+                for part in ("temp", "argument", "output"))
+            for exe in (kernels, scan)]
+    assert held[0] <= held[1], held
+    for exe in (kernels, scan):
+        named = [m.group(0) for m in re.finditer(
+            r"op_name=\"[^\"]*triangular_solve|"
+            r"custom_call_target=\"[^\"]*Triangular[^\"]*\"",
+            exe.as_text())]
+        assert bool(named) == solves, sorted(set(named))
 
 
 def _entry_schedule(text):
