@@ -21,68 +21,94 @@ really writes and ``S_0`` the state the chunk starts from::
 
 so ``T = (I + A)^-1`` (the WY / UT form: the inverse of a unit
 lower-triangular matrix of ``chunk`` rows, once a chunk and head, by block
-matrix products where ``chunk`` is a power of two: ``unit_lower_inverse``),
-``W = T diag(beta exp gamma) K`` and ``U_0 = T diag(beta) V`` are computed
-for every chunk at once, and one walk over the ``s / chunk`` chunks carries
-``S`` in float32 through four matrix products a step (``U = U_0 - W S``,
-``O``'s two, ``S_C``).
+matrix products where ``chunk`` is a power of two: ``unit_lower_inverse``)
+and ``M * Q K^T``, the two ``chunk`` x ``chunk`` matrices a chunk and head,
+are computed for every chunk at once (``_chunk_terms``), and one walk over
+the ``s / chunk`` chunks carries ``S`` in float32 and makes everything of
+``chunk`` x d a chunk at a time, from q, k, v and the gates as they are
+(``_head_step``: the first equation as it stands, ``U = T diag(beta) (V -
+diag(exp gamma) K S_0)``, then ``O`` and ``S_C``: five matrix products a
+step, ``K S_0``, ``T``'s, ``Q S_0``, ``(M * Q K^T) U`` and ``K~^T U``).
+``W = T diag(beta exp gamma) K`` and ``U_0 = T diag(beta) V`` of the papers'
+``U = U_0 - W S_0`` are never formed: they, ``diag(exp gamma) Q`` and
+``diag(exp(gamma_C - gamma)) K`` were four ``(chunks, batch, heads, chunk,
+d)`` arrays written to HBM and read back three times a layer.
 Decays are differences of logarithms, never quotients of decays, so a
 decay near 0 underflows to an exact 0 and nothing overflows.  Every product
 takes operands in the inputs' dtype (bfloat16 on the train path) and
-accumulates in float32, but the two that ``T`` multiplies, which are float32
-throughout; the decays, the inverse and the carried state are float32.
+accumulates in float32, but those that ``T`` multiplies (one forward, and
+with it ``dT``'s and ``T^T``'s transposed), which take float32 operands at
+``HIGHEST``; the decays, the inverse and the carried state are float32, and
+a decay scales a product's float32 result where it can (``diag(exp gamma) (Q
+S_0)``), a rounded operand where it must (``K~ = diag(exp(gamma_C - gamma))
+K`` in ``K~^T U``).
 
 **Fewer key heads than value heads** (``q``, ``k`` of ``H_k`` heads, ``v``,
 ``g``, ``beta`` of ``H_v = r H_k``): value head ``h`` reads the queries and
 keys of key head ``h // r``.  A chunk's ``K K^T`` and ``Q K^T`` belong to a
 key head and are computed once for it; the decays, ``beta``, ``A``, the
-inverse, ``W``, ``U_0`` and the state belong to a value head, so the two
-products meet their value heads' decays as a broadcast over the ``r`` heads of
-a group (``of_value_heads`` in ``_chunk_terms``), and so do ``q`` and ``k``
-where a value head's decay scales them (``diag(exp gamma) Q``,
-``diag(exp(gamma_C - gamma)) K``, ``diag(beta exp gamma) K``): elementwise,
-fused with the multiply.  With ``r`` = 1 nothing is broadcast and the
+inverse and the state belong to a value head, so the two products meet their
+value heads' decays as a broadcast over the ``r`` heads of a group
+(``of_value_heads`` in ``_chunk_terms``: ``chunk`` x ``chunk`` a head).  q
+and k themselves are never repeated: the kernels read a key head's block
+through the block index (a program's ``Hb`` value heads are whole groups, so
+its ``Hb / r`` key heads are block ``j`` of the key-head axis where its
+value heads are block ``j`` of theirs) and sum dq and dk over a group's
+value heads before they write them, a key head; the scan's step maps over
+(key heads, ``r``) with q and k unmapped along ``r``.  With ``r`` = 1 the
 equations are those of equal heads.
 
 **The walk over the chunks** is two Pallas kernels on a TPU (``_walk``, one
 ``jax.custom_vjp``; ``walk_form`` decides from the backend, the mesh and the
 shapes, and the ``lax.scan`` they replace runs anywhere else: off TPU, with
-a mesh axis left to the partitioner, a chunk of no whole 8-row tiles, a head
-too wide for VMEM).  Both run a grid (batch, heads / ``Hb``, chunks), the
-last dimension in order.  Forward, the state of ``Hb`` heads, ``(Hb, d_k,
-d_v)`` float32, stays in a VMEM scratch from a row's first chunk to its
-last; a program reads its chunk's ``W``, ``U_0``, ``M * Q K^T``, ``diag(exp
-gamma) Q``, ``diag(exp(gamma_C - gamma)) K`` and ``exp(gamma_C)`` through
-its block's index on the stacked ``(chunks, batch, heads, chunk, ...)``
-arrays ``_chunk_terms`` makes (the pipeline fetches the next chunk's under
-this chunk's products; nothing is sliced or stacked by an instruction), does
-the scan step's arithmetic (operands in the inputs' dtype, float32 sums, ``U``
-rounded to the inputs' dtype) and writes ``O`` and, where a backward pass
-will read it, the state the chunk starts from (not in a forward pass under
-``jax.checkpoint``, which makes the walk again before it transposes it); the
-final state leaves with the last chunk.  Transposed, the same grid counts a row's chunks from its
-end with the state's cotangent in the scratch, started from the final
-state's own (``layers.gdn`` returns the state); a program makes its chunk's
-``U`` again from the saved state and writes the cotangents of the five terms
-and of the decay: ``dQ~ = dO S^T``, ``d(M * Q K^T) = dO U^T``, ``dU = (M * Q
-K^T)^T dO + K~ dS``, ``dK~ = U dS^T``, ``dU_0 = dU``, ``dW = -dU S^T``,
-``d exp(gamma_C) = <dS, S>`` and ``dS <- exp(gamma_C) dS + Q~^T dO - W^T
-dU``, summed in float32.  ``Hb`` is the largest divisor of the heads whose
-blocks, double-buffered, fit the VMEM budget (``_head_block``: 16 of 32 heads
-at 64 x 128 / 128 in bfloat16, 10 of 30 at 64 x 96 / 192).
+a mesh axis left to the partitioner, a chunk of no whole 8-row tiles, a
+group of heads too wide for VMEM; it takes the same terms and runs the same
+``_head_step`` under ``vmap``).  Both run a grid (batch, heads / ``Hb``,
+chunks), the last dimension in order.  Forward, the state of ``Hb`` heads,
+``(Hb, d_k, d_v)`` float32, stays in a VMEM scratch from a row's first
+chunk to its last; a program reads its chunk's ``T`` (float32) and ``M * Q
+K^T``, ``chunk`` x ``chunk`` a value head, v a value head, q and k a KEY
+head and the chunk's ``gamma`` and ``beta`` as ``(Hb, chunk)`` float32 rows
+(a ``(chunk, 1)`` float32 block would be 128 lanes a value in HBM and in
+VMEM; ``_column`` turns a row into the column that scales a chunk's rows,
+exactly, and ``beta`` scales ``T``'s columns as the row it is) through its
+block's index on the stacked ``(chunks, batch, heads, chunk, ...)`` arrays
+(the pipeline fetches the next chunk's under this chunk's products; nothing
+is sliced or stacked by an instruction), runs ``_head_step`` and writes ``O``
+and, where a backward pass will read it, the state the chunk starts from
+(not in a forward pass under ``jax.checkpoint``, which makes the walk again
+before it transposes it); the final state leaves with the last chunk.  A
+head's products wait on one another (``S_0`` -> ``K S_0`` -> ``U`` -> ``O``,
+``S_C``; the float32 product is six passes of the MXU), so a program takes
+its heads ``_TRIP_HEADS`` at most a trip of a ``fori_loop`` and runs each of
+the step's three phases for all of a trip's heads before the next, what
+passes between phases staged in VMEM (``_for_trips``): the scheduler fills
+one head's waits with its neighbours'.  Transposed, the same grid counts a
+row's chunks from its end with the state's cotangent in the scratch, started
+from the final state's own (``layers.gdn`` returns the state); a program
+makes its chunk's ``U`` again from the saved state and writes what the
+rule's inputs need and nothing wider: ``dT`` (float32), ``d(M * Q K^T)``, dv
+a value head, dq and dk a key head, and the cotangents of ``gamma`` and
+``beta`` as rows (``_walk_transposed_body`` has the equations).  ``Hb`` is
+the largest divisor of the heads in whole groups whose blocks,
+double-buffered, fit the VMEM budget (``_head_block``: 16 of 32 heads at 64
+x 128 / 128 in bfloat16, 10 of 30 at 64 x 96 / 192).
 
 The backward pass is autodiff through this chunked form (the inverse
 brings its own cotangent, ``dA = -tril(T^T dT T^T, -1)``: two products a
-system; the walk its transposed kernel, or the scan's transpose under
-``jax.checkpoint``) with a ``jax.checkpoint`` around the terms: the walk
-saves each chunk's incoming state (``s / chunk`` x heads x d_k x d_v float32
-a row: 141 MB at 4,096 positions, 30 heads of 96 x 192; 268 MB at 8,192
-positions, 32 heads of 128 x 128) and the five terms it was given, in the
-inputs' dtype, and its transpose is again one walk over the chunks,
-backwards, that makes a chunk's ``U`` again; the terms' own intermediates
-(the decay matrices, ``A``, ``T``: float32, chunk x chunk a chunk and head)
-are made again from q, k, v, g and beta and not kept.  Without the two, one
-layer of 30 heads keeps 0.7 GB at 4,096 positions.
+system; ``d(M * Q K^T)`` goes on into q, k and the decays; the walk its
+transposed kernel, or the scan's transpose under ``jax.checkpoint``) with a
+``jax.checkpoint`` around the terms: the walk saves each chunk's incoming
+state (``s / chunk`` x heads x d_k x d_v float32 a row: 141 MB at 4,096
+positions, 30 heads of 96 x 192; 268 MB at 8,192 positions, 32 heads of 128
+x 128) and what it was given: ``T``, ``M * Q K^T`` and the gates' rows (103
+MB at the second shape, 48 at the first, where the five ``chunk`` x d terms
+it read before were 302 and 134) beside the chunked q, k and v, the rule's
+own inputs; its transpose is again one walk over the chunks, backwards,
+that makes a chunk's ``U`` again; the terms' own intermediates (the decay
+matrices, ``A``: float32, chunk x chunk a chunk and head) are made again
+from q, k, g and beta and not kept.  Without the two, one layer of 30 heads
+keeps 0.7 GB at 4,096 positions.
 """
 import functools
 
@@ -98,6 +124,9 @@ from autodist_tpu.utils import logging
 
 #: Positions a chunk: the rows of the matrix inverted and the walk's stride.
 CHUNK = 64
+WALK_TERMS = ("the walk reads T, M * Q K^T, q and k a key head, v and the "
+              "gates' rows and makes a chunk's U = T diag(beta) (V - "
+              "diag(exp gamma) K S_0) itself")
 BACKWARD = ("autodiff through the chunked form around the inverse's own "
             "cotangent and the walk's transpose (its kernel; off TPU the "
             "scan's); each chunk's incoming state and the walk's terms "
@@ -107,12 +136,19 @@ BACKWARD = ("autodiff through the chunked form around the inverse's own "
 _announced = set()
 
 
-def _announce(rows, s, heads, key_heads, d_k, d_v, chunk, head_block, why):
+def _announce(rows, s, heads, key_heads, d_k, d_v, chunk, head_block, why,
+              dtype):
     """Gauges and a ``gdn`` event for the rule being traced; the event and
     the log line are written once a process for each shape and form
     traced."""
     from autodist_tpu import observability
     chunks = -(-s // chunk)
+    item = jnp.dtype(dtype).itemsize
+    # What a row's forward walk reads from HBM, either form: T float32 and
+    # M * Q K^T a value head, q and k a key head, v and the two gates.
+    term_bytes = chunks * chunk * (
+        heads * chunk * (4 + item) + (2 * key_heads * d_k + heads * d_v) * item
+        + 2 * heads * 4)
     grouped = "" if key_heads == heads else (
         f", {heads // key_heads} value heads a key head ({key_heads} key "
         f"heads: K K^T and Q K^T once a key head)")
@@ -121,8 +157,8 @@ def _announce(rows, s, heads, key_heads, d_k, d_v, chunk, head_block, why):
     detail = (f"gated delta rule, chunked: ({rows}, {s}, {heads}, {d_k} / "
               f"{d_v}){grouped}, {chunks} chunks of {chunk} a row, state "
               f"{heads} x {d_k} x {d_v} float32; walk over the chunks: "
-              f"{walk} ({why}); inverse: {inverse_form(chunk)}; backward: "
-              f"{BACKWARD}")
+              f"{walk} ({why}); {WALK_TERMS}, {term_bytes} bytes a row; "
+              f"inverse: {inverse_form(chunk)}; backward: {BACKWARD}")
     new = detail not in _announced
     _announced.add(detail)
     if new:
@@ -136,6 +172,7 @@ def _announce(rows, s, heads, key_heads, d_k, d_v, chunk, head_block, why):
         registry.gauge("gdn.state_bytes_per_row").set(heads * d_k * d_v * 4)
         registry.gauge("gdn.scan_kernel").set(int(bool(head_block)))
         registry.gauge("gdn.scan_head_block").set(head_block)
+        registry.gauge("gdn.walk_term_bytes_per_row").set(term_bytes)
         if new:
             observability.record_event("gdn", detail)
 
@@ -169,9 +206,10 @@ def gated_delta_rule(q, k, v, g, beta, chunk=CHUNK, interpret=None):
             f"q and k {q.shape} / {k.shape} must hold key heads that divide "
             f"the {heads} heads of v {v.shape}, g {g.shape} and beta "
             f"{beta.shape}")
-    interpret, head_block, why = walk_form(interpret, heads, chunk, d_k, d_v,
-                                           q.dtype)
-    _announce(b, s, heads, key_heads, d_k, d_v, chunk, head_block, why)
+    interpret, head_block, why = walk_form(interpret, heads, key_heads, chunk,
+                                           d_k, d_v, q.dtype)
+    _announce(b, s, heads, key_heads, d_k, d_v, chunk, head_block, why,
+              q.dtype)
     return _chunked_rule(q, k, v, g, beta, chunk=chunk, interpret=interpret,
                          head_block=head_block)
 
@@ -185,7 +223,7 @@ def _chunked_rule(q, k, v, g, beta, chunk, interpret=None, head_block=0):
     tracing, PERF.md section 7, and the inverse's levels are operations more
     to trace than the solve they replace).  ``head_block`` heads a program
     of the kernels; 0: the scan."""
-    b, s, _, d_k = q.shape
+    b, s, key_heads, d_k = q.shape
     h, d_v = v.shape[2:]
     dtype = q.dtype
     pad = -s % chunk
@@ -199,22 +237,34 @@ def _chunked_rule(q, k, v, g, beta, chunk, interpret=None, head_block=0):
         t = t.reshape((b, n, chunk) + t.shape[2:])
         return jnp.moveaxis(jnp.moveaxis(t, 3, 1), 2, 0)
 
-    terms = jax.checkpoint(lambda *a: _chunk_terms(*a, dtype))(
-        *(chunked(t) for t in (q, k, v)),
-        *(chunked(t.astype(jnp.float32)) for t in (g, beta)))
-
-    def step(state, chunk_in):
-        w, u0, qk, q_in, k_out, carry = chunk_in
-        s0 = state.astype(dtype)
-        u = (u0 - _mm("bhik,bhkd->bhid", w, s0, dtype)).astype(dtype)
-        o = _mm("bhik,bhkd->bhid", q_in, s0, dtype) \
-            + _mm("bhij,bhjd->bhid", qk, u, dtype)
-        state = carry * state + _mm("bhik,bhid->bhkd", k_out, u, dtype)
-        return state, o.astype(dtype)
-
+    q, k, v = (chunked(t) for t in (q, k, v))
+    g, beta = (chunked(t.astype(jnp.float32)) for t in (g, beta))
+    t, qk, gamma = jax.checkpoint(lambda *a: _chunk_terms(*a, dtype))(
+        q, k, g, beta)
+    terms = (t, qk, q, k, v, gamma, beta)
     if head_block:
         state, o = _walk(*terms, head_block, interpret)
     else:
+        group = h // key_heads
+        # One head's step over the group's heads (q and k their key head's),
+        # the key heads and the rows.
+        heads = jax.vmap(jax.vmap(jax.vmap(
+            _head_step, in_axes=(0, 0, 0, None, None) + (0,) * 5)))
+
+        def grouped(x):     # (b, h, ...) -> (b, key heads, group, ...)
+            return x.reshape((b, key_heads, group) + x.shape[2:])
+
+        def step(state, chunk_in):
+            t, qk, q, k, v, gamma, beta = chunk_in
+            end = gamma[..., -1:]
+            state, o = heads(
+                grouped(state), grouped(t), grouped(qk), q, k, grouped(v),
+                grouped(beta[..., None, :]), grouped(jnp.exp(gamma)[..., None]),
+                grouped(jnp.exp(end - gamma)[..., None]),
+                grouped(jnp.exp(end)[..., None]))
+            return (state.reshape((b, h) + state.shape[3:]),
+                    o.reshape((b, h) + o.shape[3:]))
+
         state, o = lax.scan(jax.checkpoint(step),
                             jnp.zeros((b, h, d_k, d_v), jnp.float32), terms)
     # (n, b, h, chunk, d_v) -> (b, s, h, d_v)
@@ -223,38 +273,179 @@ def _chunked_rule(q, k, v, g, beta, chunk, interpret=None, head_block=0):
     return o[:, :s], state
 
 
+# -- one chunk of one head: the step both walks run, and what turns the
+# -- kernels' rows of gates into its columns ----------------------------------
+
+def _dot32(a, b, contract_a, contract_b):
+    """``_dot`` of float32 operands as a float32 product (``HIGHEST``: the
+    MXU's passes over the operands' bfloat16 parts; in a kernel Mosaic's
+    ``contract_precision<fp32>``), for the products ``T`` is part of."""
+    rows = tuple(range(a.ndim - 2))
+    return lax.dot_general(
+        a, b, (((a.ndim + contract_a,), (b.ndim + contract_b,)),
+               (rows, rows)), precision=lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
+def _masks(c):
+    """``(the (c, c) identity's mask, the (1, c) mask of the last lane)``:
+    what turns a row into a column and picks ``gamma_C``; made once a
+    kernel, outside its loops."""
+    lane = lax.broadcasted_iota(jnp.int32, (1, c), 1)
+    return lax.broadcasted_iota(jnp.int32, (c, c), 0) == lane, lane == c - 1
+
+
+def _column(row, diagonal):
+    """A (1, c) row as a (c, 1) column, exactly: the row under the
+    identity's mask, summed along the lanes.  The gates come to the kernels
+    as 64-lane rows (a ``(c, 1)`` float32 array is 128 lanes a value in HBM
+    and in VMEM) and scale a chunk's rows as a column."""
+    return jnp.sum(jnp.where(diagonal, row, 0.0), axis=1, keepdims=True)
+
+
+def _row(column, diagonal):
+    """A (c, 1) column as a (1, c) row, exactly (``_column``'s inverse)."""
+    return jnp.sum(jnp.where(diagonal, column, 0.0), axis=0, keepdims=True)
+
+
+def _into(gamma, masks):
+    """``exp(gamma)`` as a (c, 1) column, of a chunk's (1, c) row of summed
+    log decays: the decay since ``S_0``."""
+    return jnp.exp(_column(gamma, masks[0]))
+
+
+def _decays(gamma, masks):
+    """``_into``, the decay to ``S_C`` ``exp(gamma_C - gamma)`` (c, 1) and
+    ``exp(gamma_C)`` (1, 1)."""
+    diagonal, last = masks
+    column = _column(gamma, diagonal)
+    end = jnp.sum(jnp.where(last, gamma, 0.0), axis=1, keepdims=True)
+    return jnp.exp(column), jnp.exp(end - column), jnp.exp(end)
+
+
+def _chunk_reads(k, v, s0, into):
+    """``(K S_0, V - diag(exp gamma) K S_0)`` in float32: what a chunk's
+    positions read of the state it starts from, ``s0`` in the inputs'
+    dtype."""
+    ks = _dot(k, s0, -1, -2)
+    return ks, v - into * ks
+
+
+def _chunk_writes(t, beta, rhs):
+    """``U = T diag(beta) (V - diag(exp gamma) K S_0)`` in float32, what a
+    chunk's positions really write: the one product with ``T``, float32
+    operands (rounding the inverse to bf16 is the rule's largest error: the
+    hybrid cell's reference check read 7.0e-5 with it and 4.4e-5 without);
+    ``beta`` the (1, c) row that scales ``T``'s columns."""
+    return _dot32(t * beta, rhs, -1, -2)
+
+
+def _chunk_leaves(state, qk, k, u, o_s0, to_end, carry):
+    """``(S_C, O)`` from a chunk's ``U`` (inputs' dtype) and ``o_s0 =
+    diag(exp gamma) Q S_0``."""
+    o = o_s0 + _dot(qk, u, -1, -2)
+    state = carry * state + _dot((to_end * k).astype(u.dtype), u, -2, -2)
+    return state, o.astype(u.dtype)
+
+
+def _head_step(state, t, qk, q, k, v, beta, into, to_end, carry):
+    """``(S_C, O)`` of one chunk of one head from the state ``S_0`` (d_k,
+    d_v) float32 it starts from: ``t`` (c, c) float32, ``qk`` = ``M * Q
+    K^T`` (c, c), ``q``, ``k`` (c, d_k) and ``v`` (c, d_v) in the inputs'
+    dtype, ``beta`` a (1, c) float32 row, the decays since ``S_0`` and to
+    ``S_C`` as (c, 1) float32 columns ``into`` = ``exp(gamma)`` and
+    ``to_end`` = ``exp(gamma_C - gamma)``, ``carry`` = ``exp(gamma_C)`` (1,
+    1).  The module docstring's equations as they stand, ``W`` and ``U_0``
+    never formed: under ``vmap`` the scan's step, and the three functions
+    it is made of are the three phases of the kernels' forward body."""
+    dtype = v.dtype
+    s0 = state.astype(dtype)
+    u = _chunk_writes(t, beta, _chunk_reads(k, v, s0, into)[1]).astype(dtype)
+    return _chunk_leaves(state, qk, k, u, into * _dot(q, s0, -1, -2), to_end,
+                         carry)
+
+
 # -- the walk over the chunks as two Pallas kernels ---------------------------
 
 # Mosaic's scoped VMEM limit is 16 MiB a kernel on the v5e; the padded
 # estimate leaves out a program's own values (``ops/flash_attention.py``).
 _VMEM_BUDGET = 12 * 2 ** 20
+# Value heads a trip of a program's loop over its heads, at most: a head's
+# products wait on one another (S_0 -> K S_0 -> U -> O, S_C, the float32
+# product six passes of the MXU), so a trip runs each phase for all its
+# heads, what passes from phase to phase staged in VMEM, and the scheduler
+# fills one head's waits with its neighbours' products.  By the LLO dump at
+# 64 x 128 / 128 the forward trip is 304 bundles a head at 2 heads, 220 at
+# 4, 195 at 8, where the MXU's slots are 87% taken.
+_TRIP_HEADS = 8
 
 
-def _head_block(heads, chunk, d_k, d_v, dtype):
-    """Heads a program: the largest divisor of ``heads`` whose blocks,
-    double-buffered, and state scratch stay within ``_VMEM_BUDGET`` in the
-    transposed kernel, which holds the most: the five terms and the decay
-    in and their cotangents out, the saved state, ``o``'s cotangent and the
-    final state's (at 64 x 128 / 128 in bfloat16 0.58 MB a head: 16 of 32
-    heads; at 64 x 96 / 192 0.84 MB: 10 of 30)."""
-    terms = [((chunk, d_k), dtype), ((chunk, d_v), dtype),
-             ((chunk, chunk), dtype), ((chunk, d_k), dtype),
-             ((chunk, d_k), dtype), ((1, 1), jnp.float32)]
-    state = ((d_k, d_v), jnp.float32)
-    blocks = 2 * terms + [state, ((chunk, d_v), dtype), state]
-    a_head = (2 * sum(_padded_bytes(*b) for b in blocks)
-              + _padded_bytes(*state))
-    return max((n for n in range(1, heads + 1)
-                if heads % n == 0 and n * a_head <= _VMEM_BUDGET), default=0)
+def _walk_vmem(heads, trip, group, chunk, d_k, d_v, dtype, transposed):
+    """The padded VMEM bytes of a walk's program of ``heads`` value heads:
+    its blocks double-buffered (``T``, ``M * Q K^T``, v, the gates' rows and
+    a key head's q and k in; forward ``o``, the saved state and the final
+    state out; transposed the saved state, ``o``'s cotangent and the final
+    state's in and the seven cotangents out), the state scratch and the
+    staging of a trip of ``trip`` heads (forward ``R = V - diag(exp gamma) K
+    S_0``, ``diag(exp gamma) Q S_0`` and ``U``; transposed ``R``, ``K
+    S_0``, ``dU``, ``U``, ``d(K S_0)`` and the sums of dq and dk)."""
+    state, wide = ((heads, d_k, d_v), jnp.float32), ((heads, chunk, d_v), dtype)
+    terms = [((heads, chunk, chunk), jnp.float32),
+             ((heads, chunk, chunk), dtype),
+             ((heads // group, chunk, d_k), dtype),
+             ((heads // group, chunk, d_k), dtype), wide,
+             ((heads, chunk), jnp.float32), ((heads, chunk), jnp.float32)]
+    blocks = terms + [wide, state, state] + (terms if transposed else [])
+    return (2 * sum(_padded_bytes(*b) for b in blocks)
+            + _padded_bytes(*state)
+            + sum(_padded_bytes(*b) for b in _stages(
+                trip, chunk, d_k, d_v, dtype, transposed)))
 
 
-def walk_form(interpret, heads, chunk, d_k, d_v, dtype):
+def _stages(trip, chunk, d_k, d_v, dtype, transposed):
+    """The staging scratch of a trip's heads, ``(shape, dtype)``, in the
+    order the bodies take it."""
+    wide, key = (trip, chunk, d_v), (trip, chunk, d_k)
+    if not transposed:
+        return [(wide, jnp.float32), (wide, jnp.float32), (wide, dtype)]
+    return [(wide, jnp.float32)] * 3 + [(wide, dtype)] * 2 \
+        + [(key, jnp.float32)] * 2
+
+
+def _trip_heads(heads, group, chunk, d_k, d_v, dtype, transposed):
+    """Value heads a trip of a program's loop over its ``heads``: whole
+    groups, a divisor of ``heads``, ``_TRIP_HEADS`` at most (one group where
+    a group is more), as many as leave the program within ``_VMEM_BUDGET``
+    (8 of 16 forward and 4 transposed at 64 x 128 / 128 in bfloat16, 5 of
+    10 both ways at 64 x 96 / 192)."""
+    return max(n for n in range(group, max(group, _TRIP_HEADS) + 1, group)
+               if heads % n == 0 and (n == group or _walk_vmem(
+                   heads, n, group, chunk, d_k, d_v, dtype, transposed)
+                   <= _VMEM_BUDGET))
+
+
+def _head_block(heads, key_heads, chunk, d_k, d_v, dtype):
+    """Heads a program: the largest divisor of ``heads`` in whole groups of
+    ``heads / key_heads`` (a program reads its heads' key heads whole) that
+    ``_walk_vmem`` finds within ``_VMEM_BUDGET`` in the transposed kernel,
+    which holds the most, at a trip of one group (at 64 x 128 / 128 in
+    bfloat16, two heads a key head, 0.66 MB a head: 16 of 32 heads; at 64 x
+    96 / 192 0.97 MB: 10 of 30)."""
+    group = heads // key_heads
+    return max((n for n in range(group, heads + 1, group)
+                if heads % n == 0 and _walk_vmem(
+                    n, group, group, chunk, d_k, d_v, dtype, True)
+                <= _VMEM_BUDGET), default=0)
+
+
+def walk_form(interpret, heads, key_heads, chunk, d_k, d_v, dtype):
     """``(interpret, heads a program, why)``: how a trace walks a row's
     chunks.  The kernels (``interpret`` False, or True where a test asked
     for the Pallas interpreter) on a TPU backend with no mesh axis left to
     the partitioner (Mosaic kernels cannot be partitioned automatically),
-    for a chunk of whole 8-row tiles whose blocks of one head fit the
-    budget; ``(None, 0, why)``, the ``lax.scan``, anywhere else."""
+    for a chunk of whole 8-row tiles where the blocks of one key head's
+    group of value heads fit the budget; ``(None, 0, why)``, the
+    ``lax.scan``, anywhere else."""
     if interpret is None:
         backend = jax.default_backend()
         if backend != "tpu":
@@ -263,142 +454,279 @@ def walk_form(interpret, heads, chunk, d_k, d_v, dtype):
             return None, 0, "a mesh axis is left to the partitioner"
     if chunk % 8:
         return None, 0, f"a chunk of {chunk} is no multiple of 8"
-    head_block = _head_block(heads, chunk, d_k, d_v, dtype)
+    head_block = _head_block(heads, key_heads, chunk, d_k, d_v, dtype)
     if not head_block:
-        return None, 0, (f"one head's blocks at {chunk} x {d_k} / {d_v} pass "
+        return None, 0, (f"the blocks of {heads // key_heads} head(s) a key "
+                         f"head at {chunk} x {d_k} / {d_v} pass "
                          f"{_VMEM_BUDGET} bytes of VMEM")
     return bool(interpret), head_block, (
         "interpret=True requested" if interpret else
         "backend is tpu" if interpret is None else "interpret=False requested")
 
 
-def _walk_forward_body(w_ref, u0_ref, qk_ref, q_ref, k_ref, carry_ref, o_ref,
-                       *rest, heads):
-    """One chunk of ``heads`` heads: the scan's step, the state in the VMEM
-    scratch (last of ``rest``) from the row's first chunk to its last, where
-    it leaves as the row's final state; ``rest`` begins with the block the
-    chunk's incoming state is saved to where a backward pass will read
-    it."""
-    *saved, final_ref, state_ref = rest
+def _for_trips(heads, group, trip, phases):
+    """``phases`` over a program's ``heads`` value heads, ``trip`` of them a
+    trip of one ``fori_loop``: in a trip each phase runs for every one of
+    its heads before the next begins, as a loop of its own that is unrolled
+    where the kernel is lowered and not where it is traced (a body written
+    out for 16 or 10 heads, or a phase for 8, is seconds of tracing in the
+    benchmark's process).  A phase is ``(function of (key head, value head,
+    staging slot), stride)``: a slot is the head's place in its trip, and a
+    stride of ``group`` runs the function once a key head, on its first
+    value head."""
+    def run(i, carry):
+        for phase, stride in phases:
+            def head(n, carry, phase=phase, stride=stride):
+                a = n * stride
+                # The slot's key head (``//`` of a traced index is a dozen
+                # operations to lower).
+                j = n if stride == group else lax.div(a, group)
+                phase(i * (trip // group) + j, i * trip + a, a)
+                return carry
+            lax.fori_loop(0, trip // stride, head, 0, unroll=True)
+        return carry
+    lax.fori_loop(0, heads // trip, run, 0)
+
+
+def _gate(ref, h):
+    """Value head ``h``'s (1, chunk) row of a program's block of gates."""
+    return ref[0, pl.ds(h, 1), :]
+
+
+def _walk_forward_body(t_ref, qk_ref, q_ref, k_ref, v_ref, gamma_ref,
+                       beta_ref, o_ref, *rest, group, trip):
+    """One chunk of a program's heads: ``_head_step``, a phase at a time
+    over a trip's heads, the state in the VMEM scratch ``state_ref`` from
+    the row's first chunk to its last, where it leaves as the row's final
+    state; ``rest`` begins with the block the chunk's incoming state is
+    saved to where a backward pass will read it."""
+    *saved, final_ref, state_ref, rhs_ref, o_s0_ref, u_ref = rest
     c = pl.program_id(2)
+    dtype = v_ref.dtype
+    masks = _masks(t_ref.shape[-1])
 
     @pl.when(c == 0)
     def _():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    dtype = w_ref.dtype
-    for h in range(heads):
+    def reads(j, h, a):         # what the chunk reads of S_0
+        into = _into(_gate(gamma_ref, h), masks)
         state = state_ref[h]
         if saved:
             saved[0][h] = state
         s0 = state.astype(dtype)
-        u = (u0_ref[h] - _dot(w_ref[h], s0, -1, -2)).astype(dtype)
-        o_ref[h] = (_dot(q_ref[h], s0, -1, -2)
-                    + _dot(qk_ref[h], u, -1, -2)).astype(o_ref.dtype)
-        state_ref[h] = carry_ref[h] * state + _dot(k_ref[h], u, -2, -2)
+        rhs_ref[a] = _chunk_reads(k_ref[j], v_ref[h], s0, into)[1]
+        o_s0_ref[a] = into * _dot(q_ref[j], s0, -1, -2)
+
+    def writes(j, h, a):        # what its positions write
+        u_ref[a] = _chunk_writes(t_ref[h], _gate(beta_ref, h),
+                                 rhs_ref[a]).astype(dtype)
+
+    def leaves(j, h, a):        # what it leaves
+        _, to_end, carry = _decays(_gate(gamma_ref, h), masks)
+        state_ref[h], o_ref[h] = _chunk_leaves(
+            state_ref[h], qk_ref[h], k_ref[j], u_ref[a], o_s0_ref[a], to_end,
+            carry)
+
+    _for_trips(state_ref.shape[0], group, trip,
+               ((reads, 1), (writes, 1), (leaves, 1)))
 
     @pl.when(c == pl.num_programs(2) - 1)
     def _():
         final_ref[...] = state_ref[...]
 
 
-def _walk_transposed_body(w_ref, u0_ref, qk_ref, q_ref, k_ref, carry_ref,
-                          saved_ref, do_ref, dfinal_ref, dw_ref, du0_ref,
-                          dqk_ref, dq_ref, dk_ref, dcarry_ref, dstate_ref, *,
-                          heads):
-    """The step's transpose for one chunk of ``heads`` heads, the grid's
-    last index counting the chunks from a row's end: the cotangent of the
-    state the chunk leaves is in the VMEM scratch, started from the final
-    state's; the chunk's ``U`` is made again from its saved incoming
-    state."""
+def _walk_transposed_body(t_ref, qk_ref, q_ref, k_ref, v_ref, gamma_ref,
+                          beta_ref, saved_ref, do_ref, dfinal_ref, dt_ref,
+                          dqk_ref, dq_ref, dk_ref, dv_ref, dgamma_ref,
+                          dbeta_ref, dstate_ref, rhs_ref, ks_ref, du_ref,
+                          u_ref, dks_ref, dq_sum, dk_sum, *, group, trip):
+    """``_head_step``'s transpose for one chunk of a program's heads, the
+    grid's last index counting the chunks from a row's end: the cotangent
+    ``dS`` of the state the chunk leaves is in the VMEM scratch, started
+    from the final state's, and ``U`` is made again from the chunk's saved
+    incoming state.  With ``R = V - diag(exp gamma) K S_0`` and ``K~ =
+    diag(exp(gamma_C - gamma)) K``::
+
+        d(M * Q K^T) = dO U^T        dU = (M * Q K^T)^T dO + K~ dS
+        dq = diag(exp gamma) dO S_0^T        dK~ = U dS^T
+        d(T diag(beta)) = dU R^T     dR = (T diag(beta))^T dU = dv
+        d(K S_0) = -diag(exp gamma) dR
+        dk = diag(exp(gamma_C - gamma)) dK~ + d(K S_0) S_0^T
+        dS_0 = exp(gamma_C) dS + (diag(exp gamma) Q)^T dO + K^T d(K S_0)
+
+    the two with ``T`` and ``dT``'s float32 operands, float32 sums
+    everywhere; dq and dk summed over a key head's ``group`` value heads
+    here, and the gates' cotangents as rows: ``dbeta`` the column sums of
+    ``d(T diag(beta)) * T``, ``dgamma`` row sums of products made here
+    anyway (of ``dO S_0^T * Q``, ``dR * K S_0``, ``dK~ * K~``), ``gamma_C``'s
+    (with ``<dS, S_0>``) on ``gamma``'s last entry."""
+    dtype = v_ref.dtype
+    masks = diagonal, last = _masks(t_ref.shape[-1])
+
     @pl.when(pl.program_id(2) == 0)
     def _():
         dstate_ref[...] = dfinal_ref[...]
 
-    dtype = w_ref.dtype
-    for h in range(heads):
+    def reads(j, h, a):         # U again: K S_0 and R
+        into = _into(_gate(gamma_ref, h), masks)
+        ks_ref[a], rhs_ref[a] = _chunk_reads(
+            k_ref[j], v_ref[h], saved_ref[h].astype(dtype), into)
+
+    def writes(j, h, a):
+        u_ref[a] = _chunk_writes(t_ref[h], _gate(beta_ref, h),
+                                 rhs_ref[a]).astype(dtype)
+
+    def cotangents(j, h, a):    # what meets dO and dS
+        into, to_end, carry = _decays(_gate(gamma_ref, h), masks)
         state, dstate = saved_ref[h], dstate_ref[h]
-        w, qk, q_in, k_out, do = (ref[h] for ref in (w_ref, qk_ref, q_ref,
-                                                     k_ref, do_ref))
         s0, ds = state.astype(dtype), dstate.astype(dtype)
-        u = (u0_ref[h] - _dot(w, s0, -1, -2)).astype(dtype)
-        dq_ref[h] = _dot(do, s0, -1, -1).astype(dtype)          # do S^T
-        dqk_ref[h] = _dot(do, u, -1, -1).astype(dtype)          # do U^T
-        dk_ref[h] = _dot(u, ds, -1, -1).astype(dtype)           # U dS^T
-        du = _dot(qk, do, -2, -2) + _dot(k_out, ds, -1, -2)
-        du0_ref[h] = du.astype(dtype)
-        du = du.astype(dtype)
-        dw_ref[h] = (-_dot(du, s0, -1, -1)).astype(dtype)       # -dU S^T
-        dcarry_ref[h] = jnp.sum(dstate * state, axis=(0, 1), keepdims=True)
-        dstate_ref[h] = (carry_ref[h] * dstate + _dot(q_in, do, -2, -2)
-                         - _dot(w, du, -2, -2))
+        q, k, u, do = q_ref[j], k_ref[j], u_ref[a], do_ref[h]
+        dqk_ref[h] = _dot(do, u, -1, -1).astype(dqk_ref.dtype)
+        du_ref[a] = _dot(qk_ref[h], do, -2, -2) \
+            + _dot((to_end * k).astype(dtype), ds, -1, -2)
+        dk_out = _dot(u, ds, -1, -1)
+        dq = _dot(do, s0, -1, -1)
+        dq_sum[a], dk_sum[a] = into * dq, to_end * dk_out
+        dto_end = jnp.sum(dk_out * k, axis=1, keepdims=True) * to_end
+        dend = jnp.sum(dto_end, axis=0, keepdims=True) \
+            + carry * jnp.sum(dstate * state, axis=(0, 1), keepdims=True)
+        dgamma_ref[0, pl.ds(h, 1), :] = _row(
+            jnp.sum(dq * q, axis=1, keepdims=True) * into - dto_end,
+            diagonal) + jnp.where(last, dend, 0.0)
+
+    def through_writes(j, h, a):        # through U = T diag(beta) R
+        into = _into(_gate(gamma_ref, h), masks)
+        t, beta, du = t_ref[h], _gate(beta_ref, h), du_ref[a]
+        dtb = _dot32(du, rhs_ref[a], -1, -1)
+        dt_ref[h] = dtb * beta
+        dbeta_ref[0, pl.ds(h, 1), :] = jnp.sum(dtb * t, axis=0, keepdims=True)
+        drhs = _dot32(t * beta, du, -2, -2)
+        dv_ref[h] = drhs.astype(dv_ref.dtype)
+        dks_ref[a] = (-into * drhs).astype(dtype)
+        dgamma_ref[0, pl.ds(h, 1), :] -= _row(
+            jnp.sum(drhs * ks_ref[a], axis=1, keepdims=True) * into, diagonal)
+
+    def through_reads(j, h, a):         # through K S_0, and on to dS_0
+        into, _, carry = _decays(_gate(gamma_ref, h), masks)
+        q, k, dks = q_ref[j], k_ref[j], dks_ref[a]
+        dk_sum[a] += _dot(dks, saved_ref[h].astype(dtype), -1, -1)
+        dstate_ref[h] = (carry * dstate_ref[h]
+                         + _dot((into * q).astype(dtype), do_ref[h], -2, -2)
+                         + _dot(k, dks, -2, -2))
+
+    def sums(j, h, a):          # a key head's, from its first value head
+        dq_ref[j] = sum(dq_sum[a + i] for i in range(group)) \
+            .astype(dq_ref.dtype)
+        dk_ref[j] = sum(dk_sum[a + i] for i in range(group)) \
+            .astype(dk_ref.dtype)
+
+    _for_trips(dstate_ref.shape[0], group, trip, (
+        (reads, 1), (writes, 1), (cotangents, 1), (through_writes, 1),
+        (through_reads, 1), (sums, group)))
 
 
 def _walk_call(body, name, ins, outs, heads, reverse, interpret):
     """``pallas_call`` of a walk: grid (batch, head blocks, chunks), the
     chunks in order (from a row's end where ``reverse``), on ``ins`` and for
-    ``outs`` (ShapeDtypeStructs).  Of a stacked array ``(n, b, h, ...)`` a
-    block is the chunk's rows of ``heads`` heads; of a row's one state ``(b,
-    h, d_k, d_v)`` it is those heads', the same for every chunk."""
-    n, b, h = ins[0].shape[:3]
+    ``outs`` (ShapeDtypeStructs).  Of a stacked array ``(n, b, x, ...)`` a
+    block is the chunk's rows of a program's share of ``x``: ``heads`` of
+    the value heads, their key heads of q and k (the same block index on
+    fewer heads: no repeat), the one block of ``heads`` rows of the gates
+    ``(n, b, blocks, heads, chunk)``; of a row's one state ``(b, h, d_k,
+    d_v)`` it is those heads', the same for every chunk."""
+    t, _, q, _, v = ins[:5]
+    n, b, h = t.shape[:3]
+    blocks, group = h // heads, h // q.shape[2]
+    chunk, d_k, d_v = t.shape[-1], q.shape[-1], v.shape[-1]
+    trip = _trip_heads(heads, group, chunk, d_k, d_v, v.dtype, reverse)
 
     def spec(x):
         if x.ndim == 4:
             return pl.BlockSpec((None, heads) + x.shape[2:],
                                 lambda i, j, c: (i, j, 0, 0))
         return pl.BlockSpec(
-            (None, None, heads) + x.shape[3:],
+            (None, None, x.shape[2] // blocks) + x.shape[3:],
             lambda i, j, c: ((n - 1 - c if reverse else c), i, j, 0, 0))
 
     return pl.pallas_call(
-        functools.partial(body, heads=heads),
-        grid=(b, h // heads, n),
+        functools.partial(body, group=group, trip=trip),
+        grid=(b, blocks, n),
         in_specs=[spec(x) for x in ins], out_specs=[spec(x) for x in outs],
         out_shape=outs,
-        scratch_shapes=[pltpu.VMEM((heads,) + ins[0].shape[-1:]
-                                   + ins[1].shape[-1:], jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((heads, d_k, d_v), jnp.float32)] + [
+            pltpu.VMEM(*stage) for stage in _stages(trip, chunk, d_k, d_v,
+                                                    v.dtype, reverse)],
         # Only the walk over the chunks carries the scratch.
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret, name=name)(*ins)
 
 
+def _gate_rows(x, heads):
+    """(n, b, h, chunk) -> (n, b, h / heads, heads, chunk): a program's gates
+    one whole block of 64-lane rows."""
+    n, b, h, chunk = x.shape
+    return x.reshape(n, b, h // heads, heads, chunk)
+
+
+# Inlined ``jit``s, as ``_chunked_rule`` is and for its reason: a layer's
+# backward pass traces the forward rule and the transposed walk apart from
+# ``_chunked_rule``'s equations, and with these every layer after the first
+# takes the first's kernel, traced once and lowered to Mosaic once.
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("head_block", "interpret", "save"))
 def _walk_forward(terms, head_block, interpret, save):
     """``(final state, o, each chunk's incoming state or None)``."""
-    w, u0 = terms[:2]
-    n, b, h, _, d_k = w.shape
-    state = _sds((b, h, d_k, u0.shape[-1]), jnp.float32, *terms)
-    outs = [_sds(u0.shape, u0.dtype, *terms)]
+    t, _, q, _, v, gamma, beta = terms
+    n, b, h = t.shape[:3]
+    ins = terms[:5] + (_gate_rows(gamma, head_block),
+                       _gate_rows(beta, head_block))
+    state = _sds((b, h, q.shape[-1], v.shape[-1]), jnp.float32, *terms)
+    outs = [_sds(v.shape, v.dtype, *terms)]
     if save:
         outs.append(_sds((n,) + state.shape, jnp.float32, *terms))
     o, *saved, final = _walk_call(
-        _walk_forward_body, "gdn_walk_fwd", terms, outs + [state],
-        head_block, False, interpret)
+        _walk_forward_body, "gdn_walk_fwd", ins, outs + [state], head_block,
+        False, interpret)
     return final, o, (saved[0] if save else None)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _walk(w, u0, qk, q_in, k_out, carry, head_block, interpret):
-    """``(final state, o)`` of the scan over the chunks, as kernels: terms
-    ``(n, b, h, chunk, ...)`` as ``_chunk_terms`` makes them."""
-    return _walk_forward((w, u0, qk, q_in, k_out, carry), head_block,
-                         interpret, save=False)[:2]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _walk(t, qk, q, k, v, gamma, beta, head_block, interpret):
+    """``(final state, o)`` of the scan over the chunks, as kernels: the
+    stacked terms as ``_chunk_terms`` and ``chunked`` leave them, ``t`` and
+    ``qk`` ``(n, b, h, chunk, chunk)``, q and k ``(n, b, key heads, chunk,
+    d_k)``, v ``(n, b, h, chunk, d_v)``, ``gamma`` and ``beta`` ``(n, b, h,
+    chunk)``."""
+    return _walk_forward((t, qk, q, k, v, gamma, beta), head_block, interpret,
+                         save=False)[:2]
 
 
-def _walk_fwd(w, u0, qk, q_in, k_out, carry, head_block, interpret):
-    terms = (w, u0, qk, q_in, k_out, carry)
+def _walk_fwd(t, qk, q, k, v, gamma, beta, head_block, interpret):
+    terms = (t, qk, q, k, v, gamma, beta)
     final, o, saved = _walk_forward(terms, head_block, interpret, save=True)
     return (final, o), (terms, saved)
 
 
 def _walk_bwd(head_block, interpret, res, cotangents):
-    terms, saved = res
-    dfinal, do = cotangents
-    ins = terms + (saved, do, dfinal)
-    return tuple(_walk_call(
+    return _walk_transposed(*res, *cotangents, head_block=head_block,
+                            interpret=interpret)
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("head_block", "interpret"))
+def _walk_transposed(terms, saved, dfinal, do, head_block, interpret):
+    """The cotangents of ``_walk``'s seven terms from those of its final
+    state and ``o``."""
+    rows = [_gate_rows(x, head_block) for x in terms[5:]]
+    ins = terms[:5] + tuple(rows) + (saved, do, dfinal)
+    *dterms, dgamma, dbeta = _walk_call(
         _walk_transposed_body, "gdn_walk_bwd", ins,
-        [_sds(t.shape, t.dtype, *ins) for t in terms], head_block, True,
-        interpret))
+        [_sds(x.shape, x.dtype, *ins) for x in terms[:5] + tuple(rows)],
+        head_block, True, interpret)
+    return (*dterms, dgamma.reshape(terms[5].shape),
+            dbeta.reshape(terms[6].shape))
 
 
 # optimize_remat: under ``jax.checkpoint`` the forward pass proper runs
@@ -408,15 +736,18 @@ def _walk_bwd(head_block, interpret, res, cotangents):
 _walk.defvjp(_walk_fwd, _walk_bwd, optimize_remat=True)
 
 
-def _chunk_terms(q, k, v, g, beta, dtype):
-    """What the scan over the chunks takes, for every chunk at once: ``W``,
-    ``U_0``, ``M * Q K^T``, ``diag(exp gamma) Q`` and ``diag(exp(gamma_C -
-    gamma)) K`` in ``dtype``, and ``exp(gamma_C)`` in float32.  ``v`` is (n,
-    b, h, chunk, d_v), ``g`` and ``beta`` (n, b, h, chunk) in float32, ``q``
-    and ``k`` (n, b, key heads, chunk, d_k): what is computed from them alone
-    is computed a key head and met by its value heads as a broadcast."""
+def _chunk_terms(q, k, g, beta, dtype):
+    """What of a chunk is made for every chunk at once, the ``chunk`` x
+    ``chunk`` matrices a value head: ``T = (I + A)^-1`` in float32, ``M * Q
+    K^T`` in ``dtype`` and ``gamma``, the log decays summed from the chunk's
+    start, float32 (n, b, h, chunk).  ``g`` and ``beta`` are (n, b, h,
+    chunk) in float32, ``q`` and ``k`` (n, b, key heads, chunk, d_k): ``K
+    K^T`` and ``Q K^T`` are computed a key head and met by its value heads'
+    decays as a broadcast.  Either walk takes these with q, k, v and
+    ``beta`` as they are and makes the ``chunk`` x d terms a chunk at a
+    time (``_head_step``)."""
     chunk = g.shape[-1]
-    group = v.shape[2] // k.shape[2]
+    group = g.shape[2] // k.shape[2]
 
     def of_value_heads(x):      # (n, b, key heads, ...) -> (n, b, h, ...)
         return x if group == 1 else jnp.repeat(x, group, axis=2)
@@ -430,20 +761,8 @@ def _chunk_terms(q, k, v, g, beta, dtype):
                   0.0)
     with jax.named_scope("inverse"):
         t = unit_lower_inverse(a)
-    into = jnp.exp(gamma)[..., None]                      # decay since S_0
-    # T's two products stay float32, exact: rounding the inverse to bf16 is
-    # the rule's largest error (the reference check read 7.0e-5 with it and
-    # 4.4e-5 without), and at chunk x chunk a chunk and head they are cheap.
-    w, u0 = (jnp.einsum("nbhij,nbhjd->nbhid", t, rhs,
-                        precision=lax.Precision.HIGHEST)
-             for rhs in ((beta[..., None] * into) * of_value_heads(k),
-                         beta[..., None] * v))
     qk = decay * of_value_heads(_mm("nbhid,nbhjd->nbhij", q, k, dtype))
-    to_end = jnp.exp(gamma[..., -1:] - gamma)[..., None]  # decay to S_C
-    carry = jnp.exp(gamma[..., -1])[..., None, None]      # (n, b, h, 1, 1)
-    return tuple(x.astype(dtype) for x in (
-        w, u0, qk, into * of_value_heads(q),
-        to_end * of_value_heads(k))) + (carry,)
+    return t, qk.astype(dtype), gamma
 
 
 def inverse_form(chunk):

@@ -6,8 +6,11 @@ up to 2; rows that must not mix; bf16 operands within a stated tolerance.
 A chunk's inverse by block products against a float64 inverse and against
 the triangular solve it replaces, its closed cotangent against autodiff
 through the solve, and which of the two a chunk's size takes.  The walk over
-the chunks as Pallas kernels, interpreted, against the ``lax.scan``: outputs,
-final state and the five gradients with a cotangent on the final state."""
+the chunks as Pallas kernels, interpreted, against the ``lax.scan`` and
+against the rule one position at a time, one, two and four value heads a key
+head: outputs, final state and the five gradients with a cotangent on the
+final state; what the kernels are handed (no ``chunk`` x d term a value
+head, q and k a key head) and how many heads a program and a trip take."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,7 +24,10 @@ HEADS, D_K, D_V = 3, 8, 12
 
 def recurrent_gated_delta_rule(q, k, v, g, beta):
     """The rule one position at a time, in float32, as the module's
-    docstring writes it: ``(o, final state)``."""
+    docstring writes it: ``(o, final state)``; with fewer key heads than
+    value heads, q and k repeated to them."""
+    group = v.shape[2] // q.shape[2]
+    q, k = (jnp.repeat(t, group, axis=2) for t in (q, k))
     b, s, h, d_k = q.shape
     q, k, v, g, beta = (jnp.moveaxis(t.astype(jnp.float32), 1, 0)
                         for t in (q, k, v, g, beta))
@@ -237,90 +243,198 @@ def test_no_triangular_solve_is_traced_for_a_power_of_two(chunk, solves):
      (1, 48, 16, 6, 6, 8, 8, 3),        # heads no multiple of 8, 3 a program
      (1, 64, 16, 4, 4, 8, 24, 2),       # d_k != d_v
      (1, 50, 16, 4, 2, 16, 8, 4),       # a length the chunk does not divide
-     (2, 40, 8, 6, 3, 8, 12, 6)],       # two rows
+     (2, 40, 8, 6, 3, 8, 12, 6),        # two rows
+     (1, 64, 16, 8, 2, 8, 8, 4),        # four a key head, one key head a
+     (1, 48, 16, 8, 4, 8, 12, 4)],      # program; two key heads a program
     ids=["equal-heads", "grouped", "six-heads-by-three", "dk-not-dv",
-         "padded-length", "two-rows"])
+         "padded-length", "two-rows", "group-of-four", "two-groups-a-program"])
 def test_the_interpreted_kernels_are_the_scan(
         rows, s, chunk, heads, key_heads, d_k, d_v, head_block, dtype,
         monkeypatch):
     """The two kernels under the Pallas interpreter against the ``lax.scan``
     they replace on a TPU, on the same inputs: ``o``, the final state and
     the gradients of q, k, v, g and beta of a loss that reads both, so that
-    the final state's cotangent is not zero.  In float32 the two do the same
-    arithmetic; in bfloat16 the scan's transpose rounds each of a step's
-    additions to the state's cotangent to bfloat16 and the kernel sums them
-    in float32, so the gradients agree within bfloat16's rounding."""
+    the final state's cotangent is not zero.  Forward the two run the same
+    three functions a head (``_head_step``'s); backward the kernel's is written out
+    and the scan's is autodiff's, so in float32 they agree to rounding (the
+    sums associate differently), and in bfloat16 the scan's transpose rounds
+    each of a step's additions to the state's cotangent to bfloat16 where
+    the kernel sums them in float32: within bfloat16's rounding."""
     if head_block != heads:     # what the budget gives at the cells' widths
         monkeypatch.setattr(gated_delta, "_head_block",
                             lambda *_: head_block)
     args = _inputs(s + heads, rows, s, dtype=dtype, heads=heads,
                    key_heads=key_heads, d_k=d_k, d_v=d_v)
-    assert gated_delta.walk_form(True, heads, chunk, d_k, d_v, dtype)[:2] \
-        == (True, head_block)
-    w_o, w_s = (jax.random.normal(jax.random.PRNGKey(i), shape)
-                for i, shape in ((1, (rows, s, heads, d_v)),
-                                 (2, (rows, heads, d_k, d_v))))
-
-    def both(interpret):
-        def loss(*a):
-            o, state = gated_delta_rule(*a, chunk=chunk, interpret=interpret)
-            return (jnp.sum(o.astype(jnp.float32) * w_o)
-                    + jnp.sum(state * w_s)), (o, state)
-        return jax.jit(jax.value_and_grad(
-            loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
-
+    assert gated_delta.walk_form(True, heads, key_heads, chunk, d_k, d_v,
+                                 dtype)[:2] == (True, head_block)
     ((_, (o, state)), grads), ((_, (o_want, state_want)), grads_want) = \
-        both(True), both(None)
+        _walked(args, chunk, True), _walked(args, chunk, None)
     assert o.shape == (rows, s, heads, d_v) and o.dtype == dtype
     assert state.shape == (rows, heads, d_k, d_v)
     _assert_close(o, o_want, 1e-6)
     _assert_close(state, state_want, 1e-6)
     for name, got, want in zip("q k v g beta".split(), grads, grads_want):
         assert got.dtype == want.dtype and got.shape == want.shape, name
-        _assert_close(got, want, 1e-6 if dtype == jnp.float32 else 2e-2)
+        _assert_close(got, want, 5e-6 if dtype == jnp.float32 else 2e-2)
+
+
+def _walked(args, chunk, interpret):
+    """``((loss, (o, state)), the five gradients)`` of a loss that reads
+    ``o`` and the final state, the walk by ``interpret``; the recurrence one
+    position at a time where that is ``"recurrence"``."""
+    v = args[2]
+    w_o, w_s = (jax.random.normal(jax.random.PRNGKey(i), shape)
+                for i, shape in ((1, v.shape), (2, (
+                    v.shape[0], v.shape[2], args[0].shape[3], v.shape[3]))))
+
+    def loss(*a):
+        o, state = (recurrent_gated_delta_rule(*a)
+                    if interpret == "recurrence" else
+                    gated_delta_rule(*a, chunk=chunk, interpret=interpret))
+        return (jnp.sum(o.astype(jnp.float32) * w_o)
+                + jnp.sum(state * w_s)), (o, state)
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("decay", [1.0, 30.0], ids=["decay-1", "decay-30"])
+@pytest.mark.parametrize("key_heads", [4, 2, 1],
+                         ids=["ratio-1", "ratio-2", "ratio-4"])
+def test_the_interpreted_kernels_are_the_recurrence(key_heads, decay, dtype):
+    """The kernels, interpreted, against the rule one position at a time in
+    float32 on the same (rounded) inputs, one, two and four value heads a key
+    head, 50 positions in chunks of 16 (the last chunk padded), decays of
+    about 1 and near 0 (``exp(gamma)`` underflows to an exact 0 inside a
+    chunk, and ``U = T diag(beta) V`` there): ``o``, the final state and the
+    five gradients with a cotangent on the final state.  float32 to
+    rounding; bfloat16 operands within their rounding through four chunks."""
+    args = _inputs(11 + key_heads, 2, 50, decay=decay, beta_max=1.0,
+                   dtype=dtype, heads=4, key_heads=key_heads)
+    ((_, (o, state)), grads), ((_, (o_want, state_want)), grads_want) = \
+        _walked(args, 16, True), _walked(args, 16, "recurrence")
+    tol, grad_tol = (5e-6, 2e-5) if dtype == jnp.float32 else (2e-2, 4e-2)
+    _assert_close(o, o_want, tol)
+    _assert_close(state, state_want, tol)
+    for name, got, want in zip("q k v g beta".split(), grads, grads_want):
+        assert bool(jnp.isfinite(got.astype(jnp.float32)).all()), name
+        assert got.shape == want.shape, name
+        _assert_close(got, want, grad_tol)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold, but
+    a ``pallas_call``'s own body (the kernels loop over a program's key
+    heads with ``fori_loop``, a ``scan`` in there)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _equations(sub)
 
 
 def test_a_trace_takes_both_kernels_and_no_scan_where_they_engage():
     """``grad`` of the rule with the kernels: two ``pallas_call``s (a third
-    under ``jax.checkpoint``, which runs the walk twice) and no ``scan``;
-    off TPU with nothing asked, the scan and no kernel; a chunk of no whole
-    8-row tiles declines."""
+    under ``jax.checkpoint``, which runs the walk twice) and no ``scan``
+    around them; off TPU with nothing asked, the scan and no kernel; a chunk
+    of no whole 8-row tiles declines."""
     args = _inputs(8, 1, 64)
 
-    def traced(**kw):
-        return str(jax.make_jaxpr(jax.grad(
+    def trace(**kw):
+        return jax.make_jaxpr(jax.grad(
             lambda *a: jnp.sum(gated_delta_rule(*a, **kw)[0]),
-            argnums=(0, 1, 2, 3, 4)))(*args))
+            argnums=(0, 1, 2, 3, 4)))(*args)
+
+    def traced(**kw):
+        return str(trace(**kw))
     kernels, scan = traced(chunk=16, interpret=True), traced(chunk=16)
     assert "gdn_walk_fwd" in kernels and "gdn_walk_bwd" in kernels
-    assert "scan[" not in kernels
+    assert "scan" not in {e.primitive.name for e in _equations(
+        trace(chunk=16, interpret=True).jaxpr)}
     # How many arrays each call writes: o, the chunks' states, the final
-    # state forward; the six cotangents transposed.  Under jax.checkpoint
+    # state forward; transposed the cotangents of T, M * Q K^T, q, k, v and
+    # the two gates.  Under jax.checkpoint
     # (``TransformerConfig(recompute="linear_mixer")``) the forward pass
     # proper saves no states: they are written when the backward pass runs
     # the walk again (``optimize_remat``).
     def writes(jaxpr):
         return [line.count("ShapedArray") for line in jaxpr.splitlines()
                 if "out_avals=" in line]
-    assert writes(kernels) == [3, 6]
+    assert writes(kernels) == [3, 7]
     assert writes(str(jax.make_jaxpr(jax.grad(
         lambda *a: jnp.sum(jax.checkpoint(
             lambda *a: gated_delta_rule(*a, chunk=16, interpret=True))(*a)[0]),
-        argnums=(0, 1, 2, 3, 4)))(*args))) == [2, 3, 6]
+        argnums=(0, 1, 2, 3, 4)))(*args))) == [2, 3, 7]
     assert "scan[" in scan and "pallas_call" not in scan
     assert "pallas_call" not in traced(chunk=12, interpret=True)
-    assert gated_delta.walk_form(None, HEADS, 16, D_K, D_V, jnp.float32)[:2] \
-        == (None, 0)
+    assert gated_delta.walk_form(None, HEADS, HEADS, 16, D_K, D_V,
+                                 jnp.float32)[:2] == (None, 0)
 
 
-@pytest.mark.parametrize("heads, d_k, d_v, want",
-                         [(32, 128, 128, 16), (30, 96, 192, 10),
-                          (6, 8, 12, 6), (1, 2048, 2048, 0)])
-def test_heads_a_program_follow_the_shapes(heads, d_k, d_v, want):
-    """The two cells' shapes, a toy's, and a head whose blocks pass the
-    budget alone (the scan then)."""
-    assert gated_delta._head_block(heads, 64, d_k, d_v, jnp.bfloat16) == want
-    assert want == 0 or heads % want == 0
+@pytest.mark.parametrize("key_heads", [4, 2], ids=["ratio-1", "ratio-2"])
+def test_the_kernels_are_handed_no_chunk_by_width_term(key_heads):
+    """Where the kernels engage, nothing of ``chunk`` x d a value head is
+    made for them: of the arrays with a value head's (n, b, h, chunk, .)
+    the walk's operands hold ``T``, ``M * Q K^T`` (chunk x chunk) and v, and
+    the transposed kernel writes their cotangents; q and k go in, and their
+    cotangents come out, a KEY head; the gates as rows; and no equation of
+    the trace repeats or broadcasts anything to (n, b, h, chunk, d_k)."""
+    n, chunk, heads, d_k, d_v = 4, 16, 4, 8, 24
+    args = _inputs(8, 1, n * chunk, heads=heads, key_heads=key_heads,
+                   d_k=d_k, d_v=d_v, dtype=jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(gated_delta_rule(*a, chunk=chunk, interpret=True)[0]
+                           .astype(jnp.float32)),
+        argnums=(0, 1, 2, 3, 4)))(*args)
+    eqns = list(_equations(jaxpr.jaxpr))
+    calls = {e.params["name"]: e for e in eqns
+             if e.primitive.name == "pallas_call"}
+    assert set(calls) == {"gdn_walk_fwd", "gdn_walk_bwd"}
+    square, key, value, gates, state = (
+        (n, 1, heads, chunk, chunk), (n, 1, key_heads, chunk, d_k),
+        (n, 1, heads, chunk, d_v), (n, 1, 1, heads, chunk),
+        (1, heads, d_k, d_v))
+    terms = [square, square, key, key, value, gates, gates]
+    assert [v.aval.shape for v in calls["gdn_walk_fwd"].invars] == terms
+    assert [v.aval.shape for v in calls["gdn_walk_bwd"].invars] \
+        == terms + [(n,) + state, value, state]
+    assert [v.aval.shape for v in calls["gdn_walk_bwd"].outvars] == terms
+    assert [v.aval.dtype for v in calls["gdn_walk_bwd"].outvars] \
+        == [jnp.float32] + [jnp.bfloat16] * 4 + [jnp.float32] * 2
+    # With equal heads the chunked q and k have a value head's shape
+    # themselves: then it is only a repeat or a broadcast that must not be.
+    wide = (n, 1, heads, chunk, d_k)
+    made = {e.primitive.name for e in eqns for v in e.outvars
+            if getattr(v.aval, "shape", None) == wide}
+    assert not made & ({"gather", "broadcast_in_dim"} if key_heads == heads
+                       else made), made
+
+
+@pytest.mark.parametrize("heads, key_heads, d_k, d_v, want, trips",
+                         [(32, 16, 128, 128, 16, (8, 4)),
+                          (30, 30, 96, 192, 10, (5, 5)),
+                          (6, 6, 8, 12, 6, (6, 6)), (8, 2, 8, 12, 8, (8, 8)),
+                          (1, 1, 2048, 2048, 0, None),
+                          (4, 1, 1024, 1024, 0, None)])
+def test_heads_a_program_follow_the_shapes(heads, key_heads, d_k, d_v, want,
+                                           trips):
+    """The two cells' shapes, two toys', and a head (and a group of four)
+    whose blocks pass the budget alone (the scan then); a program's heads
+    are whole groups of a key head's, and so are the heads a trip of its
+    loop takes, forward and transposed: eight at most, fewer where their
+    staging would pass the budget."""
+    group = heads // key_heads
+    assert gated_delta._head_block(heads, key_heads, 64, d_k, d_v,
+                                   jnp.bfloat16) == want
+    assert want == 0 or (heads % want == 0 and want % group == 0)
+    if want:
+        assert trips == tuple(
+            gated_delta._trip_heads(want, group, 64, d_k, d_v, jnp.bfloat16,
+                                    transposed)
+            for transposed in (False, True))
+        assert all(want % trip == 0 and trip % group == 0 for trip in trips)
 
 
 @pytest.mark.parametrize("interpret, kernel", [(True, 1), (None, 0)])
@@ -334,6 +448,13 @@ def test_the_gauges_and_the_event_name_the_form_that_ran(interpret, kernel):
     gauges = observability.registry().snapshot()["gauges"]
     assert gauges["gdn.scan_kernel"] == kernel
     assert gauges["gdn.scan_head_block"] == (HEADS if kernel else 0)
+    # Three chunks of 16 a row: T in float32 and M * Q K^T, q and k, v and
+    # the two gates, float32 inputs; the same terms either way.
+    assert gauges["gdn.walk_term_bytes_per_row"] == 3 * 16 * HEADS * (
+        16 * 8 + (2 * D_K + D_V) * 4 + 2 * 4)
+    assert gated_delta.WALK_TERMS in event["detail"]
+    assert "U = T diag(beta) (V - diag(exp gamma) K S_0)" \
+        in gated_delta.WALK_TERMS
     assert ("walk over the chunks: Pallas kernels, 3 heads a program, the "
             "state in VMEM (interpret=True requested)" if kernel else
             "walk over the chunks: lax.scan (backend is cpu; the kernels "

@@ -163,14 +163,13 @@ def _no_output_gate(monkeypatch):
 
 def _no_delta_term(monkeypatch):
     """``S_t = alpha_t S_(t-1) + beta_t k_t v_t^T``: in the chunked form,
-    ``T = I`` and ``W = 0``."""
-    terms = gated_delta._chunk_terms
-
-    def without(q, k, v, g, beta, dtype):
-        w, _, qk, q_in, k_out, carry = terms(q, k, v, g, beta, dtype)
-        return (0 * w, (beta[..., None] * v).astype(dtype), qk, q_in, k_out,
-                carry)
-    monkeypatch.setattr(gated_delta, "_chunk_terms", without)
+    ``T = I`` and nothing read of ``S_0``, ``U = diag(beta) V``."""
+    monkeypatch.setattr(gated_delta, "_chunk_reads",
+                        lambda k, v, s0, into: (0.0 * v, 1.0 * v))
+    monkeypatch.setattr(
+        gated_delta, "_chunk_writes",
+        lambda t, beta, rhs: gated_delta._column(
+            beta, gated_delta._masks(beta.shape[1])[0]) * rhs)
     # The rule is an inlined ``jit``: around its cache, which would hand
     # this test a sound trace of another's and a later test this one's.
     monkeypatch.setattr(gated_delta, "_chunked_rule",

@@ -569,25 +569,45 @@ def test_v5e_compiler_takes_256_lanes_at_a_group_of_eight(monkeypatch):
     assert results.count(narrow) == 2 and wide not in results
 
 
-@pytest.mark.parametrize("shape, chunk, solves", [
-    ((4096, 30, 30, 96, 192), 64, False), ((4096, 30, 30, 96, 192), 48, True),
-    ((8192, 32, 16, 128, 128), 64, False)],
+def _mosaic_text(call):
+    """The Mosaic module of one ``tpu_custom_call`` line of a compiled
+    program's text, as MLIR text (the line carries it as bytecode)."""
+    import base64
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+    body = re.search(r'"body":"([^"]*)"', call).group(1)
+    with ir.Context() as ctx:
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
+        return str(ir.Module.parse(base64.b64decode(body)))
+
+
+@pytest.mark.parametrize("shape, chunk, solves, parent_temp", [
+    ((4096, 30, 30, 96, 192), 64, False, 667_901_440),
+    ((4096, 30, 30, 96, 192), 48, True, None),
+    ((8192, 32, 16, 128, 128), 64, False, 1_262_348_288)],
     ids=["olmo-hybrid-64", "olmo-hybrid-48", "qwen3-next-64"])
-def test_v5e_compiler_runs_no_triangular_solve_for_a_chunk_of_64(shape, chunk,
-                                                                 solves):
+def test_v5e_compiler_runs_no_triangular_solve_for_a_chunk_of_64(
+        shape, chunk, solves, parent_temp):
     """The gated delta rule at the Olmo-Hybrid cell's own shape (one row of
     4,096 positions, 30 heads of 96 / 192, bf16) and at the Qwen3-Next
     cell's (8,192 positions, 32 value heads on 16 key heads, 128 / 128),
     forward and backward, compiled by libtpu for one detached v5e chip as a
     TPU process traces it: the walk over the chunks is the two Mosaic
     kernels (``gdn_walk_fwd``, ``gdn_walk_bwd``: Mosaic takes the 96- and
-    192-lane blocks as they are) and no ``while`` is left, where the
-    ``lax.scan`` compiles to two; the step's program holds no more with the
-    kernels than with the scan.  At the train path's chunk of 64 the chunk's
-    inverse is block products: no ``triangular_solve`` and not the custom
-    call libtpu expands one into (``f32[64,1,30,1,64,64]`` in a trace, 15.4
-    ms of the step before) is left in the optimized HLO.  A chunk that is no
-    power of two still solves, and walks in the kernels all the same."""
+    192-lane blocks as they are, q and k a key head through the block
+    index) and no ``while`` is left, where the ``lax.scan`` compiles to two;
+    the step's program holds no more with the kernels than with the scan,
+    and the rule's temporaries are not above what they were when the kernels
+    read five stacked terms (PERF.md section 6, PR 41).  In the kernels'
+    own text the products with ``T`` (64 x 64 float32 operands: one a head
+    forward, three transposed) are float32 products
+    (``contract_precision<fp32>``) and every other product takes bfloat16
+    operands.  At the train path's chunk of 64 the chunk's inverse is block
+    products: no ``triangular_solve`` and not the custom call libtpu expands
+    one into (``f32[64,1,30,1,64,64]`` in a trace, 15.4 ms of the step
+    before) is left in the optimized HLO.  A chunk that is no power of two
+    still solves, and walks in the kernels all the same."""
     why_not = _why_no_detached_topology()
     if why_not:
         pytest.skip(why_not)
@@ -626,6 +646,19 @@ def test_v5e_compiler_runs_no_triangular_solve_for_a_chunk_of_64(shape, chunk,
                 for part in ("temp", "argument", "output"))
             for exe in (kernels, scan)]
     assert held[0] <= held[1], held
+    if parent_temp:
+        assert kernels.memory_analysis().temp_size_in_bytes <= parent_temp
+    for call in calls:
+        products = [
+            ("contract_precision<fp32>" in line, re.findall(
+                r"x(f32|bf16)>", line.split(" : (")[1])[:2])
+            for line in re.findall(r"tpu\.matmul.*", _mosaic_text(call))]
+        exact = [operands for fp32, operands in products if fp32]
+        # One product in five with T forward, three in twelve transposed.
+        assert len(exact) * 20 in (4 * len(products), 5 * len(products))
+        assert all(operands == ["f32", "f32"] for operands in exact)
+        assert all(operands == ["bf16", "bf16"]
+                   for fp32, operands in products if not fp32)
     for exe in (kernels, scan):
         named = [m.group(0) for m in re.finditer(
             r"op_name=\"[^\"]*triangular_solve|"
