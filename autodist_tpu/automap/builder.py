@@ -219,7 +219,7 @@ class Automap(StrategyBuilder):
         from autodist_tpu.tuner.cost_model import CostModel, Topology
         t0 = time.perf_counter()
         cal = self._calibration or Calibration.load()
-        topo = Topology.from_resource_spec(resource_spec, cal)
+        topo = Topology.from_resource_spec(resource_spec)
         model = CostModel(topo, cal)
         base_result = tuner_search.search(
             graph_item, resource_spec, budget=self._base_budget,

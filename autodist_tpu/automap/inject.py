@@ -115,103 +115,6 @@ def _replay(closed, args, anchors, mesh):
     return [read(v) for v in jaxpr.outvars]
 
 
-def _first_use_eqns(jaxpr, wanted_invars):
-    """{invar: eqn index} — the first equation consuming each wanted
-    parameter invar (transitively through nothing: the direct consumer;
-    pass-through converts still count as the first use, which is where
-    the gather belongs)."""
-    first = {}
-    want = set(wanted_invars)
-    for i, eqn in enumerate(jaxpr.eqns):
-        for v in eqn.invars:
-            if not isinstance(v, jax.core.Literal) and v in want:
-                first.setdefault(v, i)
-                want.discard(v)
-        if not want:
-            break
-    return first
-
-
-def _replay_param_anchors(closed, args, anchors, shardings):
-    """Evaluate a closed jaxpr, constraining each anchored parameter
-    invar to its full sharding immediately before its first consuming
-    equation — the per-layer all-gather granularity of the zero1
-    weight-AG reorder (``AUTODIST_ZERO1_AG_SCOPE=use``)."""
-    jaxpr = closed.jaxpr
-    env = {}
-
-    def read(v):
-        return v.val if isinstance(v, jax.core.Literal) else env[v]
-
-    def write(v, val):
-        env[v] = val
-
-    for v, c in zip(jaxpr.constvars, closed.consts):
-        write(v, c)
-    for v, a in zip(jaxpr.invars, args):
-        write(v, a)
-    by_eqn = {}
-    for invar, i in anchors.items():
-        by_eqn.setdefault(i, []).append(invar)
-    for i, eqn in enumerate(jaxpr.eqns):
-        for invar in by_eqn.get(i, ()):
-            write(invar, jax.lax.with_sharding_constraint(
-                read(invar), shardings[invar]))
-        subfuns, bind_params = eqn.primitive.get_bind_params(eqn.params)
-        vals = [read(v) for v in eqn.invars]
-        ans = eqn.primitive.bind(*subfuns, *vals, **bind_params)
-        outs = list(ans) if eqn.primitive.multiple_results else [ans]
-        for v, o in zip(eqn.outvars, outs):
-            write(v, o)
-    return [read(v) for v in jaxpr.outvars]
-
-
-def wrap_with_param_constraints(loss_fn, param_shardings):
-    """Return a loss fn that constrains each named parameter to its full
-    (storage) sharding at its FIRST forward use instead of relying on an
-    up-front gather — each zero1 parameter's all-gather is anchored at
-    the layer that consumes it, so XLA schedules per-layer gathers that
-    overlap with the preceding layers' compute
-    (``AUTODIST_ZERO1_AG_SCOPE=use``; same jaxpr-replay machinery as
-    :func:`wrap_with_constraints`).
-
-    ``param_shardings`` maps flat parameter names
-    (``graph_item.path_to_name``) to the ``NamedSharding`` the forward
-    needs (names are resolved by flattening the live params pytree).
-    Values are unchanged — fail-open on any replay error.
-    """
-    if not param_shardings:
-        return loss_fn
-
-    def constrained(params, batch):
-        try:
-            from autodist_tpu.graph_item import path_to_name
-            closed = jax.make_jaxpr(loss_fn)(params, batch)
-            jaxpr = closed.jaxpr
-            flat_params, _ = jax.tree_util.tree_flatten_with_path(params)
-            names = [path_to_name(p) for p, _ in flat_params]
-            shardings = {}
-            for invar, name in zip(jaxpr.invars[:len(names)], names):
-                sh = param_shardings.get(name)
-                if sh is not None:
-                    shardings[invar] = sh
-            anchors = _first_use_eqns(jaxpr, shardings)
-            if not anchors:
-                return loss_fn(params, batch)
-            args = jax.tree_util.tree_leaves((params, batch))
-            out_flat = _replay_param_anchors(closed, args, anchors,
-                                             shardings)
-            out_shape = jax.eval_shape(loss_fn, params, batch)
-            treedef = jax.tree_util.tree_structure(out_shape)
-            return jax.tree_util.tree_unflatten(treedef, out_flat)
-        except Exception as e:  # noqa: BLE001 - constraints are hints
-            logging.warning(
-                "zero1 gather-at-use: param constraint injection skipped "
-                "(replay failed: %s)", e)
-            return loss_fn(params, batch)
-    return constrained
-
-
 def wrap_with_constraints(loss_fn, op_shardings, mesh):
     """Return a loss fn that computes the same values with the artifact's
     per-op sharding constraints anchored at scope exits.

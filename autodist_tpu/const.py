@@ -74,28 +74,21 @@ class ENV(enum.Enum):
     AUTODIST_STRATEGY_SHIP_TIMEOUT_MS = ("AUTODIST_STRATEGY_SHIP_TIMEOUT_MS", int, 0)  # 0 => STRATEGY_SHIP_TIMEOUT_MS default
     AUTODIST_CHAOS = ("AUTODIST_CHAOS", str, "")             # fault injection knobs (resilience/chaos.py)
     AUTODIST_GUARD_CHECK_EVERY = ("AUTODIST_GUARD_CHECK_EVERY", int, 10)   # StepGuard host-check cadence (steps)
-    AUTODIST_GUARD_MAX_STRIKES = ("AUTODIST_GUARD_MAX_STRIKES", int, 3)    # consecutive rollbacks before abort
     AUTODIST_SUPERVISION = ("AUTODIST_SUPERVISION", str, "abort")          # abort | restart-worker | checkpoint-and-exit | elastic
     AUTODIST_MAX_WORKER_RESTARTS = ("AUTODIST_MAX_WORKER_RESTARTS", int, 2)  # per-worker respawn budget (restart-worker)
     AUTODIST_RETRY_MAX_ATTEMPTS = ("AUTODIST_RETRY_MAX_ATTEMPTS", int, 4)  # transient-I/O retry budget (resilience/retry.py)
     # -- elastic N->M resharding (docs/elasticity.md) ------------------------
     AUTODIST_ELASTIC_MIN_WORLD = ("AUTODIST_ELASTIC_MIN_WORLD", int, 1)  # elastic supervision never shrinks below this world size (escalates to abort)
     AUTODIST_ELASTIC_WORLD = ("AUTODIST_ELASTIC_WORLD", int, 0)  # re-formed world-size override applied to the resource spec (set by Coordinator.reform_now; 0 => spec as written)
-    # -- overlap scheduler (docs/usage/performance.md) -----------------------
-    AUTODIST_OVERLAP = ("AUTODIST_OVERLAP", bool, False)  # latency-hiding collective scheduler: async-collective XLA flags + reverse-layer bucket issue + megastep weight-AG reorder
-    AUTODIST_ZERO1_AG_SCOPE = ("AUTODIST_ZERO1_AG_SCOPE", str, "step")  # weight-AG reorder granularity under AUTODIST_OVERLAP: step (one gather of every zero1 param at scan-body start) | use (each param's all-gather anchored at its first forward use — per-layer gathers that overlap with earlier layers' compute)
+    # -- the fused reductions' bucket cap (docs/usage/performance.md) ---------
     AUTODIST_AR_BUCKET_MB = ("AUTODIST_AR_BUCKET_MB", int, 0)  # fusion-bucket size cap in MiB (0 => one bucket per strategy group/compressor/dtype)
 
     # -- observability (docs/observability.md) -------------------------------
     AUTODIST_UNROLL = ("AUTODIST_UNROLL", int, 1)  # fused steps per XLA dispatch (megastep; 1 => one dispatch per step)
-    AUTODIST_PREFETCH_DEPTH = ("AUTODIST_PREFETCH_DEPTH", int, 2)  # DevicePrefetcher in-flight transfers (0 => passthrough)
-    AUTODIST_LOADER_RING = ("AUTODIST_LOADER_RING", int, 2)        # native async assembly ring depth (0 => synchronous)
-    AUTODIST_LOADER_POOL = ("AUTODIST_LOADER_POOL", int, 0)        # staging buffer pool size (0 => auto: ring + depth + 2)
 
     # -- strategy autotuner (docs/tuning.md) ---------------------------------
     AUTODIST_STRATEGY = ("AUTODIST_STRATEGY", str, "")       # "auto" => tuner picks; else a builder name ("allreduce", "parallax", ...)
     AUTODIST_TUNER_BUDGET = ("AUTODIST_TUNER_BUDGET", int, 0)  # max candidates costed (0 => default 64; >= space size => exhaustive)
-    AUTODIST_TUNER_PROBE = ("AUTODIST_TUNER_PROBE", bool, False)  # one-shot collective micro-probe to seed calibration
     AUTODIST_TUNER_CALIBRATION = ("AUTODIST_TUNER_CALIBRATION", str, "")  # calibration file override (default <working_dir>/tuner_calibration.json)
     AUTODIST_AUTOMAP_BUDGET = ("AUTODIST_AUTOMAP_BUDGET", int, 0)  # automap mesh candidates priced incl. the DP base (0 => default 8; 1 forces the DP base)
 
@@ -111,17 +104,14 @@ class ENV(enum.Enum):
 
     # -- online re-tuning controller (docs/retuning.md) ----------------------
     AUTODIST_RETUNE = ("AUTODIST_RETUNE", str, "")  # "" / "0" => off (step loop makes zero retune calls); "exec" => tier-1 exec-knob switches only; "1" / "full" => exec-knob AND live strategy switches via reshard
-    AUTODIST_RETUNE_MARGIN_PCT = ("AUTODIST_RETUNE_MARGIN_PCT", float, 10.0)  # hysteresis: a challenger must beat the incumbent's measured step time by more than this before a switch is considered
     AUTODIST_RETUNE_PATIENCE = ("AUTODIST_RETUNE_PATIENCE", int, 3)  # consecutive evaluation windows the SAME challenger must stay past the margin before the switch fires (resets on regime flips)
     AUTODIST_RETUNE_SHIP_TIMEOUT_MS = ("AUTODIST_RETUNE_SHIP_TIMEOUT_MS", int, 60_000)  # worker wait for the chief's per-window retune verdict on the coordination-service KV store
     # -- self-healing reshape-on-degrade (docs/retuning.md) ------------------
     AUTODIST_SELFHEAL = ("AUTODIST_SELFHEAL", bool, True)  # degraded-host shrink-and-reshape decisions (active only when AUTODIST_RETUNE is on and a coordinator is bound)
     AUTODIST_SELFHEAL_PATIENCE = ("AUTODIST_SELFHEAL_PATIENCE", int, 3)  # consecutive cluster-sync rounds the SAME host must hold the straggler verdict before eviction is priced (a transient blip never evicts)
-    AUTODIST_SELFHEAL_HORIZON = ("AUTODIST_SELFHEAL_HORIZON", int, 1000)  # remaining-steps assumption for the shrink payoff when the step loop has not reported progress yet
 
     # -- serving runtime (docs/serving.md) -----------------------------------
     AUTODIST_SERVE_BUCKETS = ("AUTODIST_SERVE_BUCKETS", str, "")  # comma list of padded batch buckets, e.g. "8,32,128" ("8x128,32x128" pads (rows, seq))
-    AUTODIST_SERVE_MAX_WAIT_MS = ("AUTODIST_SERVE_MAX_WAIT_MS", int, 5)  # continuous-batching coalesce deadline (ms)
     AUTODIST_DECODE_SLOTS = ("AUTODIST_DECODE_SLOTS", int, 8)  # decode engine slot count per (slots, cache_len) bucket (must divide the per-replica device count evenly)
     AUTODIST_DECODE_CACHE_LEN = ("AUTODIST_DECODE_CACHE_LEN", int, 128)  # preallocated KV-cache length per slot (prompt + generated tokens must fit)
     AUTODIST_AUTOSCALE = ("AUTODIST_AUTOSCALE", bool, False)  # SLO-driven autoscaler: grow/shrink decode replicas on serve.slo_burn + queue depth (serve/autoscale.py)
@@ -129,7 +119,6 @@ class ENV(enum.Enum):
     AUTODIST_AUTOSCALE_MAX = ("AUTODIST_AUTOSCALE_MAX", int, 0)  # autoscaler replica ceiling (0 => local device count)
 
     AUTODIST_PROFILE = ("AUTODIST_PROFILE", bool, True)  # per-layer device-time profiler (finalize-only cost; telemetry off => provably zero calls)
-    AUTODIST_PROFILE_TOPK = ("AUTODIST_PROFILE_TOPK", int, 5)  # top-K scopes surfaced on the monitor / gauges / report
 
     # -- goodput / run-level accounting (docs/goodput.md) --------------------
     AUTODIST_RUN_ID = ("AUTODIST_RUN_ID", str, "")  # run identity carried across elastic re-exec generations (minted by the chief when unset)
@@ -146,9 +135,7 @@ class ENV(enum.Enum):
 
     AUTODIST_TELEMETRY = ("AUTODIST_TELEMETRY", bool, True)  # master switch: metrics + spans + flight recorder
     AUTODIST_TRACE = ("AUTODIST_TRACE", str, "chrome")       # chrome (trace-event JSON file) | 0 (no file)
-    AUTODIST_METRICS_WINDOW = ("AUTODIST_METRICS_WINDOW", int, 256)  # histogram window (last-N observations)
     AUTODIST_MONITOR_PORT = ("AUTODIST_MONITOR_PORT", int, 0)  # chief HTTP monitor (/metrics + /status); 0 => no server, no thread
-    AUTODIST_ANOMALY_ZSCORE = ("AUTODIST_ANOMALY_ZSCORE", float, 3.0)  # per-host latency z-score threshold for the anomaly detector
     AUTODIST_FLIGHT_MAX_MB = ("AUTODIST_FLIGHT_MAX_MB", int, 64)  # total on-disk cap across logs/flight_*.jsonl (oldest-file eviction)
     AUTODIST_SERVE_SLO_MS = ("AUTODIST_SERVE_SLO_MS", int, 50)  # serving p99 SLO target (monitor slo-burn gauge)
 

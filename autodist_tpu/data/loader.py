@@ -24,8 +24,8 @@ framework owns the native layer itself:
   transfer retired.  One code path replaces the previous three divergent
   modes (threaded / pipelined single-core / passthrough).
 
-Env knobs (docs/data.md): ``AUTODIST_PREFETCH_DEPTH``,
-``AUTODIST_LOADER_RING``, ``AUTODIST_LOADER_POOL``.
+The depths are constants (docs/data.md): :data:`PREFETCH_DEPTH`,
+:data:`LOADER_RING`; a staging pool holds what those two keep busy plus two.
 """
 import ctypes
 import hashlib
@@ -42,6 +42,12 @@ import numpy as np
 
 from autodist_tpu import const, observability
 from autodist_tpu.utils import logging
+
+#: Transfers a :class:`DevicePrefetcher` keeps in flight (the Runner's
+#: loader wiring and the memory ledger's input term read it from here).
+PREFETCH_DEPTH = 2
+#: Batches the native async assembly ring fills ahead.
+LOADER_RING = 2
 
 _SRC = os.path.join(os.path.dirname(__file__), "native", "prefetcher.cpp")
 _lib = None
@@ -210,7 +216,7 @@ class NativeDataLoader:
                  shard_index=None, shard_count=None, per_host=False,
                  block_shuffle=False, pool_size=None, ring_depth=None):
         """``pipeline=True`` keeps an async assembly ring of up to
-        ``ring_depth`` batches (default ``AUTODIST_LOADER_RING``) filling
+        ``ring_depth`` batches (default :data:`LOADER_RING`) filling
         ahead in a native (GIL-free) thread: ``__next__`` hands out the
         oldest completed assembly and tops the ring back up, so the memcpy
         overlaps whatever the consumer does next (issuing the H2D
@@ -259,15 +265,14 @@ class NativeDataLoader:
         self._sample_bytes = sample_bytes
         # Async assembly ring (native zero-thread mode only; see ctor doc).
         if ring_depth is None:
-            ring_depth = max(0, const.ENV.AUTODIST_LOADER_RING.val)
+            ring_depth = LOADER_RING
         self._ring_depth = (min(ring_depth, max(1, capacity))
                             if (pipeline and self._impl[0] == "native"
                                 and num_threads == 0 and not block_shuffle)
                             else 0)
         self._ring = deque()  # buffers with a queued/running async assembly
         if pool_size is None:
-            pool_size = const.ENV.AUTODIST_LOADER_POOL.val or \
-                (self._ring_depth + const.ENV.AUTODIST_PREFETCH_DEPTH.val + 2)
+            pool_size = self._ring_depth + PREFETCH_DEPTH + 2
         self._pool = BufferPool((batch_size,) + self.record_shape,
                                 self.dtype, pool_size)
 
@@ -521,8 +526,7 @@ class BlockStacker:
         self._k = int(unroll)
         self._recycle_to = recycle_to
         if pool_size is None:
-            pool_size = const.ENV.AUTODIST_LOADER_POOL.val or \
-                (max(0, const.ENV.AUTODIST_PREFETCH_DEPTH.val) + 2)
+            pool_size = PREFETCH_DEPTH + 2
         self._pool_size = max(1, int(pool_size))
         self._pools = {}  # (shape, dtype) -> BufferPool of block buffers
 
@@ -612,7 +616,7 @@ class DevicePrefetcher:
                  shard_in_background=None, loader=None,
                  pull_in_background=None, shard_fn=None):
         if depth is None:
-            depth = max(0, const.ENV.AUTODIST_PREFETCH_DEPTH.val)
+            depth = PREFETCH_DEPTH
         # A source exposing ``next_nowait()`` (returning None when nothing
         # is ready RIGHT NOW) opts into lazy top-up: the window fills
         # opportunistically instead of blocking until ``depth`` batches

@@ -1,9 +1,14 @@
-"""Latency-hiding collective scheduler: plan + flags + exposed-comms model.
+"""The gradients' bucket plan, the reader of a compiled step's communication
+instructions, and the predicted exposed-comms figure.
 
-Three pieces, shared by the Runner (issue order), the tuner cost model
-(pricing), and the report (the predicted figure; the text parser below also
-serves ``observability/profile.comm_table``, whose join with a trace is the
-measured one):
+Nothing here orders a step's communication: the compiler does (one chip,
+small leaves, ``scan_layers`` models) and, for the explicit step's ``fsdp``
+matrices, ``kernel/synchronization/grad_scatter`` behind ``layer_boundary``.
+What this module holds is shared by the Runner (the explicit step's fused
+reductions), the tuner's cost model (pricing) and the report (the predicted
+figure; the text parser below also serves
+``observability/profile.comm_table``, whose join with a trace is the measured
+one):
 
 * **Bucket plan** — gradient reductions are bucketed by strategy
   ``(group, compressor, dtype)`` and split at ``AUTODIST_AR_BUCKET_MB``;
@@ -13,14 +18,6 @@ measured one):
   program, so chief and workers derive the identical issue order with no
   coordination (the same contract as the tuner tie-break).
 
-* **XLA flags** — ``AUTODIST_OVERLAP=1`` turns on XLA's async-collective
-  and latency-hiding-scheduler passes so the issued collectives actually
-  pipeline behind remaining backward compute (and, inside a megastep
-  scan, across iterations: the collective pipeliner moves the ZeRO
-  weight all-gather of step *t* next to step *t+1*'s forward — the
-  arXiv:2004.13336 schedule).  Only flags this jaxlib build registers are
-  added (XLA hard-aborts on unknown flags).
-
 * **Exposed-comms model** — ``exposed_collective_ms`` walks a *scheduled*
   HLO text (instruction order == execution order), prices every async
   ``-start``/``-done`` pair on the topology's link seeds, and subtracts
@@ -29,7 +26,6 @@ measured one):
   the gauge ``comms.exposed_ms_per_step``.
 """
 import hashlib
-import os
 import re
 from collections import namedtuple
 
@@ -37,47 +33,6 @@ import jax
 
 from autodist_tpu import const
 from autodist_tpu.utils import logging
-from autodist_tpu.utils.xla_flags import xla_flag_supported
-
-# Async-collective + latency-hiding-scheduler flags, per backend family.
-# Probed against this jaxlib before use (unknown flags abort the process).
-OVERLAP_FLAG_CANDIDATES = (
-    # TPU: async collectives fused with surrounding compute + the
-    # scheduler that actually interleaves them with the TensorCore stream.
-    "--xla_tpu_enable_async_collective_fusion=true",
-    "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true",
-    "--xla_tpu_enable_async_collective_fusion_multiple_steps=true",
-    "--xla_tpu_overlap_compute_collective_tc=true",
-    "--xla_enable_async_all_gather=true",
-    "--xla_enable_async_collective_permute=true",
-    # GPU: the latency-hiding scheduler family (harmless on TPU/CPU —
-    # only added when the build registers it).
-    "--xla_gpu_enable_latency_hiding_scheduler=true",
-    "--xla_gpu_enable_pipelined_all_reduce=true",
-    "--xla_gpu_enable_pipelined_all_gather=true",
-    "--xla_gpu_enable_pipelined_reduce_scatter=true",
-)
-
-
-def overlap_xla_flags():
-    """The subset of :data:`OVERLAP_FLAG_CANDIDATES` this build knows."""
-    return tuple(f for f in OVERLAP_FLAG_CANDIDATES
-                 if xla_flag_supported(f.split("=")[0]))
-
-
-def apply_overlap_flags():
-    """Append the supported overlap flags to ``XLA_FLAGS`` (idempotent).
-
-    Must run before XLA parses the env (first backend use / first
-    compile); the Runner applies it at construction when
-    ``AUTODIST_OVERLAP=1``.  Returns the flags added this call.
-    """
-    flags = overlap_xla_flags()
-    current = os.environ.get("XLA_FLAGS", "")
-    added = tuple(f for f in flags if f.split("=")[0] not in current)
-    if added:
-        os.environ["XLA_FLAGS"] = (current + " " + " ".join(added)).strip()
-    return added
 
 
 # -- grad-production order ---------------------------------------------------

@@ -21,7 +21,7 @@ decomposes measured wall step time into named causes::
   compute calibration;
 * ``exposed_comms`` — the scheduled-HLO async-window pricing when the
   AOT path recorded ``comms.exposed_ms_per_step``, else the cost
-  model's (overlap-aware) sync estimate;
+  model's sync estimate;
 * ``residual`` — whatever is left, **surfaced, never absorbed**: the
   components plus the residual sum to the measured wall time exactly
   (a tier-1 invariant test pins it).  A large positive residual means
@@ -152,13 +152,12 @@ def terms_for_runner(runner, unroll=1):
         prog = runner.program
         topo = cm.Topology(max(1, prog.mesh.devices.size),
                            num_hosts=max(1, jax.process_count()))
-        overlap = bool(getattr(runner, "_overlap", False))
         from autodist_tpu.kernel import overlap as overlap_mod
         bd = cm.CostModel(topo).strategy_cost(
-            prog.strategy, prog.graph_item, unroll=unroll, overlap=overlap,
+            prog.strategy, prog.graph_item, unroll=unroll,
             bucket_bytes=overlap_mod.bucket_bytes_cap())
         raw_compute = bd["compute_ms"] + bd["update_ms"]
-        raw_comms = bd["exposed_sync_ms"] + bd["overlay_ms"]
+        raw_comms = bd["sync_ms"] + bd["overlay_ms"]
         compute = raw_compute * (cal.compute_scale if cal is not None else 1.0)
         comms = raw_comms * (cal.comms_scale if cal is not None else 1.0)
         sources["device_compute"] = "cost-model-roofline"

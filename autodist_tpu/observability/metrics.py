@@ -8,7 +8,7 @@ a local list and flush on the StepGuard cadence via
 is one ``time.perf_counter()`` call and a list append.
 
 Histograms are *time-window*: a bounded deque of the last N observations
-(``AUTODIST_METRICS_WINDOW``), summarized on demand.  A training job
+(:data:`METRICS_WINDOW`), summarized on demand.  A training job
 running for days must not grow memory with step count, and the questions
 telemetry answers ("why is this step slow *now*", "what is p90 over the
 last few hundred steps") are windowed questions.
@@ -23,7 +23,8 @@ import threading
 
 from collections import deque
 
-from autodist_tpu import const
+#: Observations a histogram keeps (the last N).
+METRICS_WINDOW = 256
 
 
 class Counter:
@@ -149,10 +150,9 @@ class MetricsRegistry:
     def gauge(self, name):
         return self._get(name, lambda: Gauge(name))
 
-    def histogram(self, name, window=None):
-        if window is None:
-            window = const.ENV.AUTODIST_METRICS_WINDOW.val
-        return self._get(name, lambda: WindowHistogram(name, window))
+    def histogram(self, name):
+        return self._get(name,
+                         lambda: WindowHistogram(name, METRICS_WINDOW))
 
     def snapshot(self):
         """{"counters": {...}, "gauges": {...}, "histograms": {...}}."""

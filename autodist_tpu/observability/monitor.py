@@ -21,7 +21,7 @@ The :class:`AnomalyDetector` watches the same per-host snapshots the
 report aggregates and flags, with rolling history:
 
 * **latency spikes** — a host whose median step time z-scores above
-  ``AUTODIST_ANOMALY_ZSCORE`` against its own rolling history;
+  :data:`ANOMALY_ZSCORE` against its own rolling history;
 * **data-wait dominance flips** — a host that turns input-bound after
   running compute-bound (the input pipeline regressed mid-run);
 * **heartbeat gaps** — a snapshot older than the stale threshold.
@@ -46,6 +46,9 @@ _port = None
 _lock = threading.Lock()
 
 _THREAD_NAME = "autodist-monitor"
+#: A host's median step time this many deviations above its own rolling
+#: history is a latency spike.
+ANOMALY_ZSCORE = 3.0
 
 
 # ---------------------------------------------------------------------------
@@ -61,10 +64,8 @@ class AnomalyDetector:
     while its condition holds and clears when it stops.
     """
 
-    def __init__(self, zscore=None, heartbeat_s=120.0, dominance=0.5,
-                 window=64, min_history=8):
-        if zscore is None:
-            zscore = const.ENV.AUTODIST_ANOMALY_ZSCORE.val
+    def __init__(self, zscore=ANOMALY_ZSCORE, heartbeat_s=120.0,
+                 dominance=0.5, window=64, min_history=8):
         self.zscore = float(zscore)
         self.heartbeat_s = float(heartbeat_s)
         self.dominance = float(dominance)

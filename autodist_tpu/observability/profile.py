@@ -52,6 +52,8 @@ from autodist_tpu.graph_item import UNATTRIBUTED  # noqa: E402,F401
 #: sub-scopes) collapses into "layer0/attn"; the zoo's own scopes are at
 #: most two segments deep ("stage0/block1").
 SCOPE_DEPTH = 2
+#: Scopes surfaced on the monitor, the gauges and the report.
+TOPK = 5
 
 _OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
 
@@ -62,10 +64,6 @@ def enabled():
     """Profiler gate: telemetry master switch AND ``AUTODIST_PROFILE``."""
     from autodist_tpu import observability
     return observability.enabled() and bool(const.ENV.AUTODIST_PROFILE.val)
-
-
-def topk():
-    return max(1, int(const.ENV.AUTODIST_PROFILE_TOPK.val))
 
 
 def collapse(scope, depth=SCOPE_DEPTH):
@@ -775,7 +773,7 @@ class Profile:
                        "wire_bytes": round(sum(r["wire_bytes"]
                                                for r in rows.values()), 1)},
             "coverage_pct": round(coverage, 2),
-            "top": top[:topk()],
+            "top": top[:TOPK],
             "sources": dict(self.sources),
             "reconciled": any(ledger[c] is not None
                               for c in ("compute_ms", "comms_ms")),
@@ -850,7 +848,7 @@ def feed_calibration(summary, calibration=None):
             rows, key=lambda s: -max(
                 abs(rows[s]["compute_ms"] - rows[s]["predicted_compute_ms"]),
                 abs(rows[s]["comms_ms"] - rows[s]["predicted_comms_ms"])))
-        for scope in offenders[:topk()]:
+        for scope in offenders[:TOPK]:
             r = rows[scope]
             if sources.get("compute") == "scheduled-hlo" and \
                     r["predicted_compute_ms"] > 0 and r["compute_ms"] > 0:
@@ -921,7 +919,7 @@ def last_summary_rows(limit=None):
     extra = [s for s in rows if s not in order]
     ranked = list(order) + sorted(
         extra, key=lambda s: -(rows[s]["compute_ms"] + rows[s]["comms_ms"]))
-    return [(s, rows[s]) for s in ranked[:limit or topk()]]
+    return [(s, rows[s]) for s in ranked[:limit or TOPK]]
 
 
 def last_profile():

@@ -663,8 +663,7 @@ def _render_retune():
     bits = [
         f"mode <span class=badge>{_esc(st.get('mode'))}</span>",
         f"incumbent <code>{_esc(inc.get('strategy'))}</code> "
-        f"(unroll {inc.get('unroll')}, overlap "
-        f"{'on' if inc.get('overlap') else 'off'}, bucket "
+        f"(unroll {inc.get('unroll')}, bucket "
         f"{inc.get('bucket_mb')}MB)",
         f"{st.get('windows')} windows · {st.get('evaluations')} "
         f"re-pricing passes ({st.get('eval_ms', 0):.0f} ms total)",
@@ -775,7 +774,7 @@ def _render_telemetry():
             f"{_esc(unroll)}; guard/checkpoint cadence at megastep "
             f"boundaries.</p>")
 
-    # Overlap-efficiency row: comms the scheduled HLO could not hide
+    # Exposed-comms row: comms the scheduled HLO could not hide
     # (kernel/overlap exposed-comms model, gauge set on AOT compile),
     # read against the measured step time when one is available.  The
     # gauge lands at write_report's AOT compile — AFTER the step loop's
@@ -789,21 +788,17 @@ def _render_telemetry():
         pass
     exposed = gauges0.get("comms.exposed_ms_per_step")
     if exposed is not None:
-        mode = "on" if gauges0.get("step.overlap") else "off"
         p50s = [info["step_ms"].get("p50")
                 for info in agg["hosts"].values() if info.get("step_ms")]
         p50s = [p for p in p50s if p]
         eff_html = ""
         if p50s:
             eff = max(0.0, 1.0 - float(exposed) / min(p50s))
-            eff_html = (f" &middot; overlap efficiency "
-                        f"~{100.0 * eff:.0f}% of step time hidden")
+            eff_html = (f" &middot; ~{100.0 * eff:.0f}% of step time "
+                        f"free of exposed comms")
         warn_html += (
-            f"<p><span class=badge>overlap={mode}</span> "
-            f"comms exposed {_fmt_ms(exposed)} ms/step (priced from the "
-            f"scheduled HLO's async start/done windows"
-            f"{', serialized schedule' if mode == 'off' else ''})"
-            f"{eff_html}.</p>")
+            f"<p>comms exposed {_fmt_ms(exposed)} ms/step (priced from the "
+            f"scheduled HLO's async start/done windows){eff_html}.</p>")
 
     host_rows = []
     for host, info in sorted(agg["hosts"].items()):

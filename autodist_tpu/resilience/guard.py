@@ -22,6 +22,9 @@ import numpy as np
 from autodist_tpu import const
 from autodist_tpu.utils import logging
 
+#: Consecutive rollbacks a guard tolerates where none is passed.
+MAX_STRIKES = 3
+
 
 class DivergenceAbort(RuntimeError):
     """Raised when rollback+retry exhausted ``max_strikes``."""
@@ -42,17 +45,16 @@ class StepGuard:
             skipped, preserving the skip-offending-batches contract at
             megastep granularity.
         max_strikes: consecutive rollbacks tolerated before
-            :class:`DivergenceAbort` (ENV ``AUTODIST_GUARD_MAX_STRIKES``).
+            :class:`DivergenceAbort`.
         on_rollback: optional callback ``(step, strikes) -> None`` —
             the re-seeding hook (shuffle the data pipeline, bump an rng
             epoch) invoked after state is restored.
     """
 
-    def __init__(self, check_every=None, max_strikes=None, on_rollback=None):
+    def __init__(self, check_every=None, max_strikes=MAX_STRIKES,
+                 on_rollback=None):
         if check_every is None:
             check_every = const.ENV.AUTODIST_GUARD_CHECK_EVERY.val
-        if max_strikes is None:
-            max_strikes = const.ENV.AUTODIST_GUARD_MAX_STRIKES.val
         self.check_every = max(1, int(check_every))
         self.max_strikes = max(1, int(max_strikes))
         self.on_rollback = on_rollback

@@ -9,11 +9,11 @@ none of them talked: a long run inherited its launch-time plan forever.
 The :class:`~autodist_tpu.retune.controller.Controller` is the missing
 edge (docs/retuning.md).  Evaluated on the observed step loop's existing
 flush cadence, it re-prices the tuner's candidate set **and** the
-incumbent's exec-knob grid (unroll, overlap on/off, AR bucket MB,
-pipeline microbatches) under the *current*
-:class:`~autodist_tpu.tuner.calibration.Calibration`, and when a
+incumbent's exec-knob grid (unroll, AR bucket MB, pipeline microbatches)
+under the *current* :class:`~autodist_tpu.tuner.calibration.Calibration`,
+and when a
 challenger beats the incumbent's *measured* step time by more than the
-hysteresis margin (``AUTODIST_RETUNE_MARGIN_PCT``) for
+hysteresis margin (``controller.MARGIN_PCT``) for
 ``AUTODIST_RETUNE_PATIENCE`` consecutive windows, switches in place at a
 megastep boundary:
 

@@ -10,7 +10,7 @@ a :class:`FollowerController` that adopts rather than evaluates.  Every
 evaluation window the chief:
 
 1. re-prices the incumbent program and its exec-knob grid (unroll x
-   overlap x AR bucket x microbatches, ``tuner.search.reprice``) plus —
+   AR bucket x microbatches, ``tuner.search.reprice``) plus —
    in ``full`` mode — every mesh-compatible candidate strategy from the
    tuner's last ranking, all under the CURRENT persisted
    :class:`~autodist_tpu.tuner.calibration.Calibration` (term scales,
@@ -21,7 +21,7 @@ evaluation window the chief:
    incumbent's measured window p50 is the scale, so only the *ratio* of
    model predictions matters;
 3. applies hysteresis: the challenger must beat the measured incumbent
-   by more than ``AUTODIST_RETUNE_MARGIN_PCT`` for
+   by more than :data:`MARGIN_PCT` for
    ``AUTODIST_RETUNE_PATIENCE`` consecutive windows (the streak resets
    when the best challenger changes or the measured regime flips), so
    two candidates inside the margin can never ping-pong;
@@ -63,6 +63,9 @@ from autodist_tpu.utils import logging
 #: p50s jitter, and a flip threshold at the margin itself would reset
 #: patience on noise alone.
 _REGIME_FLIP_FACTOR = 2.0
+#: Hysteresis: a challenger must beat the incumbent's measured step time by
+#: more than this many percent before a switch is considered.
+MARGIN_PCT = 10.0
 
 
 def _search_module():
@@ -181,7 +184,7 @@ class Decision(NamedTuple):
     boundary."""
     tier: int            # 1 = exec knobs only, 2 = strategy switch
     label: str           # challenger label (candidate name + knobs)
-    knobs: dict          # {"unroll", "overlap", "bucket_mb", "microbatches"}
+    knobs: dict          # {"unroll", "bucket_mb", "microbatches"}
     strategy: object     # built Strategy for tier 2, else None
     strategy_name: str   # candidate name for tier 2, else "" (incumbent)
     predicted_ms: float  # challenger predicted step time (calibrated)
@@ -204,13 +207,11 @@ class Controller:
         self._eval_requested = None  # out-of-cadence evaluation reason
         self._allow_unroll = bool(allow_unroll)
         self._mode = mode()
-        self.margin_pct = max(
-            0.0, float(const.ENV.AUTODIST_RETUNE_MARGIN_PCT.val))
+        self.margin_pct = MARGIN_PCT
         self.patience = max(1, int(const.ENV.AUTODIST_RETUNE_PATIENCE.val))
         gc = runner.program.strategy.graph_config
         self._knobs = {
             "unroll": max(1, int(unroll)),
-            "overlap": bool(runner._overlap),
             "bucket_mb": max(0, int(const.ENV.AUTODIST_AR_BUCKET_MB.val)),
             "microbatches": int(gc.pipeline_microbatches or 0),
         }
@@ -388,8 +389,7 @@ class Controller:
             hosts = 1
         mesh = self._runner.program.mesh
         n = max(1, int(mesh.devices.size))
-        topo = Topology(n, num_hosts=hosts,
-                        links=cal.apply_link_overrides({}))
+        topo = Topology(n, num_hosts=hosts)
         return CostModel(topo, cal), cal
 
     def _allowed_unrolls(self, remaining_steps):
@@ -417,8 +417,7 @@ class Controller:
         inc = search_mod.reprice(
             self._runner.program.strategy, item, model,
             unrolls=(kn["unroll"],),
-            variants=(("", {"overlap": kn["overlap"],
-                            "bucket_bytes": kn["bucket_mb"] << 20,
+            variants=(("", {"bucket_bytes": kn["bucket_mb"] << 20,
                             "microbatches": kn["microbatches"] or None}),),
             host_dispatch_ms=host_ms, batch_size=batch)
         incumbent_pred = inc[0]["predicted_ms"]
@@ -689,11 +688,6 @@ class Controller:
         compiled-step caches so the next dispatch re-lowers."""
         import os
         runner = self._runner
-        new_overlap = bool(knobs.get("overlap", self._knobs["overlap"]))
-        if new_overlap and not runner._overlap:
-            from autodist_tpu.kernel import overlap as overlap_mod
-            overlap_mod.apply_overlap_flags()
-        runner._overlap = new_overlap
         bucket = int(knobs.get("bucket_mb") or 0)
         os.environ[const.ENV.AUTODIST_AR_BUCKET_MB.var_name] = str(bucket)
         mb = int(knobs.get("microbatches") or 0)
@@ -702,8 +696,8 @@ class Controller:
         unroll = max(1, int(knobs.get("unroll", self._knobs["unroll"])))
         if not self._allow_unroll:
             unroll = self._knobs["unroll"]
-        self._knobs = {"unroll": unroll, "overlap": new_overlap,
-                       "bucket_mb": bucket, "microbatches": mb}
+        self._knobs = {"unroll": unroll, "bucket_mb": bucket,
+                       "microbatches": mb}
         runner._invalidate_compiled()
 
     # -- event closure -------------------------------------------------------

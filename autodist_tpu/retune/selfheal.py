@@ -39,6 +39,9 @@ from autodist_tpu import const, observability
 from autodist_tpu.utils import logging
 
 _healer = None
+#: Remaining steps assumed for the shrink payoff while the step loop has
+#: not reported progress yet.
+HORIZON = 1000
 
 
 def enabled():
@@ -97,7 +100,6 @@ class SelfHealer:
         self._manager = manager
         self._coordinator = coordinator
         self.patience = max(1, int(const.ENV.AUTODIST_SELFHEAL_PATIENCE.val))
-        self.horizon = max(1, int(const.ENV.AUTODIST_SELFHEAL_HORIZON.val))
         self._streak_host = None
         self._streak = 0
         self._first_degraded_ts = None
@@ -193,7 +195,7 @@ class SelfHealer:
         saving = cur - new_ms
         remaining = self._num_steps - self._step
         if remaining <= 0:
-            remaining = self.horizon
+            remaining = HORIZON
         payoff_ms = saving * remaining
         cost_ms = self._reexec_cost_ms()
         if saving <= 0 or payoff_ms <= cost_ms:

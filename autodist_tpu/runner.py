@@ -64,7 +64,7 @@ class TrainState(NamedTuple):
 class Runner:
     """Compiles and drives the distributed train step for one program."""
 
-    def __init__(self, program, overlap=None):
+    def __init__(self, program):
         self._program = program
         self._item = program.graph_item
         self._mesh = program.mesh
@@ -75,16 +75,6 @@ class Runner:
         # device values, never waited for here, for a reader to fetch once
         # its loop has ended (docs/observability.md, "Auxiliary outputs").
         self.last_aux = None
-        # Latency-hiding collective scheduler (docs/usage/performance.md):
-        # reverse-layer bucket issue + megastep weight-AG reorder, with
-        # XLA's async-collective/latency-hiding flags enabled so the
-        # issued collectives actually pipeline behind remaining compute.
-        # Resolved per Runner so paired on/off benches share one process.
-        self._overlap = (const.ENV.AUTODIST_OVERLAP.val
-                         if overlap is None else bool(overlap))
-        if self._overlap:
-            from autodist_tpu.kernel import overlap as overlap_mod
-            overlap_mod.apply_overlap_flags()
         self._grad_order = None  # lazy {var_name: production index}
         if self._item.optimizer is None:
             raise ValueError("GraphItem has no optimizer; capture with an optax "
@@ -240,7 +230,7 @@ class Runner:
     def _kind_of(self, name):
         return self.var_kinds.get(name, ("ar", None))
 
-    # -- overlap scheduler ---------------------------------------------------
+    # -- the fused reductions' issue plan -------------------------------------
 
     def grad_production_order(self):
         """{var_name: backward production index} (cached; ``{}`` when the
@@ -277,75 +267,6 @@ class Runner:
         return overlap_mod.bucket_plan(
             members, order=self.grad_production_order(),
             cap_bytes=overlap_mod.bucket_bytes_cap())
-
-    def _zero1_shardings_by_name(self):
-        """``(shard_by_name, full_by_name)`` for zero1 params: the
-        optimizer-state shard layout they are carried in across megastep
-        iterations, and the full (replicated) storage sharding the forward
-        needs — the two poles of the weight-AG reorder."""
-        shard_by_name, full_by_name = {}, {}
-        for path, sh in jax.tree_util.tree_flatten_with_path(
-                self.state_shardings.params,
-                is_leaf=lambda x: isinstance(x, NamedSharding))[0]:
-            name = path_to_name(path)
-            kind, dim = self._kind_of(name)
-            if kind != "zero1" or dim is None:
-                continue
-            spec = PartitionSpec(*([None] * dim), const.MESH_AXIS_DATA)
-            shard_by_name[name] = NamedSharding(self._mesh, spec)
-            full_by_name[name] = sh
-        return shard_by_name, full_by_name
-
-    def _constrain_zero1(self, params, shard_by_name, full_by_name,
-                         to_full):
-        def leaf(path, p):
-            name = path_to_name(path)
-            sh = shard_by_name.get(name)
-            if sh is None:
-                return p
-            return jax.lax.with_sharding_constraint(
-                p, full_by_name[name] if to_full else sh)
-        return jax.tree_util.tree_map_with_path(leaf, params)
-
-    @staticmethod
-    def _zero1_gather_at_use():
-        """True when ``AUTODIST_ZERO1_AG_SCOPE=use``: each zero1 param's
-        all-gather is anchored at its first forward use (per-layer
-        granularity) instead of one bulk gather at scan-body start."""
-        return (const.ENV.AUTODIST_ZERO1_AG_SCOPE.val or
-                "step").strip().lower() == "use"
-
-    def _wrap_gspmd_overlap(self, core):
-        """Weight-AG reorder for the GSPMD megastep (arXiv:2004.13336):
-        zero1 params are carried *sharded* across scan iterations and
-        constrained to their full (replicated) storage sharding right
-        before the forward, so step t's post-update all-gather lands
-        adjacent to step t+1's forward — where the collective pipeliner /
-        latency-hiding scheduler can hide it behind forward compute.
-        Values are unchanged (the gather merely moves); the final carry is
-        gathered once by the megastep's ``out_shardings``.
-
-        Under ``AUTODIST_ZERO1_AG_SCOPE=use`` the bulk body-start gather
-        is skipped: the loss itself carries per-param constraints at each
-        first forward use (``inject.wrap_with_param_constraints`` — see
-        ``_gspmd_step_fn``), so each layer's gather is issued where that
-        layer needs it and earlier layers' compute hides it."""
-        shard_by_name, full_by_name = self._zero1_shardings_by_name()
-        if not shard_by_name:
-            return core
-        at_use = self._zero1_gather_at_use()
-
-        def overlap_core(state, batch):
-            if at_use:
-                gathered = state.params
-            else:
-                gathered = self._constrain_zero1(
-                    state.params, shard_by_name, full_by_name, to_full=True)
-            state, metrics = core(state._replace(params=gathered), batch)
-            sharded = self._constrain_zero1(
-                state.params, shard_by_name, full_by_name, to_full=False)
-            return state._replace(params=sharded), metrics
-        return overlap_core
 
     # -- sharding assembly ---------------------------------------------------
 
@@ -642,17 +563,6 @@ class Runner:
             from autodist_tpu.automap import inject
             loss_fn = inject.wrap_with_constraints(
                 loss_fn, ctx.op_shardings, self._mesh)
-        if self._overlap and self._zero1_gather_at_use():
-            # Per-layer AG granularity (AUTODIST_ZERO1_AG_SCOPE=use):
-            # anchor each zero1 param's gather-to-full at its first
-            # forward use, so the megastep's sharded carry is gathered
-            # layer-by-layer behind earlier layers' compute instead of
-            # in one bulk constraint at body start.
-            _, full_by_name = self._zero1_shardings_by_name()
-            if full_by_name:
-                from autodist_tpu.automap import inject
-                loss_fn = inject.wrap_with_param_constraints(
-                    loss_fn, full_by_name)
 
         def padded_loss(padded_params, batch):
             # Slice off storage padding before the user program: gradients
@@ -678,27 +588,6 @@ class Runner:
                 return jax.lax.with_sharding_constraint(g, sh)
             return g
 
-        overlap_on = self._overlap
-
-        def ordered_constrain(grads):
-            # Overlap mode: trace the per-variable sharding constraints —
-            # the anchors GSPMD turns into the bucketed reductions — in
-            # grad-production order (reverse layer order), so the emitted
-            # collective chain follows "as gradients become available"
-            # and the latency-hiding scheduler sees independent chains.
-            flat, treedef = jax.tree_util.tree_flatten_with_path(grads)
-            shardings = jax.tree_util.tree_leaves(
-                grad_shardings,
-                is_leaf=lambda x: isinstance(x, NamedSharding))
-            order = self.grad_production_order()
-            big = len(flat) + len(order) + 1
-            out = [None] * len(flat)
-            for i in sorted(range(len(flat)),
-                            key=lambda i: (order.get(
-                                path_to_name(flat[i][0]), big), i)):
-                out[i] = constrain(flat[i][1], shardings[i])
-            return jax.tree_util.tree_unflatten(treedef, out)
-
         def step_fn(state, batch):
             if item.aux_output:
                 (loss, aux), grads = vg(state.params, batch)
@@ -706,11 +595,8 @@ class Runner:
                 loss, grads = vg(state.params, batch)
                 aux = None
             with jax.named_scope("grad_sync"):
-                if overlap_on:
-                    grads = ordered_constrain(grads)
-                else:
-                    grads = jax.tree_util.tree_map(constrain, grads,
-                                                   grad_shardings)
+                grads = jax.tree_util.tree_map(constrain, grads,
+                                               grad_shardings)
             with jax.named_scope("optimizer"):
                 updates, opt_state = opt.update(grads, state.opt_state,
                                                 state.params)
@@ -772,8 +658,7 @@ class Runner:
             registry.gauge("grad_sync.compiler_leaves").set(
                 scatter.compiler_leaves)
 
-    def _explicit_step_fn(self, batch_specs, zero1_as_fsdp=False,
-                          async_min_bytes=None):
+    def _explicit_step_fn(self, batch_specs, async_min_bytes=None):
         """Traceable shard_map step for the explicit path (manual over
         ``data``, GSPMD elsewhere; the megastep scans this same core).
 
@@ -797,15 +682,6 @@ class Runner:
         illegal) — a strategy carrying them onto this path gets an
         ``anchors-skipped`` flight event and a report warning instead of
         silence.
-
-        ``zero1_as_fsdp`` is the megastep weight-AG reorder
-        (arXiv:2004.13336, ``AUTODIST_OVERLAP``): zero1 params are carried
-        in shard form between scan iterations and all-gathered at the TOP
-        of the body — adjacent to the forward — instead of after the
-        update, exactly the fsdp storage contract, so they share its
-        lowering (gather for compute, gradient born reduce-scattered by
-        the gather VJP, shard-local update).  Same collectives, same
-        values; only the schedule position of the AG moves.
 
         ``async_min_bytes`` is the full gradient's size from which an
         ``fsdp`` leaf that the model hands to ``layer_boundary`` takes the
@@ -833,11 +709,6 @@ class Runner:
             if self._obs is not None:
                 self._obs.record_event("anchors-skipped", msg)
 
-        def kind_of(name):
-            kind, dim = self._kind_of(name)
-            if zero1_as_fsdp and kind == "zero1":
-                return "fsdp", dim
-            return kind, dim
         axis = const.MESH_AXIS_DATA
         n = prog.data_axis_size
         opt = self._opt
@@ -875,7 +746,7 @@ class Runner:
             # copies, then slice off uneven-shard padding.
             def gather(path, x):
                 name = path_to_name(path)
-                kind, dim = kind_of(name)
+                kind, dim = self._kind_of(name)
                 if kind == "stale":
                     return x[0]
                 if kind == "fsdp":
@@ -893,7 +764,7 @@ class Runner:
                     jax.tree_util.tree_flatten_with_path(full)[0],
                     jax.tree_util.tree_leaves(storage_params)):
                 name = path_to_name(path)
-                kind, dim = kind_of(name)
+                kind, dim = self._kind_of(name)
                 if kind == "fsdp" and name not in self._paddings:
                     scatter.offer(whole, shard, dim)
             ctx = prog.parallel_context()
@@ -940,7 +811,7 @@ class Runner:
             for name in issue_order:
                 g = named_grads[name]
                 s = syncs.get(name)
-                kind, dim = kind_of(name)
+                kind, dim = self._kind_of(name)
                 if s is None:
                     out[name] = jax.lax.pmean(g, axis)
                     continue
@@ -1072,7 +943,7 @@ class Runner:
             # optimizer state (shards for zero1/fsdp, full for ar, squeezed
             # for stale).
             def update_view(name, p_storage):
-                kind, dim = kind_of(name)
+                kind, dim = self._kind_of(name)
                 if kind == "stale":
                     return p_storage[0]
                 if kind == "zero1":
@@ -1100,7 +971,7 @@ class Runner:
             # Back to storage layout.
             def to_storage(path, p_new):
                 name = path_to_name(path)
-                kind, dim = kind_of(name)
+                kind, dim = self._kind_of(name)
                 if kind == "stale":
                     s = syncs[name]
                     period = s.staleness + 1
@@ -1129,20 +1000,10 @@ class Runner:
                                    new_sync)
             return new_state, self._metrics(loss, aux)
 
-        # Manual (data-axis) components of the storage shardings.  Under
-        # the weight-AG reorder, zero1 params are carried in shard form:
-        # their manual spec is the optimizer-state shard layout, not the
-        # replicated storage spec.
-        def param_manual(path, sh):
-            name = path_to_name(path)
-            kind, dim = kind_of(name)
-            if zero1_as_fsdp and dim is not None and \
-                    self._kind_of(name)[0] == "zero1":
-                return PartitionSpec(*([None] * dim), const.MESH_AXIS_DATA)
-            return _manual_component(sh.spec)
-        param_specs = jax.tree_util.tree_map_with_path(
-            param_manual, self.state_shardings.params,
-            is_leaf=lambda x: isinstance(x, NamedSharding))
+        # Manual (data-axis) components of the storage shardings.
+        param_specs = jax.tree_util.tree_map(
+            lambda sh: _manual_component(sh.spec),
+            self.state_shardings.params)
         opt_specs = jax.tree_util.tree_map(
             lambda sh: _manual_component(sh.spec),
             self.state_shardings.opt_state)
@@ -1396,7 +1257,7 @@ class Runner:
     def _megastep_fn(self, block, k):
         """Get-or-build the fused K-step dispatch for this block shape."""
         leaves, treedef = jax.tree_util.tree_flatten(block)
-        key = ("megastep", k, self._overlap, treedef,
+        key = ("megastep", k, treedef,
                tuple((tuple(jnp.shape(l)), jnp.result_type(l))
                      for l in leaves))
         fn = self._jit_cache.get(key)
@@ -1407,40 +1268,20 @@ class Runner:
                 jax.ShapeDtypeStruct(tuple(jnp.shape(l))[1:],
                                      jnp.result_type(l)) for l in leaves])
             specs = self._program.batch_specs(sample)
-            # Weight-AG reorder (AUTODIST_OVERLAP + zero1 vars): carry
-            # zero1 params SHARDED between scan iterations and gather
-            # them adjacent to the next forward, so XLA's collective
-            # pipeliner can hide the AG behind forward compute
-            # (arXiv:2004.13336).  One gather restores the storage form
-            # after the scan (the jit's out_shardings).
-            overlap_ag = (self._overlap and k > 1 and any(
-                kd[0] == "zero1" for kd in self.var_kinds.values()))
             if self._program.use_explicit_path:
-                core = self._explicit_step_fn(specs,
-                                              zero1_as_fsdp=overlap_ag)
+                core = self._explicit_step_fn(specs)
                 block_shardings = None
             else:
                 core = self._gspmd_step_fn()
-                if overlap_ag:
-                    core = self._wrap_gspmd_overlap(core)
                 block_shardings = self._named(jax.tree_util.tree_map(
                     lambda s: PartitionSpec(None, *s), specs,
                     is_leaf=lambda x: isinstance(x, PartitionSpec)))
-            if overlap_ag:
-                shard_by_name, full_by_name = self._zero1_shardings_by_name()
 
             def megastep_fn(state, blk):
                 # The Python step loop moves on device: one dispatch, K
                 # steps.  Per-step metrics come back stacked (K,); the
                 # notfinite flag aggregates on device so the StepGuard
                 # host-checks ONE scalar per cadence, never K.
-                if overlap_ag:
-                    # Enter the scan with zero1 params already in shard
-                    # form so the carry sharding is stable (no per-
-                    # iteration reshard thrash).
-                    state = state._replace(params=self._constrain_zero1(
-                        state.params, shard_by_name, full_by_name,
-                        to_full=False))
                 state, metrics = jax.lax.scan(core, state, blk, length=k)
                 metrics["notfinite"] = jnp.any(metrics["notfinite"])
                 return state, metrics
@@ -1491,20 +1332,19 @@ class Runner:
         """Auto-compose a framework loader with the depth-N
         DevicePrefetcher (and, under unroll, the BlockStacker) so
         loader-fed loops overlap transfer-settle with compute by default
-        (``AUTODIST_PREFETCH_DEPTH``).  Returns ``(iterator,
-        yields_blocks)``: with ``yields_blocks`` the iterator hands out
-        device-placed K-blocks, one per megastep dispatch."""
+        (``loader.PREFETCH_DEPTH`` transfers in flight).  Returns
+        ``(iterator, yields_blocks)``: with ``yields_blocks`` the iterator
+        hands out device-placed K-blocks, one per megastep dispatch."""
         from autodist_tpu.data.loader import (BlockStacker, DevicePrefetcher,
                                               NativeDataLoader)
         if not isinstance(data_iter, NativeDataLoader):
             return data_iter, False
-        depth = max(0, const.ENV.AUTODIST_PREFETCH_DEPTH.val)
         if unroll > 1:
             stacker = BlockStacker(data_iter, unroll, recycle_to=data_iter)
             return DevicePrefetcher(
-                stacker, self._remapper, depth=depth, loader=stacker,
+                stacker, self._remapper, loader=stacker,
                 shard_fn=self._remapper.shard_block), True
-        return DevicePrefetcher(data_iter, self._remapper, depth=depth,
+        return DevicePrefetcher(data_iter, self._remapper,
                                 loader=data_iter), False
 
     @property
@@ -1817,10 +1657,6 @@ class Runner:
                 # Unroll badge: report/telemetry readers must interpret
                 # step.latency_ms as per-dispatch/K.
                 reg.gauge("step.unroll").set(k)
-            if obs is not None and self._overlap:
-                # Overlap badge: the Telemetry section pairs this with
-                # comms.exposed_ms_per_step into an overlap-efficiency row.
-                reg.gauge("step.overlap").set(1)
             if step_guard is not None:
                 step_guard.mark_good(0, state)
             i = 0

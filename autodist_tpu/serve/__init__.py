@@ -13,7 +13,7 @@ constraints (docs/serving.md):
   prefetch overlap on the request path;
 * :mod:`~autodist_tpu.serve.server` — the continuous-batching
   :class:`Server`: ``submit() -> Future``, coalescing under a max-wait
-  deadline (``AUTODIST_SERVE_MAX_WAIT_MS``), FIFO packing, exact
+  deadline (``server.MAX_WAIT_MS`` where none is passed), FIFO packing, exact
   per-request de-padding;
 * :mod:`~autodist_tpu.serve.decode` — the autoregressive
   :class:`DecodeServer`: slot-based KV-cache continuous batching

@@ -326,7 +326,7 @@ class DecodeEngine:
             from autodist_tpu.tuner.calibration import Calibration
             from autodist_tpu.tuner.cost_model import CostModel, Topology
             cal = Calibration.load()
-            model = CostModel(Topology.from_resource_spec(spec, cal), cal)
+            model = CostModel(Topology.from_resource_spec(spec), cal)
         except Exception as e:  # noqa: BLE001 - advisory check only
             logging.debug("decode bucket memory check unavailable: %s", e)
             return

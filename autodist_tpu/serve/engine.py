@@ -470,7 +470,7 @@ class ServeEngine:
             from autodist_tpu.tuner.calibration import Calibration
             from autodist_tpu.tuner.cost_model import CostModel, Topology
             cal = Calibration.load()
-            model = CostModel(Topology.from_resource_spec(spec, cal), cal)
+            model = CostModel(Topology.from_resource_spec(spec), cal)
         except Exception as e:  # noqa: BLE001 - advisory check only
             logging.debug("serve bucket memory check unavailable: %s", e)
             return

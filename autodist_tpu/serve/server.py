@@ -36,6 +36,8 @@ from autodist_tpu.serve.engine import ServeEngine
 from autodist_tpu.utils import logging
 
 _STOP = object()
+#: Milliseconds the oldest queued request waits for companions.
+MAX_WAIT_MS = 5
 
 
 class _Request:
@@ -66,7 +68,7 @@ class Server:
             ``AUTODIST_SERVE_BUCKETS``, else ``(8, 32, 128)``).  Each must
             be a multiple of the per-replica device count.
         max_wait_ms: continuous-batching coalesce deadline (default
-            ``AUTODIST_SERVE_MAX_WAIT_MS``): how long the oldest queued
+            :data:`MAX_WAIT_MS`): how long the oldest queued
             request may wait for companions before its bucket dispatches.
         replicas: independent model replicas to carve the mesh into
             (least-loaded dispatch; data-only strategies).
@@ -76,7 +78,7 @@ class Server:
     """
 
     def __init__(self, apply_fn, params, example_batch, buckets=None,
-                 max_wait_ms=None, replicas=1, strategy_builder=None,
+                 max_wait_ms=MAX_WAIT_MS, replicas=1, strategy_builder=None,
                  resource_spec=None, prefetch_depth=None):
         bucket_list = buckets_from_env() if buckets is None else buckets
         self._engine = ServeEngine(apply_fn, params, example_batch,
@@ -87,8 +89,6 @@ class Server:
         self._buckets = self._engine.buckets
         self._bucket_rank = self._engine.bucket_rank
         self._max_rows = self._engine.max_rows
-        if max_wait_ms is None:
-            max_wait_ms = const.ENV.AUTODIST_SERVE_MAX_WAIT_MS.val
         self._max_wait_s = max(0.0, float(max_wait_ms)) / 1e3
         self._obs = observability if observability.enabled() else None
         self._seq = itertools.count()

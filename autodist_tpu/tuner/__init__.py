@@ -18,7 +18,7 @@ topology, and exposes the argmin as the :class:`AutoStrategy` builder —
 from autodist_tpu.tuner.auto import (AutoStrategy, builder_from_name,
                                      last_result, record_measurement,
                                      set_last_result)
-from autodist_tpu.tuner.calibration import Calibration, micro_probe
+from autodist_tpu.tuner.calibration import Calibration
 from autodist_tpu.tuner.cost_model import CostModel, Topology
 from autodist_tpu.tuner.search import (CANDIDATE_FAMILIES, OBJECTIVES,
                                        TuningResult, enumerate_candidates,
@@ -28,7 +28,7 @@ from autodist_tpu.tuner.search import (CANDIDATE_FAMILIES, OBJECTIVES,
 __all__ = [
     "AutoStrategy", "builder_from_name", "last_result",
     "record_measurement", "set_last_result",
-    "Calibration", "micro_probe",
+    "Calibration",
     "CostModel", "Topology",
     "CANDIDATE_FAMILIES", "OBJECTIVES", "TuningResult",
     "enumerate_candidates", "resolve_objective", "search",

@@ -17,9 +17,6 @@ paths converge on reality:
   the global scale — the model learns WHICH term is wrong, not just a
   single fudge factor — via :attr:`compute_scale` / :attr:`comms_scale`,
   which the cost model applies per class.
-* **Micro-probes** (opt-in, ``AUTODIST_TUNER_PROBE=1``) — a one-shot pair
-  of small/large all-reduces on the live mesh separates per-collective
-  latency from bandwidth and stores tier overrides.
 
 A fitted per-dispatch host overhead persists as :attr:`host_dispatch_ms`
 (nothing in the tree fits one today: ROADMAP D3) — the attribution ledger's
@@ -35,7 +32,6 @@ import os
 import time
 
 from autodist_tpu import const
-from autodist_tpu.resource_spec import Connectivity
 from autodist_tpu.utils import logging
 
 MAX_SAMPLES = 50
@@ -43,9 +39,6 @@ EMA_ALPHA = 0.3
 # Clamp the EMA scale: a single wild measurement (cold caches, CI host
 # contention) must not invert every future ranking.
 SCALE_BOUNDS = (0.02, 50.0)
-
-_TIER_KEYS = {"ici": Connectivity.ICI, "local": Connectivity.LOCAL,
-              "dcn": Connectivity.DCN}
 
 
 def default_path():
@@ -56,13 +49,10 @@ def default_path():
 class Calibration:
     """Persisted refinement state for the cost model."""
 
-    def __init__(self, scale=1.0, samples=None, link_overrides=None,
-                 term_scales=None, host_dispatch_ms=None, last_mfu=None,
-                 path=None):
+    def __init__(self, scale=1.0, samples=None, term_scales=None,
+                 host_dispatch_ms=None, last_mfu=None, path=None):
         self.scale = float(scale)
         self.samples = list(samples or [])
-        # {"ici": {"bandwidth": ..., "latency": ...}, ...}
-        self.link_overrides = dict(link_overrides or {})
         # Per-class refinement on top of the global scale (attribution
         # feedback): {"compute": ..., "comms": ...}.
         self.term_scales = {"compute": 1.0, "comms": 1.0,
@@ -98,7 +88,6 @@ class Calibration:
                 data = json.load(f)
             return cls(scale=data.get("scale", 1.0),
                        samples=data.get("samples", []),
-                       link_overrides=data.get("link_overrides", {}),
                        term_scales=data.get("term_scales", {}),
                        host_dispatch_ms=data.get("host_dispatch_ms"),
                        last_mfu=data.get("last_mfu"),
@@ -116,8 +105,7 @@ class Calibration:
                                            in self.term_scales.items()},
                            "host_dispatch_ms": self.host_dispatch_ms,
                            "last_mfu": self.last_mfu,
-                           "samples": self.samples[-MAX_SAMPLES:],
-                           "link_overrides": self.link_overrides}, f,
+                           "samples": self.samples[-MAX_SAMPLES:]}, f,
                           indent=1)
             os.replace(tmp, self.path)
             return self.path
@@ -184,7 +172,7 @@ class Calibration:
         warning names both."""
         if mfu is None or mfu <= 0:
             return self.last_mfu
-        self.last_mfu = round(float(mfu), 6)
+        self.last_mfu = float(mfu)
         if self.last_mfu > 1.0:
             logging.warning(
                 "goodput MFU %.3f > 1 (%s): the peak-flops table "
@@ -228,70 +216,6 @@ class Calibration:
                                     ratio * EMA_ALPHA))
         return out
 
-    def apply_link_overrides(self, links):
-        """Overlay stored per-tier (bandwidth, latency) onto seed links."""
-        out = dict(links)
-        for key, tier in _TIER_KEYS.items():
-            ov = self.link_overrides.get(key)
-            if not ov:
-                continue
-            bw, lat = out.get(tier, (None, None))
-            out[tier] = (float(ov.get("bandwidth", bw)),
-                         float(ov.get("latency", lat)))
-        return out
-
     def prediction_error_pct(self):
         """Signed error of the most recent sample (None if no samples)."""
         return self.samples[-1]["error_pct"] if self.samples else None
-
-
-def micro_probe(calibration=None):
-    """One-shot collective probe on the live backend (opt-in knob
-    ``AUTODIST_TUNER_PROBE``): times a tiny and a large all-reduce over
-    every device; the small one estimates per-collective latency, the
-    byte-delta over time-delta estimates bandwidth.  Stores the result as
-    the intra-tier link override.  Fail-open — probing must never block
-    strategy building.
-    """
-    if not const.ENV.AUTODIST_TUNER_PROBE.val:
-        return None
-    cal = calibration or Calibration.load()
-    try:
-        import numpy as np
-        import jax
-        import jax.numpy as jnp
-        import time as _t
-        devs = jax.devices()
-        if len(devs) < 2:
-            return None
-        mesh = jax.sharding.Mesh(np.array(devs), ("probe",))
-        small_n, big_n = 256, 1 << 20  # f32 elements
-
-        def timed(n):
-            fn = jax.jit(jax.shard_map(
-                lambda x: jax.lax.psum(x, "probe"), mesh=mesh,
-                in_specs=jax.sharding.PartitionSpec(),
-                out_specs=jax.sharding.PartitionSpec()))
-            x = jnp.zeros((n,), jnp.float32)
-            jax.block_until_ready(fn(x))  # compile + warm
-            t0 = _t.perf_counter()
-            for _ in range(5):
-                out = fn(x)
-            jax.block_until_ready(out)
-            return (_t.perf_counter() - t0) / 5
-
-        t_small, t_big = timed(small_n), timed(big_n)
-        d_bytes = (big_n - small_n) * 4
-        d_t = max(1e-9, t_big - t_small)
-        tier = "ici" if devs[0].platform == "tpu" else "local"
-        cal.link_overrides[tier] = {
-            "bandwidth": max(1e6, d_bytes / d_t),
-            "latency": max(1e-9, t_small / (2 * max(1, len(devs) - 1)))}
-        cal.save()
-        logging.info("tuner micro-probe: %s bw=%.2e B/s lat=%.2e s",
-                     tier, cal.link_overrides[tier]["bandwidth"],
-                     cal.link_overrides[tier]["latency"])
-        return cal.link_overrides[tier]
-    except Exception as e:  # noqa: BLE001 - probing is best-effort
-        logging.warning("tuner micro-probe failed: %s", e)
-        return None

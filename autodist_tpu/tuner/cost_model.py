@@ -68,8 +68,8 @@ class Topology:
 
     Constructed from a :class:`~autodist_tpu.resource_spec.ResourceSpec`
     (device/host counts from the spec, tier parameters from the seeds,
-    the spec's ``interconnect:`` block, then calibration), or directly in
-    tests with synthetic shapes.
+    the spec's ``interconnect:`` block), or directly in tests with
+    synthetic shapes.
     """
 
     def __init__(self, num_devices, num_hosts=1, links=None,
@@ -109,7 +109,7 @@ class Topology:
             return float(PLATFORM_FALLBACK_HBM_GB) * (1 << 30)
 
     @classmethod
-    def from_resource_spec(cls, resource_spec, calibration=None):
+    def from_resource_spec(cls, resource_spec):
         links = dict(DEFAULT_LINKS)
         for tier, key in ((Connectivity.ICI, "ici"),
                           (Connectivity.LOCAL, "local"),
@@ -122,8 +122,6 @@ class Topology:
             if us:
                 lat = float(us) * 1e-6
             links[tier] = (bw, lat)
-        if calibration is not None:
-            links = calibration.apply_link_overrides(links)
         n = max(1, len(resource_spec.accelerator_devices))
         hbm = None
         try:
@@ -478,16 +476,15 @@ class CostModel:
     # -- per-variable sync cost ---------------------------------------------
 
     def _var_sync_cost(self, var, node, n_data, ar_buckets, hier=None):
-        """Per-variable collective time split by *overlap class*, OR defer
+        """Per-variable collective time split by kind, OR defer
         fused all-reduce bytes into ``ar_buckets`` (per fusion group:
         ``[wire_bytes, raw_bytes, dcn_codec, sparse_wire_bytes]``; the
         codec is the ``hier`` exec-knob override, else the node's own
         ``spec: DCN`` selection, else None = flat; sparse-access bytes
         ride the last slot, exempt from the codec).  Returns
         ``(rs_s, ag_s, other_s, elements_updated_per_device, wire_bytes)``:
-        reduce-scatter-class time overlaps backward compute, all-gather-
-        class time overlaps the NEXT forward (inside a megastep),
-        ``other`` never overlaps (stale-period averages)."""
+        reduce-scatter-class time, all-gather-class time and ``other``
+        (stale-period averages)."""
         topo = self.topology
         size = float(var.size_bytes)
         if node is None:  # replicated, no sync recorded
@@ -549,8 +546,8 @@ class CostModel:
 
     # -- whole-candidate cost -----------------------------------------------
 
-    def strategy_cost(self, strategy, graph_item, unroll=1, overlap=False,
-                      bucket_bytes=0, microbatches=None, hier=None):
+    def strategy_cost(self, strategy, graph_item, unroll=1, bucket_bytes=0,
+                      microbatches=None, hier=None):
         """Predicted per-step cost of ``strategy`` on this topology.
 
         ``unroll=K`` amortizes the per-dispatch host overhead over K
@@ -569,18 +566,10 @@ class CostModel:
         ``spec: DCN`` themselves are priced hierarchically anyway, so a
         built hierarchical strategy artifact reprices faithfully.
 
-        ``overlap=True`` prices the latency-hiding schedule
-        (``AUTODIST_OVERLAP``): grad-sync buckets and reduce-scatters are
-        issued as gradients become available, so only
-        ``exposed = max(0, bucket_comms - overlappable_backward_compute)``
-        accumulated per bucket hits the step; ZeRO weight all-gathers
-        overlap the NEXT step's forward inside a megastep (``unroll > 1``),
-        so their exposed cost is ``max(0, ag - forward)``.  With
-        ``bucket_bytes`` each fusion group is split into
-        ceil(bytes/cap)-sized buckets, each paying its own collective
-        latency — the knob the tuner ranks (more buckets = finer issue
-        granularity but more latency terms; the model keeps the latency
-        half, which is the part that ranks).
+        Communication is priced serially, every collective in line with
+        the step (``sync_ms``).  With ``bucket_bytes`` each fusion group
+        is split into ceil(bytes/cap)-sized buckets, each paying its own
+        collective latency.
         """
         topo = self.topology
         unroll = max(1, int(unroll))
@@ -689,25 +678,8 @@ class CostModel:
             compute_s = busy_s * (mb + n_pipe - 1) / mb
             bubble_ms = (compute_s - busy_s) * 1e3
 
-        # Serialized comms (the pre-overlap model): everything in line.
-        serial_sync_s = sum(bucket_costs) + rs_s + ag_s + other_s
-        sync_s = serial_sync_s
-        if overlap:
-            # Backward compute hides grad-sync buckets + reduce-scatters,
-            # consumed in issue order; the next step's forward hides the
-            # ZeRO weight all-gather — but only when the megastep puts
-            # both steps in one program (unroll > 1).
-            backward_s = compute_s * 2.0 / 3.0
-            exposed = 0.0
-            budget = backward_s
-            for c in bucket_costs + [rs_s]:
-                exposed += max(0.0, c - budget)
-                budget = max(0.0, budget - c)
-            if unroll > 1:
-                exposed += max(0.0, ag_s - compute_s / 3.0)
-            else:
-                exposed += ag_s
-            sync_s = exposed + other_s
+        # Serialized comms: everything in line.
+        sync_s = sum(bucket_costs) + rs_s + ag_s + other_s
 
         # Non-data overlay axes (model/seq/expert) move activations every
         # step: a coarse per-axis term on the captured batch footprint —
@@ -747,15 +719,13 @@ class CostModel:
                          microbatches=mb, pipeline_stages=n_pipe)
         return CostBreakdown(
             total_ms=total_ms,
-            sync_ms=serial_sync_s * 1e3,
-            exposed_sync_ms=sync_s * 1e3,
+            sync_ms=sync_s * 1e3,
             update_ms=update_s * 1e3,
             compute_ms=compute_s * 1e3,
             overlay_ms=overlay_s * 1e3,
             **extra,
             dispatch_ms=dispatch_ms,
             unroll=unroll,
-            overlap=bool(overlap),
             bucket_mb=(cap / (1 << 20) if cap else 0),
             n_buckets=len(bucket_costs),
             wire_mb=wire_bytes / 1e6,
@@ -922,8 +892,8 @@ class CostModel:
         # Input staging: K unrolled batches per dispatch, plus the
         # prefetch pipeline's in-flight copies, at the per-device shard.
         batch_dev = _batch_bytes(graph_item) * row_scale / n_data
-        prefetch = max(0, int(const.ENV.AUTODIST_PREFETCH_DEPTH.val))
-        staging = batch_dev * unroll * (1 + prefetch)
+        from autodist_tpu.data.loader import PREFETCH_DEPTH
+        staging = batch_dev * unroll * (1 + PREFETCH_DEPTH)
         # Largest in-flight collective staging buffer: one fusion bucket
         # (capped by the bucket-size knob when set).
         cap = max(0, int(bucket_bytes or 0))

@@ -36,7 +36,7 @@ from autodist_tpu.strategy.random_axis_partition_all_reduce_strategy import \
 from autodist_tpu.strategy.sequence_parallel_strategy import SequenceParallel
 from autodist_tpu.strategy.uneven_partition_ps_strategy import \
     UnevenPartitionedPS
-from autodist_tpu.tuner.calibration import Calibration, micro_probe
+from autodist_tpu.tuner.calibration import Calibration
 from autodist_tpu.tuner.cost_model import CostModel, Topology
 from autodist_tpu.utils import logging
 
@@ -56,17 +56,13 @@ OBJECTIVES = {
 DEFAULT_OBJECTIVE = "train_step"
 
 #: Execution-knob variants priced per candidate under the ``train_step``
-#: objective: the latency-hiding overlap scheduler on/off and the
-#: ``AUTODIST_AR_BUCKET_MB`` fusion-bucket cap (docs/usage/performance.md).
-#: Variants reuse the already-built strategy — they cost one extra model
-#: evaluation each, never an extra build — and the per-candidate winner is
-#: chosen by ``(rounded cost, label)``, the serialized baseline first on
-#: ties, so rankings stay chief/worker-deterministic.
+#: objective: the pipeline's microbatch count.  Variants reuse the
+#: already-built strategy — they cost one extra model evaluation each,
+#: never an extra build — and the per-candidate winner is chosen by
+#: ``(rounded cost, label)``, the serialized baseline first on ties, so
+#: rankings stay chief/worker-deterministic.
 EXEC_VARIANTS = (
     ("", {}),
-    ("+overlap", {"overlap": True}),
-    ("+overlap/bucket=4MB", {"overlap": True, "bucket_bytes": 4 << 20}),
-    ("+overlap/bucket=32MB", {"overlap": True, "bucket_bytes": 32 << 20}),
     # Pipeline exec knob: the GPipe microbatch count trades bubble
     # fraction (S-1)/(S+M-1) against per-microbatch dispatch granularity.
     # A no-op (identical cost, so the baseline label wins the tie) for
@@ -179,7 +175,6 @@ def reprice(strategy, graph_item, cost_model, unrolls=(1,),
                 "label": f"unroll={k}{label}",
                 "unroll": k,
                 "knobs": {"unroll": k,
-                          "overlap": bool(bd.get("overlap")),
                           "bucket_mb": int(bd.get("bucket_mb") or 0),
                           "microbatches": (int(bd["microbatches"])
                                            if bd.get("microbatches")
@@ -500,9 +495,8 @@ def search(graph_item, resource_spec, budget=None, cost_model=None,
     term, param gathers charged per request (docs/serving.md).
     """
     cal = calibration or Calibration.load()
-    micro_probe(cal)  # no-op unless AUTODIST_TUNER_PROBE=1
     if cost_model is None:
-        topo = Topology.from_resource_spec(resource_spec, cal)
+        topo = Topology.from_resource_spec(resource_spec)
         cost_model = CostModel(topo, cal)
     obj_name, obj_fn = resolve_objective(objective)
     budget = effective_budget(budget)
@@ -519,8 +513,8 @@ def search(graph_item, resource_spec, budget=None, cost_model=None,
             pruned.append({"name": cand.name, "reason": str(e)[:160]})
             continue
         # Price every exec-knob variant of this plan and keep the best:
-        # overlap/bucket knobs join the search space without consuming
-        # build budget (the strategy object is shared).
+        # the knobs join the search space without consuming build budget
+        # (the strategy object is shared).
         best_label, best_bd = None, None
         for label, kw in exec_variants:
             bd = obj_fn(cost_model, strategy, graph_item,
@@ -530,7 +524,6 @@ def search(graph_item, resource_spec, budget=None, cost_model=None,
                 best_label, best_bd = label, bd
         knobs = dict(cand.knobs)
         if obj_name == DEFAULT_OBJECTIVE:
-            knobs["overlap"] = bool(best_bd.get("overlap"))
             knobs["ar_bucket_mb"] = best_bd.get("bucket_mb", 0)
             if best_bd.get("microbatches"):
                 # The winning microbatch knob becomes the artifact: the

@@ -3,8 +3,7 @@
 XLA hard-aborts the process on any unknown flag in ``XLA_FLAGS``
 (``parse_flags_from_env.cc: Unknown flags in XLA_FLAGS``) — there is no
 graceful degradation, so anything that adds a build-dependent flag (the
-test harness' CPU-collective terminate timeout, the overlap scheduler's
-backend flags) must check support first.
+test harness' CPU-collective terminate timeout) must check support first.
 
 A registered flag's name exists as a string literal in the jaxlib shared
 objects (``debug_options_flags.cc`` registers them from literals), so a

@@ -75,8 +75,7 @@ def main():
         assert not isinstance(ctl, controller_mod.FollowerController)
         decision = controller_mod.Decision(
             tier=1, label="exec:unroll=2",
-            knobs={"unroll": 2, "overlap": False, "bucket_mb": 0,
-                   "microbatches": 0},
+            knobs={"unroll": 2, "bucket_mb": 0, "microbatches": 0},
             strategy=None, strategy_name="",
             predicted_ms=1.0, incumbent_predicted_ms=2.0, measured_ms=2.0,
             margin_pct=50.0, remaining_steps=100)

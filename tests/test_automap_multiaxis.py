@@ -24,7 +24,7 @@ from autodist_tpu.parallel import moe
 from autodist_tpu.parallel.pipeline import (pipeline_apply,
                                             stack_stage_params)
 from autodist_tpu.resource_spec import ResourceSpec
-from autodist_tpu.strategy import AllReduce, ModelParallel, PS, Pipeline
+from autodist_tpu.strategy import AllReduce, ModelParallel, Pipeline
 from autodist_tpu.strategy.base import StrategyBuilder
 from autodist_tpu.tuner.calibration import Calibration
 from autodist_tpu.tuner.cost_model import CostModel, Topology
@@ -401,74 +401,3 @@ def test_1f1b_memory_hold_priced_below_gpipe(monkeypatch):
     assert f1b["activations_bytes"] == pytest.approx(
         gpipe["activations_bytes"] * 2 / 8)
     assert f1b.peak_bytes < gpipe.peak_bytes
-
-
-# -- satellite 3: zero1 gather-at-use ----------------------------------------
-
-
-def _mlp_loss(params, batch):
-    x, y = batch
-    h = jax.nn.relu(x @ params["w1"])
-    h = jax.nn.relu(h @ params["w2"])
-    return jnp.mean((h @ params["w3"] - y) ** 2)
-
-
-def _mlp_batches(n, seed=0):
-    rng = np.random.RandomState(seed)
-    return [(rng.randn(32, 8).astype(np.float32),
-             rng.randn(32, 4).astype(np.float32)) for _ in range(n)]
-
-
-def _zero1_runner(overlap, scope, monkeypatch):
-    monkeypatch.setenv("AUTODIST_OVERLAP", "1" if overlap else "0")
-    monkeypatch.setenv("AUTODIST_ZERO1_AG_SCOPE", scope)
-    _reset_default()
-    params = {"w1": jnp.zeros((8, 16)), "w2": jnp.zeros((16, 16)),
-              "w3": jnp.zeros((16, 4))}
-    ad = AutoDist(strategy_builder=PS(gspmd_update=True))
-    item = ad.capture(_mlp_loss, params, optax.adam(1e-2),
-                      example_batch=_mlp_batches(1)[0])
-    runner = ad.create_distributed_session(item)
-    monkeypatch.setattr(runner, "_obs", None)
-    return runner
-
-
-def test_zero1_gather_at_use_parity(monkeypatch):
-    """Per-layer AG granularity (AUTODIST_ZERO1_AG_SCOPE=use) is a pure
-    schedule change: the megastep trajectory is bitwise vs overlap-off."""
-    n = 8
-    batches = _mlp_batches(n)
-    ref = _zero1_runner(False, "step", monkeypatch)
-    s_ref = ref.create_state()
-    s_ref, _ = ref.run(s_ref, iter(batches), n, unroll=4)
-    want = {k: np.asarray(jax.device_get(v))
-            for k, v in ref.logical_params(s_ref).items()}
-
-    use = _zero1_runner(True, "use", monkeypatch)
-    assert use._overlap and use._zero1_gather_at_use()
-    assert all(k[0] == "zero1" for k in use.var_kinds.values())
-    s = use.create_state()
-    s, _ = use.run(s, iter(batches), n, unroll=4)
-    got = {k: np.asarray(jax.device_get(v))
-           for k, v in use.logical_params(s).items()}
-    for k in want:
-        np.testing.assert_array_equal(got[k], want[k], err_msg=k)
-
-
-def test_param_constraints_anchor_at_first_use():
-    """wrap_with_param_constraints injects exactly one constraint per
-    listed param, at its first consuming equation, values unchanged."""
-    from jax.sharding import NamedSharding, PartitionSpec
-    from autodist_tpu.automap import inject
-    mesh = Mesh(np.array(jax.devices()), axis_names=("data",))
-    full = {k: NamedSharding(mesh, PartitionSpec())
-            for k in ("w1", "w3")}
-    wrapped = inject.wrap_with_param_constraints(_mlp_loss, full)
-    params = {"w1": jnp.ones((8, 16)), "w2": jnp.ones((16, 16)),
-              "w3": jnp.ones((16, 4))}
-    batch = (jnp.ones((4, 8)), jnp.ones((4, 4)))
-    jx = jax.make_jaxpr(wrapped)(params, batch)
-    assert str(jx.jaxpr).count("sharding_constraint") == 2
-    a = _mlp_loss(params, batch)
-    b = wrapped(params, batch)
-    assert np.array_equal(np.asarray(a), np.asarray(b))

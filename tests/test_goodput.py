@@ -206,9 +206,13 @@ def test_runner_goodput_reconciles_and_mfu_sane(unroll, monkeypatch):
     state, _ = runner.run(state, _repeat(batch), 8, unroll=unroll)
     s = goodput.last_summary()
     assert s is not None and s["steps"] == 8
-    # Sum invariant: goodput + badput classes within 5% of measured wall.
+    # Sum invariant, on the ledger's own figures: goodput and the badput
+    # classes are built to add up to the wall it read, so they differ from
+    # it by the rounding of thirteen figures to 1e-3 and by no share of a
+    # clock.
     total = s["goodput_ms"] + sum(s["classes"].values())
-    assert total == pytest.approx(s["wall_ms"], rel=0.05, abs=1.0)
+    assert total == pytest.approx(s["wall_ms"], abs=0.05)
+    assert set(s["classes"]) == set(goodput.BADPUT_CLASSES)
     assert s["goodput_ms"] > 0
     assert s["mfu"] is not None and 0 < s["mfu"] <= 1
     assert s["hfu"] is not None and 0 < s["hfu"]
@@ -226,9 +230,10 @@ def test_runner_goodput_reconciles_and_mfu_sane(unroll, monkeypatch):
     # Chief persisted this generation's segment next to the flight log.
     segs = goodput.segments_for()
     assert len(segs) == 1 and segs[0]["steps"] == 8
-    # MFU fed to calibration as a sanity anchor (persisted rounded to 6
-    # decimals, so compare at that granularity).
-    assert Calibration.load().last_mfu == pytest.approx(s["mfu"], abs=1e-6)
+    # MFU fed to calibration as a sanity anchor, as the ledger computed
+    # it.  Compared relatively: the toy's is about 5e-7 against the CPU
+    # table's peak, so any fixed number of kept decimals would decide it.
+    assert Calibration.load().last_mfu == pytest.approx(s["mfu"], rel=1e-9)
 
 
 def test_goodput_ships_with_cluster_snapshot():

@@ -122,7 +122,7 @@ def test_three_adam_steps_through_the_runner_match_the_reference(steps=3):
                           example_batch=batches[0])
         runner = ad.create_distributed_session(item)
         assert not runner.program.use_explicit_path
-        # The overlap pass orders gradients by where the backward pass makes
+        # The bucket plan orders gradients by where the backward pass makes
         # them: a variable used twice (embedding, head) has one place.
         order = runner.grad_production_order()
         assert {"embed/embedding", "lm_head/kernel"} <= set(order)

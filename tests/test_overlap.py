@@ -1,29 +1,23 @@
-"""Latency-hiding collective scheduler (ISSUE 7): overlap-on/off parity,
-bucket-plan determinism, exposed-comms parsing, cost-model overlap term,
-tuner exec knobs, scheduled-HLO dump.
+"""What is left of ISSUE 7's module (``kernel/overlap.py``) once nothing but
+the compiler and ``grad_scatter`` orders a step's communication: bucket-plan
+determinism, exposed-comms parsing, the cost model's bucket and compressor
+terms, the tuner's exec variants, the scheduled-HLO dump.
 
-The contract under test: ``AUTODIST_OVERLAP=1`` restructures the step
-programs (reverse-layer bucket issue; zero1 params carried sharded inside
-a megastep so the weight all-gather sits adjacent to the next forward)
-WITHOUT changing values — trajectories match the serialized schedule
-bitwise for K in {1, 4} on both execution paths — while the bucket issue
-plan stays a pure, chief/worker-identical function of the captured
-program, and the exposed-comms metric is computed from scheduled-HLO
-async start/done windows.
+The bucket issue plan stays a pure, chief/worker-identical function of the
+captured program, and the exposed-comms figure is computed from
+scheduled-HLO async start/done windows.
 """
-import os
-
 import numpy as np
 import jax
 import jax.numpy as jnp
 import optax
 import pytest
 
-from autodist_tpu import AutoDist, const, observability
+from autodist_tpu import AutoDist, observability
 from autodist_tpu.autodist import _reset_default
 from autodist_tpu.graph_item import GraphItem, VariableItem
 from autodist_tpu.kernel import overlap
-from autodist_tpu.strategy import PS, AllReduce
+from autodist_tpu.strategy import AllReduce
 from autodist_tpu.tuner.search import EXEC_VARIANTS
 from autodist_tpu.tuner.cost_model import (CostModel, Topology,
                                            _compressor_factor)
@@ -44,8 +38,7 @@ def _batches(n, seed=0):
              rng.randn(BATCH, 4).astype(np.float32)) for _ in range(n)]
 
 
-def _build(builder, overlap_on, monkeypatch):
-    monkeypatch.setenv("AUTODIST_OVERLAP", "1" if overlap_on else "0")
+def _build(builder, monkeypatch):
     _reset_default()
     params = {"w1": jnp.zeros((8, 16)), "w2": jnp.zeros((16, 16)),
               "w3": jnp.zeros((16, 4))}
@@ -57,63 +50,6 @@ def _build(builder, overlap_on, monkeypatch):
     return runner
 
 
-def _params_np(runner, state):
-    return {k: np.asarray(jax.device_get(v))
-            for k, v in runner.logical_params(state).items()}
-
-
-# -- overlap-on vs overlap-off trajectory parity -----------------------------
-
-
-@pytest.mark.parametrize("unroll", [1, 4])
-@pytest.mark.parametrize(
-    "builder", [AllReduce, PS, lambda: PS(gspmd_update=True)],
-    ids=["gspmd-ar", "explicit-zero1", "gspmd-zero1"])
-def test_overlap_parity(builder, unroll, monkeypatch):
-    """Overlap on vs off agree bitwise for K in {1, 4} on the gspmd and
-    explicit paths, covering plain AR (bucket-issue reorder only) and
-    zero1 (megastep weight-AG reorder) variables."""
-    n = 8
-    batches = _batches(n)
-    ref = _build(builder(), False, monkeypatch)
-    s_ref = ref.create_state()
-    if unroll == 1:
-        for b in batches:
-            s_ref, m_ref = ref.step(s_ref, b)
-    else:
-        s_ref, m_ref = ref.run(s_ref, iter(batches), n, unroll=unroll)
-
-    ov = _build(builder(), True, monkeypatch)
-    assert ov._overlap
-    s = ov.create_state()
-    s, m = ov.run(s, iter(batches), n, unroll=unroll)
-
-    for k, want in _params_np(ref, s_ref).items():
-        np.testing.assert_array_equal(_params_np(ov, s)[k], want,
-                                      err_msg=f"param {k} diverged")
-    assert int(jax.device_get(s.step)) == n
-    # StepGuard contract preserved: the notfinite flag is still a scalar.
-    assert np.shape(jax.device_get(m["notfinite"])) == ()
-
-
-def test_overlap_parity_with_bucket_cap(monkeypatch):
-    """AUTODIST_AR_BUCKET_MB splits fusion buckets without changing
-    values (elementwise reductions are membership-invariant)."""
-    n = 4
-    batches = _batches(n)
-    ref = _build(AllReduce(), False, monkeypatch)
-    s_ref = ref.create_state()
-    for b in batches:
-        s_ref, _ = ref.step(s_ref, b)
-
-    monkeypatch.setenv("AUTODIST_AR_BUCKET_MB", "1")
-    capped = _build(AllReduce(), True, monkeypatch)
-    s = capped.create_state()
-    s, _ = capped.run(s, iter(batches), n, unroll=2)
-    for k, want in _params_np(ref, s_ref).items():
-        np.testing.assert_array_equal(_params_np(capped, s)[k], want)
-
-
 # -- bucket-plan determinism -------------------------------------------------
 
 
@@ -123,7 +59,7 @@ def test_bucket_order_deterministic_across_captures(monkeypatch):
     chief/worker agreement contract (same as the tuner tie-break)."""
     runs = []
     for _ in range(3):
-        r = _build(AllReduce(), True, monkeypatch)
+        r = _build(AllReduce(), monkeypatch)
         plan = r.bucket_plan()
         runs.append((plan, overlap.plan_fingerprint(plan),
                      r.grad_production_order()))
@@ -211,23 +147,11 @@ def test_sync_collectives_count_whole_and_unroll_divides():
         pytest.approx(ms / 4)
 
 
-def test_overlap_flags_probe_gated_and_idempotent(monkeypatch):
-    flags = overlap.overlap_xla_flags()
-    assert set(flags) <= set(overlap.OVERLAP_FLAG_CANDIDATES)
-    monkeypatch.setenv("XLA_FLAGS", os.environ.get("XLA_FLAGS", ""))
-    overlap.apply_overlap_flags()
-    once = os.environ["XLA_FLAGS"]
-    assert overlap.apply_overlap_flags() == ()  # second apply adds nothing
-    assert os.environ["XLA_FLAGS"] == once
-    for f in flags:
-        assert f.split("=")[0] in once
-
-
 # -- scheduled-HLO dump ------------------------------------------------------
 
 
 def test_dump_scheduled_writes_parseable_text(monkeypatch, tmp_path):
-    runner = _build(AllReduce(), False, monkeypatch)
+    runner = _build(AllReduce(), monkeypatch)
     batch = _batches(1)[0]
     path = runner.dump_scheduled(batch)
     assert path.endswith("4-scheduled-hlo.txt"), path
@@ -240,7 +164,7 @@ def test_dump_scheduled_writes_parseable_text(monkeypatch, tmp_path):
     assert np.isfinite(ms) and ms >= 0
 
 
-# -- cost model overlap term -------------------------------------------------
+# -- cost model bucket and wire terms ----------------------------------------
 
 
 def _meta_item(nbytes_each=8 << 20, n_vars=4, flops=0.0):
@@ -259,38 +183,6 @@ def _spec(tmp_path, num_hosts=4):
     path.write_text("tpu:\n  accelerator: v5e-32\n"
                     f"  num_hosts: {num_hosts}\n  chips_per_host: 8\n")
     return ResourceSpec(str(path))
-
-
-def test_overlap_term_monotone_in_overlappable_compute(tmp_path):
-    spec = _spec(tmp_path)
-    topo = Topology(32, 4)
-    model = CostModel(topo)
-    prev = None
-    for flops in (0.0, 1e12, 1e13, 1e14):
-        item = _meta_item(flops=flops)
-        strat = AllReduce(chunk_size=128).build(item, spec)
-        bd = model.strategy_cost(strat, item, overlap=True)
-        exposed = bd["exposed_sync_ms"]
-        assert exposed <= bd["sync_ms"] + 1e-9
-        if prev is not None:
-            assert exposed <= prev + 1e-9  # more compute => no more exposed
-        prev = exposed
-
-
-def test_overlap_never_costs_more_and_ag_needs_unroll(tmp_path):
-    spec = _spec(tmp_path)
-    model = CostModel(Topology(32, 4))
-    item = _meta_item(flops=1e13)
-    for builder in (AllReduce(chunk_size=128), PS()):
-        strat = builder.build(item, spec)
-        serial = model.strategy_cost(strat, item)
-        lapped = model.strategy_cost(strat, item, overlap=True)
-        assert lapped.total_ms <= serial.total_ms + 1e-9
-    # ZeRO's weight all-gather only overlaps inside a megastep.
-    ps = PS().build(item, spec)
-    k1 = model.strategy_cost(ps, item, overlap=True, unroll=1)
-    k4 = model.strategy_cost(ps, item, overlap=True, unroll=4)
-    assert k4["exposed_sync_ms"] <= k1["exposed_sync_ms"] + 1e-9
 
 
 def test_bucket_cap_adds_latency_terms(tmp_path):
@@ -339,7 +231,12 @@ def test_compressor_wire_bytes_priced(tmp_path):
 # -- tuner search exec knobs -------------------------------------------------
 
 
-def test_search_ranks_overlap_and_bucket_knobs(tmp_path):
+def test_exec_variants_fixed_literal_order(tmp_path):
+    labels = [v[0] for v in EXEC_VARIANTS]
+    assert labels[0] == ""  # serialized baseline wins ties
+    assert labels == sorted(labels, key=labels.index)  # literal order
+    assert labels[1:] == [f"+microbatches={m}" for m in (4, 8, 16)]
+
     from autodist_tpu import tuner
     from autodist_tpu.tuner.calibration import Calibration
     spec = _spec(tmp_path)
@@ -347,50 +244,34 @@ def test_search_ranks_overlap_and_bucket_knobs(tmp_path):
     result = tuner.search(item, spec, calibration=Calibration(
         path=str(tmp_path / "cal.json")))
     for row in result.ranked:
-        assert "overlap" in row["knobs"]
         assert "ar_bucket_mb" in row["knobs"]
-        assert "exposed_sync_ms" in row["breakdown"]
-    # With real overlappable compute the winner's exec config hides sync.
-    chosen = result.chosen
-    assert chosen["breakdown"]["exposed_sync_ms"] <= \
-        chosen["breakdown"]["sync_ms"] + 1e-9
-    # Serve objective stays exec-knob-free (no overlap kwargs).
+        # Priced serially, one figure: the schedule the Runner executes.
+        assert "exposed_sync_ms" not in row["breakdown"]
+        assert row["breakdown"]["sync_ms"] >= 0
+    # Serve objective stays exec-knob-free.
     serve = tuner.search(item, spec, objective="serve_latency",
                          calibration=Calibration(
                              path=str(tmp_path / "cal.json")))
-    assert all("overlap" not in r["knobs"] for r in serve.ranked)
-
-
-def test_exec_variants_fixed_literal_order():
-    labels = [v[0] for v in EXEC_VARIANTS]
-    assert labels[0] == ""  # serialized baseline wins ties
-    assert labels == sorted(labels, key=labels.index)  # literal order
+    assert all("ar_bucket_mb" not in r["knobs"] for r in serve.ranked)
 
 
 # -- telemetry surface -------------------------------------------------------
 
 
-def test_report_overlap_rows(monkeypatch):
-    """The Telemetry section renders the overlap-efficiency row from the
-    gauges, and the HLO section summarizes async pairs + exposed ms."""
-    if not observability.enabled():
-        pytest.skip("telemetry disabled in this environment")
-    from autodist_tpu import report
-    observability.registry().reset()
-    observability.registry().gauge("comms.exposed_ms_per_step").set(0.42)
-    observability.registry().gauge("step.overlap").set(1)
-    html = report._render_telemetry()
-    assert "overlap=on" in html
-    assert "comms exposed" in html
-
-
 def test_runner_records_exposed_gauge(monkeypatch):
     if not observability.enabled():
         pytest.skip("telemetry disabled in this environment")
-    runner = _build(AllReduce(), True, monkeypatch)
+    runner = _build(AllReduce(), monkeypatch)
     monkeypatch.setattr(runner, "_obs", observability)
     observability.registry().reset()
     batch = _batches(1)[0]
     runner.make_callable(batch, aot=True)
     snap = observability.registry().snapshot()
     assert "comms.exposed_ms_per_step" in (snap.get("gauges") or {})
+    # The report's Telemetry section reads that gauge into its
+    # exposed-comms row, with no scheduler badge beside it.
+    from autodist_tpu import report
+    html = report._render_telemetry()
+    assert "comms exposed" in html
+    assert "ms/step (priced from the scheduled HLO" in html
+    assert "overlap=" not in html

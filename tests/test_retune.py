@@ -277,8 +277,8 @@ def _stub_rows(*pairs):
     rows = []
     for label, pred, tier in pairs:
         rows.append({"label": label, "unroll": 1,
-                     "knobs": {"unroll": 1, "overlap": False,
-                               "bucket_mb": 0, "microbatches": 0},
+                     "knobs": {"unroll": 1, "bucket_mb": 0,
+                               "microbatches": 0},
                      "predicted_ms": pred, "breakdown": {},
                      "tier": tier, "strategy": None, "strategy_name": ""})
     rows.sort(key=lambda r: (round(r["predicted_ms"], 6), r["label"]))

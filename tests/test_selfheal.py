@@ -114,8 +114,8 @@ def _stub_rows(*triples):
     rows = []
     for label, pred, tier in triples:
         rows.append({"label": label, "unroll": 1,
-                     "knobs": {"unroll": 1, "overlap": False,
-                               "bucket_mb": 0, "microbatches": 0},
+                     "knobs": {"unroll": 1, "bucket_mb": 0,
+                               "microbatches": 0},
                      "predicted_ms": pred, "breakdown": {},
                      "tier": tier, "strategy": None, "strategy_name": ""})
     rows.sort(key=lambda r: (round(r["predicted_ms"], 6), r["label"]))
@@ -124,8 +124,7 @@ def _stub_rows(*triples):
 
 def _decision(**over):
     base = dict(tier=1, label="unroll=8", strategy=None, strategy_name="",
-                knobs={"unroll": 8, "overlap": False, "bucket_mb": 0,
-                       "microbatches": 0},
+                knobs={"unroll": 8, "bucket_mb": 0, "microbatches": 0},
                 predicted_ms=0.5, incumbent_predicted_ms=1.0,
                 measured_ms=1.2, margin_pct=50.0, remaining_steps=1000,
                 reshape=False)
@@ -147,8 +146,7 @@ def test_verdict_serialization_bitwise_deterministic():
     assert blob_a == blob_b
     assert shipping.fingerprint(blob_a) == shipping.fingerprint(blob_b)
     # Knob dict insertion order must not leak into the bytes.
-    c = _decision(knobs={"microbatches": 0, "bucket_mb": 0,
-                         "overlap": False, "unroll": 8})
+    c = _decision(knobs={"microbatches": 0, "bucket_mb": 0, "unroll": 8})
     assert (shipping.serialize_verdict(c, boundary=64)
             == shipping.serialize_verdict(_decision(), boundary=64))
     # The hold verdict is canonical too (every window ships one).

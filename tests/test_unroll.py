@@ -54,11 +54,13 @@ def _params_np(runner, state):
 
 
 @pytest.mark.parametrize("unroll", [2, 4])
-@pytest.mark.parametrize("builder", [AllReduce, PS],
-                         ids=["gspmd", "explicit"])
+@pytest.mark.parametrize(
+    "builder", [AllReduce, PS, lambda: PS(gspmd_update=True)],
+    ids=["gspmd", "explicit", "gspmd-zero1"])
 def test_unroll_parity_fast_path(builder, unroll, monkeypatch):
     """run(unroll=K) on the zero-telemetry fast path matches N sequential
-    step() calls bitwise, on both execution paths."""
+    step() calls bitwise, on both execution paths and with the optimizer
+    state sharded under the pure-GSPMD lowering."""
     n = 8
     batches = _batches(n)
     ref = _build(builder())
