@@ -329,11 +329,12 @@ _SCOPE_WRAP_RE = re.compile(
     r"\b(?:jvp|vjp|transpose|vmap|pmap|remat|checkpoint|custom_jvp|"
     r"custom_vjp|scan|while|cond)\(([^()]*)\)")
 # The frames a loop or a conditional lowers its body's instructions under
-# (``.../moe/while/body/dispatch/...``, ``.../cond/branch_1_fun/...``):
+# (``.../moe/while/body/dispatch/...``, ``.../cond/branch_1_fun/...``, a
+# differentiated scan's ``.../pass/while/body/closed_call/layer0/...``):
 # machinery between the user's scopes.  The loop or conditional itself
 # (``.../moe/while``) keeps its name.
 _BODY_FRAME_RE = re.compile(
-    r"(?<![^/])(?:while/(?:body|cond)|cond/branch_\d+_fun)/")
+    r"(?<![^/])(?:while/(?:body|cond)|cond/branch_\d+_fun|closed_call)/")
 # ``jax.checkpoint``'s frames: what the backward pass computes of its
 # function carries ``checkpoint/``, what it computes again
 # ``checkpoint/rematted_computation/``, both behind a second copy of the
@@ -343,6 +344,11 @@ _BODY_FRAME_RE = re.compile(
 _REMAT_FRAME_RE = re.compile(
     r"(transpose\(jvp\(([^()]*)\)\)/)jvp\(\2\)/"
     r"(?:checkpoint/(?:rematted_computation/)?|(?=remat2$))")
+# The same inside a differentiated loop's body, where the transform frames
+# are gone and the second copy of the scopes stands bare
+# (``pass/layer0/mlp/layer0/mlp/checkpoint/rematted_computation/...``).
+_BARE_REMAT_FRAME_RE = re.compile(
+    r"(?<![^/])((?:[^/()]+/)+)\1checkpoint/(?:rematted_computation/)?")
 
 
 def scope_path(name_stack_text):
@@ -363,7 +369,7 @@ def scope_path(name_stack_text):
     while prev != text:
         prev = text
         text = _SCOPE_WRAP_RE.sub(r"\1", text)
-    text = _BODY_FRAME_RE.sub("", text)
+    text = _BARE_REMAT_FRAME_RE.sub(r"\1", _BODY_FRAME_RE.sub("", text))
     segments = []
     for seg in text.split("/"):
         seg = seg.strip()
@@ -374,6 +380,20 @@ def scope_path(name_stack_text):
             continue
         segments.append(seg)
     return "/".join(segments)
+
+
+# A looped model (``TransformerConfig.loops``) runs its stack under the scope
+# ``pass`` (one scan over the passes) and each pass's head and gate under
+# ``pass<t>``.
+_PASS_RE = re.compile(r"^pass\d*(?:/|$)")
+
+
+def strip_pass(scope):
+    """A :func:`scope_path` less its leading ``pass`` or ``pass<t>``: a
+    variable's scope (``layer0/attn``) names one place a pass in a looped
+    program, and what joins equations to variables or folds them into a
+    plain model's rows reads the path without the pass."""
+    return _PASS_RE.sub("", scope or "")
 
 
 class GraphItem:

@@ -158,6 +158,27 @@ def qwen3_next_80b_a3b(num_layers=48, vocab=151936, experts_held=None,
         rope_by_type={T.FULL: {"theta": 1e7, "lanes": 64, "yarn": None}})
 
 
+def ouro_2_6b(num_layers=48, vocab=49152, dtype=jnp.bfloat16):
+    """Ouro-2.6B (ByteDance/Ouro-2.6B ``config.json``; arXiv:2510.25741,
+    "Scaling Latent Reasoning via Looped Language Models"): width 2,048; 48
+    layers run four times over with the same variables
+    (``total_ut_steps``), each 16 heads of 128 over as many key-value heads
+    (rotate-half rotary over a head's 128 lanes, theta 1,000,000) and a
+    SwiGLU MLP of 5,632, an RMSNorm (eps 1e-6) on each sublayer's input and
+    on its output; one final norm after every pass, from which the next
+    pass starts; an untied head and a cross-entropy on every pass, weighed
+    by an exit gate's distribution.  ``num_layers`` keeps the stack's first
+    layers.  Not in ``config.json`` (the benchmark's configuration file
+    lists each with its reason): the norms' places, the gate's form and the
+    entropy term at 0.05."""
+    return T.TransformerConfig(
+        vocab=vocab, dim=2048, num_heads=16, num_layers=num_layers,
+        mlp_dim=5632, max_len=65536, causal=True, dtype=dtype,
+        norm="rmsnorm", norm_eps=1e-6, positions="rope", rope_theta=1e6,
+        bias=False, tied_head=False, ffn="swiglu", norm_position="sandwich",
+        loops=4, exit_entropy_coef=0.05, recompute="pointwise")
+
+
 def init(key, cfg):
     return T.init(key, cfg)
 
@@ -184,7 +205,13 @@ def make_loss_fn(cfg, attn_fn=None):
     Expert layers with a selection bias put its next value under the
     reserved ``aux["state_updates"]`` (variable name -> value), which the
     Runner's step writes (``GraphItem.capture``).
+
+    With ``cfg.loops`` above 1 the loss is the looped model's
+    (:func:`looped_objective`): a head and a cross-entropy on every pass,
+    weighed position by position by the exit gate's distribution.
     """
+    if cfg.loops > 1:
+        return _looped_loss_fn(cfg, attn_fn)
     ahead = 1 + cfg.mtp_depth
 
     def loss_fn(params, batch):
@@ -245,6 +272,53 @@ def make_loss_fn(cfg, attn_fn=None):
                 aux[f"{mixer}.output_std"] = over_layers(
                     f"{mixer}_output_std")
         return loss, aux
+    return loss_fn
+
+
+def looped_objective(params, cfg, hidden, labels):
+    """A looped model's loss from its passes' states (arXiv:2510.25741's
+    entropy-regularised objective with a uniform prior), ``(loss, aux)``.
+    With ``h_t = hidden[t]`` the states after pass t's final norm
+    (``transformer.encode_passes``), ``l_t(i)`` the cross-entropy of position
+    i under ``logits_t = h_t W_head`` and ``p_t(i)`` the exit distribution
+    (``transformer.exit_distribution``):
+
+        loss = mean_i [ sum_t p_t(i) l_t(i) - beta H(p(i)) ],
+        H(p) = -sum_t p_t log p_t,  beta = cfg.exit_entropy_coef
+
+    Each pass's head and cross-entropy run under ``pass<t>/lm_head`` (float32
+    logits), the weighing under ``exit_loss``.  ``aux`` holds ``loop.xent``
+    (a value a pass: the mean cross-entropy), ``loop.exit_pdf`` (a value a
+    pass: the mean of ``p_t``), ``loop.exit_entropy`` (the mean of ``H``)
+    and ``xent``, the last pass's."""
+    xent = []
+    for t, h in enumerate(hidden):
+        with jax.named_scope(f"pass{t}"), jax.named_scope("lm_head"):
+            logp = jax.nn.log_softmax(T.logits(params, cfg, h))
+            xent.append(-jnp.take_along_axis(logp, labels[..., None],
+                                             axis=-1)[..., 0])
+    log_p = T.exit_distribution(params, hidden)
+    with jax.named_scope("exit_loss"):
+        xent, p = jnp.stack(xent), jnp.exp(log_p)
+        entropy = -jnp.sum(p * log_p, axis=0)
+        loss = jnp.mean(jnp.sum(p * xent, axis=0)
+                        - cfg.exit_entropy_coef * entropy)
+        by_pass = xent.mean(axis=(1, 2))
+        aux = {"xent": by_pass[-1], "loop.xent": by_pass,
+               "loop.exit_pdf": p.mean(axis=(1, 2)),
+               "loop.exit_entropy": entropy.mean()}
+    return loss, aux
+
+
+def _looped_loss_fn(cfg, attn_fn=None):
+    """``loss_fn(params, batch) -> (loss, aux)`` of a looped model: every
+    pass's states (``transformer.encode_passes``) under
+    :func:`looped_objective`."""
+    def loss_fn(params, batch):
+        (tokens,) = batch if isinstance(batch, (tuple, list)) else (batch,)
+        hidden, _ = T.encode_passes(params, cfg, tokens[:, :-1],
+                                    attn_fn=attn_fn)
+        return looped_objective(params, cfg, hidden, tokens[:, 1:])
     return loss_fn
 
 

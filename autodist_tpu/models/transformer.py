@@ -44,9 +44,20 @@ multi-token-prediction module (``mtp/...``, :func:`mtp_hidden`), one more
 block that predicts the token after next through the same embedding and head;
 ``norm_position="output"`` normalises each sublayer's
 output before the residual add (the Olmo 2 order) where the default
-normalises its input; ``ffn="swiglu"`` is the dense gated MLP
-(``mlp/{gate,up,down}``); ``positions="none"`` gives attention no positions
-at all (the convolutions and decays of the linear layers carry order).
+normalises its input, ``"sandwich"`` both (``ln1_out``, ``ln2_out`` beside
+``ln1``, ``ln2``); ``loops`` runs the stack of layers that many times over
+with the same variables (:func:`encode_passes`: one ``lax.scan`` over the
+passes under the scope ``pass``, the final norm after every pass, the next
+pass from what it gave), every pass's state goes to the head
+(``pass<t>/lm_head``), and :func:`exit_distribution` (``exit_gate``,
+a projection to a scalar) gives the weights of the passes' losses
+(``lm.looped_objective``, ``exit_entropy_coef``); ``loops=1`` is the plain
+stack, instruction for instruction; ``recompute="pointwise"`` makes the
+norms and the gated feed-forward's activation again in the backward pass
+(what the scan would otherwise keep a pass); ``ffn="swiglu"`` is the dense
+gated MLP (``mlp/{gate,up,down}``); ``positions="none"`` gives attention no
+positions at all (the convolutions and decays of the linear layers carry
+order).
 """
 import functools
 
@@ -75,15 +86,15 @@ class TransformerConfig:
                  kv_heads=None, heads_by_layer=None, window=None,
                  attn_gate=False, rope_by_type=None, linear_key_heads=None,
                  shared_gate=False, recompute=None, mixer_stats=False,
-                 experts_held_chunks=None):
+                 experts_held_chunks=None, loops=1, exit_entropy_coef=0.0):
         for name, value, known in (("norm", norm, ("layernorm", "rmsnorm")),
                                    ("positions", positions,
                                     ("learned", "rope", "none")),
                                    ("recompute", recompute,
-                                    (None, "linear_mixer")),
+                                    (None, "linear_mixer", "pointwise")),
                                    ("ffn", ffn, ("mlp", "moe", "swiglu")),
                                    ("norm_position", norm_position,
-                                    ("pre", "output"))):
+                                    ("pre", "output", "sandwich"))):
             if value not in known:
                 raise ValueError(f"{name} must be one of {known}, got "
                                  f"{value!r}")
@@ -154,7 +165,12 @@ class TransformerConfig:
         self.conv_width, self.allow_neg_eigval = conv_width, allow_neg_eigval
         # "linear_mixer": the backward pass computes a linear layer's mixer
         # sublayer again from its input (``jax.checkpoint``) and keeps none
-        # of what lies inside; None keeps what autodiff keeps.
+        # of what lies inside; "pointwise": it computes every norm and the
+        # gated feed-forward's ``silu(gate) * up`` again from their inputs,
+        # the matrix products' and kernels' results (a scan over the passes
+        # of a looped model keeps what autodiff keeps a pass, the norms'
+        # float32 copies with it, where the compiler would keep none in an
+        # unrolled program); None keeps what autodiff keeps.
         self.recompute = recompute
         # Every mixer reports how far what it adds to the residual stream
         # stands from its mean over a row's positions, as a root mean square
@@ -227,6 +243,27 @@ class TransformerConfig:
             raise NotImplementedError(
                 "scan_layers stacks one kind of block: first_dense and "
                 "mtp_depth need scan_layers=False")
+        # The stack of layers is run ``loops`` times over with the same
+        # variables (a looped language model): a pass ends in the final norm,
+        # the next starts from what it gave, and every pass's state goes to
+        # the head; ``exit_entropy_coef`` is the entropy term's weight in the
+        # loss over the passes (``lm.make_loss_fn``).  1 is the plain stack.
+        if loops < 1:
+            raise ValueError(f"loops must be at least 1, got {loops!r}")
+        if loops > 1 and scan_layers:
+            raise NotImplementedError(
+                f"loops={loops} with scan_layers: a scan over the layers "
+                "inside the loop over the passes is not built (ROADMAP R9); "
+                "build a looped configuration with scan_layers=False")
+        if loops > 1 and (ffn == "moe" or mtp_depth or (
+                self.layer_types is not None and LINEAR in self.layer_types)):
+            raise NotImplementedError(
+                f"loops={loops}: a pass has no account of its own of what "
+                "expert layers, linear layers or the prediction module "
+                "report from inside the step (auxiliary terms, selection "
+                "biases, final states); a looped stack holds attention and a "
+                "dense feed-forward (ROADMAP R9)")
+        self.loops, self.exit_entropy_coef = loops, exit_entropy_coef
 
     def layer_type(self, i):
         return FULL if self.layer_types is None else self.layer_types[i]
@@ -261,8 +298,10 @@ def _norm_init(cfg):
 
 
 def _norm(cfg, p, x):
-    return L.rmsnorm(p, x, cfg.norm_eps) if cfg.norm == "rmsnorm" \
-        else L.layernorm(p, x, cfg.norm_eps)
+    norm = L.rmsnorm if cfg.norm == "rmsnorm" else L.layernorm
+    if cfg.recompute == "pointwise":
+        norm = jax.checkpoint(norm, static_argnums=2)
+    return norm(p, x, cfg.norm_eps)
 
 
 def block_init(key, cfg, layer_type=FULL, ffn=None, heads=None):
@@ -271,10 +310,14 @@ def block_init(key, cfg, layer_type=FULL, ffn=None, heads=None):
     configuration's where None) whether ``moe`` or ``mlp``, ``heads`` (the
     configuration's ``num_heads`` where None) its attention's query heads.
     ``ln1`` and ``ln2`` are the norms of the mixer's and the feed-forward's
-    sublayer, wherever ``norm_position`` puts them."""
+    sublayer, wherever ``norm_position`` puts them; under ``"sandwich"``
+    they norm the sublayers' inputs and ``ln1_out``, ``ln2_out`` their
+    outputs."""
     ffn = ffn or cfg.ffn
     k1, k2, k3 = jax.random.split(key, 3)
     p = {"ln1": _norm_init(cfg)}
+    if cfg.norm_position == "sandwich":
+        p["ln1_out"], p["ln2_out"] = _norm_init(cfg), _norm_init(cfg)
     if layer_type == LINEAR:
         p["gdn"] = L.gdn_init(k1, cfg.dim, cfg.linear_heads,
                               cfg.linear_key_dim, cfg.linear_value_dim,
@@ -299,18 +342,21 @@ def block_init(key, cfg, layer_type=FULL, ffn=None, heads=None):
     return p
 
 
-def _residual(cfg, norm_p, x, sublayer):
+def _residual(cfg, p, ln, x, sublayer):
     """``x`` plus ``sublayer``, which gives ``(y, stats)``: of the
     normalised ``x`` (``norm_position="pre"``), or itself normalised before
-    the add (``"output"``)."""
+    the add (``"output"``), or both (``"sandwich"``: ``p[ln]`` norms the
+    input, ``p[ln + "_out"]`` the output)."""
     if cfg.norm_position == "output":
         y, stats = sublayer(x)
-        return x + _norm(cfg, norm_p, y), stats
-    y, stats = sublayer(_norm(cfg, norm_p, x))
+        return x + _norm(cfg, p[ln], y), stats
+    y, stats = sublayer(_norm(cfg, p[ln], x))
+    if cfg.norm_position == "sandwich":
+        y = _norm(cfg, p[ln + "_out"], y)
     return x + y, stats
 
 
-_MIXER_KEYS = ("ln1", "attn", "gdn")
+_MIXER_KEYS = ("ln1", "ln1_out", "attn", "gdn")
 
 
 def _halves(p):
@@ -380,7 +426,7 @@ def mixer_sublayer(p, x, cfg, mask=None, attn_fn=None, rope=None,
                 out - out.mean(axis=1, keepdims=True))))
             return y, {**(stats or {}), f"{scope}_output_std": std}
     with jax.named_scope(scope):
-        return _residual(cfg, p["ln1"], x, mixer)
+        return _residual(cfg, p, "ln1", x, mixer)
 
 
 def ffn_sublayer(p, x, cfg):
@@ -391,13 +437,17 @@ def ffn_sublayer(p, x, cfg):
         if "moe" in p:
             return moe.dropless_apply(p["moe"], cfg.moe, h)
         up = L.dense(p["mlp"]["up"], h, cfg.dtype)
-        if "gate" in p["mlp"]:
-            h = jax.nn.silu(L.dense(p["mlp"]["gate"], h, cfg.dtype)) * up
-        else:
-            h = jax.nn.gelu(up)
-        return L.dense(p["mlp"]["down"], h, cfg.dtype), None
+
+        def down(gate, up):
+            h = jax.nn.silu(gate) * up if gate is not None \
+                else jax.nn.gelu(up)
+            return L.dense(p["mlp"]["down"], h, cfg.dtype)
+        if cfg.recompute == "pointwise":
+            down = jax.checkpoint(down)
+        return down(L.dense(p["mlp"]["gate"], h, cfg.dtype)
+                    if "gate" in p["mlp"] else None, up), None
     with jax.named_scope("moe" if "moe" in p else "mlp"):
-        return _residual(cfg, p["ln2"], x, ffn)
+        return _residual(cfg, p, "ln2", x, ffn)
 
 
 def init(key, cfg):
@@ -414,6 +464,14 @@ def init(key, cfg):
             use_bias=False)
     if cfg.num_segments:
         params["seg_embed"] = L.normal(keys[2], (cfg.num_segments, cfg.dim), 0.02)
+    if cfg.loops > 1:
+        # The exit gate: one projection of the width to a scalar, with a
+        # bias; drawn, not zero, so that no two passes weigh alike at the
+        # start.
+        params["exit_gate"] = {
+            "kernel": L.normal(jax.random.fold_in(keys[1], 2),
+                               (cfg.dim, 1), 0.02),
+            "bias": jnp.zeros((1,))}
     if cfg.scan_layers:
         params["blocks"] = jax.vmap(
             lambda k: block_init(k, cfg, cfg.layer_type(0)))(
@@ -498,7 +556,48 @@ def encode(params, cfg, ids, segment_ids=None, attn_fn=None):
 def encode_with_stats(params, cfg, ids, segment_ids=None, attn_fn=None):
     """:func:`encode` and what the layers report from inside the step
     (:func:`block_apply`): ``(hidden, [stats of a layer that has any,
-    ...])``, the list empty for the default block."""
+    ...])``, the list empty for the default block.  Under ``cfg.loops`` the
+    hidden states are the last pass's (:func:`encode_passes` gives all)."""
+    hidden, stats = encode_passes(params, cfg, ids, segment_ids, attn_fn)
+    return hidden[-1], stats
+
+
+_loops_announced = set()
+
+
+def _announce_loops(cfg):
+    """Gauges ``loop.*`` and one ``loop`` event a distinct shape, at trace
+    time: the passes, the layers of the stack and the layer applications a
+    step makes."""
+    from autodist_tpu import observability
+    if not observability.enabled():
+        return
+    registry = observability.registry()
+    registry.gauge("loop.passes").set(cfg.loops)
+    registry.gauge("loop.layers").set(cfg.num_layers)
+    registry.gauge("loop.applications").set(cfg.loops * cfg.num_layers)
+    detail = (f"looped stack: {cfg.num_layers} layers run {cfg.loops} times "
+              f"with one set of variables ({cfg.loops * cfg.num_layers} "
+              f"layer applications a step, a scan over the passes under the "
+              f"scope 'pass'), the final norm, the head and a cross-entropy "
+              f"on every pass, an exit gate on the first {cfg.loops - 1}; "
+              f"entropy term at {cfg.exit_entropy_coef}")
+    if detail not in _loops_announced:
+        _loops_announced.add(detail)
+        observability.record_event("loop", detail)
+
+
+def encode_passes(params, cfg, ids, segment_ids=None, attn_fn=None):
+    """``([hidden states of pass 0, ..., of pass loops - 1], stats)``: the
+    stack of layers run ``cfg.loops`` times over with the same variables,
+    the final norm after every pass, the next pass starting from what that
+    norm gave.  With ``loops=1`` the list holds :func:`encode`'s one state
+    and the scopes are ``layer<i>`` and ``ln_f``.  A looped model's passes
+    are one ``lax.scan`` whose body is the unrolled stack and the final norm
+    (scopes ``pass/layer<i>``, ``pass/ln_f``, which the profiler folds into
+    a plain model's rows): the program holds one pass's instructions
+    whatever ``loops`` is, and the variables' gradients meet in the
+    backward scan's carry, in the variables' own float32."""
     s = ids.shape[1]
     with jax.named_scope("embed"):
         x = L.embed(params["embed"], ids)
@@ -517,11 +616,14 @@ def encode_with_stats(params, cfg, ids, segment_ids=None, attn_fn=None):
                             lambda bp, a: block_apply(
                                 bp, a, cfg, mask=mask, attn_fn=attn_fn,
                                 rope=rope.get(FULL))[0], x)
-    else:
-        from autodist_tpu.parallel.context import layer_boundary
-        last = cfg.num_layers - 1
-        mixers, ffns = (list(half) for half in zip(*(
-            _halves(params[f"layer{i}"]) for i in range(cfg.num_layers))))
+        with jax.named_scope("ln_f"):
+            return [_norm(cfg, params["ln_f"], x)], stats
+    from autodist_tpu.parallel.context import layer_boundary
+    last = cfg.num_layers - 1
+    halves = [_halves(params[f"layer{i}"]) for i in range(cfg.num_layers)]
+
+    def one_pass(x):
+        mixers, ffns = (list(half) for half in zip(*halves))
         # The parameters of the layer ahead meet this layer's activations,
         # a half a boundary: the mixer's where this layer begins, the
         # feed-forward's where this layer's feed-forward begins (layer 0's,
@@ -549,8 +651,18 @@ def encode_with_stats(params, cfg, ids, segment_ids=None, attn_fn=None):
             layer_stats = {**(mixed or {}), **(fed or {})} or None
             if layer_stats is not None:
                 stats.append(_named_updates(layer_stats, f"layer{i}"))
-    with jax.named_scope("ln_f"):
-        return _norm(cfg, params["ln_f"], x), stats
+        with jax.named_scope("ln_f"):
+            return _norm(cfg, params["ln_f"], x)
+    if cfg.loops == 1:
+        return [one_pass(x)], stats
+    _announce_loops(cfg)
+    # A pass reads the variables as the scan's constants, and the boundary
+    # op runs inside it: on the explicit step a variable is gathered, and
+    # its gradient scattered, once a pass.
+    with jax.named_scope("pass"):
+        _, hidden = jax.lax.scan(lambda x, _: (one_pass(x),) * 2, x, None,
+                                 length=cfg.loops)
+    return [hidden[t] for t in range(cfg.loops)], stats
 
 
 def mtp_hidden(params, cfg, hidden, next_ids, attn_fn=None):
@@ -597,9 +709,36 @@ def logits(params, cfg, hidden):
         return hidden @ head.astype(jnp.float32)
 
 
+def exit_distribution(params, hidden):
+    """The looped model's exit distribution, ``(passes, batch, seq)`` in
+    float32, as its logarithm: from the states of every pass (after the
+    final norm) the gate gives ``lam_t = sigmoid(w . h_t + b)`` on every
+    pass but the last; ``p_t = lam_t prod_(j<t) (1 - lam_j)`` is the
+    probability of leaving after pass t, and the last pass takes what is
+    left, ``prod_(j<T) (1 - lam_j)``, so that a position's ``p`` sums to
+    one.  The gate's product is a float32 sum of products, not a matrix
+    product: it is one column wide.  Each pass's gate runs under
+    ``pass<t>/exit_gate``."""
+    w = params["exit_gate"]["kernel"][:, 0].astype(jnp.float32)
+    b = params["exit_gate"]["bias"][0].astype(jnp.float32)
+    log_p, stay = [], 0.0       # log of the probability of not having left
+    for t, h in enumerate(hidden[:-1]):
+        with jax.named_scope(f"pass{t}"), jax.named_scope("exit_gate"):
+            z = jnp.sum(h.astype(jnp.float32) * w, axis=-1) + b
+            log_p.append(stay + jax.nn.log_sigmoid(z))
+            stay = stay + jax.nn.log_sigmoid(-z)
+    return jnp.stack(log_p + [stay])
+
+
 # -- autoregressive decode (KV cache) ----------------------------------------
 
 def _decodable(cfg):
+    if cfg.loops > 1:
+        raise NotImplementedError(
+            f"decoding keeps one cache entry a layer, and this configuration "
+            f"runs its layers loops={cfg.loops} times over: a cache entry a "
+            f"pass and layer, and an exit before the last pass, wait for "
+            f"ROADMAP R9")
     attention = {"grouped key-value heads (kv_heads)": cfg.kv_heads,
                  "a window (sliding_attention layers)": cfg.window,
                  "heads by layer (heads_by_layer)": cfg.heads_by_layer,
@@ -621,7 +760,8 @@ def _decodable(cfg):
         raise NotImplementedError(
             "decoding is implemented for the default block only (LayerNorm, "
             "learned positions, biased projections, an MLP, a tied head); "
-            "through rope, QK-norm, moe, output norms, linear-attention "
+            "through rope, QK-norm, moe, output or sandwich norms, "
+            "linear-attention "
             "layers (recurrent state beside a KV cache) or latent attention "
             "(a latent cache) it waits for ROADMAP R2")
 

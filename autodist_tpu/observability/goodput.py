@@ -111,6 +111,7 @@ EVENT_CLASS = {
     "gdn": None,
     "goodput": None,
     "grad_sync": None,
+    "loop": None,
     "mesh-built": "startup_ms",
     "memory": None,
     "mla": None,

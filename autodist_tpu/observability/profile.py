@@ -79,10 +79,12 @@ def scope_of(path_text, known_scopes):
 
     ``"jit(f)/transpose(jvp(layer0))/attn/dot_general"`` matches scope
     ``"layer0/attn"``; ``"layer0/attn/query/kernel"`` (a variable name)
-    matches the same row — compute and comms land on one key.
+    matches the same row — compute and comms land on one key.  So does
+    a looped model's stack: ``pass/layer0/attn/...`` is ``layer0/attn``,
+    the variables' one row.
     """
-    from autodist_tpu.graph_item import scope_path
-    segs = [s for s in scope_path(path_text).split("/") if s]
+    from autodist_tpu.graph_item import scope_path, strip_pass
+    segs = [s for s in strip_pass(scope_path(path_text)).split("/") if s]
     for i in range(min(len(segs), SCOPE_DEPTH + 1), 0, -1):
         cand = "/".join(segs[:i])
         if cand in known_scopes:
@@ -118,8 +120,9 @@ def model_scope_costs(runner, unroll=1):
     topo = cm.Topology(max(1, prog.mesh.devices.size),
                        num_hosts=max(1, jax.process_count()))
     scopes, known = {}, set()
+    from autodist_tpu.graph_item import strip_pass
     for scope, agg in item.scope_costs().items():
-        key = collapse(scope) or UNATTRIBUTED
+        key = collapse(strip_pass(scope)) or UNATTRIBUTED
         if key != UNATTRIBUTED:
             known.add(key)
         rec = scopes.setdefault(key, _zero())
@@ -255,11 +258,21 @@ MTP_SCOPE = "mtp"
 _INSTRUCTION_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
 
 
+def _user_scopes(op_name):
+    """The user's scopes one HLO ``op_name`` was traced under (the name
+    stack's last segment is the primitive), less the leading ``pass`` or
+    ``pass<t>`` of a looped model; what the scan over the passes runs in no
+    scope of its body (the carry's sums and copies) keeps ``pass``."""
+    from autodist_tpu.graph_item import scope_path, strip_pass
+    scope = "/".join(scope_path(op_name).split("/")[:-1])
+    inner = strip_pass(scope)
+    return [s for s in (scope if scope == "pass" else inner).split("/") if s]
+
+
 def _scope_and_phase(op_name):
     """``(scope, phase)`` of one HLO ``op_name``.  The last segment of the
     name stack is the primitive, what precedes it the user's scopes."""
-    from autodist_tpu.graph_item import scope_path
-    segs = scope_path(op_name).split("/")[:-1]
+    segs = _user_scopes(op_name)
     scope = UNATTRIBUTED
     if segs:
         scope = collapse("/".join(segs))
@@ -592,10 +605,8 @@ def subscope_table(hlo_text, block_scope):
     what it fused.  Joined with a trace (:func:`device_time_by_scope`) it
     splits one row of the generic table (``attn``: ``qkv``, ``rope``,
     ``core`` / ``window_core``, ``gate``, ``out`` of ``layers.mha``)."""
-    from autodist_tpu.graph_item import scope_path
-
     def place(op_name):
-        segs = scope_path(op_name).split("/")[:-1]
+        segs = _user_scopes(op_name)
         if segs and segs[0] == MTP_SCOPE:
             segs = segs[1:] or segs
         where = UNATTRIBUTED if not segs else "elsewhere"

@@ -69,13 +69,22 @@ def test_scope_path_unwraps_transform_frames():
     ("jit(step)/jvp(layer1)/moe/while", "layer1/moe/while",
      ("moe", "forward")),
     ("jit(f)/outer/cond/branch_1_fun/while/body/inner/mul",
-     "outer/inner/mul", ("outer/inner", profile.UNATTRIBUTED))])
+     "outer/inner/mul", ("outer/inner", profile.UNATTRIBUTED)),
+    # A looped model's scan over its passes, differentiated: the layers'
+    # rows, and ``pass`` for what the scan runs in no scope of its body.
+    ("jit(step_fn)/jvp(pass)/while/body/closed_call/layer2/attn/qkv/"
+     "dot_general", "pass/layer2/attn/qkv/dot_general", ("attn", "forward")),
+    ("jit(step_fn)/transpose(jvp(pass))/while/body/closed_call",
+     "pass/closed_call", ("pass", "backward")),
+    ("jit(step_fn)/jvp(pass3)/lm_head/dot_general",
+     "pass3/lm_head/dot_general", ("head", "forward"))])
 def test_a_loops_and_a_conditionals_frames_are_not_scopes(op_name, path,
                                                          scope):
-    """``lax.fori_loop`` / ``lax.cond`` lower a body's instructions under
-    ``while/body`` / ``cond/branch_<i>_fun``: what is traced inside folds
-    into the user's scopes as if there were no loop (the held expert
-    layers' ``dispatch`` and ``experts`` live in one)."""
+    """``lax.fori_loop`` / ``lax.cond`` / a differentiated ``lax.scan``
+    lower a body's instructions under ``while/body`` / ``cond/branch_<i>_fun``
+    / ``closed_call``: what is traced inside folds into the user's scopes as
+    if there were no loop (the held expert layers' ``dispatch`` and
+    ``experts`` live in one)."""
     assert scope_path(op_name) == path
     assert profile._scope_and_phase(op_name) == scope
 
@@ -88,6 +97,12 @@ def test_a_loops_and_a_conditionals_frames_are_not_scopes(op_name, path,
      "layer1/gdn/scan/dot_general", ("gdn/scan", "backward")),
     ("jit(step)/transpose(jvp(layer1))/jvp(layer1)/remat2", "layer1/remat2",
      ("layer1", "backward")),
+    # Inside a differentiated scan the second copy of the scopes is bare.
+    ("jit(step_fn)/transpose(jvp(pass))/while/body/closed_call/layer3/mlp/"
+     "layer3/mlp/checkpoint/rematted_computation/jit(silu)/logistic",
+     "pass/layer3/mlp/logistic", ("mlp", "backward")),
+    ("jit(step_fn)/transpose(jvp(pass))/while/body/closed_call/ln_f/ln_f/"
+     "checkpoint/mul", "pass/ln_f/mul", ("ln_f", "backward")),
     # A checkpoint inside a scope stays what it was: one more segment.
     ("jit(step)/jvp(layer0)/gdn/scan/checkpoint/inverse/mul",
      "layer0/gdn/scan/checkpoint/inverse/mul", ("gdn/scan", "forward"))])
