@@ -78,8 +78,35 @@ heads of 128, a block each, whose transposes cost less than packed blocks'
 short rows cost the kernels), and for every caller of ``flash_attention(q,
 k, v)`` itself: ring attention's ``block_attn_fwd`` / ``block_attn_bwd``,
 Ulysses, the chip smoke.  Where the rule says split, ``models.layers.mha``
-and the compiled step are what they were.  The row statistics (``lse``,
-``delta``) are ``(batch, heads, s, 1)`` in both.
+and the compiled step are what they were.
+
+The row statistics (``lse``, ``delta``) are ``(batch, heads, s, 1)`` to a
+caller in both layouts.  In the packed layout they cross HBM with the
+sequence along the lanes: the kernels' own arrays are ``(batch, heads, 1,
+s)``, 4 bytes a value where a last dimension of 1 occupies a 128-lane row
+each (the reshape between the two is XLA's and moves nothing), and no
+operand, result or scratch of a packed kernel has a last dimension of 1.  The
+split layout still keeps ``(batch x heads, s, 1)`` in HBM, which is not
+where it should end: lane-dense there too was built and measured, and one
+cell's step fell for a reason outside this file (PERF.md section 6, PR 45;
+ROADMAP S4 item 1 has what must come first).  ``_Layout.shape`` / ``block``
+/ ``spec`` hold the difference, and ``_stat_rows``, ``_stat_columns`` and
+the forward's last store read or write either block; a body does nothing
+else by it.  ``flash_bwd_dkv`` computes its scores transposed,
+keys down the sublanes and queries along the lanes (``s^T = k . q^T``), so
+``lse`` and ``delta`` are ``(1, block_q)`` rows that broadcast down the
+sublanes (the packed layout's as they were read, the split layout's columns
+turned once a step: ``_stat_rows``), and all four of its products are plain
+ones.  ``flash_fwd`` and ``flash_bwd_dq`` keep queries down the sublanes,
+where their second products stream a block's q rows through the MXU:
+``flash_fwd`` holds its running maximum and sum lane-replicated, ``(block_q,
+128)`` with every lane of a row the row's value (``_across`` tiles it to a
+tile's width: no lane broadcast a step), and turns ``lse`` to what crosses
+HBM once a q block (``_as_rows``); ``flash_bwd_dq`` turns the packed
+layout's rows to such columns (``_stat_columns``, ``_as_columns``).  A
+compiled call's block of q rows is whole 128-lane tiles or the whole row;
+any other says so in the log and takes the dense path (the interpreter takes
+any block).
 
 A program of the grid handles ``G`` (batch, head) rows, batch rows x the
 heads of a block: where a row's score tile is small (128 x 128 at s = 128)
@@ -154,6 +181,23 @@ def _pallas_interpret(interpret, dtype):
     return None
 
 
+def _kernels_serve(interpret, sq, block_q):
+    """``interpret`` where the kernels serve blocks of ``block_q`` of a
+    row's ``sq`` queries, None (the dense path, with a line in the log) where
+    a compiled call's block of q rows is neither whole 128-lane tiles nor
+    the whole row: the kernels turn a block's statistics between columns and
+    rows (``_as_rows``, ``_as_columns``) by transposes of whole 128-lane
+    tiles, in both layouts.  The interpreter takes any; no cell and no
+    caller on the chip has such a shape."""
+    block_q = min(block_q, sq)
+    if interpret is False and block_q % _LANES and block_q != sq:
+        _log_path("dense", f"a block of {block_q} of a row's {sq} queries is "
+                           f"neither whole {_LANES}-lane tiles nor the row: "
+                           f"the kernels turn row statistics in such tiles")
+        return None
+    return interpret
+
+
 def _sds(shape, dtype, *arrays):
     """ShapeDtypeStruct whose varying-manner matches the inputs' union.
 
@@ -190,13 +234,18 @@ _STEP_TILE = 64 * 1024
 # row against 5.1 / 5.4 / 6.5 with no walk: a step of the forward's running
 # softmax costs 1.5 us whatever its width; PERF.md, PR 32).
 _SUB_TILE = 512
+# The lanes a statistic is replicated over where queries lie down the
+# sublanes (``flash_fwd``'s running maximum and sum, ``flash_bwd_dq``'s
+# ``lse`` and ``delta``): one vector register's.
+_LANES = 128
 _announced = set()
 
 
 def _padded_bytes(shape, dtype):
     """VMEM bytes of a block of ``shape``: the last dimension occupies whole
     128-lane rows (a width of 64 or of 1 as much as one of 128), the one
-    before it whole tiles of 8 sublanes of 32 bits."""
+    before it whole tiles of 8 sublanes of 32 bits (a statistic's ``(1,
+    block_q)`` row eight times its values)."""
     itemsize = jnp.dtype(dtype).itemsize
     sublanes = 8 * max(1, 4 // itemsize)
     *lead, rows, lanes = shape
@@ -234,7 +283,7 @@ class _Layout:
     split: ``q`` is ``(batch, heads, s, d)`` and the kernels' arrays are
     ``(batch x heads, s, d)``: one head a block, ``d`` lanes, a unit of the
     grid's first dimension one (batch, head) row, the row statistics
-    ``(batch x heads, s, 1)``.
+    ``(batch x heads, s, 1)`` (still: the module's docstring).
 
     packed: ``q`` is ``(batch, s, heads, d)``, the projections' own
     ``(batch, s, heads x d)`` seen as heads, and the kernels' arrays are
@@ -242,7 +291,9 @@ class _Layout:
     whole heads (two at d = 64, one at d = 128), a unit is one batch row's
     heads of one such block, and the block's index along the last axis picks
     the heads, so the pipeline's transfers do what a transpose did.  The row
-    statistics stay ``(batch, heads, s, 1)``."""
+    statistics are ``(batch, heads, 1, s)``, the sequence along the lanes, a
+    block the ``lanes / d`` heads of its unit.  A caller sees ``(batch,
+    heads, s, 1)`` in both."""
     packed: bool
     batch: int
     num_heads: int
@@ -288,29 +339,36 @@ class _Layout:
         return x.reshape(-1, x.shape[2], self.d)
 
     def stat(self, x):
-        return x if self.packed else x.reshape(self.units, x.shape[2], 1)
+        """A caller's row statistic ``(batch, heads, s, 1)`` as the kernels
+        read it: a reshape, no copy."""
+        return x.reshape(self.shape(x.shape[2], stat=True))
 
     def shape(self, length, stat=False, width=None):
         """A result of ``length`` positions, as the kernels write it;
         ``width`` lanes where the result has a width of its own (the split
         layout's two-product form: values, the rotary part)."""
-        if not self.packed:
-            return self.units, length, 1 if stat else width or self.d
         if stat:
-            return self.batch, self.num_heads, length, 1
+            return ((self.batch, self.num_heads, 1, length) if self.packed
+                    else (self.units, length, 1))
+        if not self.packed:
+            return self.units, length, width or self.d
         return self.batch, length, self.num_heads * self.d
 
     def result(self, x, stat=False):
-        """A kernel's result in the layout ``q`` came in."""
+        """A kernel's result in the layout ``q`` came in; a row statistic as
+        a caller sees it, ``(batch, heads, s, 1)``."""
+        if stat:
+            return x.reshape(self.batch, self.num_heads, -1, 1)
         if self.packed:
-            return x if stat else x.reshape(
-                self.batch, x.shape[1], self.num_heads, self.d)
+            return x.reshape(self.batch, x.shape[1], self.num_heads, self.d)
         return x.reshape(self.batch, self.num_heads, x.shape[1], x.shape[2])
 
     def block(self, length, stat=False):
-        """One unit's block of ``length`` positions."""
+        """One unit's block of ``length`` positions; of a row statistic, a
+        ``(1, length)`` row a head in the packed layout and a ``(length, 1)``
+        column in the split one."""
         if stat:
-            return (self.heads, length, 1) if self.packed else (length, 1)
+            return (self.heads, 1, length) if self.packed else (length, 1)
         return length, self.lanes
 
     def spec(self, n, length, seq, stat=False, width=None, shared=False):
@@ -325,32 +383,32 @@ class _Layout:
         key-value heads and its index ``seq`` runs over the ``g`` query
         heads of one, ``blocks`` blocks each (the dk/dv kernel's q side)."""
         across = self.lane_blocks
-        if width is not None or shared:
-            width = width or (1 if stat else self.d)
-            if isinstance(shared, tuple):
-                group, blocks = shared
 
-                def fanned(*grid):
-                    return (grid[0] * group + jax.lax.div(grid[seq], blocks),
-                            jax.lax.rem(grid[seq], blocks), 0)
-                return pl.BlockSpec((n, length, width), fanned)
-            share = self.num_heads if shared is True else shared
-
-            def own(*grid):
-                i = jax.lax.div(grid[0], share) if share else grid[0]
-                return i, grid[seq], 0
-            return pl.BlockSpec((n, length, width), own)
-
-        def index(*grid):
+        def place(*grid):
+            """``(the block's index along the arrays' first axis, along the
+            sequence, along the packed layout's lane blocks)``."""
             i, j = grid[0], grid[seq]
-            if not self.packed:
-                return i, j, 0
             # lax, not ``//`` and ``%``: jnp's take a sign's care that costs
             # a step's lowering seconds over its hundreds of index maps.
-            rows, block = (i, 0) if across == 1 else (
-                jax.lax.div(i, across), jax.lax.rem(i, across))
-            return (rows, block, j, 0) if stat else (rows, j, block)
-        return pl.BlockSpec((n,) + self.block(length, stat), index)
+            if isinstance(shared, tuple):
+                group, blocks = shared
+                return (i * group + jax.lax.div(j, blocks),
+                        jax.lax.rem(j, blocks), 0)
+            if shared:
+                return jax.lax.div(
+                    i, self.num_heads if shared is True else shared), j, 0
+            if across == 1:
+                return i, j, 0
+            return jax.lax.div(i, across), j, jax.lax.rem(i, across)
+
+        def index(*grid):
+            rows, j, block = place(*grid)
+            if stat and self.packed:
+                return rows, block, 0, j
+            return rows, j, block
+        if stat or (width is None and not shared):
+            return pl.BlockSpec((n,) + self.block(length, stat), index)
+        return pl.BlockSpec((n, length, width or self.d), index)
 
 
 def _heads_per_block(num_heads, d):
@@ -372,7 +430,11 @@ def _announce(kernel, layout, operand, sk, block_q, block_k, rows, vmem_bytes,
     """One info line a distinct kernel and shape, and with telemetry on the
     gauges ``flash.rows_per_program`` and ``flash.heads_per_block`` and a
     ``flash`` event: which layout the shape gave this call and which program
-    the rule above made of it, read at trace time.  ``offsets`` are a causal
+    the rule above made of it, read at trace time.  The line names the row
+    statistics' array, and the gauge ``flash.stat_bytes_per_call`` carries
+    the HBM bytes of ``lse`` as a call reads or writes it, padded as Mosaic
+    lays it out (the packed layout's sequence in whole 128-lane rows; the
+    split layout's a 128-lane row a value).  ``offsets`` are a causal
     call's ``(q_offset, k_offset)``: the line then ends with the sub-tiles a
     (batch, head) row visits by ``_causal_plan`` (decided on the device where
     an offset is traced), which the gauges ``flash.causal_subtiles_visited``
@@ -408,12 +470,15 @@ def _announce(kernel, layout, operand, sk, block_q, block_k, rows, vmem_bytes,
     programs = (layout.units * layout.heads // rows * layout.lane_blocks
                 * (sq // block_q) * (sk // block_k))
     shape = ",".join(str(n) for n in operand.shape)
+    stat = layout.shape(sq, stat=True)
+    stat_bytes = math.prod(stat[:-1]) * -(-stat[-1] // _LANES) * _LANES * 4
     detail = (f"{kernel} {jnp.dtype(operand.dtype).name}[{shape}] "
               f"over {sk} keys: {layout.name} layout, {layout.heads} heads a "
               f"block of {layout.lanes} lanes, blocks {block_q} x {block_k}, "
               f"G = {rows} (batch, head) rows a program, {programs} programs "
-              f"a call, {vmem_bytes} bytes of VMEM by the padded estimate; "
-              f"{walk}")
+              f"a call, {vmem_bytes} bytes of VMEM by the padded estimate, row "
+              f"statistics f32[{','.join(str(n) for n in stat)}] "
+              f"({stat_bytes} bytes a call in HBM); {walk}")
     _log_path("pallas", detail)
     from autodist_tpu import observability
     if not observability.enabled():
@@ -421,6 +486,7 @@ def _announce(kernel, layout, operand, sk, block_q, block_k, rows, vmem_bytes,
     registry = observability.registry()
     registry.gauge("flash.rows_per_program").set(rows)
     registry.gauge("flash.heads_per_block").set(layout.heads)
+    registry.gauge("flash.stat_bytes_per_call").set(stat_bytes)
     registry.gauge("flash.causal_subtiles_visited").set(visited)
     registry.gauge("flash.causal_subtiles_total").set(total)
     registry.gauge("flash.window_subtiles_visited").set(in_window)
@@ -618,27 +684,78 @@ def _only(keep, x):
     return x if keep is None else jnp.where(keep, x, jnp.zeros_like(x))
 
 
-def _over_lanes(stat, d, like):
-    """A program's row statistics ``(rows, heads, block, 1)`` spread over
-    their heads' lanes of a block shaped ``like``; as they are where the
-    block is one head a row (the split layout) and they broadcast."""
-    if len(stat.shape) < 4:
+def _across(stat, width):
+    """A lane-replicated statistic ``(..., block, _LANES)`` at ``width``
+    lanes: whole registers side by side (no lane is broadcast), a slice of
+    one where a tile is narrower.  A tile is narrower than a register or
+    whole registers wide: the blocks divide the sequence and a head's lanes
+    are 64, 128 or 256."""
+    lanes = stat.shape[-1]
+    if width == lanes:
         return stat
-    out = stat[:, 0]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, like.shape[-1]), 2)
+    if width < lanes:
+        return stat[..., :width]
+    assert width % lanes == 0, \
+        f"a tile of {width} lanes is not whole {lanes}-lane registers"
+    return jnp.tile(stat, (1,) * (stat.ndim - 1) + (width // lanes,))
+
+
+def _as_columns(rows):
+    """Statistics read as they cross HBM, ``(..., 1, block)`` rows along the
+    lanes, turned to lane-replicated columns ``(..., block, _LANES)``: a
+    broadcast down the sublanes and one transpose of 128-lane tiles."""
+    return jnp.swapaxes(jnp.broadcast_to(
+        rows, rows.shape[:-2] + (_LANES, rows.shape[-1])), -1, -2)
+
+
+def _as_rows(columns):
+    """Lane-replicated statistics ``(..., block, _LANES)`` as the rows
+    ``(..., 1, block)`` that cross HBM."""
+    return jnp.swapaxes(columns, -1, -2)[..., :1, :]
+
+
+def _stat_columns(ref, at, width):
+    """A block of ``lse`` or ``delta`` at the rows ``at`` for a tile with
+    queries down the sublanes: the packed layout's ``(1, block)`` rows
+    turned to lane-replicated columns at the tile's ``width``, the split
+    layout's ``(block, 1)`` columns as they are (they broadcast)."""
+    stat = ref[at]
+    return _across(_as_columns(stat), width) if len(ref.shape) == 4 else stat
+
+
+def _stat_rows(ref, at):
+    """The same block for a tile with queries along the lanes (the dk/dv
+    kernel's): the packed layout's rows as they were read, the split
+    layout's columns turned to ``(1, block)`` rows."""
+    stat = ref[at]
+    if len(ref.shape) == 4:
+        return stat
+    return _as_rows(jnp.broadcast_to(stat, stat.shape[:-1] + (_LANES,)))
+
+
+def _over_lanes(stat, d, lanes):
+    """A program's lane-replicated statistics spread over a block's
+    ``lanes``: the packed layout's ``(rows, heads, block, _LANES)`` each
+    head's over its own ``d`` lanes, the split layout's one head's over
+    all."""
+    if len(stat.shape) < 4:
+        return _across(stat, lanes)
+    out = _across(stat[:, 0], lanes)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, lanes), 2)
     for head in range(1, stat.shape[1]):
-        out = jnp.where(lane >= head * d, stat[:, head], out)
+        out = jnp.where(lane >= head * d, _across(stat[:, head], lanes), out)
     return out
 
 
 def _row_of(ref, at, head=None, keys=None):
-    """The rows ``at`` of a program's scratch or statistics.  Scratch has no
-    rows dimension in a program of one row of the split layout
-    (``_scratch``): the long-sequence cells' kernels compile to the same
-    Mosaic module whether or not short rows group.  The packed layout's
-    statistics are ``(rows, heads, block, 1)`` and give those of ``head``
-    (the one head where a block holds one).  ``keys`` narrows an accumulator
-    over a k block to a sub-tile's positions."""
+    """The rows ``at`` of a program's scratch or of a block of statistics.
+    Scratch has no rows dimension in a program of one row of the split
+    layout (``_scratch``): the long-sequence cells' kernels compile to the
+    same Mosaic module whether or not short rows group.  The packed layout's
+    statistics have a heads dimension after the rows', in scratch ``(rows,
+    heads, block, _LANES)`` and in a block ``(rows, heads, 1, block)``, and
+    give those of ``head`` (the one head where a block holds one).  ``keys``
+    narrows an accumulator over a k block to a sub-tile's positions."""
     if len(ref.shape) == 4:
         return at, 0 if head is None else head
     if len(ref.shape) == 2:
@@ -713,15 +830,18 @@ def _kernel_call(offs, *arrays, name, body, layout, n, grid_tail, ins, outs,
     )(offs, *arrays)
 
 
-def causal_bias(sq, sk, q_offset=0, k_offset=0, window=None):
+def causal_bias(sq, sk, q_offset=0, k_offset=0, window=None,
+                keys_first=False):
     """Additive causal bias (0 where visible, -inf where masked) for a
     (sq, sk) score block whose rows/cols sit at the given global offsets
     (offsets may be traced scalars). The single definition of causal
     masking shared by the dense reference, the Pallas kernels, and the
     ring/Ulysses SP paths.  Under a ``window`` position t sees the keys s
-    with ``t - window < s <= t``."""
-    q_pos = q_offset + jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
-    k_pos = k_offset + jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
+    with ``t - window < s <= t``.  ``keys_first`` gives the transposed
+    block's, (sk, sq): the dk/dv kernel's scores."""
+    shape, q_dim = ((sk, sq), 1) if keys_first else ((sq, sk), 0)
+    q_pos = q_offset + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
+    k_pos = k_offset + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
     if window is None:
         return jnp.where(q_pos >= k_pos, 0.0, _NEG_INF)
     return jnp.where(jnp.logical_and(q_pos >= k_pos, k_pos > q_pos - window),
@@ -881,15 +1001,19 @@ def _fwd_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
                 if causal and not seen:
                     s = s + causal_bias(block_q, k.shape[-2], q_start, k_first,
                                         window)
+                # The running maximum and sum are lane-replicated: a
+                # row's value in every lane of its register.
                 m_prev = m[stat]
                 m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
                 alpha = jnp.exp(m_prev - m_new)
                 # Masked entries contribute EXACTLY zero (not exp(-1e30 -
                 # m)): in a fully-masked block m_new stays at the sentinel
                 # and s - m_new = 0.
-                p = jnp.exp(s - m_new) if seen else jnp.where(
-                    s > _NEG_INF / 2, jnp.exp(s - m_new), 0.0)
+                wide = _across(m_new, s.shape[-1])
+                p = jnp.exp(s - wide) if seen else jnp.where(
+                    s > _NEG_INF / 2, jnp.exp(s - wide), 0.0)
                 l[stat] = l[stat] * alpha + p.sum(-1, keepdims=True)
+                alpha = _across(alpha, acc.shape[-1])
                 if keep is not None:    # the other heads' lanes keep theirs
                     alpha = jnp.where(keep, alpha, 1.0)
                 acc[row] = acc[row] * alpha + _dot(p.astype(v.dtype), v, -1,
@@ -905,13 +1029,20 @@ def _fwd_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
         # 1e-30, NOT 1e-38: f32 subnormals flush to zero on TPU (and in the
         # interpret pipeline), and max(0, ftz(1e-38)) / 0 is how a guard
         # epsilon turns into NaN for rows that saw no visible block.
-        o_ref[every] = (acc[:] / _over_lanes(jnp.maximum(l[:], 1e-30), d,
-                                             acc)).astype(o_ref.dtype)
+        total = jnp.maximum(l[:], 1e-30)
+        o_ref[every] = (acc[:] / _over_lanes(total, d, acc.shape[-1])) \
+            .astype(o_ref.dtype)
         # Rows that saw no visible block keep the finite sentinel (not -inf:
         # downstream combines subtract lse values and -inf - -inf = nan).
-        lse_ref[every] = jnp.where(
-            l[:] > 0, m[:] + jnp.log(jnp.maximum(l[:], 1e-30)),
-            _NEG_INF).astype(lse_ref.dtype)
+        lse = jnp.where(l[:] > 0, m[:] + jnp.log(total), _NEG_INF) \
+            .astype(lse_ref.dtype)
+        # From lane-replicated columns to what crosses HBM, once a q block:
+        # the packed layout's rows a head, the split layout's columns.
+        if len(lse.shape) == 4:
+            for head in range(lse.shape[1]):
+                lse_ref[:, head] = _as_rows(lse[:, head])
+        else:
+            lse_ref[every] = lse[..., :1]
 
 
 def _blocks(sq, sk, block_q, block_k):
@@ -965,7 +1096,9 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, k_offset,
     f32 = jnp.dtype(jnp.float32)
     q_block, k_block = layout.block(block_q), layout.block(block_k)
     row_block = layout.block(block_q, stat=True)
-    scratch = (q_block, row_block, row_block)
+    # The accumulator, then the running maximum and sum, lane-replicated.
+    running = row_block[:-2] + (block_q, _LANES)
+    scratch = (q_block, running, running)
     # With grouped heads a program is one row: its k block is one
     # key-value head's.
     g, vmem = _rows_per_program(
@@ -1039,10 +1172,12 @@ def _bwd_dq_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
                 if causal and not seen:
                     s = s + causal_bias(block_q, k.shape[-2], q_start, k_first,
                                         window)
-                p = jnp.exp(s - lse_ref[stat]) if seen else jnp.where(
-                    s > _NEG_INF / 2, jnp.exp(s - lse_ref[stat]), 0.0)
+                lse = _stat_columns(lse_ref, stat, s.shape[-1])
+                delta = _stat_columns(delta_ref, stat, s.shape[-1])
+                p = jnp.exp(s - lse) if seen else jnp.where(
+                    s > _NEG_INF / 2, jnp.exp(s - lse), 0.0)
                 dp = _dot(do, v, -1, -1)
-                ds = p * (dp - delta_ref[stat]) * scale
+                ds = p * (dp - delta) * scale
                 dq_acc[_row_of(dq_acc, at)] += _dot(ds.astype(k.dtype), k, -1,
                                                     -2)
                 if qr_ref is not None:
@@ -1061,7 +1196,12 @@ def _bwd_dq_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
 def _bwd_dkv_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
                     skip_blocks, scale=None, window=None, group=1):
     """Grid (units, k-blocks, q-blocks): q innermost, the dk and dv
-    accumulators carried across it.  With ``group`` query heads a key-value
+    accumulators carried across it.  The scores are computed transposed,
+    keys down the sublanes and queries along the lanes (``s^T = k . q^T``,
+    ``dp^T = v . do^T``): a block of ``lse`` or ``delta`` is the ``(1,
+    block_q)`` row it crossed HBM as and broadcasts down the sublanes, and
+    ``dv += p^T . do`` and ``dk += ds^T . q`` are plain products (no
+    transposed left operand for Mosaic to turn).  With ``group`` query heads a key-value
     head the grid's first index counts key-value heads and its innermost
     runs over the q blocks of the group's query heads one after another
     (``_Layout.spec``'s ``(group, blocks)``), so one head's dk and dv are
@@ -1107,23 +1247,25 @@ def _bwd_dkv_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
                 do = do_ref[at]
                 keep = _lanes_of(head, d, q)
                 q, do = _only(keep, q), _only(keep, do)
-                s = _dot(q, k, -1, -1)
+                # Everything below is transposed, (keys, queries): s is s^T.
+                s = _dot(k, q, -1, -1)
                 if qr_ref is not None:
                     qr = qr_ref[at]
-                    s = s + _dot(qr, kr_ref[_keys_of(0, keys)], -1, -1)
+                    s = s + _dot(kr_ref[_keys_of(0, keys)], qr, -1, -1)
                 s = s * scale
                 if causal and not seen:
                     s = s + causal_bias(block_q, k.shape[-2], q_start, k_first,
-                                        window)
-                p = jnp.exp(s - lse_ref[stat]) if seen else jnp.where(
-                    s > _NEG_INF / 2, jnp.exp(s - lse_ref[stat]), 0.0)
-                dv_acc[row] += _dot(p.astype(do.dtype), do, -2, -2)  # p^T do
-                dp = _dot(do, v, -1, -1)
-                ds = p * (dp - delta_ref[stat]) * scale
-                dk_acc[row] += _dot(ds.astype(q.dtype), q, -2, -2)   # ds^T q
+                                        window, keys_first=True)
+                lse = _stat_rows(lse_ref, stat)
+                p = jnp.exp(s - lse) if seen else jnp.where(
+                    s > _NEG_INF / 2, jnp.exp(s - lse), 0.0)
+                dv_acc[row] += _dot(p.astype(do.dtype), do, -1, -2)  # p^T do
+                dp = _dot(v, do, -1, -1)
+                ds = p * (dp - _stat_rows(delta_ref, stat)) * scale
+                dk_acc[row] += _dot(ds.astype(q.dtype), q, -1, -2)   # ds^T q
                 if qr_ref is not None:  # this head's part of the shared key's
                     dkr_acc[_row_of(dkr_acc, at, keys=keys)] += _dot(
-                        ds.astype(qr.dtype), qr, -2, -2)
+                        ds.astype(qr.dtype), qr, -1, -2)
             _for_rows(rows, q_ref.shape[-1] // d, block_q * block_k, _rows)
         _for_keys(skip_blocks, q_start, k_start, block_q, block_k, sub, _run,
                   window)
@@ -1253,11 +1395,14 @@ def _two_product_plan(q, q_rope, k, k_rope, v, block_q, block_k):
 
 
 def _announce_two_product(kernel, layout, q, sk, blocks, widths, in_blocks,
-                          out_blocks, causal):
+                          out_blocks, causal, scratch=None):
+    """``scratch`` are the f32 scratch shapes where they are not the
+    results' blocks (the forward's running maximum and sum)."""
     r, dv = widths
     _, vmem = _rows_per_program(
         1, *blocks, in_blocks + out_blocks,
-        [(shape, jnp.float32) for shape, _ in out_blocks])
+        [(shape, jnp.float32)
+         for shape in scratch or [shape for shape, _ in out_blocks]])
     _announce(f"{kernel} two-product ({layout.d} + {r} lanes a score, one "
               f"{r}-lane key a position shared by {layout.num_heads} heads, "
               f"values of {dv})", layout, q, sk, *blocks, 1, vmem,
@@ -1273,20 +1418,21 @@ def _flash_fwd2(q, q_rope, k, k_rope, v, scale, causal, block_q, block_k,
     block_q, block_k = blocks
     f32 = jnp.dtype(jnp.float32)
     main, rope = ins(1, 2)
-    o_block, row_block = (block_q, dv), (block_q, 1)
+    o_block, row_block = (block_q, dv), layout.block(block_q, stat=True)
+    scratch = (o_block, (block_q, _LANES), (block_q, _LANES))
     _announce_two_product(
         "flash_fwd", layout, qr, sk, blocks, (r, dv),
         [((block_q, layout.d), q.dtype), ((block_k, layout.d), k.dtype),
          ((block_k, dv), v.dtype), ((block_q, r), q.dtype),
          ((block_k, r), k_rope.dtype)],
-        [(o_block, q.dtype), (row_block, f32)], causal)
+        [(o_block, q.dtype), (row_block, f32)], causal, scratch)
     out, lse = _kernel_call(
         jnp.zeros((2,), jnp.int32), qr, kr, vr, qrr, krr, name="flash_fwd",
         body=_fwd_kernel, layout=layout, n=1,
         grid_tail=(sq // block_q, sk // block_k), ins=main + rope,
         outs=((block_q, 1, False, jnp.dtype(q.dtype), sq, dv),
               (block_q, 1, True, f32, sq)),
-        scratch=(o_block, row_block, row_block), block_q=block_q,
+        scratch=scratch, block_q=block_q,
         block_k=block_k, sub=_sub_tile(causal, block_k), causal=causal,
         interpret=interpret, scale=scale)
     return (out.reshape(q.shape[:3] + (dv,)),
@@ -1306,6 +1452,7 @@ def _flash_bwd2(q, q_rope, k, k_rope, v, do, lse, delta, scale, causal,
     lser, deltar = layout.stat(lse), layout.stat(delta)
     f32 = jnp.dtype(jnp.float32)
     dtype = jnp.dtype(q.dtype)
+    row_block = layout.block(block_q, stat=True)
 
     def call(name, body, grid_tail, at_q, at_k, outs):
         """One backward kernel; ``outs`` are ``(length, whole length,
@@ -1317,7 +1464,7 @@ def _flash_bwd2(q, q_rope, k, k_rope, v, do, lse, delta, scale, causal,
             name, layout, qr, sk, blocks, (r, dv),
             [((block_q, layout.d), dtype), ((block_k, layout.d), dtype),
              ((block_k, dv), dtype), ((block_q, dv), dtype),
-             ((block_q, 1), f32), ((block_q, 1), f32),
+             (row_block, f32), (row_block, f32),
              ((block_q, r), dtype), ((block_k, r), dtype)], out_blocks,
             causal)
         return _kernel_call(
@@ -1394,7 +1541,8 @@ def flash_attention_two_product(q, q_rope, k, k_rope, v, scale, causal=True,
                            f"({block_q}, {block_k})")
         interpret = None
     else:
-        interpret = _pallas_interpret(interpret, q.dtype)
+        interpret = _kernels_serve(_pallas_interpret(interpret, q.dtype), s,
+                                   block_q)
     if interpret is None:
         return two_product_reference(q, q_rope, k, k_rope, v, scale, causal)
     return _two_product_kernels(q, q_rope, k, k_rope, v, scale, causal,
@@ -1413,7 +1561,8 @@ def _use_pallas(q, k, block_q, block_k, interpret):
         _log_path("dense", f"block attention: seq ({sq}, {sk}) does not "
                            f"divide blocks ({block_q}, {block_k})")
         return False
-    return _pallas_interpret(None, q.dtype) is not None
+    return _kernels_serve(_pallas_interpret(None, q.dtype), sq,
+                          block_q) is not None
 
 
 def block_attn_fwd(q, k, v, causal, q_offset, k_offset, block_q=512,
@@ -1478,7 +1627,8 @@ def flash_attention(q, k, v, causal=False, block_q=512, block_k=1024,
     only. ``interpret=None`` picks the Pallas kernels on TPU and the dense
     path elsewhere.
     """
-    interpret = _pallas_interpret(interpret, q.dtype)
+    interpret = _kernels_serve(_pallas_interpret(interpret, q.dtype),
+                               q.shape[2], block_q)
     if interpret is None:
         return _dense_reference(q, k, v, causal, q_offset, window)
     o, _ = _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, 0,
@@ -1488,7 +1638,8 @@ def flash_attention(q, k, v, causal=False, block_q=512, block_k=1024,
 
 def _fwd_rule(q, k, v, causal, block_q, block_k, q_offset, interpret,
               window):
-    interpret = _pallas_interpret(interpret, q.dtype)
+    interpret = _kernels_serve(_pallas_interpret(interpret, q.dtype),
+                               q.shape[2], block_q)
     if interpret is None:
         o, lse = _dense_fwd(q, k, v, causal, q_offset, window=window)
         return o.astype(q.dtype), (q, k, v, o.astype(q.dtype), lse)
@@ -1506,7 +1657,8 @@ def _bwd_rule(causal, block_q, block_k, q_offset, interpret, window, res,
     # dense elsewhere); False = native Pallas kernels; True = interpreted
     # Pallas. An explicit False must NOT mean "dense" — that would hand the
     # default TPU transformer path the O(s^2) dense backward.
-    interpret = _pallas_interpret(interpret, q.dtype)
+    interpret = _kernels_serve(_pallas_interpret(interpret, q.dtype),
+                               q.shape[2], block_q)
     if interpret is None:
         dq, dk, dv = _dense_bwd(q, k, v, do, lse, delta, causal, q_offset,
                                 window=window)
@@ -1640,7 +1792,7 @@ def make_flash_attn_fn(causal=False, block_q=512, block_k=1024):
             _log_path("dense", f"seq {s} does not divide blocks "
                                f"({block_q}, {block_k})")
             return None
-        interpret = _pallas_interpret(None, dtype)
+        interpret = _kernels_serve(_pallas_interpret(None, dtype), s, bq)
         return None if interpret is None else (bq, bk, interpret)
 
     def attn_fn(q, k, v, mask=None, window=None):

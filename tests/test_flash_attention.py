@@ -105,16 +105,19 @@ def test_kernel_goes_under_a_full_manual_region_on_a_mesh():
 # what enters the MXU and what leaves a kernel has the inputs' dtype.
 
 
-def _eqns(jaxpr):
+def _eqns(jaxpr, kernels=True):
     """Every equation of a jaxpr, nested ones (jit and custom_vjp bodies,
-    a kernel's ``pl.when`` branches) included."""
+    a kernel's ``pl.when`` branches) included; without ``kernels`` what lies
+    around the ``pallas_call``s only, not their bodies."""
     for eqn in jaxpr.eqns:
         yield eqn
+        if eqn.primitive.name == "pallas_call" and not kernels:
+            continue
         for sub in _sub_jaxprs(eqn):
-            yield from _eqns(sub)
+            yield from _eqns(sub, kernels)
         for param in eqn.params.values():    # shard_map's is a bare Jaxpr
             if hasattr(param, "eqns"):
-                yield from _eqns(param)
+                yield from _eqns(param, kernels)
 
 
 def _force_rows(monkeypatch, rows):
@@ -363,7 +366,8 @@ def test_hook_takes_the_layout_the_shape_gives(heads, d, packed, monkeypatch):
     """``make_flash_attn_fn``'s hook for heads of a shape: where several
     fill a block of 128 lanes it gives ``mha`` a function of (batch, s,
     heads, d), the kernels read that layout and the jaxpr has no transpose
-    of an operand; an odd count of 64-wide heads (gpt2-xl's 25) does not
+    around them (inside, the forward turns its statistics once a q block to
+    the rows that cross HBM); an odd count of 64-wide heads (gpt2-xl's 25) does not
     pack, a 128-wide head (OLMoE's) is a block alone and gains nothing, and
     there the hook gives None and ``mha`` transposes as ever."""
     hook = _interpreted_hook(monkeypatch, causal=True)
@@ -372,7 +376,8 @@ def test_hook_takes_the_layout_the_shape_gives(heads, d, packed, monkeypatch):
     x = jax.ShapeDtypeStruct((2, 32, heads, d), jnp.float32)
     jaxpr = jax.make_jaxpr(lambda *x: _attend(hook, *x))(x, x, x).jaxpr
     kernel = _kernels(jaxpr)["flash_fwd"]
-    transposes = [e for e in _eqns(jaxpr) if e.primitive.name == "transpose"]
+    transposes = [e for e in _eqns(jaxpr, kernels=False)
+                  if e.primitive.name == "transpose"]
     if packed:
         assert kernel.outvars[0].aval.shape == (2, 32, heads * d)
         assert not transposes
@@ -460,7 +465,7 @@ def test_mha_transposes_nothing_for_a_hook_that_takes_its_layout(monkeypatch):
     jaxpr = jax.make_jaxpr(jax.grad(loss(hook), argnums=(0, 1)))(p, x).jaxpr
     assert set(_kernels(jaxpr)) == {"flash_fwd", "flash_bwd_dq",
                                     "flash_bwd_dkv"}
-    for eqn in _eqns(jaxpr):
+    for eqn in _eqns(jaxpr, kernels=False):
         if eqn.primitive.name == "transpose":
             size = max(v.aval.size for v in eqn.invars)
             assert size < b * s * dim or eqn.invars[0].aval.ndim == 2, eqn
@@ -549,7 +554,11 @@ def test_grid_starts_with_programs_not_rows(cell):
     names (the benchmark's trace reader finds them by name and counts
     calls); the grid's first dimension is batch x heads / G, and the
     results are (batch, s, heads x d) in the packed layout, (batch x heads,
-    s, d) in the split one."""
+    s, d) in the split one; in the packed layout ``lse`` leaves the forward
+    with the sequence along the lanes, (batch, heads, 1, s), and enters both
+    backward kernels so, ``delta`` beside it; the split layout keeps the
+    (batch x heads, s, 1) its arrays had (what XLA sees of a split-layout
+    step is what it was)."""
     (b, h, s, d), (layout, _), _ = _CELL_SHAPES[cell]
     kernels, picked = _plan(cell)
     assert list(kernels) == ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
@@ -564,9 +573,13 @@ def test_grid_starts_with_programs_not_rows(cell):
             assert out.aval.shape == ((b, s, h * d) if layout == "packed"
                                       else (b * h, s, d)), (name, out.aval)
             assert out.aval.dtype == jnp.bfloat16
+    dense = (b, h, 1, s) if layout == "packed" else (b * h, s, 1)
     lse = kernels["flash_fwd"].outvars[1].aval
-    assert lse.shape == ((b, h, s, 1) if layout == "packed"
-                         else (b * h, s, 1)) and lse.dtype == jnp.float32
+    assert lse.shape == dense and lse.dtype == jnp.float32
+    for name in ("flash_bwd_dq", "flash_bwd_dkv"):
+        # offsets, q, k, v, do, then lse and delta.
+        for stat in kernels[name].invars[5:7]:
+            assert stat.aval.shape == dense and stat.aval.dtype == jnp.float32
 
 
 @pytest.mark.parametrize("rows,heads,tile,steps,a_step", [
@@ -604,9 +617,12 @@ def test_rows_a_step_follow_the_tile(rows, heads, tile, steps, a_step):
 
 
 def test_padded_bytes_count_whole_lanes_and_sublanes():
-    # A width of 64 or of 1 occupies 128 lanes; bf16 packs 16 rows a tile.
+    # A width of 64 occupies 128 lanes; bf16 packs 16 rows a tile; a
+    # statistic's (1, block) row occupies a tile of 8 sublanes.
     assert fa._padded_bytes((128, 64), jnp.bfloat16) == 128 * 128 * 2
     assert fa._padded_bytes((128, 1), jnp.float32) == 128 * 128 * 4
+    assert fa._padded_bytes((1, 512), jnp.float32) == 8 * 512 * 4
+    assert fa._padded_bytes((2, 1, 128), jnp.float32) == 2 * 8 * 128 * 4
     assert fa._padded_bytes((8, 128), jnp.bfloat16) == 16 * 128 * 2
     assert fa._padded_bytes((3, 512, 128), jnp.float32) == 3 * 512 * 128 * 4
 
@@ -618,8 +634,9 @@ def test_flash_event_and_gauges_carry_the_program_at_trace_time(
         heads, layout, lanes, shape, monkeypatch):
     """Telemetry says which layout a call's shape gave it and which program
     the rule made of it: the gauges ``flash.rows_per_program`` and
-    ``flash.heads_per_block`` and one ``flash`` event a kernel and shape,
-    written while tracing (nothing runs); the info line of the log is the
+    ``flash.heads_per_block``, ``flash.stat_bytes_per_call`` (what ``lse``
+    takes in HBM) and one ``flash`` event a kernel and shape, naming the row
+    statistics' array, written while tracing (nothing runs); the info line of the log is the
     same text, so a head count that does not pack (three heads of 64) says
     ``split`` there."""
     import logging
@@ -646,6 +663,12 @@ def test_flash_event_and_gauges_carry_the_program_at_trace_time(
     rows = 3 * heads if layout == "split" else 6
     assert gauges["flash.rows_per_program"] == rows
     assert gauges["flash.heads_per_block"] == (2 if layout == "packed" else 1)
+    # lse in HBM: packed, 4 bytes a value, the 128 positions one row of
+    # lanes; split, a 128-lane row a value.
+    stat_bytes = 3 * heads * 128 * (4 if layout == "packed" else 512)
+    assert gauges["flash.stat_bytes_per_call"] == stat_bytes
+    stats = (f"row statistics f32[3,{heads},1,128]" if layout == "packed"
+             else f"row statistics f32[{3 * heads},128,1]")
     events = [e["detail"] for e in recorder.events() if e["kind"] == "flash"]
     assert len(events) == len(set(events)) == 3
     for kernel, detail in zip(("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"),
@@ -655,6 +678,8 @@ def test_flash_event_and_gauges_carry_the_program_at_trace_time(
         assert f"blocks 128 x 128, G = {rows} " in detail, detail
         programs = 1 if layout == "split" else 2
         assert f" {programs} programs a call" in detail and "VMEM" in detail
+        assert f"{stats} ({stat_bytes} bytes a call in HBM); " in detail, \
+            detail
         assert f"flash_attention: pallas path ({detail})" in lines
 
 
@@ -844,45 +869,113 @@ def _traced_kernels(shape, causal, interpret=False):
         for name, eqn in _kernels(jaxpr.jaxpr).items()}
 
 
-# What the commit before the walk (c556520, PR 31) traced: each kernel's
-# equations, nested ones counted once, and where the walk must change
-# nothing the hash of the body's text.
-_BEFORE_THE_WALK = {
-    "bert-base.mlm-s512": {"flash_fwd": (113, "b584a10a3c262514"),
-                           "flash_bwd_dq": (70, "a2c9955b45875af7"),
-                           "flash_bwd_dkv": (80, "e416c5bb716a954f")},
-    "bert-base.mlm-s128": {"flash_fwd": (115, "6501763fbc08426b"),
-                           "flash_bwd_dq": (72, "f65bbc359df74fec"),
-                           "flash_bwd_dkv": (82, "1b2a104d4e381bd7")},
-    "gpt2-medium.train-s1024": {"flash_fwd": (124, None),
-                                "flash_bwd_dq": (81, None),
+# What this tree (PR 45: the statistics along the lanes, the dk/dv kernel's
+# scores transposed) traces with one sub-tile a block: each kernel's
+# equations, nested ones counted once, and where the walk must change nothing
+# the hash of the body's text.  Not causal, that is the cell's own program;
+# causal, the same kernels traced with a sub-tile as long as a k block
+# (``_SUB_TILE`` = 1,024: no walk).  Re-pinned by PR 45 from c556520's (PR 31,
+# the commit before the walk), whose bodies held the statistics as columns.
+_ONE_SUB_TILE = {
+    "bert-base.mlm-s512": {"flash_fwd": (118, "ed35ecdf0e61ac57"),
+                           "flash_bwd_dq": (76, "676a540209eb6dfe"),
+                           "flash_bwd_dkv": (80, "cb6753c2cb69ae69")},
+    "bert-base.mlm-s128": {"flash_fwd": (119, "a7a08f618665e4be"),
+                           "flash_bwd_dq": (76, "6fe581faa9fa870d"),
+                           "flash_bwd_dkv": (82, "6ad2bcbc5e9d2f09")},
+    "gpt2-medium.train-s1024": {"flash_fwd": (129, None),
+                                "flash_bwd_dq": (87, None),
                                 "flash_bwd_dkv": (91, None)},
-    "gpt2-xl.train-s1024-x4": {"flash_fwd": (86, None),
+    "gpt2-xl.train-s1024-x4": {"flash_fwd": (88, None),
                                "flash_bwd_dq": (60, None),
-                               "flash_bwd_dkv": (70, None)},
+                               "flash_bwd_dkv": (76, None)},
     "olmoe-1b-7b.train-s4096": {"flash_fwd": (86, None),
                                 "flash_bwd_dq": (60, None),
-                                "flash_bwd_dkv": (70, None)},
+                                "flash_bwd_dkv": (76, None)},
 }
 
 
-@pytest.mark.parametrize("cell", list(_BEFORE_THE_WALK))
-def test_the_walk_leaves_a_program_of_one_sub_tile_as_it_was(cell):
-    """Not causal (both BERT cells), the kernels' bodies are the text they
-    were before the walk.  Causal with two sub-tiles a block, the chip's form
-    holds a tile's arithmetic three times (the block whole without the mask,
-    whole with it, and a sub-tile's in the loop's body) beside the walk's own
-    few equations, so what a step's tracing costs stays within three times
-    what it was; the interpreter's form, one loop over every sub-tile, adds
-    the loop's few equations to what the body was."""
-    before = _BEFORE_THE_WALK[cell]
+@pytest.mark.parametrize("cell", list(_ONE_SUB_TILE))
+def test_the_walk_leaves_a_program_of_one_sub_tile_as_it_was(cell,
+                                                             monkeypatch):
+    """Not causal (both BERT cells), the kernels' bodies are the pinned text,
+    hash for hash: an edit that moves a non-causal body shows here.  Causal
+    with two sub-tiles a block, the chip's form holds a tile's arithmetic
+    three times (the block whole without the mask, whole with it, and a
+    sub-tile's in the loop's body) beside the walk's own few equations, so
+    what a step's tracing costs stays within three times what the same
+    kernels cost with no walk (pinned, and traced again with the module's
+    constant steered); the interpreter's form, one loop over every sub-tile,
+    adds the loop's few equations to what the body was."""
+    before = _ONE_SUB_TILE[cell]
     causal = "bert" not in cell
     now = _traced_kernels(_CELL_SHAPES[cell][0], causal)
     assert list(now) == list(before)
+    if not causal:
+        assert now == before, now
+        return
     interpreted = _traced_kernels(_CELL_SHAPES[cell][0], causal, True)
-    for name, (count, text) in before.items():
-        if not causal:
-            assert now[name] == (count, text), (name, now[name])
-            continue
+    monkeypatch.setattr(fa, "_SUB_TILE", 1024)
+    unwalked = _traced_kernels(_CELL_SHAPES[cell][0], causal)
+    for name, (count, _) in before.items():
+        assert unwalked[name][0] == count, (name, unwalked[name])
         assert 2 * count <= now[name][0] <= 3 * count, (name, now[name])
         assert count < interpreted[name][0] <= count + 10, interpreted
+
+
+# ---------------------------------------------------------------------------
+# the row statistics: the sequence along the lanes, in HBM and in VMEM
+
+
+def _two_product_kernels():
+    """The three kernels of the two-product form's jaxpr (nothing runs) at
+    JoyAI's widths: 128 + 64 lanes a score, values of 128."""
+    b, h, s, d, r, dv = 1, 2, 1024, 128, 64, 128
+    shapes = ((b, h, s, d), (b, h, s, r), (b, h, s, d), (b, s, r),
+              (b, h, s, dv))
+    ops = [jax.ShapeDtypeStruct(shape, jnp.bfloat16) for shape in shapes]
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: fa.flash_attention_two_product(
+            *a, (d + r) ** -0.5, True, 512, 1024, False)
+        .astype(jnp.float32).sum(), argnums=(0, 1, 2, 3, 4)))(*ops)
+    return _kernels(jaxpr.jaxpr)
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq",
+                                    "flash_bwd_dkv"])
+@pytest.mark.parametrize("form", ["packed", "split", "two-product"])
+def test_no_statistic_is_a_column_of_one_lane(form, kernel):
+    """Packed layout: no operand, result, block or scratch of a kernel has a
+    last dimension of 1 (128 lanes a value in HBM and in VMEM): ``lse`` and
+    ``delta`` cross HBM as f32 with the sequence along the lanes, their
+    blocks are (1, block_q) rows a head that take at most a tile of 8
+    sublanes.  Split layout (and the two-product form on it): the arrays XLA
+    sees keep their (batch x heads, s, 1), and they are the only ones with a
+    last dimension of 1.  In both, what the forward keeps of its running
+    maximum and sum is lane-replicated scratch."""
+    if form == "two-product":
+        eqn = _two_product_kernels()[kernel]
+    else:
+        cell = ("gpt2-medium.train-s1024" if form == "packed"
+                else "gpt2-xl.train-s1024-x4")
+        eqn = _plan(cell)[0][kernel]
+    arrays = [v.aval for v in eqn.invars + eqn.outvars]
+    refs = [v.aval for v in eqn.params["jaxpr"].invars]
+    assert len(refs) > len(arrays)                 # blocks, then scratch
+    for aval in refs[len(arrays):]:                # scratch
+        assert aval.shape[-1] != 1, (kernel, aval)
+    stats = [(array, ref) for array, ref in zip(arrays, refs)
+             if array.dtype == jnp.float32 and 1 in array.shape[-2:]]
+    # lse leaves the forward; lse and delta enter each backward kernel.
+    assert len(stats) == (1 if kernel == "flash_fwd" else 2), arrays
+    columns = [a for a in arrays + refs if a.shape[-1] == 1]
+    if form != "packed":
+        assert len(columns) == 2 * len(stats)      # an array and its block
+        assert all(array.shape[-2:] == (1024, 1) and ref.shape[-2:] == (512, 1)
+                   for array, ref in stats)
+        return
+    assert not columns
+    for array, ref in stats:
+        assert array.shape[-1] == 1024 and ref.shape[-2:] == (1, 512)
+        true_bytes = 4 * np.prod(ref.shape)
+        assert fa._padded_bytes(ref.shape, jnp.float32) <= 8 * true_bytes

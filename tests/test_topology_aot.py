@@ -320,32 +320,14 @@ def test_v5e_compiler_hlo(case, tmp_path):
     check(text, runner)
 
 
-@pytest.mark.parametrize("layout", ["packed", "split"])
-def test_v5e_compiler_runs_no_head_split_copy_for_the_packed_layout(
-        layout, monkeypatch):
-    """A BERT-width attention layer (12 heads of 64), forward and backward,
-    compiled by libtpu for one detached v5e chip with the flash kernels as
-    Mosaic custom calls.  Through the hook's ``bshd`` the projections' (batch,
-    s, 768) is what the kernels read and write: no standalone ``copy`` of an
-    array that size is left in the optimized HLO.  The same layer with the
-    hook's (batch, heads, s, d) function alone still has the head split's
-    copies: q, k, v and their gradients' way back at the least."""
-    why_not = _why_no_detached_topology()
-    if why_not:
-        pytest.skip(why_not)
-    import importlib
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
+def _bert_layer_text(fa, chip, b, s, packed=True):
+    """The optimized HLO of one BERT-width attention layer (12 heads of 64),
+    forward and backward, through the flash hook's packed layout or behind
+    the head split's transposes."""
     from autodist_tpu.models import layers as L
-    fa = importlib.import_module("autodist_tpu.ops.flash_attention")
-    # The hook asks the backend, which reads ``cpu`` in this process.
-    monkeypatch.setattr(fa, "_pallas_interpret", lambda *_: False)
-    topo = topologies.get_topology_desc(platform="tpu",
-                                        topology_name="v5e:2x4")
-    chip = SingleDeviceSharding(topo.devices[0])
-    b, s, heads, d = 8, 512, 12, 64
+    heads, d = 12, 64
     hook = fa.make_flash_attn_fn(causal=False)
-    if layout == "split":
+    if not packed:
         split = hook
         hook = lambda q, k, v, mask=None: split(q, k, v, mask)  # no ``bshd``
 
@@ -358,8 +340,80 @@ def test_v5e_compiler_runs_no_head_split_copy_for_the_packed_layout(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
         params)
     x = jax.ShapeDtypeStruct((b, s, heads * d), jnp.bfloat16, sharding=chip)
-    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+    return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         params, x).compile().as_text()
+
+
+@pytest.mark.parametrize("b,s", [(64, 512), (256, 128)],
+                         ids=["mlm-s512", "mlm-s128"])
+def test_v5e_compiler_keeps_the_row_statistics_along_the_lanes(
+        b, s, monkeypatch):
+    """One attention layer of a BERT cell at the cell's own rows, compiled by
+    libtpu for one detached v5e chip: ``lse`` leaves ``flash_fwd`` and enters
+    both backward kernels as ``f32[batch, heads, 1, s]`` in rows of 128 lanes
+    (``T(1,128)``: 4 bytes a value) with nothing between the kernels, and
+    ``delta`` reaches them the same; no instruction of the program, a kernel's
+    operand, a ``copy`` or the ``do x o`` row sum, holds an array shaped
+    ``f32[..., s, 1]``, which is 128 lanes a value."""
+    why_not = _why_no_detached_topology()
+    if why_not:
+        pytest.skip(why_not)
+    import importlib
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    fa = importlib.import_module("autodist_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_pallas_interpret", lambda *_: False)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x4")
+    text = _bert_layer_text(fa, SingleDeviceSharding(topo.devices[0]), b, s)
+    text = re.sub(r", (metadata|backend_config)=\{.*", "", text)
+    assert not re.findall(rf"f32\[[\d,]*{s},1\]", text)
+    dense = rf"f32\[{b},12,1,{s}\]\{{3,2,1,0:T\(1,128\)"
+    kernels = {name: line for line in text.splitlines()
+               for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+               if "tpu_custom_call" in line and f"%{name}" in line}
+    assert len(kernels) == 3
+    lse = re.search(rf"(%\S+) = {dense}\S* get-tuple-element\(%flash_fwd",
+                    text).group(1)
+    for name in ("flash_bwd_dq", "flash_bwd_dkv"):
+        operands = kernels[name].split("custom-call(")[1].split(")")[0]
+        assert f"{lse}," in operands, (name, operands)
+    # What is copied of a statistic (``delta``'s way from (batch, s, heads)
+    # to the kernels' (batch, heads, 1, s)) is its 4 bytes a value.
+    for shape in re.findall(rf"= f32\[({b},12,[\d,]+)\]\S* copy\(", text):
+        assert np.prod([int(n) for n in shape.split(",")]) == b * 12 * s
+
+
+@pytest.mark.parametrize("layout,b,s", [("packed", 8, 512), ("split", 8, 512),
+                                        ("packed", 64, 512),
+                                        ("packed", 256, 128)],
+                         ids=["packed", "split", "packed-mlm-s512",
+                              "packed-mlm-s128"])
+def test_v5e_compiler_runs_no_head_split_copy_for_the_packed_layout(
+        layout, b, s, monkeypatch):
+    """A BERT-width attention layer (12 heads of 64), forward and backward,
+    compiled by libtpu for one detached v5e chip with the flash kernels as
+    Mosaic custom calls, at eight rows and at the two BERT cells' own (64 x
+    512 with two (batch, head) rows a program, 256 x 128 with thirty-two).
+    Through the hook's ``bshd`` the projections' (batch, s, 768) is what the
+    kernels read and write: no standalone ``copy`` of an array that size is
+    left in the optimized HLO.  The same layer with the hook's (batch, heads,
+    s, d) function alone still has the head split's copies: q, k, v and their
+    gradients' way back at the least."""
+    why_not = _why_no_detached_topology()
+    if why_not:
+        pytest.skip(why_not)
+    import importlib
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    fa = importlib.import_module("autodist_tpu.ops.flash_attention")
+    # The hook asks the backend, which reads ``cpu`` in this process.
+    monkeypatch.setattr(fa, "_pallas_interpret", lambda *_: False)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x4")
+    chip = SingleDeviceSharding(topo.devices[0])
+    heads, d = 12, 64
+    text = _bert_layer_text(fa, chip, b, s, packed=layout == "packed")
     assert text.count("tpu_custom_call") >= 3
     copies = [m.group(1) for m in
               re.finditer(r"= bf16\[([\d,]+)\]\S* copy\(", text)
@@ -371,10 +425,12 @@ def test_v5e_compiler_runs_no_head_split_copy_for_the_packed_layout(
         assert len(copies) >= 6, copies
 
 
+@pytest.mark.parametrize("s", [1024, 4096])
 def test_v5e_compiler_takes_the_two_product_kernels_without_a_padded_key(
-        monkeypatch):
+        s, monkeypatch):
     """One latent-attention layer at JoyAI-LLM-Flash's widths (32 heads,
-    scores 128 + 64 wide, values 128), forward and backward, compiled by
+    scores 128 + 64 wide, values 128; 1,024 positions and the cell's 4,096,
+    four k blocks a q block), forward and backward, compiled by
     libtpu for one detached v5e chip: the three flash kernels in their
     two-product form are Mosaic custom calls, and no array in the optimized
     HLO is a 192-wide key, query, value or output of every head and position
@@ -391,7 +447,7 @@ def test_v5e_compiler_takes_the_two_product_kernels_without_a_padded_key(
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x4")
     chip = SingleDeviceSharding(topo.devices[0])
-    b, s, dim, heads, nope, rope, value = 1, 1024, 2048, 32, 128, 64, 128
+    b, dim, heads, nope, rope, value = 1, 2048, 32, 128, 64, 128
     hook = fa.make_flash_attn_fn(causal=True)
 
     def loss(p, x):
@@ -419,15 +475,20 @@ def test_v5e_compiler_takes_the_two_product_kernels_without_a_padded_key(
         assert widths <= {1, 2, rope, nope, value}, (widths, call[:300])
 
 
-@pytest.mark.parametrize("heads,s", [(4, 1024), (5, 1024), (4, 2048)],
-                         ids=["packed-s1024", "split-s1024", "packed-s2048"])
-def test_v5e_compiler_takes_the_causal_walk(heads, s, monkeypatch):
+@pytest.mark.parametrize("b,heads,s,d", [
+    (2, 4, 1024, 64), (2, 5, 1024, 64), (2, 4, 2048, 64),
+    (8, 16, 1024, 64), (2, 25, 1024, 64), (2, 16, 4096, 128)],
+    ids=["packed-s1024", "split-s1024", "packed-s2048", "gpt2-medium",
+         "gpt2-xl", "olmoe"])
+def test_v5e_compiler_takes_the_causal_walk(b, heads, s, d, monkeypatch):
     """The three causal flash kernels in the form the chip runs (a k block
     of 1,024 keys in two sub-tiles: whole where both hold a seen score, the
     one that does in a loop on the device otherwise, by the program's own
     positions), forward and backward, compiled by libtpu for one detached
     v5e chip at the GPT-2 cells' block shape: two heads of 64 a 128-lane
-    block (packed) and an odd head count (split).  Mosaic takes the
+    block (packed) and an odd head count (split); then at three cells' own
+    calls (8 x 1,024 x 16 x 64 packed, 2 x 1,024 x 25 x 64 split, 2 x 4,096
+    x 16 x 128 split).  Mosaic takes the
     conditionals, the loop with its bounds from the device and the dynamic
     slices of k, v and the dk / dv accumulators; the CPU tests run the same
     bodies interpreted, where nothing is skipped."""
@@ -443,11 +504,11 @@ def test_v5e_compiler_takes_the_causal_walk(heads, s, monkeypatch):
                                         topology_name="v5e:2x4")
     chip = SingleDeviceSharding(topo.devices[0])
     hook = fa.make_flash_attn_fn(causal=True)
-    packed = hook.bshd(heads, 64)
-    assert (packed is not None) == (heads % 2 == 0)
+    packed = hook.bshd(heads, d)
+    assert (packed is not None) == (heads % 2 == 0 and d == 64)
     assert fa._sub_tile(True, 1024) == 512
-    x = jax.ShapeDtypeStruct((2, s, heads, 64) if packed else
-                             (2, heads, s, 64), jnp.bfloat16, sharding=chip)
+    x = jax.ShapeDtypeStruct((b, s, heads, d) if packed else
+                             (b, heads, s, d), jnp.bfloat16, sharding=chip)
     attend = packed or hook
 
     def loss(q, k, v):
@@ -460,14 +521,16 @@ def test_v5e_compiler_takes_the_causal_walk(heads, s, monkeypatch):
         == 3
 
 
-@pytest.mark.parametrize("heads,window", [(18, 512), (12, None)],
-                         ids=["window-group-9", "full-group-6"])
+@pytest.mark.parametrize("heads,kv,s,window", [
+    (18, 2, 2048, 512), (12, 2, 2048, None), (72, 8, 4096, 512)],
+    ids=["window-group-9", "full-group-6", "laguna-72-over-8"])
 def test_v5e_compiler_takes_grouped_heads_and_the_windows_walk(
-        heads, window, monkeypatch):
+        heads, kv, s, window, monkeypatch):
     """One attention layer of the Laguna cell's kinds at its head width and
     groups (2 key-value heads of 128 for 18 query heads behind a 512-key
-    window, or for 12 with none; a gate; rotary), forward and backward,
-    compiled by libtpu for one detached v5e chip.  Mosaic takes the index
+    window, or for 12 with none; a gate; rotary; then the cell's own 72 over
+    8 at 4,096 positions), forward and backward, compiled by libtpu for one
+    detached v5e chip.  Mosaic takes the index
     maps that pick a key-value head by the grid's index over the group, the
     dk/dv kernel's innermost dimension over a group's query heads, and the
     window's walk with both loop bounds from the device; and keys, values,
@@ -485,7 +548,7 @@ def test_v5e_compiler_takes_grouped_heads_and_the_windows_walk(
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x4")
     chip = SingleDeviceSharding(topo.devices[0])
-    b, s, dim, d, kv = 1, 2048, 512, 128, 2
+    b, dim, d = 1, 512, 128
     hook = fa.make_flash_attn_fn(causal=True)
     rope = L.rope_tables(s, d, 1e4) if window else L.yarn_rope_tables(
         s, d // 2, 5e5, 128.0, 8192, 32.0, 1.0, 1.4852)
@@ -525,7 +588,12 @@ def test_v5e_compiler_takes_256_lanes_at_a_group_of_eight(monkeypatch):
     the three kernels at 256 lanes and blocks of 512 x 1,024 (the dk/dv
     program holds two (1,024 x 256) f32 accumulators over 8 x 16 query
     blocks) inside its scoped VMEM, and keys, values, dk and dv stay 2 heads
-    wide at the kernels."""
+    wide at the kernels.  The bodies are PR 45's: the forward's running
+    maximum and sum lane-replicated beside a 256-lane accumulator, and the
+    dk/dv kernel's scores transposed with the split layout's ``(512, 1)``
+    columns of ``lse`` and ``delta`` turned to rows a step (``_stat_rows``),
+    over the group's 16 x 8 query blocks; ``lse`` leaves the forward and
+    enters both backward kernels as ``f32[16,8192,1]``."""
     why_not = _why_no_detached_topology()
     if why_not:
         pytest.skip(why_not)
@@ -565,8 +633,13 @@ def test_v5e_compiler_takes_256_lanes_at_a_group_of_eight(monkeypatch):
                              line).group(1)
         assert operands.count(narrow) == 2, (name, operands)
         assert operands.count(wide) == (1 if name == "flash_fwd" else 2)
+        # lse and delta, a query head each.
+        assert operands.count(f"f32[{heads},{s},1]") == \
+            (0 if name == "flash_fwd" else 2), (name, operands)
     results = kernels["flash_bwd_dkv"].split(" custom-call(")[0]
     assert results.count(narrow) == 2 and wide not in results
+    assert f"f32[{heads},{s},1]" in \
+        kernels["flash_fwd"].split(" custom-call(")[0]
 
 
 def _mosaic_text(call):
