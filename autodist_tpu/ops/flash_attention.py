@@ -36,6 +36,30 @@ arithmetic, whose ``where`` keeps a masked score's weight exactly zero.  The
 ``flash.causal_subtiles_total`` say what a call's shape and offsets make of
 the walk (``_causal_plan``).
 
+A program that is not run fetches nothing either (``_Sweep``).  The grid's
+innermost dimension runs over the k blocks of a q block (forward, dq) or the
+q blocks of a k block (dk/dv), and what an outer block sees of them follows
+from the offsets, the block lengths and the window (``_reach``, which
+``_visible`` and ``_walk`` read too).  The index map of every operand on the
+inner side (k, v, the shared rotary key; q, ``do``, ``lse``, ``delta`` and
+the rotary query for dk/dv) gives a program the grid's block where it holds a
+seen score and else a neighbour's seen block (before the first, the first;
+past the last, the block the next outer block starts on, which is then
+copied behind the last body that ran), reading the offsets from the
+scalar-prefetch operand, and the pipeline issues no copy where consecutive
+programs map to the same block: an empty program costs a grid step and no
+bytes.  Under a window the grid's
+inner dimension is itself only as long as the most blocks any outer block
+sees, a step's block ``first(outer) + j``.  A body takes its positions from
+that nominal block, never from the clamped one, so where the two differ the
+program is empty: skipped on the chip, masked to exact zeros by the
+interpreter.  The same visible blocks in the same order: results are bit for
+bit those of the whole rectangle.  A call that is not causal builds the grid
+and maps it always built, and so does a causal one whose every program
+holds a seen score (a row of 1,024 keys in one k block), which the maps'
+scalar work would only slow.  Gauges ``flash.inner_blocks_fetched`` /
+``flash.inner_blocks_total``.
+
 Forward emits per-row logsumexp next to the output; backward is the fused
 FlashAttention-2 pair (a dq kernel accumulating over K blocks and a dk/dv
 kernel accumulating over Q blocks) recomputing p = exp(s - lse) blockwise —
@@ -371,7 +395,8 @@ class _Layout:
             return (self.heads, 1, length) if self.packed else (length, 1)
         return length, self.lanes
 
-    def spec(self, n, length, seq, stat=False, width=None, shared=False):
+    def spec(self, n, length, seq, stat=False, width=None, shared=False,
+             sweep=None):
         """BlockSpec of ``n`` units' blocks whose position along the sequence
         is the grid's index number ``seq`` (1 or 2).  ``width`` is an
         operand's own lanes where they are not ``d`` (split layout only,
@@ -381,7 +406,10 @@ class _Layout:
         integer ``g``, one row for ``g`` consecutive units (a key-value
         head's query heads); ``(g, blocks)``, the grid's first index counts
         key-value heads and its index ``seq`` runs over the ``g`` query
-        heads of one, ``blocks`` blocks each (the dk/dv kernel's q side)."""
+        heads of one, ``blocks`` steps each (the dk/dv kernel's q side).
+        ``sweep`` is given for an operand of a causal call's inner side
+        (``seq`` is 2): its position is the block ``_Sweep.place`` maps the
+        step to, by the offsets, which the map receives last."""
         across = self.lane_blocks
 
         def place(*grid):
@@ -390,10 +418,14 @@ class _Layout:
             i, j = grid[0], grid[seq]
             # lax, not ``//`` and ``%``: jnp's take a sign's care that costs
             # a step's lowering seconds over its hundreds of index maps.
+            head = None
             if isinstance(shared, tuple):
                 group, blocks = shared
-                return (i * group + jax.lax.div(j, blocks),
-                        jax.lax.rem(j, blocks), 0)
+                head, j = jax.lax.div(j, blocks), jax.lax.rem(j, blocks)
+            if sweep is not None:
+                j = sweep.place(grid[-1], grid[1], j)
+            if head is not None:
+                return i * group + head, j, 0
             if shared:
                 return jax.lax.div(
                     i, self.num_heads if shared is True else shared), j, 0
@@ -426,7 +458,7 @@ def _heads_per_block(num_heads, d):
 
 
 def _announce(kernel, layout, operand, sk, block_q, block_k, rows, vmem_bytes,
-              offsets=None, group=1, window=None):
+              offsets=None, group=1, window=None, sweep=None):
     """One info line a distinct kernel and shape, and with telemetry on the
     gauges ``flash.rows_per_program`` and ``flash.heads_per_block`` and a
     ``flash`` event: which layout the shape gave this call and which program
@@ -442,9 +474,15 @@ def _announce(kernel, layout, operand, sk, block_q, block_k, rows, vmem_bytes,
     With ``group`` query heads a key-value head the line says so, and under a
     ``window`` it gives the window's own walk beside the causal one's (the
     gauges ``flash.window_subtiles_visited`` / ``_total``; 0 with no
-    window)."""
+    window).  ``sweep`` is a causal call's ``_Sweep``: the line then says how
+    many inner blocks the grid steps through an outer block and, where the
+    offsets are integers, how many of a (batch, head) row's inner blocks its
+    programs run on (``_Sweep.fetched``: the rest are not copied, their
+    programs mapped to a neighbour's block), which the gauges
+    ``flash.inner_blocks_fetched`` / ``flash.inner_blocks_total`` carry; both
+    0 where an offset is traced or the call is not causal."""
     sq = operand.shape[1]
-    total = visited = in_window = 0
+    total = visited = in_window = fetched = inner_total = 0
     walk = "not causal: every score computed"
     if offsets is not None:
         sub = _sub_tile(True, block_k)
@@ -464,11 +502,23 @@ def _announce(kernel, layout, operand, sk, block_q, block_k, rows, vmem_bytes,
             walk += " visited by the offsets on the device"
             if window is not None:
                 walk += f", inside a window of {window} keys"
+    if sweep is not None:
+        side = "q" if sweep.keys_outer else "k"
+        walk += (f"; the grid steps through {sweep.extent} of an outer "
+                 f"block's {sweep.blocks} {side} blocks")
+        if all(isinstance(offset, int) for offset in offsets):
+            fetched, inner_total = sweep.fetched(offsets)
+            walk += (f", {fetched} of a row's {inner_total} {side} blocks "
+                     f"fetched")
+        else:
+            walk += f", the {side} blocks fetched by the offsets on the device"
     if group > 1:
         walk += (f"; {group} query heads read one key-value head, "
                  f"{layout.num_heads // group} key-value heads in HBM")
     programs = (layout.units * layout.heads // rows * layout.lane_blocks
                 * (sq // block_q) * (sk // block_k))
+    if sweep is not None:       # the steps of a narrowed grid
+        programs = programs // sweep.blocks * sweep.extent
     shape = ",".join(str(n) for n in operand.shape)
     stat = layout.shape(sq, stat=True)
     stat_bytes = math.prod(stat[:-1]) * -(-stat[-1] // _LANES) * _LANES * 4
@@ -492,6 +542,8 @@ def _announce(kernel, layout, operand, sk, block_q, block_k, rows, vmem_bytes,
     registry.gauge("flash.window_subtiles_visited").set(in_window)
     registry.gauge("flash.window_subtiles_total").set(
         total if window is not None else 0)
+    registry.gauge("flash.inner_blocks_fetched").set(fetched)
+    registry.gauge("flash.inner_blocks_total").set(inner_total)
     registry.gauge("flash.group").set(group)
     if detail not in _announced:
         _announced.add(detail)
@@ -508,31 +560,181 @@ def _sub_tile(causal, block_k):
     return block_k
 
 
+def _whole(count, unit, most):
+    """Whole units of ``unit`` positions in ``count`` of them, none for a
+    negative count and no more than ``most``.  Python integers give integers
+    (the plan, the gauges), the device's values traced ones (the bodies, the
+    index maps): ``lax.div`` rounds towards zero, so a negative count gives at
+    most 0."""
+    if isinstance(count, int):
+        return min(max(count, 0) // unit, most)
+    return jnp.clip(jax.lax.div(count, unit), 0, most)
+
+
+def _reach(start, length, window=None, keys=False):
+    """``(lo, hi)``: the first and the last position of the other side with
+    which a block of ``length`` positions from ``start`` holds a seen score,
+    None where nothing bounds it.  Position t sees the keys s with ``t -
+    window < s <= t`` (``s <= t`` with no window): a block of queries sees
+    from its first row's window to its last row's diagonal; with ``keys`` the
+    block is of keys, seen from its first key's diagonal to the last row whose
+    window holds its last key.  The ONE definition of what a block sees:
+    ``_visible`` (a program's tile), ``_walk`` (its sub-tiles) and
+    ``_Sweep`` (the grid's inner dimension and its index maps) count blocks
+    against these two positions."""
+    last = start + length - 1
+    if keys:
+        return start, None if window is None else last + (window - 1)
+    return None if window is None else start - (window - 1), last
+
+
+def _least(a, b):
+    return min(a, b) if isinstance(a, int) and isinstance(b, int) \
+        else jnp.minimum(a, b)
+
+
+def _most(a, b):
+    return max(a, b) if isinstance(a, int) and isinstance(b, int) \
+        else jnp.maximum(a, b)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Sweep:
+    """The innermost grid dimension of a causal call: which of the inner
+    side's blocks an outer block's programs step through, and which block
+    each step's inner-side operands are mapped to.  The forward and dq
+    kernels' outer block is q's and the inner runs over k; with
+    ``keys_outer`` (dk/dv) the outer is k's and the inner runs over the q
+    blocks of a query head.  ``outer`` / ``block`` are the positions of an
+    outer / inner block, ``outers`` / ``blocks`` their counts a row.
+
+    An outer block holds a seen score with the inner blocks ``[behind,
+    visited)`` (``seen``).  Its programs' NOMINAL inner blocks are ``first +
+    j`` for the grid's ``j`` in ``[0, extent)``: with no window ``extent`` is
+    the whole row and ``first`` 0, the grid it always was; under a window
+    ``extent`` is the most blocks any outer block sees (exact where the
+    offsets are integers, else what a span of ``outer + window - 1``
+    positions can touch however it lies against the blocks) and ``first``
+    the first seen block, held back so that the last of the ``extent`` is the
+    row's last.  A body's positions come from the nominal block
+    (``nominal``), so a program whose nominal block holds no seen score is
+    skipped as it ever was; an operand's index map gives the nominal block
+    where it is seen and a neighbour's seen block where it is not
+    (``place``), and since the pipeline issues no copy where consecutive
+    programs map to the same block, a skipped program costs a grid step and
+    no bytes.  ``offs`` is the scalar-prefetch ref (or a
+    pair of integers): traced offsets are read where the map runs.
+    ``plain`` says that, at integer offsets, every program of the whole
+    rectangle holds a seen score (a row of 1,024 keys in one k block): the
+    sweep's maps are then the plain ones, and the call builds those."""
+    keys_outer: bool
+    outer: int
+    outers: int
+    block: int
+    blocks: int
+    window: int = None
+    extent: int = None
+    plain: bool = False
+
+    @classmethod
+    def of(cls, keys_outer, sq, sk, block_q, block_k, window, offsets):
+        outer, block = (block_k, block_q) if keys_outer else (block_q, block_k)
+        whole = cls(keys_outer, outer, (sk if keys_outer else sq) // outer,
+                    block, (sq if keys_outer else sk) // block, window)
+        extent, plain = whole.blocks, False
+        if all(isinstance(o, int) for o in offsets):
+            seen = [visited - behind for behind, visited in
+                    (whole.seen(offsets, at) for at in range(whole.outers))]
+            plain = min(seen) == whole.blocks
+            if window is not None:
+                extent = max(1, *seen)
+        elif window is not None:
+            extent = min(extent, 1 + -(-(outer + window - 2) // block))
+        return dataclasses.replace(whole, extent=extent, plain=plain)
+
+    def seen(self, offs, at):
+        """``(behind, visited)`` of outer block ``at``: the inner blocks
+        wholly before the first position it sees, and those that start at or
+        before the last; none is seen where ``visited <= behind``."""
+        mine, other = (1, 0) if self.keys_outer else (0, 1)
+        lo, hi = _reach(offs[mine] + at * self.outer, self.outer, self.window,
+                        self.keys_outer)
+        behind = 0 if lo is None else \
+            _whole(lo - offs[other], self.block, self.blocks)
+        visited = self.blocks if hi is None else \
+            _whole(hi - offs[other] + self.block, self.block, self.blocks)
+        return behind, visited
+
+    def _first(self, behind):
+        if self.extent == self.blocks:
+            return 0
+        return _least(behind, self.blocks - self.extent)
+
+    def nominal(self, offs, at, j):
+        """The inner block whose positions step ``j`` of outer block ``at``
+        computes with."""
+        if self.extent == self.blocks:
+            return j
+        return self._first(self.seen(offs, at)[0]) + j
+
+    def place(self, offs, at, j):
+        """The inner block step ``j``'s operands are mapped to: the nominal
+        one wherever it holds a seen score; before the first that does, the
+        first; past the last, the block the next program that runs will
+        read, which the pipeline then copies behind the last body that ran:
+        the next outer block's first seen block for the forward and dq
+        kernels (the library kernels' form; on the v5e 4-9% off the
+        forward's time at 4,096 and 8,192 keys against resting on the last:
+        PERF.md, PR 46), the outer block's own last for the last outer
+        block and for dk/dv, whose next program is another query head's or
+        another key block's (its empty programs lie before its first seen
+        block, the diagonal's, but for a window's tail)."""
+        behind, visited = self.seen(offs, at)
+        nominal = self._first(behind) + j
+        own = _least(_most(nominal, _least(behind, self.blocks - 1)),
+                     _most(visited - 1, 0))
+        if self.keys_outer:
+            return own
+        ahead = _least(self.seen(offs, _least(at + 1, self.outers - 1))[0],
+                       self.blocks - 1)
+        past = nominal >= visited
+        if isinstance(past, bool):
+            return ahead if past and at + 1 < self.outers else own
+        return jnp.where(jnp.logical_and(past, at + 1 < self.outers), ahead,
+                         own)
+
+    def fetched(self, offsets):
+        """``(the inner blocks a (batch, head) row's programs run on, each
+        outer block's seen blocks counted; the blocks of the whole
+        rectangle)`` at integer offsets: what the gauges
+        ``flash.inner_blocks_fetched`` / ``flash.inner_blocks_total`` carry.
+        No other block is copied (but the one an outer block that sees none
+        rests on), and fewer are where an outer block starts on the block the
+        one before ended on."""
+        return (sum(max(visited - behind, 0) for behind, visited in
+                    (self.seen(offsets, at) for at in range(self.outers))),
+                self.outers * self.blocks)
+
+
 def _walk(q_start, k_start, block_q, block_k, sub, window=None):
     """``(below, visited)`` of a causal program whose score tile has its
     first row at position ``q_start`` and its first key at ``k_start``: of
     its ``block_k / sub`` sub-tiles of keys, in order, the first ``below`` lie
     wholly below the diagonal (every score seen: ``k_start + (j + 1) sub - 1
-    <= q_start``), those up to ``visited`` hold a seen score (``q_start +
-    block_q - 1 >= k_start + j sub``), and the rest are wholly masked.  Python
-    integers give integers (``_causal_plan``), the device's values traced
-    ones: the one rule for what a kernel runs and for what it says it ran.
-    Under a ``window`` (position t sees the keys s with ``t - window < s <=
-    t``) a third count: the first ``behind`` sub-tiles lie wholly behind the
-    window of every row (``k_start + (j + 1) sub - 1 <= q_start - window``)
-    and are not run; a visible block has ``behind < visited``."""
-    def tiles(keys):
-        """Whole sub-tiles in ``keys`` keys, none for a negative count and no
-        more than the block's."""
-        if isinstance(keys, int):
-            return min(max(keys, 0) // sub, block_k // sub)
-        # ``lax.div`` rounds towards zero: a negative count gives at most 0.
-        return jnp.clip(jax.lax.div(keys, sub), 0, block_k // sub)
-    walk = (tiles(q_start - k_start + 1),
-            tiles(q_start + block_q - 1 - k_start + sub))
+    <= q_start``), those up to ``visited`` hold a seen score (they start at or
+    before the last key the rows see, ``_reach``), and the rest are wholly
+    masked.  Python integers give integers (``_causal_plan``), the device's
+    values traced ones: the one rule for what a kernel runs and for what it
+    says it ran.  Under a ``window`` a third count: the first ``behind``
+    sub-tiles lie wholly before the first key any row sees and are not run; a
+    visible block has ``behind < visited``."""
+    n = block_k // sub
+    below = _whole(q_start - k_start + 1, sub, n)
+    lo, hi = _reach(q_start, block_q, window)
+    walk = below, _whole(hi - k_start + sub, sub, n)
     if window is None:
         return walk
-    return walk + (tiles(q_start - window - k_start + 1),)
+    return walk + (_whole(lo - k_start, sub, n),)
 
 
 def _causal_plan(sq, sk, block_q, block_k, sub, q_offset=0, k_offset=0,
@@ -778,10 +980,10 @@ def _scratch(layout, n, shape):
 @functools.partial(jax.jit, inline=True, static_argnames=(
     "name", "body", "layout", "n", "grid_tail", "ins", "outs", "scratch",
     "block_q", "block_k", "sub", "causal", "interpret", "scale", "window",
-    "group"))
+    "group", "sweep"))
 def _kernel_call(offs, *arrays, name, body, layout, n, grid_tail, ins, outs,
                  scratch, block_q, block_k, sub, causal, interpret,
-                 scale=None, window=None, group=1):
+                 scale=None, window=None, group=1, sweep=None):
     """One kernel over ``arrays`` in the kernels' own shapes, ``n`` units a
     program.  ``ins`` give each array's block as ``(length, which of the
     grid's indices places it along the sequence, whether it is a row
@@ -795,7 +997,12 @@ def _kernel_call(offs, *arrays, name, body, layout, n, grid_tail, ins, outs,
     the module's constant, so that this function's cache is keyed by it).
     ``window`` and ``group`` reach a body only where there is a window or
     several query heads a key-value head: a call with neither builds the
-    body it built before they existed.
+    body it built before they existed.  ``sweep`` is a causal call's
+    ``_Sweep``: the operands placed by the grid's last index (the inner
+    side's) take their blocks through it, and under a window, where the grid
+    is narrower than the row, the body takes its positions from it too; a
+    call that is not causal has none and builds the maps it always built, as
+    does one whose every program holds a seen score (``_Sweep.plain``).
 
     An inlined ``jit``: a model's layers make the same call, and every one
     after the first takes the first's equations from the cache, the kernel's
@@ -808,6 +1015,10 @@ def _kernel_call(offs, *arrays, name, body, layout, n, grid_tail, ins, outs,
         form["window"] = window
     if group > 1:
         form["group"] = group
+    if sweep is not None and sweep.plain:   # nothing to clamp or narrow
+        sweep = None
+    if window is not None and sweep is not None:
+        form["sweep"] = sweep
     return pl.pallas_call(
         functools.partial(body, d=layout.d, block_q=block_q, block_k=block_k,
                           sub=sub, causal=causal, skip_blocks=not interpret,
@@ -815,7 +1026,9 @@ def _kernel_call(offs, *arrays, name, body, layout, n, grid_tail, ins, outs,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(layout.units // n * layout.lane_blocks,) + grid_tail,
-            in_specs=[layout.spec(n, *block) for block in ins],
+            in_specs=[layout.spec(n, *block,
+                                  sweep=sweep if block[1] == 2 else None)
+                      for block in ins],
             out_specs=[layout.spec(n, *out[:3], *out[5:]) for out in outs],
             scratch_shapes=[_scratch(layout, n, shape) for shape in scratch],
         ),
@@ -948,32 +1161,35 @@ def _visible(causal, skip_blocks, q_start, k_start, block_q, block_k, window):
     interpreter's state discharge loses multi-scratch writes under a skipped
     runtime-conditional); the p-masking keeps skipped-block contributions
     exactly zero either way."""
-    visible = jnp.logical_or(not (causal and skip_blocks),
-                             q_start + block_q - 1 >= k_start)
+    lo, hi = _reach(q_start, block_q, window)
+    visible = jnp.logical_or(not (causal and skip_blocks), hi >= k_start)
     if window is None or not skip_blocks:
         return visible
-    return jnp.logical_and(visible,
-                           k_start + block_k - 1 > q_start - window)
+    return jnp.logical_and(visible, k_start + block_k - 1 >= lo)
 
 
 def _fwd_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
-                skip_blocks, scale=None, window=None, group=1):
+                skip_blocks, scale=None, window=None, group=1, sweep=None):
     """Grid (units / rows a program x lane blocks, q-blocks, k-blocks): k
     innermost, accumulators in VMEM scratch carried across the k dimension,
     each of a program's rows with its own; ``d`` lanes a head; ``sub`` keys a
     step of the walk over the k block (``_for_keys``).  With ``group`` query
     heads a key-value head the body is the same: the k and v blocks' index
-    maps pick the head (``_Layout.spec``)."""
+    maps pick the head (``_Layout.spec``).  Under a ``window`` the grid's
+    last dimension is the ``sweep``'s extent and a step's k block the one it
+    names (``_Sweep.nominal``)."""
     (q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l), qr_ref, kr_ref = \
         _two_product(refs, 3, scale)
     rows = q_ref.shape[0]
     iq = pl.program_id(1)
-    ik = pl.program_id(2)
+    step = ik = pl.program_id(2)
     num_kb = pl.num_programs(2)
     if scale is None:
         scale = 1.0 / math.sqrt(d)
+    if sweep is not None:
+        ik = sweep.nominal(offs_ref, iq, step)
 
-    @pl.when(ik == 0)
+    @pl.when(step == 0)
     def _init():
         acc[:] = jnp.zeros_like(acc)
         m[:] = jnp.full_like(m, _NEG_INF)
@@ -1023,7 +1239,7 @@ def _fwd_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
         _for_keys(skip_blocks, q_start, k_start, block_q, block_k, sub, _run,
                   window)
 
-    @pl.when(ik == num_kb - 1)
+    @pl.when(step == num_kb - 1)
     def _finalize():
         every = _all_rows(acc)
         # 1e-30, NOT 1e-38: f32 subnormals flush to zero on TPU (and in the
@@ -1068,6 +1284,23 @@ def _grouping(q, k, packed, causal, window):
     return group
 
 
+def _grid(causal, keys_outer, sq, sk, block_q, block_k, window, offsets,
+          group=1):
+    """``(sweep, grid_tail)`` of a kernel call: a causal call's ``_Sweep``,
+    none where the call is not causal, whose grid and index maps are then
+    what they always were; and the grid behind its first dimension, the
+    outer blocks (k's with ``keys_outer``, the dk/dv kernel's) and then the
+    inner ones an outer block's programs step through, for each of a
+    key-value head's ``group`` query heads."""
+    outers, inners = sq // block_q, sk // block_k
+    if keys_outer:
+        outers, inners = inners, outers
+    sweep = _Sweep.of(keys_outer, sq, sk, block_q, block_k, window,
+                      offsets) if causal else None
+    return sweep, (outers,
+                   group * (inners if sweep is None else sweep.extent))
+
+
 def _kv_block(block_k, at, group):
     """``_kernel_call``'s entry of a k or v block that follows the grid's
     index ``at``: a unit's own, or one for the ``group`` query heads of its
@@ -1106,19 +1339,21 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, k_offset,
         [(q_block, q.dtype), (k_block, k.dtype), (k_block, v.dtype),
          (q_block, out_dtype), (row_block, f32)],
         [(shape, f32) for shape in scratch], layout.heads)
+    sweep, grid_tail = _grid(causal, False, sq, sk, block_q, block_k, window,
+                             (q_offset, k_offset))
     _announce("flash_fwd", layout, qr, sk, block_q, block_k, g, vmem,
-              (q_offset, k_offset) if causal else None, group, window)
+              (q_offset, k_offset) if causal else None, group, window, sweep)
     # q's blocks follow the grid's second index, k's and v's its third.
     out, lse = _kernel_call(
         offs, qr, kr, vr, name="flash_fwd", body=_fwd_kernel, layout=layout,
-        n=g // layout.heads, grid_tail=(sq // block_q, sk // block_k),
+        n=g // layout.heads, grid_tail=grid_tail,
         ins=((block_q, 1, False), _kv_block(block_k, 2, group),
              _kv_block(block_k, 2, group)),
         outs=((block_q, 1, False, out_dtype, sq),
               (block_q, 1, True, f32, sq)),
         scratch=scratch, block_q=block_q, block_k=block_k,
         sub=_sub_tile(causal, block_k), causal=causal, interpret=interpret,
-        window=window, group=group)
+        window=window, group=group, sweep=sweep)
     return layout.result(out), layout.result(lse, stat=True)
 
 
@@ -1127,7 +1362,8 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, q_offset, k_offset,
 
 
 def _bwd_dq_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
-                   skip_blocks, scale=None, window=None, group=1):
+                   skip_blocks, scale=None, window=None, group=1,
+                   sweep=None):
     refs, qr_ref, kr_ref = _two_product(refs, 6, scale)
     if qr_ref is None:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -1137,12 +1373,14 @@ def _bwd_dq_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
          dq_acc, dqr_acc) = refs
     rows = q_ref.shape[0]
     iq = pl.program_id(1)
-    ik = pl.program_id(2)
+    step = ik = pl.program_id(2)
     num_kb = pl.num_programs(2)
     if scale is None:
         scale = 1.0 / math.sqrt(d)
+    if sweep is not None:
+        ik = sweep.nominal(offs_ref, iq, step)
 
-    @pl.when(ik == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
         if qr_ref is not None:
@@ -1186,7 +1424,7 @@ def _bwd_dq_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
         _for_keys(skip_blocks, q_start, k_start, block_q, block_k, sub, _run,
                   window)
 
-    @pl.when(ik == num_kb - 1)
+    @pl.when(step == num_kb - 1)
     def _finalize():
         dq_ref[_all_rows(dq_acc)] = dq_acc[:].astype(dq_ref.dtype)
         if qr_ref is not None:
@@ -1194,7 +1432,8 @@ def _bwd_dq_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
 
 
 def _bwd_dkv_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
-                    skip_blocks, scale=None, window=None, group=1):
+                    skip_blocks, scale=None, window=None, group=1,
+                    sweep=None):
     """Grid (units, k-blocks, q-blocks): q innermost, the dk and dv
     accumulators carried across it.  The scores are computed transposed,
     keys down the sublanes and queries along the lanes (``s^T = k . q^T``,
@@ -1205,7 +1444,9 @@ def _bwd_dkv_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
     head the grid's first index counts key-value heads and its innermost
     runs over the q blocks of the group's query heads one after another
     (``_Layout.spec``'s ``(group, blocks)``), so one head's dk and dv are
-    summed over its query heads where they are accumulated."""
+    summed over its query heads where they are accumulated.  Under a
+    ``window`` a query head's steps are the ``sweep``'s extent, from the
+    first q block that sees the k block's keys (``_Sweep.nominal``)."""
     refs, qr_ref, kr_ref = _two_product(refs, 6, scale)
     if qr_ref is None:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
@@ -1221,6 +1462,8 @@ def _bwd_dkv_kernel(offs_ref, *refs, d, block_q, block_k, sub, causal,
         scale = 1.0 / math.sqrt(d)
     if group > 1:       # the q block within its query head
         iq = jax.lax.rem(step, num_qb // group)
+    if sweep is not None:
+        iq = sweep.nominal(offs_ref, ik, iq)
 
     @pl.when(step == 0)
     def _init():
@@ -1303,28 +1546,33 @@ def _flash_bwd(q, k, v, do, lse, delta, causal, block_q, block_k, q_offset,
     in_blocks = [(q_block, q.dtype), (k_block, k.dtype), (k_block, v.dtype),
                  (q_block, do.dtype), (row_block, f32), (row_block, f32)]
 
-    def call(name, body, grid_tail, at_q, at_k, out_block, out_len, n_out,
-             of=layout, fan=None):
+    def call(name, body, at_q, at_k, out_block, out_len, n_out, of=layout,
+             fan=1):
         """One backward kernel: ``n_out`` results of ``out_block`` a unit at
         the grid's second index, each with its f32 accumulator in scratch;
-        q's blocks follow the grid's index ``at_q``, k's ``at_k``.  ``of``
-        is the layout whose units the grid's first index counts; with
-        ``fan = (group, q blocks)`` those are key-value heads and the q side
-        runs over a head's query heads."""
+        q's blocks follow the grid's index ``at_q``, k's ``at_k``, and the
+        side at index 2 is the inner one, a causal call's stepped through by
+        its ``_Sweep``.  ``of`` is the layout whose units the grid's first
+        index counts; with ``fan`` above 1 those are key-value heads and the
+        q side runs over a head's ``fan`` query heads."""
+        sweep, grid_tail = _grid(causal, at_k == 1, sq, sk, block_q, block_k,
+                                 window, (q_offset, k_offset), fan)
         g, vmem = _rows_per_program(
             of.units if group == 1 else 1, block_q, block_k,
             in_blocks + [(out_block, out_dtype)] * n_out,
             [(out_block, f32)] * n_out, of.heads)
         _announce(name, layout, qr, sk, block_q, block_k, g, vmem,
-                  (q_offset, k_offset) if causal else None, group, window)
-        if fan is None:
+                  (q_offset, k_offset) if causal else None, group, window,
+                  sweep)
+        if fan == 1:
             at_k = _kv_block(block_k, at_k, group)
             at_q = (block_q, at_q, False)
             stat = at_q[:2] + (True,)
         else:
+            heads = (fan, grid_tail[1] // fan)
             at_k = (block_k, at_k, False)
-            at_q = (block_q, at_q, False, None, fan)
-            stat = (block_q, at_q[1], True, None, fan)
+            at_q = (block_q, at_q, False, None, heads)
+            stat = (block_q, at_q[1], True, None, heads)
         return _kernel_call(
             offs, qr, kr, vr, dor, lser, deltar, name=name, body=body,
             layout=of, n=g // of.heads, grid_tail=grid_tail,
@@ -1332,20 +1580,17 @@ def _flash_bwd(q, k, v, do, lse, delta, causal, block_q, block_k, q_offset,
             outs=((out_block[0], 1, False, out_dtype, out_len),) * n_out,
             scratch=(out_block,) * n_out, block_q=block_q, block_k=block_k,
             sub=_sub_tile(causal, block_k), causal=causal,
-            interpret=interpret, window=window, group=group)
+            interpret=interpret, window=window, group=group, sweep=sweep)
 
     # dq: q blocks outside, accumulated over the k blocks inside; dk and dv:
     # k blocks outside, accumulated over the q blocks inside.
-    dq, = call("flash_bwd_dq", _bwd_dq_kernel,
-               (sq // block_q, sk // block_k), 1, 2, q_block, sq, 1)
+    dq, = call("flash_bwd_dq", _bwd_dq_kernel, 1, 2, q_block, sq, 1)
     if group == 1:
-        dk, dv = call("flash_bwd_dkv", _bwd_dkv_kernel,
-                      (sk // block_k, sq // block_q), 2, 1, k_block, sk, 2)
+        dk, dv = call("flash_bwd_dkv", _bwd_dkv_kernel, 2, 1, k_block, sk, 2)
         return layout.result(dq), layout.result(dk), layout.result(dv)
     kv = _Layout.of(k, packed)
-    dk, dv = call("flash_bwd_dkv", _bwd_dkv_kernel,
-                  (sk // block_k, group * (sq // block_q)), 2, 1, k_block,
-                  sk, 2, of=kv, fan=(group, sq // block_q))
+    dk, dv = call("flash_bwd_dkv", _bwd_dkv_kernel, 2, 1, k_block, sk, 2,
+                  of=kv, fan=group)
     return layout.result(dq), kv.result(dk), kv.result(dv)
 
 
@@ -1395,7 +1640,7 @@ def _two_product_plan(q, q_rope, k, k_rope, v, block_q, block_k):
 
 
 def _announce_two_product(kernel, layout, q, sk, blocks, widths, in_blocks,
-                          out_blocks, causal, scratch=None):
+                          out_blocks, causal, scratch=None, sweep=None):
     """``scratch`` are the f32 scratch shapes where they are not the
     results' blocks (the forward's running maximum and sum)."""
     r, dv = widths
@@ -1406,7 +1651,7 @@ def _announce_two_product(kernel, layout, q, sk, blocks, widths, in_blocks,
     _announce(f"{kernel} two-product ({layout.d} + {r} lanes a score, one "
               f"{r}-lane key a position shared by {layout.num_heads} heads, "
               f"values of {dv})", layout, q, sk, *blocks, 1, vmem,
-              (0, 0) if causal else None)
+              (0, 0) if causal else None, sweep=sweep)
 
 
 def _flash_fwd2(q, q_rope, k, k_rope, v, scale, causal, block_q, block_k,
@@ -1420,21 +1665,23 @@ def _flash_fwd2(q, q_rope, k, k_rope, v, scale, causal, block_q, block_k,
     main, rope = ins(1, 2)
     o_block, row_block = (block_q, dv), layout.block(block_q, stat=True)
     scratch = (o_block, (block_q, _LANES), (block_q, _LANES))
+    sweep, grid_tail = _grid(causal, False, sq, sk, block_q, block_k, None,
+                             (0, 0))
     _announce_two_product(
         "flash_fwd", layout, qr, sk, blocks, (r, dv),
         [((block_q, layout.d), q.dtype), ((block_k, layout.d), k.dtype),
          ((block_k, dv), v.dtype), ((block_q, r), q.dtype),
          ((block_k, r), k_rope.dtype)],
-        [(o_block, q.dtype), (row_block, f32)], causal, scratch)
+        [(o_block, q.dtype), (row_block, f32)], causal, scratch, sweep)
     out, lse = _kernel_call(
         jnp.zeros((2,), jnp.int32), qr, kr, vr, qrr, krr, name="flash_fwd",
         body=_fwd_kernel, layout=layout, n=1,
-        grid_tail=(sq // block_q, sk // block_k), ins=main + rope,
+        grid_tail=grid_tail, ins=main + rope,
         outs=((block_q, 1, False, jnp.dtype(q.dtype), sq, dv),
               (block_q, 1, True, f32, sq)),
         scratch=scratch, block_q=block_q,
         block_k=block_k, sub=_sub_tile(causal, block_k), causal=causal,
-        interpret=interpret, scale=scale)
+        interpret=interpret, scale=scale, sweep=sweep)
     return (out.reshape(q.shape[:3] + (dv,)),
             layout.result(lse, stat=True))
 
@@ -1454,19 +1701,22 @@ def _flash_bwd2(q, q_rope, k, k_rope, v, do, lse, delta, scale, causal,
     dtype = jnp.dtype(q.dtype)
     row_block = layout.block(block_q, stat=True)
 
-    def call(name, body, grid_tail, at_q, at_k, outs):
+    def call(name, body, at_q, at_k, outs):
         """One backward kernel; ``outs`` are ``(length, whole length,
-        lanes)`` of each result, all at the grid's second index."""
+        lanes)`` of each result, all at the grid's second index, the side at
+        index 2 the inner one."""
         main, rope = ins(at_q, at_k)
         stat = (block_q, at_q, True)
         out_blocks = [((length, lanes), dtype) for length, _, lanes in outs]
+        sweep, grid_tail = _grid(causal, at_k == 1, sq, sk, block_q, block_k,
+                                 None, (0, 0))
         _announce_two_product(
             name, layout, qr, sk, blocks, (r, dv),
             [((block_q, layout.d), dtype), ((block_k, layout.d), dtype),
              ((block_k, dv), dtype), ((block_q, dv), dtype),
              (row_block, f32), (row_block, f32),
              ((block_q, r), dtype), ((block_k, r), dtype)], out_blocks,
-            causal)
+            causal, sweep=sweep)
         return _kernel_call(
             jnp.zeros((2,), jnp.int32), qr, kr, vr, dor, lser, deltar, qrr,
             krr, name=name, body=body, layout=layout, n=1,
@@ -1476,13 +1726,11 @@ def _flash_bwd2(q, q_rope, k, k_rope, v, do, lse, delta, scale, causal,
                        for length, whole, lanes in outs),
             scratch=tuple(shape for shape, _ in out_blocks), block_q=block_q,
             block_k=block_k, sub=_sub_tile(causal, block_k), causal=causal,
-            interpret=interpret, scale=scale)
+            interpret=interpret, scale=scale, sweep=sweep)
 
-    dq, dq_rope = call("flash_bwd_dq", _bwd_dq_kernel,
-                       (sq // block_q, sk // block_k), 1, 2,
+    dq, dq_rope = call("flash_bwd_dq", _bwd_dq_kernel, 1, 2,
                        ((block_q, sq, layout.d), (block_q, sq, r)))
-    dk, dv_, dk_rope = call("flash_bwd_dkv", _bwd_dkv_kernel,
-                            (sk // block_k, sq // block_q), 2, 1,
+    dk, dv_, dk_rope = call("flash_bwd_dkv", _bwd_dkv_kernel, 2, 1,
                             ((block_k, sk, layout.d), (block_k, sk, dv),
                              (block_k, sk, r)))
     b, h = layout.batch, layout.num_heads

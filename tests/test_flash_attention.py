@@ -979,3 +979,323 @@ def test_no_statistic_is_a_column_of_one_lane(form, kernel):
         assert array.shape[-1] == 1024 and ref.shape[-2:] == (1, 512)
         true_bytes = 4 * np.prod(ref.shape)
         assert fa._padded_bytes(ref.shape, jnp.float32) <= 8 * true_bytes
+
+
+# ---------------------------------------------------------------------------
+# the inner sweep: a causal call's innermost grid dimension addresses only
+# blocks its outer block can see
+
+
+# A row's positions in the ten cells' kernel calls (blocks 512 x 1,024 capped
+# by the row), in BENCHMARK.json's order.
+_SWEEP_ROWS = {
+    "gpt2-medium": 1024, "bert-s512": 512, "bert-s128": 128, "gpt2-xl": 1024,
+    "olmoe": 4096, "olmo-hybrid": 4096, "joyai": 4096, "laguna": 4096,
+    "qwen3-next": 8192, "ouro": 2048}
+_SWEEP_OFFSETS = {"diagonal": lambda bq, bk, s: (0, 0),
+                  "q-a-block-on": lambda bq, bk, s: (bq, 0),
+                  "k-a-block-on": lambda bq, bk, s: (0, bk),
+                  "k-ahead-of-q": lambda bq, bk, s: (0, s)}
+
+
+def _pairs_seen(sq, sk, block_q, block_k, q_offset, k_offset, window):
+    """``[q block][k block]``: whether the pair's tile holds a seen score, a
+    query position at a time from the mask's own words (position t sees the
+    keys s with ``t - window < s <= t``)."""
+    t = q_offset + np.arange(sq)
+    first_key = t - window + 1 if window else np.full_like(t, -2 ** 40)
+    starts = k_offset + np.arange(0, sk, block_k)
+    holds = (first_key[:, None] <= starts[None, :] + block_k - 1) \
+        & (t[:, None] >= starts[None, :])
+    return holds.reshape(sq // block_q, block_q, -1).any(1)
+
+
+@pytest.mark.parametrize("offsets", list(_SWEEP_OFFSETS))
+@pytest.mark.parametrize("window", [None, 16, 512, 10000],
+                         ids=["no-window", "window-16", "window-512",
+                              "window-longer-than-the-row"])
+@pytest.mark.parametrize("cell", list(_SWEEP_ROWS))
+def test_the_sweep_addresses_what_an_outer_block_sees(cell, window, offsets):
+    """``_Sweep`` as a pure function of integers, against brute force, for
+    the q-outer kernels (forward, dq) and the k-outer one (dk/dv): the seen
+    blocks of every outer block are ``[behind, visited)``; the grid's extent
+    covers them (the whole row with no window, fewer under one); for every
+    program the block its operands are mapped to is the nominal one wherever
+    ``_visible`` says the tile holds a seen score, and always a block of the
+    row; an empty program is mapped to a seen block of its own outer block
+    or, past the last of them in the forward and dq kernels, to the block the
+    next outer block starts on; ``fetched`` counts the seen blocks, and the
+    blocks a row's programs are mapped to change no more often than that
+    along the row (the copies the pipeline issues an operand)."""
+    s = _SWEEP_ROWS[cell]
+    block_q, block_k = min(s, 512), min(s, 1024)
+    offs = _SWEEP_OFFSETS[offsets](block_q, block_k, s)
+    pairs = _pairs_seen(s, s, block_q, block_k, *offs, window)
+    for keys_outer in (False, True):
+        sweep = fa._Sweep.of(keys_outer, s, s, block_q, block_k, window, offs)
+        want = pairs.T if keys_outer else pairs
+        assert (sweep.outers, sweep.blocks) == want.shape
+        assert 1 <= sweep.extent <= sweep.blocks
+        assert window is not None or sweep.extent == sweep.blocks
+        changes, before, rests = 0, None, 0
+        for at in range(sweep.outers):
+            behind, visited = sweep.seen(offs, at)
+            seen = [b for b in range(sweep.blocks) if want[at, b]]
+            assert seen == list(range(behind, max(visited, behind))), \
+                (keys_outer, at, behind, visited, seen)
+            assert len(seen) <= sweep.extent
+            mapped = [sweep.place(offs, at, j) for j in range(sweep.extent)]
+            nominal = [sweep.nominal(offs, at, j)
+                       for j in range(sweep.extent)]
+            assert nominal == list(range(nominal[0],
+                                         nominal[0] + sweep.extent))
+            assert 0 <= nominal[0] and nominal[-1] < sweep.blocks
+            assert set(seen) <= set(nominal), (keys_outer, at, seen, nominal)
+            for block, at_block in zip(nominal, mapped):
+                iq, ik = (block, at) if keys_outer else (at, block)
+                visible = bool(fa._visible(
+                    True, True, offs[0] + iq * block_q, offs[1] + ik * block_k,
+                    block_q, block_k, window))
+                assert visible == bool(want[at, block])
+                assert 0 <= at_block < sweep.blocks
+                ahead = at + 1 < sweep.outers and not keys_outer \
+                    and block >= visited
+                if visible:
+                    assert at_block == block
+                elif ahead:         # what the next outer block starts on
+                    assert at_block == min(sweep.seen(offs, at + 1)[0],
+                                           sweep.blocks - 1)
+                elif seen:          # an empty program rests on a seen block
+                    assert at_block in seen
+            rests += not seen
+            for at_block in mapped:
+                changes += at_block != before
+                before = at_block
+        assert sweep.fetched(offs) == (int(want.sum()),
+                                       sweep.outers * sweep.blocks)
+        assert changes <= int(want.sum()) + rests, (keys_outer, changes)
+
+
+@pytest.mark.parametrize("cell,window,fwd,dkv", [
+    ("laguna", 512, (2, 11, 32), (3, 11, 32)),
+    ("laguna", None, (4, 20, 32), (8, 20, 32)),
+    ("qwen3-next", None, (8, 72, 128), (16, 72, 128)),
+    ("ouro", None, (2, 6, 8), (4, 6, 8)),
+    ("gpt2-medium", None, (1, 2, 2), (2, 2, 2)),
+    ("bert-s512", None, (1, 1, 1), (1, 1, 1))],
+    ids=["laguna-sliding", "laguna-full", "qwen3-next", "ouro", "gpt2-medium",
+         "s512-causal"])
+def test_the_sweep_at_the_cells_shapes(cell, window, fwd, dkv, monkeypatch):
+    """``(extent, fetched, total)`` a (batch, head) row: under Laguna's
+    window of 512 the forward's grid steps through 2 of a q block's 4 k
+    blocks and the dk/dv kernel's through 3 of a k block's 8 q blocks, and
+    11 of a row's 32 blocks are addressed; a causal row of 4,096 addresses
+    20 of 32, of 8,192 72 of 128, of 2,048 6 of 8, of 1,024 both its two.
+    The gauges and the ``flash`` event's line carry them; a call that is not
+    causal, or whose offsets are traced, reads 0 of 0."""
+    from autodist_tpu import observability
+    from autodist_tpu.observability import recorder
+    s = _SWEEP_ROWS[cell]
+    block_q, block_k = min(s, 512), min(s, 1024)
+    for keys_outer, want in ((False, fwd), (True, dkv)):
+        sweep = fa._Sweep.of(keys_outer, s, s, block_q, block_k, window,
+                             (0, 0))
+        assert (sweep.extent,) + sweep.fetched((0, 0)) == want
+    layout = fa._Layout(False, 1, 1, 64)
+    operand = jax.ShapeDtypeStruct((1, s, 64), jnp.bfloat16)
+
+    def announced(offsets, sweep):
+        observability.reset()
+        monkeypatch.setattr(fa, "_logged_paths", set())
+        monkeypatch.setattr(fa, "_announced", set())
+        fa._announce("flash_fwd", layout, operand, s, block_q, block_k, 1, 0,
+                     offsets, 1, window, sweep)
+        (detail,) = [e["detail"] for e in recorder.events()
+                     if e["kind"] == "flash"]
+        gauges = observability.registry().snapshot()["gauges"]
+        return detail, (gauges["flash.inner_blocks_fetched"],
+                        gauges["flash.inner_blocks_total"])
+    detail, gauges = announced((0, 0), sweep := fa._Sweep.of(
+        False, s, s, block_q, block_k, window, (0, 0)))
+    assert gauges == fwd[1:]
+    assert (f"; the grid steps through {fwd[0]} of an outer block's "
+            f"{s // block_k} k blocks, {fwd[1]} of a row's {fwd[2]} k blocks "
+            f"fetched") in detail, detail
+    assert f" {s // block_q * fwd[0]} programs a call" in detail
+    detail, gauges = announced((jnp.int32(0), 0), sweep)
+    assert gauges == (0, 0)
+    assert "the k blocks fetched by the offsets on the device" in detail
+    detail, gauges = announced(None, None)
+    assert gauges == (0, 0) and "the grid steps" not in detail
+
+
+def test_traced_offsets_take_the_extent_any_alignment_needs():
+    """Where an offset is traced the windowed grid's extent is what a span
+    of ``outer + window - 1`` positions can touch however it lies against
+    the inner blocks (never less than the integers' exact count), and the
+    maps read the offsets where they run: on traced values ``place`` and
+    ``nominal`` give what the integers give."""
+    for keys_outer, exact in ((False, 2), (True, 3)):
+        traced = fa._Sweep.of(keys_outer, 4096, 4096, 512, 1024, 512,
+                              (jnp.int32(0), 0))
+        assert traced.extent == (2 if not keys_outer else 4)
+        assert fa._Sweep.of(keys_outer, 4096, 4096, 512, 1024, 512,
+                            (0, 0)).extent == exact
+    for offs in ((0, 0), (512, 0), (0, 1024), (96, 40), (0, 4096)):
+        for keys_outer in (False, True):
+            sweep = fa._Sweep.of(keys_outer, 4096, 4096, 512, 1024, 512,
+                                 (jnp.int32(0), 0))
+            at, j = np.meshgrid(np.arange(sweep.outers),
+                                np.arange(sweep.extent), indexing="ij")
+            got = jax.jit(jax.vmap(lambda o, a, b: (
+                sweep.place(o, a, b), sweep.nominal(o, a, b)),
+                in_axes=(None, 0, 0)))(
+                    jnp.asarray(offs, jnp.int32), at.ravel(), j.ravel())
+            want = [(sweep.place(offs, int(a), int(b)),
+                     sweep.nominal(offs, int(a), int(b)))
+                    for a, b in zip(at.ravel(), j.ravel())]
+            assert [tuple(int(x) for x in pair) for pair in zip(*got)] == want
+            pairs = _pairs_seen(4096, 4096, 512, 1024, *offs, 512)
+            pairs = pairs.T if keys_outer else pairs
+            for a in range(sweep.outers):   # every seen block has a program
+                behind, visited = sweep.seen(offs, a)
+                nominal = {sweep.nominal(offs, a, b)
+                           for b in range(sweep.extent)}
+                assert set(np.flatnonzero(pairs[a])) <= nominal
+
+
+@pytest.mark.parametrize("group", [1, 6, 9])
+def test_the_fanned_map_places_a_query_heads_blocks(group):
+    """The dk/dv kernel's q side where the grid fans a key-value head over
+    its ``group`` query heads: step ``head x extent + j`` of k block ``at``
+    reads query head ``kv head x group + head`` at the block the sweep maps
+    ``j`` to, inside that head's run of q blocks."""
+    s, block_q, block_k, window, kv_heads = 4096, 512, 1024, 512, 2
+    sweep = fa._Sweep.of(True, s, s, block_q, block_k, window, (0, 0))
+    layout = fa._Layout(False, 1, kv_heads, 128)
+    spec = layout.spec(1, block_q, 2, False, None, (group, sweep.extent),
+                       sweep=sweep)
+    plain = layout.spec(1, block_q, 2, False, None, (group, s // block_q))
+    offs = jnp.zeros((2,), jnp.int32)
+    for i in range(kv_heads):
+        for at in range(sweep.outers):
+            for step in range(group * sweep.extent):
+                head, j = divmod(step, sweep.extent)
+                row, block, lane = (int(x) for x in
+                                    spec.index_map(i, at, step, offs))
+                assert (row, block, lane) == (
+                    i * group + head, sweep.place((0, 0), at, j), 0)
+    # Without a sweep (a call that is not causal) the map is the plain one.
+    assert [int(x) for x in plain.index_map(1, 2, 8 * (group - 1) + 5, offs)] \
+        == [group + group - 1, 5, 0]
+
+
+def _on_the_whole_rectangle(monkeypatch):
+    """The kernels as the parent (PR 45) built them: every call on the whole
+    rectangle of blocks with the plain index maps.  A call with no
+    ``_Sweep`` builds just that (the bodies read one only under a window,
+    and without one take the grid's own index for a step's block), so the
+    parent's values are computed beside the tree's in one process; the
+    builder's chip run compared the parent's module itself (PERF.md, PR
+    46)."""
+    grid = fa._grid
+    monkeypatch.setattr(fa, "_grid",
+                        lambda causal, *a, **k: grid(False, *a, **k))
+    return fa
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("packed,rows", [(False, 1), (False, 3), (True, 2)],
+                         ids=["split-one-row", "split-three-rows", "packed"])
+@pytest.mark.parametrize("blocks", [(16, 16), (16, 32), (32, 16)],
+                         ids=["16x16", "16x32", "32x16"])
+def test_the_clamped_maps_change_no_bit(blocks, packed, rows, dtype,
+                                        monkeypatch):
+    """A causal call with empty programs (a row of 64 in 4 x 4, 4 x 2 and 2
+    x 4 blocks: 6, 2 and 2 of its programs a kernel see no score): o, lse,
+    dq, dk, dv with the inner blocks clamped to what the outer block sees
+    are, bit for bit, those of the whole rectangle with the plain maps.  The
+    interpreter runs every program through the masked arithmetic, so an
+    empty program really computes on the block it was mapped to, and its
+    positions, which come from the nominal block, mask every score of it to
+    an exact zero."""
+    d = 64 if packed else 16
+    q, k, v, do = (jax.random.normal(key, (3, 2, 64, d)).astype(dtype)
+                   for key in jax.random.split(jax.random.PRNGKey(11), 4))
+    if packed:
+        q, k, v, do = (_swap(x) for x in (q, k, v, do))
+
+    def run(fa):
+        def both(q, k, v, do):
+            o, lse = fa._flash_fwd(q, k, v, True, *blocks, 0, 0, True,
+                                   packed=packed)
+            delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)
+            delta = (delta.transpose(0, 2, 1) if packed else delta)[..., None]
+            return (o, lse) + tuple(fa._flash_bwd(
+                q, k, v, do, lse, delta, True, *blocks, 0, 0, True,
+                packed=packed))
+        return jax.jit(both).lower(q, k, v, do).compile(
+            compiler_options={"xla_backend_optimization_level": 0})(
+                q, k, v, do)
+    _force_rows(monkeypatch, rows)
+    got = run(fa)
+    want = run(_on_the_whole_rectangle(monkeypatch))
+    for name, a, b in zip(("o", "lse", "dq", "dk", "dv"), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32), err_msg=name)
+    assert np.isfinite(np.asarray(got[0], np.float32)).all()
+
+
+def _maps(jaxpr):
+    """``{kernel: (grid, hash of its operands' and results' index maps)}``
+    of a jaxpr's three kernels."""
+    import hashlib
+    found = {}
+    for name, eqn in _kernels(jaxpr).items():
+        mapping = eqn.params["grid_mapping"]
+        text = "".join(str(block.index_map_jaxpr)
+                       for block in mapping.block_mappings)
+        found[name] = (tuple(mapping.grid),
+                       hashlib.sha1(text.encode()).hexdigest()[:16])
+    return found
+
+
+# The grids and index maps of the parent (e9a4239, PR 45) at the cells whose
+# calls have no empty program, hashed from its module: a call that is not
+# causal (both BERT cells) builds what it built, and so does a causal one
+# whose every program holds a seen score (1,024 keys are one k block:
+# ``_Sweep.plain``).
+_PLAIN_MAPS = {
+    "gpt2-medium.train-s1024": {
+        "flash_fwd": ((64, 2, 1), "80d880011ff37ec2"),
+        "flash_bwd_dq": ((64, 2, 1), "9480987d4e003165"),
+        "flash_bwd_dkv": ((64, 1, 2), "dee9f29f9432cdd4")},
+    "gpt2-xl.train-s1024-x4": {
+        "flash_fwd": ((50, 2, 1), "b4b97249210e5ae0"),
+        "flash_bwd_dq": ((50, 2, 1), "3c11561d75611447"),
+        "flash_bwd_dkv": ((50, 1, 2), "c003ec10857a6b82")},
+    "bert-base.mlm-s512": {"flash_fwd": ((384, 1, 1), "931099718a67d2ad"),
+                           "flash_bwd_dq": ((384, 1, 1), "c6d1ad6dcb717a87"),
+                           "flash_bwd_dkv": ((384, 1, 1), "c58802ab5fb7cc12")},
+    "bert-base.mlm-s128": {"flash_fwd": ((96, 1, 1), "931099718a67d2ad"),
+                           "flash_bwd_dq": ((96, 1, 1), "c6d1ad6dcb717a87"),
+                           "flash_bwd_dkv": ((96, 1, 1), "c58802ab5fb7cc12")},
+}
+
+
+@pytest.mark.parametrize("cell", list(_PLAIN_MAPS))
+def test_a_call_with_no_empty_program_builds_the_maps_it_built(cell):
+    (b, h, s, d), _, _ = _CELL_SHAPES[cell]
+    resolve = fa._pallas_interpret
+    fa._pallas_interpret = lambda *_: False
+    try:
+        hook = fa.make_flash_attn_fn("bert" not in cell)
+        x = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda q, k, v: (_attend(hook, q, k, v).astype(jnp.float32)
+                             ** 2).sum(), argnums=(0, 1, 2)))(x, x, x)
+    finally:
+        fa._pallas_interpret = resolve
+    assert _maps(jaxpr.jaxpr) == _PLAIN_MAPS[cell]

@@ -181,3 +181,53 @@ def test_causal_plan_at_the_joyai_cells_shape():
     assert fa._sub_tile(True, 1024) == 512
     assert fa._causal_plan(4096, 4096, 512, 1024, 512) == (64, 36, 12)
     assert fa._causal_plan(4096, 4096, 512, 1024, 1024) == (32, 20, 8)
+
+
+def _whole_rectangle(monkeypatch):
+    """The parent's program: every call on the whole rectangle of blocks
+    with the plain index maps, which is what a call with no ``_Sweep`` (one
+    that is not causal, to ``_grid``) builds."""
+    grid = fa._grid
+    monkeypatch.setattr(fa, "_grid",
+                        lambda causal, *a, **k: grid(False, *a, **k))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("block_q,block_k", [(32, 64), (64, 32)])
+def test_the_clamped_maps_change_no_bit_of_the_two_product_form(
+        block_q, block_k, dtype, monkeypatch):
+    """The two-product form goes through the same ``_kernel_call`` and gets
+    the same maps (the shared key's block clamps with its head's): o and the
+    five gradients of a causal row of 256 in 8 x 4 and 4 x 8 blocks, many of
+    whose programs see no score, are bit for bit those of the whole
+    rectangle with the plain maps (a call with no ``_Sweep``: the parent's
+    program)."""
+    monkeypatch.setattr(fa, "_SUB_TILE", 16)
+    b, h, s, d, r, dv = 2, 2, 256, 32, 16, 48
+    *ops, w = _operands(b, h, s, d, r, dv, dtype, seed=3)
+    scale = (d + r) ** -0.5
+
+    def both(*ops):
+        o, lse = fa._flash_fwd2(*ops, scale, True, block_q, block_k, True)
+        delta = (w.astype(jnp.float32) * o.astype(jnp.float32)) \
+            .sum(-1, keepdims=True)
+        return (o, lse) + tuple(fa._flash_bwd2(
+            *ops, w, lse, delta, scale, True, block_q, block_k, True))
+
+    def level_0():
+        return jax.jit(both).lower(*ops).compile(compiler_options={
+            "xla_backend_optimization_level": 0})(*ops)
+    grids = [e.params["grid_mapping"].grid
+             for e in jax.make_jaxpr(both)(*ops).jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert grids == [(b * h, s // block_q, s // block_k)] * 2 \
+        + [(b * h, s // block_k, s // block_q)]
+    got = level_0()
+    _whole_rectangle(monkeypatch)
+    want = level_0()
+    names = ("o", "lse", "dq", "dq_rope", "dk", "dk_rope", "dv")
+    for name, x, y in zip(names, got, want):
+        assert x.dtype == y.dtype and x.shape == y.shape, name
+        np.testing.assert_array_equal(np.asarray(x, np.float32),
+                                      np.asarray(y, np.float32), err_msg=name)

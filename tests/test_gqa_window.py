@@ -128,6 +128,96 @@ def test_traced_offsets_as_ring_attention_passes_them(window, q_offset,
             np.testing.assert_allclose(g, e, atol=1e-4)
 
 
+def _whole_rectangle(monkeypatch):
+    """The parent's program: every call on the whole rectangle of blocks
+    with the plain index maps, which is what a call with no ``_Sweep`` (one
+    that is not causal, to ``_grid``) builds."""
+    grid = fa._grid
+    monkeypatch.setattr(fa, "_grid",
+                        lambda causal, *a, **k: grid(False, *a, **k))
+
+
+def _level_0(fn, *args):
+    """``fn(*args)`` compiled without LLVM's optimisations: two programs of
+    different grids then run the same arithmetic a tile
+    (``test_flash_attention._fwd_and_bwd``)."""
+    return jax.jit(fn).lower(*args).compile(
+        compiler_options={"xla_backend_optimization_level": 0})(*args)
+
+
+@pytest.mark.parametrize("block_k", [32, 16], ids=["16x32", "16x16"])
+@pytest.mark.parametrize("window", [16, 5, 40], ids=lambda w: f"window-{w}")
+@pytest.mark.parametrize("group", [6, 9], ids=lambda g: f"group-{g}")
+def test_a_windows_own_grid_changes_no_bit(group, window, block_k,
+                                           monkeypatch):
+    """Under a window the grid's inner dimension is the window's extent and
+    a step's block ``first + j``: o, lse, dq and the key-value-head-wide dk
+    and dv are, bit for bit, those of the whole rectangle with the plain
+    maps (the parent's program: a call with no ``_Sweep`` builds it), with
+    the dk/dv kernel's grid fanned over groups of 6 and 9 query heads."""
+    monkeypatch.setattr(fa, "_SUB_TILE", SUB)
+    q, k, v, do = _operands(group)
+
+    def both(q, k, v, do):
+        o, lse = fa._flash_fwd(q, k, v, True, 16, block_k, 0, 0, True,
+                               window=window)
+        delta = (do * o).sum(-1, keepdims=True)
+        return (o, lse) + tuple(fa._flash_bwd(
+            q, k, v, do, lse, delta, True, 16, block_k, 0, 0, True,
+            window=window))
+    narrow = fa._Sweep.of(False, 64, 64, 16, block_k, window, (0, 0))
+    fanned = fa._Sweep.of(True, 64, 64, 16, block_k, window, (0, 0))
+    # A q block's 16 rows see both k blocks of 32 whatever the window, and 2
+    # of the 4 of 16 (all 4 under the window of 40); a k block of 32 keys is
+    # seen from 3 of the 4 q blocks, one of 16 from 2 (4 under the 40).
+    assert (narrow.extent, narrow.blocks) == \
+        ((2, 2) if block_k == 32 else (4 if window == 40 else 2, 4))
+    assert (fanned.extent, fanned.blocks) == \
+        (4 if window == 40 else 3 if block_k == 32 else 2, 4)
+    kernels = {e.params["name"]: e.params["grid_mapping"].grid
+               for e in jax.make_jaxpr(both)(q, k, v, do).jaxpr.eqns
+               if e.primitive.name == "pallas_call"}
+    assert kernels == {
+        "flash_fwd": (2 * group, 4, narrow.extent),
+        "flash_bwd_dq": (2 * group, 4, narrow.extent),
+        "flash_bwd_dkv": (2, 64 // block_k, group * fanned.extent)}
+    got = _level_0(both, q, k, v, do)
+    _whole_rectangle(monkeypatch)
+    want = _level_0(both, q, k, v, do)
+    for name, a, b in zip(("o", "lse", "dq", "dk", "dv"), got, want):
+        assert a.shape == b.shape, name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+@pytest.mark.parametrize("q_offset,k_offset", [(0, 0), (32, 0), (0, 32),
+                                               (64, 16), (16, 48)],
+                         ids=["diagonal", "behind", "ahead", "shifted",
+                              "half-seen"])
+@pytest.mark.parametrize("window", [None, 16, 40])
+def test_traced_offsets_clamp_on_the_device_and_change_no_bit(
+        window, q_offset, k_offset, monkeypatch):
+    """Ring attention's hop: the offsets are traced, so the maps read them
+    from the scalar-prefetch operand where they run, and a block wholly
+    masked by them clamps to an empty range and is skipped as it was; the
+    results are the whole rectangle's, bit for bit."""
+    monkeypatch.setattr(fa, "_SUB_TILE", SUB)
+    q, k, v, do = _operands(3, s=64)
+
+    def hop(qo, ko):
+        o, lse = fa.block_attn_fwd(q, k, v, True, qo, ko, 16, 32,
+                                   interpret=True, window=window)
+        delta = (do * o).sum(-1, keepdims=True)
+        return (o, lse) + tuple(fa.block_attn_bwd(
+            q, k, v, do, lse, delta, True, qo, ko, 16, 32, interpret=True,
+            window=window))
+    offsets = jnp.int32(q_offset), jnp.int32(k_offset)
+    got = _level_0(hop, *offsets)
+    _whole_rectangle(monkeypatch)
+    want = _level_0(hop, *offsets)
+    for name, a, b in zip(("o", "lse", "dq", "dk", "dv"), got, want):
+        np.testing.assert_array_equal(a, b, err_msg=name)
+
+
 def _brute_force(sq, sk, block_q, block_k, sub, q_offset, k_offset, window):
     """Sub-tiles of ``block_q x sub`` that hold a seen score."""
     t = (q_offset + np.arange(sq))[:, None]

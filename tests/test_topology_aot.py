@@ -535,7 +535,13 @@ def test_v5e_compiler_takes_grouped_heads_and_the_windows_walk(
     dk/dv kernel's innermost dimension over a group's query heads, and the
     window's walk with both loop bounds from the device; and keys, values,
     dk and dv stay ``kv_heads`` wide at the kernels: no operand or result of
-    a kernel that is a key's is as wide as the query heads."""
+    a kernel that is a key's is as wide as the query heads.  Since PR 46 the
+    inner-side operands' index maps clamp the grid's block into what the
+    outer block sees, by the offsets read from the scalar-prefetch operand,
+    and under the window the grid's last dimension is the window's own
+    extent: 2 of a q block's k blocks (4 at Laguna's 4,096) for the forward
+    and dq, 3 q blocks a query head (of 8 there) for dk/dv; Mosaic takes
+    both, and the lowered calls' grids say so."""
     why_not = _why_no_detached_topology()
     if why_not:
         pytest.skip(why_not)
@@ -578,6 +584,12 @@ def test_v5e_compiler_takes_grouped_heads_and_the_windows_walk(
         assert operands.count(wide) == (1 if name == "flash_fwd" else 2)
     results = kernels["flash_bwd_dkv"].split(" custom-call(")[0]
     assert results.count(f"bf16[{kv},{s},{d}]") == 2 and wide not in results
+    # The grids: (heads, outer blocks, inner steps an outer block).
+    inner_k, inner_q = (2, 3) if window else (s // 1024, s // 512)
+    assert _grid(kernels["flash_fwd"]) == (heads, s // 512, inner_k)
+    assert _grid(kernels["flash_bwd_dq"]) == (heads, s // 512, inner_k)
+    assert _grid(kernels["flash_bwd_dkv"]) == (kv, s // 1024,
+                                               heads // kv * inner_q)
 
 
 def test_v5e_compiler_takes_256_lanes_at_a_group_of_eight(monkeypatch):
@@ -640,6 +652,20 @@ def test_v5e_compiler_takes_256_lanes_at_a_group_of_eight(monkeypatch):
     assert results.count(narrow) == 2 and wide not in results
     assert f"f32[{heads},{s},1]" in \
         kernels["flash_fwd"].split(" custom-call(")[0]
+    # No window: the grid is the whole rectangle (16 q blocks x 8 k blocks,
+    # the dk/dv kernel's 8 k blocks x 8 heads' 16 q blocks) and the clamped
+    # maps, which read the offsets, are what keep the 56 of 128 programs a
+    # kernel that see no score from copying a block.
+    assert _grid(kernels["flash_fwd"]) == (heads, 16, 8)
+    assert _grid(kernels["flash_bwd_dq"]) == (heads, 16, 8)
+    assert _grid(kernels["flash_bwd_dkv"]) == (kv, 8, heads // kv * 16)
+
+
+def _grid(call):
+    """The grid of one ``tpu_custom_call`` line's Mosaic module."""
+    bounds = re.search(r"iteration_bounds = array<i64: ([\d, ]+)>",
+                       _mosaic_text(call)).group(1)
+    return tuple(int(n) for n in bounds.split(","))
 
 
 def _mosaic_text(call):
