@@ -461,20 +461,30 @@ def mha_decode(p, x, num_heads, k_cache, v_cache, pos, dtype=None):
 
 # -- latent attention ----------------------------------------------------------
 
-def mla_init(key, dim, heads, q_rank, kv_rank, nope_dim, rope_dim, value_dim):
+def mla_init(key, dim, heads, q_rank, kv_rank, nope_dim, rope_dim, value_dim,
+             gate=False):
     """Parameters of one latent-attention mixer (:func:`mla`): the queries'
     low-rank path ``q_down`` (dim -> q_rank), ``q_norm``, ``q_up`` (q_rank
-    -> heads x (nope_dim + rope_dim), a head's unrotated part first); the
+    -> heads x (nope_dim + rope_dim), a head's unrotated part first), or
+    with ``q_rank=0`` no such path: one full-rank matrix ``q`` (dim -> heads
+    x (nope_dim + rope_dim)) and neither ``q_down`` nor ``q_norm``; the
     keys' and values' ``kv_down`` (dim -> kv_rank + rope_dim: the latent,
     then the one rotary key of the position), ``kv_norm`` over the latent,
     ``kv_up`` (kv_rank -> heads x (nope_dim + value_dim), a head's keys
-    first); ``out`` (heads x value_dim -> dim).  No bias anywhere."""
+    first); ``out`` (heads x value_dim -> dim); with ``gate`` a ``gate``
+    (dim -> heads: one scalar a head).  No bias anywhere."""
     ks = jax.random.split(key, 5)
-    return {
+    queries = {
         "q_down": dense_init(ks[0], dim, q_rank, use_bias=False),
         "q_norm": rmsnorm_init(q_rank),
         "q_up": dense_init(ks[1], q_rank, heads * (nope_dim + rope_dim),
                            use_bias=False),
+    } if q_rank else {"q": dense_init(
+        ks[0], dim, heads * (nope_dim + rope_dim), use_bias=False)}
+    gates = {"gate": dense_init(jax.random.fold_in(key, 5), dim, heads,
+                                use_bias=False)} if gate else {}
+    return {
+        **queries, **gates,
         "kv_down": dense_init(ks[2], dim, kv_rank + rope_dim, use_bias=False),
         "kv_norm": rmsnorm_init(kv_rank),
         "kv_up": dense_init(ks[3], kv_rank, heads * (nope_dim + value_dim),
@@ -523,8 +533,10 @@ def _announce_mla(heads, q_rank, kv_rank, nope_dim, rope_dim, value_dim, path):
     registry.gauge("mla.nope_width").set(nope_dim)
     registry.gauge("mla.rope_width").set(rope_dim)
     registry.gauge("mla.value_width").set(value_dim)
-    detail = (f"latent attention: {heads} heads, queries through a latent of "
-              f"{q_rank}, keys and values through one of {kv_rank}; scores "
+    detail = (f"latent attention: {heads} heads, queries "
+              + (f"through a latent of {q_rank}" if q_rank
+                 else "by one full-rank matrix")
+              + f", keys and values through one of {kv_rank}; scores "
               f"{nope_dim} + {rope_dim} wide (the rotary key one a position, "
               f"shared by the heads), values {value_dim}; core: {path}")
     if detail not in _mla_announced:
@@ -537,14 +549,19 @@ def mla(p, x, heads, nope_dim, rope_dim, value_dim, rope, mask=None,
     """Latent attention (multi-head, DeepSeek-V2's) over ``x`` (batch, s,
     dim).
 
-    ``c_q = rmsnorm(W_dq x)``; a head's query is ``W_uq c_q`` split into an
+    ``c_q = rmsnorm(W_dq x)``; a head's query is ``W_uq c_q`` (where the
+    parameters hold no low-rank path, ``mla_init(q_rank=0)``: ``W_q x``,
+    one matrix and no norm) split into an
     unrotated part of ``nope_dim`` and a rotary part of ``rope_dim``;
     ``[c_kv ; k_r] = W_dkv x``, ``c_kv`` normalised, ``k_r`` the ONE rotary
     key of the position that every head reads; a head's unrotated key and
     its value (``value_dim``) are ``W_ukv c_kv``; ``rope``
     (:func:`rope_pair_tables`) rotates the queries' rotary parts and
     ``k_r``; the score is the sum of the two products over
-    ``sqrt(nope_dim + rope_dim)``, softmax, values, ``W_o``.
+    ``sqrt(nope_dim + rope_dim)``, softmax, values, ``W_o``.  Where the
+    parameters hold ``gate`` (``mla_init(gate=True)``), head h's output is
+    multiplied by ``sigmoid(W_g x)_h`` (float32) before ``W_o``, under the
+    scope ``gate``.
 
     The core is ``attn_fn.two_product`` where the hook has one (the flash
     kernels' two-product form: ``k_r`` is never broadcast over the heads
@@ -563,9 +580,12 @@ def mla(p, x, heads, nope_dim, rope_dim, value_dim, rope, mask=None,
             "with .two_product (ops.flash_attention.make_flash_attn_fn), and "
             "this one (ring, Ulysses or a caller's own) has none")
     with jax.named_scope("q_latent"):
-        c_q = rmsnorm(p["q_norm"], dense(p["q_down"], x, dtype), norm_eps)
-        q = dense(p["q_up"], c_q, dtype).reshape(b, s, heads, -1) \
-            .transpose(0, 2, 1, 3)
+        if "q" in p:
+            q = dense(p["q"], x, dtype)
+        else:
+            c_q = rmsnorm(p["q_norm"], dense(p["q_down"], x, dtype), norm_eps)
+            q = dense(p["q_up"], c_q, dtype)
+        q = q.reshape(b, s, heads, -1).transpose(0, 2, 1, 3)
         q_nope, q_rope = q[..., :nope_dim], q[..., nope_dim:]
     with jax.named_scope("kv_latent"):
         down = dense(p["kv_down"], x, dtype)
@@ -580,7 +600,9 @@ def mla(p, x, heads, nope_dim, rope_dim, value_dim, rope, mask=None,
         k_rope = apply_rope_pairs(k_rope, rope)
     with jax.named_scope("core"):
         fused = two_product is not None and mask is None
-        _announce_mla(heads, p["q_down"]["kernel"].shape[1], kv_rank,
+        _announce_mla(heads,
+                      0 if "q" in p else p["q_down"]["kernel"].shape[1],
+                      kv_rank,
                       nope_dim, rope_dim, value_dim,
                       "the hook's two-product form" if fused
                       else "the two products in plain jnp")
@@ -590,6 +612,11 @@ def mla(p, x, heads, nope_dim, rope_dim, value_dim, rope, mask=None,
             from autodist_tpu.ops.flash_attention import two_product_reference
             o = two_product_reference(q_nope, q_rope, k_nope, k_rope, v,
                                       scale, causal and mask is None, mask)
+    if "gate" in p:
+        with jax.named_scope("gate"):
+            g = jax.nn.sigmoid(dense(p["gate"], x, dtype).astype(jnp.float32))
+            o = (o.astype(jnp.float32)
+                 * g.transpose(0, 2, 1)[..., None]).astype(o.dtype)
     with jax.named_scope("out"):
         o = o.transpose(0, 2, 1, 3).reshape(b, s, heads * value_dim)
         return dense(p["out"], o, dtype)
@@ -678,6 +705,16 @@ def _conv_bwd(res, dy):
 causal_depthwise_conv.defvjp(_conv_fwd, _conv_bwd)
 
 
+def _convolved(kernel, q, k, v):
+    """A linear mixer's q~, k~, v~ (batch, s, channels), each channel
+    convolved causally with its own taps of the one ``kernel`` (taps, q's +
+    k's + v's channels), then ``silu``."""
+    qk = q.shape[-1]
+    return tuple(jax.nn.silu(causal_depthwise_conv(kernel[:, lo:hi], t))
+                 for t, lo, hi in ((q, 0, qk), (k, qk, 2 * qk),
+                                   (v, 2 * qk, kernel.shape[1])))
+
+
 def l2_unit(t, eps=1e-6):
     """``t / sqrt(sum(t^2) + eps)`` over the last axis, in float32."""
     t = t.astype(jnp.float32)
@@ -711,11 +748,8 @@ def gdn(p, x, heads, dtype=None, allow_neg_eigval=True, norm_eps=1e-6,
         q, k, v, z, a, beta = (dense(p[name], x, dtype)
                                for name in ("q", "k", "v", "z", "a", "b"))
     with jax.named_scope("conv"):
-        kernel = p["conv"]["kernel"]
         qk = q.shape[-1]
-        q, k, v = (jax.nn.silu(causal_depthwise_conv(kernel[:, lo:hi], t))
-                   for t, lo, hi in ((q, 0, qk), (k, qk, 2 * qk),
-                                     (v, 2 * qk, kernel.shape[1])))
+        q, k, v = _convolved(p["conv"]["kernel"], q, k, v)
     with jax.named_scope("gates"):
         key_heads = key_heads or heads
         key_dim = qk // key_heads
@@ -733,6 +767,99 @@ def gdn(p, x, heads, dtype=None, allow_neg_eigval=True, norm_eps=1e-6,
     with jax.named_scope("out"):
         o = gated_rmsnorm(p["norm"], o, z.reshape(o.shape), norm_eps)
         return dense(p["out"], o.reshape(b, s, -1), dtype), state
+
+
+# -- delta rule with a decay a channel (Kimi Delta Attention) -----------------
+
+def kda_init(key, dim, heads, key_dim, value_dim, conv_width=4,
+             gate_lower_bound=-5.0):
+    """Parameters of one KDA mixer (:func:`kda`; Kimi Linear,
+    arXiv:2510.26692): ``q``, ``k`` (heads x key_dim), ``v`` (heads x
+    value_dim), the decay's full-rank projection ``f`` (heads x key_dim: a
+    gate a CHANNEL of the key), the write strength's ``b`` and the output
+    gate's ``z`` (heads: one scalar a head each), one depthwise convolution
+    kernel (conv_width, 2 heads key_dim + heads value_dim) over q, k and v
+    together, ``A_log`` a head and ``dt_bias`` a channel, one norm scale over
+    all heads x value_dim lanes, and ``out``.  No bias anywhere.  ``A_log``
+    is the log of uniform(1, 16); ``dt_bias`` is drawn so that at a zero
+    projection the bounded gate ``gate_lower_bound x sigmoid(exp(A_log)
+    dt_bias)`` is ``-exp(A_log) dt`` with ``dt`` log-uniform in [1e-3,
+    1e-1], the decays flash-linear-attention's draw gives the unbounded
+    gate."""
+    ks = jax.random.split(key, 11)
+    qk, vz = heads * key_dim, heads * value_dim
+    rate = jax.random.uniform(ks[7], (heads, 1), minval=1.0, maxval=16.0)
+    step = jnp.exp(jax.random.uniform(
+        ks[8], (heads, key_dim), minval=math.log(1e-3), maxval=math.log(1e-1)))
+    share = rate * step / -gate_lower_bound
+    return {
+        "q": dense_init(ks[0], dim, qk, use_bias=False),
+        "k": dense_init(ks[1], dim, qk, use_bias=False),
+        "v": dense_init(ks[2], dim, vz, use_bias=False),
+        "f": dense_init(ks[3], dim, qk, use_bias=False),
+        "b": dense_init(ks[4], dim, heads, use_bias=False),
+        "z": dense_init(ks[5], dim, heads, use_bias=False),
+        "conv": {"kernel": jax.random.uniform(
+            ks[6], (conv_width, 2 * qk + vz), minval=-conv_width ** -0.5,
+            maxval=conv_width ** -0.5)},
+        "A_log": jnp.log(rate[:, 0]),
+        "dt_bias": (jnp.log(share / (1.0 - share)) / rate).reshape(-1),
+        "norm": rmsnorm_init(vz),
+        "out": dense_init(ks[9], vz, dim, use_bias=False),
+    }
+
+
+def kda(p, x, heads, dtype=None, norm_eps=1e-6, gate_lower_bound=-5.0):
+    """One KDA mixer over ``x`` (batch, s, dim): ``(y, final state, the
+    most negative log decay summed inside a sub-block)``, the state (batch,
+    heads, key_dim, value_dim) in float32.
+
+    ``q~, k~, v~, f, b, z`` are projections of ``x``; each channel of q~,
+    k~, v~ is convolved causally over time with its own taps, then ``silu``;
+    per head q and k are L2-normalised (eps 1e-6 inside the root; q also
+    scaled by key_dim^-1/2); ``beta = sigmoid(b)``, one a head; the gate a
+    CHANNEL of a head, in float32, ``g = gate_lower_bound x
+    sigmoid(exp(A_log) (f + dt_bias))`` (the bounded, "safe" gate: ``exp g``
+    in ``(exp gate_lower_bound, 1)``, which is what the chunked rule's
+    sub-block form rests on, ``gated_delta.GATE_LOWER_BOUND``); the chunked
+    rule with a decay a channel (``ops/gated_delta.py``); ``y =
+    W_o(sigmoid(z)_h * rmsnorm(o))``, the norm over all heads x value_dim
+    lanes together with a scale as wide, the gate one scalar a head.  The
+    five named scopes are the rows of the profiler's table
+    (``kda/<part>``)."""
+    from autodist_tpu.ops import gated_delta
+    if gate_lower_bound < gated_delta.GATE_LOWER_BOUND:
+        raise ValueError(
+            f"a gate down to {gate_lower_bound} a position overflows the "
+            f"chunked rule's sub-blocks: it holds down to "
+            f"{gated_delta.GATE_LOWER_BOUND}")
+    b, s, _ = x.shape
+    with jax.named_scope("proj"):
+        q, k, v, f, beta, z = (dense(p[name], x, dtype)
+                               for name in ("q", "k", "v", "f", "b", "z"))
+    with jax.named_scope("conv"):
+        qk = q.shape[-1]
+        q, k, v = _convolved(p["conv"]["kernel"], q, k, v)
+    with jax.named_scope("gates"):
+        key_dim = qk // heads
+        q = (l2_unit(q.reshape(b, s, heads, key_dim))
+             * key_dim ** -0.5).astype(q.dtype)
+        k = l2_unit(k.reshape(b, s, heads, key_dim)).astype(k.dtype)
+        beta = jax.nn.sigmoid(beta.astype(jnp.float32))
+        g = gate_lower_bound * jax.nn.sigmoid(
+            jnp.exp(p["A_log"])[:, None]
+            * (f.astype(jnp.float32) + p["dt_bias"])
+            .reshape(b, s, heads, key_dim))
+        gate_min = gated_delta.sub_block_gate_min(lax.stop_gradient(g))
+    with jax.named_scope("scan"):
+        o, state = gated_delta.gated_delta_rule(
+            q, k, v.reshape(b, s, heads, -1), g, beta)
+    with jax.named_scope("out"):
+        o = rmsnorm(p["norm"], o.reshape(b, s, -1), norm_eps)
+        gate = jax.nn.sigmoid(z.astype(jnp.float32))
+        o = (o.reshape(b, s, heads, -1).astype(jnp.float32)
+             * gate[..., None]).astype(o.dtype)
+        return dense(p["out"], o.reshape(b, s, -1), dtype), state, gate_min
 
 
 # -- recurrent ---------------------------------------------------------------

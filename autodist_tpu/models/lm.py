@@ -158,6 +158,44 @@ def qwen3_next_80b_a3b(num_layers=48, vocab=151936, experts_held=None,
         rope_by_type={T.FULL: {"theta": 1e7, "lanes": 64, "yarn": None}})
 
 
+def ling_3_0_flash(num_layers=42, vocab=157184, experts_held=None,
+                   first_dense=2, dtype=jnp.bfloat16):
+    """The language model of Ling-3.0-flash-VL (inclusionAI/Ling-3.0-flash-VL
+    ``config.json``): width 2,560; a period of six layers
+    (``layer_group_size``), five KDA layers (the delta rule with a decay a
+    channel: 32 heads of 128 / 128, a 4-tap convolution, the gate bounded
+    below by -5, a head-wise output gate) then latent attention (32 heads,
+    full-rank queries, keys and values through a latent of 512, scores 128 +
+    64 wide with adjacent-pair rotary at theta 6,000,000, values 128, a
+    sigmoid gate a head); the first ``first_dense`` layers' feed-forward a
+    dense SwiGLU of 6,144, every later one 512 routed SwiGLU experts of 768,
+    8 a token by sigmoid scores plus a selection bias, chosen inside the 4
+    of 8 groups of 64 whose two best scores sum highest, weights over their
+    sum times 2.5, beside one shared expert; RMSNorm eps 1e-6, no bias, an
+    untied head.  ``num_layers`` keeps the pattern's first layers;
+    ``experts_held = (first, count)`` is one rank's share of each expert
+    layer (``parallel/moe.py``).  Not in ``config.json`` (the benchmark's
+    configuration file lists each with its reason): the gates' forms, the
+    initial decays, the bias's 0.001 a step, no auxiliary balance term.
+    Not built: the vision tower, the prediction module, the clamped SwiGLU
+    of the layers from 34 up."""
+    period = (T.KDA,) * 5 + (T.LATENT,)
+    return T.TransformerConfig(
+        vocab=vocab, dim=2560, num_heads=32, num_layers=num_layers,
+        mlp_dim=6144, max_len=131072, causal=True, dtype=dtype,
+        norm="rmsnorm", norm_eps=1e-6, positions="none",
+        rope_theta=6000000.0, bias=False, tied_head=False, ffn="moe",
+        num_experts=512, experts_per_token=8, expert_dim=768, norm_topk=True,
+        layer_types=[period[i % 6] for i in range(num_layers)],
+        linear_heads=32, linear_key_dim=128, linear_value_dim=128,
+        conv_width=4, linear_gate_bound=-5.0, expert_scoring="sigmoid",
+        route_scale=2.5, shared_experts=1, select_bias=True,
+        bias_update_rate=0.001, experts_held=experts_held,
+        expert_groups=8, expert_groups_kept=4, first_dense=first_dense,
+        q_rank=0, kv_rank=512, nope_dim=128, rope_dim=64, value_dim=128,
+        attn_gate=True)
+
+
 def ouro_2_6b(num_layers=48, vocab=49152, dtype=jnp.bfloat16):
     """Ouro-2.6B (ByteDance/Ouro-2.6B ``config.json``; arXiv:2510.25741,
     "Scaling Latent Reasoning via Looped Language Models"): width 2,048; 48
@@ -194,8 +232,11 @@ def make_loss_fn(cfg, attn_fn=None):
     statistics under the names of docs/observability.md.  With
     ``"linear_attention"`` layers it returns the pair too, ``aux`` holding
     ``gdn.state_absmax``, the largest magnitude of any such layer's final
-    state.  With ``cfg.mixer_stats`` it returns the pair, ``aux`` holding
-    ``attn.output_std`` and ``gdn.output_std``: the mean over the layers of
+    state; with ``"kda_attention"`` layers ``kda.state_absmax`` likewise and
+    ``kda.gate_min``, the most negative log decay summed inside a sub-block
+    of the chunked rule (what stands between it and an overflow).  With
+    ``cfg.mixer_stats`` it returns the pair, ``aux`` holding
+    ``attn.output_std``, ``gdn.output_std`` and ``kda.output_std``: the mean over the layers of
     each kind of the root mean square of the mixer's output about its mean
     over a row's positions.
 
@@ -267,7 +308,13 @@ def make_loss_fn(cfg, attn_fn=None):
         if reported("gdn_state_absmax"):
             aux["gdn.state_absmax"] = over_layers("gdn_state_absmax",
                                                   jnp.max)
-        for mixer in ("attn", "gdn"):
+        if reported("kda_state_absmax"):
+            aux["kda.state_absmax"] = over_layers("kda_state_absmax",
+                                                  jnp.max)
+            aux["kda.gate_min"] = over_layers("kda_gate_min", jnp.min)
+        if reported("groups_reached"):
+            aux["moe.groups_reached"] = over_layers("groups_reached")
+        for mixer in ("attn", "gdn", "kda"):
             if reported(f"{mixer}_output_std"):
                 aux[f"{mixer}.output_std"] = over_layers(
                     f"{mixer}_output_std")

@@ -21,9 +21,15 @@ current decoder block whose feed-forward is a layer of routed experts
 (``layers.gdn`` over ``ops/gated_delta.py``, parameters under
 ``layer<i>/gdn``) or ``"latent_attention"`` (``layers.mla``: queries, keys
 and values through low-rank latents, one rotary key a position shared by the
-heads, parameters under ``layer<i>/attn``) or ``"sliding_attention"`` (full
-attention's mixer behind a ``window``: position t sees the keys ``t -
-window < s <= t``).  A layer's mixer and its feed-forward are chosen apart:
+heads, parameters under ``layer<i>/attn``; ``q_rank=0``: the queries by one
+full-rank matrix; ``attn_gate`` gates its heads too) or ``"sliding_attention"``
+(full attention's mixer behind a ``window``: position t sees the keys ``t -
+window < s <= t``) or ``"kda_attention"`` (``layers.kda``: the delta rule
+with a decay a channel of the key, its gate bounded below by
+``linear_gate_bound``, at ``linear_heads`` x ``linear_key_dim`` /
+``linear_value_dim``, parameters under ``layer<i>/kda``;
+``recompute="linear_mixer"`` covers it).  A layer's mixer and its
+feed-forward are chosen apart:
 a ``"linear_attention"`` layer of an ``ffn="moe"`` model holds ``gdn`` and
 ``moe`` (``linear_key_heads``: fewer key heads than ``linear_heads``, each
 read by a group of value heads).  ``head_dim`` is a head's width where it is
@@ -86,7 +92,9 @@ class TransformerConfig:
                  kv_heads=None, heads_by_layer=None, window=None,
                  attn_gate=False, rope_by_type=None, linear_key_heads=None,
                  shared_gate=False, recompute=None, mixer_stats=False,
-                 experts_held_chunks=None, loops=1, exit_entropy_coef=0.0):
+                 experts_held_chunks=None, loops=1, exit_entropy_coef=0.0,
+                 linear_gate_bound=None, expert_groups=None,
+                 expert_groups_kept=None):
         for name, value, known in (("norm", norm, ("layernorm", "rmsnorm")),
                                    ("positions", positions,
                                     ("learned", "rope", "none")),
@@ -134,7 +142,8 @@ class TransformerConfig:
                 scoring=expert_scoring, route_scale=route_scale,
                 shared=shared_experts, select_bias=select_bias,
                 bias_update_rate=bias_update_rate, held=experts_held,
-                shared_gate=shared_gate, held_chunks=experts_held_chunks)
+                shared_gate=shared_gate, held_chunks=experts_held_chunks,
+                groups=expert_groups, groups_kept=expert_groups_kept)
         # The first ``first_dense`` layers of an ffn="moe" model keep a dense
         # SwiGLU MLP of ``mlp_dim``.
         self.first_dense = first_dense
@@ -163,6 +172,9 @@ class TransformerConfig:
         self.linear_key_dim, self.linear_value_dim = (linear_key_dim,
                                                       linear_value_dim)
         self.conv_width, self.allow_neg_eigval = conv_width, allow_neg_eigval
+        # A "kda_attention" layer's gate a position and channel lies in
+        # (``linear_gate_bound``, 0).
+        self.linear_gate_bound = linear_gate_bound
         # "linear_mixer": the backward pass computes a linear layer's mixer
         # sublayer again from its input (``jax.checkpoint``) and keeps none
         # of what lies inside; "pointwise": it computes every norm and the
@@ -217,7 +229,8 @@ class TransformerConfig:
                 raise ValueError(
                     f"layer_types must name one of {LAYER_TYPES} for each "
                     f"of the {num_layers} layers, got {layer_types!r}")
-            if scan_layers and LINEAR in self.layer_types:
+            if scan_layers and (LINEAR in self.layer_types
+                                or KDA in self.layer_types):
                 raise NotImplementedError(
                     "scan_layers stacks one kind of block and does not "
                     "carry the linear layers' final states out of the scan; "
@@ -228,12 +241,17 @@ class TransformerConfig:
                 raise ValueError(
                     "a 'linear_attention' layer needs linear_heads, "
                     "linear_key_dim and linear_value_dim")
-            if LATENT in self.layer_types and not (
-                    q_rank and kv_rank and nope_dim and rope_dim
-                    and value_dim):
+            if KDA in self.layer_types and not (
+                    linear_heads and linear_key_dim and linear_value_dim
+                    and linear_gate_bound):
                 raise ValueError(
-                    "a 'latent_attention' layer needs q_rank, kv_rank, "
-                    "nope_dim, rope_dim and value_dim")
+                    "a 'kda_attention' layer needs linear_heads, "
+                    "linear_key_dim, linear_value_dim and linear_gate_bound")
+            if LATENT in self.layer_types and not (
+                    kv_rank and nope_dim and rope_dim and value_dim):
+                raise ValueError(
+                    "a 'latent_attention' layer needs kv_rank, nope_dim, "
+                    "rope_dim and value_dim (q_rank 0: full-rank queries)")
             if scan_layers and LATENT in self.layer_types:
                 raise NotImplementedError(
                     "scan_layers stacks the default block; build a "
@@ -256,7 +274,8 @@ class TransformerConfig:
                 "inside the loop over the passes is not built (ROADMAP R9); "
                 "build a looped configuration with scan_layers=False")
         if loops > 1 and (ffn == "moe" or mtp_depth or (
-                self.layer_types is not None and LINEAR in self.layer_types)):
+                self.layer_types is not None
+                and {LINEAR, KDA} & set(self.layer_types))):
             raise NotImplementedError(
                 f"loops={loops}: a pass has no account of its own of what "
                 "expert layers, linear layers or the prediction module "
@@ -287,9 +306,9 @@ class TransformerConfig:
             else self.ffn
 
 
-FULL, LINEAR, LATENT, SLIDING = LAYER_TYPES = (
+FULL, LINEAR, LATENT, SLIDING, KDA = LAYER_TYPES = (
     "full_attention", "linear_attention", "latent_attention",
-    "sliding_attention")
+    "sliding_attention", "kda_attention")
 
 
 def _norm_init(cfg):
@@ -306,7 +325,8 @@ def _norm(cfg, p, x):
 
 def block_init(key, cfg, layer_type=FULL, ffn=None, heads=None):
     """One block's parameters; ``layer_type`` decides whether it holds
-    ``attn`` (full, sliding or latent attention) or ``gdn``, ``ffn`` (the
+    ``attn`` (full, sliding or latent attention), ``gdn`` or ``kda``, ``ffn``
+    (the
     configuration's where None) whether ``moe`` or ``mlp``, ``heads`` (the
     configuration's ``num_heads`` where None) its attention's query heads.
     ``ln1`` and ``ln2`` are the norms of the mixer's and the feed-forward's
@@ -322,10 +342,15 @@ def block_init(key, cfg, layer_type=FULL, ffn=None, heads=None):
         p["gdn"] = L.gdn_init(k1, cfg.dim, cfg.linear_heads,
                               cfg.linear_key_dim, cfg.linear_value_dim,
                               cfg.conv_width, cfg.linear_key_heads)
+    elif layer_type == KDA:
+        p["kda"] = L.kda_init(k1, cfg.dim, cfg.linear_heads,
+                              cfg.linear_key_dim, cfg.linear_value_dim,
+                              cfg.conv_width, cfg.linear_gate_bound)
     elif layer_type == LATENT:
-        p["attn"] = L.mla_init(k1, cfg.dim, cfg.num_heads, cfg.q_rank,
-                               cfg.kv_rank, cfg.nope_dim, cfg.rope_dim,
-                               cfg.value_dim)
+        p["attn"] = L.mla_init(
+            k1, cfg.dim, cfg.num_heads, cfg.q_rank, cfg.kv_rank, cfg.nope_dim,
+            cfg.rope_dim, cfg.value_dim,
+            **({"gate": True} if cfg.attn_gate else {}))
     else:
         p["attn"] = L.mha_init(k1, cfg.dim, heads or cfg.num_heads, cfg.bias,
                                cfg.qk_norm, cfg.head_dim, cfg.kv_heads,
@@ -356,7 +381,7 @@ def _residual(cfg, p, ln, x, sublayer):
     return x + y, stats
 
 
-_MIXER_KEYS = ("ln1", "ln1_out", "attn", "gdn")
+_MIXER_KEYS = ("ln1", "ln1_out", "attn", "gdn", "kda")
 
 
 def _halves(p):
@@ -385,7 +410,8 @@ def block_apply(p, x, cfg, mask=None, attn_fn=None, rope=None, window=None):
 def mixer_sublayer(p, x, cfg, mask=None, attn_fn=None, rope=None,
                    window=None):
     """The first half of a block, ``(x, stats)``: the token mixer the
-    parameters hold (``attn``, full or latent, or ``gdn``) with its norm
+    parameters hold (``attn``, full or latent, ``gdn`` or ``kda``) with its
+    norm
     and residual.  The parameters say how many query heads full attention
     has (``query`` is dim -> heads x ``cfg.head_dim``); ``window`` makes it
     sliding, and an explicit ``mask`` is then narrowed to the window."""
@@ -399,7 +425,14 @@ def mixer_sublayer(p, x, cfg, mask=None, attn_fn=None, rope=None,
                              key_heads=cfg.linear_key_heads)
             return y, {"gdn_state_absmax": jnp.max(jnp.abs(
                 jax.lax.stop_gradient(state)))}
-    elif "q_down" in p["attn"]:
+    elif "kda" in p:
+        def mixer(h):
+            y, state, gate_min = L.kda(
+                p["kda"], h, cfg.linear_heads, dtype=cfg.dtype,
+                norm_eps=cfg.norm_eps, gate_lower_bound=cfg.linear_gate_bound)
+            return y, {"kda_state_absmax": jnp.max(jnp.abs(
+                jax.lax.stop_gradient(state))), "kda_gate_min": gate_min}
+    elif "kv_down" in p["attn"]:
         def mixer(h):
             return L.mla(p["attn"], h, cfg.num_heads, cfg.nope_dim,
                          cfg.rope_dim, cfg.value_dim, rope, mask=mask,
@@ -415,7 +448,7 @@ def mixer_sublayer(p, x, cfg, mask=None, attn_fn=None, rope=None,
                          dtype=cfg.dtype, attn_fn=attn_fn, rope=rope,
                          norm_eps=cfg.norm_eps, kv_heads=cfg.kv_heads,
                          window=window), None
-    scope = "gdn" if "gdn" in p else "attn"
+    scope = next((name for name in ("gdn", "kda") if name in p), "attn")
     if cfg.mixer_stats:
         plain = mixer
 
@@ -641,7 +674,8 @@ def encode_passes(params, cfg, ids, segment_ids=None, attn_fn=None):
                     mixer_sublayer, cfg=cfg, mask=mask, attn_fn=attn_fn,
                     rope=rope.get(kind),
                     window=cfg.window if kind == SLIDING else None)
-                if kind == LINEAR and cfg.recompute == "linear_mixer":
+                if kind in (LINEAR, KDA) \
+                        and cfg.recompute == "linear_mixer":
                     sublayer = jax.checkpoint(sublayer)
                 x, mixed = sublayer(mixers[i], x)
             if i < last:

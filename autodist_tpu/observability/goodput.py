@@ -109,6 +109,7 @@ EVENT_CLASS = {
     "emergency-save": "emergency_save_ms",
     "flash": None,
     "gdn": None,
+    "kda": None,
     "goodput": None,
     "grad_sync": None,
     "loop": None,

@@ -247,6 +247,13 @@ MOE_SCOPE = "moe"
 #: (likewise ``gdn/proj``, ``gdn/conv``, ``gdn/gates``, ``gdn/out``; the
 #: norm of the mixer's output, in none of them, is ``gdn``).
 GDN_SCOPE = "gdn"
+#: And the mixer with a decay a channel: ``layer<i>/kda/scan`` -> ``kda/scan``
+#: (``kda/proj``, ``kda/conv``, ``kda/gates``, ``kda/out``).
+KDA_SCOPE = "kda"
+#: The router's group-limited choice keeps a level more:
+#: ``layer<i>/moe/router/groups`` -> ``moe/router/groups``, a row beside
+#: ``moe/router``.
+ROUTER_GROUPS = ("moe", "router", "groups")
 #: The multi-token-prediction module runs under the top-level scope ``mtp``
 #: and its block's, its head's and its loss's scopes fold into the generic
 #: rows with the model's own (``mtp/block/attn`` -> ``attn``,
@@ -282,8 +289,9 @@ def _scope_and_phase(op_name):
             scope = "head"
         elif len(segs) > 1 and segs[1] in BLOCK_SCOPES:
             scope = segs[1]
-        elif len(segs) > 1 and segs[1] in (MOE_SCOPE, GDN_SCOPE):
-            scope = "/".join(segs[1:3])
+        elif len(segs) > 1 and segs[1] in (MOE_SCOPE, GDN_SCOPE, KDA_SCOPE):
+            scope = "/".join(segs[1:4] if tuple(segs[1:4]) == ROUTER_GROUPS
+                             else segs[1:3])
         elif segs[0] in UPDATE_SCOPES:
             scope = segs[0]
     if scope in UPDATE_SCOPES:

@@ -44,7 +44,8 @@ EVENT_TYPES = frozenset({
     "chaos:kv-delay", "chaos:nan", "chaos:oom", "chaos:slow-host",
     "checkpoint-restore", "checkpoint-save",
     "ckpt-fallback", "compile", "divergence-abort", "emergency-save",
-    "flash", "gdn", "goodput", "grad_sync", "loop", "memory", "mesh-built",
+    "flash", "gdn", "goodput", "grad_sync", "kda", "loop", "memory",
+    "mesh-built",
     "mla",
     "moe",
     "monitor-start", "oom",

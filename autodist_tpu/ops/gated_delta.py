@@ -1,5 +1,7 @@
 """The gated delta rule (Gated DeltaNet, arXiv:2412.06464), chunked
-(arXiv:2406.06484), with its backward pass.
+(arXiv:2406.06484), with its backward pass; with one decay a head and
+position, or one a CHANNEL of the key (Kimi Delta Attention,
+arXiv:2510.26692: the last section), by the rank of ``g``.
 
 Per head, a state ``S`` of (d_k, d_v) starts at zero at a row's first
 position and is rewritten once a position::
@@ -33,8 +35,10 @@ step, ``K S_0``, ``T``'s, ``Q S_0``, ``(M * Q K^T) U`` and ``K~^T U``).
 ``U = U_0 - W S_0`` are never formed: they, ``diag(exp gamma) Q`` and
 ``diag(exp(gamma_C - gamma)) K`` were four ``(chunks, batch, heads, chunk,
 d)`` arrays written to HBM and read back three times a layer.
-Decays are differences of logarithms, never quotients of decays, so a
-decay near 0 underflows to an exact 0 and nothing overflows.  Every product
+With one decay a head and position, decays are differences of logarithms,
+never quotients of decays, so a decay near 0 underflows to an exact 0 and
+nothing overflows (a decay a channel needs a quotient in two places, and a
+bound: the last section).  Every product
 takes operands in the inputs' dtype (bfloat16 on the train path) and
 accumulates in float32, but those that ``T`` multiplies (one forward, and
 with it ``dT``'s and ``T^T``'s transposed), which take float32 operands at
@@ -109,8 +113,55 @@ that makes a chunk's ``U`` again; the terms' own intermediates (the decay
 matrices, ``A``: float32, chunk x chunk a chunk and head) are made again
 from q, k, g and beta and not kept.  Without the two, one layer of 30 heads
 keeps 0.7 GB at 4,096 positions.
+
+**A decay a channel of the key** (``g`` of (batch, s, heads, d_k); the rule
+is ``S_t = (I - beta_t k_t k_t^T) diag(alpha_t) S_(t-1) + beta_t k_t
+v_t^T``, ``alpha_t = exp(g_t)`` a vector of d_k).  With ``gamma_i`` the sum
+of ``g`` over the chunk's positions up to ``i``, now a vector, and ``G_i =
+exp(gamma_i)``::
+
+    (I + A) U = diag(beta) (V - (K * G) S_0)
+    A_ij = beta_i sum_c k_ic k_jc exp(gamma_ic - gamma_jc)    for j < i
+    O    = (Q * G) S_0 + P U     P_ij = sum_c q_ic k_jc exp(gamma_ic -
+    S_C  = diag(G_C) S_0 + (K * exp(gamma_C - gamma))^T U     gamma_jc), j <= i
+
+Where the scalar form scales a product's result (``diag(exp gamma) (Q
+S_0)``) the decay rides on the operand (``(Q * G) S_0``, rounded to the
+inputs' dtype after the decay: ``_state_read``), the state's carry-over is a
+scaling of its rows, and ``gamma``'s cotangent is a channel's
+(``_walk_transposed_body``); the walk's kernels (``kda_walk_fwd``,
+``kda_walk_bwd``) are the same bodies with ``by_channel``, their ``gamma``
+block ``(Hb, chunk, d_k)`` float32 where the scalar form's is a row a head
+(32 KB a head at 64 x 128, and as much for its cotangent: ``_walk_vmem``
+counts it, 8 of 32 heads a program at 128 / 128 where the scalar form takes
+16), and the ``lax.scan`` runs the same ``_head_step``.  **Only ``A`` and
+``P`` hold a quotient of decays**, and they are no masked products: ``(K * G)
+(K / G)^T`` over a chunk's 64 positions overflows (a gate of -5 a position
+is ``exp(320)``).  The form taken (``_chunk_terms_by_channel``): the chunk's
+positions in sub-blocks of ``SUB_BLOCK`` = 16, and for each pair of
+sub-blocks ``J <= I`` ONE product of two scaled operands, both measured from
+``r_I``, the summed log decay at sub-block ``I``'s first position: the rows
+take ``exp(gamma_i - r_I)`` <= 1, the columns ``exp(r_I - gamma_j)``, which
+is <= 1 for an earlier sub-block (``gamma_j >= r_I``) and at most
+``exp(-GATE_LOWER_BOUND x 15)`` = e^75 inside ``I`` itself; float32 and
+bfloat16 both hold that (their largest is e^88.7, and a sum of 128 channels
+of it stays under), so the diagonal sub-blocks take the same product as the
+others and none is computed a pair at a time on the vector unit.  That is
+what the bound of -5 on a position's gate is for (``layers.kda``'s gate is
+``-5 sigmoid(.)``; ``GATE_LOWER_BOUND``, the gauge
+``kda.gate_lower_bound``, and ``sub_block_gate_min`` reads how far a step
+came): a gate under it overflows, and the form is for bounded gates only.
+The sub-block width is the widest the bound allows (-80 / 16) and the
+narrowest that keeps a product's rows at a bf16 tile's 16.  The columns'
+operand exists ``chunk / SUB_BLOCK`` = 4 times, once under each ``r_I``
+(``(n, b, h, 4, chunk, d_k)`` in the inputs' dtype, of which the sub-blocks
+above the diagonal are exact zeros: 4 copies of k a layer, 67 MB at 2,048
+positions of 32 heads); no ``(chunk, chunk, d_k)`` array exists anywhere.
+A decay of a whole sub-block or more away underflows to an exact 0, as in
+the scalar form.
 """
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -124,6 +175,15 @@ from autodist_tpu.utils import logging
 
 #: Positions a chunk: the rows of the matrix inverted and the walk's stride.
 CHUNK = 64
+#: With a decay a channel, the positions of a chunk's sub-blocks: between two
+#: of them and inside one, a chunk's two matrices are products of operands
+#: that carry the decay measured from the later sub-block's first position.
+SUB_BLOCK = 16
+#: The lowest log decay a position and channel for which that form holds:
+#: inside a sub-block an operand's decay reaches ``exp(-GATE_LOWER_BOUND x
+#: (SUB_BLOCK - 1))`` = e^75, and a sum over a key's channels of such
+#: products stays under float32's and bfloat16's largest, e^88.7.
+GATE_LOWER_BOUND = -5.0
 WALK_TERMS = ("the walk reads T, M * Q K^T, q and k a key head, v and the "
               "gates' rows and makes a chunk's U = T diag(beta) (V - "
               "diag(exp gamma) K S_0) itself")
@@ -177,6 +237,70 @@ def _announce(rows, s, heads, key_heads, d_k, d_v, chunk, head_block, why,
             observability.record_event("gdn", detail)
 
 
+def _announce_by_channel(rows, s, heads, key_heads, d_k, d_v, chunk,
+                         head_block, why, dtype):
+    """Gauges ``kda.*`` and a ``kda`` event for the rule with a decay a
+    channel being traced; the event and the log line are written once a
+    process for each shape and form traced."""
+    from autodist_tpu import observability
+    chunks = -(-s // chunk)
+    sub = _sub_block(chunk)
+    group = heads // key_heads
+    walk = "lax.scan"
+    if head_block:
+        trips = [_trip_heads(head_block, group, chunk, d_k, d_v, dtype, t,
+                             True) for t in (False, True)]
+        vmem = [_walk_vmem(head_block, trip, group, chunk, d_k, d_v, dtype, t,
+                           True) for trip, t in zip(trips, (False, True))]
+        walk = (f"Pallas kernels kda_walk_fwd / kda_walk_bwd, {head_block} "
+                f"heads a program ({trips[0]} / {trips[1]} a trip), the state "
+                f"in VMEM, {vmem[0]} / {vmem[1]} bytes of VMEM by the padded "
+                f"estimate")
+    detail = (f"delta rule with a decay a channel, chunked: ({rows}, {s}, "
+              f"{heads}, {d_k} / {d_v}), {key_heads} key heads, {chunks} "
+              f"chunks of {chunk} a row, state {heads} x {d_k} x {d_v} "
+              f"float32; a chunk's two matrices from sub-blocks of {sub} "
+              f"positions, both operands of a sub-block pair scaled by the "
+              f"decay measured from the later one's first position (one "
+              f"product a pair, nothing above exp({-GATE_LOWER_BOUND:g} x "
+              f"{sub - 1}); a gate under {GATE_LOWER_BOUND:g} a position "
+              f"would overflow); walk over the chunks: {walk} ({why}), gamma "
+              f"a ({chunk}, {d_k}) float32 block a head; inverse: "
+              f"{inverse_form(chunk)}; backward: {BACKWARD}")
+    new = detail not in _announced
+    _announced.add(detail)
+    if new:
+        logging.info("gated_delta_rule: %s", detail)
+    if observability.enabled():
+        registry = observability.registry()
+        registry.gauge("kda.heads").set(heads)
+        registry.gauge("kda.key_dim").set(d_k)
+        registry.gauge("kda.sub_block").set(sub)
+        registry.gauge("kda.gate_lower_bound").set(GATE_LOWER_BOUND)
+        registry.gauge("kda.scan_kernel").set(int(bool(head_block)))
+        registry.gauge("kda.scan_head_block").set(head_block)
+        if new:
+            observability.record_event("kda", detail)
+
+
+def _sub_block(chunk):
+    """The positions of a chunk's sub-blocks: ``SUB_BLOCK``, or the largest
+    divisor of ``chunk`` under it."""
+    return math.gcd(chunk, SUB_BLOCK)
+
+
+def sub_block_gate_min(g, chunk=CHUNK):
+    """The most negative log decay summed inside a sub-block, over ``g``
+    (batch, s, heads, d_k): what stands between the sub-block form and an
+    overflow (``GATE_LOWER_BOUND x (SUB_BLOCK - 1)`` is the least it
+    holds)."""
+    sub = _sub_block(chunk)
+    b, s = g.shape[:2]
+    g = jnp.pad(g, ((0, 0), (0, -s % sub), (0, 0), (0, 0)))
+    return jnp.min(jnp.sum(
+        g.reshape((b, -1, sub) + g.shape[2:])[:, :, 1:], axis=2))
+
+
 def _mm(spec, a, b, dtype):
     """``einsum`` with both operands in ``dtype``, accumulated and returned
     in float32."""
@@ -189,7 +313,10 @@ def gated_delta_rule(q, k, v, g, beta, chunk=CHUNK, interpret=None):
     and each row's final state (batch, heads, d_k, d_v) in float32, over
     ``q``, ``k`` (batch, s, key heads, d_k), ``v`` (batch, s, heads, d_v),
     the log decays ``g`` <= 0 and the write strengths ``beta`` (batch, s,
-    heads); one document a row, the state zero at its start.  The key heads
+    heads); one document a row, the state zero at its start.  ``g`` of
+    (batch, s, heads, d_k) is a decay a CHANNEL of the key (the module
+    docstring's last section: the rank of ``g`` decides the form, and a
+    position's gate is then held to ``GATE_LOWER_BOUND``).  The key heads
     are ``heads`` or a divisor of them: value head ``h`` reads key head ``h
     // (heads / key heads)`` (the module docstring).  ``q`` and ``k`` come
     as the rule takes them (normalised and scaled by the caller).  A length
@@ -200,16 +327,18 @@ def gated_delta_rule(q, k, v, g, beta, chunk=CHUNK, interpret=None):
     CPU tests)."""
     b, s, key_heads, d_k = q.shape
     heads, d_v = v.shape[2:]
+    by_channel = g.ndim == 4
     if heads % key_heads or k.shape != q.shape \
-            or g.shape != v.shape[:3] or beta.shape != g.shape:
+            or g.shape != v.shape[:3] + ((d_k,) if by_channel else ()) \
+            or beta.shape != v.shape[:3]:
         raise ValueError(
             f"q and k {q.shape} / {k.shape} must hold key heads that divide "
-            f"the {heads} heads of v {v.shape}, g {g.shape} and beta "
-            f"{beta.shape}")
-    interpret, head_block, why = walk_form(interpret, heads, key_heads, chunk,
-                                           d_k, d_v, q.dtype)
-    _announce(b, s, heads, key_heads, d_k, d_v, chunk, head_block, why,
-              q.dtype)
+            f"the {heads} heads of v {v.shape}, g {g.shape} (a head, or a "
+            f"head and channel of the key) and beta {beta.shape}")
+    interpret, head_block, why = walk_form(
+        interpret, heads, key_heads, chunk, d_k, d_v, q.dtype, by_channel)
+    (_announce_by_channel if by_channel else _announce)(
+        b, s, heads, key_heads, d_k, d_v, chunk, head_block, why, q.dtype)
     return _chunked_rule(q, k, v, g, beta, chunk=chunk, interpret=interpret,
                          head_block=head_block)
 
@@ -254,14 +383,20 @@ def _chunked_rule(q, k, v, g, beta, chunk, interpret=None, head_block=0):
         def grouped(x):     # (b, h, ...) -> (b, key heads, group, ...)
             return x.reshape((b, key_heads, group) + x.shape[2:])
 
+        by_channel = g.ndim == 5
+
+        def column(x):      # a chunk's decays as ``_head_step`` takes them
+            return x if by_channel else x[..., None]
+
         def step(state, chunk_in):
             t, qk, q, k, v, gamma, beta = chunk_in
-            end = gamma[..., -1:]
+            end = gamma[..., -1:, :] if by_channel else gamma[..., -1:]
             state, o = heads(
                 grouped(state), grouped(t), grouped(qk), q, k, grouped(v),
-                grouped(beta[..., None, :]), grouped(jnp.exp(gamma)[..., None]),
-                grouped(jnp.exp(end - gamma)[..., None]),
-                grouped(jnp.exp(end)[..., None]))
+                grouped(beta[..., None, :]), grouped(column(jnp.exp(gamma))),
+                grouped(column(jnp.exp(end - gamma))),
+                grouped(jnp.swapaxes(jnp.exp(end), -1, -2) if by_channel
+                        else jnp.exp(end)[..., None]))
             return (state.reshape((b, h) + state.shape[3:]),
                     o.reshape((b, h) + o.shape[3:]))
 
@@ -323,10 +458,43 @@ def _decays(gamma, masks):
     return jnp.exp(column), jnp.exp(end - column), jnp.exp(end)
 
 
+def _by_channel(into):
+    """Whether a chunk's decays are a channel's: ``into`` is then ``exp
+    gamma`` (c, d_k) and not a (c, 1) column.  A (c, 1) ``into`` of a d_k of
+    one channel takes the column's form, which is then the same number."""
+    return into.shape[-1] != 1
+
+
+def _state_read(x, s0, into):
+    """``diag(exp gamma) X S_0`` in float32, of ``x`` = ``Q`` or ``K`` (c,
+    d_k): a decay a position scales the product's float32 result; a decay a
+    channel rides on the operand, ``(X * exp gamma) S_0``, rounded to the
+    inputs' dtype."""
+    if _by_channel(into):
+        return _dot((into * x).astype(x.dtype), s0, -1, -2)
+    return into * _dot(x, s0, -1, -2)
+
+
+def _channel_carry(gamma, key_masks):
+    """``exp(gamma_C)`` as the (d_k, 1) column that scales the state's rows,
+    of a chunk's (c, d_k) summed log decays a channel; ``key_masks`` =
+    ``_masks(d_k)``."""
+    return _column(jnp.exp(gamma[-1:, :]), key_masks[0])
+
+
+def _channel_decays(gamma, key_masks):
+    """``(exp(gamma_C - gamma) (c, d_k), _channel_carry)``."""
+    return jnp.exp(gamma[-1:, :] - gamma), _channel_carry(gamma, key_masks)
+
+
 def _chunk_reads(k, v, s0, into):
     """``(K S_0, V - diag(exp gamma) K S_0)`` in float32: what a chunk's
     positions read of the state it starts from, ``s0`` in the inputs'
-    dtype."""
+    dtype.  With a decay a channel the first is ``(K * exp gamma) S_0``,
+    the decay in it."""
+    if _by_channel(into):
+        ks = _state_read(k, s0, into)
+        return ks, v - ks
     ks = _dot(k, s0, -1, -2)
     return ks, v - into * ks
 
@@ -355,13 +523,15 @@ def _head_step(state, t, qk, q, k, v, beta, into, to_end, carry):
     dtype, ``beta`` a (1, c) float32 row, the decays since ``S_0`` and to
     ``S_C`` as (c, 1) float32 columns ``into`` = ``exp(gamma)`` and
     ``to_end`` = ``exp(gamma_C - gamma)``, ``carry`` = ``exp(gamma_C)`` (1,
-    1).  The module docstring's equations as they stand, ``W`` and ``U_0``
+    1); with a decay a channel ``into`` and ``to_end`` are (c, d_k) and
+    ``carry`` the (d_k, 1) column that scales the state's rows.  The module
+    docstring's equations as they stand, ``W`` and ``U_0``
     never formed: under ``vmap`` the scan's step, and the three functions
     it is made of are the three phases of the kernels' forward body."""
     dtype = v.dtype
     s0 = state.astype(dtype)
     u = _chunk_writes(t, beta, _chunk_reads(k, v, s0, into)[1]).astype(dtype)
-    return _chunk_leaves(state, qk, k, u, into * _dot(q, s0, -1, -2), to_end,
+    return _chunk_leaves(state, qk, k, u, _state_read(q, s0, into), to_end,
                          carry)
 
 
@@ -380,7 +550,8 @@ _VMEM_BUDGET = 12 * 2 ** 20
 _TRIP_HEADS = 8
 
 
-def _walk_vmem(heads, trip, group, chunk, d_k, d_v, dtype, transposed):
+def _walk_vmem(heads, trip, group, chunk, d_k, d_v, dtype, transposed,
+               by_channel=False):
     """The padded VMEM bytes of a walk's program of ``heads`` value heads:
     its blocks double-buffered (``T``, ``M * Q K^T``, v, the gates' rows and
     a key head's q and k in; forward ``o``, the saved state and the final
@@ -388,31 +559,36 @@ def _walk_vmem(heads, trip, group, chunk, d_k, d_v, dtype, transposed):
     state's in and the seven cotangents out), the state scratch and the
     staging of a trip of ``trip`` heads (forward ``R = V - diag(exp gamma) K
     S_0``, ``diag(exp gamma) Q S_0`` and ``U``; transposed ``R``, ``K
-    S_0``, ``dU``, ``U``, ``d(K S_0)`` and the sums of dq and dk)."""
+    S_0``, ``dU``, ``U``, ``d(K S_0)`` and the sums of dq and dk).
+    ``by_channel``: ``gamma``'s block, and its cotangent's, is ``(heads,
+    chunk, d_k)`` float32 and not a row a head."""
     state, wide = ((heads, d_k, d_v), jnp.float32), ((heads, chunk, d_v), dtype)
+    gamma = (heads, chunk, d_k) if by_channel else (heads, chunk)
     terms = [((heads, chunk, chunk), jnp.float32),
              ((heads, chunk, chunk), dtype),
              ((heads // group, chunk, d_k), dtype),
              ((heads // group, chunk, d_k), dtype), wide,
-             ((heads, chunk), jnp.float32), ((heads, chunk), jnp.float32)]
+             (gamma, jnp.float32), ((heads, chunk), jnp.float32)]
     blocks = terms + [wide, state, state] + (terms if transposed else [])
     return (2 * sum(_padded_bytes(*b) for b in blocks)
             + _padded_bytes(*state)
             + sum(_padded_bytes(*b) for b in _stages(
-                trip, chunk, d_k, d_v, dtype, transposed)))
+                trip, chunk, d_k, d_v, dtype, transposed, by_channel)))
 
 
-def _stages(trip, chunk, d_k, d_v, dtype, transposed):
+def _stages(trip, chunk, d_k, d_v, dtype, transposed, by_channel=False):
     """The staging scratch of a trip's heads, ``(shape, dtype)``, in the
-    order the bodies take it."""
+    order the bodies take it (transposed with a decay a channel ``K S_0``
+    is not staged: ``gamma``'s cotangent is made of the operands')."""
     wide, key = (trip, chunk, d_v), (trip, chunk, d_k)
     if not transposed:
         return [(wide, jnp.float32), (wide, jnp.float32), (wide, dtype)]
-    return [(wide, jnp.float32)] * 3 + [(wide, dtype)] * 2 \
-        + [(key, jnp.float32)] * 2
+    return [(wide, jnp.float32)] * (2 if by_channel else 3) \
+        + [(wide, dtype)] * 2 + [(key, jnp.float32)] * 2
 
 
-def _trip_heads(heads, group, chunk, d_k, d_v, dtype, transposed):
+def _trip_heads(heads, group, chunk, d_k, d_v, dtype, transposed,
+                by_channel=False):
     """Value heads a trip of a program's loop over its ``heads``: whole
     groups, a divisor of ``heads``, ``_TRIP_HEADS`` at most (one group where
     a group is more), as many as leave the program within ``_VMEM_BUDGET``
@@ -420,25 +596,28 @@ def _trip_heads(heads, group, chunk, d_k, d_v, dtype, transposed):
     10 both ways at 64 x 96 / 192)."""
     return max(n for n in range(group, max(group, _TRIP_HEADS) + 1, group)
                if heads % n == 0 and (n == group or _walk_vmem(
-                   heads, n, group, chunk, d_k, d_v, dtype, transposed)
-                   <= _VMEM_BUDGET))
+                   heads, n, group, chunk, d_k, d_v, dtype, transposed,
+                   by_channel) <= _VMEM_BUDGET))
 
 
-def _head_block(heads, key_heads, chunk, d_k, d_v, dtype):
+def _head_block(heads, key_heads, chunk, d_k, d_v, dtype, by_channel=False):
     """Heads a program: the largest divisor of ``heads`` in whole groups of
     ``heads / key_heads`` (a program reads its heads' key heads whole) that
     ``_walk_vmem`` finds within ``_VMEM_BUDGET`` in the transposed kernel,
     which holds the most, at a trip of one group (at 64 x 128 / 128 in
     bfloat16, two heads a key head, 0.66 MB a head: 16 of 32 heads; at 64 x
-    96 / 192 0.97 MB: 10 of 30)."""
+    96 / 192 0.97 MB: 10 of 30; with a decay a channel, ``gamma`` and its
+    cotangent (chunk, d_k) float32 a head, 0.79 MB at 64 x 128 / 128: 8 of
+    32)."""
     group = heads // key_heads
     return max((n for n in range(group, heads + 1, group)
                 if heads % n == 0 and _walk_vmem(
-                    n, group, group, chunk, d_k, d_v, dtype, True)
+                    n, group, group, chunk, d_k, d_v, dtype, True, by_channel)
                 <= _VMEM_BUDGET), default=0)
 
 
-def walk_form(interpret, heads, key_heads, chunk, d_k, d_v, dtype):
+def walk_form(interpret, heads, key_heads, chunk, d_k, d_v, dtype,
+              by_channel=False):
     """``(interpret, heads a program, why)``: how a trace walks a row's
     chunks.  The kernels (``interpret`` False, or True where a test asked
     for the Pallas interpreter) on a TPU backend with no mesh axis left to
@@ -454,7 +633,8 @@ def walk_form(interpret, heads, key_heads, chunk, d_k, d_v, dtype):
             return None, 0, "a mesh axis is left to the partitioner"
     if chunk % 8:
         return None, 0, f"a chunk of {chunk} is no multiple of 8"
-    head_block = _head_block(heads, key_heads, chunk, d_k, d_v, dtype)
+    head_block = _head_block(heads, key_heads, chunk, d_k, d_v, dtype,
+                             by_channel)
     if not head_block:
         return None, 0, (f"the blocks of {heads // key_heads} head(s) a key "
                          f"head at {chunk} x {d_k} / {d_v} pass "
@@ -494,36 +674,42 @@ def _gate(ref, h):
 
 
 def _walk_forward_body(t_ref, qk_ref, q_ref, k_ref, v_ref, gamma_ref,
-                       beta_ref, o_ref, *rest, group, trip):
+                       beta_ref, o_ref, *rest, group, trip, by_channel=False):
     """One chunk of a program's heads: ``_head_step``, a phase at a time
     over a trip's heads, the state in the VMEM scratch ``state_ref`` from
     the row's first chunk to its last, where it leaves as the row's final
     state; ``rest`` begins with the block the chunk's incoming state is
-    saved to where a backward pass will read it."""
+    saved to where a backward pass will read it.  ``by_channel``: a head's
+    ``gamma`` is its (chunk, d_k) block and not a row of the gates'."""
     *saved, final_ref, state_ref, rhs_ref, o_s0_ref, u_ref = rest
     c = pl.program_id(2)
     dtype = v_ref.dtype
     masks = _masks(t_ref.shape[-1])
+    key_masks = _masks(k_ref.shape[-1]) if by_channel else None
 
     @pl.when(c == 0)
     def _():
         state_ref[...] = jnp.zeros_like(state_ref)
 
     def reads(j, h, a):         # what the chunk reads of S_0
-        into = _into(_gate(gamma_ref, h), masks)
+        into = jnp.exp(gamma_ref[h]) if by_channel \
+            else _into(_gate(gamma_ref, h), masks)
         state = state_ref[h]
         if saved:
             saved[0][h] = state
         s0 = state.astype(dtype)
         rhs_ref[a] = _chunk_reads(k_ref[j], v_ref[h], s0, into)[1]
-        o_s0_ref[a] = into * _dot(q_ref[j], s0, -1, -2)
+        o_s0_ref[a] = _state_read(q_ref[j], s0, into)
 
     def writes(j, h, a):        # what its positions write
         u_ref[a] = _chunk_writes(t_ref[h], _gate(beta_ref, h),
                                  rhs_ref[a]).astype(dtype)
 
     def leaves(j, h, a):        # what it leaves
-        _, to_end, carry = _decays(_gate(gamma_ref, h), masks)
+        if by_channel:
+            to_end, carry = _channel_decays(gamma_ref[h], key_masks)
+        else:
+            _, to_end, carry = _decays(_gate(gamma_ref, h), masks)
         state_ref[h], o_ref[h] = _chunk_leaves(
             state_ref[h], qk_ref[h], k_ref[j], u_ref[a], o_s0_ref[a], to_end,
             carry)
@@ -539,8 +725,8 @@ def _walk_forward_body(t_ref, qk_ref, q_ref, k_ref, v_ref, gamma_ref,
 def _walk_transposed_body(t_ref, qk_ref, q_ref, k_ref, v_ref, gamma_ref,
                           beta_ref, saved_ref, do_ref, dfinal_ref, dt_ref,
                           dqk_ref, dq_ref, dk_ref, dv_ref, dgamma_ref,
-                          dbeta_ref, dstate_ref, rhs_ref, ks_ref, du_ref,
-                          u_ref, dks_ref, dq_sum, dk_sum, *, group, trip):
+                          dbeta_ref, dstate_ref, rhs_ref, *stages, group,
+                          trip, by_channel=False):
     """``_head_step``'s transpose for one chunk of a program's heads, the
     grid's last index counting the chunks from a row's end: the cotangent
     ``dS`` of the state the chunk leaves is in the VMEM scratch, started
@@ -560,9 +746,29 @@ def _walk_transposed_body(t_ref, qk_ref, q_ref, k_ref, v_ref, gamma_ref,
     here, and the gates' cotangents as rows: ``dbeta`` the column sums of
     ``d(T diag(beta)) * T``, ``dgamma`` row sums of products made here
     anyway (of ``dO S_0^T * Q``, ``dR * K S_0``, ``dK~ * K~``), ``gamma_C``'s
-    (with ``<dS, S_0>``) on ``gamma``'s last entry."""
+    (with ``<dS, S_0>``) on ``gamma``'s last entry.
+
+    ``by_channel`` (``gamma`` (chunk, d_k) a head; ``G = exp gamma``, ``E =
+    exp(gamma_C - gamma)``, ``R = V - (K * G) S_0``, ``K~ = K * E``)::
+
+        d(Q * G) = dO S_0^T          dq = G * d(Q * G)
+        dK~ = U dS^T                 dk = E * dK~ + G * d(K * G)
+        d((K * G) S_0) = -dR         d(K * G) = -dR S_0^T
+        dgamma = d(Q * G) * Q * G + d(K * G) * K * G - dK~ * K~
+        dS_0 = diag(exp gamma_C) dS + (Q * G)^T dO - (K * G)^T dR
+
+    ``gamma_C``'s a channel (the column sums of ``dK~ * K~`` and the row
+    sums of ``dS * S_0``, times ``exp gamma_C``) on ``gamma``'s last row;
+    the rest as above, and ``K S_0`` is not staged."""
     dtype = v_ref.dtype
     masks = diagonal, last = _masks(t_ref.shape[-1])
+    if by_channel:
+        du_ref, u_ref, dks_ref, dq_sum, dk_sum = stages
+        key_masks = _masks(k_ref.shape[-1])
+        last_row = lax.broadcasted_iota(
+            jnp.int32, (t_ref.shape[-1], 1), 0) == t_ref.shape[-1] - 1
+    else:
+        ks_ref, du_ref, u_ref, dks_ref, dq_sum, dk_sum = stages
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -596,13 +802,16 @@ def _walk_transposed_body(t_ref, qk_ref, q_ref, k_ref, v_ref, gamma_ref,
             diagonal) + jnp.where(last, dend, 0.0)
 
     def through_writes(j, h, a):        # through U = T diag(beta) R
-        into = _into(_gate(gamma_ref, h), masks)
+        into = None if by_channel else _into(_gate(gamma_ref, h), masks)
         t, beta, du = t_ref[h], _gate(beta_ref, h), du_ref[a]
         dtb = _dot32(du, rhs_ref[a], -1, -1)
         dt_ref[h] = dtb * beta
         dbeta_ref[0, pl.ds(h, 1), :] = jnp.sum(dtb * t, axis=0, keepdims=True)
         drhs = _dot32(t * beta, du, -2, -2)
         dv_ref[h] = drhs.astype(dv_ref.dtype)
+        if by_channel:      # d((K * G) S_0) = -dR, the decay in the operand
+            dks_ref[a] = (-drhs).astype(dtype)
+            return
         dks_ref[a] = (-into * drhs).astype(dtype)
         dgamma_ref[0, pl.ds(h, 1), :] -= _row(
             jnp.sum(drhs * ks_ref[a], axis=1, keepdims=True) * into, diagonal)
@@ -621,12 +830,52 @@ def _walk_transposed_body(t_ref, qk_ref, q_ref, k_ref, v_ref, gamma_ref,
         dk_ref[j] = sum(dk_sum[a + i] for i in range(group)) \
             .astype(dk_ref.dtype)
 
+    def channel_reads(j, h, a):
+        rhs_ref[a] = _chunk_reads(
+            k_ref[j], v_ref[h], saved_ref[h].astype(dtype),
+            jnp.exp(gamma_ref[h]))[1]
+
+    def channel_cotangents(j, h, a):
+        gamma = gamma_ref[h]
+        into = jnp.exp(gamma)
+        to_end, carry = _channel_decays(gamma, key_masks)
+        state, dstate = saved_ref[h], dstate_ref[h]
+        s0, ds = state.astype(dtype), dstate.astype(dtype)
+        q, k, u, do = q_ref[j], k_ref[j], u_ref[a], do_ref[h]
+        dqk_ref[h] = _dot(do, u, -1, -1).astype(dqk_ref.dtype)
+        du_ref[a] = _dot(qk_ref[h], do, -2, -2) \
+            + _dot((to_end * k).astype(dtype), ds, -1, -2)
+        dk_out = _dot(u, ds, -1, -1)
+        dq = _dot(do, s0, -1, -1)
+        dq_sum[a], dk_sum[a] = into * dq, to_end * dk_out
+        dto_end = dk_out * k * to_end
+        dend = jnp.sum(dto_end, axis=0, keepdims=True) + _row(
+            carry * jnp.sum(dstate * state, axis=1, keepdims=True),
+            key_masks[0])
+        dgamma_ref[h] = dq * q * into - dto_end \
+            + jnp.where(last_row, dend, 0.0)
+
+    def channel_through_reads(j, h, a):
+        gamma = gamma_ref[h]
+        into, carry = jnp.exp(gamma), _channel_carry(gamma, key_masks)
+        q, k, dks = q_ref[j], k_ref[j], dks_ref[a]
+        dkg = _dot(dks, saved_ref[h].astype(dtype), -1, -1)
+        dk_sum[a] += into * dkg
+        dgamma_ref[h] += dkg * k * into
+        dstate_ref[h] = (carry * dstate_ref[h]
+                         + _dot((into * q).astype(dtype), do_ref[h], -2, -2)
+                         + _dot((into * k).astype(dtype), dks, -2, -2))
+
+    if by_channel:
+        reads, cotangents, through_reads = (
+            channel_reads, channel_cotangents, channel_through_reads)
     _for_trips(dstate_ref.shape[0], group, trip, (
         (reads, 1), (writes, 1), (cotangents, 1), (through_writes, 1),
         (through_reads, 1), (sums, group)))
 
 
-def _walk_call(body, name, ins, outs, heads, reverse, interpret):
+def _walk_call(body, name, ins, outs, heads, reverse, interpret,
+               by_channel=False):
     """``pallas_call`` of a walk: grid (batch, head blocks, chunks), the
     chunks in order (from a row's end where ``reverse``), on ``ins`` and for
     ``outs`` (ShapeDtypeStructs).  Of a stacked array ``(n, b, x, ...)`` a
@@ -634,12 +883,15 @@ def _walk_call(body, name, ins, outs, heads, reverse, interpret):
     the value heads, their key heads of q and k (the same block index on
     fewer heads: no repeat), the one block of ``heads`` rows of the gates
     ``(n, b, blocks, heads, chunk)``; of a row's one state ``(b, h, d_k,
-    d_v)`` it is those heads', the same for every chunk."""
+    d_v)`` it is those heads', the same for every chunk.  ``by_channel``:
+    ``gamma`` (and its cotangent) is ``(n, b, h, chunk, d_k)``, a block of
+    ``heads`` of its heads as v's is."""
     t, _, q, _, v = ins[:5]
     n, b, h = t.shape[:3]
     blocks, group = h // heads, h // q.shape[2]
     chunk, d_k, d_v = t.shape[-1], q.shape[-1], v.shape[-1]
-    trip = _trip_heads(heads, group, chunk, d_k, d_v, v.dtype, reverse)
+    trip = _trip_heads(heads, group, chunk, d_k, d_v, v.dtype, reverse,
+                       by_channel)
 
     def spec(x):
         if x.ndim == 4:
@@ -650,13 +902,14 @@ def _walk_call(body, name, ins, outs, heads, reverse, interpret):
             lambda i, j, c: ((n - 1 - c if reverse else c), i, j, 0, 0))
 
     return pl.pallas_call(
-        functools.partial(body, group=group, trip=trip),
+        functools.partial(body, group=group, trip=trip,
+                          by_channel=by_channel),
         grid=(b, blocks, n),
         in_specs=[spec(x) for x in ins], out_specs=[spec(x) for x in outs],
         out_shape=outs,
         scratch_shapes=[pltpu.VMEM((heads, d_k, d_v), jnp.float32)] + [
-            pltpu.VMEM(*stage) for stage in _stages(trip, chunk, d_k, d_v,
-                                                    v.dtype, reverse)],
+            pltpu.VMEM(*stage) for stage in _stages(
+                trip, chunk, d_k, d_v, v.dtype, reverse, by_channel)],
         # Only the walk over the chunks carries the scratch.
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -680,15 +933,17 @@ def _walk_forward(terms, head_block, interpret, save):
     """``(final state, o, each chunk's incoming state or None)``."""
     t, _, q, _, v, gamma, beta = terms
     n, b, h = t.shape[:3]
-    ins = terms[:5] + (_gate_rows(gamma, head_block),
+    by_channel = gamma.ndim == 5
+    ins = terms[:5] + (gamma if by_channel
+                       else _gate_rows(gamma, head_block),
                        _gate_rows(beta, head_block))
     state = _sds((b, h, q.shape[-1], v.shape[-1]), jnp.float32, *terms)
     outs = [_sds(v.shape, v.dtype, *terms)]
     if save:
         outs.append(_sds((n,) + state.shape, jnp.float32, *terms))
     o, *saved, final = _walk_call(
-        _walk_forward_body, "gdn_walk_fwd", ins, outs + [state], head_block,
-        False, interpret)
+        _walk_forward_body, "kda_walk_fwd" if by_channel else "gdn_walk_fwd",
+        ins, outs + [state], head_block, False, interpret, by_channel)
     return final, o, (saved[0] if save else None)
 
 
@@ -719,12 +974,15 @@ def _walk_bwd(head_block, interpret, res, cotangents):
 def _walk_transposed(terms, saved, dfinal, do, head_block, interpret):
     """The cotangents of ``_walk``'s seven terms from those of its final
     state and ``o``."""
-    rows = [_gate_rows(x, head_block) for x in terms[5:]]
+    by_channel = terms[5].ndim == 5
+    rows = [terms[5] if by_channel else _gate_rows(terms[5], head_block),
+            _gate_rows(terms[6], head_block)]
     ins = terms[:5] + tuple(rows) + (saved, do, dfinal)
     *dterms, dgamma, dbeta = _walk_call(
-        _walk_transposed_body, "gdn_walk_bwd", ins,
+        _walk_transposed_body,
+        "kda_walk_bwd" if by_channel else "gdn_walk_bwd", ins,
         [_sds(x.shape, x.dtype, *ins) for x in terms[:5] + tuple(rows)],
-        head_block, True, interpret)
+        head_block, True, interpret, by_channel)
     return (*dterms, dgamma.reshape(terms[5].shape),
             dbeta.reshape(terms[6].shape))
 
@@ -746,6 +1004,8 @@ def _chunk_terms(q, k, g, beta, dtype):
     decays as a broadcast.  Either walk takes these with q, k, v and
     ``beta`` as they are and makes the ``chunk`` x d terms a chunk at a
     time (``_head_step``)."""
+    if g.ndim == 5:
+        return _chunk_terms_by_channel(q, k, g, beta, dtype)
     chunk = g.shape[-1]
     group = g.shape[2] // k.shape[2]
 
@@ -763,6 +1023,54 @@ def _chunk_terms(q, k, g, beta, dtype):
         t = unit_lower_inverse(a)
     qk = decay * of_value_heads(_mm("nbhid,nbhjd->nbhij", q, k, dtype))
     return t, qk.astype(dtype), gamma
+
+
+def _chunk_terms_by_channel(q, k, g, beta, dtype):
+    """``_chunk_terms`` with a decay a channel, ``g`` (n, b, h, chunk, d_k):
+    ``A_ij = beta_i sum_c k_ic k_jc exp(gamma_ic - gamma_jc)`` and ``P_ij =
+    sum_c q_ic k_jc exp(gamma_ic - gamma_jc)`` are no masked products, so
+    the decay rides on the operands, a sub-block of ``SUB_BLOCK`` positions
+    at a time: with ``r_I`` the summed log decay at sub-block ``I``'s first
+    position, rows ``i`` in ``I`` take ``exp(gamma_i - r_I)`` <= 1 and
+    columns ``j`` in ``J`` <= ``I`` take ``exp(r_I - gamma_j)``, <= 1 for an
+    earlier sub-block and at most ``exp(-GATE_LOWER_BOUND x (SUB_BLOCK -
+    1))`` inside ``I`` itself; one product a pair of sub-blocks (q's rows
+    and k's share the columns), float32 sums, what lies above the diagonal
+    masked after.  The columns' operand is ``chunk / SUB_BLOCK`` copies of k
+    in ``dtype``, each under another ``r_I``; nothing is ``chunk`` x
+    ``chunk`` x d_k.  Gives ``T``, ``P`` in ``dtype`` and ``gamma`` (n, b,
+    h, chunk, d_k)."""
+    n, b, h, chunk, d_k = g.shape
+    group = h // k.shape[2]
+    sub = _sub_block(chunk)
+    m = chunk // sub
+
+    def sub_blocks(x):  # (n, b, key heads, chunk, d) -> (n, b, h, m, sub, d)
+        x = x if group == 1 else jnp.repeat(x, group, axis=2)
+        return x.reshape(n, b, h, m, sub, d_k)
+
+    gamma = jnp.cumsum(g, axis=-2)
+    by_block = gamma.reshape(n, b, h, m, sub, d_k)
+    first = by_block[..., :1, :]
+    # (I, i) rows against (I, c) columns: the chunk's positions c as
+    # sub-block I's rows meet them, nothing where c lies in a later one.
+    earlier = (jnp.arange(m)[:, None] >= jnp.arange(chunk) // sub)[..., None]
+    columns = jnp.exp(jnp.where(
+        earlier, first - gamma[:, :, :, None], -jnp.inf))
+    rows = jnp.exp(by_block - first)
+    q, k = sub_blocks(q), sub_blocks(k)
+    both = jnp.einsum(
+        "nbhIid,nbhIcd->nbhIic",
+        jnp.concatenate([k * rows, q * rows], axis=-2).astype(dtype),
+        (k.reshape(n, b, h, 1, chunk, d_k) * columns).astype(dtype),
+        preferred_element_type=jnp.float32)
+    kk, qk = (x.reshape(n, b, h, chunk, chunk)
+              for x in (both[..., :sub, :], both[..., sub:, :]))
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    a = jnp.where(jnp.tril(lower, -1), beta[..., None] * kk, 0.0)
+    with jax.named_scope("inverse"):
+        t = unit_lower_inverse(a)
+    return t, jnp.where(lower, qk, 0.0).astype(dtype), gamma
 
 
 def inverse_form(chunk):

@@ -80,7 +80,8 @@ class MoEConfig:
                  dtype=jnp.float32, capacity_factor=1.25, expert="gelu",
                  norm_topk=True, scoring="softmax", route_scale=1.0,
                  shared=0, select_bias=False, bias_update_rate=0.0,
-                 held=None, shared_gate=False, held_chunks=None):
+                 held=None, shared_gate=False, held_chunks=None, groups=None,
+                 groups_kept=None):
         if expert not in EXPERT_KINDS:
             raise ValueError(f"expert must be one of {EXPERT_KINDS}, got "
                              f"{expert!r}")
@@ -107,6 +108,16 @@ class MoEConfig:
                 f"held_chunks splits a held layer's assignments: it needs "
                 f"held and a count >= 1, got {held_chunks!r} with held = "
                 f"{held!r}")
+        if (groups is None) != (groups_kept is None) or groups is not None \
+                and not (scoring == "sigmoid" and num_experts % groups == 0
+                         and 0 < groups_kept <= groups
+                         and top_k <= groups_kept * (num_experts // groups)):
+            raise ValueError(
+                f"groups / groups_kept limit a sigmoid router's choice to "
+                f"groups_kept of groups equal groups of consecutive experts "
+                f"that hold top_k between them, got {groups!r} / "
+                f"{groups_kept!r} with {num_experts} experts, top_k {top_k} "
+                f"and scoring {scoring!r}")
         self.num_experts = num_experts
         self.top_k = top_k
         self.d_model = d_model
@@ -151,6 +162,12 @@ class MoEConfig:
         # count that puts its share well inside a chunk.
         self.held_chunks = (_HELD_CHUNKS if held_chunks is None
                             else int(held_chunks))
+        # The group-limited choice (DeepSeek-V3's; sigmoid scoring): the
+        # experts are ``groups`` groups of consecutive ids, a group's score
+        # is the sum of its two largest score-plus-bias, and a token's
+        # ``top_k`` are chosen inside the ``groups_kept`` best groups
+        # (:func:`_route_sigmoid` alone reads these); None: no limit.
+        self.groups, self.groups_kept = groups, groups_kept
 
     @property
     def num_held(self):
@@ -393,6 +410,9 @@ def _announce(cfg, assignments):
     registry.gauge("moe.experts_held").set(cfg.num_held)
     registry.gauge("moe.softmax_scoring").set(int(cfg.scoring == "softmax"))
     registry.gauge("moe.shared_gate").set(int(cfg.shared_gate))
+    if cfg.groups:
+        registry.gauge("moe.groups").set(cfg.groups)
+        registry.gauge("moe.groups_kept").set(cfg.groups_kept)
     rungs = (held_rungs(assignments, cfg.held_chunks)
              if cfg.held is not None else ())
     registry.gauge("moe.held_buffer_rungs").set(len(rungs))
@@ -412,7 +432,9 @@ def _announce(cfg, assignments):
                    + f"), {cfg.shared} shared"
                    + (" times the sigmoid of a scalar a token"
                       if cfg.shared_gate else "")
-                   + (", selection bias" if cfg.select_bias else ""))
+                   + (", selection bias" if cfg.select_bias else "")
+                   + (f", chosen inside the {cfg.groups_kept} best of "
+                      f"{cfg.groups} groups" if cfg.groups else ""))
     if detail not in _announced:
         _announced.add(detail)
         observability.record_event("moe", detail)
@@ -479,12 +501,29 @@ def _route_sigmoid(logits, params, cfg):
     scores = jax.nn.sigmoid(logits)
     choice = scores + jax.lax.stop_gradient(params["bias"]) \
         if cfg.select_bias else scores
+    if cfg.groups:
+        with jax.named_scope("groups"):
+            choice = _kept_groups(choice, cfg)
     _, top_idx = jax.lax.top_k(choice, cfg.top_k)
     top_vals = jnp.take_along_axis(scores, top_idx, axis=-1)
     if cfg.norm_topk:
         top_vals = top_vals / (top_vals.sum(-1, keepdims=True) + 1e-20)
     return (top_vals * cfg.route_scale, top_idx,
             scores / scores.sum(-1, keepdims=True))
+
+
+def _kept_groups(choice, cfg):
+    """``choice`` (T, E), the scores a token chooses by, with every expert
+    outside the token's ``cfg.groups_kept`` best groups at ``-inf``: a group
+    is ``E / cfg.groups`` consecutive experts, its score the sum of its two
+    largest entries, and ties go to the lower group, as ``top_k``'s go to
+    the lower expert."""
+    tokens, experts = choice.shape
+    grouped = choice.reshape(tokens, cfg.groups, experts // cfg.groups)
+    best = jax.lax.top_k(grouped, min(2, grouped.shape[-1]))[0].sum(-1)
+    _, kept = jax.lax.top_k(best, cfg.groups_kept)
+    keep = jax.nn.one_hot(kept, cfg.groups, dtype=jnp.bool_).any(-2)
+    return jnp.where(keep[..., None], grouped, -jnp.inf).reshape(choice.shape)
 
 
 def _shared_expert(params, cfg, flat_x, gate=None):
@@ -666,7 +705,10 @@ def dropless_apply(params, cfg, x):
 
     The router runs in float32; a token's ``top_k`` weights are left as the
     softmax gave them unless ``cfg.norm_topk``, then times
-    ``cfg.route_scale`` (``cfg.scoring="sigmoid"``: :func:`_route_sigmoid`).
+    ``cfg.route_scale`` (``cfg.scoring="sigmoid"``: :func:`_route_sigmoid`,
+    with ``cfg.groups`` the choice limited to the best groups under the scope
+    ``router/groups``; ``stats["groups_reached"]`` is then the mean number
+    of groups a token's choices fall in).
     The ``T * k`` assignments
     are sorted by expert (stable, so an expert's rows keep the tokens'
     order), the tokens' rows gathered in that order, and each of the
@@ -751,6 +793,10 @@ def dropless_apply(params, cfg, x):
                 jax.nn.logsumexp(logits, axis=-1) ** 2)
         stats["load_max_over_mean"] = group_sizes.max().astype(jnp.float32) \
             * num_e / (tokens * top_k)
+        if cfg.groups:
+            # The groups a token's choices fall in, mean over the tokens.
+            stats["groups_reached"] = jnp.mean(jnp.sum(jax.nn.one_hot(
+                top_idx // (num_e // cfg.groups), cfg.groups).max(-2), -1))
         if cfg.select_bias:
             load = group_sizes.astype(jnp.float32)
             bias = params["bias"] + cfg.bias_update_rate * jnp.sign(

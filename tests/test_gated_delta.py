@@ -483,3 +483,54 @@ def test_the_trace_announces_the_rule_once_a_shape():
     assert gauges["gdn.heads"] == HEADS and gauges["gdn.chunk"] == 16
     assert gauges["gdn.chunks_per_row"] == 3
     assert gauges["gdn.state_bytes_per_row"] == HEADS * D_K * D_V * 4
+
+
+# ---------------------------------------------------------------------------
+# a scalar decay traces the program it traced before the rule took a decay a
+# channel
+
+
+def _traced_rule(heads, key_heads, d_k, d_v, interpret):
+    """The text of ``grad`` of the rule at a cell's heads over two chunks
+    (bf16 operands, float32 gates, cotangents on ``o`` and on the final
+    state; nothing runs), the addresses of the functions it names struck."""
+    import hashlib
+    import re
+    sds = jax.ShapeDtypeStruct
+    qk = sds((1, 128, key_heads, d_k), jnp.bfloat16)
+    v = sds((1, 128, heads, d_v), jnp.bfloat16)
+    gate = sds((1, 128, heads), jnp.float32)
+
+    def f(*a):
+        o, state = gated_delta_rule(*a, interpret=interpret)
+        return jnp.sum(o.astype(jnp.float32)) + jnp.sum(state)
+
+    text = str(jax.make_jaxpr(jax.grad(f, argnums=(0, 1, 2, 3, 4)))(
+        qk, qk, v, gate, gate))
+    return hashlib.sha1(re.sub(r"0x[0-9a-f]+", "0x", text).encode()) \
+        .hexdigest()[:16]
+
+
+# Pinned on PR 46's tree (2afff19), before ``ops/gated_delta.py`` took a decay
+# a channel: the whole traced program, terms, kernels' bodies (or the scan's
+# step) and their transposes, of the two cells that run the scalar rule.
+_SCALAR_DECAY = {
+    ("olmo-hybrid-7b.train-s4096", False): (
+        (30, 30, 96, 192), "07bb6de77f1d20af"),
+    ("olmo-hybrid-7b.train-s4096", None): (
+        (30, 30, 96, 192), "e5ee061208f42c62"),
+    ("qwen3-next-80b-a3b.train-s8192", False): (
+        (32, 16, 128, 128), "2987eb1646415507"),
+    ("qwen3-next-80b-a3b.train-s8192", None): (
+        (32, 16, 128, 128), "02ed9c2eb1deb2c5"),
+}
+
+
+@pytest.mark.parametrize("cell, interpret", list(_SCALAR_DECAY))
+def test_a_scalar_decay_traces_the_program_it_traced(cell, interpret):
+    """With ``g`` a scalar a head and position the rule's traced program is
+    the parent's, text for text: the kernels (``interpret=False``: the form
+    the chip runs) and the scan, at the heads of the two cells whose decay is
+    a scalar, so the vector form cannot move either."""
+    shape, want = _SCALAR_DECAY[cell, interpret]
+    assert _traced_rule(*shape, interpret) == want

@@ -169,7 +169,7 @@ def test_the_published_configuration_counts_its_parameters():
 @pytest.mark.parametrize("wrong, message", [
     (dict(mtp_depth=2), "one block deep"),
     (dict(scan_layers=True), "scan_layers"),
-    (dict(q_rank=0), "q_rank"),
+    (dict(kv_rank=0), "kv_rank"),
     (dict(expert_scoring="tanh"), "scoring")])
 def test_the_configuration_refuses_what_it_cannot_build(wrong, message):
     with pytest.raises((ValueError, NotImplementedError), match=message):

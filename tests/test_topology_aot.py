@@ -860,3 +860,38 @@ def test_v5e_compiler_keeps_the_scatter_in_the_backward_pass(tmp_path,
     in_flight = grad_scatter.PERMUTES_IN_FLIGHT * 2 * (6400 * 1600 * 4 // 4)
     assert change.memory_analysis().temp_size_in_bytes <= \
         parent.memory_analysis().temp_size_in_bytes + in_flight
+
+
+def test_v5e_compiler_takes_the_walk_with_a_decay_a_channel():
+    """The delta rule with a decay a channel at the Ling cell's heads (32 of
+    128 / 128, bf16; 512 positions), forward and backward, compiled by libtpu
+    for one detached v5e chip as a TPU process traces it: the walk over the
+    chunks is the two Mosaic kernels ``kda_walk_fwd`` and ``kda_walk_bwd``
+    (``gamma`` a (8, 64, 128) float32 block, its cotangent as wide; Mosaic
+    takes the row of ``gamma_C`` as the column that scales the state's rows)
+    and no ``while`` is left; the columns' operand of the sub-block form is
+    four copies of k and nothing is 64 x 64 x 128 a head."""
+    why_not = _why_no_detached_topology()
+    if why_not:
+        pytest.skip(why_not)
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from autodist_tpu.ops.gated_delta import gated_delta_rule
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x4")
+    chip = SingleDeviceSharding(topo.devices[0])
+    s, heads, d = 512, 32, 128
+    x = jax.ShapeDtypeStruct((1, s, heads, d), jnp.bfloat16, sharding=chip)
+    g = jax.ShapeDtypeStruct((1, s, heads, d), jnp.float32, sharding=chip)
+    beta = jax.ShapeDtypeStruct((1, s, heads), jnp.float32, sharding=chip)
+
+    def loss(*args):
+        o, state = gated_delta_rule(*args, interpret=False)
+        return (o.astype(jnp.float32) ** 2).sum() + state.sum()
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))) \
+        .lower(x, x, x, g, beta).compile().as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 2 and " while(" not in text
+    assert any("kda_walk_fwd" in c for c in calls) \
+        and any("kda_walk_bwd" in c for c in calls)
+    assert not re.search(rf"\[{s // 64},1,{heads},64,64,{d}\]", text)
